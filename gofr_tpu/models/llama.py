@@ -361,7 +361,8 @@ def params_nbytes(params) -> int:
                if hasattr(leaf, "nbytes"))
 
 
-def _attention_block(x, layer, k_cache_l, v_cache_l, positions, cfg: LlamaConfig):
+def _attention_block(x, layer, k_cache_l, v_cache_l, positions, cfg: LlamaConfig,
+                     mesh=None):
     """One attention sublayer with cache write + masked read.
 
     x: [B, T, D]; k/v_cache_l: [B, Hkv, dh, S] (S-minor, see init_kv_cache);
@@ -376,6 +377,11 @@ def _attention_block(x, layer, k_cache_l, v_cache_l, positions, cfg: LlamaConfig
     cfg.attn_impl == "flash", attention runs through the Pallas flash
     kernel on the fresh k/v tensors — no [T, S] score materialization in
     HBM and no layout shuffling of the cache.
+
+    mesh: the engine's tensor-parallel mesh, or None. Every cache-aware
+    step function below takes it and hands it to the Pallas kernels, which
+    run per tp shard under shard_map (ops/paged_attention.paged_attention
+    says why); the XLA attention paths partition under plain jit.
     """
     B, T, D = x.shape
     S = k_cache_l.shape[-1]
@@ -398,7 +404,7 @@ def _attention_block(x, layer, k_cache_l, v_cache_l, positions, cfg: LlamaConfig
     if T == S and cfg.attn_impl == "flash":
         from ..ops.flash_attention import flash_attention
 
-        attn = flash_attention(q, k, v, True)  # [B, T, H, dh]
+        attn = flash_attention(q, k, v, True, mesh=mesh)  # [B, T, H, dh]
         out = _mm(attn.reshape(B, T, H * dh), layer, "wo")
         return out, k_cache_l, v_cache_l
 
@@ -408,7 +414,7 @@ def _attention_block(x, layer, k_cache_l, v_cache_l, positions, cfg: LlamaConfig
         # the scatter above put this step's k/v at `positions`, so the live
         # window is [0, positions] inclusive — lengths = positions + 1
         attn = decode_attention(q[:, 0], k_cache_l, v_cache_l,
-                                positions[:, 0] + 1)        # [B, H, dh]
+                                positions[:, 0] + 1, mesh=mesh)  # [B, H, dh]
         out = _mm(attn.reshape(B, 1, H * dh), layer, "wo")
         return out, k_cache_l, v_cache_l
 
@@ -439,7 +445,7 @@ def _ffn_block(x, layer, cfg: LlamaConfig):
 
 
 def llama_forward_hidden(params, cfg: LlamaConfig, tokens, positions, k_cache,
-                         v_cache):
+                         v_cache, mesh=None):
     """Cache-writing forward returning final-norm hidden states, NOT logits.
 
     tokens: [B, T] int32; positions: [B, T] absolute positions (row-wise
@@ -456,7 +462,8 @@ def llama_forward_hidden(params, cfg: LlamaConfig, tokens, positions, k_cache,
 
     def body(x, scan_in):
         layer, k_l, v_l = scan_in
-        attn_out, k_l, v_l = _attention_block(x, layer, k_l, v_l, positions, cfg)
+        attn_out, k_l, v_l = _attention_block(x, layer, k_l, v_l, positions,
+                                              cfg, mesh)
         x = x + attn_out
         x = x + _ffn_block(x, layer, cfg)
         return x, (k_l, v_l)
@@ -467,7 +474,8 @@ def llama_forward_hidden(params, cfg: LlamaConfig, tokens, positions, k_cache,
     return x, k_cache, v_cache
 
 
-def llama_forward(params, cfg: LlamaConfig, tokens, positions, k_cache, v_cache):
+def llama_forward(params, cfg: LlamaConfig, tokens, positions, k_cache, v_cache,
+                  mesh=None):
     """Cache-writing forward over a token chunk.
 
     tokens: [B, T] int32; positions: [B, T] absolute positions (row-wise
@@ -475,13 +483,13 @@ def llama_forward(params, cfg: LlamaConfig, tokens, positions, k_cache, v_cache)
     Returns (logits [B, T, V] float32, k_cache, v_cache).
     """
     x, k_cache, v_cache = llama_forward_hidden(params, cfg, tokens, positions,
-                                               k_cache, v_cache)
+                                               k_cache, v_cache, mesh)
     logits = _head(x, params)
     return logits, k_cache, v_cache
 
 
 def llama_prefill_last(params, cfg: LlamaConfig, tokens, positions, lengths,
-                       k_cache, v_cache):
+                       k_cache, v_cache, mesh=None):
     """Prefill forward that projects ONLY each row's last prompt position.
 
     tokens: [B, T]; positions: [B, T]; lengths: [B] true prompt lengths.
@@ -492,7 +500,7 @@ def llama_prefill_last(params, cfg: LlamaConfig, tokens, positions, lengths,
     T× wasted head FLOPs (VERDICT r2 missing #3).
     """
     hidden, k_cache, v_cache = llama_forward_hidden(
-        params, cfg, tokens, positions, k_cache, v_cache)
+        params, cfg, tokens, positions, k_cache, v_cache, mesh)
     B = hidden.shape[0]
     last = hidden[jnp.arange(B), lengths - 1]  # [B, D]
     logits = _head(last, params)
@@ -542,7 +550,7 @@ def init_kv_cache_layers(cfg: LlamaConfig, batch: int,
 
 
 def llama_decode_step_unrolled(params, cfg: LlamaConfig, tokens, positions,
-                               k_layers, v_layers):
+                               k_layers, v_layers, mesh=None):
     """One decode step over PER-LAYER cache buffers (python-unrolled loop).
 
     tokens: [B]; positions: [B]; k/v_layers: tuples of L [B, Hkv, dh, S]
@@ -557,7 +565,7 @@ def llama_decode_step_unrolled(params, cfg: LlamaConfig, tokens, positions,
     for l in range(cfg.n_layers):
         layer = jax.tree_util.tree_map(lambda w: w[l], params["layers"])
         attn, k_l, v_l = _attention_block(x, layer, k_layers[l], v_layers[l],
-                                          pos_grid, cfg)
+                                          pos_grid, cfg, mesh)
         x = x + attn
         x = x + _ffn_block(x, layer, cfg)
         k_out.append(k_l)
@@ -582,7 +590,8 @@ def init_kv_scale_layers(cfg: LlamaConfig, batch: int,
 
 
 def llama_decode_step_unrolled_q8(params, cfg: LlamaConfig, tokens, positions,
-                                  k_layers, v_layers, ks_layers, vs_layers):
+                                  k_layers, v_layers, ks_layers, vs_layers,
+                                  mesh=None):
     """One decode step over INT8 per-layer caches with per-token scales.
 
     tokens/positions: [B]; k/v_layers: tuples of [B, Hkv, dh, S] int8;
@@ -621,7 +630,7 @@ def llama_decode_step_unrolled_q8(params, cfg: LlamaConfig, tokens, positions,
         ks_out[l] = ks_out[l].at[batch_idx, :, positions].set(ks)
         vs_out[l] = vs_out[l].at[batch_idx, :, positions].set(vs)
         attn = decode_attention(q[:, 0], k_out[l], v_out[l], positions + 1,
-                                ks_out[l], vs_out[l])      # [B, H, dh]
+                                ks_out[l], vs_out[l], mesh=mesh)  # [B, H, dh]
         x = x + _mm(attn.reshape(B, 1, H * dh), layer, "wo")
         x = x + _ffn_block(x, layer, cfg)
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)
@@ -669,7 +678,8 @@ def llama_decode_step_inplace(params, cfg: LlamaConfig, tokens, positions,
 
 
 def llama_prefill_chunk(params, cfg: LlamaConfig, tokens, positions,
-                        k_layers, v_layers, slots, project_last=None):
+                        k_layers, v_layers, slots, project_last=None,
+                        mesh=None):
     """One CHUNK of a cached prefill over the per-layer serving caches.
 
     tokens: [K, C] the chunk's token ids; positions: [K, C] their absolute
@@ -699,7 +709,7 @@ def llama_prefill_chunk(params, cfg: LlamaConfig, tokens, positions,
         k_rows = k_out[l][slots]                           # [K, Hkv, dh, S]
         v_rows = v_out[l][slots]
         attn, k_rows, v_rows = _attention_block(x, layer, k_rows, v_rows,
-                                                positions, cfg)
+                                                positions, cfg, mesh)
         x = x + attn
         x = x + _ffn_block(x, layer, cfg)
         k_out[l] = k_out[l].at[slots].set(k_rows)
@@ -714,7 +724,7 @@ def llama_prefill_chunk(params, cfg: LlamaConfig, tokens, positions,
 
 
 def llama_verify_step(params, cfg: LlamaConfig, tokens, drafts, positions,
-                      k_layers, v_layers):
+                      k_layers, v_layers, mesh=None):
     """Speculative-decode VERIFY: score the current token plus d drafted
     tokens for every slot in ONE forward.
 
@@ -756,7 +766,7 @@ def llama_verify_step(params, cfg: LlamaConfig, tokens, drafts, positions,
     for l in range(cfg.n_layers):
         layer = jax.tree_util.tree_map(lambda w: w[l], params["layers"])
         attn, k_l, v_l = _attention_block(x, layer, k_layers[l], v_layers[l],
-                                          pos_grid, cfg)
+                                          pos_grid, cfg, mesh)
         x = x + attn
         x = x + _ffn_block(x, layer, cfg)
         k_out.append(k_l)
@@ -857,7 +867,7 @@ def llama_prefill_chunk_q8(params, cfg: LlamaConfig, tokens, positions,
 
 
 def llama_decode_step_paged(params, cfg: LlamaConfig, tokens, positions,
-                            k_pool, v_pool, table):
+                            k_pool, v_pool, table, mesh=None):
     """One decode step against a PAGED KV cache.
 
     tokens: [B]; positions: [B] absolute write positions; k/v_pool:
@@ -870,9 +880,10 @@ def llama_decode_step_paged(params, cfg: LlamaConfig, tokens, positions,
     Pallas kernel (paged_attention) — per-step HBM traffic tracks the
     table width (live pages), not a dense [B, S] allocation.
 
-    Pools are carried through a fori_loop with per-layer DUS (not scan
-    xs/ys) for the same in-place aliasing reason as
-    llama_decode_step_inplace.
+    The STACKED pools are carried whole through a fori_loop and handed to
+    both kernels with the layer index; neither a slice of one layer nor an
+    XLA scatter ever touches them (ops/paged_attention's module docstring
+    says what either costs on the chip).
     """
     from ..ops.paged_attention import paged_attention, paged_write_decode
 
@@ -884,21 +895,19 @@ def llama_decode_step_paged(params, cfg: LlamaConfig, tokens, positions,
     def layer_body(l, state):
         x, k_pool, v_pool = state
         layer = jax.tree_util.tree_map(lambda w: w[l], params["layers"])
-        kp_l = jax.lax.dynamic_index_in_dim(k_pool, l, 0, keepdims=False)
-        vp_l = jax.lax.dynamic_index_in_dim(v_pool, l, 0, keepdims=False)
         normed = rms_norm(x, layer["attn_norm"], cfg.rms_eps)
         q = rope(_mm(normed, layer, "wq").reshape(B, 1, H, dh), pos_grid,
                  cfg.rope_theta)
         k = rope(_mm(normed, layer, "wk").reshape(B, 1, Hkv, dh), pos_grid,
                  cfg.rope_theta)
         v = _mm(normed, layer, "wv").reshape(B, 1, Hkv, dh)
-        kp_l, vp_l = paged_write_decode(kp_l, vp_l, k[:, 0], v[:, 0],
-                                        table, positions)
-        attn = paged_attention(q[:, 0], kp_l, vp_l, table, positions + 1)
+        k_pool, v_pool = paged_write_decode(
+            k_pool, v_pool, k[:, 0], v[:, 0], table, positions,
+            layer=l, mesh=mesh)
+        attn = paged_attention(q[:, 0], k_pool, v_pool, table, positions + 1,
+                               layer=l, mesh=mesh)
         x = x + _mm(attn.reshape(B, 1, H * dh), layer, "wo")
         x = x + _ffn_block(x, layer, cfg)
-        k_pool = jax.lax.dynamic_update_index_in_dim(k_pool, kp_l, l, 0)
-        v_pool = jax.lax.dynamic_update_index_in_dim(v_pool, vp_l, l, 0)
         return x, k_pool, v_pool
 
     x, k_pool, v_pool = jax.lax.fori_loop(
@@ -909,7 +918,8 @@ def llama_decode_step_paged(params, cfg: LlamaConfig, tokens, positions,
 
 
 def llama_decode_step_paged_q8(params, cfg: LlamaConfig, tokens, positions,
-                               k_pool, v_pool, ks_pool, vs_pool, table):
+                               k_pool, v_pool, ks_pool, vs_pool, table,
+                               mesh=None):
     """One decode step against an INT8 paged KV pool.
 
     MIRRORS llama_decode_step_paged with per-token scales: k/v_pool are
@@ -926,18 +936,10 @@ def llama_decode_step_paged_q8(params, cfg: LlamaConfig, tokens, positions,
     H, Hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     x = _embed(params, cfg, tokens)[:, None]               # [B, 1, D]
     pos_grid = positions[:, None]
-    ps = k_pool.shape[-1]
-    # scale writes share the value writer's index rule (paged_write_decode)
-    page_ids = table[jnp.arange(B), positions // ps]       # [B]
-    offsets = positions % ps
 
     def layer_body(l, state):
         x, k_pool, v_pool, ks_pool, vs_pool = state
         layer = jax.tree_util.tree_map(lambda w: w[l], params["layers"])
-        kp_l = jax.lax.dynamic_index_in_dim(k_pool, l, 0, keepdims=False)
-        vp_l = jax.lax.dynamic_index_in_dim(v_pool, l, 0, keepdims=False)
-        ksp_l = jax.lax.dynamic_index_in_dim(ks_pool, l, 0, keepdims=False)
-        vsp_l = jax.lax.dynamic_index_in_dim(vs_pool, l, 0, keepdims=False)
         normed = rms_norm(x, layer["attn_norm"], cfg.rms_eps)
         q = rope(_mm(normed, layer, "wq").reshape(B, 1, H, dh), pos_grid,
                  cfg.rope_theta)
@@ -946,17 +948,13 @@ def llama_decode_step_paged_q8(params, cfg: LlamaConfig, tokens, positions,
         v = _mm(normed, layer, "wv").reshape(B, 1, Hkv, dh)
         k8, ks = quantize_kv(k[:, 0], axis=-1)             # [B,Hkv,dh],[B,Hkv]
         v8, vs = quantize_kv(v[:, 0], axis=-1)
-        kp_l, vp_l = paged_write_decode(kp_l, vp_l, k8, v8, table, positions)
-        ksp_l = ksp_l.at[page_ids, :, offsets].set(ks)
-        vsp_l = vsp_l.at[page_ids, :, offsets].set(vs)
-        attn = paged_attention(q[:, 0], kp_l, vp_l, table, positions + 1,
-                               ksp_l, vsp_l)
+        k_pool, v_pool, ks_pool, vs_pool = paged_write_decode(
+            k_pool, v_pool, k8, v8, table, positions, ks_pool, vs_pool,
+            ks, vs, layer=l, mesh=mesh)
+        attn = paged_attention(q[:, 0], k_pool, v_pool, table, positions + 1,
+                               ks_pool, vs_pool, layer=l, mesh=mesh)
         x = x + _mm(attn.reshape(B, 1, H * dh), layer, "wo")
         x = x + _ffn_block(x, layer, cfg)
-        k_pool = jax.lax.dynamic_update_index_in_dim(k_pool, kp_l, l, 0)
-        v_pool = jax.lax.dynamic_update_index_in_dim(v_pool, vp_l, l, 0)
-        ks_pool = jax.lax.dynamic_update_index_in_dim(ks_pool, ksp_l, l, 0)
-        vs_pool = jax.lax.dynamic_update_index_in_dim(vs_pool, vsp_l, l, 0)
         return x, k_pool, v_pool, ks_pool, vs_pool
 
     x, k_pool, v_pool, ks_pool, vs_pool = jax.lax.fori_loop(
@@ -967,7 +965,7 @@ def llama_decode_step_paged_q8(params, cfg: LlamaConfig, tokens, positions,
 
 
 def llama_verify_step_paged(params, cfg: LlamaConfig, tokens, drafts,
-                            positions, k_pool, v_pool, table):
+                            positions, k_pool, v_pool, table, mesh=None):
     """Speculative-decode VERIFY against the PAGED pool.
 
     Same contract as llama_verify_step (score current token + d drafts in
@@ -1008,21 +1006,20 @@ def llama_verify_step_paged(params, cfg: LlamaConfig, tokens, drafts,
     def layer_body(l, state):
         x, k_pool, v_pool = state
         layer = jax.tree_util.tree_map(lambda w: w[l], params["layers"])
-        kp_l = jax.lax.dynamic_index_in_dim(k_pool, l, 0, keepdims=False)
-        vp_l = jax.lax.dynamic_index_in_dim(v_pool, l, 0, keepdims=False)
         normed = rms_norm(x, layer["attn_norm"], cfg.rms_eps)
         q = rope(_mm(normed, layer, "wq").reshape(B, d + 1, H, dh),
                  pos_grid, cfg.rope_theta)
         k = rope(_mm(normed, layer, "wk").reshape(B, d + 1, Hkv, dh),
                  pos_grid, cfg.rope_theta)
         v = _mm(normed, layer, "wv").reshape(B, d + 1, Hkv, dh)
-        # window scatter BEFORE the gather so the gathered rows already
+        # window write BEFORE the gather so the gathered rows already
         # contain this window's fresh K/V (the dense verify's .at[].set)
         for i in range(d + 1):
-            kp_l, vp_l = paged_write_decode(kp_l, vp_l, k[:, i], v[:, i],
-                                            table, positions + i)
-        k_rows = jnp.moveaxis(kp_l[table], 1, 3).reshape(B, Hkv, dh, S)
-        v_rows = jnp.moveaxis(vp_l[table], 1, 3).reshape(B, Hkv, dh, S)
+            k_pool, v_pool = paged_write_decode(
+                k_pool, v_pool, k[:, i], v[:, i], table, positions + i,
+                layer=l, mesh=mesh)
+        k_rows = jnp.moveaxis(k_pool[l, table], 1, 3).reshape(B, Hkv, dh, S)
+        v_rows = jnp.moveaxis(v_pool[l, table], 1, 3).reshape(B, Hkv, dh, S)
         qg = q.reshape(B, d + 1, Hkv, G, dh)
         scores = jnp.einsum("bthgd,bhds->bhgts", qg, k_rows,
                             preferred_element_type=jnp.float32
@@ -1036,8 +1033,6 @@ def llama_verify_step_paged(params, cfg: LlamaConfig, tokens, drafts,
                           preferred_element_type=jnp.float32).astype(x.dtype)
         x = x + _mm(attn.reshape(B, d + 1, H * dh), layer, "wo")
         x = x + _ffn_block(x, layer, cfg)
-        k_pool = jax.lax.dynamic_update_index_in_dim(k_pool, kp_l, l, 0)
-        v_pool = jax.lax.dynamic_update_index_in_dim(v_pool, vp_l, l, 0)
         return x, k_pool, v_pool
 
     x, k_pool, v_pool = jax.lax.fori_loop(
@@ -1071,46 +1066,41 @@ def llama_prefill_paged_prefix(params, cfg: LlamaConfig, tokens, prefix_lens,
     first, then the row's fresh pages); project_last: [K] within-window
     index of each row's last prompt token.
 
-    Per layer: tail K/V scatter into their pages (pad positions past
-    lengths[k] divert to the garbage page), then the tail queries attend
-    the GATHERED pages ([K, Hkv, dh, NP*ps] contiguous rows, one pool
-    read per layer — the same shape trick as llama_verify_step_paged)
-    under the standard `j <= q_pos` mask, which covers the shared prefix
-    and the tail's own causal window in one rule.
+    Per layer: the tail's K/V are written as whole pages into the row's
+    fresh pages (paged_write_window; the tail starts on a page boundary),
+    then the tail queries attend the GATHERED pages ([K, Hkv, dh, NP*ps]
+    contiguous rows, one pool read per layer — the same shape trick as
+    llama_verify_step_paged) under the standard `j <= q_pos` mask, which
+    covers the shared prefix and the tail's own causal window in one rule.
 
     Returns (last_logits [K, V] float32, k_pool, v_pool).
     """
+    from ..ops.paged_attention import paged_write_window
+
     K, T = tokens.shape
     H, Hkv, dh, G = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.q_per_kv
     ps = k_pool.shape[-1]
     NP = table.shape[1]
     S = NP * ps
     pos_grid = prefix_lens[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
-    in_prompt = pos_grid < lengths[:, None]                     # [K, T]
-    # scatter rule (shared with _prefill_scatter_indices' semantics):
-    # token at absolute pos -> (table[k, pos // ps], pos % ps); pads -> 0
-    page_slot = jnp.clip(pos_grid // ps, 0, NP - 1)
-    page_ids = jnp.take_along_axis(table, page_slot, axis=1)    # [K, T]
-    page_ids = jnp.where(in_prompt, page_ids, jnp.int32(0))
-    offsets = pos_grid % ps
     x = _embed(params, cfg, tokens)
 
     def layer_body(l, state):
         x, k_pool, v_pool = state
         layer = jax.tree_util.tree_map(lambda w: w[l], params["layers"])
-        kp_l = jax.lax.dynamic_index_in_dim(k_pool, l, 0, keepdims=False)
-        vp_l = jax.lax.dynamic_index_in_dim(v_pool, l, 0, keepdims=False)
         normed = rms_norm(x, layer["attn_norm"], cfg.rms_eps)
         q = rope(_mm(normed, layer, "wq").reshape(K, T, H, dh),
                  pos_grid, cfg.rope_theta)
         k = rope(_mm(normed, layer, "wk").reshape(K, T, Hkv, dh),
                  pos_grid, cfg.rope_theta)
         v = _mm(normed, layer, "wv").reshape(K, T, Hkv, dh)
-        # advanced indices on pool dims 0+3 -> value shape [K, T, Hkv, dh]
-        kp_l = kp_l.at[page_ids, :, :, offsets].set(k)
-        vp_l = vp_l.at[page_ids, :, :, offsets].set(v)
-        k_rows = jnp.moveaxis(kp_l[table], 1, 3).reshape(K, Hkv, dh, S)
-        v_rows = jnp.moveaxis(vp_l[table], 1, 3).reshape(K, Hkv, dh, S)
+        # [K, T, Hkv, dh] -> the writer's [1, K, Hkv, dh, T] window
+        k_pool = paged_write_window(k_pool, k.transpose(0, 2, 3, 1)[None],
+                                    table, prefix_lens, lengths, layer=l)
+        v_pool = paged_write_window(v_pool, v.transpose(0, 2, 3, 1)[None],
+                                    table, prefix_lens, lengths, layer=l)
+        k_rows = jnp.moveaxis(k_pool[l, table], 1, 3).reshape(K, Hkv, dh, S)
+        v_rows = jnp.moveaxis(v_pool[l, table], 1, 3).reshape(K, Hkv, dh, S)
         qg = q.reshape(K, T, Hkv, G, dh)
         scores = jnp.einsum("bthgd,bhds->bhgts", qg, k_rows,
                             preferred_element_type=jnp.float32
@@ -1124,8 +1114,6 @@ def llama_prefill_paged_prefix(params, cfg: LlamaConfig, tokens, prefix_lens,
                           preferred_element_type=jnp.float32).astype(x.dtype)
         x = x + _mm(attn.reshape(K, T, H * dh), layer, "wo")
         x = x + _ffn_block(x, layer, cfg)
-        k_pool = jax.lax.dynamic_update_index_in_dim(k_pool, kp_l, l, 0)
-        v_pool = jax.lax.dynamic_update_index_in_dim(v_pool, vp_l, l, 0)
         return x, k_pool, v_pool
 
     x, k_pool, v_pool = jax.lax.fori_loop(
@@ -1151,6 +1139,7 @@ def llama_prefill_paged_prefix_q8(params, cfg: LlamaConfig, tokens,
     Returns (last_logits [K, V] f32, k_pool, v_pool, ks_pool, vs_pool).
     """
     from ..ops.decode_attention import quantize_kv
+    from ..ops.paged_attention import paged_write_window
 
     K, T = tokens.shape
     H, Hkv, dh, G = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.q_per_kv
@@ -1159,20 +1148,15 @@ def llama_prefill_paged_prefix_q8(params, cfg: LlamaConfig, tokens,
     S = NP * ps
     dt = _np_dtype(cfg.dtype)
     pos_grid = prefix_lens[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
-    in_prompt = pos_grid < lengths[:, None]
-    page_slot = jnp.clip(pos_grid // ps, 0, NP - 1)
-    page_ids = jnp.take_along_axis(table, page_slot, axis=1)
-    page_ids = jnp.where(in_prompt, page_ids, jnp.int32(0))
-    offsets = pos_grid % ps
     x = _embed(params, cfg, tokens)
+
+    def write(pool, window, l):
+        return paged_write_window(pool, window[None], table, prefix_lens,
+                                  lengths, layer=l)
 
     def layer_body(l, state):
         x, k_pool, v_pool, ks_pool, vs_pool = state
         layer = jax.tree_util.tree_map(lambda w: w[l], params["layers"])
-        kp_l = jax.lax.dynamic_index_in_dim(k_pool, l, 0, keepdims=False)
-        vp_l = jax.lax.dynamic_index_in_dim(v_pool, l, 0, keepdims=False)
-        ksp_l = jax.lax.dynamic_index_in_dim(ks_pool, l, 0, keepdims=False)
-        vsp_l = jax.lax.dynamic_index_in_dim(vs_pool, l, 0, keepdims=False)
         normed = rms_norm(x, layer["attn_norm"], cfg.rms_eps)
         q = rope(_mm(normed, layer, "wq").reshape(K, T, H, dh),
                  pos_grid, cfg.rope_theta)
@@ -1181,14 +1165,14 @@ def llama_prefill_paged_prefix_q8(params, cfg: LlamaConfig, tokens,
         v = _mm(normed, layer, "wv").reshape(K, T, Hkv, dh)
         k8, ks = quantize_kv(k, axis=-1)           # [K,T,Hkv,dh], [K,T,Hkv]
         v8, vs = quantize_kv(v, axis=-1)
-        kp_l = kp_l.at[page_ids, :, :, offsets].set(k8)
-        vp_l = vp_l.at[page_ids, :, :, offsets].set(v8)
-        ksp_l = ksp_l.at[page_ids, :, offsets].set(ks)
-        vsp_l = vsp_l.at[page_ids, :, offsets].set(vs)
-        k_rows = jnp.moveaxis(kp_l[table], 1, 3).reshape(K, Hkv, dh, S)
-        v_rows = jnp.moveaxis(vp_l[table], 1, 3).reshape(K, Hkv, dh, S)
-        ks_rows = jnp.moveaxis(ksp_l[table], 1, 2).reshape(K, Hkv, S)
-        vs_rows = jnp.moveaxis(vsp_l[table], 1, 2).reshape(K, Hkv, S)
+        k_pool = write(k_pool, k8.transpose(0, 2, 3, 1), l)
+        v_pool = write(v_pool, v8.transpose(0, 2, 3, 1), l)
+        ks_pool = write(ks_pool, ks.transpose(0, 2, 1), l)
+        vs_pool = write(vs_pool, vs.transpose(0, 2, 1), l)
+        k_rows = jnp.moveaxis(k_pool[l, table], 1, 3).reshape(K, Hkv, dh, S)
+        v_rows = jnp.moveaxis(v_pool[l, table], 1, 3).reshape(K, Hkv, dh, S)
+        ks_rows = jnp.moveaxis(ks_pool[l, table], 1, 2).reshape(K, Hkv, S)
+        vs_rows = jnp.moveaxis(vs_pool[l, table], 1, 2).reshape(K, Hkv, S)
         k_deq = (k_rows.astype(jnp.float32)
                  * ks_rows[:, :, None, :]).astype(dt)
         v_deq = (v_rows.astype(jnp.float32)
@@ -1206,10 +1190,6 @@ def llama_prefill_paged_prefix_q8(params, cfg: LlamaConfig, tokens,
                           preferred_element_type=jnp.float32).astype(x.dtype)
         x = x + _mm(attn.reshape(K, T, H * dh), layer, "wo")
         x = x + _ffn_block(x, layer, cfg)
-        k_pool = jax.lax.dynamic_update_index_in_dim(k_pool, kp_l, l, 0)
-        v_pool = jax.lax.dynamic_update_index_in_dim(v_pool, vp_l, l, 0)
-        ks_pool = jax.lax.dynamic_update_index_in_dim(ks_pool, ksp_l, l, 0)
-        vs_pool = jax.lax.dynamic_update_index_in_dim(vs_pool, vsp_l, l, 0)
         return x, k_pool, v_pool, ks_pool, vs_pool
 
     x, k_pool, v_pool, ks_pool, vs_pool = jax.lax.fori_loop(
@@ -1221,7 +1201,7 @@ def llama_prefill_paged_prefix_q8(params, cfg: LlamaConfig, tokens,
 
 
 def _attention_block_nocache(x, layer, positions, cfg: LlamaConfig,
-                             attn_fn=None):
+                             attn_fn=None, mesh=None):
     """Plain causal attention sublayer (no cache). x: [B, T, D] -> [B, T, D].
 
     attn_fn overrides the attention primitive (q, k, v) -> [B, T, H, dh] —
@@ -1238,7 +1218,7 @@ def _attention_block_nocache(x, layer, positions, cfg: LlamaConfig,
     elif cfg.attn_impl == "flash":
         from ..ops.flash_attention import flash_attention
 
-        attn = flash_attention(q, k, v, True)
+        attn = flash_attention(q, k, v, True, mesh=mesh)
     else:
         from ..ops.flash_attention import attention_reference
 
@@ -1247,7 +1227,7 @@ def _attention_block_nocache(x, layer, positions, cfg: LlamaConfig,
 
 
 def forward_nocache_at(params, cfg: LlamaConfig, tokens, positions,
-                       attn_fn=None):
+                       attn_fn=None, mesh=None):
     """Cache-free forward over a token chunk at explicit absolute positions.
 
     The shared body behind llama_forward_nocache and the sequence-parallel
@@ -1256,7 +1236,8 @@ def forward_nocache_at(params, cfg: LlamaConfig, tokens, positions,
     x = _embed(params, cfg, tokens)
 
     def body(x, layer):
-        x = x + _attention_block_nocache(x, layer, positions, cfg, attn_fn)
+        x = x + _attention_block_nocache(x, layer, positions, cfg, attn_fn,
+                                         mesh)
         x = x + _ffn_block(x, layer, cfg)
         return x, None
 
@@ -1265,7 +1246,7 @@ def forward_nocache_at(params, cfg: LlamaConfig, tokens, positions,
     return _head(x, params)
 
 
-def llama_forward_nocache(params, cfg: LlamaConfig, tokens):
+def llama_forward_nocache(params, cfg: LlamaConfig, tokens, mesh=None):
     """Training/eval forward without a cache: plain causal attention.
 
     Kept separate from the serving path so the training step doesn't carry
@@ -1273,4 +1254,4 @@ def llama_forward_nocache(params, cfg: LlamaConfig, tokens):
     """
     B, T = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None, :], (B, T))
-    return forward_nocache_at(params, cfg, tokens, positions)
+    return forward_nocache_at(params, cfg, tokens, positions, mesh=mesh)

@@ -1,13 +1,18 @@
-"""ctypes loader for the native runtime helpers (libgofr_native.so).
+"""ctypes loader for the native runtime helpers.
 
-The shared library is built from gofr_native.cc on first import when a C++
-toolchain is present (auto-build, cached next to the source); every consumer
-degrades to its pure-Python path when `available()` is False, so the
-framework never hard-requires the toolchain — the same graceful-nil posture
-datasources take on misconfiguration (reference sql/sql.go:33-36).
+The shared library is built from gofr_native.cc on first use when a C++
+toolchain is present, into libgofr_native-<hash of the source>.so beside it.
+The name carries the source's hash because a file's time does not survive a
+copy of the tree: a library built from other source is never loaded, and a
+checkout that carries no library (git commits only the source) builds its
+own. Every consumer degrades to its pure-Python path when `available()` is
+False — a machine without a toolchain still serves — and `status()` says
+which of the two it is, so that a build which should have worked and did
+not is an error somebody sees (chip_smoke.py fails on it).
 
 API:
   available() -> bool
+  status() -> dict                  — built / fallback, and why
   BPECore(merge_triples)   — id-level greedy BPE merges (hot encode loop)
   pad_batch(rows, max_len, pad_id) -> np.ndarray[int32]
   utf8_complete_prefix(buf) -> int
@@ -17,7 +22,10 @@ API:
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
+import shutil
 import subprocess
 import threading
 from typing import List, Optional, Sequence, Tuple
@@ -25,26 +33,49 @@ from typing import List, Optional, Sequence, Tuple
 import numpy as np
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
-_SO = os.path.join(_DIR, "libgofr_native.so")
 _SRC = os.path.join(_DIR, "gofr_native.cc")
 
 _lib = None
 _load_lock = threading.Lock()
 _load_attempted = False
+_load_error: Optional[str] = None
 
 _i32p = ctypes.POINTER(ctypes.c_int32)
 _i64p = ctypes.POINTER(ctypes.c_int64)
 
 
-def _build() -> bool:
-    cxx = os.environ.get("CXX", "g++")
+def _cxx() -> Optional[str]:
+    return shutil.which(os.environ.get("CXX", "g++"))
+
+
+def _so_path() -> str:
+    with open(_SRC, "rb") as fp:
+        digest = hashlib.sha256(fp.read()).hexdigest()[:16]
+    return os.path.join(_DIR, f"libgofr_native-{digest}.so")
+
+
+def _build(so: str) -> Optional[str]:
+    """Compile the source to `so`; returns an error text, or None."""
+    tmp = f"{so}.tmp.{os.getpid()}"
     try:
         result = subprocess.run(
-            [cxx, "-O3", "-std=c++17", "-fPIC", "-shared", "-o", _SO, _SRC],
+            [_cxx(), "-O3", "-std=c++17", "-fPIC", "-shared", "-o", tmp, _SRC],
             capture_output=True, timeout=120)
-        return result.returncode == 0 and os.path.exists(_SO)
-    except (OSError, subprocess.TimeoutExpired):
-        return False
+        if result.returncode != 0:
+            return result.stderr.decode(errors="replace")[-2000:]
+        os.replace(tmp, so)    # a concurrent loader sees all of it or none
+    except (OSError, subprocess.TimeoutExpired) as exc:
+        return f"{type(exc).__name__}: {exc}"
+    finally:
+        if os.path.exists(tmp):
+            os.remove(tmp)
+    for stale in glob.glob(os.path.join(_DIR, "libgofr_native*.so")):
+        if stale != so:        # libraries of earlier sources
+            try:
+                os.remove(stale)
+            except OSError:
+                pass
+    return None
 
 
 def _bind(lib) -> None:
@@ -66,53 +97,41 @@ def _bind(lib) -> None:
 
 
 def _load():
-    global _lib, _load_attempted
+    global _lib, _load_attempted, _load_error
     if _lib is not None:  # fast path: no lock once loaded (hot callers)
         return _lib
     with _load_lock:
         if _lib is not None or _load_attempted:
             return _lib
         _load_attempted = True
-        if not os.path.exists(_SO) or (os.path.exists(_SRC) and
-                                       os.path.getmtime(_SRC) > os.path.getmtime(_SO)):
-            if not _build():
+        so = _so_path()
+        if not os.path.exists(so):
+            if _cxx() is None:
+                _load_error = "no C++ toolchain"
                 return None
-        for attempt in (0, 1):
-            lib = None
-            try:
-                lib = ctypes.CDLL(_SO)
-                _bind(lib)
-                _lib = lib
-                break
-            except (OSError, AttributeError):
-                # AttributeError: a stale cached .so missing a newly added
-                # symbol (same-second mtimes can defeat the rebuild check).
-                # Delete the stale artifact and rebuild ONCE — a silent
-                # permanent fallback would also disable the helpers the
-                # stale library did support (BPE, pad_batch)
-                _lib = None
-                if attempt == 0:
-                    if lib is not None:
-                        # dlopen dedups by pathname: without closing the
-                        # failed handle, the retry's CDLL would rebind the
-                        # SAME stale in-memory image, not the rebuilt file
-                        try:
-                            import _ctypes
-
-                            _ctypes.dlclose(lib._handle)
-                        except Exception:  # noqa: BLE001
-                            break
-                    try:
-                        os.remove(_SO)
-                    except OSError:
-                        break
-                    if not _build():
-                        break
+            _load_error = _build(so)
+            if _load_error is not None:
+                return None
+        try:
+            lib = ctypes.CDLL(so)
+            _bind(lib)
+        except (OSError, AttributeError) as exc:
+            _load_error = f"{type(exc).__name__}: {exc}"
+            return None
+        _lib = lib
         return _lib
 
 
 def available() -> bool:
     return _load() is not None
+
+
+def status() -> dict:
+    """Which implementation serves and why: {"native": bool, "toolchain":
+    bool, "error": text or None}. native False with a toolchain present is
+    a build or load that failed — an error, not a fallback to accept."""
+    return {"native": available(), "toolchain": _cxx() is not None,
+            "error": None if _lib is not None else _load_error}
 
 
 def version() -> str:

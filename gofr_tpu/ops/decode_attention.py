@@ -140,15 +140,34 @@ def _decode_kernel(len_ref, *refs, block_s: int, n_kv: int, scale: float,
 
 
 def decode_attention(q, k_cache, v_cache, lengths, k_scale=None, v_scale=None,
-                     *, block_s: int = 512, interpret=None):
+                     *, block_s: int = 512, mesh=None, interpret=None):
     """Pallas decode attention. q: [B, H, dh]; k/v_cache: [B, Hkv, dh, S];
     lengths: [B] int32. Returns [B, H, dh] in q.dtype.
 
     k/v_scale: optional [B, Hkv, S] per-token dequant scales — pass both to
     read int8 caches (the int8 bytes are what cross HBM; dequant folds into
-    the existing dots, see _decode_kernel)."""
+    the existing dots, see _decode_kernel).
+
+    mesh: the serving mesh when the caches are sharded over its "tp" axis
+    (parallel/sharding.kv_cache_layer_spec); each shard then runs the
+    kernel on its own heads under shard_map, as in ops/paged_attention."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
+
+    if mesh is not None and mesh.shape.get("tp", 1) > 1:
+        from jax.sharding import PartitionSpec as P
+
+        operands = [q, k_cache, v_cache, lengths]
+        specs = [P(None, "tp", None), P(None, "tp", None, None),
+                 P(None, "tp", None, None), P()]
+        if k_scale is not None and v_scale is not None:
+            operands += [k_scale, v_scale]
+            specs += [P(None, "tp", None), P(None, "tp", None)]
+        return jax.shard_map(
+            functools.partial(decode_attention, block_s=block_s,
+                              interpret=interpret),
+            mesh=mesh, in_specs=tuple(specs), out_specs=P(None, "tp", None),
+            check_vma=False)(*operands)
 
     B, H, dh = q.shape
     Hkv, S = k_cache.shape[1], k_cache.shape[-1]

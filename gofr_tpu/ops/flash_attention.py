@@ -245,12 +245,30 @@ def attention_reference(q, k, v, *, causal: bool = True):
     return out.reshape(B, T, H, dh).astype(q.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def flash_attention(q, k, v, causal: bool = True, block_q: int = 128,
-                    block_kv: int = 128, interpret: Optional[bool] = None):
+                    block_kv: int = 128, interpret: Optional[bool] = None,
+                    *, mesh=None):
     """Flash attention on [B, T, H, dh] q and [B, S, Hkv, dh] k/v (GQA folds
     query head h onto kv head h // (H // Hkv)). Returns [B, T, H, dh] in
-    q.dtype."""
+    q.dtype.
+
+    mesh: the serving mesh when q/k/v are sharded over its "tp" axis on
+    their head dims (column-parallel wq/wk/wv); each shard then runs the
+    kernel on its own heads under shard_map, as in ops/paged_attention."""
+    local = functools.partial(_flash_local, causal=causal, block_q=block_q,
+                              block_kv=block_kv, interpret=interpret)
+    if mesh is not None and mesh.shape.get("tp", 1) > 1:
+        from jax.sharding import PartitionSpec as P
+
+        heads = P(None, None, "tp", None)
+        return jax.shard_map(local, mesh=mesh, in_specs=(heads, heads, heads),
+                             out_specs=heads, check_vma=False)(q, k, v)
+    return local(q, k, v)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash_local(q, k, v, causal: bool = True, block_q: int = 128,
+                 block_kv: int = 128, interpret: Optional[bool] = None):
     if causal and q.shape[1] != k.shape[1]:
         # mixed-length causal needs the position offset folded into the mask;
         # the kernel path covers the hot shapes (T==S full-causal, and any
@@ -265,7 +283,7 @@ def flash_attention(q, k, v, causal: bool = True, block_q: int = 128,
 
 
 def _flash_fwd(q, k, v, causal, block_q, block_kv, interpret):
-    return flash_attention(q, k, v, causal, block_q, block_kv, interpret), (q, k, v)
+    return _flash_local(q, k, v, causal, block_q, block_kv, interpret), (q, k, v)
 
 
 def _flash_bwd(causal, block_q, block_kv, interpret, residuals, g):
@@ -275,4 +293,4 @@ def _flash_bwd(causal, block_q, block_kv, interpret, residuals, g):
     return vjp(g)
 
 
-flash_attention.defvjp(_flash_fwd, _flash_bwd)
+_flash_local.defvjp(_flash_fwd, _flash_bwd)

@@ -23,8 +23,6 @@ import math
 import jax
 import jax.numpy as jnp
 
-from ..parallel.compat import axis_size
-
 
 def _block_attend(q, k, v, scale, mask):
     """q: [B,Tq,H,dh]; k/v: [B,Tk,Hkv,dh]; mask: [Tq,Tk] bool.
@@ -53,7 +51,7 @@ def ring_attention(q, k, v, axis_name: str = "sp"):
     q,k,v: [B, T_local, H(kv), dh]. Returns [B, T_local, H, dh] in q.dtype.
     """
     B, T, H, dh = q.shape
-    sp = axis_size(axis_name)
+    sp = jax.lax.axis_size(axis_name)
     my_chunk = jax.lax.axis_index(axis_name)
     scale = 1.0 / math.sqrt(dh)
 

@@ -26,7 +26,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ..parallel.compat import axis_size
 from .flash_attention import flash_attention
 
 
@@ -39,7 +38,7 @@ def ulysses_attention(q, k, v, axis_name: str = "sp"):
     """
     H = q.shape[2]
     Hkv = k.shape[2]
-    sp = axis_size(axis_name)
+    sp = jax.lax.axis_size(axis_name)
     if H % sp != 0:
         raise ValueError(f"query heads ({H}) must divide by |{axis_name}|={sp}")
     if Hkv % sp != 0:  # GQA with fewer KV heads than devices: replicate up

@@ -8,7 +8,6 @@ shard_map is used only where the schedule IS the algorithm (ring attention,
 pipeline microbatching).
 """
 
-from .compat import shard_map
 from .longcontext import make_sp_forward, sp_llama_forward
 from .mesh import MeshPlan, make_mesh
 from .sharding import (batch_spec, kv_cache_layer_spec, kv_cache_spec,
@@ -17,5 +16,4 @@ from .sharding import (batch_spec, kv_cache_layer_spec, kv_cache_spec,
 
 __all__ = ["MeshPlan", "make_mesh", "batch_spec", "llama_param_specs",
            "serving_param_specs", "kv_cache_spec", "kv_cache_layer_spec", "shard_params",
-           "unsharded_like", "sp_llama_forward", "make_sp_forward",
-           "shard_map"]
+           "unsharded_like", "sp_llama_forward", "make_sp_forward"]

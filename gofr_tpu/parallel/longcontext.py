@@ -45,7 +45,6 @@ def sp_llama_forward(params, cfg, tokens, mesh, attn: str = "ring",
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from .compat import shard_map
     from ..models.llama import forward_nocache_at
     from ..ops.ring_attention import ring_attention
     from ..ops.ulysses import ulysses_attention
@@ -73,7 +72,7 @@ def sp_llama_forward(params, cfg, tokens, mesh, attn: str = "ring",
             attn_fn=lambda q, k, v: attn_impl(q, k, v, axis_name=sp_axis))
 
     pspecs = jax.tree_util.tree_map(lambda _: P(), params)
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(pspecs, P(dp_axis, sp_axis)),
         out_specs=P(dp_axis, sp_axis, None),

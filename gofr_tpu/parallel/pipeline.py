@@ -22,8 +22,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
-from .compat import axis_size, shard_map
-
 
 def gpipe(stage_fn: Callable, local_params, x_micro, axis_name: str = "pp"):
     """Run the pipeline schedule. Must be called inside shard_map over `axis_name`.
@@ -33,7 +31,7 @@ def gpipe(stage_fn: Callable, local_params, x_micro, axis_name: str = "pp"):
     other ranks receive activations over the ring).
     Returns [n_micro, mb, ...] outputs (replicated across the pp axis).
     """
-    pp = axis_size(axis_name)
+    pp = jax.lax.axis_size(axis_name)
     rank = jax.lax.axis_index(axis_name)
     n_micro = x_micro.shape[0]
     perm = [(i, (i + 1) % pp) for i in range(pp)]
@@ -96,7 +94,7 @@ def pipelined_llama_forward(params, cfg, tokens, mesh, n_microbatches: int = 4):
 
     layer_specs = jax.tree_util.tree_map(
         lambda leaf: P(*(("pp",) + (None,) * (leaf.ndim - 1))), params["layers"])
-    piped = shard_map(
+    piped = jax.shard_map(
         lambda lp, xm: gpipe(stage_fn, lp, xm, axis_name="pp"),
         mesh=mesh,
         in_specs=(layer_specs, P()),

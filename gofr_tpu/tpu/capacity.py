@@ -186,14 +186,25 @@ def plan_capacity(cfg, n_slots: int, max_seq_len: int,
 
 
 def device_budget_bytes(tpu_client=None) -> int:
-    """The first device's bytes_limit, or 0 when unknown (CPU backends)."""
+    """The first device's bytes_limit. A CPU backend reports none and gets
+    0, which callers read as "no capacity plan". A TPU that reports none
+    is an error: planning nothing there would let the first burst find the
+    chip's memory limit instead of the boot."""
     if tpu_client is not None:
-        stats = tpu_client.memory_stats()
-        return int(stats[0]["bytes_limit"]) if stats else 0
-    try:
+        device = tpu_client.devices[0] if tpu_client.devices else None
+    else:
         import jax
 
-        stats = jax.devices()[0].memory_stats() or {}
-        return int(stats.get("bytes_limit", 0))
-    except Exception:  # noqa: BLE001
+        device = jax.devices()[0]
+    if device is None:
         return 0
+    try:
+        stats = device.memory_stats() or {}
+    except Exception:  # noqa: BLE001 - CPU backends have no stats
+        stats = {}
+    limit = int(stats.get("bytes_limit", 0))
+    if not limit and device.platform == "tpu":
+        raise RuntimeError(
+            f"{device.device_kind} reports no bytes_limit "
+            f"(memory_stats: {sorted(stats)}): cannot plan its memory")
+    return limit

@@ -9,14 +9,79 @@ programs. The cache is the analog of the reference keeping its expensive init
 
 from __future__ import annotations
 
+import functools
 import hashlib
 import os
 import pickle
 import threading
 import time
+import zlib
 from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
 DEFAULT_BUCKETS = (1, 2, 4, 8, 16, 32, 64, 128, 256, 512, 1024, 2048, 4096, 8192)
+
+# -- where compiled programs persist: ONE rule ---------------------------------
+COMPILE_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+# a fixed path inside the checkout (git-ignored): the path is part of JAX's
+# cache key, so a directory named after a pid, a time or a temp name would
+# never hit twice
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".compile_cache")
+
+
+def compile_cache_dir(override: Optional[str] = None) -> str:
+    """The directory both compile caches live in — JAX's persistent
+    compilation cache and the Executor's serialized executables (.jexec).
+
+    Where JAX_COMPILATION_CACHE_DIR is set (the machine's owner placed the
+    cache) it wins and nothing here sets another; else an explicit
+    `override` (a deployment's PROGRAM_CACHE_DIR, shared by its replicas);
+    else DEFAULT_COMPILE_CACHE_DIR."""
+    return (os.environ.get(COMPILE_CACHE_ENV) or override
+            or DEFAULT_COMPILE_CACHE_DIR)
+
+
+def enable_compile_cache(override: Optional[str] = None) -> str:
+    """Turn JAX's persistent cache on at compile_cache_dir(override) and
+    return the directory for Executor(cache_dir=...). Entry points call
+    this before their first jit, so that weight init, the device probe and
+    the scoring families land in the same cache as the executor's
+    programs. Programs that compile in under a second are kept too: a
+    boot traces dozens of them.
+
+    On the CPU backend only the Executor's artifacts persist, unless the
+    environment placed JAX's cache itself: XLA:CPU executables that
+    jaxlib 0.9.0 reloads from JAX's cache fail when run beside others in
+    one process ("NOT_FOUND: Function add_convert_fusion not found")."""
+    import jax
+
+    path = compile_cache_dir(override)
+    os.makedirs(path, exist_ok=True)
+    if os.environ.get(COMPILE_CACHE_ENV):
+        return path                             # JAX reads it itself
+    if jax.default_backend() != "cpu":
+        jax.config.update("jax_compilation_cache_dir", path)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    return path
+
+
+@functools.lru_cache(maxsize=None)
+def _package_digest() -> str:
+    """Hash of this package's sources. A persisted executable is looked up
+    by the program's NAME and the code object of its outermost function;
+    the model and kernel code that function calls is not in that key, so
+    every artifact also carries this digest and an edit anywhere in the
+    package retires them all."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    digest = hashlib.sha256()
+    for folder, _, files in sorted(os.walk(root)):
+        for fname in sorted(files):
+            if fname.endswith(".py"):
+                with open(os.path.join(folder, fname), "rb") as fp:
+                    digest.update(fp.read())
+    return digest.hexdigest()
 
 
 def next_bucket(n: int, buckets: Sequence[int] = DEFAULT_BUCKETS) -> int:
@@ -41,6 +106,28 @@ def pad_to(array, size: int, axis: int = 0, value=0):
     widths = [(0, 0)] * array.ndim
     widths[axis] = (0, size - current)
     return xp.pad(array, widths, constant_values=value)
+
+
+def _code_digest(code, digest=None) -> str:
+    """Hash of a code object: its bytecode, names and constants, nested
+    code objects included (`x+1` and `x+2` share co_code; the constant
+    lives in co_consts). Not marshal.dumps: marshal writes a back-reference
+    for any constant whose REFCOUNT is above one, so its bytes change once
+    the function has been traced, and the first program of every family
+    was saved under a name the next boot never looked for."""
+    digest = digest if digest is not None else hashlib.sha256()
+    digest.update(code.co_code)
+    digest.update(repr((code.co_names, code.co_varnames, code.co_freevars,
+                        code.co_cellvars, code.co_argcount,
+                        code.co_kwonlyargcount, code.co_flags)).encode())
+    for const in code.co_consts:
+        if hasattr(const, "co_code"):
+            _code_digest(const, digest)
+        elif isinstance(const, frozenset):   # order follows the hash seed
+            digest.update(repr(sorted(map(repr, const))).encode())
+        else:
+            digest.update(repr(const).encode())
+    return digest.hexdigest()
 
 
 def _abstract_key(tree) -> Tuple:
@@ -182,18 +269,15 @@ class Executor:
 
         try:
             device = jax.devices()[0]
-            # the full marshalled code object (bytecode + consts + names +
-            # nested code) AND the closure cell values go into the
-            # fingerprint: co_code alone is identical for `x+1` vs `x+2`
-            # (constants live in co_consts), and engine program factories
-            # close over the model config — neither may resurrect a stale
-            # executable. Address-bearing reprs (plain objects) are reduced
-            # to their type name so the digest is stable across processes.
-            import marshal
+            # the whole code object (_code_digest) AND the closure cell
+            # values go into the fingerprint: engine program factories
+            # close over the model config, and neither a changed constant
+            # nor a changed config may resurrect a stale executable.
+            # Address-bearing reprs (plain objects) are reduced to their
+            # type name so the digest is stable across processes.
             import re
 
             code = getattr(fn, "__code__", None)
-            code_bytes = marshal.dumps(code) if code is not None else b""
             cells = []
             for cell in (getattr(fn, "__closure__", None) or ()):
                 try:
@@ -204,9 +288,10 @@ class Executor:
                     text = type(cell.cell_contents).__name__
                 cells.append(re.sub(r"0x[0-9a-f]+", "", text))
             fingerprint = (key, jax.__version__, device.platform,
-                           device.device_kind, dev_sig,
-                           hashlib.sha256(code_bytes).hexdigest(),
-                           tuple(cells))
+                           device.device_kind,
+                           device.client.platform_version, dev_sig,
+                           _code_digest(code) if code is not None else "",
+                           tuple(cells), _package_digest())
         except Exception:  # noqa: BLE001
             return None
         digest = hashlib.sha256(repr(fingerprint).encode()).hexdigest()[:32]
@@ -222,7 +307,8 @@ class Executor:
 
         try:
             with open(path, "rb") as fp:
-                blob, in_tree, out_tree, device_ids = pickle.load(fp)
+                blob, in_tree, out_tree, device_ids = pickle.loads(
+                    zlib.decompress(fp.read()))
             # the artifact records the mesh's DEVICE ORDER (a device count
             # cannot reconstruct an assignment; a wrong order would
             # silently mis-shard). Restore exactly that ordering — if any
@@ -232,21 +318,8 @@ class Executor:
                 raise ValueError(f"device ids {device_ids} not all present")
             execution_devices = ([by_id[i] for i in device_ids]
                                  if device_ids else jax.devices()[:1])
-            import inspect
-            params = inspect.signature(
-                serialize_executable.deserialize_and_load).parameters
-            if "execution_devices" in params:
-                compiled = serialize_executable.deserialize_and_load(
-                    blob, in_tree, out_tree,
-                    execution_devices=execution_devices)
-            else:
-                # jax 0.4.x: no execution_devices kwarg — the PJRT blob
-                # carries its own device assignment, which load() restores
-                # through the backend client; the device-id presence check
-                # above still discards artifacts from a changed topology
-                compiled = serialize_executable.deserialize_and_load(
-                    blob, in_tree, out_tree,
-                    backend=execution_devices[0].client)
+            compiled = serialize_executable.deserialize_and_load(
+                blob, in_tree, out_tree, execution_devices=execution_devices)
         except Exception as exc:  # noqa: BLE001 - stale/foreign artifact
             if self.logger is not None:
                 self.logger.warnf("discarding persisted program %s: %s",
@@ -319,7 +392,10 @@ class Executor:
             else:
                 device_ids = []   # uncommitted: default device at load
             blob, in_tree, out_tree = serialize_executable.serialize(compiled)
-            payload = pickle.dumps((blob, in_tree, out_tree, device_ids))
+            # level 1: an executable is mostly tables and shrinks severalfold
+            # at once; the cache directory holds JAX's copy of each too
+            payload = zlib.compress(
+                pickle.dumps((blob, in_tree, out_tree, device_ids)), 1)
             tmp = f"{path}.tmp.{os.getpid()}"
             with open(tmp, "wb") as fp:
                 fp.write(payload)

@@ -33,7 +33,7 @@ import numpy as np
 from ..models.llama import LlamaConfig, llama_decode_step_paged, llama_prefill_last
 from ..ops.paged_attention import paged_write_prefill_stacked
 from .engine import (CacheLostError, GenerationRequest, LLMEngine,
-                     _pin_standard_layout)
+                     _admission_widths, _pin_standard_layout)
 from .ownership import loop_only
 
 
@@ -687,15 +687,10 @@ class PagedLLMEngine(LLMEngine):
     # -- programs -------------------------------------------------------------
     def warmup(self, grow: bool = True, k_variants: bool = False) -> None:
         with self._state_lock:
-            ks = [1]
-            if k_variants:
-                # every power-of-two fused-admission width: organic
-                # staggered traffic admits in unpredictable group sizes
-                # (see the dense warmup's rationale)
-                K = 2
-                while K <= self.n_slots:
-                    ks.append(K)
-                    K *= 2
+            # every fused-admission width: organic staggered traffic
+            # admits in unpredictable group sizes (see the dense warmup)
+            ks = (sorted(_admission_widths(self.n_slots)) if k_variants
+                  else [1])
             chunk = self.chunk_prefill_tokens
             for bucket in self.prefill_buckets:
                 # buckets routed to the chunk path skip the (dead) fused
@@ -729,13 +724,20 @@ class PagedLLMEngine(LLMEngine):
                 # first hand-off) doesn't compile on the loop thread
                 for n in (1, 2):
                     self._restore_program(n)
-            # warm the table widths the first admissions will actually hit:
-            # dispatch uses pow2(widest_pages + 1), so NP=1 never occurs
-            warm_widths = set()
-            for bucket in self.prefill_buckets[:1] or (self.page_size,):
-                pages = self.allocator.pages_for(
-                    min(bucket + 128, self.max_seq_len))
-                warm_widths.add(_pow2_at_least(pages + 1))
+            # the table widths dispatch can ask for: _build_table uses
+            # pow2(widest_pages + 1) over the active slots' reservations.
+            # Warm every width an ADMISSION can produce — a prompt within
+            # the largest bucket plus a page of generation, and anything
+            # shorter (a lone short request has a narrower table than the
+            # widest one) — or the first such request compiles on the loop
+            # thread. k_variants warms the rest too, up to max_seq_len: a
+            # long generation then never meets a cold width either
+            reach = (self.max_seq_len if k_variants else min(
+                max(self.prefill_buckets or (self.page_size,))
+                + self.page_size, self.max_seq_len))
+            warm_widths = {
+                _pow2_at_least(pages + 1)
+                for pages in range(1, self.allocator.pages_for(reach) + 1)}
             for width in sorted(warm_widths):
                 self._decode_program_paged(width)
                 if self.decode_block_size > 1:
@@ -747,7 +749,7 @@ class PagedLLMEngine(LLMEngine):
                     self._verify_program(width)
 
     def _prefill_fn(self, bucket: int, K: int):
-        cfg = self.cfg
+        cfg, mesh = self.cfg, self.mesh
         jnp = self._jnp
         top_k = self.top_k
         from .sampling import sample_tokens
@@ -765,7 +767,7 @@ class PagedLLMEngine(LLMEngine):
             pos_grid = jnp.broadcast_to(
                 jnp.arange(bucket, dtype=jnp.int32)[None, :], (K, bucket))
             last, tmp_k, tmp_v = llama_prefill_last(
-                params, cfg, ptokens, pos_grid, lengths, tmp_k, tmp_v)
+                params, cfg, ptokens, pos_grid, lengths, tmp_k, tmp_v, mesh)
             # scatter the window into pages: token t of row k goes to
             # (ptable[k, t // ps], t % ps); pad junk past lengths[k] is
             # redirected to the garbage page so live pages stay clean
@@ -784,7 +786,7 @@ class PagedLLMEngine(LLMEngine):
         """MIRRORS the paged _prefill_fn with int8 pools + scale pools:
         full-precision window forward into bf16 temps, quantize per
         token/head, scatter values and scales into the pages."""
-        cfg = self.cfg
+        cfg, mesh = self.cfg, self.mesh
         jnp = self._jnp
         top_k = self.top_k
         from ..models.llama import _np_dtype
@@ -803,7 +805,7 @@ class PagedLLMEngine(LLMEngine):
             pos_grid = jnp.broadcast_to(
                 jnp.arange(bucket, dtype=jnp.int32)[None, :], (K, bucket))
             last, tmp_k, tmp_v = llama_prefill_last(
-                params, cfg, ptokens, pos_grid, lengths, tmp_k, tmp_v)
+                params, cfg, ptokens, pos_grid, lengths, tmp_k, tmp_v, mesh)
             k8, ks = quantize_kv(tmp_k, axis=-2)   # scales [L, K, Hkv, bucket]
             v8, vs = quantize_kv(tmp_v, axis=-2)
             k_pool, v_pool = paged_write_prefill_stacked(
@@ -849,7 +851,7 @@ class PagedLLMEngine(LLMEngine):
             args, donate_argnums=(1, 2, 7, 8, 9))
 
     def _decode_fn_paged(self, block: int, n_table: int):
-        cfg = self.cfg
+        cfg, mesh = self.cfg, self.mesh
         top_k = self.top_k
         import jax
 
@@ -862,7 +864,7 @@ class PagedLLMEngine(LLMEngine):
             def step(carry, _):
                 kp, vp, tok, pos, rng = carry
                 logits, kp, vp = llama_decode_step_paged(
-                    params, cfg, tok, pos, kp, vp, table)
+                    params, cfg, tok, pos, kp, vp, table, mesh)
                 nxt, rng = sample_tokens(logits, rng, temps, top_k=top_k)
                 return (kp, vp, nxt, pos + 1, rng), nxt
 
@@ -877,7 +879,7 @@ class PagedLLMEngine(LLMEngine):
 
     def _decode_fn_paged_q8(self, block: int, n_table: int):
         """MIRRORS _decode_fn_paged over int8 pools + scale pools."""
-        cfg = self.cfg
+        cfg, mesh = self.cfg, self.mesh
         top_k = self.top_k
         import jax
 
@@ -889,7 +891,7 @@ class PagedLLMEngine(LLMEngine):
             def step(carry, _):
                 kp, vp, ks, vs, tok, pos, rng = carry
                 logits, kp, vp, ks, vs = llama_decode_step_paged_q8(
-                    params, cfg, tok, pos, kp, vp, ks, vs, table)
+                    params, cfg, tok, pos, kp, vp, ks, vs, table, mesh)
                 nxt, rng = sample_tokens(logits, rng, temps, top_k=top_k)
                 return (kp, vp, ks, vs, nxt, pos + 1, rng), nxt
 
@@ -934,7 +936,7 @@ class PagedLLMEngine(LLMEngine):
     # inactive slot's table row is all zeros, so lock-step junk writes land
     # in the garbage page by construction.
     def _chunk_fn_paged(self, chunk: int, K: int, final: bool):
-        cfg = self.cfg
+        cfg, mesh = self.cfg, self.mesh
         jnp = self._jnp
         top_k = self.top_k
         from ..models.llama import llama_prefill_chunk
@@ -947,7 +949,8 @@ class PagedLLMEngine(LLMEngine):
             logits, tmp_k, tmp_v = llama_prefill_chunk(
                 params, cfg, ctokens, cpositions, tmp_k, tmp_v,
                 jnp.arange(K, dtype=jnp.int32),
-                project_last=jnp.clip(lengths - 1 - start, 0, chunk - 1))
+                project_last=jnp.clip(lengths - 1 - start, 0, chunk - 1),
+                mesh=mesh)
             in_chunk = ((lengths - 1 >= start)
                         & (lengths - 1 < start + chunk))       # [K]
             selected = jnp.where(in_chunk[:, None], logits, selected)
@@ -1202,7 +1205,7 @@ class PagedLLMEngine(LLMEngine):
         """The paged window forward (llama_verify_step_paged) around the
         SHARED acceptance epilogue (engine.spec_accept_epilogue — one
         implementation for both engines by construction)."""
-        cfg = self.cfg
+        cfg, mesh = self.cfg, self.mesh
         top_k = self.top_k
         from ..models.llama import llama_verify_step_paged
         from .engine import spec_accept_epilogue
@@ -1212,7 +1215,7 @@ class PagedLLMEngine(LLMEngine):
             k_pool, v_pool = _pin_standard_layout(k_pool, v_pool)
             g, logits0, k_pool, v_pool = llama_verify_step_paged(
                 params, cfg, tokens, drafts, positions, k_pool, v_pool,
-                table)
+                table, mesh)
             tokens, positions, rng, out, n_emit = spec_accept_epilogue(
                 g, logits0, temps, rng, drafts, draft_lens, positions, d,
                 top_k)

@@ -44,7 +44,7 @@ def _window_pass(engine, length: int, program_name: str, make_fn,
     under JAX's own serialization), so a busy server scores/embeds without
     pausing decode.
 
-    make_fn(cfg, W) builds the window program (signature
+    make_fn(cfg, W, mesh) builds the window program (signature
     (params, *extra, positions, k, v) -> (k, v, *outputs));
     window_args(w0, n, W) returns the pass-specific extra arrays for the
     window starting at w0 holding n live tokens; collect(w0, n, W, outs)
@@ -68,7 +68,7 @@ def _window_pass(engine, length: int, program_name: str, make_fn,
     # keep the full W=128 window (ADVICE r5).
     W = math.gcd(S, 128)
     k, v = init_kv_cache(engine.cfg, 1, S)
-    fn = make_fn(engine.cfg, W)
+    fn = make_fn(engine.cfg, W, engine.mesh)
     # work_length < length lets a pass skip trailing positions it never
     # reads (scoring: position L-1 has no target, so an L ≡ 1 (mod W)
     # sequence must not dispatch a whole discarded window for it)
@@ -84,7 +84,7 @@ def _window_pass(engine, length: int, program_name: str, make_fn,
         collect(w0, n, W, outs)
 
 
-def make_score_fn(cfg, W: int, K: int):
+def make_score_fn(cfg, W: int, K: int, mesh=None):
     """Window program: forward W tokens against the running cache, emit
     (new_k, new_v, chosen_lp [W], top_ids [W, K], top_lps [W, K]).
 
@@ -96,7 +96,8 @@ def make_score_fn(cfg, W: int, K: int):
     from ..models.llama import llama_forward
 
     def fn(params, toks, targets, positions, k, v):
-        logits, k, v = llama_forward(params, cfg, toks, positions, k, v)
+        logits, k, v = llama_forward(params, cfg, toks, positions, k, v,
+                                     mesh)
         lsm = jax.nn.log_softmax(logits[0].astype(jnp.float32), axis=-1)
         top_lps, top_ids = jax.lax.top_k(lsm, K)
         chosen = jnp.take_along_axis(lsm, targets[0][:, None], axis=1)[:, 0]
@@ -105,7 +106,7 @@ def make_score_fn(cfg, W: int, K: int):
     return fn
 
 
-def make_embed_fn(cfg, W: int):
+def make_embed_fn(cfg, W: int, mesh=None):
     """Window program for embeddings: forward W tokens against the running
     cache, emit (new_k, new_v, hidden [W, D]) — the final-norm hidden
     states (llama_forward_hidden); the host takes the last live position's
@@ -115,7 +116,7 @@ def make_embed_fn(cfg, W: int):
 
     def fn(params, toks, positions, k, v):
         hidden, k, v = llama_forward_hidden(params, cfg, toks, positions,
-                                            k, v)
+                                            k, v, mesh)
         return k, v, hidden[0]
 
     return fn
@@ -174,7 +175,7 @@ def score_tokens(engine, prompt_tokens: Sequence[int],
         lps_parts.append(np.asarray(top_lps)[:m])
 
     _window_pass(engine, L, "score",
-                 lambda cfg, W: make_score_fn(cfg, W, _SCORE_K),
+                 lambda cfg, W, mesh: make_score_fn(cfg, W, _SCORE_K, mesh),
                  window_args, collect, work_length=L - 1)
 
     chosen = np.concatenate(chosen_parts)[P - 1:L - 1]

@@ -68,9 +68,9 @@ PEAK_TABLE: Tuple[Tuple[str, Tuple[float, float]], ...] = (
     ("v3", (123e12, 900e9)),
     ("v2", (46e12, 700e9)),
 )
-# CPU / unknown backends: a nominal placeholder so the plumbing (gauges,
-# snapshot, tests) works everywhere — the absolute MFU number is only
-# meaningful on a device the table (or the env override) knows.
+# Backends that are not a TPU (the CPU the tests run on): a nominal
+# placeholder so the plumbing (gauges, snapshot, tests) works there — its
+# MFU means nothing. A TPU the table does not know is an error, not this.
 DEFAULT_PEAKS = (1e12, 1e11)
 
 PEAK_FLOPS_ENV = "TPU_PEAK_FLOPS"
@@ -80,27 +80,32 @@ PEAK_HBM_BW_ENV = "TPU_PEAK_HBM_BW"
 def resolve_peaks(platform: Optional[str] = None,
                   device_kind: Optional[str] = None) -> Tuple[float, float, str]:
     """(peak_flops, peak_hbm_bw, source) per device. Env overrides win;
-    then the device-kind table; then the nominal placeholder."""
+    then the device-kind table. A TPU whose kind the table does not know
+    raises — a utilization against an assumed peak is a wrong number under
+    a right name; any other platform gets the nominal placeholder."""
     env_flops = os.environ.get(PEAK_FLOPS_ENV)
     env_bw = os.environ.get(PEAK_HBM_BW_ENV)
+    peaks, source = _lookup_peaks(device_kind), "table"
+    if peaks is None:
+        if (platform or "").lower() == "tpu" and not (env_flops and env_bw):
+            raise ValueError(
+                f"no peak FLOP/s and HBM bandwidth known for TPU "
+                f"device_kind {device_kind!r}: add it to PEAK_TABLE with "
+                f"its source, or set both {PEAK_FLOPS_ENV} and "
+                f"{PEAK_HBM_BW_ENV}")
+        peaks, source = DEFAULT_PEAKS, "default"
     if env_flops or env_bw:
-        table = _lookup_peaks(device_kind)
-        return (float(env_flops) if env_flops else table[0],
-                float(env_bw) if env_bw else table[1], "env")
-    if platform and platform.lower() not in ("tpu",) and not device_kind:
-        return (*DEFAULT_PEAKS, "default")
-    flops, bw = _lookup_peaks(device_kind)
-    if (flops, bw) == DEFAULT_PEAKS:
-        return flops, bw, "default"
-    return flops, bw, "table"
+        return (float(env_flops) if env_flops else peaks[0],
+                float(env_bw) if env_bw else peaks[1], "env")
+    return (*peaks, source)
 
 
-def _lookup_peaks(device_kind: Optional[str]) -> Tuple[float, float]:
+def _lookup_peaks(device_kind: Optional[str]) -> Optional[Tuple[float, float]]:
     kind = (device_kind or "").lower()
     for needle, peaks in PEAK_TABLE:
         if needle in kind:
             return peaks
-    return DEFAULT_PEAKS
+    return None
 
 
 # -- analytic roofline model (pure functions, hand-checkable) -----------------

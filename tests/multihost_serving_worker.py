@@ -25,11 +25,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax  # noqa: E402
 
-try:
-    jax.config.update("jax_platforms", "cpu")
-except Exception:  # noqa: BLE001
-    pass
-
 from gofr_tpu.config import MockConfig  # noqa: E402
 from gofr_tpu.models.llama import LlamaConfig, llama_init  # noqa: E402
 from gofr_tpu.parallel import MeshPlan, make_mesh  # noqa: E402

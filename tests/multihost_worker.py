@@ -18,11 +18,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax  # noqa: E402
 
-try:
-    jax.config.update("jax_platforms", "cpu")
-except Exception:  # noqa: BLE001
-    pass
-
 import numpy as np  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 from jax.sharding import PartitionSpec  # noqa: E402

@@ -208,3 +208,30 @@ def test_llama3_70b_int8_weights_fit_tp8_slice():
                          params_nbytes=w8_bytes)
     assert plan.fits
     assert plan.n_slots * plan.max_seq_len >= 64 * 512, plan.summary()
+
+
+class _Device:
+    def __init__(self, platform, stats):
+        self.platform, self.device_kind, self._stats = platform, "fake", stats
+
+    def memory_stats(self):
+        return self._stats
+
+
+class _Client:
+    def __init__(self, device):
+        self.devices = [device]
+
+
+def test_device_budget_is_the_devices_limit_and_zero_only_off_the_tpu():
+    from gofr_tpu.tpu.capacity import device_budget_bytes
+
+    limit = 16 << 30
+    assert device_budget_bytes(
+        _Client(_Device("tpu", {"bytes_limit": limit}))) == limit
+    # the CPU reports no stats: 0 = "no plan", as the tests run
+    assert device_budget_bytes(_Client(_Device("cpu", None))) == 0
+    assert device_budget_bytes() == 0
+    # a TPU that reports none must not boot unplanned
+    with pytest.raises(RuntimeError, match="bytes_limit"):
+        device_budget_bytes(_Client(_Device("tpu", {})))

@@ -1,0 +1,420 @@
+"""Compile-only checks against a DESCRIBED v5e: what the chip's compiler
+refuses here costs no chip time (on-chip-measurement guide §2.3).
+
+The TPU compiler is installed in the sandbox and compiles for a topology
+that is described, not attached. Nothing executes: these tests say that the
+serving path's kernels and step programs lower for the chip at real widths
+(Mosaic accepts the kernel, the program fits the chip's memory, the pool is
+updated in place, a mesh program holds its collectives) — never that a
+result is right or how long anything takes.
+
+Interpret mode is what every other test runs the kernels in; it cannot see
+a misaligned slice, a kernel the compiler cannot partition, or a scatter
+that makes the compiler re-lay the whole page pool out. Each of those was
+found by a compile like the ones below before the first chip call.
+"""
+
+import dataclasses
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or libtpu logs under /tmp
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+from gofr_tpu.models.llama import LlamaConfig, llama_init
+from gofr_tpu.ops.decode_attention import decode_attention
+from gofr_tpu.ops.flash_attention import flash_attention
+from gofr_tpu.ops.paged_attention import paged_attention, paged_write_decode
+from gofr_tpu.parallel.sharding import (kv_cache_layer_spec, kv_cache_spec,
+                                        kv_scale_pool_spec,
+                                        serving_param_specs)
+
+# published head geometry of the two presets the chip serves
+WIDTHS = {"llama1b": (32, 8, 64), "llama3-8b": (32, 8, 128)}
+PAGE = 128
+GIB = 1 << 30
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as exc:  # noqa: BLE001 - no TPU compiler installed here
+        pytest.skip(f"cannot describe a v5e:2x2 topology: {exc}")
+
+
+@pytest.fixture(scope="module", autouse=True)
+def no_persistent_cache():
+    """A compile for a described chip is written to JAX's persistent cache
+    but cannot be read back without the chip: the next run would warn and
+    compile again. Keep these compiles out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+class Chips:
+    """Shapes placed on n described chips: one device, or a tp mesh."""
+
+    def __init__(self, topo, n: int):
+        self.mesh = (Mesh(np.array(topo.devices[:n]), ("tp",))
+                     if n > 1 else None)
+        self._one = SingleDeviceSharding(topo.devices[0])
+
+    def shape(self, dims, dtype, spec=P()):
+        sharding = (NamedSharding(self.mesh, spec) if self.mesh is not None
+                    else self._one)
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=sharding)
+
+
+def _compile(fn, *args, donate=()):
+    return jax.jit(fn, donate_argnums=donate).lower(*args).compile()
+
+
+def _kernels(compiled) -> int:
+    return compiled.as_text().count("tpu_custom_call")
+
+
+def _pools(chips, Hkv, dh, dtype, n_pages=513, layers=2):
+    """(k_pool, v_pool[, k_scale, v_scale]) stacked shapes, heads over tp."""
+    pool = chips.shape((layers, n_pages, Hkv, dh, PAGE), dtype,
+                       kv_cache_spec())
+    if dtype != jnp.int8:
+        return (pool, pool)
+    scale = chips.shape((layers, n_pages, Hkv, PAGE), jnp.float32,
+                        kv_scale_pool_spec())
+    return (pool, pool, scale, scale)
+
+
+# -- the serving path's kernels, one chip -------------------------------------
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.int8],
+                         ids=["bf16", "int8"])
+@pytest.mark.parametrize("preset", list(WIDTHS))
+def test_paged_attention_compiles(topo, preset, dtype):
+    H, Hkv, dh = WIDTHS[preset]
+    chips = Chips(topo, 1)
+    B, NP = 64, 8
+    q = chips.shape((B, H, dh), jnp.bfloat16)
+    k_pool, v_pool, *scales = _pools(chips, Hkv, dh, dtype)
+    table = chips.shape((B, NP), jnp.int32)
+    lengths = chips.shape((B,), jnp.int32)
+
+    def read(q, k_pool, v_pool, table, lengths, *scales):
+        return paged_attention(q, k_pool, v_pool, table, lengths, *scales,
+                               layer=jnp.int32(1), interpret=False)
+
+    compiled = _compile(read, q, k_pool, v_pool, table, lengths, *scales)
+    assert _kernels(compiled) == 1
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.int8],
+                         ids=["bf16", "int8"])
+@pytest.mark.parametrize("preset", list(WIDTHS))
+def test_paged_write_decode_compiles_in_place(topo, preset, dtype):
+    _, Hkv, dh = WIDTHS[preset]
+    chips = Chips(topo, 1)
+    B, NP = 64, 8
+    pools = _pools(chips, Hkv, dh, dtype)
+    new = chips.shape((B, Hkv, dh), dtype)
+    new_scale = chips.shape((B, Hkv), jnp.float32)
+    table = chips.shape((B, NP), jnp.int32)
+    positions = chips.shape((B,), jnp.int32)
+    n = len(pools)
+
+    def write(*args):
+        pools, (table, positions, new, new_scale) = args[:n], args[n:]
+        extra = (pools[2], pools[3], new_scale, new_scale) if n == 4 else ()
+        return paged_write_decode(pools[0], pools[1], new, new, table,
+                                  positions, *extra, layer=jnp.int32(1),
+                                  interpret=False)
+
+    compiled = _compile(write, *pools, table, positions, new, new_scale,
+                        donate=tuple(range(n)))
+    assert _kernels(compiled) == 1
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(np.prod(p.shape) * p.dtype.itemsize for p in pools)
+    # every pool is updated where it lies: aliased out, nothing copied
+    assert mem.alias_size_in_bytes == pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes // 100
+
+
+# the smoke's prefill buckets at llama1b widths; the largest at llama3-8b
+@pytest.mark.parametrize("preset,T", [("llama1b", 16), ("llama1b", 32),
+                                      ("llama1b", 64), ("llama1b", 128),
+                                      ("llama1b", 256), ("llama3-8b", 256)])
+def test_flash_attention_resident_compiles(topo, preset, T):
+    H, Hkv, dh = WIDTHS[preset]
+    chips = Chips(topo, 1)
+    q = chips.shape((4, T, H, dh), jnp.bfloat16)
+    kv = chips.shape((4, T, Hkv, dh), jnp.bfloat16)
+    compiled = _compile(
+        lambda q, k, v: flash_attention(q, k, v, True, interpret=False),
+        q, kv, kv)
+    assert _kernels(compiled) == 1
+
+
+@pytest.mark.parametrize("preset", list(WIDTHS))
+def test_flash_attention_streaming_compiles(topo, preset):
+    """K+V of one head past the resident kernel's VMEM budget: the
+    streaming kernel (kv innermost, carry in scratch) takes over."""
+    from gofr_tpu.ops.flash_attention import VMEM_KV_BUDGET_BYTES
+
+    H, Hkv, dh = WIDTHS[preset]
+    T = 32768
+    assert T * dh * 2 * 2 > VMEM_KV_BUDGET_BYTES
+    chips = Chips(topo, 1)
+    q = chips.shape((1, T, H, dh), jnp.bfloat16)
+    kv = chips.shape((1, T, Hkv, dh), jnp.bfloat16)
+    compiled = _compile(
+        lambda q, k, v: flash_attention(q, k, v, True, interpret=False),
+        q, kv, kv)
+    assert _kernels(compiled) == 1
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.int8],
+                         ids=["bf16", "int8"])
+@pytest.mark.parametrize("preset", list(WIDTHS))
+def test_decode_attention_compiles(topo, preset, dtype):
+    H, Hkv, dh = WIDTHS[preset]
+    chips = Chips(topo, 1)
+    B, S = 128, 1024
+    q = chips.shape((B, H, dh), jnp.bfloat16)
+    cache = chips.shape((B, Hkv, dh, S), dtype)
+    lengths = chips.shape((B,), jnp.int32)
+    scales = ((chips.shape((B, Hkv, S), jnp.float32),) * 2
+              if dtype == jnp.int8 else ())
+    compiled = _compile(
+        lambda q, k, v, n, *s: decode_attention(q, k, v, n, *s,
+                                                interpret=False),
+        q, cache, cache, lengths, *scales)
+    assert _kernels(compiled) == 1
+
+
+# -- the four-chip tp mesh ------------------------------------------------------
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.int8],
+                         ids=["bf16", "int8"])
+def test_paged_kernels_compile_sharded_over_tp(topo, dtype):
+    """The pool sharded over KV heads on a 4-chip tp mesh, as
+    PagedLLMEngine._place_state places it. Under plain jit the compiler
+    refuses ("Mosaic kernels cannot be automatically partitioned"); the
+    kernels run per shard under shard_map when handed the mesh."""
+    H, Hkv, dh = WIDTHS["llama1b"]
+    chips = Chips(topo, 4)
+    B, NP = 64, 8
+    heads = P(None, "tp", None)
+    q = chips.shape((B, H, dh), jnp.bfloat16, heads)
+    new = chips.shape((B, Hkv, dh), dtype, heads)
+    new_scale = chips.shape((B, Hkv), jnp.float32, P(None, "tp"))
+    pools = _pools(chips, Hkv, dh, dtype)
+    table = chips.shape((B, NP), jnp.int32)
+    positions = chips.shape((B,), jnp.int32)
+    n = len(pools)
+
+    def step(*args):
+        pools, (table, positions, q, new, new_scale) = args[:n], args[n:]
+        extra = (pools[2], pools[3], new_scale, new_scale) if n == 4 else ()
+        pools = paged_write_decode(pools[0], pools[1], new, new, table,
+                                   positions, *extra, layer=jnp.int32(1),
+                                   mesh=chips.mesh, interpret=False)
+        out = paged_attention(q, pools[0], pools[1], table, positions + 1,
+                              *pools[2:], layer=jnp.int32(1),
+                              mesh=chips.mesh, interpret=False)
+        return out, pools
+
+    compiled = _compile(step, *pools, table, positions, q, new, new_scale,
+                        donate=tuple(range(n)))
+    assert _kernels(compiled) == 2
+    # heads are independent: no collective inside either kernel's shard_map
+    assert "all-reduce(" not in compiled.as_text()
+
+
+def test_dense_kernels_compile_sharded_over_tp(topo):
+    """The dense engine's kernel call sites under a mesh: flash prefill
+    (heads of q/k/v over tp) and the decode kernel (cache heads over tp)."""
+    H, Hkv, dh = WIDTHS["llama1b"]
+    chips = Chips(topo, 4)
+    heads4 = P(None, None, "tp", None)
+    q = chips.shape((4, 256, H, dh), jnp.bfloat16, heads4)
+    kv = chips.shape((4, 256, Hkv, dh), jnp.bfloat16, heads4)
+    compiled = _compile(
+        lambda q, k, v: flash_attention(q, k, v, True, interpret=False,
+                                        mesh=chips.mesh), q, kv, kv)
+    assert _kernels(compiled) == 1
+
+    B, S = 64, 1024
+    qd = chips.shape((B, H, dh), jnp.bfloat16, P(None, "tp", None))
+    cache = chips.shape((B, Hkv, dh, S), jnp.bfloat16, kv_cache_layer_spec())
+    lengths = chips.shape((B,), jnp.int32)
+    compiled = _compile(
+        lambda q, k, v, n: decode_attention(q, k, v, n, mesh=chips.mesh,
+                                            interpret=False),
+        qd, cache, cache, lengths)
+    assert _kernels(compiled) == 1
+
+
+# -- the paged engine's step programs at llama1b widths -------------------------
+# The engine picks interpret mode from the PROCESS's backend, which is the
+# CPU here. The test steers that (not an option of the program): with the
+# backend reported as "tpu" the step lowers the real kernels, so its
+# memory_analysis() is the chip's.
+N_SLOTS, N_PAGES = 128, 2049      # 128 slots x 2048 tokens: an 8 GiB pool
+
+
+@pytest.fixture()
+def as_tpu(monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _engine_shell(cls, cfg, mesh):
+    """The program factories read only these attributes; no device state."""
+    engine = cls.__new__(cls)
+    engine.cfg, engine.mesh, engine.top_k = cfg, mesh, 0
+    engine._jnp, engine.sampling_controls = jnp, False
+    return engine
+
+
+def _params(chips, cfg):
+    shapes = jax.eval_shape(lambda: llama_init(cfg, 0))
+    return jax.tree_util.tree_map(
+        lambda a, spec: chips.shape(a.shape, a.dtype, spec),
+        shapes, serving_param_specs())
+
+
+def _loop_state(chips, rows):
+    return (chips.shape((rows,), jnp.int32), chips.shape((rows,), jnp.int32),
+            chips.shape((rows,), jnp.float32))
+
+
+def _assert_pool_in_place(compiled, pools):
+    pool_bytes = sum(np.prod(p.shape) * p.dtype.itemsize for p in pools)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    # a re-laid-out copy of one pool would be half of this or more
+    assert mem.temp_size_in_bytes < pool_bytes // 8, (
+        f"temp {mem.temp_size_in_bytes / GIB:.2f} GiB beside a "
+        f"{pool_bytes / GIB:.2f} GiB pool: the program copies the pool")
+
+
+@pytest.mark.parametrize("kv_dtype", [None, "int8"], ids=["bf16", "int8"])
+def test_paged_decode_step_compiles_in_place(topo, as_tpu, kv_dtype):
+    from gofr_tpu.tpu.paging import PagedLLMEngine
+
+    cfg = dataclasses.replace(LlamaConfig.llama1b(), kv_dtype=kv_dtype)
+    chips = Chips(topo, 1)
+    engine = _engine_shell(PagedLLMEngine, cfg, None)
+    pools = _pools(chips, cfg.n_kv_heads, cfg.head_dim,
+                   jnp.int8 if kv_dtype else jnp.bfloat16, N_PAGES,
+                   cfg.n_layers)
+    tokens, positions, temps = _loop_state(chips, N_SLOTS)
+    table = chips.shape((N_SLOTS, 4), jnp.int32)
+    rng = chips.shape((2,), jnp.uint32)
+    fn = (engine._decode_fn_paged_q8 if kv_dtype else engine._decode_fn_paged)
+    compiled = _compile(fn(8, 4), _params(chips, cfg), *pools, table, tokens,
+                        positions, temps, rng,
+                        donate=tuple(range(1, 1 + len(pools))))
+    assert _kernels(compiled) == 2      # the page write and the paged read
+    _assert_pool_in_place(compiled, pools)
+
+
+def test_paged_prefill_step_compiles_in_place(topo, as_tpu):
+    from gofr_tpu.tpu.paging import PagedLLMEngine
+
+    cfg = dataclasses.replace(LlamaConfig.llama1b(), attn_impl="flash")
+    chips = Chips(topo, 1)
+    engine = _engine_shell(PagedLLMEngine, cfg, None)
+    pools = _pools(chips, cfg.n_kv_heads, cfg.head_dim, jnp.bfloat16,
+                   N_PAGES, cfg.n_layers)
+    tokens, positions, temps = _loop_state(chips, N_SLOTS)
+    bucket, K = 256, 1
+    rows = chips.shape((K,), jnp.int32)
+    compiled = _compile(
+        engine._prefill_fn(bucket, K), _params(chips, cfg), *pools,
+        chips.shape((K, bucket), jnp.int32),
+        chips.shape((K, bucket // PAGE), jnp.int32), rows, rows,
+        tokens, positions, temps, chips.shape((K,), jnp.float32),
+        chips.shape((2,), jnp.uint32), donate=(1, 2, 7, 8, 9))
+    assert _kernels(compiled) == 1      # flash over the fresh window
+    _assert_pool_in_place(compiled, pools)
+
+
+def test_paged_prefix_prefill_step_compiles_in_place(topo, as_tpu):
+    """The prefix-cache hit path: a 16-token tail behind one shared page."""
+    from gofr_tpu.tpu.paging import PagedLLMEngine
+
+    cfg = LlamaConfig.llama1b()
+    chips = Chips(topo, 1)
+    engine = _engine_shell(PagedLLMEngine, cfg, None)
+    pools = _pools(chips, cfg.n_kv_heads, cfg.head_dim, jnp.bfloat16,
+                   N_PAGES, cfg.n_layers)
+    tokens, positions, temps = _loop_state(chips, N_SLOTS)
+    K, bucket, n_table = 1, 16, 2
+    rows = chips.shape((K,), jnp.int32)
+    compiled = _compile(
+        engine._prefix_fn(bucket, K, n_table), _params(chips, cfg), *pools,
+        chips.shape((K, bucket), jnp.int32),
+        chips.shape((K, n_table), jnp.int32), rows, rows, rows,
+        tokens, positions, temps, chips.shape((K,), jnp.float32),
+        chips.shape((2,), jnp.uint32), donate=(1, 2, 8, 9, 10))
+    _assert_pool_in_place(compiled, pools)
+
+
+def test_paged_decode_step_compiles_on_tp_mesh(topo, as_tpu):
+    """TP serving on the default engine: params Megatron-split, the pool's
+    KV heads over tp, both kernels per shard, XLA's all-reduces between."""
+    from gofr_tpu.tpu.paging import PagedLLMEngine
+
+    cfg = LlamaConfig.llama1b()
+    chips = Chips(topo, 4)
+    engine = _engine_shell(PagedLLMEngine, cfg, chips.mesh)
+    pools = _pools(chips, cfg.n_kv_heads, cfg.head_dim, jnp.bfloat16,
+                   N_PAGES, cfg.n_layers)
+    tokens, positions, temps = _loop_state(chips, N_SLOTS)
+    compiled = _compile(
+        engine._decode_fn_paged(8, 4), _params(chips, cfg), *pools,
+        chips.shape((N_SLOTS, 4), jnp.int32), tokens, positions, temps,
+        chips.shape((2,), jnp.uint32), donate=(1, 2))
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert "all-reduce(" in text        # the row-parallel wo / w_down sums
+    # memory_analysis is per device: a quarter of the pool lives on each
+    quarter = [jax.ShapeDtypeStruct(
+        (p.shape[0], p.shape[1], p.shape[2] // 4) + p.shape[3:], p.dtype)
+        for p in pools]
+    _assert_pool_in_place(compiled, quarter)
+
+
+@pytest.mark.slow  # an unrolled 16-layer program: ~10 s, a rehearsal to
+# repeat when the dense engine's programs change, not a tier-1 guard
+@pytest.mark.parametrize("decode_attn", ["xla", "kernel"])
+def test_dense_decode_step_compiles_with_layout_pin(topo, as_tpu,
+                                                    decode_attn):
+    """engine._pin_standard_layout builds Layout(major_to_minor) with no
+    tiling: the TPU compiler has to take it."""
+    from gofr_tpu.tpu.engine import LLMEngine
+
+    cfg = dataclasses.replace(LlamaConfig.llama1b(), decode_attn=decode_attn)
+    chips = Chips(topo, 1)
+    engine = _engine_shell(LLMEngine, cfg, None)
+    B, S = 64, 256
+    layers = tuple(
+        chips.shape((B, cfg.n_kv_heads, cfg.head_dim, S), jnp.bfloat16)
+        for _ in range(cfg.n_layers))
+    tokens, positions, temps = _loop_state(chips, B)
+    compiled = _compile(engine._decode_fn(8), _params(chips, cfg), layers,
+                        layers, tokens, positions, temps,
+                        chips.shape((2,), jnp.uint32), donate=(1, 2))
+    assert _kernels(compiled) == (cfg.n_layers if decode_attn == "kernel"
+                                  else 0)

@@ -74,6 +74,64 @@ def test_paged_writes_round_trip():
                                   np.asarray(knew[0]))
 
 
+@pytest.mark.parametrize("dtype,tp", [("float32", 1), ("bfloat16", 1),
+                                      ("int8", 1), ("bfloat16", 2),
+                                      ("int8", 2)])
+def test_paged_write_kernel_equals_the_column_write(dtype, tp):
+    """The decode write's Pallas kernel (what the chip runs: a page
+    read-modify-write, in place) against the plain per-token column write
+    (what the CPU runs, and the kernel's reference): the same pools,
+    exactly — values and int8 scales, the written layer and the others,
+    one device and heads sharded over a tp mesh."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    rng = np.random.default_rng(3)
+    L, P, Hkv, dh, ps, B, NP = 3, 12, 4, 16, 8, 5, 2
+    quantized = dtype == "int8"
+
+    def pool(shape, dt):
+        values = rng.integers(-100, 100, size=shape) if dt == "int8" \
+            else rng.normal(size=shape)
+        return jnp.asarray(values, dtype=dt)
+
+    pools = [pool((L, P, Hkv, dh, ps), dtype) for _ in range(2)]
+    news = [pool((B, Hkv, dh), dtype) for _ in range(2)]
+    if quantized:
+        pools += [pool((L, P, Hkv, ps), "float32") for _ in range(2)]
+        news += [pool((B, Hkv), "float32") for _ in range(2)]
+    # distinct live pages per row, plus two inactive rows on the garbage page
+    table = jnp.asarray([[1, 2], [3, 4], [5, 6], [0, 0], [0, 0]], jnp.int32)
+    positions = jnp.asarray([0, 7, 11, 3, 3], jnp.int32)
+    mesh = None
+    if tp > 1:
+        mesh = Mesh(np.array(jax.devices()[:tp]), ("tp",))
+        heads = {5: PartitionSpec(None, None, "tp", None, None),
+                 4: PartitionSpec(None, None, "tp", None),
+                 3: PartitionSpec(None, "tp", None),
+                 2: PartitionSpec(None, "tp")}
+        pools = [jax.device_put(x, NamedSharding(mesh, heads[x.ndim]))
+                 for x in pools]
+        news = [jax.device_put(x, NamedSharding(mesh, heads[x.ndim]))
+                for x in news]
+
+    def write(interpret):
+        def fn(pools, news):
+            return paged_write_decode(
+                pools[0], pools[1], news[0], news[1], table, positions,
+                *pools[2:], *news[2:], layer=jnp.int32(1), mesh=mesh,
+                interpret=interpret)
+        return jax.jit(fn)(pools, news)
+
+    got, want = write(True), write(None)
+    assert len(got) == len(want) == len(pools)
+    for g, w, before in zip(got, want, pools):
+        g, w, before = np.asarray(g), np.asarray(w), np.asarray(before)
+        live = np.arange(P) != 0      # the garbage page holds whichever won
+        np.testing.assert_array_equal(g[:, live], w[:, live])
+        assert not np.array_equal(w[1], before[1])       # layer 1 written
+        np.testing.assert_array_equal(w[[0, 2]], before[[0, 2]])
+
+
 # -- allocator ----------------------------------------------------------------
 def test_page_allocator_ledger():
     a = PageAllocator(n_pages=9, page_size=16)

@@ -10,7 +10,7 @@ from jax.sharding import NamedSharding, PartitionSpec
 from gofr_tpu.models.llama import LlamaConfig, llama_forward_nocache, llama_init
 from gofr_tpu.models.moe import MoELlamaConfig, moe_llama_forward_nocache, moe_llama_init
 from gofr_tpu.parallel import (MeshPlan, batch_spec, llama_param_specs,
-                               make_mesh, shard_map, shard_params)
+                               make_mesh, shard_params)
 from gofr_tpu.train import make_train_step
 
 
@@ -101,7 +101,7 @@ def test_ring_attention_matches_full_attention():
 
     mesh = make_mesh(MeshPlan(sp=8))
     spec = PartitionSpec(None, "sp", None, None)
-    ring = jax.jit(shard_map(
+    ring = jax.jit(jax.shard_map(
         lambda q, k, v: ring_attention(q, k, v, axis_name="sp"),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=False))
@@ -123,7 +123,7 @@ def test_ring_attention_differentiable():
     spec = PartitionSpec(None, "sp", None, None)
 
     def loss(q, k, v):
-        out = shard_map(
+        out = jax.shard_map(
             lambda q, k, v: ring_attention(q, k, v, axis_name="sp"),
             mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
             check_vma=False)(q, k, v)
@@ -226,7 +226,7 @@ def test_ulysses_attention_matches_full_attention():
 
     mesh = make_mesh(MeshPlan(sp=8))
     spec = PartitionSpec(None, "sp", None, None)
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         lambda q, k, v: ulysses_attention(q, k, v, axis_name="sp"),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=False))
@@ -248,7 +248,7 @@ def test_ulysses_matches_ring():
     spec = PartitionSpec(None, "sp", None, None)
 
     def wrap(fn):
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             lambda q, k, v: fn(q, k, v, axis_name="sp"),
             mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
             check_vma=False))
@@ -271,7 +271,7 @@ def test_ulysses_differentiable():
     spec = PartitionSpec(None, "sp", None, None)
 
     def loss(q, k, v):
-        out = shard_map(
+        out = jax.shard_map(
             lambda q, k, v: ulysses_attention(q, k, v, axis_name="sp"),
             mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
             check_vma=False)(q, k, v)
@@ -289,7 +289,7 @@ def test_ulysses_rejects_indivisible_heads():
     spec = PartitionSpec(None, "sp", None, None)
     q = jnp.ones((1, 16, 6, 8))  # 6 heads not divisible by sp=8
     with pytest.raises(ValueError, match="divide"):
-        shard_map(
+        jax.shard_map(
             lambda q, k, v: ulysses_attention(q, k, v, axis_name="sp"),
             mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
             check_vma=False)(q, q, q)

@@ -128,6 +128,19 @@ def test_peak_table_resolution(monkeypatch):
     assert bw == 819e9  # unset half falls back to the table
 
 
+def test_unknown_tpu_kind_is_an_error_not_a_default(monkeypatch):
+    monkeypatch.delenv("TPU_PEAK_FLOPS", raising=False)
+    monkeypatch.delenv("TPU_PEAK_HBM_BW", raising=False)
+    with pytest.raises(ValueError, match="TPU v99"):
+        resolve_peaks("tpu", "TPU v99")
+    with pytest.raises(ValueError):
+        resolve_peaks("tpu", None)
+    # a fleet that knows its chip better than the table says both peaks
+    monkeypatch.setenv("TPU_PEAK_FLOPS", "1e15")
+    monkeypatch.setenv("TPU_PEAK_HBM_BW", "3e12")
+    assert resolve_peaks("tpu", "TPU v99") == (1e15, 3e12, "env")
+
+
 def test_executor_compile_table():
     import jax.numpy as jnp
 

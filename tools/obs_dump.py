@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """Poll the flight recorder + SLO gauges during a soak and append JSONL.
 
-Soak runs (tools/soak.py, tools/tpu_watch.sh) record aggregate
+Soak runs (tools/soak.py) record aggregate
 throughput; this sidecar records the per-request TAIL evidence next to
 it — who is in flight, recent completions' phase timings, the SLO
 goodput fractions, and engine events (cache growth, resets, sheds) —
