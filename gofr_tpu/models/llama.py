@@ -866,13 +866,24 @@ def llama_prefill_chunk_q8(params, cfg: LlamaConfig, tokens, positions,
     return (logits,) + out_caches
 
 
+def _attended_lengths(table, positions):
+    """What each row of a paged decode step attends: its context with the
+    token just written, or NOTHING for a row that holds no request. Such a
+    row's table starts at page 0 (the PageAllocator's garbage page, never
+    handed out), and its position is whatever its last request left,
+    advanced by every step since: read as a length it would walk the
+    garbage page up to the table's width, every layer of every step."""
+    return jnp.where(table[:, 0] > 0, positions + 1, 0)
+
+
 def llama_decode_step_paged(params, cfg: LlamaConfig, tokens, positions,
                             k_pool, v_pool, table, mesh=None):
     """One decode step against a PAGED KV cache.
 
     tokens: [B]; positions: [B] absolute write positions; k/v_pool:
     [L, P, Hkv, dh, page_size]; table: [B, NP] page ids per slot (entries
-    past a slot's reservation must hold a valid id, e.g. 0).
+    past a slot's live pages are not read; a row that starts at page 0
+    holds no request and attends nothing).
     Returns (logits [B, V] float32, k_pool, v_pool).
 
     Per-layer: write this token's K/V into its page (paged_write_decode),
@@ -891,6 +902,7 @@ def llama_decode_step_paged(params, cfg: LlamaConfig, tokens, positions,
     H, Hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     x = _embed(params, cfg, tokens)[:, None]               # [B, 1, D]
     pos_grid = positions[:, None]                          # [B, 1]
+    lengths = _attended_lengths(table, positions)
 
     def layer_body(l, state):
         x, k_pool, v_pool = state
@@ -904,7 +916,7 @@ def llama_decode_step_paged(params, cfg: LlamaConfig, tokens, positions,
         k_pool, v_pool = paged_write_decode(
             k_pool, v_pool, k[:, 0], v[:, 0], table, positions,
             layer=l, mesh=mesh)
-        attn = paged_attention(q[:, 0], k_pool, v_pool, table, positions + 1,
+        attn = paged_attention(q[:, 0], k_pool, v_pool, table, lengths,
                                layer=l, mesh=mesh)
         x = x + _mm(attn.reshape(B, 1, H * dh), layer, "wo")
         x = x + _ffn_block(x, layer, cfg)
@@ -936,6 +948,7 @@ def llama_decode_step_paged_q8(params, cfg: LlamaConfig, tokens, positions,
     H, Hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     x = _embed(params, cfg, tokens)[:, None]               # [B, 1, D]
     pos_grid = positions[:, None]
+    lengths = _attended_lengths(table, positions)
 
     def layer_body(l, state):
         x, k_pool, v_pool, ks_pool, vs_pool = state
@@ -951,7 +964,7 @@ def llama_decode_step_paged_q8(params, cfg: LlamaConfig, tokens, positions,
         k_pool, v_pool, ks_pool, vs_pool = paged_write_decode(
             k_pool, v_pool, k8, v8, table, positions, ks_pool, vs_pool,
             ks, vs, layer=l, mesh=mesh)
-        attn = paged_attention(q[:, 0], k_pool, v_pool, table, positions + 1,
+        attn = paged_attention(q[:, 0], k_pool, v_pool, table, lengths,
                                ks_pool, vs_pool, layer=l, mesh=mesh)
         x = x + _mm(attn.reshape(B, 1, H * dh), layer, "wo")
         x = x + _ffn_block(x, layer, cfg)

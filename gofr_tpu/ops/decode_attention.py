@@ -13,7 +13,11 @@ Grid design (the first paged kernel's mistake, corrected): COARSE. One grid
 step covers ALL Hkv heads x one S block — grid (B, S/block_s) — so a
 B=128, S=1024 Llama-1B decode is 256 grid steps/layer, not the 16k of a
 (B, Hkv, page) grid whose per-step launch overhead dominated. Per-head dots
-([G, dh] x [dh, block_s]) unroll in Python inside the kernel body.
+([G, dh] x [dh, block_s]) unroll in Python inside the kernel body. (The
+paged read took the lesson one step further: ops/paged_attention's grid is
+one step a ROW, with the row's live pages streamed by the kernel's own
+copies, because a dense cache has no dead blocks to walk past and a block
+table mostly has.)
 
 Online softmax carries (m, l, acc) in VMEM scratch across the S axis
 (innermost), masked by per-row lengths via scalar prefetch — identical

@@ -8,29 +8,34 @@ Paging fixes both: K/V live in a fixed pool of fixed-size pages
 models/llama.init_kv_cache) and each slot owns just the pages its context
 needs, mapped by a block table [B, NP] of page indices.
 
-The TPU-native read is a Pallas kernel with SCALAR PREFETCH: the block
-table and per-slot lengths ride in SMEM ahead of the grid walk, and the
-K/V BlockSpec index_map reads table[b, p] to choose WHICH page the next
-grid step DMAs from HBM — hardware-paced gather with no materialized
-gathered cache (an XLA gather would copy the whole live cache every step).
-Online softmax (m, l, acc) carries in VMEM scratch across the page axis,
-exactly like ops/flash_attention's streaming kernel.
+The TPU-native read is a Pallas kernel with SCALAR PREFETCH: the layer,
+the block table and the per-slot lengths ride in SMEM ahead of the grid,
+the pools stay in HBM un-blocked, and the kernel's own async copies fetch
+page [layer, table[b, i]] of each pool into VMEM — a gather with no
+materialized gathered cache (an XLA gather would copy the whole live cache
+every step). Online softmax (m, l, acc) carries in f32 across a row's
+pages, exactly like ops/flash_attention's streaming kernel.
 
-Grid: COARSE (B, NP) with NP innermost — one grid step covers ALL Hkv
-heads of one page (per-head dots unroll in Python inside the body), the
-lesson ops/decode_attention's module docstring records: a (B, Hkv, page)
-grid's per-step launch overhead dominated the tiny per-step compute.
-Pages past a slot's live length re-select its LAST live page in the
-index map; Pallas skips the copy when consecutive steps map to the same
-block, so per-row HBM traffic tracks live pages, and their compute is
-skipped with pl.when.
+Grid: (B,), ONE STEP A ROW, sequential. Inside it a loop over the row's
+ceil(length / page_size) live pages and no others, each page ALL Hkv heads
+at once (the dots batch over the KV heads), double-buffered: page i + 1 is
+in flight while page i is folded in. The walk is by row because a grid
+over (row, table column) pays for every column of the table, live or not
+(on the v5e about half a microsecond each, which at a table a quarter
+full was two thirds of the kernel's time); by row, time follows the live
+tokens. A row is only a few pages, so a DMA queue that drained at every
+row boundary would idle for a large part of each row: before a row folds
+its last page it starts the first page of the next row that has one, and
+which buffer that is carries over in SMEM scratch. Table entries past a
+row's live pages are never read.
 
 The pool is STACKED over layers ([L, P, Hkv, dh, ps]) and carried whole
 through the step programs' layer loops, so everything here takes the
 stacked pool plus a `layer` index and never a per-layer slice: the kernels
-receive the layer as one more prefetched scalar and their index maps pick
-(layer, page). A dynamic slice of one layer would be a copy of that layer's
-whole slab per layer per step.
+receive the layer as one more prefetched scalar and address (layer, page)
+themselves, the read in its copies and the write in its index maps. A
+dynamic slice of one layer would be a copy of that layer's whole slab per
+layer per step.
 
 Writes keep the storage layout. The TPU compiler lays a scatter's operand
 out with the scattered window's dims minor; a per-token window is
@@ -64,7 +69,7 @@ def paged_attention_reference(q, k_pool, v_pool, table, lengths,
     table: [B, NP] int32 page ids; lengths: [B] live tokens per slot
     (including the current token). k/v_scale: optional [P, Hkv, ps]
     per-token dequant scales for int8 pools. Returns [B, H, dh] in
-    q.dtype."""
+    q.dtype; a row with lengths[b] == 0 returns zeros."""
     B, H, dh = q.shape
     P, Hkv, _, ps = k_pool.shape
     NP = table.shape[1]
@@ -86,77 +91,121 @@ def paged_attention_reference(q, k_pool, v_pool, table, lengths,
                   DEFAULT_MASK_VALUE)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhgs,bhds->bhgd", p, v)
+    # nothing to attend is zeros, not the uniform mean of junk v: the
+    # convention of ops/decode_attention's oracle and of the kernel
+    out = jnp.where((lengths > 0)[:, None, None, None], out, 0.0)
     return out.reshape(B, H, dh).astype(q.dtype)
 
 
-def _paged_kernel(layer_ref, table_ref, len_ref, *refs, page_size: int,
-                  n_kv: int, scale: float, quantized: bool):
-    """One (b, p) grid step: fold page p (ALL heads) into the online
-    softmax. Heads unroll in Python — the coarse grid keeps per-step
-    launch overhead amortized over Hkv head-dots.
+def _paged_kernel(layer_ref, table_ref, len_ref, q_ref, *refs, scale: float,
+                  quantized: bool):
+    """One grid step = one row b: stream the row's live pages (ALL heads of
+    a page at a time) through two VMEM buffers a pool and fold each into
+    the online softmax, the dots batched over the KV heads.
 
-    quantized=False refs: (q, k, v, o, m, l, acc)
-    quantized=True  refs: (q, k, v, k_scale, v_scale, o, m, l, acc) — int8
-    pages with per-token scales; dequant FOLDS into the dots exactly like
+    refs: the n stacked pools left in HBM (k, v[, k_scale, v_scale]), o,
+    their n VMEM buffers [2, *page], DMA semaphores [n, 2] and `first_slot`
+    (SMEM: the buffer this row's first page was started in). int8 pages
+    carry per-token scales; dequant FOLDS into the dots exactly like
     ops/decode_attention's quantized kernel (k's scale multiplies score
     rows, v's folds into the probabilities)."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
-    if quantized:
-        q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = refs
-    else:
-        q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr = refs
-        ks_ref = vs_ref = None
+    n = 4 if quantized else 2
+    pools, o_ref, bufs = refs[:n], refs[n], refs[n + 1:2 * n + 1]
+    sems, first_slot = refs[2 * n + 1:]
+    k_buf, v_buf = bufs[:2]
+    ks_buf, vs_buf = bufs[2:] if quantized else (None, None)
 
     b = pl.program_id(0)
-    p = pl.program_id(1)
-    n_pages = pl.num_programs(1)
+    last_row = pl.num_programs(0) - 1
+    layer = layer_ref[0]
     length = len_ref[b]
-    G = q_ref.shape[2]
-    dh = q_ref.shape[3]
+    n_kv, G, dh = q_ref.shape[1:]
+    page_size = k_buf.shape[-1]
+    n_pages = jnp.minimum((length + page_size - 1) // page_size,
+                          table_ref.shape[1])
 
-    @pl.when(p == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, DEFAULT_MASK_VALUE)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+    def page_copies(row, i, slot):
+        page = table_ref[row, i]
+        return [pltpu.make_async_copy(pool.at[layer, page], buf.at[slot],
+                                      sems.at[j, slot])
+                for j, (pool, buf) in enumerate(zip(pools, bufs))]
 
-    @pl.when(p * page_size < length)
-    def _compute():
-        kv_pos = p * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (G, page_size), 1)
-        mask = kv_pos < length
-        for h in range(n_kv):                             # unrolled heads
-            q = q_ref[0, h]                               # [G, dh]
-            k = k_ref[0, h]                               # [dh, ps]
-            v = v_ref[0, h]
-            if quantized:
-                k = k.astype(jnp.bfloat16)                # in-VMEM upcast
-            s = jax.lax.dot_general(q, k, (((1,), (0,)), ((), ())),
-                                    preferred_element_type=jnp.float32) * scale
-            if quantized:
-                s = s * ks_ref[0, h][None, :].astype(jnp.float32)
-            s = jnp.where(mask, s, DEFAULT_MASK_VALUE)
-            row = slice(h * G, (h + 1) * G)
-            m_prev = m_scr[row]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            pr = jnp.exp(s - m_new)
-            alpha = jnp.exp(m_prev - m_new)
-            m_scr[row] = m_new
-            l_scr[row] = l_scr[row] * alpha + jnp.sum(pr, axis=-1,
-                                                      keepdims=True)
-            if quantized:
-                pr = pr * vs_ref[0, h][None, :].astype(jnp.float32)
-                v = v.astype(jnp.bfloat16)
-            pv = jax.lax.dot_general(pr.astype(v.dtype), v,
-                                     (((1,), (1,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-            acc_scr[row] = acc_scr[row] * alpha + pv
+    def start_first_page_after(row, slot):
+        # the next row that HAS a page: a row of length 0 owns none
+        def live_or_end(r):
+            return jnp.logical_or(r > last_row,
+                                  len_ref[jnp.minimum(r, last_row)] > 0)
 
-    @pl.when(p == n_pages - 1)
-    def _finalize():
-        o_ref[0] = (acc_scr[:] / jnp.maximum(l_scr[:], 1e-30)
-                    ).reshape(n_kv, G, dh).astype(o_ref.dtype)
+        nxt, _ = jax.lax.while_loop(
+            lambda c: jnp.logical_not(c[1]),
+            lambda c: (c[0] + 1, live_or_end(c[0] + 1)),
+            (row + 1, live_or_end(row + 1)))
+
+        @pl.when(nxt <= last_row)
+        def _start():
+            for copy in page_copies(nxt, 0, slot):
+                copy.start()
+
+    @pl.when(b == 0)
+    def _first_row():
+        first_slot[0] = 0
+        start_first_page_after(-1, 0)
+
+    q = q_ref[0]                                          # [Hkv, G, dh]
+    slot0 = first_slot[0]
+
+    def fold_page(i, carry):
+        m_prev, l_prev, acc = carry
+        slot = (slot0 + i) % 2
+
+        # keep the DMA queue fed before waiting: this row's next page or,
+        # from its last page, the first page of the next row that has one
+        @pl.when(i + 1 < n_pages)
+        def _next_page():
+            for copy in page_copies(b, i + 1, 1 - slot):
+                copy.start()
+
+        @pl.when(i + 1 == n_pages)
+        def _next_row():
+            start_first_page_after(b, 1 - slot)
+
+        for copy in page_copies(b, i, slot):
+            copy.wait()
+
+        k = k_buf[slot]                                   # [Hkv, dh, ps]
+        v = v_buf[slot]
+        if quantized:
+            k = k.astype(jnp.bfloat16)                    # in-VMEM upcast
+        # every head's [G, dh] x [dh, ps], batched over the KV heads
+        s = jax.lax.dot_general(q, k, (((2,), (1,)), ((0,), (0,))),
+                                preferred_element_type=jnp.float32) * scale
+        if quantized:
+            s = s * ks_buf[slot][:, None, :].astype(jnp.float32)
+        kv_pos = i * page_size + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 2)
+        s = jnp.where(kv_pos < length, s, DEFAULT_MASK_VALUE)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        pr = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_new = l_prev * alpha + jnp.sum(pr, axis=-1, keepdims=True)
+        if quantized:
+            pr = pr * vs_buf[slot][:, None, :].astype(jnp.float32)
+            v = v.astype(jnp.bfloat16)
+        pv = jax.lax.dot_general(pr.astype(v.dtype), v,
+                                 (((2,), (2,)), ((0,), (0,))),
+                                 preferred_element_type=jnp.float32)
+        return m_new, l_new, acc * alpha + pv
+
+    _, l, acc = jax.lax.fori_loop(
+        0, n_pages, fold_page,
+        (jnp.full((n_kv, G, 1), DEFAULT_MASK_VALUE, jnp.float32),
+         jnp.zeros((n_kv, G, 1), jnp.float32),
+         jnp.zeros((n_kv, G, dh), jnp.float32)))
+    first_slot[0] = (slot0 + n_pages) % 2
+    o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
 def _stacked(pool, layer):
@@ -203,10 +252,9 @@ def paged_attention(q, k_pool, v_pool, table, lengths, k_scale=None,
     (parallel/sharding.kv_cache_spec): q splits on H, pools and scales on
     Hkv, table, lengths and layer replicate.
 
-    Dead table entries (p*ps >= lengths[b]) must hold a VALID page id
-    (0 is fine); the index map re-selects the row's last live page for
-    them, so consecutive dead steps skip their DMA entirely and their
-    compute is skipped via pl.when.
+    Table entries past a row's ceil(lengths[b] / ps) live pages are not
+    read, and a row of length 0 reads nothing and returns zeros. The walk
+    stops at the table's width whatever the length says.
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -240,66 +288,29 @@ def paged_attention(q, k_pool, v_pool, table, lengths, k_scale=None,
                              check_vma=False)(*operands)
 
     B, H, dh = q.shape
-    _, _, Hkv, _, ps = k_pool.shape
-    NP = table.shape[1]
+    Hkv = k_pool.shape[2]
     G = H // Hkv
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    if interpret:
-        # the interpreter carries each operand whole through its grid
-        # loop; one layer's slab (a copy the chip must never make) halves
-        # a CPU decode step against handing it the stack
-        def one_layer(pool):
-            return jax.lax.dynamic_index_in_dim(pool, layer_arr[0], 0)
 
-        k_pool, v_pool = one_layer(k_pool), one_layer(v_pool)
-        if quantized:
-            k_scale, v_scale = one_layer(k_scale), one_layer(v_scale)
-        layer_arr = jnp.zeros((1,), jnp.int32)
-
-    qg = q.reshape(B, Hkv, G, dh)
-    kernel = functools.partial(_paged_kernel, page_size=ps, n_kv=Hkv,
-                               scale=1.0 / math.sqrt(dh),
+    pools = [k_pool, v_pool] + ([k_scale, v_scale] if quantized else [])
+    kernel = functools.partial(_paged_kernel, scale=1.0 / math.sqrt(dh),
                                quantized=quantized)
 
-    def live_page(b, p, table, lens):
-        # LIVE-PAGE DMA CLAMP (see ops/decode_attention.kv_index): dead
-        # steps re-select the last live page; equal consecutive block
-        # indices skip the copy
-        last_live = jnp.maximum((lens[b] + ps - 1) // ps - 1, 0)
-        return table[b, jnp.minimum(p, last_live)]
-
-    def page_index(b, p, layer, table, lens):
-        return (layer[0], live_page(b, p, table, lens), 0, 0, 0)
-
-    def scale_index(b, p, layer, table, lens):
-        return (layer[0], live_page(b, p, table, lens), 0, 0)
-
-    def row_index(b, p, layer, table, lens):
+    def row_index(b, layer, table, lens):
         return (b, 0, 0, 0)
-
-    # the layer dim is squeezed out of the kernel's refs (None), so the
-    # body indexes [page, head] exactly as it would one layer's pool
-    in_specs = [
-        pl.BlockSpec((1, Hkv, G, dh), row_index),
-        pl.BlockSpec((None, 1, Hkv, dh, ps), page_index),
-        pl.BlockSpec((None, 1, Hkv, dh, ps), page_index),
-    ]
-    operands = [layer_arr, table, lengths, qg, k_pool, v_pool]
-    if quantized:
-        in_specs += [pl.BlockSpec((None, 1, Hkv, ps), scale_index),
-                     pl.BlockSpec((None, 1, Hkv, ps), scale_index)]
-        operands += [k_scale, v_scale]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,  # layer, table, lengths
-        grid=(B, NP),
-        in_specs=in_specs,
+        grid=(B,),
+        # the pools stay in HBM, whole: the kernel copies [layer, page]
+        in_specs=[pl.BlockSpec((1, Hkv, G, dh), row_index)]
+        + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
         out_specs=pl.BlockSpec((1, Hkv, G, dh), row_index),
-        scratch_shapes=[
-            pltpu.VMEM((Hkv * G, 1), jnp.float32),
-            pltpu.VMEM((Hkv * G, 1), jnp.float32),
-            pltpu.VMEM((Hkv * G, dh), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((2,) + pool.shape[2:], pool.dtype)
+                        for pool in pools] + [
+            pltpu.SemaphoreType.DMA((len(pools), 2)),
+            pltpu.SMEM((1,), jnp.int32),
         ],
     )
     with kernel_scope("paged_read"):
@@ -307,8 +318,11 @@ def paged_attention(q, k_pool, v_pool, table, lengths, k_scale=None,
             kernel,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((B, Hkv, G, dh), q.dtype),
+            # sequential rows: a row starts its successor's first page
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",)),
             interpret=interpret,
-        )(*operands)
+        )(layer_arr, table, lengths, q.reshape(B, Hkv, G, dh), *pools)
     return out.reshape(B, H, dh)
 
 

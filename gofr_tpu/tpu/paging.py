@@ -13,8 +13,9 @@ TPU wants it fixed (SURVEY.md §5 long-context row; VERDICT r2 missing #4):
     the pool is an explicit budget instead of an OOM surprise
   - decode reads ride the scalar-prefetch Pallas kernel
     (ops/paged_attention): the block table rides in SMEM and picks which
-    HBM page each grid step DMAs — per-step traffic tracks live pages, and
-    the pallas operands keep the pool in its unpadded S-minor layout
+    HBM pages the kernel copies in, a row's live pages and no others —
+    per-step traffic and time track live pages, and the pallas operands
+    keep the pool in its unpadded S-minor layout
   - the block table is host-owned (plain numpy) and uploaded per dispatch,
     bucketed to power-of-two widths to bound compiled decode variants
 

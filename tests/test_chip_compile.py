@@ -35,7 +35,8 @@ from gofr_tpu.parallel.sharding import (kv_cache_layer_spec, kv_cache_spec,
                                         serving_param_specs)
 
 # published head geometry of the two presets the chip serves
-WIDTHS = {"llama1b": (32, 8, 64), "llama3-8b": (32, 8, 128)}
+WIDTHS = {"llama1b": (32, 8, 64), "llama3-8b": (32, 8, 128),
+          "internlm2": (16, 8, 128)}
 PAGE = 128
 GIB = 1 << 30
 
@@ -206,12 +207,13 @@ def test_decode_attention_compiles(topo, preset, dtype):
 # -- the four-chip tp mesh ------------------------------------------------------
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.int8],
                          ids=["bf16", "int8"])
-def test_paged_kernels_compile_sharded_over_tp(topo, dtype):
+@pytest.mark.parametrize("preset", ["llama1b", "internlm2"])
+def test_paged_kernels_compile_sharded_over_tp(topo, preset, dtype):
     """The pool sharded over KV heads on a 4-chip tp mesh, as
     PagedLLMEngine._place_state places it. Under plain jit the compiler
     refuses ("Mosaic kernels cannot be automatically partitioned"); the
     kernels run per shard under shard_map when handed the mesh."""
-    H, Hkv, dh = WIDTHS["llama1b"]
+    H, Hkv, dh = WIDTHS[preset]
     chips = Chips(topo, 4)
     B, NP = 64, 8
     heads = P(None, "tp", None)
@@ -308,22 +310,37 @@ def _assert_pool_in_place(compiled, pools):
         f"{pool_bytes / GIB:.2f} GiB pool: the program copies the pool")
 
 
+def _served(preset: str):
+    """(config, slots, pages, decode block, table width): llama1b as the
+    smoke serves it, internlm2-1.8b as the benchmark's cells do
+    (benchmark/configs/internlm2-1.8b.json, `jit_decode__x16_NP16`)."""
+    if preset == "llama1b":
+        return LlamaConfig.llama1b(), N_SLOTS, N_PAGES, 8, 4
+    H, Hkv, dh = WIDTHS["internlm2"]
+    cfg = LlamaConfig(vocab_size=92544, dim=H * dh, n_layers=24, n_heads=H,
+                      n_kv_heads=Hkv, ffn_dim=8192, max_seq_len=2048,
+                      rope_theta=1e6)
+    return cfg, 96, 769, 16, 16
+
+
 @pytest.mark.parametrize("kv_dtype", [None, "int8"], ids=["bf16", "int8"])
-def test_paged_decode_step_compiles_in_place(topo, as_tpu, kv_dtype):
+@pytest.mark.parametrize("preset", ["llama1b", "internlm2"])
+def test_paged_decode_step_compiles_in_place(topo, as_tpu, preset, kv_dtype):
     from gofr_tpu.tpu.paging import PagedLLMEngine
 
-    cfg = dataclasses.replace(LlamaConfig.llama1b(), kv_dtype=kv_dtype)
+    cfg, n_slots, n_pages, block, n_table = _served(preset)
+    cfg = dataclasses.replace(cfg, kv_dtype=kv_dtype)
     chips = Chips(topo, 1)
     engine = _engine_shell(PagedLLMEngine, cfg, None)
     pools = _pools(chips, cfg.n_kv_heads, cfg.head_dim,
-                   jnp.int8 if kv_dtype else jnp.bfloat16, N_PAGES,
+                   jnp.int8 if kv_dtype else jnp.bfloat16, n_pages,
                    cfg.n_layers)
-    tokens, positions, temps = _loop_state(chips, N_SLOTS)
-    table = chips.shape((N_SLOTS, 4), jnp.int32)
+    tokens, positions, temps = _loop_state(chips, n_slots)
+    table = chips.shape((n_slots, n_table), jnp.int32)
     rng = chips.shape((2,), jnp.uint32)
     fn = (engine._decode_fn_paged_q8 if kv_dtype else engine._decode_fn_paged)
-    compiled = _compile(fn(8, 4), _params(chips, cfg), *pools, table, tokens,
-                        positions, temps, rng,
+    compiled = _compile(fn(block, n_table), _params(chips, cfg), *pools,
+                        table, tokens, positions, temps, rng,
                         donate=tuple(range(1, 1 + len(pools))))
     assert _kernels(compiled) == 2      # the page write and the paged read
     _assert_pool_in_place(compiled, pools)
@@ -365,14 +382,33 @@ def _kernel_calls(compiled):
     return out
 
 
+def _computation_of(compiled, instruction: str) -> str:
+    """The name of the HLO computation that holds `instruction`."""
+    computation = None
+    for line in compiled.as_text().splitlines():
+        if line and not line[0].isspace() and line.rstrip().endswith("{"):
+            computation = line.removeprefix("ENTRY ").split()[0].lstrip("%")
+        elif line.strip().removeprefix("ROOT ").startswith(
+                f"%{instruction} = "):
+            return computation
+    raise AssertionError(f"{instruction} is in no computation")
+
+
+def _while_bodies(compiled) -> set:
+    import re
+
+    return set(re.findall(r"while\(.*body=%([\w.\-]+)", compiled.as_text()))
+
+
 @pytest.mark.parametrize("program", ["decode", "prefill"])
 def test_step_programs_name_their_module_and_kernels(topo, as_tpu, program):
     """What a profiler trace shows of a step program (ISSUE 24): the XLA
     module carries the table width and block, and each Pallas kernel's
     instruction is named after its scope. The name keeps the
     `closed_call` prefix that the benchmark's kernel readers select on
-    for now (ops/scopes.py says until when), and the page write is still
-    the custom-call that returns the two pools."""
+    for now (ops/scopes.py says until when), the page write is still the
+    custom-call that returns the two pools, and the paged read is one
+    custom-call in the layer loop's body with one array as its result."""
     from gofr_tpu.tpu.executor import _named_after
     from gofr_tpu.tpu.paging import PagedLLMEngine
 
@@ -392,10 +428,15 @@ def test_step_programs_name_their_module_and_kernels(topo, as_tpu, program):
             tokens, positions, temps, rng, donate=(1, 2))
         assert "HloModule jit_decode__x8_NP4," in compiled.as_text()
         calls = sorted(_kernel_calls(compiled))
+        # what benchmark/harness/tracered.kernels relies on: ONE read a
+        # layer-loop body, whose result is one array (a tuple result is
+        # counted as the page write)
         assert [(name.rsplit(".", 1)[0], tupled)
                 for name, tupled, _ in calls] == [
             ("closed_call_paged_read", False),
             ("closed_call_paged_write", True)]
+        assert _computation_of(compiled, calls[0][0]) in _while_bodies(
+            compiled)
     else:
         K, bucket = 1, 256
         rows = chips.shape((K,), jnp.int32)

@@ -33,19 +33,115 @@ class MockLogger:
 
 
 # -- kernel -------------------------------------------------------------------
-def test_paged_attention_kernel_matches_reference():
-    rng = np.random.default_rng(0)
-    B, H, Hkv, dh, ps, P, NP = 3, 4, 2, 16, 8, 10, 4
-    q = jnp.asarray(rng.normal(size=(B, H, dh)), dtype=jnp.float32)
-    k_pool = jnp.asarray(rng.normal(size=(P, Hkv, dh, ps)), dtype=jnp.float32)
-    v_pool = jnp.asarray(rng.normal(size=(P, Hkv, dh, ps)), dtype=jnp.float32)
-    table = jnp.asarray(rng.integers(0, P, size=(B, NP)), dtype=jnp.int32)
-    lengths = jnp.asarray([5, 17, 32], dtype=jnp.int32)
+# The read walks each row's live pages: one loop iteration a page, the
+# first page of the next row that has one started from the row before.
+# Every edge of that loop, at the two head geometries the chip serves in
+# miniature (G = 2 like internlm2, G = 4 like llama1b).
+PS, NP_TABLE, N_LAYERS = 8, 4, 3
+RAGGED = [PS + 1, 0, NP_TABLE * PS, 0, 0, 1, PS, PS - 1]
+ROW_LENGTHS = {"0": [0] * 8, "1": [1] * 8, "ps-1": [PS - 1] * 8,
+               "ps": [PS] * 8, "ps+1": [PS + 1] * 8,
+               "full-table": [NP_TABLE * PS] * 8, "ragged": RAGGED}
+GEOMETRY = {"G2": (4, 2, 32), "G4": (8, 2, 16)}          # H, Hkv, dh
 
-    ref = paged_attention_reference(q, k_pool, v_pool, table, lengths)
-    out = paged_attention(q, k_pool, v_pool, table, lengths)
+
+def _paged_case(geometry, dtype, lengths, seed=0):
+    """q, one layer's pools, a table of DISTINCT pages (page 0 kept as the
+    dead entries' target) and the lengths."""
+    H, Hkv, dh = GEOMETRY[geometry]
+    rng = np.random.default_rng(seed)
+    B, n_pool_pages = len(lengths), 40
+    q = jnp.asarray(rng.normal(size=(B, H, dh)), dtype=dtype)
+    k_pool, v_pool = (
+        jnp.asarray(rng.normal(size=(n_pool_pages, Hkv, dh, PS)), dtype=dtype)
+        for _ in range(2))
+    table = np.zeros((B, NP_TABLE), np.int32)
+    free = iter(rng.permutation(np.arange(1, n_pool_pages)))
+    for b, n in enumerate(lengths):
+        for i in range(-(-n // PS)):
+            table[b, i] = next(free)
+    return (q, k_pool, v_pool, jnp.asarray(table),
+            jnp.asarray(lengths, dtype=jnp.int32))
+
+
+def _in_layer(pool, layer):
+    """`pool` as layer `layer` of a stack whose other layers are junk."""
+    if layer is None:
+        return pool
+    junk = jnp.full((N_LAYERS,) + pool.shape, 7, pool.dtype)
+    return junk.at[layer].set(pool)
+
+
+_read = jax.jit(paged_attention)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("layer", [0, N_LAYERS - 1, None],
+                         ids=["first-layer", "last-layer", "one-layer"])
+@pytest.mark.parametrize("geometry", list(GEOMETRY))
+@pytest.mark.parametrize("lengths", list(ROW_LENGTHS))
+def test_paged_attention_kernel_matches_reference(lengths, geometry, layer,
+                                                  dtype):
+    q, k_pool, v_pool, table, lens = _paged_case(geometry, dtype,
+                                                 ROW_LENGTHS[lengths])
+    ref = paged_attention_reference(q.astype(jnp.float32), k_pool, v_pool,
+                                    table, lens)
+    out = _read(q, _in_layer(k_pool, layer), _in_layer(v_pool, layer), table,
+                lens, layer=None if layer is None else jnp.int32(layer))
+    assert out.dtype == dtype
+    tol = 2e-5 if dtype == jnp.float32 else 3e-2
+    np.testing.assert_allclose(np.asarray(out, dtype=np.float32),
+                               np.asarray(ref), rtol=tol, atol=tol)
+    # a row with nothing to attend reads nothing and answers zeros
+    empty = np.asarray(lens) == 0
+    assert not np.asarray(out, dtype=np.float32)[empty].any()
+
+
+@pytest.mark.parametrize("geometry", list(GEOMETRY))
+def test_paged_attention_reads_live_pages_only(geometry):
+    """Every page no live token sits in is NaN, and so is the page every
+    dead table entry names: the kernel dereferences neither."""
+    q, k_pool, v_pool, table, lens = _paged_case(geometry, jnp.float32,
+                                                 RAGGED, seed=3)
+    ref = paged_attention_reference(q, k_pool, v_pool, table, lens)
+    live = np.zeros(k_pool.shape[0], bool)
+    for b, n in enumerate(RAGGED):
+        live[np.asarray(table)[b, :-(-n // PS)]] = True
+    assert not live[0] and live.sum() == sum(-(-n // PS) for n in RAGGED)
+    poison = jnp.asarray(~live)[:, None, None, None]
+    out = _read(q, jnp.where(poison, jnp.nan, k_pool),
+                jnp.where(poison, jnp.nan, v_pool), table, lens)
+    assert np.isfinite(np.asarray(out)).all()
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
+
+
+def test_decode_step_row_without_request_attends_nothing():
+    """An idle slot's row of the table is zeros (the garbage page) and its
+    position is stale and still advancing: the step hands the read a
+    length of 0 for it, so it walks no page — here the garbage page is
+    NaN but for the columns the step itself writes, and the idle row's
+    stale position lies far past the table."""
+    from gofr_tpu.models.llama import llama_decode_step_paged
+
+    params = llama_init(CFG, seed=0)
+    ps, n_pool_pages = 8, 6
+    shape = (CFG.n_layers, n_pool_pages, CFG.n_kv_heads, CFG.head_dim, ps)
+    rng = np.random.default_rng(7)
+    pool = jnp.asarray(rng.normal(size=shape), dtype=jnp.float32)
+    poisoned = pool.at[:, 0].set(jnp.nan)
+    table = jnp.asarray([[2, 3, 0, 0], [0, 0, 0, 0], [4, 0, 0, 0]],
+                        dtype=jnp.int32)
+    tokens = jnp.asarray([5, 6, 7], dtype=jnp.int32)
+    positions = jnp.asarray([11, 10_000, 3], dtype=jnp.int32)
+    step = jax.jit(lambda k, v: llama_decode_step_paged(
+        params, CFG, tokens, positions, k, v, table)[0])
+    logits = np.asarray(step(poisoned, poisoned))
+    assert np.isfinite(logits).all()
+    np.testing.assert_allclose(logits[[0, 2]],
+                               np.asarray(step(pool, pool))[[0, 2]],
+                               rtol=1e-5, atol=1e-5)
 
 
 def test_paged_writes_round_trip():
@@ -375,20 +471,13 @@ def test_paged_q8_engine_matches_paged_fp_closely():
     assert q8 == serve(cfg_q8)       # deterministic
 
 
-def test_paged_attention_int8_matches_reference():
+@pytest.mark.parametrize("geometry", list(GEOMETRY))
+def test_paged_attention_int8_matches_reference(geometry):
     from gofr_tpu.ops.decode_attention import quantize_kv
-    from gofr_tpu.ops.paged_attention import paged_attention_reference
 
-    rng = np.random.default_rng(5)
-    B, H, Hkv, dh, P, ps, NP = 3, 4, 2, 16, 9, 8, 4
-    q = jnp.asarray(rng.normal(size=(B, H, dh)), dtype=jnp.float32)
-    k = jnp.asarray(rng.normal(size=(P, Hkv, dh, ps)), dtype=jnp.float32)
-    v = jnp.asarray(rng.normal(size=(P, Hkv, dh, ps)), dtype=jnp.float32)
+    q, k, v, table, lens = _paged_case(geometry, jnp.float32, RAGGED, seed=5)
     k8, ks = quantize_kv(k)     # axis=-2 (dh) -> scales [P, Hkv, ps]
     v8, vs = quantize_kv(v)
-    table = jnp.asarray([[1, 2, 3, 4], [5, 6, 0, 0], [7, 8, 0, 0]],
-                        dtype=jnp.int32)
-    lens = jnp.asarray([29, 11, 16], dtype=jnp.int32)
     ref = paged_attention_reference(q, k8, v8, table, lens, ks, vs)
     out = paged_attention(q, k8, v8, table, lens, ks, vs)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -397,6 +486,12 @@ def test_paged_attention_int8_matches_reference():
     exact = paged_attention_reference(q, k, v, table, lens)
     np.testing.assert_allclose(np.asarray(out), np.asarray(exact),
                                rtol=0.15, atol=0.15)
+    # and the same through a stack, whose scale pools ride with it
+    last = N_LAYERS - 1
+    stacked = paged_attention(q, _in_layer(k8, last), _in_layer(v8, last),
+                              table, lens, _in_layer(ks, last),
+                              _in_layer(vs, last), layer=jnp.int32(last))
+    np.testing.assert_array_equal(np.asarray(stacked), np.asarray(out))
 
 
 def test_paged_priority_no_head_of_line_inversion():
