@@ -4,7 +4,8 @@ POST /generate {"prompt": "...", "max_tokens": 64, "temperature": 0.7,
 "stream": true} -> server-sent events, one JSON per token chunk, then a final
 {"done": true} summary. stream=false returns one JSON response.
 
-Model size comes from MODEL_PRESET (debug | llama1b | llama3-8b). Weights
+Model size comes from MODEL_PRESET (debug | llama1b | llama3-8b, and the
+nemotron_h family's nemotron-h-debug | nemotron-3-nano-30b-a3b-ep2). Weights
 boot from a real HF-layout safetensors checkpoint when WEIGHTS_PATH is set
 (models.weights.load_llama_safetensors — streaming, int8 quantize-on-load);
 otherwise random-initialised (no checkpoints ship in this environment) with
@@ -21,6 +22,8 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", ".."))
 from gofr_tpu import App, Stream  # noqa: E402
 from gofr_tpu.http.errors import InvalidParam, ServiceUnavailable  # noqa: E402
 from gofr_tpu.models.llama import LlamaConfig, llama_init  # noqa: E402
+from gofr_tpu.models.nemotron_h import (NemotronHConfig,  # noqa: E402
+                                        nemotron_h_init)
 from gofr_tpu.models.tokenizer import (ByteTokenizer, DebugTokenizer,  # noqa: E402
                                        StreamingDecoder)
 from gofr_tpu.tpu.device import TPUClient  # noqa: E402
@@ -32,6 +35,12 @@ PRESETS = {
     "llama1b": LlamaConfig.llama1b,
     "llama3-8b": LlamaConfig.llama3_8b,
     "llama3-70b": LlamaConfig.llama3_70b,  # TP_SHARDS=8 territory (config 5)
+    # the nemotron_h family (docs/model-families.md): Mamba-2, sparse-expert
+    # and attention blocks in one stack, served by the paged engine only
+    "nemotron-h-debug": NemotronHConfig.debug,
+    # one chip's share of NVIDIA-Nemotron-3-Nano-30B-A3B, as the benchmark
+    # runs it (benchmark/configs/nemotron-3-nano-30b-a3b-ep2.json)
+    "nemotron-3-nano-30b-a3b-ep2": NemotronHConfig.nano_30b_a3b_ep2,
 }
 
 
@@ -100,7 +109,8 @@ def build_engine(app: App, default_sampling_controls: bool = False) -> LLMEngine
     import dataclasses
 
     attn_impl = app.config.get_or_default("ATTN_IMPL", cfg.attn_impl)
-    decode_attn = app.config.get_or_default("DECODE_ATTN", cfg.decode_attn)
+    decode_attn = app.config.get_or_default(
+        "DECODE_ATTN", getattr(cfg, "decode_attn", "xla"))
     # KV_DTYPE=int8 halves cache HBM bytes (quantize-on-write, kernel
     # dequant) — requires DECODE_ATTN=kernel
     kv_dtype = app.config.get_or_default("KV_DTYPE", "") or None
@@ -110,8 +120,19 @@ def build_engine(app: App, default_sampling_controls: bool = False) -> LLMEngine
         raise ValueError(f"DECODE_ATTN must be xla|kernel, got {decode_attn!r}")
     if kv_dtype not in (None, "int8"):
         raise ValueError(f"KV_DTYPE must be int8 or unset, got {kv_dtype!r}")
-    cfg = dataclasses.replace(cfg, attn_impl=attn_impl,
-                              decode_attn=decode_attn, kv_dtype=kv_dtype)
+    asked = {"attn_impl": attn_impl, "decode_attn": decode_attn,
+             "kv_dtype": kv_dtype}
+    has = {f.name for f in dataclasses.fields(cfg)}
+    # a family with one decode read and no lower-precision cache has no
+    # such field (models/protocol.py): asked of it, refuse by name
+    absent = sorted(k for k, v in asked.items()
+                    if k not in has and v not in (None, "xla"))
+    if absent:
+        raise ValueError(
+            f"MODEL_PRESET={preset} has no {', '.join(absent)}: unset "
+            f"{', '.join(k.upper() for k in absent)}")
+    cfg = dataclasses.replace(
+        cfg, **{k: v for k, v in asked.items() if k in has})
     # VOCAB_PATH deploys a real model vocabulary (JSON {vocab, merges},
     # BPETokenizer.from_file — native merge loop when the C++ lib is built);
     # without it the exact-and-reversible byte tokenizer serves
@@ -147,7 +168,13 @@ def build_engine(app: App, default_sampling_controls: bool = False) -> LLMEngine
     # preset before any bytes load; WEIGHT_DTYPE=int8 quantizes each leaf
     # on device as it streams in, so the float tree never materializes
     weights_path = app.config.get_or_default("WEIGHTS_PATH", "")
-    if weights_path:
+    if isinstance(cfg, NemotronHConfig):
+        if weights_path or weight_dtype:
+            raise ValueError("the nemotron_h family has no checkpoint "
+                             "loader and no int8 weight path yet: unset "
+                             "WEIGHTS_PATH and WEIGHT_DTYPE")
+        params = nemotron_h_init(cfg, seed=0)
+    elif weights_path:
         from gofr_tpu.models.weights import load_llama_safetensors
 
         t_load = time.time()
@@ -184,7 +211,10 @@ def build_engine(app: App, default_sampling_controls: bool = False) -> LLMEngine
         # PREFIX_CACHE shares whole prompt-prefix pages between requests
         # (system prompts re-prefill once, not per request); int8 pools
         # share their scale pages alongside
-        paged_kw["prefix_cache"] = app.config.get_bool("PREFIX_CACHE", True)
+        # (on by default for a family that can serve it; asked for of one
+        # that cannot, the engine refuses it by name)
+        paged_kw["prefix_cache"] = app.config.get_bool(
+            "PREFIX_CACHE", "prefix_cache" not in cfg.paged_model().refuses)
         # KV_HOST_TIER_BYTES>0 adds a host-RAM tier under the prefix
         # cache: evicted refs==0 pages spill to pinned host blobs and
         # restore via one H2D scatter at admission, so a re-sent prefix

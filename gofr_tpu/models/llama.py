@@ -62,6 +62,28 @@ class LlamaConfig:
     def q_per_kv(self) -> int:
         return self.n_heads // self.n_kv_heads
 
+    @property
+    def kv_layers(self) -> int:
+        """Every block keeps K and V in pages."""
+        return self.n_layers
+
+    state_bytes_per_slot = 0    # a sequence's only cached state is pages
+
+    def paged_model(self):
+        """The paged engine's view of this family (models/protocol.py):
+        the paged floating-point path, unchanged in arithmetic."""
+        from .protocol import PagedModel
+
+        return PagedModel(
+            family="llama_like", program_tag="llama",
+            kv_layers=self.n_layers, state_shapes=lambda slots: (),
+            prefill=lambda params, tokens, lengths, mesh=None:
+            llama_prefill_paged(params, self, tokens, lengths, mesh),
+            decode=lambda params, tokens, positions, k_pool, v_pool, table,
+            state, mesh=None: (*llama_decode_step_paged(
+                params, self, tokens, positions, k_pool, v_pool, table,
+                mesh), state, None))
+
     @classmethod
     def debug(cls) -> "LlamaConfig":
         """CI-sized model: compiles in seconds on CPU."""
@@ -505,6 +527,22 @@ def llama_prefill_last(params, cfg: LlamaConfig, tokens, positions, lengths,
     last = hidden[jnp.arange(B), lengths - 1]  # [B, D]
     logits = _head(last, params)
     return logits, k_cache, v_cache
+
+
+def llama_prefill_paged(params, cfg: LlamaConfig, tokens, lengths, mesh=None):
+    """The protocol's prefill (models/protocol.py): a [K, bucket] window
+    from an empty cache into window-sized temporaries the engine's page
+    writer scatters. Returns (last logits, k, v [L, K, Hkv, dh, bucket],
+    ()): no state beside the pages."""
+    K, bucket = tokens.shape
+    tmp_k = jnp.zeros((cfg.n_layers, K, cfg.n_kv_heads, cfg.head_dim, bucket),
+                      dtype=_np_dtype(cfg.dtype))
+    pos_grid = jnp.broadcast_to(
+        jnp.arange(bucket, dtype=jnp.int32)[None, :], (K, bucket))
+    last, tmp_k, tmp_v = llama_prefill_last(
+        params, cfg, tokens, pos_grid, lengths, tmp_k, jnp.zeros_like(tmp_k),
+        mesh)
+    return last, tmp_k, tmp_v, ()
 
 
 def llama_prefill(params, cfg: LlamaConfig, tokens, k_cache, v_cache):

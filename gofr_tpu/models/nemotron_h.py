@@ -1,0 +1,615 @@
+"""nemotron_h family (NVIDIA Nemotron-H / Nemotron 3 Nano): a stack of blocks
+`x <- x + mixer(RMSNorm(x))`, one mixer a block, its kind given by a pattern
+string: `M` Mamba-2, `E` sparse experts, `*` grouped-query attention (no
+rotary embedding: the state-space blocks carry position). After the last
+block a final RMSNorm and an untied head.
+
+On the serving path a sequence holds TWO kinds of cached state: pages of K
+and V for the attention blocks (the paged engine's pool, whose leading axis
+counts attention blocks, not blocks) and, for every Mamba-2 block, a
+recurrent state [N, heads * P] float32 and the convolution's tail (the last
+W - 1 columns of xBC): fixed in size, a SLOT's worth, beside the pool
+(ops/ssm_update.py says why the state is held transposed). The model
+protocol (models/protocol.py) hands both to the engine:
+
+- `prefill`: a [K, bucket] window, right-padded, from an empty state. The
+  Mamba-2 blocks run the chunked (SSD) form in jax.numpy; a padded position
+  gets dt = 0, so it decays nothing and adds nothing and the final state is
+  the state as of each row's LAST REAL token; the tail is taken at
+  lengths - (W - 1) ... lengths - 1. Returns the last real position's
+  logits, K and V of the attention blocks, and each row's states.
+- `decode_step`: one token a row over the pool and the per-slot states,
+  the state updated in place by ops/ssm_update.py, live rows only.
+
+An expert block routes over all `n_experts` and is told which it holds
+(`experts_held`): the tree carries only those ([held, D, F]), and what the
+others would add is left out, here and in the reference alike (the other
+chip of the pair adds its share; on one chip there is no exchange and
+nothing stands in for it). Rows that hold no request are kept out of the
+routing and of the counters.
+
+Weights: {"tok_emb" [V, D], "layers": [one dict a block], "final_norm" [D],
+"lm_head" [D, V]}; matrices [in, out] but the experts' up matrices "w1"
+[held, F, D], kept [out, in] as the checkpoint stores them (ops/
+moe_experts.py says why); per-block leaves, never stacked: the blocks differ in
+kind, the layer loop is unrolled, and a static slice of a stack feeding a
+matmul may be copied (1.3 GB for a block's experts).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Any, Dict, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from .llama import _np_dtype, rms_norm
+
+KINDS = {"M": "mamba", "E": "experts", "*": "attention"}
+
+# leaves held in float32 whatever `dtype` is, as the published checkpoint
+# keeps them: the state-space constants and the router's bias
+FLOAT32_LEAVES = ("A_log", "D", "dt_bias", "router_bias")
+
+# what a decode step counts, a row of int32 a step (summed over the expert
+# blocks): live rows, picks that fell on held experts, held experts touched,
+# the busiest held expert's tokens
+COUNTERS = ("rows", "held_picks", "experts_touched", "busiest_expert_tokens")
+
+
+@dataclasses.dataclass(frozen=True)
+class NemotronHConfig:
+    vocab_size: int = 131072
+    dim: int = 2688
+    pattern: str = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
+    n_heads: int = 32
+    n_kv_heads: int = 2
+    head_dim: int = 128
+    mamba_heads: int = 64
+    mamba_head_dim: int = 64
+    n_groups: int = 8
+    state_size: int = 128
+    conv_kernel: int = 4
+    chunk_size: int = 128
+    n_experts: int = 128                    # the router's outputs
+    experts_held: Tuple[int, int] = (0, 128)    # the range this chip holds
+    experts_per_token: int = 6
+    expert_dim: int = 1856
+    shared_dim: int = 3712
+    routed_scale: float = 2.5
+    max_seq_len: int = 262144
+    rms_eps: float = 1e-5
+    dtype: str = "bfloat16"
+    attn_impl: str = "xla"      # "xla" | "flash": the prefill window
+
+    def __post_init__(self):
+        lo, hi = self.experts_held
+        if not 0 <= lo < hi <= self.n_experts:
+            raise ValueError(f"experts_held {self.experts_held} is not a "
+                             f"range of the {self.n_experts} experts")
+        if set(self.pattern) - set(KINDS):
+            raise ValueError(f"pattern {self.pattern!r}: use M, E and *")
+
+    @property
+    def n_layers(self) -> int:
+        return len(self.pattern)
+
+    @property
+    def kv_layers(self) -> int:
+        return self.pattern.count("*")
+
+    @property
+    def mamba_layers(self) -> int:
+        return self.pattern.count("M")
+
+    @property
+    def expert_layers(self) -> int:
+        return self.pattern.count("E")
+
+    @property
+    def held(self) -> int:
+        return self.experts_held[1] - self.experts_held[0]
+
+    @property
+    def d_inner(self) -> int:
+        return self.mamba_heads * self.mamba_head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        return self.d_inner + 2 * self.n_groups * self.state_size
+
+    @property
+    def in_proj_dim(self) -> int:
+        return self.d_inner + self.conv_dim + self.mamba_heads
+
+    @property
+    def q_per_kv(self) -> int:
+        return self.n_heads // self.n_kv_heads
+
+    @property
+    def ffn_dim(self) -> int:
+        """The widest activation a block makes (the capacity plan's
+        prefill temporaries)."""
+        return max(self.in_proj_dim, self.shared_dim)
+
+    @property
+    def state_bytes_per_slot(self) -> int:
+        """What a sequence holds beside its pages (tpu/capacity.py)."""
+        tail = self.conv_dim * (self.conv_kernel - 1) * (
+            2 if self.dtype != "float32" else 4)
+        return self.mamba_layers * (
+            self.d_inner * self.state_size * 4 + tail)
+
+    @classmethod
+    def debug(cls) -> "NemotronHConfig":
+        """CI-sized: compiles in seconds on the CPU. Held: all 8 experts."""
+        return cls(vocab_size=512, dim=64, pattern="ME*E", n_heads=4,
+                   n_kv_heads=2, head_dim=16, mamba_heads=4,
+                   mamba_head_dim=16, n_groups=2, state_size=16,
+                   chunk_size=16, n_experts=8, experts_held=(0, 8),
+                   experts_per_token=2, expert_dim=32, shared_dim=64,
+                   max_seq_len=256, dtype="float32")
+
+    @classmethod
+    def nano_30b_a3b_ep2(cls) -> "NemotronHConfig":
+        """NVIDIA-Nemotron-3-Nano-30B-A3B at its published widths, cut to
+        one v5e chip as benchmark/configs/nemotron-3-nano-30b-a3b-ep2.json
+        states: two chips share each layer, this one holds experts 0-63 of
+        128 and half the vocabulary; the first 16 of 52 blocks."""
+        return cls(vocab_size=65536, pattern="MEMEM*EMEMEM*EME",
+                   experts_held=(0, 64), max_seq_len=2048)
+
+    def matrix_params(self) -> Dict[str, int]:
+        """Matrix parameters of one block of each kind, and of what a token
+        meets in an expert block (router, shared expert, its k picks)."""
+        D = self.dim
+        per_expert = 2 * D * self.expert_dim
+        outside = D * self.n_experts + 2 * D * self.shared_dim
+        return {
+            "mamba": D * self.in_proj_dim + self.d_inner * D
+            + self.conv_kernel * self.conv_dim,
+            "attention": 2 * D * (self.n_heads + self.n_kv_heads)
+            * self.head_dim,
+            "experts_held": outside + self.held * per_expert,
+            "experts_met": outside + self.experts_per_token * per_expert
+            * self.held // self.n_experts,
+        }
+
+    def param_count(self) -> int:
+        """The parameters a TOKEN meets (the utilization ledger's 2 P flops
+        a token): the mixers, the router, the shared expert and the share of
+        its k picks that falls on held experts; not the held experts it
+        does not pick."""
+        m = self.matrix_params()
+        return (self.mamba_layers * m["mamba"]
+                + self.kv_layers * m["attention"]
+                + self.expert_layers * m["experts_met"]
+                + self.dim * self.vocab_size)
+
+    def paged_model(self):
+        from .protocol import PagedModel
+
+        return PagedModel(
+            family="nemotron_h", program_tag="nemotron-h",
+            kv_layers=self.kv_layers,
+            state_shapes=lambda slots: state_shapes(self, slots),
+            prefill=lambda params, tokens, lengths, mesh=None: prefill(
+                params, self, tokens, lengths),
+            decode=lambda params, tokens, positions, k_pool, v_pool, table,
+            state, mesh=None: decode_step(
+                params, self, tokens, positions, k_pool, v_pool, table,
+                state),
+            counters=COUNTERS,
+            describe=lambda counts, steps: describe(self, counts, steps),
+            refuses=REFUSES)
+
+
+# what the family cannot do yet, refused by name at construction
+_SNAPSHOT = ("a Mamba-2 state cannot be rebuilt from pages: it needs a "
+             "snapshot of the recurrent state at the page boundary")
+REFUSES = {
+    "prefix_cache": _SNAPSHOT,
+    "kv_host_tier": _SNAPSHOT,
+    "disagg": "a hand-off ships page blobs; the recurrent state and the "
+              "convolution tail have no blob yet",
+    "speculative_tokens": "a rejected draft would have to roll the "
+                          "recurrent state back: no snapshot yet",
+    "chunk_prefill_tokens": "the state a chunk ends in is not carried into "
+                            "the next job's prefill",
+    "int8_weights": "no int8 weight path for this family",
+    "mesh": "the expert and vocabulary shares have specs "
+            "(parallel/sharding.py) but no exchange yet",
+}
+
+
+def describe(cfg: NemotronHConfig, counts: Dict[str, int], steps: int):
+    """`/debug/engine` "model": what a slot holds, the experts held, and
+    how the routing of `steps` decode steps fell (COUNTERS' sums), an
+    expert block and step."""
+    out = {"state_bytes_per_slot": cfg.state_bytes_per_slot,
+           "experts_held": cfg.held, "experts_total": cfg.n_experts}
+    layer_steps = steps * cfg.expert_layers
+    if not layer_steps or not counts["rows"]:
+        return out
+    mean = counts["held_picks"] / (layer_steps * cfg.held)
+    out["routing"] = {
+        "rows_per_step": counts["rows"] / steps,
+        "tokens_per_held_expert_mean": mean,
+        "tokens_per_held_expert_max_over_mean": (
+            counts["busiest_expert_tokens"] / layer_steps / mean
+            if mean else 0.0),
+        "experts_touched_per_layer_step":
+            counts["experts_touched"] / layer_steps,
+        "held_pick_share": counts["held_picks"] / (
+            counts["rows"] * cfg.experts_per_token * cfg.expert_layers)}
+    return out
+
+
+def layer_shapes(cfg: NemotronHConfig, kind: str) -> Dict[str, tuple]:
+    D = cfg.dim
+    if kind == "mamba":
+        return {"norm": (D,), "in_proj": (D, cfg.in_proj_dim),
+                "conv_w": (cfg.conv_kernel, cfg.conv_dim),
+                "conv_b": (cfg.conv_dim,), "dt_bias": (cfg.mamba_heads,),
+                "A_log": (cfg.mamba_heads,), "D": (cfg.mamba_heads,),
+                "gate_norm": (cfg.d_inner,), "out_proj": (cfg.d_inner, D)}
+    if kind == "experts":
+        return {"norm": (D,), "router": (D, cfg.n_experts),
+                "router_bias": (cfg.n_experts,),
+                "w1": (cfg.held, cfg.expert_dim, D),
+                "w2": (cfg.held, cfg.expert_dim, D),
+                "shared_w1": (D, cfg.shared_dim),
+                "shared_w2": (cfg.shared_dim, D)}
+    q, kv = cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim
+    return {"norm": (D,), "wq": (D, q), "wk": (D, kv), "wv": (D, kv),
+            "wo": (q, D)}
+
+
+def nemotron_h_init(cfg: NemotronHConfig, seed: int = 0) -> Dict[str, Any]:
+    """Random-init params, a jitted call a block (a block's experts are
+    1.3 GB at the published widths). The state-space constants as Mamba-2
+    initialises them: A uniform in [1, 16], dt log-uniform in [1e-3, 1e-1]
+    through the inverse softplus, D ones."""
+    dt = _np_dtype(cfg.dtype)
+
+    def matrix(key, shape, fan_in):
+        return (jax.random.normal(key, shape, jnp.float32)
+                / math.sqrt(fan_in)).astype(dt)
+
+    def make(key, kind):
+        shapes = layer_shapes(cfg, kind)
+        keys = iter(jax.random.split(key, 8))
+        out = {}
+        for name, shape in shapes.items():
+            if name in ("norm", "gate_norm"):
+                out[name] = jnp.ones(shape, dt)
+            elif name == "conv_b":
+                out[name] = jnp.zeros(shape, dt)
+            elif name == "D":
+                out[name] = jnp.ones(shape, jnp.float32)
+            elif name == "A_log":
+                out[name] = jnp.log(jax.random.uniform(
+                    next(keys), shape, jnp.float32, 1.0, 16.0))
+            elif name == "dt_bias":
+                step = jnp.exp(jax.random.uniform(
+                    next(keys), shape, jnp.float32, math.log(1e-3),
+                    math.log(1e-1)))
+                out[name] = step + jnp.log(-jnp.expm1(-step))
+            elif name == "router_bias":
+                out[name] = jnp.zeros(shape, jnp.float32)
+            else:
+                out[name] = matrix(next(keys), shape,
+                                   shape[-1] if name == "w1" else shape[-2])
+        return out
+
+    make = jax.jit(make, static_argnums=1)
+    key = jax.random.PRNGKey(seed)
+    return {
+        "tok_emb": jax.jit(matrix, static_argnums=(1, 2))(
+            jax.random.fold_in(key, 1), (cfg.vocab_size, cfg.dim), cfg.dim),
+        "layers": [make(jax.random.fold_in(key, 16 + i), KINDS[mark])
+                   for i, mark in enumerate(cfg.pattern)],
+        "final_norm": jnp.ones((cfg.dim,), dt),
+        "lm_head": jax.jit(matrix, static_argnums=(1, 2))(
+            jax.random.fold_in(key, 2), (cfg.dim, cfg.vocab_size), cfg.dim),
+    }
+
+
+def state_shapes(cfg: NemotronHConfig, slots: int):
+    """((shape, dtype), ...) of the per-slot arrays, the slot axis second:
+    the recurrent state (float32 whatever the weights are held in: the
+    vendor's serving note for the family asks for it) and the convolution
+    tail."""
+    return (((cfg.mamba_layers, slots, cfg.state_size, cfg.d_inner),
+             jnp.float32),
+            ((cfg.mamba_layers, slots, cfg.conv_kernel - 1, cfg.conv_dim),
+             _np_dtype(cfg.dtype)))
+
+
+# -- mixers -------------------------------------------------------------------
+def _gated_norm(y, z, weight, cfg: NemotronHConfig):
+    """RMSNorm over each group of d_inner / G of y silu(z), float32."""
+    y = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    shape = y.shape
+    grouped = y.reshape(*shape[:-1], cfg.n_groups, cfg.d_inner // cfg.n_groups)
+    grouped = grouped * jax.lax.rsqrt(
+        jnp.mean(grouped * grouped, axis=-1, keepdims=True) + cfg.rms_eps)
+    return grouped.reshape(shape) * weight.astype(jnp.float32)
+
+
+def _in_proj(u, w):
+    """[z | xBC | dt] in float32: dt and B feed an exponential and a
+    recurrence, where bfloat16's 8 bits would compound."""
+    return jnp.dot(u, w["in_proj"], preferred_element_type=jnp.float32)
+
+
+def _split_proj(proj, cfg: NemotronHConfig):
+    return jnp.split(proj, [cfg.d_inner, cfg.d_inner + cfg.conv_dim], axis=-1)
+
+
+def _split_xbc(xBC, cfg: NemotronHConfig):
+    gn = cfg.n_groups * cfg.state_size
+    x, B, C = jnp.split(xBC, [cfg.d_inner, cfg.d_inner + gn], axis=-1)
+    groups = (*xBC.shape[:-1], cfg.n_groups, cfg.state_size)
+    return x, B.reshape(groups), C.reshape(groups)
+
+
+def mamba_prefill(u, w, lengths, cfg: NemotronHConfig):
+    """u [K, T, D] (normed), right-padded to T; lengths [K]. The chunked
+    (SSD) form from an empty state. Returns (out [K, T, D], state
+    [K, N, heads * P] float32 as of each row's last real token, tail
+    [K, W - 1, conv_dim])."""
+    K, T, _ = u.shape
+    H, P, G, N, W = (cfg.mamba_heads, cfg.mamba_head_dim, cfg.n_groups,
+                     cfg.state_size, cfg.conv_kernel)
+    Q = min(cfg.chunk_size, T)
+    if T % Q:
+        raise ValueError(f"window {T} is not a multiple of the chunk {Q}")
+    z, xBC, dt = _split_proj(_in_proj(u, w), cfg)
+    real = jnp.arange(T)[None, :] < lengths[:, None]              # [K, T]
+    # the tail decode continues from: xBC (before the convolution) at
+    # lengths - (W - 1) ... lengths - 1, zeros before the sequence's start
+    at = lengths[:, None] - (W - 1) + jnp.arange(W - 1)[None, :]  # [K, W-1]
+    tail = jnp.where((at >= 0)[:, :, None], jnp.take_along_axis(
+        xBC, jnp.maximum(at, 0)[:, :, None], axis=1), 0).astype(u.dtype)
+    padded = jnp.pad(xBC, ((0, 0), (W - 1, 0), (0, 0)))
+    conv = sum(w["conv_w"][j] * padded[:, j:j + T] for j in range(W))
+    x, B, C = _split_xbc(jax.nn.silu(conv + w["conv_b"]), cfg)
+    x = x.reshape(K, T, H, P).astype(jnp.float32)
+    B, C = B.astype(jnp.float32), C.astype(jnp.float32)
+    # padding neither decays nor adds: dt = 0 there
+    dt = jnp.where(real[:, :, None], jax.nn.softplus(
+        dt.astype(jnp.float32) + w["dt_bias"]), 0.0)              # [K, T, H]
+    a = dt * -jnp.exp(w["A_log"])                                 # <= 0
+    nc = T // Q
+    x, dt, a = (v.reshape(K, nc, Q, *v.shape[2:]) for v in (x, dt, a))
+    B, C = (v.reshape(K, nc, Q, G, N) for v in (B, C))
+    cs = jnp.cumsum(a, axis=2)                                    # [K,c,Q,H]
+    xdt = x * dt[..., None]                                       # [K,c,Q,H,P]
+    per = H // G
+    # within a chunk: y_t = sum_{s<=t} exp(cs_t - cs_s) (C_t . B_s) dt_s x_s
+    cb = jnp.einsum("kctgn,kcsgn->kcgts", C, B)                   # [K,c,G,Q,Q]
+    decay = jnp.exp(jnp.where(
+        jnp.tril(jnp.ones((Q, Q), bool))[None, None, :, :, None],
+        cs[:, :, :, None, :] - cs[:, :, None, :, :], -jnp.inf))   # [K,c,t,s,H]
+    scores = jnp.repeat(cb, per, axis=2).transpose(0, 1, 3, 4, 2) * decay
+    y = jnp.einsum("kctsh,kcshp->kcthp", scores, xdt)
+    # what a chunk adds to the state, and the state carried between chunks
+    to_end = jnp.exp(cs[:, :, -1:, :] - cs)                       # [K,c,Q,H]
+    Bh = jnp.repeat(B, per, axis=3)                               # [K,c,Q,H,N]
+    added = jnp.einsum("kcsh,kcshp,kcshn->kchpn", to_end, xdt, Bh)
+    total = jnp.exp(cs[:, :, -1, :])                              # [K,c,H]
+
+    def carry(h, inputs):
+        added_c, total_c = inputs
+        return total_c[:, :, None, None] * h + added_c, h         # h BEFORE
+
+    last, before = jax.lax.scan(
+        carry, jnp.zeros((K, H, P, N), jnp.float32),
+        (jnp.moveaxis(added, 1, 0), jnp.moveaxis(total, 1, 0)))
+    before = jnp.moveaxis(before, 0, 1)                           # [K,c,H,P,N]
+    Ch = jnp.repeat(C, per, axis=3)
+    y = y + jnp.einsum("kcth,kcthn,kchpn->kcthp", jnp.exp(cs), Ch, before)
+    y = (y + w["D"][:, None] * x).reshape(K, T, H * P)
+    out = _gated_norm(y, z, w["gate_norm"], cfg).astype(u.dtype) \
+        @ w["out_proj"]
+    state = last.transpose(0, 3, 1, 2).reshape(K, N, H * P)
+    return out, state, tail
+
+
+def mamba_decode(u, w, state, tail, layer: int, live, cfg: NemotronHConfig):
+    """u [B, D] (normed); state [Lm, B, N, heads * P]; tail
+    [Lm, B, W - 1, conv_dim]; `layer` this block's index among the Mamba-2
+    blocks; live [B]. Returns (out [B, D], state, tail)."""
+    from ..ops.ssm_update import ssm_update
+
+    H, P, W = cfg.mamba_heads, cfg.mamba_head_dim, cfg.conv_kernel
+    z, xBC, dt = _split_proj(_in_proj(u, w), cfg)
+    window = jnp.concatenate([tail[layer].astype(jnp.float32),
+                              xBC[:, None]], axis=1)              # [B, W, c]
+    tail = tail.at[layer].set(window[:, 1:].astype(tail.dtype))
+    conv = jnp.sum(w["conv_w"][None] * window, axis=1) + w["conv_b"]
+    x, B, C = _split_xbc(jax.nn.silu(conv), cfg)
+    x = x.reshape(-1, H, P).astype(jnp.float32)
+    dt = jax.nn.softplus(dt.astype(jnp.float32) + w["dt_bias"])   # [B, H]
+    decay = jnp.exp(dt * -jnp.exp(w["A_log"]))
+    y, state = ssm_update(
+        state, layer, jnp.repeat(decay, P, axis=1),
+        (x * dt[:, :, None]).reshape(-1, H * P),
+        B.astype(jnp.float32), C.astype(jnp.float32), live)
+    y = y + (w["D"][None, :, None] * x).reshape(-1, H * P)
+    out = _gated_norm(y, z, w["gate_norm"], cfg).astype(u.dtype) \
+        @ w["out_proj"]
+    return out, state, tail
+
+
+def route(x, w, cfg: NemotronHConfig):
+    """(picks [..., k] int32 over ALL experts, weights [..., k] float32):
+    sigmoid scores in float32 at full matmul precision (as the published
+    code), the k largest of score + bias picked, weighted by their scores
+    normalised and scaled."""
+    s = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), w["router"].astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, picks = jax.lax.top_k(s + w["router_bias"], cfg.experts_per_token)
+    chosen = jnp.take_along_axis(s, picks, axis=-1)
+    chosen = cfg.routed_scale * chosen / (
+        jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
+    return picks.astype(jnp.int32), chosen
+
+
+def _shared_expert(x, w):
+    from ..ops.moe_experts import relu2
+
+    return relu2(x @ w["shared_w1"]) @ w["shared_w2"]
+
+
+def experts_prefill(x, w, real, cfg: NemotronHConfig):
+    """x [K, T, D] (normed); real [K, T] marks tokens that are not padding.
+    The held experts by a grouped product over the (token, pick) pairs
+    sorted by expert; the shared expert over every token."""
+    from ..ops.moe_experts import prefill_experts
+
+    K, T, D = x.shape
+    flat = x.reshape(K * T, D)
+    picks, weights = route(flat, w, cfg)
+    weights = jnp.where(real.reshape(K * T, 1), weights, 0.0)
+    routed = prefill_experts(flat, w["w1"], w["w2"], picks, weights,
+                             cfg.experts_held[0],
+                             tm=min(128, max(8, K * T)))
+    return (routed.astype(x.dtype) + _shared_expert(flat, w)).reshape(K, T, D)
+
+
+def experts_decode(x, w, live, cfg: NemotronHConfig):
+    """x [B, D] (normed); live [B]. Returns (out [B, D], counters [4]
+    int32 of COUNTERS less `rows`)."""
+    from ..ops.moe_experts import decode_experts
+
+    lo, hi = cfg.experts_held
+    picks, weights = route(x, w, cfg)
+    mine = (picks >= lo) & (picks < hi) & live[:, None]           # [B, k]
+    rows = jnp.arange(x.shape[0])[:, None]
+    combine = jnp.zeros((x.shape[0], cfg.held + 1), jnp.float32).at[
+        rows, jnp.where(mine, picks - lo, cfg.held)].set(
+            jnp.where(mine, weights, 0.0))[:, :cfg.held]
+    tokens = jnp.sum(combine != 0.0, axis=0)                      # an expert
+    counted = jnp.stack([jnp.sum(mine), jnp.sum(tokens > 0),
+                         jnp.max(tokens)]).astype(jnp.int32)
+    routed = decode_experts(x, w["w1"], w["w2"], combine)
+    return routed.astype(x.dtype) + _shared_expert(x, w), counted
+
+
+def _qkv(x, w, cfg: NemotronHConfig):
+    lead = x.shape[:-1]
+    q = (x @ w["wq"]).reshape(*lead, cfg.n_heads, cfg.head_dim)
+    k = (x @ w["wk"]).reshape(*lead, cfg.n_kv_heads, cfg.head_dim)
+    v = (x @ w["wv"]).reshape(*lead, cfg.n_kv_heads, cfg.head_dim)
+    return q, k, v
+
+
+def attention_prefill(x, w, cfg: NemotronHConfig):
+    """x [K, T, D] (normed): causal attention over the fresh window (the
+    padding is on the right, so no real token sees it). Returns (out, k, v
+    [K, Hkv, dh, T]: the layout the page writer takes)."""
+    K, T, _ = x.shape
+    H, Hkv, dh, G = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.q_per_kv
+    q, k, v = _qkv(x, w, cfg)
+    if cfg.attn_impl == "flash":
+        from ..ops.flash_attention import flash_attention
+
+        attn = flash_attention(q, k, v, True)                     # [K,T,H,dh]
+    else:
+        scores = jnp.einsum("kthgd,kshd->khgts", q.reshape(K, T, Hkv, G, dh),
+                            k, preferred_element_type=jnp.float32
+                            ) / math.sqrt(dh)
+        causal = jnp.tril(jnp.ones((T, T), bool))
+        probs = jax.nn.softmax(jnp.where(causal, scores, -1e30), axis=-1)
+        attn = jnp.einsum("khgts,kshd->kthgd", probs.astype(v.dtype), v,
+                          preferred_element_type=jnp.float32).astype(x.dtype)
+    out = attn.reshape(K, T, H * dh) @ w["wo"]
+    return out, k.transpose(0, 2, 3, 1), v.transpose(0, 2, 3, 1)
+
+
+def attention_decode(x, w, k_pool, v_pool, table, positions, lengths,
+                     layer: int, cfg: NemotronHConfig):
+    """x [B, D] (normed); `layer` this block's index among the attention
+    blocks = the pools' leading axis."""
+    from ..ops.paged_attention import paged_attention, paged_write_decode
+
+    q, k, v = _qkv(x, w, cfg)
+    k_pool, v_pool = paged_write_decode(k_pool, v_pool, k, v, table,
+                                        positions, layer=layer)
+    attn = paged_attention(q, k_pool, v_pool, table, lengths, layer=layer)
+    return attn.reshape(x.shape[0], -1) @ w["wo"], k_pool, v_pool
+
+
+def _head(x, params, cfg: NemotronHConfig):
+    x = rms_norm(x, params["final_norm"], cfg.rms_eps)
+    return (x @ params["lm_head"]).astype(jnp.float32)
+
+
+def prefill(params, cfg: NemotronHConfig, tokens, lengths):
+    """tokens [K, T] right-padded; lengths [K]. Returns (last logits
+    [K, V] float32, k, v [kv_layers, K, Hkv, dh, T], (state
+    [mamba_layers, K, N, heads * P], tail [mamba_layers, K, W - 1, c]))."""
+    K, T = tokens.shape
+    real = jnp.arange(T)[None, :] < lengths[:, None]
+    x = params["tok_emb"][tokens]
+    ks, vs, states, tails = [], [], [], []
+    for mark, w in zip(cfg.pattern, params["layers"]):
+        normed = rms_norm(x, w["norm"], cfg.rms_eps)
+        if mark == "M":
+            out, state, tail = mamba_prefill(normed, w, lengths, cfg)
+            states.append(state)
+            tails.append(tail)
+        elif mark == "E":
+            out = experts_prefill(normed, w, real, cfg)
+        else:
+            out, k, v = attention_prefill(normed, w, cfg)
+            ks.append(k)
+            vs.append(v)
+        x = x + out
+    last = x[jnp.arange(K), lengths - 1]
+
+    def stacked(parts, empty):
+        # a pattern may lack a kind: its stack is then empty, not missing
+        return jnp.stack(parts) if parts else jnp.zeros(empty, x.dtype)
+
+    kv = (0, K, cfg.n_kv_heads, cfg.head_dim, T)
+    (state_like, _), (tail_like, _) = state_shapes(cfg, K)
+    return (_head(last, params, cfg), stacked(ks, kv), stacked(vs, kv),
+            (stacked(states, state_like), stacked(tails, tail_like)))
+
+
+def decode_step(params, cfg: NemotronHConfig, tokens, positions, k_pool,
+                v_pool, table, state):
+    """One token a row. tokens, positions [B]; pools
+    [kv_layers, P, Hkv, dh, ps]; table [B, NP] (a row that starts at page 0
+    holds no request); state = (ssm, tail). Returns (logits [B, V]
+    float32, k_pool, v_pool, state, counters [len(COUNTERS)] int32)."""
+    from .llama import _attended_lengths
+
+    ssm, tail = state
+    live = table[:, 0] > 0
+    lengths = _attended_lengths(table, positions)
+    x = params["tok_emb"][tokens]
+    counted = jnp.zeros((3,), jnp.int32)
+    m = a = 0
+    for mark, w in zip(cfg.pattern, params["layers"]):
+        normed = rms_norm(x, w["norm"], cfg.rms_eps)
+        if mark == "M":
+            out, ssm, tail = mamba_decode(normed, w, ssm, tail, m, live, cfg)
+            m += 1
+        elif mark == "E":
+            out, seen = experts_decode(normed, w, live, cfg)
+            counted = counted + seen
+        else:
+            out, k_pool, v_pool = attention_decode(
+                normed, w, k_pool, v_pool, table, positions, lengths, a, cfg)
+            a += 1
+        x = x + out
+    counters = jnp.concatenate([jnp.sum(live, dtype=jnp.int32)[None],
+                                counted])
+    return _head(x, params, cfg), k_pool, v_pool, (ssm, tail), counters

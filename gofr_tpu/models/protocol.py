@@ -1,0 +1,66 @@
+"""The model protocol of the paged engine: what `tpu/paging.py` asks of a
+model family, only as wide as the paged floating-point path needs.
+
+A config object answers `paged_model()` with a `PagedModel`. The engine
+owns every device array (the K and V page pools, the per-slot state, the
+loop's token / position / temperature vectors), donates them to the step
+programs and takes them back; the model says what state there is and
+computes on it:
+
+- `kv_layers`: how many blocks keep K and V in pages: the pools' leading
+  axis. A page id spans these blocks, not all blocks.
+- `state_shapes(slots)`: ((shape, dtype), ...) of the arrays a sequence
+  holds BESIDE its pages, fixed in size, the slot axis second
+  ([layers, slots, ...]); () for a model whose only cached state is pages.
+- `prefill(params, tokens [K, bucket], lengths [K], mesh)` from an empty
+  state -> (last real position's logits [K, V] float32, k, v
+  [kv_layers, K, Hkv, dh, bucket] for the page writer, one
+  [layers, K, ...] array for each of `state_shapes`: the state as of each
+  row's last real token). The engine scatters pages and slot states.
+- `decode(params, tokens [B], positions [B], k_pool, v_pool, table, state,
+  mesh)` -> (logits [B, V] float32, k_pool, v_pool, state, counters): one
+  token a row, pools and state updated in place. `counters` is an int32
+  vector named by `counters` (None when the family counts nothing); the
+  engine sums it over a block's steps and carries it to the host on the
+  block's own token copy.
+- `refuses`: {engine feature: reason} the family cannot serve yet; the
+  engine refuses each BY NAME at construction (docs/model-families.md).
+  A config MAY carry `kv_dtype` (a lower-precision page pool) and
+  `decode_attn` (a second decode read); the engine takes a config without
+  them as pages in the model's dtype and the one paged read.
+- `describe(counts, steps)`: what `/debug/engine` shows of the family:
+  static facts and what it makes of its counters' sums ({name: sum} over
+  `steps` decode steps).
+
+`models/llama.py` (pages only, no state, no counters, refuses nothing) and
+`models/nemotron_h.py` (pages for 6 blocks in 52, a recurrent state and a
+convolution tail a slot, expert counters) are the two families.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any, Callable, Dict, Tuple
+
+
+@dataclasses.dataclass(frozen=True)
+class PagedModel:
+    family: str
+    program_tag: str            # leads the step programs' names
+    kv_layers: int
+    state_shapes: Callable[[int], Tuple]
+    prefill: Callable
+    decode: Callable
+    counters: Tuple[str, ...] = ()
+    refuses: Dict[str, str] = dataclasses.field(default_factory=dict)
+    describe: Callable[[Dict[str, int], int], Dict[str, Any]] = (
+        lambda counts, steps: {})
+
+    def refuse(self, asked: Dict[str, Any]) -> None:
+        """Raise for the first feature in `asked` ({feature: the value the
+        caller gave}) that is switched on and that the family refuses."""
+        for feature, value in asked.items():
+            if value and feature in self.refuses:
+                raise ValueError(
+                    f"the {self.family} family refuses {feature}="
+                    f"{value!r}: {self.refuses[feature]}")

@@ -4,7 +4,8 @@ Megatron-style tensor parallelism for the Llama block: attention heads and
 FFN hidden dim shard over "tp" (column-parallel wq/wk/wv/gate/up, row-parallel
 wo/down — XLA inserts the reduce-scatter/all-gather pairs), vocab shards the
 embedding/lm_head over "tp", the stacked layer axis shards over "pp", MoE
-expert axis over "ep". Batches shard [B, T] as ("dp", "sp") — sequence
+expert axis over "ep" (training: `llama_param_specs(moe=True)`; serving,
+with the vocabulary share: `expert_share_specs`). Batches shard [B, T] as ("dp", "sp") — sequence
 parallelism for long context; the attention implementation decides whether
 the sp collectives are all-gather (XLA auto) or a ring (ops/ring_attention).
 """
@@ -96,6 +97,36 @@ def serving_param_specs(quantized: bool = False) -> Dict[str, Any]:
         out["tok_emb_s"] = _P(None)      # per-row scales ride the gather
         out["lm_head_s"] = _P("tp")      # column scales follow the vocab split
     return out
+
+
+def expert_share_specs(pattern: str) -> Dict[str, Any]:
+    """PartitionSpec pytree for models/nemotron_h.py's tree (one dict a
+    block of `pattern`, per-block leaves): the routed experts' axis and the
+    vocabulary split over "ep", everything else on every chip alike.
+
+    This is the deployment a cut configuration states (two chips share each
+    layer: each holds half the experts and half the vocabulary; tokens,
+    mixers, router and shared expert on both alike): on an "ep" mesh each
+    shard's `experts_held` is its slice of w1 / w2, each computes its own
+    experts' part, and the parts are summed across "ep"; the head's logits
+    are gathered across it. The exchange is not written yet: the engine
+    refuses a mesh for the family, and on one chip the layer runs without.
+    """
+    rep = _P()
+
+    def block(mark: str) -> Dict[str, Any]:
+        if mark == "M":
+            return dict.fromkeys(("norm", "in_proj", "conv_w", "conv_b",
+                                  "dt_bias", "A_log", "D", "gate_norm",
+                                  "out_proj"), rep)
+        if mark == "E":
+            return {"norm": rep, "router": rep, "router_bias": rep,
+                    "w1": _P("ep", None, None), "w2": _P("ep", None, None),
+                    "shared_w1": rep, "shared_w2": rep}
+        return dict.fromkeys(("norm", "wq", "wk", "wv", "wo"), rep)
+
+    return {"tok_emb": _P("ep", None), "layers": [block(m) for m in pattern],
+            "final_norm": rep, "lm_head": _P(None, "ep")}
 
 
 def kv_cache_spec():

@@ -63,9 +63,11 @@ class CapacityPlan:
 
 def kv_token_bytes(cfg, dtype: Optional[str] = None) -> int:
     """HBM bytes one cached token occupies across BOTH (k, v) caches:
-    2 * n_layers * n_kv_heads * head_dim * itemsize. The per-token unit the
-    capacity plan and the utilization ledger's bandwidth model share."""
-    return (2 * cfg.n_layers * cfg.n_kv_heads * cfg.head_dim
+    2 * kv_layers * n_kv_heads * head_dim * itemsize, over the blocks that
+    keep K and V (all of models/llama.py's, 6 in 52 of nemotron_h's). The
+    per-token unit the capacity plan and the utilization ledger's bandwidth
+    model share."""
+    return (2 * cfg.kv_layers * cfg.n_kv_heads * cfg.head_dim
             * _dtype_bytes(dtype or getattr(cfg, "kv_dtype", None)
                            or cfg.dtype))
 
@@ -86,7 +88,7 @@ def params_bytes(cfg) -> int:
 
 def kv_scales_bytes(cfg, n_slots: int, seq_len: int) -> int:
     """The int8 cache's f32 dequant-scale buffers: 2 * [L, B, Hkv, S]."""
-    return 2 * cfg.n_layers * n_slots * cfg.n_kv_heads * seq_len * 4
+    return 2 * cfg.kv_layers * n_slots * cfg.n_kv_heads * seq_len * 4
 
 
 def prefill_temp_bytes(cfg, k_max: int, bucket_max: int) -> int:
@@ -99,7 +101,7 @@ def prefill_temp_bytes(cfg, k_max: int, bucket_max: int) -> int:
     prefill projects only [K, D] last-position rows (llama_prefill_last).
     """
     dt = _dtype_bytes(cfg.dtype)
-    tmp_kv = 2 * (cfg.n_layers * k_max * bucket_max * cfg.n_kv_heads
+    tmp_kv = 2 * (cfg.kv_layers * k_max * bucket_max * cfg.n_kv_heads
                   * cfg.head_dim * dt)
     acts = 4 * k_max * bucket_max * max(cfg.dim, cfg.ffn_dim) * dt
     return tmp_kv + acts
@@ -145,7 +147,10 @@ def plan_capacity(cfg, n_slots: int, max_seq_len: int,
 
     def peak(slots: int, seq: int) -> Tuple[int, int, int]:
         kv_dtype = getattr(cfg, "kv_dtype", None)
-        cache = kv_cache_bytes(cfg, slots, seq, dtype=kv_dtype)
+        # pages, and what each slot holds beside them (a recurrent state a
+        # slot is fixed in size: it scales with slots, not with seq)
+        cache = (kv_cache_bytes(cfg, slots, seq, dtype=kv_dtype)
+                 + slots * cfg.state_bytes_per_slot)
         if kv_dtype == "int8":
             cache += kv_scales_bytes(cfg, slots, seq)
         # dense decode ping-pongs the scanned cache carries (one extra

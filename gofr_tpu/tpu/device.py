@@ -165,6 +165,11 @@ class TPUClient:
              "KV page-pool occupancy (kind=used|free)"),
             ("app_tpu_breaker_state",
              "reset-storm breaker state (0=closed, 1=half_open, 2=open)"),
+            ("app_tpu_moe_routing",
+             "expert routing of the decode steps since the last reset, an "
+             "expert block and step (what=rows_per_step|tokens_per_held_expert_mean|"
+             "tokens_per_held_expert_max_over_mean|"
+             "experts_touched_per_layer_step|held_pick_share)"),
         ):
             try:
                 m.new_gauge(name, desc)

@@ -540,6 +540,11 @@ class LLMEngine:
                     f"n_heads={cfg.n_heads} (whole heads per shard)")
             params = shard_params(params, mesh,
                                   serving_param_specs(quantized=self._w8))
+        if not self._plan_paged and not isinstance(cfg, LlamaConfig):
+            raise ValueError(
+                f"the dense engine serves models/llama.py only; "
+                f"{type(cfg).__name__} is served by PagedLLMEngine through "
+                f"the model protocol (models/protocol.py)")
         self.params = params
         self.cfg = cfg
         self.n_slots = n_slots
@@ -583,7 +588,11 @@ class LLMEngine:
         # MID-SERVING on the first grow that hits the cap (ADVICE r3).
         # Round the cap down at boot instead: fail loud at config time,
         # never in the serving loop. (Paged engines never hit this read.)
-        if (cfg.decode_attn == "kernel" and not self._plan_paged
+        # a family with one decode read and no lower-precision cache has
+        # neither field (models/protocol.py)
+        decode_attn = getattr(cfg, "decode_attn", "xla")
+        kv_dtype = getattr(cfg, "kv_dtype", None)
+        if (decode_attn == "kernel" and not self._plan_paged
                 and self.max_seq_len > 512 and self.max_seq_len % 512):
             rounded = (self.max_seq_len // 512) * 512
             if logger is not None:
@@ -629,17 +638,17 @@ class LLMEngine:
         # int8 KV cache: halves cache HBM traffic (the decode bandwidth
         # bound) and doubles context per GiB. Quantize-on-write + kernel
         # dequant only — the XLA einsum read would materialize a bf16 copy
-        if cfg.kv_dtype not in (None, "int8", cfg.dtype):
+        if kv_dtype not in (None, "int8", cfg.dtype):
             # a float kv_dtype differing from cfg.dtype would make the
             # capacity plan (which reads kv_dtype) and the allocation
             # (which uses cfg.dtype) disagree — reject until supported
-            raise ValueError(f"kv_dtype={cfg.kv_dtype!r} not supported; "
+            raise ValueError(f"kv_dtype={kv_dtype!r} not supported; "
                              f"use None or 'int8'")
-        self._q8 = cfg.kv_dtype == "int8"
+        self._q8 = kv_dtype == "int8"
         if self._q8:
             # the paged engine's decode read is ALWAYS its paged kernel, so
             # the dense-path requirement doesn't apply there
-            if cfg.decode_attn != "kernel" and not self._plan_paged:
+            if decode_attn != "kernel" and not self._plan_paged:
                 raise ValueError("kv_dtype='int8' requires decode_attn="
                                  "'kernel' (no efficient XLA dequant read)")
 
@@ -1273,6 +1282,7 @@ class LLMEngine:
         exactly without touching the serving hot path."""
         from .score import score_tokens
 
+        self._llama_only("score")
         return score_tokens(self, prompt_tokens, completion_tokens, top=top)
 
     def embed(self, tokens: Sequence[int], normalize: bool = True):
@@ -1281,7 +1291,15 @@ class LLMEngine:
         Additive post-hoc pass like score(); see tpu/score.py."""
         from .score import embed_tokens
 
+        self._llama_only("embed")
         return embed_tokens(self, tokens, normalize=normalize)
+
+    def _llama_only(self, what: str) -> None:
+        """tpu/score.py's post-hoc passes are models/llama.py's no-cache
+        forward: another family is refused by name."""
+        if not isinstance(self.cfg, LlamaConfig):
+            raise ValueError(f"{what}() is models/llama.py's forward; "
+                             f"{type(self.cfg).__name__} has none yet")
 
     def warmup_scoring(self, embeddings: bool = True) -> int:
         """Pre-compile the logprobs/embeddings program families (one
@@ -2990,6 +3008,9 @@ class LLMEngine:
             raise CacheLostError(f"decode execution failed: {exc}") from exc
         if dspan is not None:
             dspan.end()
+        # rows past the slots are the family's counters, carried by the
+        # same copy (models/protocol.py); none for models/llama.py
+        self._note_model_counts(tokens_host, block)
         synced = time.monotonic()
         step_s = (synced - started) / block
         self._obs.hist("app_tpu_execute_seconds", synced - started)
@@ -3053,6 +3074,9 @@ class LLMEngine:
             exemplar=(self._exemplar_of(slowest) if slowest else None))
         self._obs.hist("app_tpu_batch_size", n_active)
         self._track_throughput(emitted)
+
+    def _note_model_counts(self, tokens_host, block: int) -> None:
+        """The dense engine's model counts nothing."""
 
     def _fail_request(self, request: GenerationRequest,
                       exc: Optional[BaseException] = None) -> None:

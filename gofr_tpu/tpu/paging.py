@@ -31,7 +31,7 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
-from ..models.llama import LlamaConfig, llama_decode_step_paged, llama_prefill_last
+from ..models.llama import LlamaConfig, llama_prefill_last
 from ..ops.paged_attention import paged_write_prefill_stacked
 from .engine import (CacheLostError, GenerationRequest, LLMEngine,
                      _admission_widths, _pin_standard_layout, program_lookup)
@@ -106,6 +106,17 @@ class PagedLLMEngine(LLMEngine):
         # the dense engine (same reasons apply)
         self.page_size = page_size
         self._requested_pages = n_pages
+        # what the model's family cannot serve yet is refused by name here,
+        # before anything is built (models/protocol.py `refuses`)
+        cfg.paged_model().refuse({
+            "prefix_cache": prefix_cache,
+            "kv_host_tier": kv_host_tier_bytes,
+            "disagg": kw.get("disagg_role"),
+            "speculative_tokens": kw.get("speculative_tokens"),
+            "chunk_prefill_tokens": kw.get("chunk_prefill_tokens"),
+            "kv_dtype": getattr(cfg, "kv_dtype", None),
+            "int8_weights": isinstance(params, dict) and "lm_head_s" in params,
+            "mesh": kw.get("mesh")})
         # prefix_cache=True shares whole prompt-prefix pages between
         # requests (refcounted, LRU-evicted back into the allocator) —
         # see tpu/prefixcache.py. int8 pools share scales alongside values
@@ -138,6 +149,15 @@ class PagedLLMEngine(LLMEngine):
         # set pre-super: _init_device_state runs inside super().__init__
         super().__init__(params, cfg, **kw)
 
+    # -- the model, through its protocol ---------------------------------------
+    @property
+    def model(self):
+        """models/protocol.py `PagedModel` of this engine's config: what
+        state the family holds beside the pools, its prefill and its decode
+        step. Asked of the config each time, so an engine made without
+        __init__ (the compile rehearsal) has it too."""
+        return self.cfg.paged_model()
+
     # -- device state ---------------------------------------------------------
     def _init_device_state(self) -> None:
         import jax
@@ -158,21 +178,22 @@ class PagedLLMEngine(LLMEngine):
                        if getattr(self, "_prefix_enabled", False) else None)
         self._prefix_hits: Dict[int, List[int]] = {}
         self._cache_len = self.max_seq_len  # admission_limit compatibility
-        L, Hkv, dh = self.cfg.n_layers, self.cfg.n_kv_heads, self.cfg.head_dim
+        # the pools' leading axis counts the blocks that keep K and V
+        L, Hkv, dh = self.model.kv_layers, self.cfg.n_kv_heads, self.cfg.head_dim
+        held_in = getattr(self.cfg, "kv_dtype", None) or self.cfg.dtype
         dt = {"bfloat16": jnp.bfloat16, "float32": jnp.float32,
-              "float16": jnp.float16, "int8": jnp.int8}[
-                  self.cfg.kv_dtype or self.cfg.dtype]
+              "float16": jnp.float16, "int8": jnp.int8}[held_in]
         # the capacity plan (budget_bytes, paged=True) clamped n_slots and
         # max_seq_len; the pool derived from them must itself fit — check
         # explicitly, since an explicit n_pages bypasses the plan's sizing
-        itemsize = {"bfloat16": 2, "float16": 2, "int8": 1}.get(
-            self.cfg.kv_dtype or self.cfg.dtype, 4)
+        itemsize = {"bfloat16": 2, "float16": 2, "int8": 1}.get(held_in, 4)
         pool_bytes = 2 * L * n_pages * Hkv * dh * ps * itemsize
         if self._q8:  # f32 dequant scale pools ride along
             pool_bytes += 2 * L * n_pages * Hkv * ps * 4
         if self.plan is not None:
             usable = int(self.plan.budget_bytes * 0.92)
             need = (self.plan.params_bytes + pool_bytes
+                    + self.n_slots * self.cfg.state_bytes_per_slot
                     + self.plan.prefill_temp_bytes)
             if need > usable:
                 raise ValueError(
@@ -186,6 +207,15 @@ class PagedLLMEngine(LLMEngine):
             self.k_scale = jnp.zeros((L, n_pages, Hkv, ps), dtype=jnp.float32)
             self.v_scale = jnp.zeros_like(self.k_scale)
         B = self.n_slots
+        # what a sequence holds beside its pages, a slot's worth each
+        # (none for a model whose only cached state is pages): donated to
+        # and returned by the step programs like the pools. A slot's state
+        # is written whole by its prefill at admission
+        self.state = tuple(jnp.zeros(shape, dtype=dtype)
+                           for shape, dtype in self.model.state_shapes(B))
+        # the family's decode counters, summed since the last reset
+        self.model_counts = np.zeros(len(self.model.counters), np.int64)
+        self.model_count_steps = 0
         self._tokens = jnp.zeros((B,), dtype=jnp.int32)
         self._positions = jnp.zeros((B,), dtype=jnp.int32)
         self._temps = self._temps_init(B)
@@ -216,6 +246,10 @@ class PagedLLMEngine(LLMEngine):
         self._positions = jax.device_put(self._positions, rep)
         self._temps = jax.device_put(self._temps, rep)
         self.rng = jax.device_put(self.rng, rep)
+
+    def state_bytes(self) -> int:
+        """The per-slot state beside the pools, all slots."""
+        return sum(a.size * a.dtype.itemsize for a in self.state)
 
     def pool_bytes(self) -> int:
         total = 2 * self.k_cache.size * self.k_cache.dtype.itemsize
@@ -435,7 +469,7 @@ class PagedLLMEngine(LLMEngine):
         exactly. Any mismatch (mid-flight oddity, no pages) degrades to
         the blob-less export — peer-side recompute, never a wrong blob."""
         n_ctx = slot.length
-        if (slot.pages is None or n_ctx <= 0
+        if (slot.pages is None or n_ctx <= 0 or self.state
                 or n_ctx != len(request.resume_tokens) - 1):
             return None, max(0, len(request.resume_tokens) - 1)
         from .kvtier import PageBlob
@@ -472,10 +506,15 @@ class PagedLLMEngine(LLMEngine):
         # the loop thread (allocator state is loop-owned), flushed off it
         used, free = self.allocator.used_pages, self.allocator.free_pages
 
+        routing = (self.model_snapshot().get("routing", {})
+                   if self.model_count_steps else {})
+
         def flush() -> None:
             self._obs.gauge("app_tpu_pages_used", used)
             self._obs.gauge("app_tpu_kv_pool_pages", used, kind="used")
             self._obs.gauge("app_tpu_kv_pool_pages", free, kind="free")
+            for what, value in routing.items():
+                self._obs.gauge("app_tpu_moe_routing", value, what=what)
 
         self._run_off_loop(flush)
 
@@ -751,36 +790,36 @@ class PagedLLMEngine(LLMEngine):
                     self._verify_program(width)
 
     def _prefill_fn(self, bucket: int, K: int):
-        cfg, mesh = self.cfg, self.mesh
-        jnp = self._jnp
+        model, mesh = self.model, self.mesh
         top_k = self.top_k
         from .sampling import sample_tokens
 
         def prefill(params, k_pool, v_pool, ptokens, ptable, slots, lengths,
-                    tokens, positions, temps, new_temps, rng):
-            """Fused K-way paged admission: forward the [K, bucket] window
-            (flash or dense attention over the fresh window), scatter the
-            per-layer K/V into the slots' pages, sample first tokens, and
-            splice loop state. ptable: [K, ceil(bucket/ps)] page ids."""
-            L, P, Hkv, dh, _ = k_pool.shape
+                    tokens, positions, temps, new_temps, rng, *state):
+            """Fused K-way paged admission: the model's prefill of the
+            [K, bucket] window, its K/V scattered into the slots' pages and
+            its rows' final states into the slots' state (`state`: the
+            family's per-slot arrays, none for a model that holds only
+            pages), first tokens sampled, loop state spliced.
+            ptable: [K, ceil(bucket/ps)] page ids."""
             k_pool, v_pool = _pin_standard_layout(k_pool, v_pool)
-            tmp_k = jnp.zeros((L, K, Hkv, dh, bucket), dtype=k_pool.dtype)
-            tmp_v = jnp.zeros_like(tmp_k)
-            pos_grid = jnp.broadcast_to(
-                jnp.arange(bucket, dtype=jnp.int32)[None, :], (K, bucket))
-            last, tmp_k, tmp_v = llama_prefill_last(
-                params, cfg, ptokens, pos_grid, lengths, tmp_k, tmp_v, mesh)
+            last, tmp_k, tmp_v, rows = model.prefill(params, ptokens, lengths,
+                                                     mesh)
             # scatter the window into pages: token t of row k goes to
             # (ptable[k, t // ps], t % ps); pad junk past lengths[k] is
             # redirected to the garbage page so live pages stay clean
             k_pool, v_pool = paged_write_prefill_stacked(
                 k_pool, v_pool, tmp_k, tmp_v, ptable, lengths)
+            # a slot's state is written whole, in place (donated)
+            state = tuple(held.at[:, slots].set(row.astype(held.dtype))
+                          for held, row in zip(state, rows))
             first, rng = sample_tokens(last, rng, new_temps, top_k=top_k)
             tokens = tokens.at[slots].set(first)
             positions = positions.at[slots].set(lengths)
             temps = temps.at[slots].set(new_temps)
             k_pool, v_pool = _pin_standard_layout(k_pool, v_pool)
-            return k_pool, v_pool, tokens, positions, temps, rng, first
+            return (k_pool, v_pool, tokens, positions, temps, rng, first,
+                    *state)
 
         return prefill
 
@@ -847,36 +886,47 @@ class PagedLLMEngine(LLMEngine):
                 jnp.zeros((K,), dtype=jnp.int32),
                 jnp.ones((K,), dtype=jnp.int32),
                 self._tokens, self._positions, self._temps,
-                self._temps_init(K), self.rng)
+                self._temps_init(K), self.rng, *self.state)
         return self.executor.compile(
-            f"llama-paged-prefill-{bucket}x{K}{self._id_tag}",
-            self._prefill_fn(bucket, K),
-            args, donate_argnums=(1, 2, 7, 8, 9))
+            f"{self.model.program_tag}-paged-prefill-{bucket}x{K}{self._id_tag}",
+            self._prefill_fn(bucket, K), args,
+            donate_argnums=(1, 2, 7, 8, 9) + tuple(
+                range(12, 12 + len(self.state))))
 
     def _decode_fn_paged(self, block: int, n_table: int):
-        cfg, mesh = self.cfg, self.mesh
+        model, mesh = self.model, self.mesh
         top_k = self.top_k
         import jax
 
         from .sampling import sample_tokens
 
         def decode(params, k_pool, v_pool, table, tokens, positions, temps,
-                   rng):
-            """`block` paged decode steps under scan; table [B, n_table]."""
+                   rng, *state):
+            """`block` paged decode steps under scan; table [B, n_table];
+            `state` the family's per-slot arrays (none for a model that
+            holds only pages), carried and returned like the pools. A
+            family that counts (models/protocol.py `counters`) gives a row
+            of int32 a step; their sum over the block rides below the
+            block's tokens, so one copy to the host carries both."""
 
             def step(carry, _):
-                kp, vp, tok, pos, rng = carry
-                logits, kp, vp = llama_decode_step_paged(
-                    params, cfg, tok, pos, kp, vp, table, mesh)
+                kp, vp, held, tok, pos, rng = carry
+                logits, kp, vp, held, counted = model.decode(
+                    params, tok, pos, kp, vp, table, held, mesh)
                 nxt, rng = sample_tokens(logits, rng, temps, top_k=top_k)
-                return (kp, vp, nxt, pos + 1, rng), nxt
+                return (kp, vp, held, nxt, pos + 1, rng), (nxt, counted)
 
             k_pool, v_pool = _pin_standard_layout(k_pool, v_pool)
-            (k_pool, v_pool, tok, pos, rng), out = jax.lax.scan(
-                step, (k_pool, v_pool, tokens, positions, rng), None,
-                length=block)
+            (k_pool, v_pool, state, tok, pos, rng), (out, counted) = \
+                jax.lax.scan(step, (k_pool, v_pool, tuple(state), tokens,
+                                    positions, rng), None, length=block)
             k_pool, v_pool = _pin_standard_layout(k_pool, v_pool)
-            return k_pool, v_pool, tok, pos, rng, out.T
+            out = out.T
+            if counted is not None:
+                below = jax.numpy.zeros((counted.shape[1], block), out.dtype)
+                out = jax.numpy.concatenate(
+                    [out, below.at[:, 0].set(counted.sum(axis=0))])
+            return (k_pool, v_pool, tok, pos, rng, out, *state)
 
         return decode
 
@@ -923,11 +973,13 @@ class PagedLLMEngine(LLMEngine):
                 donate_argnums=(1, 2, 3, 4))
         args = (self.params, self.k_cache, self.v_cache,
                 jnp.zeros((self.n_slots, n_table), dtype=jnp.int32),
-                self._tokens, self._positions, self._temps, self.rng)
+                self._tokens, self._positions, self._temps, self.rng,
+                *self.state)
         return self.executor.compile(
-            f"llama-paged-decode-x{block}-NP{n_table}{self._id_tag}",
+            f"{self.model.program_tag}-paged-decode-x{block}-NP{n_table}"
+            f"{self._id_tag}",
             self._decode_fn_paged(block, n_table), args,
-            donate_argnums=(1, 2))
+            donate_argnums=(1, 2) + tuple(range(8, 8 + len(self.state))))
 
     # -- chunked prefill over the pool ---------------------------------------
     # A long prompt's chunks run against bucket-sized per-JOB temp caches
@@ -1647,6 +1699,24 @@ class PagedLLMEngine(LLMEngine):
         self._temps = self._temps.at[sl].set(jnp.asarray(new_temps))
 
     # -- dispatch -------------------------------------------------------------
+    def _note_model_counts(self, tokens_host, block: int) -> None:
+        """Fold a synced decode block's counter rows (below the slots'
+        token rows, column 0: the sum over the block's steps) into the
+        engine's totals. No device access: the rows came with the tokens."""
+        if len(self.model_counts):
+            self.model_counts += tokens_host[self.n_slots:, 0]
+            self.model_count_steps += block
+
+    def model_snapshot(self) -> dict:
+        """`/debug/engine` "model": the family, what it holds beside the
+        pools, and what the family makes of its decode counters since the
+        last reset (models/protocol.py `describe`)."""
+        counts = dict(zip(self.model.counters, self.model_counts.tolist()))
+        return {"family": self.model.family,
+                "kv_layers": self.model.kv_layers,
+                "state_bytes": self.state_bytes(),
+                **self.model.describe(counts, self.model_count_steps)}
+
     def _build_table(self) -> np.ndarray:
         """Block table for the current active slots, padded to a power-of-
         two width with one extra garbage column (see _dispatch_decode)."""
@@ -1704,12 +1774,15 @@ class PagedLLMEngine(LLMEngine):
                         self._temps, jnp.asarray(new_temps), self.rng)
                 else:
                     (self.k_cache, self.v_cache, self._tokens,
-                     self._positions, self._temps, self.rng, first) = program(
+                     self._positions, self._temps, self.rng, first,
+                     *state) = program(
                         self.params, self.k_cache, self.v_cache,
                         jnp.asarray(ptokens), jnp.asarray(ptable),
                         jnp.asarray(np.asarray(slots_idx, dtype=np.int32)),
                         jnp.asarray(lengths), self._tokens, self._positions,
-                        self._temps, jnp.asarray(new_temps), self.rng)
+                        self._temps, jnp.asarray(new_temps), self.rng,
+                        *self.state)
+                    self.state = tuple(state)
         except Exception as exc:
             raise CacheLostError(f"paged prefill dispatch failed: {exc}") from exc
 
@@ -1751,10 +1824,12 @@ class PagedLLMEngine(LLMEngine):
                                 self._positions, self._temps, self.rng)
                 else:
                     (self.k_cache, self.v_cache, self._tokens,
-                     self._positions, self.rng, out_tokens) = program(
+                     self._positions, self.rng, out_tokens,
+                     *state) = program(
                         self.params, self.k_cache, self.v_cache,
                         jnp.asarray(table), self._tokens, self._positions,
-                        self._temps, self.rng)
+                        self._temps, self.rng, *self.state)
+                    self.state = tuple(state)
         except Exception as exc:
             raise CacheLostError(f"paged decode dispatch failed: {exc}") from exc
         self._start_d2h(out_tokens)

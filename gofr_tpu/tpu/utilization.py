@@ -111,7 +111,10 @@ def _lookup_peaks(device_kind: Optional[str]) -> Optional[Tuple[float, float]]:
 # -- analytic roofline model (pure functions, hand-checkable) -----------------
 def prefill_flops(cfg, tokens: int) -> float:
     """Forward-pass FLOPs for `tokens` prompt tokens: 2·P·T (the PaLM MFU
-    convention — matmul MACs only, attention quadratic term excluded)."""
+    convention — matmul MACs only, attention quadratic term excluded). P is
+    `cfg.param_count()`: the parameters a token MEETS, which for a family
+    with sparse experts is its mixers, router, shared expert and held
+    picks, not every expert held (models/nemotron_h.py)."""
     return 2.0 * cfg.param_count() * tokens
 
 
@@ -134,12 +137,14 @@ def decode_bytes(cfg, rows: int, steps: int, kv_tokens: int,
                  params_nbytes: Optional[int] = None) -> float:
     """HBM traffic of one decode dispatch: per step, the whole weight tree
     is read once (shared across the batch — THE reason batching wins) plus
-    the live KV context (`kv_tokens` tokens across all rows) and one KV
-    write per row."""
+    the live KV context (`kv_tokens` tokens across all rows), one KV
+    write per row and, for a family that holds a state a slot beside its
+    pages, that state read and written a row."""
     weights = params_nbytes if params_nbytes else params_bytes(cfg)
     per_step = (float(weights)
                 + float(kv_tokens) * kv_token_bytes(cfg)
-                + float(rows) * kv_token_bytes(cfg))
+                + float(rows) * kv_token_bytes(cfg)
+                + 2.0 * rows * cfg.state_bytes_per_slot)
     return float(steps) * per_step
 
 
@@ -349,6 +354,11 @@ def register_utilization_metrics(metrics) -> None:
          "host KV tier occupancy in bytes (kind=used|capacity)"),
         ("app_tpu_kv_tier_pages",
          "page blobs resident in the host KV tier"),
+        ("app_tpu_moe_routing",
+         "expert routing of the decode steps since the last reset, an "
+         "expert block and step (what=rows_per_step|tokens_per_held_expert_mean|"
+         "tokens_per_held_expert_max_over_mean|"
+         "experts_touched_per_layer_step|held_pick_share)"),
     ):
         try:
             if metrics.get(name) is None:
@@ -531,6 +541,10 @@ def engine_snapshot(engine, tpu=None) -> Dict[str, Any]:
                 out["page_pool"]["kv_tier"] = tier
             except Exception:  # noqa: BLE001
                 pass
+
+    if hasattr(engine, "model_snapshot"):
+        # the model family: what it holds beside the pools, its routing
+        out["model"] = engine.model_snapshot()
 
     breaker = getattr(engine, "breaker", None)
     if breaker is not None:

@@ -404,11 +404,11 @@ def _while_bodies(compiled) -> set:
 def test_step_programs_name_their_module_and_kernels(topo, as_tpu, program):
     """What a profiler trace shows of a step program (ISSUE 24): the XLA
     module carries the table width and block, and each Pallas kernel's
-    instruction is named after its scope. The name keeps the
-    `closed_call` prefix that the benchmark's kernel readers select on
-    for now (ops/scopes.py says until when), the page write is still the
-    custom-call that returns the two pools, and the paged read is one
-    custom-call in the layer loop's body with one array as its result."""
+    instruction is named after its scope, bare (the `closed_call_` prefix
+    went in PR 27: the benchmark's readers select by name), the page write
+    is still the custom-call that returns the two pools, and the paged read
+    is one custom-call in the layer loop's body with one array as its
+    result."""
     from gofr_tpu.tpu.executor import _named_after
     from gofr_tpu.tpu.paging import PagedLLMEngine
 
@@ -433,8 +433,7 @@ def test_step_programs_name_their_module_and_kernels(topo, as_tpu, program):
         # counted as the page write)
         assert [(name.rsplit(".", 1)[0], tupled)
                 for name, tupled, _ in calls] == [
-            ("closed_call_paged_read", False),
-            ("closed_call_paged_write", True)]
+            ("paged_read", False), ("paged_write", True)]
         assert _computation_of(compiled, calls[0][0]) in _while_bodies(
             compiled)
     else:
@@ -450,7 +449,7 @@ def test_step_programs_name_their_module_and_kernels(topo, as_tpu, program):
         assert "HloModule jit_prefill__256x1," in compiled.as_text()
         calls = _kernel_calls(compiled)
         assert [name.rsplit(".", 1)[0] for name, _, _ in calls] == [
-            "closed_call_flash_prefill"]
+            "flash_prefill"]
     for name, _, op_name in calls:
         assert op_name.endswith("/pallas_call")
 
@@ -523,3 +522,92 @@ def test_dense_decode_step_compiles_with_layout_pin(topo, as_tpu,
                         chips.shape((2,), jnp.uint32), donate=(1, 2))
     assert _kernels(compiled) == (cfg.n_layers if decode_attn == "kernel"
                                   else 0)
+
+
+# -- the nemotron_h family's step programs (ISSUE 27) ---------------------------
+def _nemotron_programs(topo, pattern="ME*ME", slots=96, n_pages=961):
+    """(engine shell, abstract params, pools, state, loop state, rng) at the
+    published widths, a few blocks deep: every kind of block, two of the
+    kinds that hold state."""
+    from gofr_tpu.models.nemotron_h import (FLOAT32_LEAVES, KINDS,
+                                            NemotronHConfig, layer_shapes)
+    from gofr_tpu.tpu.paging import PagedLLMEngine
+
+    cfg = dataclasses.replace(NemotronHConfig.nano_30b_a3b_ep2(),
+                              pattern=pattern, attn_impl="flash")
+    chips = Chips(topo, 1)
+    engine = _engine_shell(PagedLLMEngine, cfg, None)
+    params = {
+        "tok_emb": chips.shape((cfg.vocab_size, cfg.dim), jnp.bfloat16),
+        "final_norm": chips.shape((cfg.dim,), jnp.bfloat16),
+        "lm_head": chips.shape((cfg.dim, cfg.vocab_size), jnp.bfloat16),
+        "layers": [{name: chips.shape(shape, jnp.float32 if name in FLOAT32_LEAVES
+                                      else jnp.bfloat16)
+                    for name, shape in layer_shapes(cfg, KINDS[m]).items()}
+                   for m in pattern]}
+    pools = _pools(chips, cfg.n_kv_heads, cfg.head_dim, jnp.bfloat16,
+                   n_pages, cfg.kv_layers)
+    state = tuple(chips.shape(shape, dtype)
+                  for shape, dtype in engine.model.state_shapes(slots))
+    return (cfg, chips, engine, params, pools, state,
+            _loop_state(chips, slots), chips.shape((2,), jnp.uint32))
+
+
+def test_nemotron_h_decode_step_compiles_with_its_state_in_place(topo,
+                                                                as_tpu):
+    """The cell's decode program shape (96 slots, 961 pages, table 16 wide)
+    over Mamba-2, expert and attention blocks at the published widths: the
+    per-slot state (a 2 MiB recurrent state a slot a Mamba-2 block) and the
+    pools are aliased, no state-sized or expert-sized copy is made (the up
+    matrices held [held, D, F] were copied whole, 630 MB a block: compile-
+    only, PR 27), the module is named `jit_decode...` and each new kernel's
+    instruction after its scope, in the scan's body."""
+    from gofr_tpu.tpu.executor import _named_after
+
+    cfg, chips, engine, params, pools, state, loop, rng = \
+        _nemotron_programs(topo)
+    compiled = _compile(
+        _named_after(engine._decode_fn_paged(16, 16),
+                     "nemotron-h-paged-decode-x16-NP16"),
+        params, *pools, chips.shape((96, 16), jnp.int32), *loop, rng, *state,
+        donate=(1, 2, 8, 9))
+    assert "HloModule jit_decode__x16_NP16," in compiled.as_text()
+    names = sorted(name.rsplit(".", 1)[0]
+                   for name, _, _ in _kernel_calls(compiled))
+    assert names == ["moe_experts", "moe_experts", "paged_read",
+                     "paged_write", "ssm_update", "ssm_update"]
+    held = sum(np.prod(a.shape) * a.dtype.itemsize for a in pools + state)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= held
+    expert = cfg.held * cfg.dim * cfg.expert_dim * 2
+    assert mem.temp_size_in_bytes < min(expert, held) // 8, (
+        f"{mem.temp_size_in_bytes / GIB:.2f} GiB of temporaries: a copy of "
+        f"the state or of a block's experts")
+
+
+def test_nemotron_h_prefill_compiles_with_its_state_in_place(topo, as_tpu):
+    """The cell's widest admission (16 x 128): the slots' state rows are
+    written into the donated state, the experts run as a grouped product
+    over sorted (token, pick) pairs (one kernel a block), attention as the
+    flash kernel."""
+    from gofr_tpu.tpu.executor import _named_after
+
+    cfg, chips, engine, params, pools, state, loop, rng = \
+        _nemotron_programs(topo)
+    K, bucket = 16, 128
+    rows = chips.shape((K,), jnp.int32)
+    compiled = _compile(
+        _named_after(engine._prefill_fn(bucket, K),
+                     "nemotron-h-paged-prefill-128x16"),
+        params, *pools, chips.shape((K, bucket), jnp.int32),
+        chips.shape((K, bucket // PAGE), jnp.int32), rows, rows, *loop,
+        chips.shape((K,), jnp.float32), rng, *state,
+        donate=(1, 2, 7, 8, 9, 12, 13))
+    assert "HloModule jit_prefill__128x16," in compiled.as_text()
+    names = sorted(name.rsplit(".", 1)[0]
+                   for name, _, _ in _kernel_calls(compiled))
+    assert names.count("moe_experts") == 2 and "flash_prefill" in names
+    held = sum(np.prod(a.shape) * a.dtype.itemsize for a in pools + state)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= held
+    assert mem.temp_size_in_bytes < 1 * GIB
