@@ -254,9 +254,11 @@ def check_kernels() -> dict:
     from gofr_tpu.ops.decode_attention import quantize_kv
     from gofr_tpu.ops.flash_attention import (attention_reference,
                                               flash_attention)
-    from gofr_tpu.ops.paged_attention import (paged_attention,
+    from gofr_tpu.ops.paged_attention import (block_tail, paged_attention,
+                                              paged_attention_in_block,
                                               paged_attention_reference,
-                                              paged_write_decode)
+                                              paged_flush_block,
+                                              paged_write_decode, tail_put)
 
     H, Hkv, dh, L, P, NP = 32, 8, 64, 2, 40, 4
     lengths = jnp.asarray([1, 100, 128, 129, 255, 256, 300, 512], jnp.int32)
@@ -305,6 +307,42 @@ def check_kernels() -> dict:
                                        max_err(got_v, want_v))
     check(errors["paged_write_decode"] == 0.0,
           f"paged_write_decode is not exact: {errors}")
+
+    # a decode block's tail: 8 tokens a row in every layer (two rows cross
+    # into their next page, one row holds no request); the eighth put by
+    # the read itself, which attends pages and tail: against the reference
+    # on a layer that had them written by columns; then flushed into the
+    # pages: the same columns, exactly
+    starts = jnp.asarray([1, 100, 124, 129, 250, 256, 300, 500], jnp.int32)
+    live = jnp.arange(B) != 3
+    block_k = jax.random.normal(keys[3], (8, B, Hkv, dh), jnp.bfloat16)
+    block_v = jax.random.normal(keys[4], (8, B, Hkv, dh), jnp.bfloat16)
+    want_k, want_v = k_pool, v_pool
+    tail = block_tail(k_pool, B, 8)
+    for t in range(8):
+        at = starts + t
+        pages = jnp.where(live, table[jnp.arange(B), at // PAGE], 0)
+        for l in range(L):
+            want_k = want_k.at[l, pages, :, :, at % PAGE].set(block_k[t])
+            want_v = want_v.at[l, pages, :, :, at % PAGE].set(block_v[t])
+            if (l, t) != (1, 7):     # the read below puts this one itself
+                tail = tail_put(*tail, block_k[t], block_v[t], l, t)
+    with jax.default_matmul_precision("highest"):
+        ref = jax.jit(paged_attention_reference)(
+            q, want_k[1], want_v[1], table, jnp.where(live, starts + 8, 0))
+    out, *tail = jax.jit(lambda q, k, v, kt, vt: paged_attention_in_block(
+        q, block_k[7], block_v[7], k, v, kt, vt, table,
+        jnp.where(live, starts, 0), jnp.where(live, 8, 0), layer=layer))(
+            q, k_pool, v_pool, *tail)
+    errors["paged_attention_tail"] = max_err(out, ref)
+    got_k, got_v = jax.jit(lambda k, v, kt, vt: paged_flush_block(
+        k, v, kt, vt, table, starts, jnp.where(live, 8, 0)))(
+            k_pool, v_pool, *tail)
+    errors["paged_flush_block"] = max(      # page 0: the idle row's junk
+        max_err(got_k[:, 1:], want_k[:, 1:]),
+        max_err(got_v[:, 1:], want_v[:, 1:]))
+    check(errors["paged_flush_block"] == 0.0,
+          f"paged_flush_block is not exact: {errors}")
 
     T = 256
     fq = jax.random.normal(keys[5], (2, T, H, dh), jnp.bfloat16)

@@ -80,9 +80,9 @@ class LlamaConfig:
             prefill=lambda params, tokens, lengths, mesh=None:
             llama_prefill_paged(params, self, tokens, lengths, mesh),
             decode=lambda params, tokens, positions, k_pool, v_pool, table,
-            state, mesh=None: (*llama_decode_step_paged(
+            state, tail, step, mesh=None: (*llama_decode_step_paged(
                 params, self, tokens, positions, k_pool, v_pool, table,
-                mesh), state, None))
+                tail, step, mesh), state, None))
 
     @classmethod
     def debug(cls) -> "LlamaConfig":
@@ -905,45 +905,63 @@ def llama_prefill_chunk_q8(params, cfg: LlamaConfig, tokens, positions,
 
 
 def _attended_lengths(table, positions):
-    """What each row of a paged decode step attends: its context with the
-    token just written, or NOTHING for a row that holds no request. Such a
-    row's table starts at page 0 (the PageAllocator's garbage page, never
-    handed out), and its position is whatever its last request left,
-    advanced by every step since: read as a length it would walk the
-    garbage page up to the table's width, every layer of every step."""
-    return jnp.where(table[:, 0] > 0, positions + 1, 0)
+    """What each row of a per-token paged decode step attends (the int8
+    pools' step): its context with the token just written, or NOTHING for
+    a row that holds no request (ops/paged_attention `holds_request`):
+    read as a length, such a row's stale position would walk the garbage
+    page up to the table's width, every layer of every step."""
+    from ..ops.paged_attention import holds_request
+
+    return jnp.where(holds_request(table), positions + 1, 0)
+
+
+def _attended_in_block(table, positions, step):
+    """The same for step `step` of a decode block whose new tokens wait in
+    the block's tail (ops/paged_attention `block_tail`): (tokens attended
+    in pages: the row's context when the block began; tokens attended in
+    the tail: this step's and the block's earlier ones), both 0 for a row
+    that holds no request."""
+    from ..ops.paged_attention import holds_request
+
+    live = holds_request(table)
+    return (jnp.where(live, positions - step, 0),
+            jnp.where(live, step + 1, 0))
 
 
 def llama_decode_step_paged(params, cfg: LlamaConfig, tokens, positions,
-                            k_pool, v_pool, table, mesh=None):
-    """One decode step against a PAGED KV cache.
+                            k_pool, v_pool, table, tail, step, mesh=None):
+    """One decode step of a block against a PAGED KV cache.
 
-    tokens: [B]; positions: [B] absolute write positions; k/v_pool:
-    [L, P, Hkv, dh, page_size]; table: [B, NP] page ids per slot (entries
-    past a slot's live pages are not read; a row that starts at page 0
-    holds no request and attends nothing).
-    Returns (logits [B, V] float32, k_pool, v_pool).
+    tokens: [B]; positions: [B] absolute positions of these tokens;
+    k/v_pool: [L, P, Hkv, dh, page_size] as they were when the block began,
+    read only; table: [B, NP] page ids per slot (entries past a slot's live
+    pages are not read; a row that starts at page 0 holds no request and
+    attends nothing); tail: the block's (k_tail, v_tail), `step` this
+    step's index in the block.
+    Returns (logits [B, V] float32, tail).
 
-    Per-layer: write this token's K/V into its page (paged_write_decode),
-    then read attention through the block table with the scalar-prefetch
-    Pallas kernel (paged_attention) — per-step HBM traffic tracks the
-    table width (live pages), not a dense [B, S] allocation.
+    Per-layer, ONE kernel call (paged_attention_in_block): this token's
+    K/V go into the tail at `step`, and attention reads the row's pages
+    through the block table and the tail's first step + 1 tokens in one
+    softmax — per-step HBM traffic tracks the live pages, not a dense
+    [B, S] allocation, and no page is written until the block is over
+    (`paged_flush_block`, the engine's).
 
-    The STACKED pools are carried whole through a fori_loop and handed to
-    both kernels with the layer index; neither a slice of one layer nor an
-    XLA scatter ever touches them (ops/paged_attention's module docstring
-    says what either costs on the chip).
+    The STACKED pools and tails are handed whole to the kernel with the
+    layer index; neither a slice of one layer nor an XLA scatter ever
+    touches the pools (ops/paged_attention's module docstring says what
+    either costs on the chip).
     """
-    from ..ops.paged_attention import paged_attention, paged_write_decode
+    from ..ops.paged_attention import paged_attention_in_block
 
     B = tokens.shape[0]
     H, Hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     x = _embed(params, cfg, tokens)[:, None]               # [B, 1, D]
     pos_grid = positions[:, None]                          # [B, 1]
-    lengths = _attended_lengths(table, positions)
+    lengths, tail_lens = _attended_in_block(table, positions, step)
 
     def layer_body(l, state):
-        x, k_pool, v_pool = state
+        x, k_tail, v_tail = state
         layer = jax.tree_util.tree_map(lambda w: w[l], params["layers"])
         normed = rms_norm(x, layer["attn_norm"], cfg.rms_eps)
         q = rope(_mm(normed, layer, "wq").reshape(B, 1, H, dh), pos_grid,
@@ -951,20 +969,18 @@ def llama_decode_step_paged(params, cfg: LlamaConfig, tokens, positions,
         k = rope(_mm(normed, layer, "wk").reshape(B, 1, Hkv, dh), pos_grid,
                  cfg.rope_theta)
         v = _mm(normed, layer, "wv").reshape(B, 1, Hkv, dh)
-        k_pool, v_pool = paged_write_decode(
-            k_pool, v_pool, k[:, 0], v[:, 0], table, positions,
-            layer=l, mesh=mesh)
-        attn = paged_attention(q[:, 0], k_pool, v_pool, table, lengths,
-                               layer=l, mesh=mesh)
+        attn, k_tail, v_tail = paged_attention_in_block(
+            q[:, 0], k[:, 0], v[:, 0], k_pool, v_pool, k_tail, v_tail,
+            table, lengths, tail_lens, layer=l, mesh=mesh)
         x = x + _mm(attn.reshape(B, 1, H * dh), layer, "wo")
         x = x + _ffn_block(x, layer, cfg)
-        return x, k_pool, v_pool
+        return x, k_tail, v_tail
 
-    x, k_pool, v_pool = jax.lax.fori_loop(
-        0, cfg.n_layers, layer_body, (x, k_pool, v_pool))
+    x, k_tail, v_tail = jax.lax.fori_loop(
+        0, cfg.n_layers, layer_body, (x, *tail))
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)
     logits = _head(x[:, 0], params)
-    return logits, k_pool, v_pool
+    return logits, (k_tail, v_tail)
 
 
 def llama_decode_step_paged_q8(params, cfg: LlamaConfig, tokens, positions,

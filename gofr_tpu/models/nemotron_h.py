@@ -198,9 +198,9 @@ class NemotronHConfig:
             prefill=lambda params, tokens, lengths, mesh=None: prefill(
                 params, self, tokens, lengths),
             decode=lambda params, tokens, positions, k_pool, v_pool, table,
-            state, mesh=None: decode_step(
+            state, tail, step, mesh=None: decode_step(
                 params, self, tokens, positions, k_pool, v_pool, table,
-                state),
+                state, tail, step),
             counters=COUNTERS,
             describe=lambda counts, steps: describe(self, counts, steps),
             refuses=REFUSES)
@@ -532,17 +532,20 @@ def attention_prefill(x, w, cfg: NemotronHConfig):
     return out, k.transpose(0, 2, 3, 1), v.transpose(0, 2, 3, 1)
 
 
-def attention_decode(x, w, k_pool, v_pool, table, positions, lengths,
+def attention_decode(x, w, k_pool, v_pool, table, lengths, tail, tail_lens,
                      layer: int, cfg: NemotronHConfig):
     """x [B, D] (normed); `layer` this block's index among the attention
-    blocks = the pools' leading axis."""
-    from ..ops.paged_attention import paged_attention, paged_write_decode
+    blocks = the pools' and the tail's leading axis. The token's K and V
+    go into the decode block's tail as token tail_lens[b] - 1; the read
+    attends lengths[b] tokens in pages and tail_lens[b] in the tail.
+    Returns (out, tail)."""
+    from ..ops.paged_attention import paged_attention_in_block
 
     q, k, v = _qkv(x, w, cfg)
-    k_pool, v_pool = paged_write_decode(k_pool, v_pool, k, v, table,
-                                        positions, layer=layer)
-    attn = paged_attention(q, k_pool, v_pool, table, lengths, layer=layer)
-    return attn.reshape(x.shape[0], -1) @ w["wo"], k_pool, v_pool
+    attn, *tail = paged_attention_in_block(
+        q, k, v, k_pool, v_pool, *tail, table, lengths, tail_lens,
+        layer=layer)
+    return attn.reshape(x.shape[0], -1) @ w["wo"], tuple(tail)
 
 
 def _head(x, params, cfg: NemotronHConfig):
@@ -584,16 +587,19 @@ def prefill(params, cfg: NemotronHConfig, tokens, lengths):
 
 
 def decode_step(params, cfg: NemotronHConfig, tokens, positions, k_pool,
-                v_pool, table, state):
-    """One token a row. tokens, positions [B]; pools
-    [kv_layers, P, Hkv, dh, ps]; table [B, NP] (a row that starts at page 0
-    holds no request); state = (ssm, tail). Returns (logits [B, V]
-    float32, k_pool, v_pool, state, counters [len(COUNTERS)] int32)."""
-    from .llama import _attended_lengths
+                v_pool, table, state, kv_tail, step):
+    """One token a row, step `step` of a decode block. tokens, positions
+    [B]; pools [kv_layers, P, Hkv, dh, ps] as the block found them, read
+    only; table [B, NP] (a row that starts at page 0 holds no request);
+    state = (ssm, tail); kv_tail the block's (k_tail, v_tail)
+    (models/protocol.py). Returns (logits [B, V] float32, kv_tail, state,
+    counters [len(COUNTERS)] int32)."""
+    from ..ops.paged_attention import holds_request
+    from .llama import _attended_in_block
 
     ssm, tail = state
-    live = table[:, 0] > 0
-    lengths = _attended_lengths(table, positions)
+    live = holds_request(table)
+    lengths, tail_lens = _attended_in_block(table, positions, step)
     x = params["tok_emb"][tokens]
     counted = jnp.zeros((3,), jnp.int32)
     m = a = 0
@@ -606,10 +612,11 @@ def decode_step(params, cfg: NemotronHConfig, tokens, positions, k_pool,
             out, seen = experts_decode(normed, w, live, cfg)
             counted = counted + seen
         else:
-            out, k_pool, v_pool = attention_decode(
-                normed, w, k_pool, v_pool, table, positions, lengths, a, cfg)
+            out, kv_tail = attention_decode(
+                normed, w, k_pool, v_pool, table, lengths, kv_tail,
+                tail_lens, a, cfg)
             a += 1
         x = x + out
     counters = jnp.concatenate([jnp.sum(live, dtype=jnp.int32)[None],
                                 counted])
-    return _head(x, params, cfg), k_pool, v_pool, (ssm, tail), counters
+    return _head(x, params, cfg), kv_tail, (ssm, tail), counters

@@ -18,11 +18,17 @@ computes on it:
   [layers, K, ...] array for each of `state_shapes`: the state as of each
   row's last real token). The engine scatters pages and slot states.
 - `decode(params, tokens [B], positions [B], k_pool, v_pool, table, state,
-  mesh)` -> (logits [B, V] float32, k_pool, v_pool, state, counters): one
-  token a row, pools and state updated in place. `counters` is an int32
-  vector named by `counters` (None when the family counts nothing); the
-  engine sums it over a block's steps and carries it to the host on the
-  block's own token copy.
+  tail, step, mesh)` -> (logits [B, V] float32, tail, state, counters):
+  one token a row, step `step` (int32) of a decode block. The pools are
+  READ ONLY here, as the block found them: the token's K and V go into the
+  block's `tail` = (k_tail, v_tail) (ops/paged_attention `block_tail`:
+  the engine makes it when the block begins and flushes it into the pages
+  when the block is over), and the read attends the row's pages as of the
+  block's start plus the tail's first step + 1 tokens
+  (`paged_attention_in_block` does both). State is updated in place. `counters` is
+  an int32 vector named by `counters` (None when the family counts
+  nothing); the engine sums it over a block's steps and carries it to the
+  host on the block's own token copy.
 - `refuses`: {engine feature: reason} the family cannot serve yet; the
   engine refuses each BY NAME at construction (docs/model-families.md).
   A config MAY carry `kv_dtype` (a lower-precision page pool) and

@@ -42,10 +42,22 @@ out with the scattered window's dims minor; a per-token window is
 [Hkv, dh], so a per-token XLA scatter into the pool makes the compiler
 carry the WHOLE pool dh-minor (64 lanes padded to 128: twice the bytes,
 plus a copy in and out of every program — llama1b widths with a 4 GiB
-pool did not compile for a 16 GiB chip). So the decode write is a Pallas
-read-modify-write of the row's current page (`paged_write_decode`), and
-prefill windows are written as WHOLE pages (`paged_write_window`), whose
-[Hkv, dh, ps] window is the storage layout's own minor dims.
+pool did not compile for a 16 GiB chip). And the pool is token-minor: one
+token's column touches every tile of its page, so placing it is a Pallas
+read-modify-write of the WHOLE page, 2 x 256 KiB moved to place 2 x 2 KiB
+at 8 heads of 128. What can be chosen is how often. The decode write of
+the floating-point pools is a FLUSH A BLOCK: a decode program's new K and
+V wait in a small tail beside the pool (`block_tail`, token-major a head:
+the step's read puts each row's new token there itself), the read attends
+each row's pages as the block found them plus the tail's tokens so far in
+one online softmax (`paged_attention_in_block`), and when the block's
+steps are over `paged_flush_block` reads each live row's page, places the
+block's columns and writes it back: once a block, not once a token; a row
+that holds no request moves nothing. The int8 pools and the speculative
+verify window still place one token a call (`paged_write_decode`, the
+same read-modify-write, every row). Prefill windows are written as WHOLE
+pages (`paged_write_window`), whose [Hkv, dh, ps] window is the storage
+layout's own minor dims.
 
 The XLA `paged_attention_reference` (gather-based) is the numerics oracle.
 """
@@ -97,24 +109,43 @@ def paged_attention_reference(q, k_pool, v_pool, table, lengths,
     return out.reshape(B, H, dh).astype(q.dtype)
 
 
-def _paged_kernel(layer_ref, table_ref, len_ref, q_ref, *refs, scale: float,
-                  quantized: bool):
+def _paged_kernel(layer_ref, table_ref, len_ref, *refs, scale: float,
+                  quantized: bool, tailed: bool):
     """One grid step = one row b: stream the row's live pages (ALL heads of
     a page at a time) through two VMEM buffers a pool and fold each into
-    the online softmax, the dots batched over the KV heads.
+    the online softmax, the dots batched over the KV heads. `tailed`, the
+    row is in a decode block: its new k and v are put into its tail as
+    token tail_len[b] - 1, the tail goes back where it came from, and its
+    first tail_len[b] tokens are one more segment of the same softmax.
 
-    refs: the n stacked pools left in HBM (k, v[, k_scale, v_scale]), o,
-    their n VMEM buffers [2, *page], DMA semaphores [n, 2] and `first_slot`
-    (SMEM: the buffer this row's first page was started in). int8 pages
-    carry per-token scales; dequant FOLDS into the dots exactly like
-    ops/decode_attention's quantized kernel (k's scale multiplies score
-    rows, v's folds into the probabilities)."""
+    refs: [tail_len (SMEM, with the other scalars),] q, [the row's new k,
+    v [Hkv, 1, dh'],] the n stacked pools left in HBM (k, v[, k_scale,
+    v_scale]), [the two stacked tails left where they are,] o, [the tails
+    again: the outputs alias them,] the pools' n VMEM buffers [2, *page],
+    [the tails' two [2, Hkv, T, dh'],] DMA semaphores [n, 2], [the tails'
+    [2, 2] in and [2] out,] and `first_slot` (SMEM: the buffer this row's
+    first page was started in). int8 pages carry per-token scales; dequant
+    FOLDS into the dots exactly like ops/decode_attention's quantized
+    kernel (k's scale multiplies score rows, v's folds into the
+    probabilities)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    refs = list(refs)
+    tail_len_ref = refs.pop(0) if tailed else None
+    q_ref = refs.pop(0)
+    news = [refs.pop(0) for _ in range(2 if tailed else 0)]
     n = 4 if quantized else 2
-    pools, o_ref, bufs = refs[:n], refs[n], refs[n + 1:2 * n + 1]
-    sems, first_slot = refs[2 * n + 1:]
+    pools = [refs.pop(0) for _ in range(n)]
+    tails = [refs.pop(0) for _ in news]
+    o_ref = refs.pop(0)
+    tails_out = [refs.pop(0) for _ in news]
+    bufs = [refs.pop(0) for _ in range(n)]
+    tail_bufs = [refs.pop(0) for _ in news]
+    sems = refs.pop(0)
+    tail_sems, put_sems = (refs.pop(0), refs.pop(0)) if tailed else (None,
+                                                                    None)
+    first_slot, = refs
     k_buf, v_buf = bufs[:2]
     ks_buf, vs_buf = bufs[2:] if quantized else (None, None)
 
@@ -156,14 +187,94 @@ def _paged_kernel(layer_ref, table_ref, len_ref, q_ref, *refs, scale: float,
 
     q = q_ref[0]                                          # [Hkv, G, dh]
     slot0 = first_slot[0]
+    folded = (jnp.full((n_kv, G, 1), DEFAULT_MASK_VALUE, jnp.float32),
+              jnp.zeros((n_kv, G, 1), jnp.float32),
+              jnp.zeros((n_kv, G, dh), jnp.float32))
+
+    if tailed:
+        # The tail folds FIRST, while the row's first page (started by the
+        # row before) and its second (started here) stream in: folded last,
+        # it would leave the copy queue one page deep at every row's end.
+        # Its own copy was started by the row before, into the buffer of
+        # this row's parity, so nothing waits for it either.
+        tail_len = tail_len_ref[b]
+
+        def tail_copies(row):
+            return [pltpu.make_async_copy(
+                tail.at[layer, row], buf.at[row % 2], tail_sems.at[j, row % 2])
+                for j, (tail, buf) in enumerate(zip(tails, tail_bufs))]
+
+        put_copies = [
+            pltpu.make_async_copy(buf.at[b % 2], tail.at[layer, b],
+                                  put_sems.at[j])
+            for j, (tail, buf) in enumerate(zip(tails_out, tail_bufs))]
+
+        def start_tail(row):
+            @pl.when(tail_len_ref[jnp.minimum(row, last_row)] > 0)
+            def _start():
+                for copy in tail_copies(row):
+                    copy.start()
+
+        @pl.when(b == 0)
+        def _first_tail():
+            start_tail(b)
+
+        @pl.when(n_pages > 1)
+        def _second_page():
+            for copy in page_copies(b, 1, 1 - slot0):
+                copy.start()
+
+        @pl.when(b < last_row)
+        def _next_tail():
+            start_tail(b + 1)
+
+        def fold_tail(carry):
+            m_prev, l_prev, acc = carry
+            for copy in tail_copies(b):
+                copy.wait()
+            # the step's token joins the tail: here, for this row's read,
+            # and where the tail lives, for the steps to come (the copy
+            # back runs under the page loop; a whole tail, since one
+            # token's row of a packed tile cannot be copied alone)
+            token = jax.lax.broadcasted_iota(
+                jnp.int32, tail_bufs[0].shape[1:], 1)
+            for buf, new in zip(tail_bufs, news):
+                buf[b % 2] = jnp.where(token == tail_len - 1, new[0],
+                                       buf[b % 2])
+            for copy in put_copies:
+                copy.start()
+            # the tail is token-major a head ([Hkv, T, dh], dh on lanes,
+            # less the lanes that pad a narrower head): its scores are the
+            # plain q . k^T, batched over the KV heads
+            k, v = (buf[b % 2][:, :, :dh] for buf in tail_bufs)
+            s = scale * jax.lax.dot_general(
+                q, k, (((2,), (2,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32)
+            held = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+                    < tail_len)
+            m_new = jnp.maximum(m_prev, jnp.max(
+                jnp.where(held, s, DEFAULT_MASK_VALUE), axis=-1,
+                keepdims=True))
+            pr = jnp.where(held, jnp.exp(s - m_new), 0.0)
+            alpha = jnp.exp(m_prev - m_new)
+            pv = jax.lax.dot_general(pr.astype(v.dtype), v,
+                                     (((2,), (1,)), ((0,), (0,))),
+                                     preferred_element_type=jnp.float32)
+            return (m_new,
+                    l_prev * alpha + jnp.sum(pr, axis=-1, keepdims=True),
+                    acc * alpha + pv)
+
+        # a row that holds no request has no tail either: it reads nothing
+        folded = jax.lax.cond(tail_len > 0, fold_tail, lambda c: c, folded)
 
     def fold_page(i, carry):
         m_prev, l_prev, acc = carry
         slot = (slot0 + i) % 2
 
-        # keep the DMA queue fed before waiting: this row's next page or,
-        # from its last page, the first page of the next row that has one
-        @pl.when(i + 1 < n_pages)
+        # keep the DMA queue fed before waiting: this row's next page (the
+        # second is under way already where a tail folded first) or, from
+        # its last page, the first page of the next row that has one
+        @pl.when(jnp.logical_and(i + 1 < n_pages, i >= int(tailed)))
         def _next_page():
             for copy in page_copies(b, i + 1, 1 - slot):
                 copy.start()
@@ -199,12 +310,13 @@ def _paged_kernel(layer_ref, table_ref, len_ref, q_ref, *refs, scale: float,
                                  preferred_element_type=jnp.float32)
         return m_new, l_new, acc * alpha + pv
 
-    _, l, acc = jax.lax.fori_loop(
-        0, n_pages, fold_page,
-        (jnp.full((n_kv, G, 1), DEFAULT_MASK_VALUE, jnp.float32),
-         jnp.zeros((n_kv, G, 1), jnp.float32),
-         jnp.zeros((n_kv, G, dh), jnp.float32)))
+    _, l, acc = jax.lax.fori_loop(0, n_pages, fold_page, folded)
     first_slot[0] = (slot0 + n_pages) % 2
+    if tailed:
+        @pl.when(tail_len > 0)
+        def _tail_is_back():
+            for copy in put_copies:
+                copy.wait()
     o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
@@ -256,74 +368,127 @@ def paged_attention(q, k_pool, v_pool, table, lengths, k_scale=None,
     read, and a row of length 0 reads nothing and returns zeros. The walk
     stops at the table's width whatever the length says.
     """
+    if (k_scale is None) != (v_scale is None):
+        raise ValueError("pass both k_scale and v_scale or neither")
+    scales = [] if k_scale is None else [k_scale, v_scale]
+    return _paged_read(q, [k_pool, v_pool] + scales, table, lengths, None,
+                       layer, mesh, interpret)
+
+
+def paged_attention_in_block(q, k, v, k_pool, v_pool, k_tail, v_tail, table,
+                             lengths, tail_lens, *, layer=None, mesh=None,
+                             interpret=None):
+    """One step's attention inside a decode block (floating-point pools):
+    row b's new k, v [B, Hkv, dh] become token tail_lens[b] - 1 of its tail
+    (`block_tail`, stacked like the pools: [L, B, Hkv, T, dh'], or one
+    layer's with layer=None), and the row attends its lengths[b] tokens in
+    pages, then the first tail_lens[b] tokens of its tail, in one softmax.
+    A row with tail_lens[b] == 0 (`holds_request`: lengths[b] is 0 too)
+    puts nothing, reads nothing and returns zeros.
+    Returns (attention [B, H, dh], k_tail, v_tail), the tails updated in
+    place. Keys past tail_lens[b] may hold anything; values there must be
+    finite (`block_tail` makes zeros): they meet a probability of 0.0.
+    q, pools, table, layer, mesh: as `paged_attention` has them; the tails
+    and k, v split on Hkv under a tp mesh."""
+    return _paged_read(q, [k_pool, v_pool], table, lengths,
+                       (k, v, k_tail, v_tail, tail_lens), layer, mesh,
+                       interpret)
+
+
+def _paged_read(q, pools, table, lengths, block, layer, mesh, interpret):
+    """Both reads' one call. pools: (k, v[, k_scale, v_scale]); block: None
+    or (k, v, k_tail, v_tail, tail_lens) of `paged_attention_in_block`."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    quantized = k_scale is not None
-    if quantized != (v_scale is not None):
-        raise ValueError("pass both k_scale and v_scale or neither")
+    quantized, tailed = len(pools) == 4, block is not None
+    stacked = layer is not None
     layer_arr = _layer_operand(layer)
-    k_pool, v_pool = _stacked(k_pool, layer), _stacked(v_pool, layer)
-    if quantized:
-        k_scale, v_scale = _stacked(k_scale, layer), _stacked(v_scale, layer)
+    pools = [_stacked(pool, layer) for pool in pools]
+    news, tails, tail_lens = [], [], []
+    if tailed:
+        news, tail_lens = list(block[:2]), [block[4]]
+        tails = [_stacked(tail, layer) for tail in block[2:4]]
 
     if _tp(mesh):
         from jax.sharding import PartitionSpec
 
         rep = PartitionSpec()
-        operands = [q, k_pool, v_pool, table, lengths, layer_arr]
-        specs = [_heads_spec(3, 1), _heads_spec(5, 2), _heads_spec(5, 2),
-                 rep, rep, rep]
-        if quantized:
-            operands += [k_scale, v_scale]
-            specs += [_heads_spec(4, 2), _heads_spec(4, 2)]
+        heads = _heads_spec(3, 1)
+        tail_specs = [_heads_spec(5, 2)] * len(tails)
+        specs = ([heads] + [_heads_spec(pool.ndim, 2) for pool in pools]
+                 + [rep, rep, rep] + [heads] * len(news) + tail_specs
+                 + [rep] * len(tail_lens))
+        n = len(pools)
 
-        def local(q, k_pool, v_pool, table, lengths, layer_arr, *scales):
-            return paged_attention(q, k_pool, v_pool, table, lengths,
-                                   *scales, layer=layer_arr[0],
-                                   interpret=interpret)
+        def local(q, *rest):
+            pools, (table, lengths, layer_arr) = rest[:n], rest[n:n + 3]
+            block = rest[n + 3:] or None
+            return _paged_read(q, list(pools), table, lengths, block,
+                               layer_arr[0], None, interpret)
 
-        return jax.shard_map(local, mesh=mesh, in_specs=tuple(specs),
-                             out_specs=_heads_spec(3, 1),
-                             check_vma=False)(*operands)
+        out = jax.shard_map(
+            local, mesh=mesh, in_specs=tuple(specs),
+            out_specs=(heads, *tail_specs) if tailed else heads,
+            check_vma=False)(q, *pools, table, lengths, layer_arr, *news,
+                             *tails, *tail_lens)
+        return out if not tailed else (out[0], *_unstack(out[1:], stacked))
 
     B, H, dh = q.shape
-    Hkv = k_pool.shape[2]
+    Hkv = pools[0].shape[2]
     G = H // Hkv
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    # the row's new k, v as the tail holds a token: [Hkv, 1, dh'] a row
+    news = [jnp.pad(new.astype(tail.dtype),
+                    ((0, 0), (0, 0), (0, tail.shape[-1] - dh)))[:, :, None]
+            for new, tail in zip(news, tails)]
 
-    pools = [k_pool, v_pool] + ([k_scale, v_scale] if quantized else [])
+    scalars = [layer_arr, table, lengths] + tail_lens
     kernel = functools.partial(_paged_kernel, scale=1.0 / math.sqrt(dh),
-                               quantized=quantized)
+                               quantized=quantized, tailed=tailed)
 
-    def row_index(b, layer, table, lens):
+    def row_index(b, *scalars):
         return (b, 0, 0, 0)
 
+    # the pools and tails stay where they are, whole: the kernel copies
+    # [layer, page] and [layer, row] (a tail is small enough that the
+    # compiler may keep all of it in VMEM)
+    whole = pl.BlockSpec(memory_space=pl.ANY)
+    out_row = pl.BlockSpec((1, Hkv, G, dh), row_index)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,  # layer, table, lengths
+        num_scalar_prefetch=len(scalars),  # layer, table, lengths[, tail]
         grid=(B,),
-        # the pools stay in HBM, whole: the kernel copies [layer, page]
-        in_specs=[pl.BlockSpec((1, Hkv, G, dh), row_index)]
-        + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
-        out_specs=pl.BlockSpec((1, Hkv, G, dh), row_index),
+        in_specs=[out_row]
+        + [pl.BlockSpec((1,) + new.shape[1:], row_index) for new in news]
+        + [whole] * (len(pools) + len(tails)),
+        out_specs=[out_row] + [whole] * len(tails),
         scratch_shapes=[pltpu.VMEM((2,) + pool.shape[2:], pool.dtype)
-                        for pool in pools] + [
-            pltpu.SemaphoreType.DMA((len(pools), 2)),
-            pltpu.SMEM((1,), jnp.int32),
-        ],
+                        for pool in pools]
+        + [pltpu.VMEM((2,) + x.shape[2:], x.dtype) for x in tails]
+        + [pltpu.SemaphoreType.DMA((len(pools), 2))]
+        + [pltpu.SemaphoreType.DMA((2, 2)),
+           pltpu.SemaphoreType.DMA((2,))] * tailed
+        + [pltpu.SMEM((1,), jnp.int32)],
     )
+    first_tail = len(scalars) + 1 + len(news) + len(pools)
     with kernel_scope("paged_read"):
-        out = pl.pallas_call(
+        attended, *tails = pl.pallas_call(
             kernel,
             grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((B, Hkv, G, dh), q.dtype),
+            out_shape=[jax.ShapeDtypeStruct((B, Hkv, G, dh), q.dtype)]
+            + [jax.ShapeDtypeStruct(x.shape, x.dtype) for x in tails],
+            # the tails are updated where they lie
+            input_output_aliases={first_tail + i: 1 + i
+                                  for i in range(len(tails))},
             # sequential rows: a row starts its successor's first page
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",)),
             interpret=interpret,
-        )(layer_arr, table, lengths, q.reshape(B, Hkv, G, dh), *pools)
-    return out.reshape(B, H, dh)
+        )(*scalars, q.reshape(B, Hkv, G, dh), *news, *pools, *tails)
+    attended = attended.reshape(B, H, dh)
+    return attended if not tailed else (attended,
+                                        *_unstack(tails, stacked))
 
 
 def _write_kernel(layer_ref, page_ref, off_ref, *refs, n_kv: int,
@@ -472,6 +637,219 @@ def _write_columns(pools, news, table, positions, layer):
     mid = {5: (slice(None), slice(None)), 4: (slice(None),)}
     return [pool.at[(layer, page_ids) + mid[pool.ndim] + (offsets,)].set(new)
             for pool, new in zip(pools, news)]
+
+
+# -- a decode block's tail ----------------------------------------------------
+# A decode program runs `block` steps between two looks at the pool. Its new
+# K and V wait in a tail beside the pool, [L, B, Hkv, T, dh] each (token-major
+# a head, dh on lanes: the read's dots take it as it lies), made inside the
+# program and dead when it returns; `paged_attention_in_block` fills and
+# attends it, a token a step, and `paged_flush_block` puts it into the pages
+# once, when the block is over.
+def holds_request(table):
+    """[B] bool: which rows of a block table hold a request. A row that
+    holds none starts at page 0, the PageAllocator's garbage page, which
+    is never handed out; its position is whatever its last request left,
+    advanced by every step since. The ONE place that reads this fact: the
+    read's lengths and the flush's counts both come from it, so such a row
+    attends nothing and flushes nothing."""
+    return table[:, 0] > 0
+
+
+def block_tail(k_pool, rows: int, block: int, mesh=None):
+    """(k_tail, v_tail): zeros [L, rows, Hkv, T, dh'] in the pool's dtype
+    for a stacked pool [L, P, Hkv, dh, ps]; under a tp mesh sharded on the
+    heads as the pools are. T >= block and dh' >= dh are whole tiles (16
+    tokens, 128 lanes): the kernels copy a row's [Hkv, T, dh'] out of the
+    stack, and a copy's window has to be whole tiles. The padding is never
+    attended and never placed."""
+    L, _, Hkv, dh, _ = k_pool.shape
+    tail = jnp.zeros((L, rows, Hkv, -(-block // 16) * 16,
+                      -(-dh // 128) * 128), k_pool.dtype)
+    if _tp(mesh):
+        from jax.sharding import NamedSharding
+
+        tail = jax.lax.with_sharding_constraint(
+            tail, NamedSharding(mesh, _heads_spec(5, 2)))
+    return tail, tail
+
+
+def tail_put(k_tail, v_tail, k, v, layer, step):
+    """New k, v [B, Hkv, dh] as token `step` of every row of `layer`'s
+    tail, as a plain window update: the reference of the put that
+    `paged_attention_in_block` does in its kernel, and what tests and
+    tools fill a tail with. (Not the serving path: on the chip the window
+    is one sublane row in each of B x Hkv tiles, 11 us a pool a layer
+    inside the decode program: PERF.md, PR 28.)"""
+    def put(tail, new):
+        zero = jnp.zeros((), jnp.int32)
+        return jax.lax.dynamic_update_slice(
+            tail, new.astype(tail.dtype)[None, :, :, None, :],
+            (jnp.asarray(layer, jnp.int32), zero, zero,
+             jnp.asarray(step, jnp.int32), zero))
+
+    return put(k_tail, k), put(v_tail, v)
+
+
+def _flush_kernel(page_ref, row_ref, lane_ref, count_ref, k_tail, v_tail,
+                  k_page, v_page, k_out, v_out):
+    """One grid step = one (layer, item): put the tokens of one row's tail
+    that land in one page at their lanes and write the page back. An item
+    (`_flush_items`) is a (row, page its block reaches) that has tokens to
+    place; its scalars are the page id, the row, the lane of the tail's
+    token 0 in this page's frame (below 0 in a page the block crossed
+    into) and how many tokens the tail holds. The items come first; the
+    steps left over (count 0) name the last item's page and row again, so
+    nothing is copied for them, and leave the page alone.
+
+    The tail's tokens lie [T, dh] a head and a page wants them down its dh
+    sublanes at `count` lanes: the transposed-lhs dot
+    tail[T, dh]^T x selection[T, ps] moves them there on the MXU, exactly
+    (each product is a value times 1.0 or 0.0, accumulated in f32), as
+    `_write_kernel` does for one column."""
+    from jax.experimental import pallas as pl
+
+    item = pl.program_id(1)
+    count, lane0 = count_ref[item], lane_ref[item]
+    n_kv, T = k_tail.shape[1:3]
+    dh, ps = k_out.shape[-2:]
+
+    @pl.when(count > 0)
+    def _place():
+        token = jax.lax.broadcasted_iota(jnp.int32, (T, ps), 0)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (T, ps), 1)
+        selection = jnp.logical_and(lane == lane0 + token, token < count)
+        lanes = jax.lax.broadcasted_iota(jnp.int32, (dh, ps), 1)
+        placed = jnp.logical_and(lanes >= lane0, lanes < lane0 + count)
+        for tail, page, out in ((k_tail, k_page, k_out),
+                                (v_tail, v_page, v_out)):
+            # bf16 products with 1.0 are exact as they are; f32 values need
+            # the full-precision passes or the MXU rounds them to bf16
+            precision = (jax.lax.Precision.HIGHEST
+                         if tail.dtype == jnp.float32 else None)
+            for h in range(n_kv):                         # unrolled heads
+                cols = jax.lax.dot_general(
+                    tail[0, h, :, :dh], selection.astype(tail.dtype),
+                    (((0,), (0,)), ((), ())), precision=precision,
+                    preferred_element_type=jnp.float32)   # [dh, ps]
+                out[0, h] = jnp.where(placed, cols.astype(out.dtype),
+                                      page[0, h])
+
+    # no item at all (no row holds a request): every step names the same
+    # page, which goes back as it came (never unwritten VMEM)
+    @pl.when(jnp.logical_and(count == 0, item == 0))
+    def _keep():
+        k_out[...] = k_page[...]
+        v_out[...] = v_page[...]
+
+
+def _flush_items(table, starts, counts, ps: int, spans: int):
+    """The flush's scalars (see `_flush_kernel`), [B * spans] each: one
+    item a (row, page its block reaches), the items with tokens to place
+    first, in row order, so that consecutive grid steps move consecutive
+    pages and the steps left over move nothing."""
+    B, NP = table.shape
+    span = jnp.arange(spans, dtype=jnp.int32)[None, :]
+    slots = jnp.clip(starts[:, None] // ps + span, 0, NP - 1)
+    pages = jnp.take_along_axis(table, slots, axis=1)      # [B, spans]
+    off = (starts % ps)[:, None]
+    live = jnp.logical_and(counts[:, None] > 0,
+                           off + counts[:, None] > span * ps)
+    rows = jnp.broadcast_to(jnp.arange(B, dtype=jnp.int32)[:, None],
+                            (B, spans))
+    first = jnp.argsort(jnp.logical_not(live).reshape(-1), stable=True)
+    # a step past the last item repeats it, with nothing to place (with no
+    # item at all every step names one page, which `_keep` puts back)
+    n_items, step = jnp.sum(live), jnp.arange(B * spans)
+    at = first[jnp.minimum(step, jnp.maximum(n_items - 1, 0))]
+
+    def of(x):
+        return x.reshape(-1)[at].astype(jnp.int32)
+
+    counts = jnp.broadcast_to(counts[:, None], (B, spans))
+    return (of(pages), of(rows), of(off - span * ps),
+            jnp.where(step < n_items, of(counts), 0))
+
+
+def paged_flush_block(k_pool, v_pool, k_tail, v_tail, table, starts, counts,
+                      *, mesh=None, interpret=None):
+    """Put a decode block's tail into the pages, in place, every layer at
+    once: token i < counts[b] of row b's tail goes to absolute position
+    starts[b] + i, i.e. column (starts[b] + i) % ps of page
+    table[b, (starts[b] + i) // ps]. A row's page is read and written once
+    (once more for each page boundary its block crossed) whatever
+    counts[b] is; a row with counts[b] == 0 moves nothing.
+
+    k/v_pool: [L, P, Hkv, dh, ps]; k/v_tail: [L, B, Hkv, T, dh']
+    (`block_tail`); table: [B, NP]; starts, counts: [B] int32, counts <= T.
+    Returns (k_pool, v_pool). `interpret` as `paged_write_decode` has it:
+    off the TPU, None takes the plain scatter (`_flush_columns`)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    if interpret is None and jax.default_backend() != "tpu":
+        return _flush_columns(k_pool, v_pool, k_tail, v_tail, table, starts,
+                              counts)
+    if _tp(mesh):
+        from jax.sharding import PartitionSpec
+
+        rep = PartitionSpec()
+        heads = _heads_spec(5, 2)
+        return jax.shard_map(
+            functools.partial(paged_flush_block, interpret=interpret),
+            mesh=mesh, in_specs=(heads,) * 4 + (rep,) * 3,
+            out_specs=(heads, heads), check_vma=False)(
+                k_pool, v_pool, k_tail, v_tail, table, starts, counts)
+
+    L, _, Hkv, dh, ps = k_pool.shape
+    B, T = k_tail.shape[1], k_tail.shape[3]
+    spans = (T + ps - 2) // ps + 1    # pages T tokens can reach: 2 at ps=128
+
+    def page_block():
+        return pl.BlockSpec(
+            (None, 1, Hkv, dh, ps),
+            lambda l, i, pages, rows, lanes, counts: (l, pages[i], 0, 0, 0))
+
+    def tail_block():
+        return pl.BlockSpec(
+            (None, 1) + k_tail.shape[2:],
+            lambda l, i, pages, rows, lanes, counts: (l, rows[i], 0, 0, 0))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,  # page ids, rows, first lanes, counts
+        grid=(L, B * spans),
+        in_specs=[tail_block(), tail_block(), page_block(), page_block()],
+        out_specs=[page_block(), page_block()],
+    )
+    with kernel_scope("paged_write"):
+        return tuple(pl.pallas_call(
+            _flush_kernel,
+            grid_spec=grid_spec,
+            out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)
+                       for x in (k_pool, v_pool)],
+            # operand order: 4 scalars, 2 tails, 2 pools -> pool i = out i
+            input_output_aliases={6: 0, 7: 1},
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",) * 2),
+            interpret=bool(interpret),
+        )(*_flush_items(table, starts, counts, ps, spans), k_tail, v_tail,
+          k_pool, v_pool))
+
+
+def _flush_columns(k_pool, v_pool, k_tail, v_tail, table, starts, counts):
+    """The flush as one plain scatter a pool: the kernel's reference, and
+    what runs off the TPU. A token past its row's count is dropped."""
+    P, ps = k_pool.shape[1], k_pool.shape[-1]
+    T, dh = k_tail.shape[3], k_pool.shape[3]
+    positions = starts[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
+    slots = jnp.clip(positions // ps, 0, table.shape[1] - 1)
+    pages = jnp.take_along_axis(table, slots, axis=1)         # [B, T]
+    held = jnp.arange(T)[None, :] < counts[:, None]
+    pages = jnp.where(held, pages, P)                         # P: dropped
+    return tuple(
+        pool.at[:, pages, :, :, positions % ps].set(
+            jnp.transpose(tail[..., :dh], (1, 3, 0, 2, 4)), mode="drop")
+        for pool, tail in ((k_pool, k_tail), (v_pool, v_tail)))
 
 
 def _unstack(pools, stacked: bool):

@@ -3017,6 +3017,7 @@ class LLMEngine:
         # slot lengths are pre-demux here: the live context this dispatch
         # actually read each step (the MBU KV term)
         live = [(i, r) for i, r in snapshot if self.slots[i].request is r]
+        page_writes = self._note_page_writes(live, block)
         self.util.record_decode(
             rows=len(snapshot), steps=block,
             kv_tokens=sum(self.slots[i].length for i, r in live),
@@ -3068,7 +3069,8 @@ class LLMEngine:
         # TPOT histogram ONCE per sync, not per token (VERDICT r2 weak #9)
         self.steps.note_sync(
             "decode", tokens=emitted,
-            slowest_request_id=slowest.id if slowest else None)
+            slowest_request_id=slowest.id if slowest else None,
+            page_writes=page_writes)
         self._obs.hist_n(
             "app_tpu_tpot_seconds", step_s, emitted,
             exemplar=(self._exemplar_of(slowest) if slowest else None))
@@ -3077,6 +3079,10 @@ class LLMEngine:
 
     def _note_model_counts(self, tokens_host, block: int) -> None:
         """The dense engine's model counts nothing."""
+
+    def _note_page_writes(self, live, block: int) -> int:
+        """The dense engine has no pages to write."""
+        return 0
 
     def _fail_request(self, request: GenerationRequest,
                       exc: Optional[BaseException] = None) -> None:

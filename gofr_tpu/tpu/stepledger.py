@@ -142,8 +142,8 @@ class StepRecord:
     __slots__ = ("seq", "started_at", "wall_s", "idle_gap_s", "phase",
                  "segments", "segments_cpu", "cpu_s", "active_slots",
                  "inflight", "queue_depth",
-                 "tokens", "dispatches", "slowest_request_id", "straggler",
-                 "cause", "baseline_s")
+                 "tokens", "page_writes", "dispatches", "slowest_request_id",
+                 "straggler", "cause", "baseline_s")
 
     def __init__(self, seq: int, started_at: float, wall_s: float,
                  idle_gap_s: float, phase: str,
@@ -165,6 +165,10 @@ class StepRecord:
         self.inflight = 0
         self.queue_depth = 0
         self.tokens = 0
+        # pages the synced decode block's flush wrote (the paged engine's
+        # floating-point pools: one a live row a block, two where the
+        # block crossed a page; 0 where no block was synced)
+        self.page_writes = 0
         self.dispatches: Dict[str, int] = {}
         self.slowest_request_id: Optional[int] = None
         self.straggler = False
@@ -192,6 +196,8 @@ class StepRecord:
             "queue_depth": self.queue_depth,
             "tokens": self.tokens,
         }
+        if self.page_writes:
+            out["page_writes"] = self.page_writes
         if self.dispatches:
             out["dispatches"] = dict(self.dispatches)
         if self.slowest_request_id is not None:
@@ -305,6 +311,7 @@ class StepLedger:
         self._dispatches: Dict[str, int] = {}
         self._sync_kind: Optional[str] = None
         self._tokens = 0
+        self._page_writes = 0
         self._slowest: Optional[int] = None
 
     # -- wiring ---------------------------------------------------------------
@@ -376,6 +383,7 @@ class StepLedger:
         self._dispatches = {}
         self._sync_kind = None
         self._tokens = 0
+        self._page_writes = 0
         self._slowest = None
 
     class _Seg:
@@ -469,10 +477,12 @@ class StepLedger:
 
     @loop_only
     def note_sync(self, kind: str, tokens: int = 0,
-                  slowest_request_id: Optional[int] = None) -> None:
+                  slowest_request_id: Optional[int] = None,
+                  page_writes: int = 0) -> None:
         if self._mine():
             self._sync_kind = kind
             self._tokens += int(tokens)
+            self._page_writes += int(page_writes)
             if slowest_request_id is not None:
                 self._slowest = slowest_request_id
 
@@ -543,6 +553,7 @@ class StepLedger:
         rec.inflight = int(inflight)
         rec.queue_depth = int(queue_depth)
         rec.tokens = self._tokens
+        rec.page_writes = self._page_writes
         rec.dispatches = dict(self._dispatches)
         rec.slowest_request_id = self._slowest
         with self.between("step_close"):

@@ -542,6 +542,9 @@ def engine_snapshot(engine, tpu=None) -> Dict[str, Any]:
             except Exception:  # noqa: BLE001
                 pass
 
+    if hasattr(engine, "paging_snapshot"):
+        # how many decode tokens each page write places (the block's tail)
+        out["paging"] = engine.paging_snapshot()
     if hasattr(engine, "model_snapshot"):
         # the model family: what it holds beside the pools, its routing
         out["model"] = engine.model_snapshot()

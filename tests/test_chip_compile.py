@@ -29,7 +29,10 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
 from gofr_tpu.models.llama import LlamaConfig, llama_init
 from gofr_tpu.ops.decode_attention import decode_attention
 from gofr_tpu.ops.flash_attention import flash_attention
-from gofr_tpu.ops.paged_attention import paged_attention, paged_write_decode
+from gofr_tpu.ops.paged_attention import (block_tail, paged_attention,
+                                          paged_attention_in_block,
+                                          paged_flush_block,
+                                          paged_write_decode)
 from gofr_tpu.parallel.sharding import (kv_cache_layer_spec, kv_cache_spec,
                                         kv_scale_pool_spec,
                                         serving_param_specs)
@@ -152,6 +155,58 @@ def test_paged_write_decode_compiles_in_place(topo, preset, dtype):
     assert mem.temp_size_in_bytes < pool_bytes // 100
 
 
+def _tails(chips, pool, rows, block):
+    """A decode block's (k_tail, v_tail) shapes for `pool`, heads over tp."""
+    like = jax.eval_shape(lambda: block_tail(
+        jax.ShapeDtypeStruct(pool.shape, pool.dtype), rows, block))
+    return tuple(chips.shape(x.shape, x.dtype, kv_cache_spec()) for x in like)
+
+
+# a full block, the half block the engine takes while requests wait, and the
+# block of one token: the tail is padded to whole tiles for each
+@pytest.mark.parametrize("block", [16, 8, 1])
+@pytest.mark.parametrize("preset", list(WIDTHS))
+def test_block_tail_kernels_compile_in_place(topo, preset, block):
+    """The decode path of the floating-point pools: the read that puts
+    the step's token into the block's tail (in place) and attends pages
+    and tail, and the flush that writes each row's page once a block,
+    where the pools lie (llama1b's heads of 64 pad the tail's lanes to
+    128: a copy's window is whole tiles)."""
+    H, Hkv, dh = WIDTHS[preset]
+    chips = Chips(topo, 1)
+    B, NP = 64, 8
+    q = chips.shape((B, H, dh), jnp.bfloat16)
+    new = chips.shape((B, Hkv, dh), jnp.bfloat16)
+    pools = _pools(chips, Hkv, dh, jnp.bfloat16)
+    tails = _tails(chips, pools[0], B, block)
+    table = chips.shape((B, NP), jnp.int32)
+    rows = chips.shape((B,), jnp.int32)
+
+    def read(k_tail, v_tail, q, new, k_pool, v_pool, table, lengths,
+             tail_lens):
+        return paged_attention_in_block(
+            q, new, new, k_pool, v_pool, k_tail, v_tail, table, lengths,
+            tail_lens, layer=jnp.int32(1), interpret=False)
+
+    compiled = _compile(read, *tails, q, new, *pools, table, rows, rows,
+                        donate=(0, 1))
+    assert _kernels(compiled) == 1
+    tail_bytes = sum(np.prod(t.shape) * t.dtype.itemsize for t in tails)
+    assert compiled.memory_analysis().alias_size_in_bytes == tail_bytes
+
+    def flush(k_pool, v_pool, k_tail, v_tail, table, starts, counts):
+        return paged_flush_block(k_pool, v_pool, k_tail, v_tail, table,
+                                 starts, counts, interpret=False)
+
+    compiled = _compile(flush, *pools, *tails, table, rows, rows,
+                        donate=(0, 1))
+    assert _kernels(compiled) == 1
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(np.prod(p.shape) * p.dtype.itemsize for p in pools)
+    assert mem.alias_size_in_bytes == pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes // 100
+
+
 # the smoke's prefill buckets at llama1b widths; the largest at llama3-8b
 @pytest.mark.parametrize("preset,T", [("llama1b", 16), ("llama1b", 32),
                                       ("llama1b", 64), ("llama1b", 128),
@@ -240,6 +295,36 @@ def test_paged_kernels_compile_sharded_over_tp(topo, preset, dtype):
                         donate=tuple(range(n)))
     assert _kernels(compiled) == 2
     # heads are independent: no collective inside either kernel's shard_map
+    assert "all-reduce(" not in compiled.as_text()
+
+
+@pytest.mark.parametrize("preset", ["llama1b", "internlm2"])
+def test_block_tail_kernels_compile_sharded_over_tp(topo, preset):
+    """The same mesh for the decode block's tail: it follows the pools'
+    head sharding, and the read over pages and tail and the flush run per
+    shard with no collective."""
+    H, Hkv, dh = WIDTHS[preset]
+    chips = Chips(topo, 4)
+    B, NP = 64, 8
+    q = chips.shape((B, H, dh), jnp.bfloat16, P(None, "tp", None))
+    new = chips.shape((B, Hkv, dh), jnp.bfloat16, P(None, "tp", None))
+    pools = _pools(chips, Hkv, dh, jnp.bfloat16)
+    tails = _tails(chips, pools[0], B, 16)
+    table = chips.shape((B, NP), jnp.int32)
+    rows = chips.shape((B,), jnp.int32)
+
+    def step(k_pool, v_pool, k_tail, v_tail, table, starts, q, new):
+        out, k_tail, v_tail = paged_attention_in_block(
+            q, new, new, k_pool, v_pool, k_tail, v_tail, table, starts,
+            starts % 16 + 1, layer=jnp.int32(1), mesh=chips.mesh,
+            interpret=False)
+        return out, paged_flush_block(
+            k_pool, v_pool, k_tail, v_tail, table, starts,
+            jnp.full_like(starts, 16), mesh=chips.mesh, interpret=False)
+
+    compiled = _compile(step, *pools, *tails, table, rows, q, new,
+                        donate=(0, 1))
+    assert _kernels(compiled) == 2
     assert "all-reduce(" not in compiled.as_text()
 
 
@@ -406,9 +491,11 @@ def test_step_programs_name_their_module_and_kernels(topo, as_tpu, program):
     module carries the table width and block, and each Pallas kernel's
     instruction is named after its scope, bare (the `closed_call_` prefix
     went in PR 27: the benchmark's readers select by name), the page write
-    is still the custom-call that returns the two pools, and the paged read
-    is one custom-call in the layer loop's body with one array as its
-    result."""
+    (since PR 28 the flush of the block's tail, once a program, outside
+    every loop) is still the custom-call that returns the two pools, and
+    the paged read is one custom-call in the layer loop's body (since PR
+    28 it returns the block's tail beside the attention: the step's token
+    is put there by the read itself)."""
     from gofr_tpu.tpu.executor import _named_after
     from gofr_tpu.tpu.paging import PagedLLMEngine
 
@@ -428,13 +515,13 @@ def test_step_programs_name_their_module_and_kernels(topo, as_tpu, program):
             tokens, positions, temps, rng, donate=(1, 2))
         assert "HloModule jit_decode__x8_NP4," in compiled.as_text()
         calls = sorted(_kernel_calls(compiled))
-        # what benchmark/harness/tracered.kernels relies on: ONE read a
-        # layer-loop body, whose result is one array (a tuple result is
-        # counted as the page write)
-        assert [(name.rsplit(".", 1)[0], tupled)
-                for name, tupled, _ in calls] == [
-            ("paged_read", False), ("paged_write", True)]
+        # what benchmark/harness/readers relies on: ONE read a layer-loop
+        # body a step (its calls count the steps), both found by name
+        assert [name.rsplit(".", 1)[0] for name, _, _ in calls] == [
+            "paged_read", "paged_write"]
         assert _computation_of(compiled, calls[0][0]) in _while_bodies(
+            compiled)
+        assert _computation_of(compiled, calls[1][0]) not in _while_bodies(
             compiled)
     else:
         K, bucket = 1, 256

@@ -27,7 +27,8 @@ from gofr_tpu.models.nemotron_h import (NemotronHConfig, REFUSES,  # noqa: E402
                                         prefill, state_shapes)
 from gofr_tpu.ops.moe_experts import (decode_experts, experts_reference,  # noqa: E402
                                       prefill_experts)
-from gofr_tpu.ops.paged_attention import paged_write_prefill_stacked  # noqa: E402
+from gofr_tpu.ops.paged_attention import (block_tail, paged_flush_block,  # noqa: E402
+                                          paged_write_prefill_stacked)
 from gofr_tpu.ops.ssm_update import ssm_update, ssm_update_reference  # noqa: E402
 from gofr_tpu.tpu.paging import PagedLLMEngine  # noqa: E402
 
@@ -72,10 +73,16 @@ def _reference_logits(params, dims, tokens):
 class Served:
     """Pools, a block table and per-slot state as the engine holds them,
     driven by the model's two functions directly so that LOGITS can be
-    compared (the engine hands out tokens only). Dead slots hold junk."""
+    compared (the engine hands out tokens only). Dead slots hold junk.
+    Decode runs in blocks of BLOCK steps as the engine's program does: the
+    new K and V wait in the block's tail and reach the pages when the
+    block is over, or when an admission needs the pool."""
+
+    BLOCK = 5       # a block ends inside a page, at its edge and across it
 
     def __init__(self, cfg, params, slots=4, page=16, pages_a_slot=4):
         self.cfg, self.params, self.page = cfg, params, page
+        self.kv_tail, self.at = None, 0
         (s1, d1), (s2, d2) = state_shapes(cfg, slots)
         self.state = (jnp.full(s1, 7.0, d1), jnp.full(s2, 3.0, d2))
         n_pages = slots * pages_a_slot + 1
@@ -87,11 +94,24 @@ class Served:
                     for s in range(slots)}
         self.pos = np.zeros((slots,), np.int32)
         self._prefill = jax.jit(lambda p, t, n: prefill(p, cfg, t, n))
-        self._step = jax.jit(lambda p, t, pos, k, v, tb, st: decode_step(
-            p, cfg, t, pos, k, v, tb, st))
+        self._step = jax.jit(lambda p, t, pos, k, v, tb, st, tail, at:
+                             decode_step(p, cfg, t, pos, k, v, tb, st, tail,
+                                         at))
+
+    def flush(self):
+        """End the open block: its tail into the pages of the rows that
+        held a request when it began."""
+        if self.kv_tail is not None:
+            table, began = self._block
+            self.k, self.v = paged_flush_block(
+                self.k, self.v, *self.kv_tail, jnp.asarray(table),
+                jnp.asarray(began),
+                jnp.where(jnp.asarray(table[:, 0] > 0), self.at, 0))
+            self.kv_tail, self.at = None, 0
 
     def admit(self, rows, bucket):
         """rows: {slot: prompt}. Returns {slot: last-position logits}."""
+        self.flush()
         slots = sorted(rows)
         window = np.zeros((len(slots), bucket), np.int32)
         for i, s in enumerate(slots):
@@ -112,6 +132,7 @@ class Served:
         return {s: np.asarray(last[i]) for i, s in enumerate(slots)}
 
     def retire(self, slot):
+        self.flush()
         self.table[slot] = 0
 
     def step(self, tokens):
@@ -119,11 +140,18 @@ class Served:
         fed = np.zeros_like(self.pos)
         for s, t in tokens.items():
             fed[s] = t
+        if self.kv_tail is None:
+            self.kv_tail = block_tail(self.k, len(self.pos), self.BLOCK)
+            self._block = (self.table.copy(), self.pos.copy())
         with jax.default_matmul_precision("highest"):
-            logits, self.k, self.v, self.state, counted = self._step(
+            logits, self.kv_tail, self.state, counted = self._step(
                 self.params, jnp.asarray(fed), jnp.asarray(self.pos), self.k,
-                self.v, jnp.asarray(self.table), self.state)
+                self.v, jnp.asarray(self._block[0]), self.state,
+                self.kv_tail, jnp.int32(self.at))
         self.pos = self.pos + 1
+        self.at += 1
+        if self.at == self.BLOCK:
+            self.flush()
         return {s: np.asarray(logits[s]) for s in tokens}, np.asarray(counted)
 
 

@@ -15,10 +15,13 @@ import numpy as np
 import pytest
 
 from gofr_tpu.models.llama import LlamaConfig, llama_init
-from gofr_tpu.ops.paged_attention import (paged_attention,
+from gofr_tpu.ops.paged_attention import (_write_columns, block_tail,
+                                          paged_attention,
+                                          paged_attention_in_block,
                                           paged_attention_reference,
+                                          paged_flush_block,
                                           paged_write_decode,
-                                          paged_write_prefill)
+                                          paged_write_prefill, tail_put)
 from gofr_tpu.tpu.engine import LLMEngine
 from gofr_tpu.tpu.paging import PageAllocator, PagedLLMEngine
 
@@ -120,9 +123,9 @@ def test_paged_attention_reads_live_pages_only(geometry):
 def test_decode_step_row_without_request_attends_nothing():
     """An idle slot's row of the table is zeros (the garbage page) and its
     position is stale and still advancing: the step hands the read a
-    length of 0 for it, so it walks no page — here the garbage page is
-    NaN but for the columns the step itself writes, and the idle row's
-    stale position lies far past the table."""
+    length of 0 for it, in pages and in the block's tail, so it walks no
+    page — here the garbage page is NaN, and the idle row's stale position
+    lies far past the table."""
     from gofr_tpu.models.llama import llama_decode_step_paged
 
     params = llama_init(CFG, seed=0)
@@ -136,12 +139,204 @@ def test_decode_step_row_without_request_attends_nothing():
     tokens = jnp.asarray([5, 6, 7], dtype=jnp.int32)
     positions = jnp.asarray([11, 10_000, 3], dtype=jnp.int32)
     step = jax.jit(lambda k, v: llama_decode_step_paged(
-        params, CFG, tokens, positions, k, v, table)[0])
+        params, CFG, tokens, positions, k, v, table,
+        block_tail(k, 3, 4), jnp.int32(0))[0])
     logits = np.asarray(step(poisoned, poisoned))
     assert np.isfinite(logits).all()
     np.testing.assert_allclose(logits[[0, 2]],
                                np.asarray(step(pool, pool))[[0, 2]],
                                rtol=1e-5, atol=1e-5)
+
+
+# -- a decode block's tail ----------------------------------------------------
+# Pages of 128 tokens as the chip serves them. Rows: a block that starts at
+# lane 0 of a fresh page, one at lane 120 (it crosses into the next page
+# after 8 tokens), one inside a page, a row that holds no request (length
+# 0, its table row kept real so that "untouched" can be seen), a row whose
+# pages are still empty, and one on its fourth page.
+TAIL_PS = 128
+TAIL_STARTS = [TAIL_PS, 120, 37, 0, 0, 3 * TAIL_PS + 77]
+TAIL_IDLE = 3
+TAIL_GEOMETRY = {"Hkv8": (16, 8, 16), "Hkv2": (8, 2, 32)}   # H, Hkv, dh
+
+
+def _tail_case(geometry, dtype, block, seed=0, layers=2):
+    """Stacked pools holding each row's context, the table, the block's
+    new K and V [block, L, B, Hkv, dh] and the starts."""
+    H, Hkv, dh = TAIL_GEOMETRY[geometry]
+    rng = np.random.default_rng(seed)
+    B, n_table, n_pool_pages = len(TAIL_STARTS), 5, 40
+    k_pool, v_pool = (jnp.asarray(rng.normal(
+        size=(layers, n_pool_pages, Hkv, dh, TAIL_PS)), dtype=dtype)
+        for _ in range(2))
+    free = iter(rng.permutation(np.arange(1, n_pool_pages)))
+    table = np.zeros((B, n_table), np.int32)
+    for b, start in enumerate(TAIL_STARTS):
+        for i in range((start + block - 1) // TAIL_PS + 1):
+            table[b, i] = next(free)
+    news = [jnp.asarray(rng.normal(size=(block, layers, B, Hkv, dh)),
+                        dtype=dtype) for _ in range(2)]
+    q = jnp.asarray(rng.normal(size=(B, H, dh)), dtype=dtype)
+    live = np.arange(B) != TAIL_IDLE
+    return (q, k_pool, v_pool, jnp.asarray(table), news,
+            jnp.asarray(TAIL_STARTS, jnp.int32), jnp.asarray(live))
+
+
+def _written_by_columns(k_pool, v_pool, news, table, starts, live, steps):
+    """The pools after `steps` per-token column writes of the live rows
+    (an idle row's go to page 0 of a table row of zeros, as the engine's
+    do): what the parent's decode write left."""
+    table = jnp.where(live[:, None], table, 0)
+    for t in range(steps):
+        for layer in range(k_pool.shape[0]):
+            k_pool, v_pool = _write_columns(
+                [k_pool, v_pool], [news[0][t, layer], news[1][t, layer]],
+                table, starts + t, layer)
+    return k_pool, v_pool
+
+
+def _tail_of(k_pool, news, steps, block):
+    tail = block_tail(k_pool, news[0].shape[2], block)
+    for t in range(steps):
+        for layer in range(k_pool.shape[0]):
+            tail = tail_put(*tail, news[0][t, layer], news[1][t, layer],
+                            layer, t)
+    return tail
+
+
+_read_in_block = jax.jit(paged_attention_in_block)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("geometry", list(TAIL_GEOMETRY))
+@pytest.mark.parametrize("t", [0, 7, 15])
+def test_paged_attention_over_pages_and_tail_matches_reference(t, geometry,
+                                                               dtype):
+    """Step t of a block of 16: the step's token put into a tail that
+    holds t, and the read over the pages as the block found them plus the
+    tail's first t + 1 tokens, against the plain put and the reference on
+    a pool that had the same tokens written column by column."""
+    q, k_pool, v_pool, table, news, starts, live = _tail_case(
+        geometry, dtype, 16)
+    k_ref, v_ref = _written_by_columns(k_pool, v_pool, news, table, starts,
+                                       live, t + 1)
+    layer = k_pool.shape[0] - 1
+    ref = paged_attention_reference(
+        q.astype(jnp.float32), k_ref[layer], v_ref[layer], table,
+        jnp.where(live, starts + t + 1, 0))
+    out, k_tail, v_tail = _read_in_block(
+        q, news[0][t, layer], news[1][t, layer], k_pool, v_pool,
+        *_tail_of(k_pool, news, t, 16), table, jnp.where(live, starts, 0),
+        jnp.where(live, t + 1, 0), layer=jnp.int32(layer))
+    assert out.dtype == dtype
+    tol = 2e-5 if dtype == jnp.float32 else 3e-2
+    np.testing.assert_allclose(np.asarray(out, dtype=np.float32),
+                               np.asarray(ref), rtol=tol, atol=tol)
+    assert not np.asarray(out, dtype=np.float32)[TAIL_IDLE].any()
+    # the tail it returns: the plain put's in this layer's live rows, the
+    # other layers and the row without a request as they were
+    want = _tail_of(k_pool, news, t + 1, 16)
+    before = _tail_of(k_pool, news, t, 16)
+    live = np.asarray(live)
+    for got, put, was in zip((k_tail, v_tail), want, before):
+        got, put, was = np.asarray(got), np.asarray(put), np.asarray(was)
+        np.testing.assert_array_equal(got[layer][live], put[layer][live])
+        np.testing.assert_array_equal(got[layer][~live], was[layer][~live])
+        np.testing.assert_array_equal(got[:layer], was[:layer])
+
+
+def test_paged_attention_reads_no_tail_of_a_row_without_request():
+    """The idle row's tail is NaN, and so is every key past a live row's
+    count: neither is attended. (A value past the count meets a
+    probability of 0.0: `block_tail` makes it zero and nothing else writes
+    there.)"""
+    q, k_pool, v_pool, table, news, starts, live = _tail_case(
+        "Hkv2", jnp.float32, 16)
+    k_tail, v_tail = _tail_of(k_pool, news, 3, 16)
+    args = (q, news[0][3, 0], news[1][3, 0], k_pool, v_pool)
+    rest = (table, jnp.where(live, starts, 0), jnp.where(live, 4, 0))
+    want = _read_in_block(*args, k_tail, v_tail, *rest, layer=jnp.int32(0))[0]
+    idle = ~live[None, :, None, None, None]
+    unheld = jnp.arange(16)[None, None, None, :, None] >= 4
+    got = _read_in_block(
+        *args, jnp.where(jnp.logical_or(idle, unheld), jnp.nan, k_tail),
+        jnp.where(idle, jnp.nan, v_tail), *rest, layer=jnp.int32(0))[0]
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("path", ["kernel", "plain"])
+@pytest.mark.parametrize("dtype,tp", [("float32", 1), ("bfloat16", 1),
+                                      ("bfloat16", 2)])
+@pytest.mark.parametrize("block", [1, 8, 16])
+def test_paged_flush_equals_the_column_writes(block, dtype, tp, path):
+    """One flush of a block's tail (the Pallas kernel interpreted: what
+    the chip runs; the plain scatter: what the CPU runs) against `block`
+    per-token column writes: the same pools, exactly, every layer, the row
+    that crosses a page written in both, the idle row's page untouched —
+    one device and heads sharded over a tp mesh."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    q, k_pool, v_pool, table, news, starts, live = _tail_case(
+        "Hkv2", dtype, block, seed=5)
+    want = _written_by_columns(k_pool, v_pool, news, table, starts, live,
+                               block)
+    tail = _tail_of(k_pool, news, block, block)
+    mesh = None
+    if tp > 1:
+        mesh = Mesh(np.array(jax.devices()[:tp]), ("tp",))
+        heads = NamedSharding(mesh, PartitionSpec(None, None, "tp"))
+        k_pool, v_pool, *tail = (jax.device_put(x, heads) for x in
+                                 (k_pool, v_pool, *tail))
+    got = jax.jit(lambda k, v, kt, vt: paged_flush_block(
+        k, v, kt, vt, table, starts, jnp.where(live, block, 0), mesh=mesh,
+        interpret=True if path == "kernel" else None))(k_pool, v_pool, *tail)
+    crossing = np.asarray(table)[1, :2]
+    for g, w, before in zip(got, want, (k_pool, v_pool)):
+        g, w, before = np.asarray(g), np.asarray(w), np.asarray(before)
+        np.testing.assert_array_equal(g[:, 1:], w[:, 1:])  # 0: the garbage
+        idle = np.asarray(table)[TAIL_IDLE, 0]
+        np.testing.assert_array_equal(g[:, idle], before[:, idle])
+        assert not np.array_equal(g[:, crossing[0]], before[:, crossing[0]])
+        assert (block <= 8) == np.array_equal(g[:, crossing[1]],
+                                              before[:, crossing[1]])
+
+
+@pytest.mark.parametrize("path", ["kernel", "plain"])
+def test_paged_flush_reaches_every_page_a_long_block_crosses(path):
+    """Pages of 8 tokens and a block of 16 from lane 7: three pages of one
+    row, each written once."""
+    rng = np.random.default_rng(2)
+    L, P, Hkv, dh, ps, B = 2, 9, 2, 16, 8, 2
+    k_pool, v_pool = (jnp.asarray(rng.normal(size=(L, P, Hkv, dh, ps)),
+                                  jnp.float32) for _ in range(2))
+    news = [jnp.asarray(rng.normal(size=(16, L, B, Hkv, dh)), jnp.float32)
+            for _ in range(2)]
+    table = jnp.asarray([[1, 2, 3, 0], [4, 5, 6, 7]], jnp.int32)
+    starts, live = jnp.asarray([7, 8], jnp.int32), jnp.asarray([True, True])
+    want = _written_by_columns(k_pool, v_pool, news, table, starts, live, 16)
+    got = paged_flush_block(
+        k_pool, v_pool, *_tail_of(k_pool, news, 16, 16), table, starts,
+        jnp.full((B,), 16, jnp.int32),
+        interpret=True if path == "kernel" else None)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g)[:, 1:],
+                                      np.asarray(w)[:, 1:])
+    assert not np.array_equal(np.asarray(got[0])[:, 3],
+                              np.asarray(k_pool)[:, 3])
+
+
+def test_paged_flush_with_no_live_row_changes_nothing():
+    """Every step of the flush names the garbage page then, and the page
+    goes back as it came."""
+    q, k_pool, v_pool, table, news, starts, live = _tail_case(
+        "Hkv2", jnp.float32, 8)
+    tail = _tail_of(k_pool, news, 8, 8)
+    for interpret in (True, None):
+        got = paged_flush_block(k_pool, v_pool, *tail, table, starts,
+                                jnp.zeros_like(starts), interpret=interpret)
+        np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(k_pool))
+        np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(v_pool))
 
 
 def test_paged_writes_round_trip():
@@ -277,6 +472,49 @@ def test_paged_engine_matches_dense_engine():
     finally:
         paged.stop()
     assert got == want
+
+
+@pytest.mark.parametrize("block", [1, 4, 16])
+def test_every_decode_block_size_serves_the_dense_engines_tokens(block):
+    """The block's tail is how the decode write works at every block
+    size: pages of 8 tokens, so a block of 16 crosses two boundaries and a
+    block of 1 flushes one column; the tokens are the dense engine's, and
+    `/debug/engine` and the step ledger say how many tokens a page write
+    placed."""
+    from gofr_tpu.tpu.utilization import engine_snapshot
+
+    params = llama_init(CFG, seed=0)
+    prompts = [[5, 6, 7], [9, 10, 11, 12, 13, 14, 15, 16, 17], [1, 2]]
+    dense = LLMEngine(params, CFG, n_slots=4, max_seq_len=64,
+                      prefill_buckets=(8, 16), logger=MockLogger())
+    dense.start()
+    try:
+        want = [dense.generate(p, max_new_tokens=20, temperature=0.0)
+                for p in prompts]
+    finally:
+        dense.stop()
+    paged = _make_paged(decode_block_size=block)
+    try:
+        requests = [paged.submit(p, max_new_tokens=20, temperature=0.0)
+                    for p in prompts]
+        got = [r.result(timeout_s=300) for r in requests]
+        assert engine_snapshot(paged)["paging"]["write"]["page_writes"] > 0
+    finally:
+        paged.stop()
+    # read once the loop has stopped: the last block's record is closed
+    write = paged.paging_snapshot()["write"]
+    records = paged.steps.records(recent=256)
+    assert got == want
+    assert write["tokens"] >= 3 * 19 and write["page_writes"] > 0
+    assert write["tokens_per_page_write"] == round(
+        write["tokens"] / write["page_writes"], 3)
+    # a block of b tokens over pages of 8 is written in 1 + (b - 1) // 8
+    # pages, or one more where it started late in its page
+    fewest = 1 + (block - 1) // 8
+    assert (block / (fewest + 1) - 1e-3 <= write["tokens_per_page_write"]
+            <= block / fewest + 1e-3)
+    assert sum(r.page_writes for r in records) == write["page_writes"]
+    assert all(r.page_writes == 0 for r in records if r.phase != "decode")
 
 
 def test_paged_engine_concurrent_mixed_lengths():

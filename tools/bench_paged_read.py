@@ -1,4 +1,4 @@
-"""The paged read kernel alone, at the benchmark cells' shapes, on the chip.
+"""The paged kernels alone, at the benchmark cells' shapes, on the chip.
 
     chiprun -- python tools/bench_paged_read.py [label=path/to/paged_attention.py ...]
 
@@ -12,6 +12,18 @@ of about 480 tokens, as `decode-closed` holds) and `chat` (50 live rows and
 24-layer loop x 8), the share of the device's peak bytes/s over the WHOLE
 live pages, and the largest error against `paged_attention_reference` on
 one layer.
+
+Then the decode block's tail (PR 28), for every module that has one, at
+two geometries (`internlm2`: 8 KV heads x 2 queries, 24 layers; `nemotron`:
+2 KV heads x 16 queries, 2 layers of attention) under the `closed` table,
+block 16: `read+tail` (`paged_attention_in_block`: the step's token put
+into the tail and the read over pages and tail, a call, the mean over the
+block's 16 steps), `tail_put` (the put as a plain window update alone: the
+reference, not the serving path), `flush` (one flush of all layers, a
+layer) and, for comparison, `write` (the per-token page write a call,
+which the int8 pools and the verify window still run). `read+tail` is
+checked against the reference on a layer that had the tail's tokens
+written column by column, `flush` against those columns, exactly.
 
 It is not the benchmark: it says what a kernel costs alone, never what a
 cell gains (PERF.md section 5 keeps its table). It refuses a device that
@@ -49,8 +61,9 @@ def load(label: str, path: str):
     return module
 
 
-def table_of(name: str, rng):
-    """(table [B, NP], lengths [B], whole live pages)."""
+def table_of(name: str, rng, room: int = 0, n_pool_pages: int = P):
+    """(table [B, NP], lengths [B], whole live pages). `room`: tokens each
+    live row's pages must still take past its length (a decode block)."""
     if name == "closed":
         lengths = rng.integers(60, 900, size=B)
         lengths[5] = 1152
@@ -62,9 +75,10 @@ def table_of(name: str, rng):
         lengths[live[0]] = 1100
     n_pages = -(-lengths // PS)
     table = np.zeros((B, NP), np.int32)
-    free = list(rng.permutation(np.arange(1, P)))
+    free = list(rng.permutation(np.arange(1, n_pool_pages)))
     for b in np.flatnonzero(lengths > 1):
-        table[b, :n_pages[b]] = [free.pop() for _ in range(n_pages[b])]
+        held = -(-(lengths[b] + room) // PS)
+        table[b, :held] = [free.pop() for _ in range(held)]
     return (jnp.asarray(table), jnp.asarray(lengths, jnp.int32),
             int(n_pages.sum()))
 
@@ -86,6 +100,25 @@ def pools_of(dtype):
     return make(k1), make(k2), scales
 
 
+def best_of_five(fn, *args, donated=()) -> float:
+    """Seconds a run, the best of five after one that compiles. `donated`:
+    positions of arguments the run consumes and returns first."""
+    def run(args):
+        out = fn(*args)
+        jax.block_until_ready(out)
+        for i, x in zip(donated, out):
+            args[i] = x
+        return args
+
+    args = run(list(args))
+    best = float("inf")
+    for _ in range(5):
+        start = time.perf_counter()
+        args = run(args)
+        best = min(best, time.perf_counter() - start)
+    return best
+
+
 def time_one(module, args) -> float:
     """Microseconds a call, from the best of five 24-layer loops x STEPS."""
     def loop(q, *rest):
@@ -97,14 +130,128 @@ def time_one(module, args) -> float:
             0, STEPS, lambda _, acc: jax.lax.fori_loop(0, L, layer, acc),
             jnp.zeros(q.shape, jnp.float32))
 
-    fn = jax.jit(loop)
-    fn(*args).block_until_ready()
-    best = float("inf")
-    for _ in range(5):
-        start = time.perf_counter()
-        fn(*args).block_until_ready()
-        best = min(best, time.perf_counter() - start)
-    return best / (L * STEPS) * 1e6
+    return best_of_five(jax.jit(loop), *args) / (L * STEPS) * 1e6
+
+
+# -- the decode block's tail ----------------------------------------------------
+BLOCK = 16
+GEOMETRIES = {"internlm2": (24, 769, 8, 16), "nemotron": (2, 961, 2, 32)}
+
+
+def tail_lines(label: str, module, geometry: str, device) -> None:
+    """The block's tail at one geometry (layers, pages, KV heads, heads):
+    one JSON line a measurement (see the module docstring)."""
+    layers, pages, n_kv, heads = GEOMETRIES[geometry]
+    keys = jax.random.split(jax.random.PRNGKey(2), 5)
+    draw = lambda k, shape: jax.random.normal(           # noqa: E731
+        k, shape, jnp.float32).astype(jnp.bfloat16)
+    one = [draw(k, (pages, n_kv, DH, PS)) for k in keys[:2]]
+    stack = jax.jit(lambda x: jnp.tile(x[None], (layers, 1, 1, 1, 1)))
+    k_pool, v_pool = stack(one[0]), stack(one[1])
+    q = draw(keys[2], (B, heads, DH))
+    news = [draw(k, (BLOCK, B, n_kv, DH)) for k in keys[3:]]
+    table, starts, live_pages = table_of("closed", np.random.default_rng(1),
+                                         room=BLOCK, n_pool_pages=pages)
+    live = table[:, 0] > 0
+    paged = jnp.where(live, starts, 0)
+    counts = jnp.where(live, BLOCK, 0)
+
+    def line(what: str, us: float, **more) -> None:
+        print(json.dumps({
+            "device": device.device_kind, "kernel": label, "what": what,
+            "geometry": geometry, "rows": int(live.sum()),
+            "live_pages": live_pages, "block": BLOCK,
+            "us": round(us, 1), **more}), flush=True)
+
+    def filled(k_tail, v_tail, layer, tokens=BLOCK):
+        for t in range(tokens):
+            k_tail, v_tail = module.tail_put(k_tail, v_tail, news[0][t],
+                                             news[1][t], layer, t)
+        return k_tail, v_tail
+
+    def steps(read: bool):
+        """BLOCK steps x `layers` of the read that puts, or of the plain
+        put alone."""
+        def run(q, k_pool, v_pool):
+            def layer(l, carry):
+                t, acc, k_tail, v_tail = carry
+                if read:
+                    out, k_tail, v_tail = module.paged_attention_in_block(
+                        q, news[0][t], news[1][t], k_pool, v_pool, k_tail,
+                        v_tail, table, paged, jnp.where(live, t + 1, 0),
+                        layer=l)
+                    acc = acc + out.astype(jnp.float32)
+                else:
+                    k_tail, v_tail = module.tail_put(
+                        k_tail, v_tail, news[0][t], news[1][t], l, t)
+                return t, acc, k_tail, v_tail
+
+            def step(t, carry):
+                return jax.lax.fori_loop(0, layers, layer, (t,) + carry)[1:]
+
+            acc, k_tail, v_tail = jax.lax.fori_loop(
+                0, BLOCK, step, (jnp.zeros(q.shape, jnp.float32),
+                                 *module.block_tail(k_pool, B, BLOCK)))
+            return (acc + k_tail[0, :, 0, 0, :1][:, :, None]
+                    + v_tail[0, :, 0, 0, :1][:, :, None])
+
+        return (best_of_five(jax.jit(run), q, k_pool, v_pool)
+                / (layers * BLOCK) * 1e6)
+
+    # what one layer holds once the block's tokens are written by columns
+    last = layers - 1
+    want_k, want_v = one
+    for t in range(BLOCK):
+        want_k, want_v = (
+            module._write_columns([x[None]], [new[t]], table, starts + t,
+                                  0)[0][0]
+            for x, new in ((want_k, news[0]), (want_v, news[1])))
+    want = jax.jit(gofr_tpu.ops.paged_attention.paged_attention_reference)(
+        q.astype(jnp.float32), want_k, want_v, table,
+        jnp.where(live, starts + BLOCK, 0))
+    def last_step(q, k_pool, v_pool):
+        return module.paged_attention_in_block(
+            q, news[0][-1], news[1][-1], k_pool, v_pool,
+            *filled(*module.block_tail(k_pool, B, BLOCK), last, BLOCK - 1),
+            table, paged, counts, layer=jnp.int32(last))[0]
+
+    got = jax.jit(last_step)(q, k_pool, v_pool)
+    line("tail_put", steps(read=False))
+    line("read+tail", steps(read=True), max_abs_err=float(np.max(np.abs(
+        np.asarray(got, np.float32) - np.asarray(want)))))
+
+    def write(k_pool, v_pool):
+        def layer(l, pools):
+            return module.paged_write_decode(
+                *pools, news[0][0], news[1][0], table, starts, layer=l)
+
+        return jax.lax.fori_loop(
+            0, STEPS, lambda _, pools: jax.lax.fori_loop(
+                0, layers, layer, pools), (k_pool, v_pool))
+
+    seconds = best_of_five(jax.jit(write, donate_argnums=(0, 1)), k_pool,
+                           v_pool, donated=(0, 1))
+    line("write", seconds / (layers * STEPS) * 1e6)
+    del k_pool, v_pool
+
+    # the flush: tails that hold the block in every layer, pools consumed
+    k_pool, v_pool = stack(one[0]), stack(one[1])
+    tails = jax.jit(lambda k: tuple(
+        jnp.tile(x[last][None], (layers, 1, 1, 1, 1))
+        for x in filled(*module.block_tail(k, B, BLOCK), last)))(k_pool)
+
+    def flush(k_pool, v_pool, k_tail, v_tail):
+        return jax.lax.fori_loop(
+            0, STEPS, lambda _, pools: module.paged_flush_block(
+                *pools, k_tail, v_tail, table, starts, counts),
+            (k_pool, v_pool))
+
+    fn = jax.jit(flush, donate_argnums=(0, 1))
+    pools = fn(k_pool, v_pool, *tails)
+    exact = all(bool(jnp.array_equal(pool[last, 1:], want[1:]))
+                for pool, want in zip(pools, (want_k, want_v)))
+    seconds = best_of_five(fn, *pools, *tails, donated=(0, 1))
+    line("flush", seconds / (layers * STEPS) * 1e6, equals_columns=exact)
 
 
 def main(argv) -> None:
@@ -138,6 +285,10 @@ def main(argv) -> None:
                     "max_abs_err": float(np.max(np.abs(
                         np.asarray(got, np.float32) - want)))}), flush=True)
         del k_pool, v_pool, scales, args
+    for label, module in kernels.items():
+        if hasattr(module, "block_tail"):
+            for geometry in GEOMETRIES:
+                tail_lines(label, module, geometry, device)
 
 
 if __name__ == "__main__":
