@@ -251,7 +251,7 @@ def check_kernels() -> dict:
     import jax.numpy as jnp
     import numpy as np
 
-    from gofr_tpu.ops.decode_attention import quantize_kv
+    from gofr_tpu.ops.paged_attention import quantize_kv
     from gofr_tpu.ops.flash_attention import (attention_reference,
                                               flash_attention)
     from gofr_tpu.ops.paged_attention import (block_tail, paged_attention,

@@ -27,7 +27,7 @@ from gofr_tpu.models.nemotron_h import (NemotronHConfig,  # noqa: E402
 from gofr_tpu.models.tokenizer import (ByteTokenizer, DebugTokenizer,  # noqa: E402
                                        StreamingDecoder)
 from gofr_tpu.tpu.device import TPUClient  # noqa: E402
-from gofr_tpu.tpu.engine import LLMEngine  # noqa: E402
+from gofr_tpu.tpu.paging import PagedLLMEngine  # noqa: E402
 from gofr_tpu.tpu.executor import Executor, enable_compile_cache  # noqa: E402
 
 PRESETS = {
@@ -90,7 +90,15 @@ def _register_engine_observability(app: App, engine) -> None:
             "app_tpu_engine_stall_seconds", round(engine.stall_seconds, 1)))
 
 
-def build_engine(app: App, default_sampling_controls: bool = False) -> LLMEngine:
+def build_engine(app: App,
+                 default_sampling_controls: bool = False) -> PagedLLMEngine:
+    if not app.config.get_bool("PAGED", True):
+        # outside input: an environment that names an engine this server
+        # does not have is refused, not served silently from another
+        raise ValueError(
+            "PAGED=false asks for the dense per-slot engine, which no "
+            "longer exists: the page-pool engine is the only one. Unset "
+            "PAGED (PAGE_SIZE / N_PAGES size the pool)")
     # before the first jit (weight init below), so that every program of
     # this boot lands in one compile cache. The directory follows
     # executor.compile_cache_dir: JAX_COMPILATION_CACHE_DIR where the
@@ -103,27 +111,19 @@ def build_engine(app: App, default_sampling_controls: bool = False) -> LLMEngine
     preset = app.config.get_or_default("MODEL_PRESET", "debug")
     cfg = PRESETS[preset]()
     # ATTN_IMPL: xla | flash (prefill / no-cache forward impl)
-    # DECODE_ATTN: xla | kernel (the T=1 cached read; "kernel" streams the
-    # S-minor cache through the Pallas decode kernel, HBM traffic bounded
-    # by live lengths — see ops/decode_attention)
     import dataclasses
 
     attn_impl = app.config.get_or_default("ATTN_IMPL", cfg.attn_impl)
-    decode_attn = app.config.get_or_default(
-        "DECODE_ATTN", getattr(cfg, "decode_attn", "xla"))
-    # KV_DTYPE=int8 halves cache HBM bytes (quantize-on-write, kernel
-    # dequant) — requires DECODE_ATTN=kernel
+    # KV_DTYPE=int8 halves pool HBM bytes (quantize-on-write, dequant
+    # folded into the paged read)
     kv_dtype = app.config.get_or_default("KV_DTYPE", "") or None
     if attn_impl not in ("xla", "flash"):
         raise ValueError(f"ATTN_IMPL must be xla|flash, got {attn_impl!r}")
-    if decode_attn not in ("xla", "kernel"):
-        raise ValueError(f"DECODE_ATTN must be xla|kernel, got {decode_attn!r}")
     if kv_dtype not in (None, "int8"):
         raise ValueError(f"KV_DTYPE must be int8 or unset, got {kv_dtype!r}")
-    asked = {"attn_impl": attn_impl, "decode_attn": decode_attn,
-             "kv_dtype": kv_dtype}
+    asked = {"attn_impl": attn_impl, "kv_dtype": kv_dtype}
     has = {f.name for f in dataclasses.fields(cfg)}
-    # a family with one decode read and no lower-precision cache has no
+    # a family with one prefill read and no lower-precision cache has no
     # such field (models/protocol.py): asked of it, refuse by name
     absent = sorted(k for k, v in asked.items()
                     if k not in has and v not in (None, "xla"))
@@ -194,48 +194,40 @@ def build_engine(app: App, default_sampling_controls: bool = False) -> LLMEngine
     # config 5: Llama-70B TP=8 on v5e-8) — same engine, sharded mesh
     tp = app.config.get_int("TP_SHARDS", 1)
     mesh = tpu.mesh({"tp": tp}, allow_subset=True) if tp > 1 else None
-    # PAGED (DEFAULT since r4) serves from the paged KV pool (block tables
-    # + page allocator + scalar-prefetch Pallas read); PAGE_SIZE tokens per
-    # page, N_PAGES caps the pool. PAGED=false falls back to the dense
-    # per-slot cache (whose DECODE_ATTN/KV_DTYPE kernel variants remain
-    # the per-row-bandwidth levers for long single streams)
-    engine_cls, paged_kw = LLMEngine, {}
-    if app.config.get_bool("PAGED", True):
-        from gofr_tpu.tpu.paging import PagedLLMEngine
+    # the page pool (block tables + page allocator + scalar-prefetch Pallas
+    # read): PAGE_SIZE tokens per page, N_PAGES caps the pool
+    paged_kw = {"page_size": app.config.get_int("PAGE_SIZE", 128)}
+    n_pages = app.config.get_int("N_PAGES", 0)
+    if n_pages:
+        paged_kw["n_pages"] = n_pages
+    # PREFIX_CACHE shares whole prompt-prefix pages between requests
+    # (system prompts re-prefill once, not per request); int8 pools
+    # share their scale pages alongside
+    # (on by default for a family that can serve it; asked for of one
+    # that cannot, the engine refuses it by name)
+    paged_kw["prefix_cache"] = app.config.get_bool(
+        "PREFIX_CACHE", "prefix_cache" not in cfg.paged_model().refuses)
+    # KV_HOST_TIER_BYTES>0 adds a host-RAM tier under the prefix
+    # cache: evicted refs==0 pages spill to pinned host blobs and
+    # restore via one H2D scatter at admission, so a re-sent prefix
+    # pays a copy instead of a re-prefill even after HBM pressure
+    # evicted it. KV_REDIS_TIER=true chains a write-behind Redis cold
+    # tier below host RAM (blobs versioned + checksummed; any
+    # corruption degrades to a miss, never wrong KV)
+    tier_bytes = app.config.get_int("KV_HOST_TIER_BYTES", 0)
+    if tier_bytes > 0:
+        paged_kw["kv_host_tier_bytes"] = tier_bytes
+        paged_kw["conversation_pin_s"] = app.config.get_float(
+            "CONVERSATION_PIN_S", 600.0)
+        if app.config.get_bool("KV_REDIS_TIER", False):
+            from gofr_tpu.datasource.kvredis import RedisKVStore
 
-        engine_cls = PagedLLMEngine
-        paged_kw = {"page_size": app.config.get_int("PAGE_SIZE", 128)}
-        n_pages = app.config.get_int("N_PAGES", 0)
-        if n_pages:
-            paged_kw["n_pages"] = n_pages
-        # PREFIX_CACHE shares whole prompt-prefix pages between requests
-        # (system prompts re-prefill once, not per request); int8 pools
-        # share their scale pages alongside
-        # (on by default for a family that can serve it; asked for of one
-        # that cannot, the engine refuses it by name)
-        paged_kw["prefix_cache"] = app.config.get_bool(
-            "PREFIX_CACHE", "prefix_cache" not in cfg.paged_model().refuses)
-        # KV_HOST_TIER_BYTES>0 adds a host-RAM tier under the prefix
-        # cache: evicted refs==0 pages spill to pinned host blobs and
-        # restore via one H2D scatter at admission, so a re-sent prefix
-        # pays a copy instead of a re-prefill even after HBM pressure
-        # evicted it. KV_REDIS_TIER=true chains a write-behind Redis cold
-        # tier below host RAM (blobs versioned + checksummed; any
-        # corruption degrades to a miss, never wrong KV)
-        tier_bytes = app.config.get_int("KV_HOST_TIER_BYTES", 0)
-        if tier_bytes > 0:
-            paged_kw["kv_host_tier_bytes"] = tier_bytes
-            paged_kw["conversation_pin_s"] = app.config.get_float(
-                "CONVERSATION_PIN_S", 600.0)
-            if app.config.get_bool("KV_REDIS_TIER", False):
-                from gofr_tpu.datasource.kvredis import RedisKVStore
-
-                paged_kw["kv_redis"] = RedisKVStore(
-                    app.config, app.logger,
-                    app.container.metrics_manager)
-                ttl = app.config.get_float("KV_REDIS_TTL_S", 0.0)
-                if ttl > 0:
-                    paged_kw["kv_redis_ttl_s"] = ttl
+            paged_kw["kv_redis"] = RedisKVStore(
+                app.config, app.logger,
+                app.container.metrics_manager)
+            ttl = app.config.get_float("KV_REDIS_TTL_S", 0.0)
+            if ttl > 0:
+                paged_kw["kv_redis_ttl_s"] = ttl
     # HBM capacity plan: clamp (MAX_BATCH, MAX_SEQ_LEN) to the device budget
     # before boot instead of discovering RESOURCE_EXHAUSTED mid-serve.
     # Auto-detected from the device (0 on CPU backends = no plan);
@@ -248,15 +240,13 @@ def build_engine(app: App, default_sampling_controls: bool = False) -> LLMEngine
     # DISAGG_MODE splits serving into a prefill pool and a decode pool
     # (tpu/disagg.py): "both" builds the split pair in-process behind a
     # DisaggRouter (the single-host deployment), "prefill"/"decode" build
-    # one engine in that role for operator-wired pairs. Requires PAGED —
-    # the hand-off ships KV page blobs.
+    # one engine in that role for operator-wired pairs. The hand-off ships
+    # KV page blobs.
     disagg_mode = app.config.get_or_default("DISAGG_MODE", "off").lower()
     if disagg_mode not in ("off", "prefill", "decode", "both"):
         raise ValueError(f"DISAGG_MODE must be off|prefill|decode|both, "
                          f"got {disagg_mode!r}")
     if disagg_mode != "off":
-        if engine_cls is LLMEngine:
-            raise ValueError("DISAGG_MODE requires PAGED=true")
         paged_kw["disagg_role"] = ("decode" if disagg_mode == "both"
                                    else disagg_mode)
     engine_kw = dict(
@@ -299,7 +289,7 @@ def build_engine(app: App, default_sampling_controls: bool = False) -> LLMEngine
         finisher_queue=app.config.get_int("ENGINE_FINISHER_QUEUE", 256),
         **paged_kw,
     )
-    engine = engine_cls(params, cfg, **engine_kw)
+    engine = PagedLLMEngine(params, cfg, **engine_kw)
     engine.tokenizer = tokenizer
     engine.start()
     # graceful drain: finish active generations (bounded) before the HTTP
@@ -373,7 +363,7 @@ def build_engine(app: App, default_sampling_controls: bool = False) -> LLMEngine
         n_pre = app.config.get_int("DISAGG_PREFILL_SLOTS", 0)
         if n_pre:
             prefill_kw["n_slots"] = n_pre
-        prefill_engine = engine_cls(params, cfg, **prefill_kw)
+        prefill_engine = PagedLLMEngine(params, cfg, **prefill_kw)
         prefill_engine.tokenizer = tokenizer
         prefill_engine.start()
         if warm_mode not in ("false", "0", "no", "off"):

@@ -8,7 +8,7 @@ Drop-in endpoints for clients speaking the OpenAI REST shapes:
 
 Streaming responses emit `data: {json}` SSE chunks and terminate with
 `data: [DONE]`, matching the OpenAI wire contract, so existing SDKs can
-point their base_url here. The engine underneath is the same LLMEngine
+point their base_url here. The engine underneath is the same PagedLLMEngine
 the native /generate endpoint uses (examples/llm-server), with every
 framework feature available (kernel decode, int8 KV, speculation, drain).
 """

@@ -645,8 +645,7 @@ class App:
         twin — the router's /debug/fleet/capacity rollup with
         replicas_needed — lives in gofr_tpu/fleet/capacity.py.
 
-        Config: METER_PAGE_TOKENS (KV page granularity for dense
-        engines; paged engines inherit the allocator's page size),
+        Config (KV page-seconds bill at the allocator's page size):
         METER_WINDOW_S (bounded-window spend horizon, 300),
         METER_REQUESTS (finished per-request rows retained, 512),
         METER_TOP_K (tenants in the /debug/capacity table, 10);
@@ -661,14 +660,9 @@ class App:
         metrics = self.container.metrics_manager
         if metrics is not None:
             register_meter_metrics(metrics)
-        # paged engines bill at the allocator's real page size; dense
-        # engines at a fixed accounting granularity
-        page_tokens = getattr(getattr(engine, "allocator", None),
-                              "page_size", None) \
-            or cfg.get_int("METER_PAGE_TOKENS", 16)
         meter = TPUMeter(
             cfg=getattr(engine, "cfg", None),
-            page_tokens=page_tokens,
+            page_tokens=engine.allocator.page_size,
             window_s=cfg.get_float("METER_WINDOW_S", 300.0),
             done_capacity=cfg.get_int("METER_REQUESTS", 512),
             top_k=cfg.get_int("METER_TOP_K", 10),

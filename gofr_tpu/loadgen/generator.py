@@ -2,7 +2,7 @@
 damned.
 
 This is the one property every closed-loop worker harness in the repo
-(tools/soak.py's thread pools, bench.py's phases) structurally cannot
+(tools/soak.py's thread pools) structurally cannot
 express: a closed-loop worker that is stuck waiting on a slow stream
 stops *offering* load, so the measured system never sees λ > μ for
 long and queueing collapse is invisible. Here a dispatcher thread

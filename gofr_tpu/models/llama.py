@@ -39,19 +39,10 @@ class LlamaConfig:
     # (training/eval) AND the serving prefill (full-window T == S case in
     # _attention_block)
     attn_impl: str = "xla"
-    # "xla" | "kernel" — the cached T=1 decode read. "xla" is the masked
-    # einsum over the whole allocated cache; "kernel" is the Pallas
-    # streaming read (ops/decode_attention) whose per-step HBM traffic is
-    # bounded by each row's LIVE length, not the allocated S (the einsum
-    # also reads the S-minor storage well below DMA peak — see the kernel
-    # module docstring for the measured gap)
-    decode_attn: str = "xla"
-    # None (= cfg.dtype) | "int8" — the serving KV cache's storage dtype.
-    # int8 halves cache HBM bytes (the decode bandwidth bound) and doubles
+    # None (= cfg.dtype) | "int8" — the page pool's storage dtype. int8
+    # halves pool HBM bytes (the decode bandwidth bound) and doubles
     # context capacity per GiB; values quantize on write with per-token
-    # per-head scales and dequantize inside the decode kernels' dots.
-    # Dense engine: requires decode_attn == "kernel". Paged engine: the
-    # paged kernel dequant-folds natively (pool + page capacity both halve)
+    # per-head scales and the paged kernel dequant-folds inside its dots
     kv_dtype: Optional[str] = None
 
     @property
@@ -390,10 +381,6 @@ def _attention_block(x, layer, k_cache_l, v_cache_l, positions, cfg: LlamaConfig
     x: [B, T, D]; k/v_cache_l: [B, Hkv, dh, S] (S-minor, see init_kv_cache);
     positions: [B, T]. Returns (out [B, T, D], k_cache_l, v_cache_l).
 
-    Per-step HBM traffic scales with the ALLOCATED seq dim S, so the engine
-    allocates the cache at the bucket covering the live contexts and grows
-    it on demand (engine._grow_cache) instead of sizing for max_seq_len.
-
     When T == S (a full-window prefill: positions are arange over the
     window, so the cache after the write IS this chunk's k/v) and
     cfg.attn_impl == "flash", attention runs through the Pallas flash
@@ -428,16 +415,6 @@ def _attention_block(x, layer, k_cache_l, v_cache_l, positions, cfg: LlamaConfig
 
         attn = flash_attention(q, k, v, True, mesh=mesh)  # [B, T, H, dh]
         out = _mm(attn.reshape(B, T, H * dh), layer, "wo")
-        return out, k_cache_l, v_cache_l
-
-    if T == 1 and cfg.decode_attn == "kernel":
-        from ..ops.decode_attention import decode_attention
-
-        # the scatter above put this step's k/v at `positions`, so the live
-        # window is [0, positions] inclusive — lengths = positions + 1
-        attn = decode_attention(q[:, 0], k_cache_l, v_cache_l,
-                                positions[:, 0] + 1, mesh=mesh)  # [B, H, dh]
-        out = _mm(attn.reshape(B, 1, H * dh), layer, "wo")
         return out, k_cache_l, v_cache_l
 
     # GQA attention over the cache: q grouped [B, T, Hkv, G, dh].
@@ -564,166 +541,16 @@ def llama_decode_step(params, cfg: LlamaConfig, tokens, positions, k_cache,
     return logits[:, 0, :], k_cache, v_cache
 
 
-def init_kv_cache_layers(cfg: LlamaConfig, batch: int,
-                         seq_len: Optional[int] = None,
-                         dtype: Optional[str] = None) -> Tuple[Tuple, Tuple]:
-    """Per-LAYER zeroed (k, v) caches: tuples of L arrays [B, Hkv, dh, S].
-
-    The serving engine's decode representation. A stacked [L, ...] cache
-    must be sliced per layer inside the loop (lax.scan xs or
-    dynamic_index+DUS), and on v5e that slicing throttled decode to
-    ~36 GB/s effective — 167 ms/step at B=128, S=1024 — while separate
-    per-layer buffers with an unrolled layer loop run the same math at
-    35 ms/step (measured). Trace/compile time grows with n_layers; decode
-    compiles once per cache size, so the trade is right for serving.
-    """
-    import jax.numpy as jnp
-
-    S = seq_len or cfg.max_seq_len
-    shape = (batch, cfg.n_kv_heads, cfg.head_dim, S)
-    dt = _np_dtype(dtype or cfg.dtype)
-    k = tuple(jnp.zeros(shape, dtype=dt) for _ in range(cfg.n_layers))
-    v = tuple(jnp.zeros(shape, dtype=dt) for _ in range(cfg.n_layers))
-    return k, v
-
-
-def llama_decode_step_unrolled(params, cfg: LlamaConfig, tokens, positions,
-                               k_layers, v_layers, mesh=None):
-    """One decode step over PER-LAYER cache buffers (python-unrolled loop).
-
-    tokens: [B]; positions: [B]; k/v_layers: tuples of L [B, Hkv, dh, S]
-    arrays (init_kv_cache_layers). Returns (logits [B, V] f32, k_layers,
-    v_layers). Same math as llama_decode_step; the representation exists
-    purely so XLA never slices a stacked cache in the hot loop (see
-    init_kv_cache_layers).
-    """
-    x = _embed(params, cfg, tokens)[:, None]               # [B, 1, D]
-    pos_grid = positions[:, None]
-    k_out, v_out = [], []
-    for l in range(cfg.n_layers):
-        layer = jax.tree_util.tree_map(lambda w: w[l], params["layers"])
-        attn, k_l, v_l = _attention_block(x, layer, k_layers[l], v_layers[l],
-                                          pos_grid, cfg, mesh)
-        x = x + attn
-        x = x + _ffn_block(x, layer, cfg)
-        k_out.append(k_l)
-        v_out.append(v_l)
-    x = rms_norm(x, params["final_norm"], cfg.rms_eps)
-    logits = _head(x[:, 0], params)
-    return logits, tuple(k_out), tuple(v_out)
-
-
-def init_kv_scale_layers(cfg: LlamaConfig, batch: int,
-                         seq_len: Optional[int] = None) -> Tuple[Tuple, Tuple]:
-    """Per-layer (k_scale, v_scale) buffers for the int8 cache: tuples of
-    L arrays [B, Hkv, S] float32 (dequant value = int8 * scale). ~6% of the
-    int8 cache's bytes at dh=64."""
-    import jax.numpy as jnp
-
-    S = seq_len or cfg.max_seq_len
-    shape = (batch, cfg.n_kv_heads, S)
-    k = tuple(jnp.zeros(shape, dtype=jnp.float32) for _ in range(cfg.n_layers))
-    v = tuple(jnp.zeros(shape, dtype=jnp.float32) for _ in range(cfg.n_layers))
-    return k, v
-
-
-def llama_decode_step_unrolled_q8(params, cfg: LlamaConfig, tokens, positions,
-                                  k_layers, v_layers, ks_layers, vs_layers,
-                                  mesh=None):
-    """One decode step over INT8 per-layer caches with per-token scales.
-
-    tokens/positions: [B]; k/v_layers: tuples of [B, Hkv, dh, S] int8;
-    ks/vs_layers: tuples of [B, Hkv, S] float32 scales. Returns
-    (logits [B, V] f32, k_layers, v_layers, ks_layers, vs_layers).
-
-    The cache crosses HBM as int8 — half the bf16 bytes, so the
-    bandwidth-bound decode step's cache term halves. The new token's K/V
-    quantize on write (symmetric per-token-per-head, ops/decode_attention.
-    quantize_kv); the read is the Pallas kernel with dequant FOLDED into
-    its two dots (k's scale multiplies scores, v's folds into probs).
-    Requires cfg.decode_attn == "kernel" — there is no efficient XLA-einsum
-    dequant read (it would materialize the full cache in bf16).
-    """
-    from ..ops.decode_attention import decode_attention, quantize_kv
-
-    B = tokens.shape[0]
-    H, Hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    x = _embed(params, cfg, tokens)[:, None]               # [B, 1, D]
-    pos_grid = positions[:, None]
-    batch_idx = jnp.arange(B)
-    k_out, v_out = list(k_layers), list(v_layers)
-    ks_out, vs_out = list(ks_layers), list(vs_layers)
-    for l in range(cfg.n_layers):
-        layer = jax.tree_util.tree_map(lambda w: w[l], params["layers"])
-        normed = rms_norm(x, layer["attn_norm"], cfg.rms_eps)
-        q = _mm(normed, layer, "wq").reshape(B, 1, H, dh)
-        k = _mm(normed, layer, "wk").reshape(B, 1, Hkv, dh)
-        v = _mm(normed, layer, "wv").reshape(B, 1, Hkv, dh)
-        q = rope(q, pos_grid, cfg.rope_theta)
-        k = rope(k, pos_grid, cfg.rope_theta)
-        k8, ks = quantize_kv(k[:, 0], axis=-1)             # [B,Hkv,dh], [B,Hkv]
-        v8, vs = quantize_kv(v[:, 0], axis=-1)
-        k_out[l] = k_out[l].at[batch_idx, :, :, positions].set(k8)
-        v_out[l] = v_out[l].at[batch_idx, :, :, positions].set(v8)
-        ks_out[l] = ks_out[l].at[batch_idx, :, positions].set(ks)
-        vs_out[l] = vs_out[l].at[batch_idx, :, positions].set(vs)
-        attn = decode_attention(q[:, 0], k_out[l], v_out[l], positions + 1,
-                                ks_out[l], vs_out[l], mesh=mesh)  # [B, H, dh]
-        x = x + _mm(attn.reshape(B, 1, H * dh), layer, "wo")
-        x = x + _ffn_block(x, layer, cfg)
-    x = rms_norm(x, params["final_norm"], cfg.rms_eps)
-    logits = _head(x[:, 0], params)
-    return (logits, tuple(k_out), tuple(v_out), tuple(ks_out),
-            tuple(vs_out))
-
-
-def llama_decode_step_inplace(params, cfg: LlamaConfig, tokens, positions,
-                              k_cache, v_cache):
-    """One decode step with the caches updated IN PLACE per layer.
-
-    Same math as llama_decode_step, different loop structure: a fori_loop
-    over layers with dynamic_update_slice on the FULL [L, ...] caches,
-    instead of lax.scan consuming cache slices as xs and re-stacking ys.
-    The scan form makes XLA double-buffer the stacked cache outputs across
-    the serving engine's block-decode loop — two cache-sized AllocateBuffer
-    temps that OOM'd the round-2/3 benches at S=1024 (B=128, Llama-1B) —
-    while DUS-on-carry aliases cleanly. Measured on v5e at S=512/B=128:
-    47 ms/step vs 60 ms/step and 4.3 GiB vs 12.3 GiB program temps.
-
-    tokens: [B]; positions: [B]. Returns (logits [B, V] f32, k, v).
-    """
-    B = tokens.shape[0]
-    x = _embed(params, cfg, tokens)[:, None]               # [B, 1, D]
-    pos_grid = positions[:, None]
-
-    def layer_body(l, state):
-        x, k_cache, v_cache = state
-        layer = jax.tree_util.tree_map(lambda w: w[l], params["layers"])
-        k_l = jax.lax.dynamic_index_in_dim(k_cache, l, 0, keepdims=False)
-        v_l = jax.lax.dynamic_index_in_dim(v_cache, l, 0, keepdims=False)
-        attn, k_l, v_l = _attention_block(x, layer, k_l, v_l, pos_grid, cfg)
-        x = x + attn
-        x = x + _ffn_block(x, layer, cfg)
-        k_cache = jax.lax.dynamic_update_index_in_dim(k_cache, k_l, l, 0)
-        v_cache = jax.lax.dynamic_update_index_in_dim(v_cache, v_l, l, 0)
-        return x, k_cache, v_cache
-
-    x, k_cache, v_cache = jax.lax.fori_loop(
-        0, cfg.n_layers, layer_body, (x, k_cache, v_cache))
-    x = rms_norm(x, params["final_norm"], cfg.rms_eps)
-    logits = _head(x[:, 0], params)
-    return logits, k_cache, v_cache
-
-
 def llama_prefill_chunk(params, cfg: LlamaConfig, tokens, positions,
                         k_layers, v_layers, slots, project_last=None,
                         mesh=None):
-    """One CHUNK of a cached prefill over the per-layer serving caches.
+    """One CHUNK of a cached prefill over per-layer window buffers (the
+    paged engine's per-job temps, scattered into pages at the final chunk).
 
     tokens: [K, C] the chunk's token ids; positions: [K, C] their absolute
     positions (a later chunk attends the earlier chunks' KV already written
-    in the cache rows — the mask `j <= q_pos` needs nothing more);
-    k/v_layers: per-layer cache tuples ([B, Hkv, dh, S]); slots: [K] row
+    in the rows — the mask `j <= q_pos` needs nothing more);
+    k/v_layers: per-layer tuples ([B, Hkv, dh, S]); slots: [K] row
     ids. Gathers the K rows, runs the cache-aware attention for the chunk,
     scatters the rows back.
 
@@ -759,149 +586,6 @@ def llama_prefill_chunk(params, cfg: LlamaConfig, tokens, positions,
     last = x[jnp.arange(K), project_last]                  # [K, D]
     logits = _head(last, params)
     return logits, tuple(k_out), tuple(v_out)
-
-
-def llama_verify_step(params, cfg: LlamaConfig, tokens, drafts, positions,
-                      k_layers, v_layers, mesh=None):
-    """Speculative-decode VERIFY: score the current token plus d drafted
-    tokens for every slot in ONE forward.
-
-    tokens: [B] each slot's current (already-sampled) token; drafts: [B, d]
-    proposed continuations (junk rows allowed — acceptance is decided by
-    the caller); positions: [B] the current token's absolute position;
-    k/v_layers: per-layer serving caches.
-
-    Window = [tokens | drafts] at positions [pos .. pos+d]. The forward
-    writes the window's K/V into the cache — for the accepted prefix these
-    ARE the tokens decode would have written (a draft is only accepted when
-    it equals the model's own greedy choice), and rejected positions hold
-    junk that is overwritten by their eventual real occupant before any
-    query attends them (the engine's standard lock-step junk-write
-    invariant).
-
-    Returns (greedy [B, d+1] int32 — argmax continuation after each window
-    position, logits0 [B, V] float32 — position-0 logits for temperature
-    sampling, k_layers, v_layers).
-
-    The lm_head projects one window position at a time ([B, D] @ [D, V],
-    then argmax) so no [B, d+1, V] logits buffer ever materializes — at
-    Llama-3 vocab that buffer would be ~0.5 GB per dispatch.
-
-    NOTE: the window's cached attention is the dense masked einsum (a
-    T=d+1 read never hits the T==1 decode kernel branch), so each verify
-    dispatch reads the full allocated cache per layer regardless of
-    cfg.decode_attn — speculation trades the kernel's live-length
-    streaming read for multi-token verification. Favorable when acceptance
-    is high or contexts are short; long-context random text prefers plain
-    kernel-mode block decode.
-    """
-    B, d = drafts.shape
-    window = jnp.concatenate([tokens[:, None], drafts], axis=1)  # [B, d+1]
-    pos_grid = positions[:, None] + jnp.arange(d + 1, dtype=jnp.int32)[None, :]
-
-    x = _embed(params, cfg, window)
-    k_out, v_out = [], []
-    for l in range(cfg.n_layers):
-        layer = jax.tree_util.tree_map(lambda w: w[l], params["layers"])
-        attn, k_l, v_l = _attention_block(x, layer, k_layers[l], v_layers[l],
-                                          pos_grid, cfg, mesh)
-        x = x + attn
-        x = x + _ffn_block(x, layer, cfg)
-        k_out.append(k_l)
-        v_out.append(v_l)
-    x = rms_norm(x, params["final_norm"], cfg.rms_eps)       # [B, d+1, D]
-
-    greedy_cols = []
-    logits0 = None
-    for i in range(d + 1):
-        logits_i = _head(x[:, i], params)
-        if i == 0:
-            logits0 = logits_i
-        greedy_cols.append(jnp.argmax(logits_i, axis=-1).astype(jnp.int32))
-    greedy = jnp.stack(greedy_cols, axis=1)                  # [B, d+1]
-    return greedy, logits0, tuple(k_out), tuple(v_out)
-
-
-def llama_prefill_chunk_q8(params, cfg: LlamaConfig, tokens, positions,
-                           k_layers, v_layers, ks_layers, vs_layers, slots,
-                           project_last=None):
-    """One CHUNK of a cached prefill over INT8 per-layer caches.
-
-    MIRRORS llama_prefill_chunk with the quantized storage: gathers the K
-    slots' int8 rows + scales, quantizes THIS chunk's fresh K/V into them
-    (old tokens keep their original quantization — no requantize drift),
-    and runs the chunk's attention over the dequantized gathered rows.
-    Dequant materializes only [K, Hkv, dh, S] per layer — K gathered rows,
-    not the whole B-row cache, so the int8 cache's HBM win is preserved.
-    The read uses the dequant-of-quantized values for this chunk too, so
-    numerics match what later chunks and decode steps will read.
-
-    tokens: [K, C]; positions: [K, C]; k/v_layers: int8 cache tuples;
-    ks/vs_layers: [B, Hkv, S] f32 scale tuples; slots: [K].
-    Returns (logits [K, V] or None, k_layers, v_layers, ks_layers,
-    vs_layers).
-    """
-    from ..ops.decode_attention import quantize_kv
-
-    K, C = tokens.shape
-    H, Hkv, dh, G = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.q_per_kv
-    dt = _np_dtype(cfg.dtype)
-    k_out, v_out = list(k_layers), list(v_layers)
-    ks_out, vs_out = list(ks_layers), list(vs_layers)
-    x = _embed(params, cfg, tokens)                        # [K, C, D]
-    batch_idx = jnp.arange(K)[:, None]
-    for l in range(cfg.n_layers):
-        layer = jax.tree_util.tree_map(lambda w: w[l], params["layers"])
-        k_rows8 = k_out[l][slots]                          # [K, Hkv, dh, S]
-        v_rows8 = v_out[l][slots]
-        ks_rows = ks_out[l][slots]                         # [K, Hkv, S]
-        vs_rows = vs_out[l][slots]
-
-        normed = rms_norm(x, layer["attn_norm"], cfg.rms_eps)
-        q = _mm(normed, layer, "wq").reshape(K, C, H, dh)
-        k = _mm(normed, layer, "wk").reshape(K, C, Hkv, dh)
-        v = _mm(normed, layer, "wv").reshape(K, C, Hkv, dh)
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
-        k8c, ksc = quantize_kv(k, axis=-1)                 # [K,C,Hkv,dh],[K,C,Hkv]
-        v8c, vsc = quantize_kv(v, axis=-1)
-        k_rows8 = k_rows8.at[batch_idx, :, :, positions].set(k8c)
-        v_rows8 = v_rows8.at[batch_idx, :, :, positions].set(v8c)
-        ks_rows = ks_rows.at[batch_idx, :, positions].set(ksc)
-        vs_rows = vs_rows.at[batch_idx, :, positions].set(vsc)
-
-        k_deq = (k_rows8.astype(jnp.float32)
-                 * ks_rows[:, :, None, :]).astype(dt)
-        v_deq = (v_rows8.astype(jnp.float32)
-                 * vs_rows[:, :, None, :]).astype(dt)
-        # GQA masked read over the dequantized rows — the dense branch of
-        # _attention_block, inlined (the write above had to target the
-        # int8 storage, not the float rows that function scatters into)
-        S = k_deq.shape[-1]
-        qg = q.reshape(K, C, Hkv, G, dh)
-        scores = jnp.einsum("bthgd,bhds->bhgts", qg, k_deq,
-                            preferred_element_type=jnp.float32) / math.sqrt(dh)
-        cache_pos = jnp.arange(S)[None, None, :]
-        visible = cache_pos <= positions[:, :, None]
-        scores = jnp.where(visible[:, None, None, :, :], scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1)
-        attn = jnp.einsum("bhgts,bhds->bthgd", probs.astype(v_deq.dtype),
-                          v_deq,
-                          preferred_element_type=jnp.float32).astype(x.dtype)
-        x = x + _mm(attn.reshape(K, C, H * dh), layer, "wo")
-        x = x + _ffn_block(x, layer, cfg)
-
-        k_out[l] = k_out[l].at[slots].set(k_rows8)
-        v_out[l] = v_out[l].at[slots].set(v_rows8)
-        ks_out[l] = ks_out[l].at[slots].set(ks_rows)
-        vs_out[l] = vs_out[l].at[slots].set(vs_rows)
-    out_caches = (tuple(k_out), tuple(v_out), tuple(ks_out), tuple(vs_out))
-    if project_last is None:
-        return (None,) + out_caches
-    x = rms_norm(x, params["final_norm"], cfg.rms_eps)
-    last = x[jnp.arange(K), project_last]                  # [K, D]
-    logits = _head(last, params)
-    return (logits,) + out_caches
 
 
 def _attended_lengths(table, positions):
@@ -995,7 +679,7 @@ def llama_decode_step_paged_q8(params, cfg: LlamaConfig, tokens, positions,
     per-step read AND the page capacity per GiB double.
     Returns (logits [B, V] f32, k_pool, v_pool, ks_pool, vs_pool).
     """
-    from ..ops.decode_attention import quantize_kv
+    from ..ops.paged_attention import quantize_kv
     from ..ops.paged_attention import paged_attention, paged_write_decode
 
     B = tokens.shape[0]
@@ -1035,8 +719,9 @@ def llama_verify_step_paged(params, cfg: LlamaConfig, tokens, drafts,
                             positions, k_pool, v_pool, table, mesh=None):
     """Speculative-decode VERIFY against the PAGED pool.
 
-    Same contract as llama_verify_step (score current token + d drafts in
-    one forward, cache-writing), re-shaped for paged storage:
+    Scores each slot's current (already-sampled) token plus d drafted
+    tokens in ONE cache-writing forward (junk draft rows allowed:
+    acceptance is decided by the caller against `greedy`):
 
       - the window's K/V scatter into pages via paged_write_decode, one
         window position at a time — positions past a slot's reservation
@@ -1045,12 +730,12 @@ def llama_verify_step_paged(params, cfg: LlamaConfig, tokens, drafts,
       - the window attention gathers each slot's pages into contiguous
         [B, Hkv, dh, NP*ps] rows (ONE pool read per layer — the paged
         kernel is a T=1 read; d+1 kernel calls would re-stream the live
-        pages d+1 times) and runs the dense masked einsum over them.
+        pages d+1 times) and runs the masked einsum over them.
         Page j of a slot's table covers absolute positions [j*ps, (j+1)*ps),
         so gathered offset IS absolute position and the `j <= q_pos` mask
         carries over unchanged.
 
-    Junk-safety mirrors the dense verify: rejected window positions hold
+    Junk-safety: rejected window positions hold
     junk that the eventual real occupant overwrites before any query
     attends it (lock-step invariant), and garbage-page content is only
     reachable at offsets the mask already excludes for live queries.
@@ -1080,7 +765,7 @@ def llama_verify_step_paged(params, cfg: LlamaConfig, tokens, drafts,
                  pos_grid, cfg.rope_theta)
         v = _mm(normed, layer, "wv").reshape(B, d + 1, Hkv, dh)
         # window write BEFORE the gather so the gathered rows already
-        # contain this window's fresh K/V (the dense verify's .at[].set)
+        # contain this window's fresh K/V
         for i in range(d + 1):
             k_pool, v_pool = paged_write_decode(
                 k_pool, v_pool, k[:, i], v[:, i], table, positions + i,
@@ -1200,12 +885,12 @@ def llama_prefill_paged_prefix_q8(params, cfg: LlamaConfig, tokens,
     on write (so the pages hold exactly what later decode reads), then the
     gathered rows dequantize [K, Hkv, dh, NP*ps] for the tail window's
     attention — prefix pages keep the DONOR's quantization (no requantize
-    drift), the same posture as the dense engine's chunked-q8 path.
+    drift).
 
     k/v_pool: [L, P, Hkv, dh, ps] int8; ks/vs_pool: [L, P, Hkv, ps] f32.
     Returns (last_logits [K, V] f32, k_pool, v_pool, ks_pool, vs_pool).
     """
-    from ..ops.decode_attention import quantize_kv
+    from ..ops.paged_attention import quantize_kv
     from ..ops.paged_attention import paged_write_window
 
     K, T = tokens.shape

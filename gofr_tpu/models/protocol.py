@@ -31,9 +31,8 @@ computes on it:
   host on the block's own token copy.
 - `refuses`: {engine feature: reason} the family cannot serve yet; the
   engine refuses each BY NAME at construction (docs/model-families.md).
-  A config MAY carry `kv_dtype` (a lower-precision page pool) and
-  `decode_attn` (a second decode read); the engine takes a config without
-  them as pages in the model's dtype and the one paged read.
+  A config MAY carry `kv_dtype` (a lower-precision page pool); the engine
+  takes a config without it as pages in the model's dtype.
 - `describe(counts, steps)`: what `/debug/engine` shows of the family:
   static facts and what it makes of its counters' sums ({name: sum} over
   `steps` decode steps).

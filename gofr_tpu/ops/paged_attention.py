@@ -75,6 +75,21 @@ from .scopes import kernel_scope
 DEFAULT_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
+def quantize_kv(x, axis: int = -2):
+    """Symmetric int8 quantization along `axis` (the dh axis of a
+    [..., dh, S]-shaped cache entry): returns (int8 values, scale) with
+    dequant = int8 * scale and scale shaped like x minus `axis`.
+
+    Per-token-per-head scales keep the quantization error of any one token
+    independent of its neighbors — the property that makes int8 KV safe for
+    long-context serving."""
+    amax = jnp.max(jnp.abs(x.astype(jnp.float32)), axis=axis, keepdims=True)
+    scale = jnp.maximum(amax, 1e-8) / 127.0
+    q8 = jnp.clip(jnp.round(x.astype(jnp.float32) / scale), -127, 127
+                  ).astype(jnp.int8)
+    return q8, jnp.squeeze(scale, axis=axis)
+
+
 def paged_attention_reference(q, k_pool, v_pool, table, lengths,
                               k_scale=None, v_scale=None):
     """Gather-based oracle. q: [B, H, dh]; pools: [P, Hkv, dh, ps];
@@ -104,7 +119,7 @@ def paged_attention_reference(q, k_pool, v_pool, table, lengths,
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhgs,bhds->bhgd", p, v)
     # nothing to attend is zeros, not the uniform mean of junk v: the
-    # convention of ops/decode_attention's oracle and of the kernel
+    # kernel's convention too
     out = jnp.where((lengths > 0)[:, None, None, None], out, 0.0)
     return out.reshape(B, H, dh).astype(q.dtype)
 
@@ -125,9 +140,8 @@ def _paged_kernel(layer_ref, table_ref, len_ref, *refs, scale: float,
     [the tails' two [2, Hkv, T, dh'],] DMA semaphores [n, 2], [the tails'
     [2, 2] in and [2] out,] and `first_slot` (SMEM: the buffer this row's
     first page was started in). int8 pages carry per-token scales; dequant
-    FOLDS into the dots exactly like ops/decode_attention's quantized
-    kernel (k's scale multiplies score rows, v's folds into the
-    probabilities)."""
+    FOLDS into the dots (k's scale multiplies score rows, v's folds into
+    the probabilities)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 

@@ -137,16 +137,9 @@ def kv_cache_spec():
 
 
 def kv_cache_layer_spec():
-    """One per-layer cache buffer [B, Hkv, dh, S] (the dense engine's
-    representation, init_kv_cache_layers): KV heads over tp."""
+    """One per-layer window buffer [K, Hkv, dh, S] (a chunked prefill's
+    per-job temps, tpu/paging.py): KV heads over tp."""
     return _P(None, "tp", None, None)
-
-
-def kv_scale_layer_spec():
-    """Per-layer int8 dequant scales [B, Hkv, S]: KV heads over tp,
-    row-aligned with kv_cache_layer_spec so each shard reads exactly its
-    heads' scales."""
-    return _P(None, "tp", None)
 
 
 def kv_scale_pool_spec():

@@ -1,7 +1,7 @@
 """HBM capacity planner: fit the serving config to the device budget.
 
 The reference never plans memory — a Go microservice trusts the heap. A
-TPU serving engine cannot: params + KV caches + growth transients + prefill
+TPU serving engine cannot: params + KV pool + per-slot state + prefill
 temporaries must fit a fixed HBM budget (16 GB on v5e) or the program dies
 with RESOURCE_EXHAUSTED mid-serve (the round-2 bench failure mode). This
 module is the fit calculation the engine runs at construction, the analog of
@@ -33,13 +33,7 @@ class CapacityPlan:
     prefill_buckets: Tuple[int, ...]
     budget_bytes: int
     params_bytes: int
-    cache_bytes_max: int        # both caches at the planned max_seq_len
-    # decode-program transient: the multi-step decode scan carries both
-    # caches through a while loop, and XLA ping-pong-buffers the carried
-    # updates — one extra cache-sized allocation pair was observed in the
-    # round-2 OOM dump ("AllocateBuffer" temps). Dominates the one-off
-    # grow-copy transient, so it is THE dense-cache transient budget.
-    growth_transient_bytes: int
+    cache_bytes_max: int        # both pools at the planned max_seq_len
     prefill_temp_bytes: int      # worst fused-admission temporaries
     fits: bool
     clamped: bool                # True if the requested config was shrunk
@@ -48,14 +42,14 @@ class CapacityPlan:
     def peak_bytes(self) -> int:
         """Worst simultaneous residency the plan accounts for."""
         return (self.params_bytes + self.cache_bytes_max
-                + max(self.growth_transient_bytes, self.prefill_temp_bytes))
+                + self.prefill_temp_bytes)
 
     def summary(self) -> str:
         gb = 1 << 30
         return (f"capacity plan: slots={self.n_slots} max_seq={self.max_seq_len} "
                 f"params={self.params_bytes / gb:.2f}GiB "
                 f"kv={self.cache_bytes_max / gb:.2f}GiB "
-                f"transient={max(self.growth_transient_bytes, self.prefill_temp_bytes) / gb:.2f}GiB "
+                f"transient={self.prefill_temp_bytes / gb:.2f}GiB "
                 f"peak={self.peak_bytes / gb:.2f}GiB "
                 f"budget={self.budget_bytes / gb:.2f}GiB "
                 f"fits={self.fits} clamped={self.clamped}")
@@ -111,7 +105,6 @@ def plan_capacity(cfg, n_slots: int, max_seq_len: int,
                   budget_bytes: int,
                   prefill_buckets: Sequence[int] = (),
                   safety_frac: float = 0.92,
-                  paged: bool = False,
                   clamp: bool = True,
                   min_slots: int = 1,
                   min_seq: int = 128,
@@ -119,9 +112,9 @@ def plan_capacity(cfg, n_slots: int, max_seq_len: int,
     """Compute the fit; optionally shrink (n_slots, max_seq_len) until it fits.
 
     budget_bytes: the device's bytes_limit (TPUClient.memory_stats()). A
-    safety fraction keeps headroom for XLA scratch + fragmentation.
-    paged=True drops the growth transient (the paged cache never copies the
-    world) — the pool is allocated once at its planned size.
+    safety fraction keeps headroom for XLA scratch + fragmentation. The
+    pool is allocated once at its planned size and never carried whole, so
+    the only transient is the widest prefill's.
 
     Clamping halves whichever of (max_seq_len, n_slots) currently costs more
     cache bytes, so a long-context config sheds sequence first and a
@@ -139,13 +132,13 @@ def plan_capacity(cfg, n_slots: int, max_seq_len: int,
         buckets = tuple(b for b in prefill_buckets if b <= max_seq_len)
         return CapacityPlan(n_slots, max_seq_len, buckets, 0,
                             p_known, kv_cache_bytes(cfg, n_slots, max_seq_len),
-                            0, 0, fits=True, clamped=False)
+                            0, fits=True, clamped=False)
 
     p_bytes = p_known
     usable = int(budget_bytes * safety_frac)
     requested = (n_slots, max_seq_len)
 
-    def peak(slots: int, seq: int) -> Tuple[int, int, int]:
+    def peak(slots: int, seq: int) -> Tuple[int, int]:
         kv_dtype = getattr(cfg, "kv_dtype", None)
         # pages, and what each slot holds beside them (a recurrent state a
         # slot is fixed in size: it scales with slots, not with seq)
@@ -153,23 +146,19 @@ def plan_capacity(cfg, n_slots: int, max_seq_len: int,
                  + slots * cfg.state_bytes_per_slot)
         if kv_dtype == "int8":
             cache += kv_scales_bytes(cfg, slots, seq)
-        # dense decode ping-pongs the scanned cache carries (one extra
-        # cache-sized pair); this also covers the smaller one-off grow copy.
-        # the paged pool is never carried whole, so it has no such transient
-        transient = 0 if paged else cache
         bucket_max = max((b for b in prefill_buckets if b <= seq), default=0)
         ptmp = prefill_temp_bytes(cfg, slots, bucket_max) if bucket_max else 0
-        return cache, transient, ptmp
+        return cache, ptmp
 
     while True:
-        cache, transient, ptmp = peak(n_slots, max_seq_len)
-        total = p_bytes + cache + max(transient, ptmp)
+        cache, ptmp = peak(n_slots, max_seq_len)
+        total = p_bytes + cache + ptmp
         if total <= usable:
             break
         if not clamp:
             buckets = tuple(b for b in prefill_buckets if b <= max_seq_len)
             return CapacityPlan(n_slots, max_seq_len, buckets, budget_bytes,
-                                p_bytes, cache, transient, ptmp,
+                                p_bytes, cache, ptmp,
                                 fits=False, clamped=False)
         if n_slots <= min_slots and max_seq_len <= min_seq:
             raise ValueError(
@@ -186,7 +175,7 @@ def plan_capacity(cfg, n_slots: int, max_seq_len: int,
 
     buckets = tuple(b for b in prefill_buckets if b <= max_seq_len)
     return CapacityPlan(n_slots, max_seq_len, buckets, budget_bytes,
-                        p_bytes, cache, transient, ptmp,
+                        p_bytes, cache, ptmp,
                         fits=True, clamped=(n_slots, max_seq_len) != requested)
 
 

@@ -1,12 +1,18 @@
-"""Continuous-batching LLM engine: pipelined, chunked decode with device-resident state.
+"""Continuous-batching LLM engine, the loop half: queue, admission heap,
+pipelined dispatch, sync / demux / emit, failure handling.
+
+`LLMEngine` is the serving loop of the ONE engine, `paging.PagedLLMEngine`,
+which is what callers construct: that file holds the device state (page
+pools, block tables, per-slot model state) and every compiled program.
+This file keeps no cache of its own, compiles no program of its own and
+imports no step forward from `models/`; what it needs of the device it
+asks through the hooks at the end of the class. Two files, one engine: the
+loop below is what the next host-side `perf_opt` rewrites (ROADMAP S2),
+and it can be read without the kernels' plumbing beside it.
 
 The TPU-first shape of the problem (SURVEY.md §5 long-context + §7.5):
   - a fixed pool of `n_slots` sequences decodes in lock-step — one compiled
-    decode program, static shapes, no per-request recompiles
-  - the KV cache lives in HBM as PER-LAYER buffers [n_slots, Hkv, dh, S]
-    (S-minor: zero tile-padding waste; per-layer: no stacked-cache slicing
-    in the hot loop — see init_kv_cache_layers) and is DONATED to every
-    prefill/decode call, so XLA updates it in place (no copy per token)
+    decode program a table width, static shapes, no per-request recompiles
   - prefills are bucketed by prompt length (powers of two) to bound the
     number of compiled programs, and multiple admissions are fused into ONE
     prefill dispatch ([K, bucket] prompts scattered into K slots, first token
@@ -14,7 +20,7 @@ The TPU-first shape of the problem (SURVEY.md §5 long-context + §7.5):
   - the decode program runs `decode_block_size` steps under lax.scan per
     dispatch, sampling on device each step and returning a [B, M] token
     block; ALL loop state (current tokens, positions, temperatures, rng,
-    both caches) stays on device between dispatches
+    the pools) stays on device between dispatches
   - up to `pipeline_depth` dispatches are kept in flight; the host syncs the
     oldest block while the device executes the younger ones, so the
     host↔device round-trip and the Python demux loop are overlapped with
@@ -25,10 +31,8 @@ The TPU-first shape of the problem (SURVEY.md §5 long-context + §7.5):
 Safety of speculative decode for freed slots: a freed slot keeps "decoding"
 junk inside already-dispatched blocks. Its junk tokens are discarded on sync
 (the slot's request identity changed), and its junk KV writes are harmless:
-every cache position is written by its current occupant before it is ever
-attended (the mask is j <= q_pos and decode writes position p before reading
-it), and out-of-range writes past the cache end are dropped by XLA scatter
-semantics.
+a freed slot's table row is zeros, so they land in the garbage page
+(paging.PageAllocator).
 
 The reference's analog is the per-topic subscriber loop + per-request
 goroutine bridging (subscriber.go:27-57, handler.go:58-63); here the "broker"
@@ -45,10 +49,7 @@ import threading
 import time
 from typing import Any, Dict, Iterator, List, Optional, Sequence, Set, Tuple
 
-from ..models.llama import (LlamaConfig, init_kv_cache_layers,
-                            init_kv_scale_layers, llama_decode_step_unrolled,
-                            llama_decode_step_unrolled_q8, llama_prefill_chunk,
-                            llama_prefill_last, params_nbytes)
+from ..models.llama import LlamaConfig, params_nbytes
 from .executor import Executor, next_bucket
 from .obs import MetricsHook
 from .ownership import loop_only
@@ -272,10 +273,10 @@ class _Slot:
         self.request: Optional[GenerationRequest] = None
         self.length = 0
         self.remaining = 0
-        self.pages: Optional[List[int]] = None  # paged engine: owned page
+        self.pages: Optional[List[int]] = None  # owned page
         # ids, table order (shared prefix pages first; _finish_slot asks
         # the prefix cache which pages it owns)
-        # chunked prefill in progress: the slot is RESERVED (its cache row
+        # chunked prefill in progress: the slot is RESERVED (its window
         # is being filled chunk by chunk) but not yet emitting — excluded
         # from the free list and from decode demux until the final chunk
         self.chunking: Optional[GenerationRequest] = None
@@ -409,10 +410,8 @@ def _admission_split(n: int, cap: int) -> List[int]:
 
 def spec_accept_epilogue(g, logits0, temps, rng, drafts, draft_lens,
                          positions, d: int, top_k: int):
-    """Speculative-verify acceptance, shared by the dense and paged verify
-    programs (one implementation on purpose — the hand-mirrored copies
-    diverged once already): sample position 0, accept the greedy prefix of
-    matching drafts on greedy-eligible rows, advance loop state.
+    """Speculative-verify acceptance: sample position 0, accept the greedy
+    prefix of matching drafts on greedy-eligible rows, advance loop state.
 
     g: [B, d+1] device greedy continuations; logits0: [B, V] position-0
     logits; temps: [B] or [B, 3] row controls; drafts/draft_lens: [B, d] /
@@ -437,15 +436,9 @@ def spec_accept_epilogue(g, logits0, temps, rng, drafts, draft_lens,
 
 
 class LLMEngine:
-    # capacity-plan mode: the paged subclass plans without the dense cache's
-    # growth/ping-pong transient (its pool is fixed and never carried whole)
-    _plan_paged = False
-
-    # KV hand-off landing: the paged subclass flips this True — its _admit
-    # can restore shipped page blobs (kvtier.PageBlob) into the pool. Used
-    # by disaggregated decode pools AND by elastic drain-with-migration
-    # (fleet/elastic.py); the dense engine always replays from tokens.
-    supports_kv_handoff = False
+    """The loop of the one engine. Constructed only as
+    `paging.PagedLLMEngine`, which fills the hooks at the end of this
+    class (tools/analysis holds that: rule `oneengine`)."""
 
     # adaptive-speculation tuning (class attrs so tests can tighten them):
     # EMA smoothing of accepted-per-slot, the floor below which verify
@@ -460,15 +453,11 @@ class LLMEngine:
     # submit() sheds (503) once the loop has been stuck inside one device
     # call this long. Must clear any LEGITIMATE in-dispatch pause, and the
     # longest is a program that compiles on the loop thread mid-serve (a
-    # table width, a fused-admission width or a dense-cache growth that
-    # warm-up left cold). On a v5e with nothing cached the slowest of the
-    # 32 programs of a llama1b paged boot compiled in 4.2 s and its decode
-    # programs in 2.9-3.3 s (chip_smoke.py run, PR 21). The dense engine's
-    # unrolled programs were not measured on the chip; compiled for a
-    # described chip in the sandbox they take about three times as long
-    # (10-11 s at 16 layers), and a 32-layer model doubles that. 60 s
-    # clears a 32-layer unrolled compile twice over. Class attr so
-    # deployments and tests can tune it per instance.
+    # table width or a fused-admission width that warm-up left cold). On a
+    # v5e with nothing cached the slowest of the 32 programs of a llama1b
+    # boot compiled in 4.2 s and its decode programs in 2.9-3.3 s
+    # (chip_smoke.py run, PR 21). 60 s clears that many times over.
+    # Class attr so deployments and tests can tune it per instance.
     STALL_REJECT_S = 60.0
 
     def __init__(
@@ -540,11 +529,6 @@ class LLMEngine:
                     f"n_heads={cfg.n_heads} (whole heads per shard)")
             params = shard_params(params, mesh,
                                   serving_param_specs(quantized=self._w8))
-        if not self._plan_paged and not isinstance(cfg, LlamaConfig):
-            raise ValueError(
-                f"the dense engine serves models/llama.py only; "
-                f"{type(cfg).__name__} is served by PagedLLMEngine through "
-                f"the model protocol (models/protocol.py)")
         self.params = params
         self.cfg = cfg
         self.n_slots = n_slots
@@ -552,7 +536,7 @@ class LLMEngine:
         self.prefill_buckets = tuple(b for b in prefill_buckets if b <= self.max_seq_len)
         # HBM budget discipline (VERDICT r2 missing #2): when a budget is
         # known, the capacity plan clamps (n_slots, max_seq_len) so params +
-        # caches + growth/prefill transients fit — instead of discovering
+        # pool + prefill transients fit — instead of discovering
         # RESOURCE_EXHAUSTED mid-serve
         self.plan = None
         if budget_bytes is not None and budget_bytes > 0:
@@ -572,7 +556,6 @@ class LLMEngine:
             self.plan = plan_capacity(cfg, self.n_slots, self.max_seq_len,
                                       budget_bytes,
                                       prefill_buckets=self.prefill_buckets,
-                                      paged=self._plan_paged,
                                       params_nbytes=_tree_nbytes(self.params))
             self.n_slots = self.plan.n_slots
             self.max_seq_len = self.plan.max_seq_len
@@ -581,34 +564,6 @@ class LLMEngine:
             if logger is not None:
                 (logger.warnf if self.plan.clamped else logger.infof)(
                     "%s", self.plan.summary())
-        # the Pallas decode kernel reads the cache in min(512, S)-wide
-        # blocks and requires S to divide evenly. Grow targets are powers
-        # of two (always compliant) EXCEPT when clamped to max_seq_len —
-        # a 1000- or 1536-token cap would raise "S must divide by block_s"
-        # MID-SERVING on the first grow that hits the cap (ADVICE r3).
-        # Round the cap down at boot instead: fail loud at config time,
-        # never in the serving loop. (Paged engines never hit this read.)
-        # a family with one decode read and no lower-precision cache has
-        # neither field (models/protocol.py)
-        decode_attn = getattr(cfg, "decode_attn", "xla")
-        kv_dtype = getattr(cfg, "kv_dtype", None)
-        if (decode_attn == "kernel" and not self._plan_paged
-                and self.max_seq_len > 512 and self.max_seq_len % 512):
-            rounded = (self.max_seq_len // 512) * 512
-            if logger is not None:
-                logger.warnf(
-                    "max_seq_len %d rounded down to %d: decode_attn='kernel' "
-                    "needs the clamped cache length to divide into 512-wide "
-                    "blocks", self.max_seq_len, rounded)
-            self.max_seq_len = rounded
-            self.prefill_buckets = tuple(b for b in self.prefill_buckets
-                                         if b <= rounded)
-            if not self.prefill_buckets:
-                raise ValueError(
-                    f"decode_attn='kernel' rounded max_seq_len to {rounded} "
-                    f"and no prefill bucket fits under it — requests could "
-                    f"be accepted but never admitted; configure a bucket "
-                    f"<= {rounded} or a 512-aligned max_seq_len")
         self.top_k = top_k
         self.decode_block_size = max(1, decode_block_size)
         self.pipeline_depth = max(1, pipeline_depth)
@@ -619,25 +574,21 @@ class LLMEngine:
         self._seed = seed
         self._reset_counter = itertools.count(seed)
 
-        # attention impls are part of program identity: the in-memory
+        # what picks a program's code is part of its name: the in-memory
         # compile cache keys on (name, shapes), and an executor shared
-        # across engines with different cfg.attn_impl/decode_attn must not
-        # hand one config the other's compiled program. Prefill names carry
-        # the attn_impl (its T==S window hits the flash branch); decode
-        # names carry decode_attn (its T=1 read hits the kernel branch).
-        # "-w8" marks int8-weight trees, "-sc" the widened sampling
-        # state: the arg-shape cache key already separates them, but names
-        # must too (disk-cache filenames and the "program identity is
-        # visible in logs" rule). Every program-name site (prefill/chunk/
-        # decode/verify + the paged subclass) carries the tag
+        # across engines must not hand one the other's compiled program.
+        # "-w8" marks int8-weight trees, "-sc" the widened sampling state:
+        # the arg-shape cache key already separates them, but names must
+        # too (disk-cache filenames and the "program identity is visible
+        # in logs" rule). Every program-name site carries the tag
         self._id_tag = ("-w8" if self._w8 else "") + (
             "-sc" if self.sampling_controls else "")
-        self._attn_suffix = ("-flash" if cfg.attn_impl == "flash"
-                             else "") + self._id_tag
 
-        # int8 KV cache: halves cache HBM traffic (the decode bandwidth
-        # bound) and doubles context per GiB. Quantize-on-write + kernel
-        # dequant only — the XLA einsum read would materialize a bf16 copy
+        # int8 KV pools: halve pool HBM traffic (the decode bandwidth
+        # bound) and double context per GiB. Quantize-on-write, dequant
+        # folded into the paged kernel's dots. A family with no
+        # lower-precision cache has no such field (models/protocol.py)
+        kv_dtype = getattr(cfg, "kv_dtype", None)
         if kv_dtype not in (None, "int8", cfg.dtype):
             # a float kv_dtype differing from cfg.dtype would make the
             # capacity plan (which reads kv_dtype) and the allocation
@@ -645,12 +596,6 @@ class LLMEngine:
             raise ValueError(f"kv_dtype={kv_dtype!r} not supported; "
                              f"use None or 'int8'")
         self._q8 = kv_dtype == "int8"
-        if self._q8:
-            # the paged engine's decode read is ALWAYS its paged kernel, so
-            # the dense-path requirement doesn't apply there
-            if decode_attn != "kernel" and not self._plan_paged:
-                raise ValueError("kv_dtype='int8' requires decode_attn="
-                                 "'kernel' (no efficient XLA dequant read)")
 
         # speculative decoding (prompt-lookup drafting): d > 0 replaces the
         # block-decode dispatch with a VERIFY dispatch scoring each slot's
@@ -692,7 +637,7 @@ class LLMEngine:
         self._pending: "queue.PriorityQueue" = queue.PriorityQueue()
         # priority-ordered admission heap: (priority, id, request)
         # entries merged from _pending each loop round; requests parked on
-        # a subclass resource (paged engine: free pages) stay here — see
+        # free pages stay here — see
         # _admit for the ordering/fairness rules. Loop-thread-only.
         self._admission_heap: List[tuple] = []
         self._wake = threading.Event()
@@ -708,7 +653,7 @@ class LLMEngine:
         # drain(): reject new work, let active generations finish
         self._draining = False
         self._thread: Optional[threading.Thread] = None
-        # serializes device-state mutation (cache growth, program dispatch)
+        # serializes device-state mutation (program dispatch)
         # between the engine loop and boot-time warmup() on the caller thread
         self._state_lock = threading.Lock()
         self._jnp = jnp
@@ -798,14 +743,11 @@ class LLMEngine:
         # entering decode, "decode" = this engine accepts pre-filled-KV
         # admissions (submit_handoff) and only dispatches a prefill as the
         # lost-hand-off recompute fallback. KV ships page-granular
-        # (kvtier.PageBlob), so both roles require the paged engine.
+        # (kvtier.PageBlob).
         self.disagg_role = str(disagg_role or "")
         if self.disagg_role not in ("", "prefill", "decode"):
             raise ValueError(f"disagg_role={disagg_role!r}: "
                              f"use '', 'prefill' or 'decode'")
-        if self.disagg_role and not self._plan_paged:
-            raise ValueError("disaggregated serving requires the paged "
-                             "engine (KV hands off as page blobs)")
         if self.disagg_role and admission_plane is not None:
             raise ValueError(
                 "disaggregated roles are single-controller only; the "
@@ -857,61 +799,8 @@ class LLMEngine:
         self._finisher: Optional[_Finisher] = (
             _Finisher(finisher_queue) if finisher_queue > 0 else None)
 
-        self._init_device_state()
-
         # rolling throughput window
         self._tok_window: "collections.deque" = collections.deque()
-
-    def _init_device_state(self) -> None:
-        jnp = self._jnp
-        import jax
-
-        B = self.n_slots
-        # allocate the cache at the smallest bucket and grow on demand:
-        # per-step cost scales with the ALLOCATED seq dim (the scatter walks
-        # the whole buffer), so capacity tracks the live contexts, not
-        # max_seq_len (measured 1.8x decode throughput on v5e at 512 alloc
-        # vs 256 for ~136-token contexts)
-        self._cache_len = min(self.max_seq_len,
-                              max(16, min(self.prefill_buckets or (16,))))
-        # PER-LAYER cache buffers (tuples of [B, Hkv, dh, S]): slicing a
-        # stacked [L, ...] cache inside the decode loop ran at ~36 GB/s
-        # effective on v5e (167 ms/step at B=128/S=1024); separate buffers
-        # with an unrolled layer loop run 35 ms/step — see
-        # init_kv_cache_layers
-        self.k_cache, self.v_cache = init_kv_cache_layers(
-            self.cfg, B, self._cache_len,
-            dtype="int8" if self._q8 else None)
-        self.k_scale = self.v_scale = None
-        if self._q8:
-            self.k_scale, self.v_scale = init_kv_scale_layers(
-                self.cfg, B, self._cache_len)
-        self._tokens = jnp.zeros((B,), dtype=jnp.int32)
-        self._positions = jnp.zeros((B,), dtype=jnp.int32)
-        self._temps = self._temps_init(B)
-        self.rng = jax.random.PRNGKey(next(self._reset_counter))
-        if self.mesh is not None:
-            self._place_state()
-
-    def _place_cache(self) -> None:
-        """Commit the cache buffers (and, for int8, their scale buffers) to
-        the mesh: KV heads over tp. Called at init and after every growth
-        re-pad — the two sites MUST place identically or grown caches would
-        serve with a different sharding than fresh ones."""
-        import jax
-        from jax.sharding import NamedSharding
-
-        from ..parallel.sharding import kv_cache_layer_spec, kv_scale_layer_spec
-
-        cache_s = NamedSharding(self.mesh, kv_cache_layer_spec())
-        self.k_cache = tuple(jax.device_put(k, cache_s) for k in self.k_cache)
-        self.v_cache = tuple(jax.device_put(v, cache_s) for v in self.v_cache)
-        if self._q8:
-            scale_s = NamedSharding(self.mesh, kv_scale_layer_spec())
-            self.k_scale = tuple(jax.device_put(s, scale_s)
-                                 for s in self.k_scale)
-            self.v_scale = tuple(jax.device_put(s, scale_s)
-                                 for s in self.v_scale)
 
     def _temps_init(self, rows: int):
         """Zeroed per-row sampling state: [rows] temperatures, or [rows, 3]
@@ -920,85 +809,12 @@ class LLMEngine:
         shape = (rows, 3) if self.sampling_controls else (rows,)
         return jnp.zeros(shape, dtype=jnp.float32)
 
-    def _place_state(self) -> None:
-        """Commit device state to the mesh: cache KV-heads over tp, loop
-        state replicated. Committed shardings propagate into every compiled
-        program; XLA inserts the tp collectives."""
-        import jax
-        from jax.sharding import NamedSharding, PartitionSpec
-
-        self._place_cache()
-        rep = NamedSharding(self.mesh, PartitionSpec())
-        self._tokens = jax.device_put(self._tokens, rep)
-        self._positions = jax.device_put(self._positions, rep)
-        self._temps = jax.device_put(self._temps, rep)
-        self.rng = jax.device_put(self.rng, rep)
-
-    def _grow_cache(self, needed: int) -> None:
-        """Pad the KV cache's seq dim to the next power-of-two bucket
-        covering `needed` (one-time copy; capped at max_seq_len).
-
-        The copy runs under jit with BOTH old caches donated, so XLA frees
-        each source buffer as soon as its copy completes — peak transient is
-        old+new for one cache at a time, not both (the capacity plan budgets
-        cache/2 for this). Compiled through the executor cache so repeated
-        regrowth after resets reuses the program instead of recompiling."""
-        jnp = self._jnp
-        new_len = min(self.max_seq_len, 1 << (max(needed, 16) - 1).bit_length())
-        if new_len <= self._cache_len:
-            return
-        pad = ((0, 0), (0, 0), (0, 0), (0, new_len - self._cache_len))
-        spad = pad[1:]  # scale buffers are [B, Hkv, S]
-
-        def grow_fn(k_layers, v_layers):
-            return (tuple(_pin_standard_layout(jnp.pad(k, pad)) for k in k_layers),
-                    tuple(_pin_standard_layout(jnp.pad(v, pad)) for v in v_layers))
-
-        def grow_fn_q8(k_layers, v_layers, ks_layers, vs_layers):
-            k, v = grow_fn(k_layers, v_layers)
-            return (k, v,
-                    tuple(jnp.pad(s, spad) for s in ks_layers),
-                    tuple(jnp.pad(s, spad) for s in vs_layers))
-
-        try:
-            with self.steps.seg("cache_grow"):
-                if self.faults is not None:
-                    self.faults.hit("engine.cache_grow")
-                if self._q8:
-                    program = self.executor.compile(
-                        f"kv-grow-q8-{self._cache_len}-to-{new_len}",
-                        grow_fn_q8,
-                        (self.k_cache, self.v_cache, self.k_scale,
-                         self.v_scale),
-                        donate_argnums=(0, 1, 2, 3))
-                    (self.k_cache, self.v_cache, self.k_scale,
-                     self.v_scale) = program(self.k_cache, self.v_cache,
-                                             self.k_scale, self.v_scale)
-                else:
-                    program = self.executor.compile(
-                        f"kv-grow-{self._cache_len}-to-{new_len}", grow_fn,
-                        (self.k_cache, self.v_cache), donate_argnums=(0, 1))
-                    self.k_cache, self.v_cache = program(self.k_cache,
-                                                         self.v_cache)
-        except Exception as exc:
-            # the grow program consumed the donated caches: this is a
-            # device-state loss, not a host-prep failure — _admit's per-wave
-            # handler must NOT swallow it
-            raise CacheLostError(f"cache growth to {new_len} failed: {exc}") from exc
-        if self.mesh is not None:  # re-commit: pad must not drop the sharding
-            self._place_cache()
-        self._cache_len = new_len
-        if self.recorder is not None:
-            self.recorder.record_engine_event("cache_grow", new_len=new_len)
-        if self.logger is not None:
-            self.logger.debugf("grew KV cache to %d", new_len)
-
     # -- public API -----------------------------------------------------------
     @property
     def admission_limit(self) -> int:
         """Longest admissible prompt: the largest prefill bucket, bounded so
         the first decode step's KV write (at position len(prompt)) stays
-        inside the cache's logical seq dim."""
+        inside max_seq_len."""
         bucket_limit = (self.prefill_buckets[-1] if self.prefill_buckets
                         else self.max_seq_len)
         return min(bucket_limit, self.max_seq_len - 1)
@@ -1179,8 +995,8 @@ class LLMEngine:
         request admits at ``prompt + emitted`` with its REMAINING budget
         and nothing already delivered is ever re-emitted.
 
-        blobs (one kvtier.PageBlob per full-or-partial prompt page, paged
-        decode-role engines only) short-circuits the prefill recompute:
+        blobs (one kvtier.PageBlob per full-or-partial prompt page; not on
+        a prefill-role engine) short-circuits the prefill recompute:
         admission validates each blob against this pool's shape/dtype,
         lands the KV with the donated H2D scatter under the ``kv_handoff``
         step segment, and the slot binds straight into decode. blobs=None
@@ -1212,8 +1028,8 @@ class LLMEngine:
         if not prompt_tokens:
             raise ValueError("prompt_tokens must be non-empty")
         if blobs is not None and not self._lands_handoffs:
-            raise ValueError("KV blobs require a paged engine outside the "
-                             "prefill role")
+            raise ValueError("KV blobs cannot land on a prefill-role "
+                             "engine")
         if (top_p or top_k) and not self.sampling_controls:
             raise ValueError("per-request top_p/top_k need an engine built "
                              "with sampling_controls=True")
@@ -1390,11 +1206,11 @@ class LLMEngine:
 
     @property
     def _lands_handoffs(self) -> bool:
-        """True when this engine can restore shipped KV page blobs at
-        admission: the paged pool outside the prefill disagg role. Decode
-        pools land disagg hand-offs; ANY colocated paged replica lands
-        elastic migration exports."""
-        return self.supports_kv_handoff and self.disagg_role != "prefill"
+        """True when this engine restores shipped KV page blobs at
+        admission: any role but the prefill disagg role. Decode pools land
+        disagg hand-offs; ANY colocated replica lands elastic migration
+        exports."""
+        return self.disagg_role != "prefill"
 
     def request_migration(self, sink) -> None:
         """Ask the loop to export every live decode session to ``sink``
@@ -1479,367 +1295,7 @@ class LLMEngine:
         self._obs.gauge("app_tpu_active_slots",
                         sum(1 for s in self.slots if s.active))
 
-    def _export_slot_kv(self, slot, request):
-        """(blobs, n_ctx) for a migration export. The dense engine ships
-        nothing — blobs=None means the peer replays prompt+emitted (the
-        crash-only recompute contract), which is always correct, just not
-        prefill-free. The paged engine overrides this with the D2H page
-        pull (paging._handoff_slot's recipe)."""
-        return None, max(0, len(request.resume_tokens) - 1)
-
-    def warmup(self, grow: bool = True, k_variants: bool = False) -> None:
-        """Pre-compile single-admission prefill buckets and the decode
-        program. Programs for grown cache sizes (and batched-K prefill
-        variants) compile on first use — one ~1s hiccup per power-of-two
-        growth over the engine's lifetime.
-
-        k_variants=True additionally compiles every fused-admission width
-        per bucket (_admission_widths). Organic (staggered) arrivals admit
-        in unpredictable group sizes, so without this a production server
-        pays a first-use compile mid-request whenever traffic first
-        produces a new (bucket, K) — the TTFT spike the HTTP-boundary
-        bench phase exposed. Costs buckets x log4(slots) compiles at boot,
-        amortized by the compile cache.
-
-        grow=True (server boot) grows the cache to cover the largest prefill
-        bucket up front so no request pays a growth copy; grow=False grows
-        only to the smallest SERVABLE size (min bucket + 1 — dispatch always
-        needs one decode-write slot past the prompt) so short-context
-        workloads keep a small allocation (per-step decode cost tracks the
-        ALLOCATED seq dim) while the warmed programs are the ones the first
-        request actually runs.
-
-        Safe against an already-started loop: cache growth and compiles run
-        under the same state lock the loop's dispatch phase takes."""
-        with self._state_lock:
-            if self.prefill_buckets:
-                target = (max(self.prefill_buckets) if grow
-                          else min(self.prefill_buckets))
-                self._grow_cache(target + 1)
-            chunk = self.chunk_prefill_tokens
-            ks = (sorted(_admission_widths(self.n_slots)) if k_variants
-                  else [1])
-            for bucket in self.prefill_buckets:
-                # a bucket is compilable once it fits the allocated cache
-                # (bucket == cache uses the full-row splice branch); buckets
-                # routed to the chunk path skip the (dead) fused program
-                if bucket <= self._cache_len and not (chunk and bucket > chunk):
-                    for K in ks:
-                        self._prefill_program(bucket, K)
-                    if self.logger is not None:
-                        self.logger.debugf("warmed prefill bucket %d", bucket)
-            if chunk and any(b > chunk for b in self.prefill_buckets):
-                # chunk-program shapes depend on (chunk, K) only; warm the
-                # first/middle/final variants the first long prompt hits
-                for K in ks:
-                    self._chunk_program(chunk, K, first=True, final=False)
-                    self._chunk_program(chunk, K, first=False, final=True)
-                    if any(b > 2 * chunk for b in self.prefill_buckets):
-                        self._chunk_program(chunk, K, first=False,
-                                            final=False)
-            if self.speculative_tokens:
-                self._verify_program()
-            # adaptive cooloff (spec mode) falls back to exactly these
-            # block-decode programs: warm both variants either way
-            self._decode_program()
-            if self.decode_block_size > 1:  # adaptive short-block variant
-                self._decode_program(max(1, self.decode_block_size // 2))
-
     # -- compiled programs ----------------------------------------------------
-    def _prefill_fn(self, bucket: int, K: int):
-        cfg, mesh = self.cfg, self.mesh
-        jnp = self._jnp
-        top_k = self.top_k
-
-        def prefill(params, k_cache, v_cache, ptokens, slots, lengths,
-                    tokens, positions, temps, new_temps, rng):
-            """Fused K-way admission: prefill K prompts ([K, bucket]) into K
-            slot rows, sample their first tokens on device, and splice the
-            per-slot loop state (tokens/positions/temps) in one program.
-            Returns (k_cache, v_cache, tokens, positions, temps, rng,
-            first_tokens [K]).
-
-            Only each row's LAST prompt position is projected through
-            lm_head ([K, D] gather before the vocab matmul) — the full
-            [K, bucket, V] float32 logits would be GBs per fused admission
-            at Llama-3 vocab and was the round-2 bench OOM suspect.
-
-            k_cache/v_cache are PER-LAYER tuples ([B, Hkv, dh, S] each,
-            init_kv_cache_layers); the prefill forward still runs the
-            stacked-scan body (one compile regardless of depth), then the
-            splice unrolls per layer into the separate buffers."""
-            L = cfg.n_layers
-            S = k_cache[0].shape[-1]
-            Hkv, dh = cfg.n_kv_heads, cfg.head_dim
-            k_cache = tuple(_pin_standard_layout(k) for k in k_cache)
-            v_cache = tuple(_pin_standard_layout(v) for v in v_cache)
-            tmp_k = jnp.zeros((L, K, Hkv, dh, bucket), dtype=k_cache[0].dtype)
-            tmp_v = jnp.zeros_like(tmp_k)
-            tmp_k, tmp_v = _pin_standard_layout(tmp_k, tmp_v)
-            pos_grid = jnp.broadcast_to(
-                jnp.arange(bucket, dtype=jnp.int32)[None, :], (K, bucket))
-            last, tmp_k, tmp_v = llama_prefill_last(
-                params, cfg, ptokens, pos_grid, lengths, tmp_k, tmp_v, mesh)
-            # splice: scatter rows along the batch axis with a STATIC seq
-            # slice, per layer (tmp_k[l] is a static slice of a temp)
-            if bucket == S:
-                k_cache = tuple(k_cache[l].at[slots].set(tmp_k[l])
-                                for l in range(L))
-                v_cache = tuple(v_cache[l].at[slots].set(tmp_v[l])
-                                for l in range(L))
-            else:
-                k_cache = tuple(k_cache[l].at[slots, :, :, :bucket].set(tmp_k[l])
-                                for l in range(L))
-                v_cache = tuple(v_cache[l].at[slots, :, :, :bucket].set(tmp_v[l])
-                                for l in range(L))
-            first, rng = sample_tokens(last, rng, new_temps, top_k=top_k)
-            tokens = tokens.at[slots].set(first)
-            positions = positions.at[slots].set(lengths)
-            temps = temps.at[slots].set(new_temps)
-            k_cache = tuple(_pin_standard_layout(k) for k in k_cache)
-            v_cache = tuple(_pin_standard_layout(v) for v in v_cache)
-            return k_cache, v_cache, tokens, positions, temps, rng, first
-
-        return prefill
-
-    def _prefill_fn_q8(self, bucket: int, K: int):
-        """Fused K-way admission into the INT8 cache: the window forward
-        runs full-precision into bf16 temps (prefill accuracy is free —
-        the temps never hit HBM as cache), then values quantize per
-        token/head at the splice.
-
-        MIRRORS _prefill_fn with (k_scale, v_scale) threaded through; a
-        behavioral change to the splice/sampling there must land here too
-        (kept separate so each program's donated signature stays legible).
-        """
-        cfg, mesh = self.cfg, self.mesh
-        jnp = self._jnp
-        top_k = self.top_k
-
-        def prefill(params, k_cache, v_cache, k_scale, v_scale, ptokens,
-                    slots, lengths, tokens, positions, temps, new_temps, rng):
-            from ..ops.decode_attention import quantize_kv
-
-            L = cfg.n_layers
-            S = k_cache[0].shape[-1]
-            Hkv, dh = cfg.n_kv_heads, cfg.head_dim
-            k_cache = tuple(_pin_standard_layout(k) for k in k_cache)
-            v_cache = tuple(_pin_standard_layout(v) for v in v_cache)
-            from ..models.llama import _np_dtype
-
-            tmp_k = jnp.zeros((L, K, Hkv, dh, bucket), dtype=_np_dtype(cfg.dtype))
-            tmp_v = jnp.zeros_like(tmp_k)
-            tmp_k, tmp_v = _pin_standard_layout(tmp_k, tmp_v)
-            pos_grid = jnp.broadcast_to(
-                jnp.arange(bucket, dtype=jnp.int32)[None, :], (K, bucket))
-            last, tmp_k, tmp_v = llama_prefill_last(
-                params, cfg, ptokens, pos_grid, lengths, tmp_k, tmp_v, mesh)
-            k8, ks = quantize_kv(tmp_k, axis=-2)   # [L,K,Hkv,d,b] -> scales [L,K,Hkv,b]
-            v8, vs = quantize_kv(tmp_v, axis=-2)
-            if bucket == S:
-                k_cache = tuple(k_cache[l].at[slots].set(k8[l]) for l in range(L))
-                v_cache = tuple(v_cache[l].at[slots].set(v8[l]) for l in range(L))
-                k_scale = tuple(k_scale[l].at[slots].set(ks[l]) for l in range(L))
-                v_scale = tuple(v_scale[l].at[slots].set(vs[l]) for l in range(L))
-            else:
-                k_cache = tuple(k_cache[l].at[slots, :, :, :bucket].set(k8[l])
-                                for l in range(L))
-                v_cache = tuple(v_cache[l].at[slots, :, :, :bucket].set(v8[l])
-                                for l in range(L))
-                k_scale = tuple(k_scale[l].at[slots, :, :bucket].set(ks[l])
-                                for l in range(L))
-                v_scale = tuple(v_scale[l].at[slots, :, :bucket].set(vs[l])
-                                for l in range(L))
-            first, rng = sample_tokens(last, rng, new_temps, top_k=top_k)
-            tokens = tokens.at[slots].set(first)
-            positions = positions.at[slots].set(lengths)
-            temps = temps.at[slots].set(new_temps)
-            k_cache = tuple(_pin_standard_layout(k) for k in k_cache)
-            v_cache = tuple(_pin_standard_layout(v) for v in v_cache)
-            return (k_cache, v_cache, k_scale, v_scale, tokens, positions,
-                    temps, rng, first)
-
-        return prefill
-
-    @program_lookup
-    def _prefill_program(self, bucket: int, K: int):
-        jnp = self._jnp
-        if self._q8:
-            args = (self.params, self.k_cache, self.v_cache, self.k_scale,
-                    self.v_scale,
-                    jnp.zeros((K, bucket), dtype=jnp.int32),
-                    jnp.zeros((K,), dtype=jnp.int32),
-                    jnp.ones((K,), dtype=jnp.int32),
-                    self._tokens, self._positions, self._temps,
-                    self._temps_init(K), self.rng)
-            return self.executor.compile(
-                f"llama-prefill-q8-{bucket}x{K}-S{self._cache_len}"
-                f"{self._attn_suffix}",
-                self._prefill_fn_q8(bucket, K),
-                args, donate_argnums=(1, 2, 3, 4, 8, 9, 10))
-        args = (self.params, self.k_cache, self.v_cache,
-                jnp.zeros((K, bucket), dtype=jnp.int32),
-                jnp.zeros((K,), dtype=jnp.int32),
-                jnp.ones((K,), dtype=jnp.int32),
-                self._tokens, self._positions, self._temps,
-                self._temps_init(K), self.rng)
-        return self.executor.compile(
-            f"llama-prefill-{bucket}x{K}-S{self._cache_len}"
-            f"{self._attn_suffix}",
-            self._prefill_fn(bucket, K),
-            args, donate_argnums=(1, 2, 6, 7, 8))
-
-    def _chunk_fn(self, chunk: int, K: int, first: bool, final: bool):
-        """One chunked-prefill dispatch: process tokens [K, chunk] at
-        absolute positions [start..start+chunk) against the live cache rows
-        (llama_prefill_chunk), fold this chunk's last-position logits into
-        the carried `selected` buffer (a short row's last token may fall in
-        ANY chunk), and on the first/final chunk handle slot parking /
-        sampling+splice."""
-        cfg, mesh = self.cfg, self.mesh
-        jnp = self._jnp
-        top_k = self.top_k
-
-        def run_chunk(params, k_cache, v_cache, ctokens, cpositions, slots,
-                      lengths, start, selected, tokens, positions, temps,
-                      new_temps, rng):
-            # start is a traced scalar; chunk/K are static
-            k_cache = tuple(_pin_standard_layout(k) for k in k_cache)
-            v_cache = tuple(_pin_standard_layout(v) for v in v_cache)
-            logits, k_cache, v_cache = llama_prefill_chunk(
-                params, cfg, ctokens, cpositions, k_cache, v_cache, slots,
-                project_last=jnp.clip(lengths - 1 - start, 0, chunk - 1),
-                mesh=mesh)
-            in_chunk = ((lengths - 1 >= start)
-                        & (lengths - 1 < start + chunk))       # [K]
-            selected = jnp.where(in_chunk[:, None], logits, selected)
-            if first:
-                # PARK the reserved slots' decode positions at the cache
-                # tail: decode blocks interleaving with later chunks write
-                # their lock-step junk there, never inside the prompt range
-                park = k_cache[0].shape[-1] - 1
-                positions = positions.at[slots].set(park)
-            if final:
-                first_tok, rng = sample_tokens(selected, rng, new_temps,
-                                               top_k=top_k)
-                tokens = tokens.at[slots].set(first_tok)
-                positions = positions.at[slots].set(lengths)
-                temps = temps.at[slots].set(new_temps)
-            else:
-                first_tok = selected[:, 0].astype(jnp.int32)  # unused filler
-            k_cache = tuple(_pin_standard_layout(k) for k in k_cache)
-            v_cache = tuple(_pin_standard_layout(v) for v in v_cache)
-            return (k_cache, v_cache, selected, tokens, positions, temps,
-                    rng, first_tok)
-
-        return run_chunk
-
-    def _chunk_fn_q8(self, chunk: int, K: int, first: bool, final: bool):
-        """MIRRORS _chunk_fn over the int8 cache + scale buffers (see
-        _prefill_fn_q8 note; the chunk forward is llama_prefill_chunk_q8)."""
-        cfg = self.cfg
-        jnp = self._jnp
-        top_k = self.top_k
-
-        def run_chunk(params, k_cache, v_cache, k_scale, v_scale, ctokens,
-                      cpositions, slots, lengths, start, selected, tokens,
-                      positions, temps, new_temps, rng):
-            from ..models.llama import llama_prefill_chunk_q8
-
-            k_cache = tuple(_pin_standard_layout(k) for k in k_cache)
-            v_cache = tuple(_pin_standard_layout(v) for v in v_cache)
-            logits, k_cache, v_cache, k_scale, v_scale = \
-                llama_prefill_chunk_q8(
-                    params, cfg, ctokens, cpositions, k_cache, v_cache,
-                    k_scale, v_scale, slots,
-                    project_last=jnp.clip(lengths - 1 - start, 0, chunk - 1))
-            in_chunk = ((lengths - 1 >= start)
-                        & (lengths - 1 < start + chunk))       # [K]
-            selected = jnp.where(in_chunk[:, None], logits, selected)
-            if first:
-                park = k_cache[0].shape[-1] - 1
-                positions = positions.at[slots].set(park)
-            if final:
-                first_tok, rng = sample_tokens(selected, rng, new_temps,
-                                               top_k=top_k)
-                tokens = tokens.at[slots].set(first_tok)
-                positions = positions.at[slots].set(lengths)
-                temps = temps.at[slots].set(new_temps)
-            else:
-                first_tok = selected[:, 0].astype(jnp.int32)  # unused filler
-            k_cache = tuple(_pin_standard_layout(k) for k in k_cache)
-            v_cache = tuple(_pin_standard_layout(v) for v in v_cache)
-            return (k_cache, v_cache, k_scale, v_scale, selected, tokens,
-                    positions, temps, rng, first_tok)
-
-        return run_chunk
-
-    @program_lookup
-    def _chunk_program(self, chunk: int, K: int, first: bool, final: bool):
-        jnp = self._jnp
-        tag = (f"{'-first' if first else ''}{'-final' if final else ''}"
-               f"-S{self._cache_len}{self._id_tag}")
-        if self._q8:
-            args = (self.params, self.k_cache, self.v_cache, self.k_scale,
-                    self.v_scale,
-                    jnp.zeros((K, chunk), dtype=jnp.int32),
-                    jnp.zeros((K, chunk), dtype=jnp.int32),
-                    jnp.zeros((K,), dtype=jnp.int32),
-                    jnp.ones((K,), dtype=jnp.int32),
-                    jnp.zeros((), dtype=jnp.int32),
-                    jnp.zeros((K, self.cfg.vocab_size), dtype=jnp.float32),
-                    self._tokens, self._positions, self._temps,
-                    self._temps_init(K), self.rng)
-            return self.executor.compile(
-                f"llama-chunk-q8-{chunk}x{K}{tag}",
-                self._chunk_fn_q8(chunk, K, first, final), args,
-                donate_argnums=(1, 2, 3, 4, 10, 11, 12, 13))
-        args = (self.params, self.k_cache, self.v_cache,
-                jnp.zeros((K, chunk), dtype=jnp.int32),
-                jnp.zeros((K, chunk), dtype=jnp.int32),
-                jnp.zeros((K,), dtype=jnp.int32),
-                jnp.ones((K,), dtype=jnp.int32),
-                jnp.zeros((), dtype=jnp.int32),
-                jnp.zeros((K, self.cfg.vocab_size), dtype=jnp.float32),
-                self._tokens, self._positions, self._temps,
-                self._temps_init(K), self.rng)
-        return self.executor.compile(
-            f"llama-chunk-{chunk}x{K}{tag}",
-            self._chunk_fn(chunk, K, first, final), args,
-            donate_argnums=(1, 2, 8, 9, 10, 11))
-
-    def _start_chunk_job(self, bucket: int, slots_idx: List[int],
-                         batch: List[GenerationRequest]) -> None:
-        """Prep + dispatch the FIRST chunk synchronously (its parking write
-        must land before any later decode dispatch), then register the job.
-        Host-prep failures before the dispatch leave no reservation behind,
-        so _admit's per-wave handler semantics hold unchanged."""
-        import numpy as np
-
-        jnp = self._jnp
-        if bucket + 1 > self._cache_len:
-            self._grow_cache(bucket + 1)
-        with self.steps.seg("host_prep"):
-            ptokens, lengths, new_temps = self._prep_admission(bucket, batch)
-            job = {
-                "batch": batch, "slots_idx": slots_idx, "bucket": bucket,
-                "chunk": self.chunk_prefill_tokens, "next_start": 0,
-                "ptokens": np.asarray(ptokens), "lengths": lengths,
-                "new_temps": new_temps,
-                "selected": jnp.zeros((len(batch), self.cfg.vocab_size),
-                                      dtype=jnp.float32),
-            }
-        self._dispatch_chunk(job)  # chunk 1 parks the positions
-        now = time.monotonic()
-        for row, request in enumerate(batch):
-            request.admitted_at = now
-            self._obs.hist("app_tpu_queue_wait_seconds",
-                           now - request.enqueued_at)
-            self.slots[slots_idx[row]].chunking = request
-            if self.recorder is not None:
-                self.recorder.record_admitted(request, slots_idx[row],
-                                              bucket, chunked=True)
-        self._chunk_jobs.append(job)
 
     def _advance_chunk_job(self) -> None:
         """Dispatch ONE chunk of the oldest job; decode dispatches fill the
@@ -1855,63 +1311,6 @@ class LLMEngine:
         if final:
             self._chunk_jobs.popleft()
             self._finish_chunk_job(job)
-
-    def _dispatch_chunk(self, job) -> bool:
-        """Run the job's next chunk program; returns True when it was the
-        final chunk (job['first_tok'] then holds the sampled tokens)."""
-        import numpy as np
-
-        jnp = self._jnp
-        batch = job["batch"]
-        K = len(batch)
-        chunk = job["chunk"]
-        start = job["next_start"]
-        final = start + chunk >= job["bucket"]
-        ctokens = job["ptokens"][:, start:start + chunk]
-        cpositions = np.broadcast_to(
-            np.arange(start, start + chunk, dtype=np.int32)[None, :],
-            (K, chunk))
-        program = self._chunk_program(chunk, K, first=(start == 0),
-                                      final=final)
-        self.steps.note_dispatch("chunk")
-        try:
-            with self.steps.seg("dispatch"):
-                if self.faults is not None:
-                    self.faults.hit("engine.chunk")
-                if self._q8:
-                    (self.k_cache, self.v_cache, self.k_scale, self.v_scale,
-                     job["selected"], self._tokens, self._positions,
-                     self._temps, self.rng, first_tok) = program(
-                        self.params, self.k_cache, self.v_cache, self.k_scale,
-                        self.v_scale, jnp.asarray(ctokens),
-                        jnp.asarray(cpositions),
-                        jnp.asarray(np.asarray(job["slots_idx"],
-                                               dtype=np.int32)),
-                        jnp.asarray(job["lengths"]),
-                        jnp.asarray(start, dtype=jnp.int32), job["selected"],
-                        self._tokens, self._positions, self._temps,
-                        jnp.asarray(job["new_temps"]), self.rng)
-                else:
-                    (self.k_cache, self.v_cache, job["selected"],
-                     self._tokens, self._positions, self._temps, self.rng,
-                     first_tok) = program(
-                        self.params, self.k_cache, self.v_cache,
-                        jnp.asarray(ctokens), jnp.asarray(cpositions),
-                        jnp.asarray(np.asarray(job["slots_idx"],
-                                               dtype=np.int32)),
-                        jnp.asarray(job["lengths"]),
-                        jnp.asarray(start, dtype=jnp.int32), job["selected"],
-                        self._tokens, self._positions, self._temps,
-                        jnp.asarray(job["new_temps"]), self.rng)
-        except Exception as exc:
-            raise CacheLostError(f"chunk prefill dispatch failed: {exc}") from exc
-        job["next_start"] = start + chunk
-        job["first_tok"] = first_tok
-        if self.recorder is not None:
-            for request in batch:
-                self.recorder.record_event(request.id, "prefill_chunk",
-                                           start=start, final=final)
-        return final
 
     def _finish_chunk_job(self, job) -> None:
         for slot_idx in job["slots_idx"]:
@@ -1930,52 +1329,6 @@ class LLMEngine:
             self.slots[slot_idx].chunking = None
         for request in job["batch"]:
             self._fail_request(request, exc)
-
-    def _decode_fn(self, block: int):
-        cfg, mesh = self.cfg, self.mesh
-        top_k = self.top_k
-        import jax
-
-        def decode(params, k_cache, v_cache, tokens, positions, temps, rng):
-            """`block` lock-step decode steps under scan; loop state chains on
-            device. The cache arrives at its current grown bucket, so
-            per-step HBM traffic tracks the live contexts, not max_seq_len.
-            Returns (k_cache, v_cache, tokens, positions, rng,
-            out_tokens [B, block])."""
-
-            def step(carry, _):
-                k, v, tok, pos, rng = carry
-                logits, k, v = llama_decode_step_unrolled(params, cfg, tok,
-                                                          pos, k, v, mesh)
-                nxt, rng = sample_tokens(logits, rng, temps, top_k=top_k)
-                return (k, v, nxt, pos + 1, rng), nxt
-
-            k_cache = tuple(_pin_standard_layout(k) for k in k_cache)
-            v_cache = tuple(_pin_standard_layout(v) for v in v_cache)
-            (k_cache, v_cache, tok, pos, rng), out = jax.lax.scan(
-                step, (k_cache, v_cache, tokens, positions, rng), None,
-                length=block)
-            k_cache = tuple(_pin_standard_layout(k) for k in k_cache)
-            v_cache = tuple(_pin_standard_layout(v) for v in v_cache)
-            return k_cache, v_cache, tok, pos, rng, out.T  # [B, block]
-
-        return decode
-
-    def _decode_need(self) -> int:
-        """Cache slots every active row needs after this dispatch.
-
-        Host-side slot.length lags the device by the pipelined in-flight
-        blocks, so budget block tokens for each outstanding dispatch plus
-        this one."""
-        longest = max((slot.length for slot in self.slots if slot.active),
-                      default=0)
-        outstanding = len(self._inflight) + 1
-        # adaptive spec interleaves verify (d+1 tokens) and block-decode
-        # dispatches: budget the larger of the two
-        per_dispatch = (max(self.speculative_tokens + 1,
-                            self.decode_block_size)
-                        if self.speculative_tokens else self.decode_block_size)
-        return longest + per_dispatch * outstanding + 1
 
     # -- speculative decoding (prompt-lookup drafting) ------------------------
     def _propose_draft(self, history: List[int]) -> List[int]:
@@ -1998,68 +1351,11 @@ class LLMEngine:
                 return history[i + n: i + n + d]
         return []
 
-    def _verify_fn(self, d: int):
-        cfg, mesh = self.cfg, self.mesh
-        top_k = self.top_k
-
-        def verify(params, k_cache, v_cache, tokens, positions, temps, rng,
-                   drafts, draft_lens):
-            """Score current+drafts, accept the device-computed greedy
-            prefix, and advance all loop state on device. Returns
-            (k, v, tokens, positions, rng, out_tokens [B, d+1], n_emit [B]):
-            row b emits out_tokens[b, :n_emit[b]]."""
-            from ..models.llama import llama_verify_step
-
-            k_cache = tuple(_pin_standard_layout(k) for k in k_cache)
-            v_cache = tuple(_pin_standard_layout(v) for v in v_cache)
-            g, logits0, k_cache, v_cache = llama_verify_step(
-                params, cfg, tokens, drafts, positions, k_cache, v_cache,
-                mesh)
-            tokens, positions, rng, out, n_emit = spec_accept_epilogue(
-                g, logits0, temps, rng, drafts, draft_lens, positions, d,
-                top_k)
-            k_cache = tuple(_pin_standard_layout(k) for k in k_cache)
-            v_cache = tuple(_pin_standard_layout(v) for v in v_cache)
-            return (k_cache, v_cache, tokens, positions, rng, out, n_emit)
-
-        return verify
-
-    @program_lookup
-    def _verify_program(self):
-        jnp = self._jnp
-        d = self.speculative_tokens
-        args = (self.params, self.k_cache, self.v_cache,
-                self._tokens, self._positions, self._temps, self.rng,
-                jnp.zeros((self.n_slots, d), dtype=jnp.int32),
-                jnp.zeros((self.n_slots,), dtype=jnp.int32))
-        # attn/weight suffix rides along (ADVICE r3: program identity must
-        # not silently depend on the verify window never hitting the
-        # flash/kernel branch conditions)
-        name = f"llama-verify-x{d}-S{self._cache_len}{self._attn_suffix}"
-        return self.executor.compile(name, self._verify_fn(d), args,
-                                     donate_argnums=(1, 2))
-
-    def _verify_call(self, drafts, lens):
-        """Compile-or-hit + run the verify program, splicing device state.
-        The paged subclass overrides this (its program carries the block
-        table and reads/writes the pool); the surrounding draft proposal,
-        snapshot, and acceptance-EMA logic in _dispatch_verify is shared."""
-        program = self._verify_program()
-        (self.k_cache, self.v_cache, self._tokens, self._positions,
-         self.rng, out_tokens, n_emit) = program(
-            self.params, self.k_cache, self.v_cache,
-            self._tokens, self._positions, self._temps, self.rng,
-            drafts, lens)
-        return out_tokens, n_emit
-
     def _dispatch_verify(self) -> None:
         import numpy as np
 
         jnp = self._jnp
         d = self.speculative_tokens
-        need = self._decode_need()
-        if need > self._cache_len:
-            self._grow_cache(need)
         drafts = np.zeros((self.n_slots, d), dtype=np.int32)
         lens = np.zeros((self.n_slots,), dtype=np.int32)
         snapshot = []
@@ -2115,53 +1411,6 @@ class LLMEngine:
         # closes dspans by fixed index for non-prefill entries
         self._inflight.append(("verify", (out_tokens, n_emit), snapshot,
                                d, start, dspan))
-
-    def _decode_fn_q8(self, block: int):
-        """MIRRORS _decode_fn with scale buffers in the scan carry; keep
-        the two in sync (see _prefill_fn_q8 note)."""
-        cfg, mesh = self.cfg, self.mesh
-        top_k = self.top_k
-        import jax
-
-        def decode(params, k_cache, v_cache, k_scale, v_scale, tokens,
-                   positions, temps, rng):
-            def step(carry, _):
-                k, v, ks, vs, tok, pos, rng = carry
-                logits, k, v, ks, vs = llama_decode_step_unrolled_q8(
-                    params, cfg, tok, pos, k, v, ks, vs, mesh)
-                nxt, rng = sample_tokens(logits, rng, temps, top_k=top_k)
-                return (k, v, ks, vs, nxt, pos + 1, rng), nxt
-
-            k_cache = tuple(_pin_standard_layout(k) for k in k_cache)
-            v_cache = tuple(_pin_standard_layout(v) for v in v_cache)
-            (k_cache, v_cache, k_scale, v_scale, tok, pos, rng), out = \
-                jax.lax.scan(step, (k_cache, v_cache, k_scale, v_scale,
-                                    tokens, positions, rng), None,
-                             length=block)
-            k_cache = tuple(_pin_standard_layout(k) for k in k_cache)
-            v_cache = tuple(_pin_standard_layout(v) for v in v_cache)
-            return (k_cache, v_cache, k_scale, v_scale, tok, pos, rng,
-                    out.T)
-
-        return decode
-
-    @program_lookup
-    def _decode_program(self, block: Optional[int] = None):
-        block = block or self.decode_block_size
-        if self._q8:
-            args = (self.params, self.k_cache, self.v_cache, self.k_scale,
-                    self.v_scale, self._tokens, self._positions, self._temps,
-                    self.rng)
-            name = f"llama-decode-q8-x{block}-S{self._cache_len}{self._id_tag}"
-            return self.executor.compile(name, self._decode_fn_q8(block),
-                                         args, donate_argnums=(1, 2, 3, 4))
-        args = (self.params, self.k_cache, self.v_cache,
-                self._tokens, self._positions, self._temps, self.rng)
-        suffix = ("-kern" if self.cfg.decode_attn == "kernel"
-                  else "") + self._id_tag
-        name = f"llama-decode-x{block}-S{self._cache_len}{suffix}"
-        return self.executor.compile(name, self._decode_fn(block), args,
-                                     donate_argnums=(1, 2))
 
     # -- engine loop ----------------------------------------------------------
     def _loop(self) -> None:
@@ -2390,7 +1639,7 @@ class LLMEngine:
         # slots — cancels and the drain flag ride waves, and a saturated
         # server is exactly where cancellation must still free capacity
         # ONE priority-ordered admission heap: arrivals from _pending merge
-        # with requests parked earlier on a subclass resource (pages).
+        # with requests parked earlier on free pages.
         # Heap order (priority, id) means a later higher-priority request
         # pops BEFORE a parked lower-priority one (no head-of-line
         # inversion), while same-priority requests stay strictly FIFO —
@@ -2525,7 +1774,7 @@ class LLMEngine:
             for request in itertools.chain(taken, handed):
                 self.qos.note_admitted(request)
 
-        # group by admission bucket (the paged engine's prefix cache may
+        # group by admission bucket (the prefix cache may
         # shrink a request's window to its un-cached tail), then split
         # counts into powers of two
         by_bucket: Dict[int, List[GenerationRequest]] = {}
@@ -2583,15 +1832,14 @@ class LLMEngine:
     def _admission_bucket(self, request: GenerationRequest) -> int:
         """The prefill bucket this request admits under: resume_tokens so a
         replay-after-reset re-admission prefills prompt + already-delivered
-        tokens (identical to the prompt for fresh requests). The paged
-        engine overrides it to the un-cached TAIL's bucket on a prefix
-        hit."""
+        tokens (identical to the prompt for fresh requests). On a prefix
+        hit paging.py narrows it to the un-cached TAIL's bucket."""
         return next_bucket(len(request.resume_tokens), self.prefill_buckets)
 
     def _prep_admission(self, bucket: int, batch: List[GenerationRequest]):
-        """Host-side admission arrays shared by the dense and paged engines:
-        (ptokens [K, bucket], lengths [K], temperatures [K]). Windows are
-        resume_tokens — replayed requests rebuild their full context."""
+        """Host-side admission arrays: (ptokens [K, bucket], lengths [K],
+        temperatures [K]). Windows are resume_tokens — replayed requests
+        rebuild their full context."""
         import numpy as np
 
         from .. import native
@@ -2627,12 +1875,12 @@ class LLMEngine:
     def _bind_slots(self, slots_idx: List[int],
                     batch: List[GenerationRequest], first,
                     bucket: int, batch_id: int, dspan=None) -> None:
-        """Post-dispatch slot bookkeeping shared by dense and paged.
+        """Post-dispatch slot bookkeeping.
 
         Stamps the trace correlation on each request's span: batch.id (the
         fused dispatch this request rode in), tpu.slot, tpu.prefill_bucket.
         """
-        self._start_d2h(first)  # covers every prefill path (dense, paged,
+        self._start_d2h(first)  # covers every prefill path (fused,
         # prefix, chunk final) — they all bind through here
         admitted = []
         now = time.monotonic()
@@ -2673,51 +1921,6 @@ class LLMEngine:
         # (monotonic, like every util/step stamp)
         self._inflight.append(("prefill", first, admitted, dspan,
                                time.monotonic()))
-
-    def _dispatch_prefill(self, bucket: int,
-                          slots_idx: List[int],
-                          batch: List[GenerationRequest]) -> None:
-        import numpy as np
-
-        K = len(batch)
-        jnp = self._jnp
-        with self.steps.seg("host_prep"):
-            ptokens, lengths, new_temps = self._prep_admission(bucket, batch)
-
-        if bucket + 1 > self._cache_len:  # prompts must land inside the cache
-            self._grow_cache(bucket + 1)
-        program = self._prefill_program(bucket, K)
-        self.steps.note_dispatch("prefill")
-        try:
-            with self.steps.seg("dispatch"):
-                if self.faults is not None:
-                    self.faults.hit("engine.prefill")
-                if self._q8:
-                    (self.k_cache, self.v_cache, self.k_scale, self.v_scale,
-                     self._tokens, self._positions, self._temps, self.rng,
-                     first) = program(
-                        self.params, self.k_cache, self.v_cache, self.k_scale,
-                        self.v_scale, jnp.asarray(ptokens),
-                        jnp.asarray(np.asarray(slots_idx, dtype=np.int32)),
-                        jnp.asarray(lengths), self._tokens, self._positions,
-                        self._temps, jnp.asarray(new_temps), self.rng)
-                else:
-                    (self.k_cache, self.v_cache, self._tokens,
-                     self._positions, self._temps, self.rng, first) = program(
-                        self.params, self.k_cache, self.v_cache,
-                        jnp.asarray(ptokens),
-                        jnp.asarray(np.asarray(slots_idx, dtype=np.int32)),
-                        jnp.asarray(lengths), self._tokens, self._positions,
-                        self._temps, jnp.asarray(new_temps), self.rng)
-        except Exception as exc:
-            raise CacheLostError(f"prefill dispatch failed: {exc}") from exc
-
-        with self.steps.seg("bind"):
-            batch_id = next(self._batch_seq)
-            dspan = self._dispatch_span("tpu.prefill", batch_id,
-                                        **{"batch.size": K,
-                                           "tpu.prefill_bucket": bucket})
-            self._bind_slots(slots_idx, batch, first, bucket, batch_id, dspan)
 
     def _decode_block_now(self) -> int:
         """Adaptive block: full blocks for pure decode throughput, half
@@ -2766,44 +1969,6 @@ class LLMEngine:
 
         self._start_d2h(*arrays)
         return [np.asarray(a) for a in arrays]
-
-    def _dispatch_decode(self) -> None:
-        # one decode program per allocated cache size: growth keeps the
-        # allocation (and so the per-step scatter+read cost) tracking the
-        # live contexts, making read-views redundant — and avoiding the
-        # (cache size x view) compile product
-        need = self._decode_need()
-        if need > self._cache_len:
-            self._grow_cache(need)
-        block = self._decode_block_now()
-        program = self._decode_program(block)
-        snapshot = [(i, slot.request) for i, slot in enumerate(self.slots)
-                    if slot.active]
-        self.steps.note_dispatch("decode")
-        start = time.monotonic()
-        try:
-            with self.steps.seg("dispatch"):
-                if self.faults is not None:
-                    self.faults.hit("engine.decode")
-                if self._q8:
-                    (self.k_cache, self.v_cache, self.k_scale, self.v_scale,
-                     self._tokens, self._positions, self.rng, out_tokens) = \
-                        program(self.params, self.k_cache, self.v_cache,
-                                self.k_scale, self.v_scale, self._tokens,
-                                self._positions, self._temps, self.rng)
-                else:
-                    (self.k_cache, self.v_cache, self._tokens,
-                     self._positions, self.rng, out_tokens) = program(
-                        self.params, self.k_cache, self.v_cache,
-                        self._tokens, self._positions, self._temps, self.rng)
-        except Exception as exc:
-            raise CacheLostError(f"decode dispatch failed: {exc}") from exc
-        self._start_d2h(out_tokens)
-        dspan = self._dispatch_span("tpu.decode", next(self._batch_seq),
-                                    **{"batch.size": len(snapshot),
-                                       "tpu.block": block})
-        self._inflight.append(("decode", out_tokens, snapshot,
-                               block, start, dspan))
 
     def _exemplar_of(self, request) -> Dict[str, str]:
         """Histogram exemplar labels for a request: the deep-link payload
@@ -3077,13 +2242,6 @@ class LLMEngine:
         self._obs.hist("app_tpu_batch_size", n_active)
         self._track_throughput(emitted)
 
-    def _note_model_counts(self, tokens_host, block: int) -> None:
-        """The dense engine's model counts nothing."""
-
-    def _note_page_writes(self, live, block: int) -> int:
-        """The dense engine has no pages to write."""
-        return 0
-
     def _fail_request(self, request: GenerationRequest,
                       exc: Optional[BaseException] = None) -> None:
         """Terminate a request that never reached (or lost) a slot: close
@@ -3336,7 +2494,7 @@ class LLMEngine:
                     survivors.append(slot.request)
                     # evacuate WITHOUT terminating: no out_queue sentinel,
                     # no span end — the request lives on in the replay
-                    # queue. Pages are not released (paged: the allocator
+                    # queue. Pages are not released (the allocator
                     # is rebuilt wholesale by _init_device_state below)
                     slot.request = None
                     slot.length = 0
@@ -3419,7 +2577,7 @@ class LLMEngine:
         """Act on the QoS shed ladder (tpu/qos.py) from the engine loop,
         under the state lock, immediately before admission. Level >= 2
         (preempt_batch) evacuates running batch-class generations via the
-        replay contract so the slots (and, paged, their pages) free for
+        replay contract so the slots (and their pages) free for
         the interactive work the ladder is protecting. Levels 0/1/3 need
         no loop-side action: parking and standard-shed happen at the
         admission gate and the submit door."""
@@ -3489,9 +2647,9 @@ class LLMEngine:
     def _release_slot_for_preempt(self, slot: _Slot) -> None:
         """Evacuate one slot for preemption: the reset-survivor recipe
         (request lives on, stream stays open) plus the freed-row control
-        zeroing from _finish_slot. Paged engines override to release the
-        slot's pages first — unlike a device reset, the allocator is NOT
-        rebuilt, so pages must be returned explicitly."""
+        zeroing from _finish_slot. paging.py releases the slot's pages
+        first — unlike a device reset, the allocator is NOT rebuilt, so
+        pages must be returned explicitly."""
         request = slot.request
         slot.request = None
         slot.length = 0
@@ -3514,31 +2672,6 @@ class LLMEngine:
         if self._plane is not None:
             return request.id in self._plane.synced_cancelled
         return request.cancelled.is_set()
-
-    def _admission_ready(self, request: GenerationRequest) -> bool:
-        """Subclass hook: reserve per-request resources (pages) before the
-        request can join an admission wave. False defers it FIFO."""
-        return True
-
-    def _abort_admission(self, request: GenerationRequest) -> None:
-        """Subclass hook: release _admission_ready reservations for a
-        request that exits without reaching a dispatch."""
-
-    def _admit_handoff(self, batch: List[GenerationRequest], free_iter,
-                       dispatched: Set[int]) -> None:
-        """Subclass hook (paged): bind hand-off requests whose KV arrived
-        as page blobs straight into decode slots. Base engines never see
-        them — submit_handoff rejects blobs off the paged decode role."""
-        raise NotImplementedError(
-            "page-blob hand-off admission needs the paged engine")
-
-    def _handoff_slot(self, slot: _Slot, request: GenerationRequest) -> None:
-        """Subclass hook (paged): export a freshly-prefilled slot's KV to
-        the hand-off sink and release the slot WITHOUT terminating the
-        stream. Only reachable under disagg_role='prefill', which the
-        constructor restricts to paged engines."""
-        raise NotImplementedError(
-            "page-blob KV export needs the paged engine")
 
     @loop_only
     def _handoff_fallback(self, request: GenerationRequest,
@@ -3583,3 +2716,74 @@ class LLMEngine:
             total = sum(t for _, t in self._tok_window)
             if span > 0:
                 self._obs.gauge("app_tpu_tokens_per_second", total / span)
+
+    # -- hooks: the device half, filled by paging.PagedLLMEngine ------------
+    # The loop above calls these and holds no body for them: pools, tables
+    # and programs live in paging.py.
+    def _init_device_state(self) -> None:
+        """(Re)build everything the device holds: pools, allocator, loop
+        state. paging.py calls it at construction; _reset_device_state
+        calls it after a device loss."""
+        raise NotImplementedError
+
+    def _admission_ready(self, request: GenerationRequest) -> bool:
+        """Reserve the request's pages before it can join an admission
+        wave. False defers it FIFO."""
+        raise NotImplementedError
+
+    def _abort_admission(self, request: GenerationRequest) -> None:
+        """Release _admission_ready's reservation for a request that exits
+        without reaching a dispatch."""
+        raise NotImplementedError
+
+    def _dispatch_prefill(self, bucket: int, slots_idx: List[int],
+                          batch: List[GenerationRequest]) -> None:
+        """One fused K-way prefill dispatch, then _bind_slots."""
+        raise NotImplementedError
+
+    def _start_chunk_job(self, bucket: int, slots_idx: List[int],
+                         batch: List[GenerationRequest]) -> None:
+        """Prep a chunked-prefill job, dispatch its FIRST chunk and
+        register it in _chunk_jobs. Host-prep failures before the dispatch
+        leave no reservation behind, so _admit's per-wave handler holds."""
+        raise NotImplementedError
+
+    def _dispatch_chunk(self, job) -> bool:
+        """Run the job's next chunk program; True when it was the final
+        chunk (job['first_tok'] then holds the sampled tokens)."""
+        raise NotImplementedError
+
+    def _dispatch_decode(self) -> None:
+        """One decode-block dispatch appended to _inflight."""
+        raise NotImplementedError
+
+    def _verify_call(self, drafts, lens):
+        """Compile-or-hit + run the verify program, splicing device state;
+        returns (out_tokens [B, d+1], n_emit [B]) futures."""
+        raise NotImplementedError
+
+    def _admit_handoff(self, batch: List[GenerationRequest], free_iter,
+                       dispatched: Set[int]) -> None:
+        """Bind hand-off requests whose KV arrived as page blobs straight
+        into decode slots."""
+        raise NotImplementedError
+
+    def _handoff_slot(self, slot: _Slot, request: GenerationRequest) -> None:
+        """Prefill role: export a freshly-prefilled slot's KV to the
+        hand-off sink and release the slot WITHOUT terminating the
+        stream."""
+        raise NotImplementedError
+
+    def _export_slot_kv(self, slot: _Slot, request: GenerationRequest):
+        """(blobs, n_ctx) of a live decode slot for a migration export;
+        blobs=None means the peer replays prompt+emitted."""
+        raise NotImplementedError
+
+    def _note_model_counts(self, tokens_host, block: int) -> None:
+        """Fold a synced decode block's counter rows into the totals."""
+        raise NotImplementedError
+
+    def _note_page_writes(self, live, block: int) -> int:
+        """Count a synced decode block's page writes; returns them for
+        the step ledger's record."""
+        raise NotImplementedError

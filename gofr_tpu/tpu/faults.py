@@ -62,7 +62,6 @@ KNOWN_SITES = (
     "engine.verify",        # speculative verify dispatch
     "engine.chunk",         # chunked-prefill dispatch
     "engine.sync",          # host sync of the oldest in-flight dispatch
-    "engine.cache_grow",    # dense KV growth copy
     "engine.probe",         # the breaker's half-open probe dispatch
     "executor.compile",     # program compile-or-hit lookups
     "device.health_probe",  # TPUClient._probe_device round-trip

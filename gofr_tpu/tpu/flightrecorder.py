@@ -29,7 +29,7 @@ Operator surface (install_routes / App.enable_flight_recorder):
 
     GET /debug/requests        -> in-flight + recent completions with
                                   phase timings + SLO goodput + engine
-                                  events (cache growth, resets, sheds)
+                                  events (resets, sheds, stragglers)
     GET /debug/requests/{id}   -> one request's full event timeline
 """
 
@@ -218,8 +218,8 @@ class FlightRecorder:
         # still back a stable gauge
         self._slo: "collections.deque" = collections.deque(
             maxlen=max(1, int(slo_window)))
-        # engine-level happenings not owned by one request (cache growth,
-        # device resets, stall sheds) — small and recent-only
+        # engine-level happenings not owned by one request (device
+        # resets, stall sheds) — small and recent-only
         self._engine_events: "collections.deque" = collections.deque(
             maxlen=64)
         self._obs = MetricsHook(metrics)

@@ -58,7 +58,7 @@ from .obs import MetricsHook
 from .qos import _MAX_TENANTS, _TENANT_OVERFLOW, effective_class
 from .utilization import decode_flops, prefill_flops
 
-DEFAULT_PAGE_TOKENS = 16      # dense engines: KV billed in 16-token pages
+DEFAULT_PAGE_TOKENS = 16      # a meter made without an engine's allocator
 DEFAULT_WINDOW_S = 300.0      # bounded-window spend horizon
 DEFAULT_DONE_CAPACITY = 512   # finished per-request rows retained
 DEFAULT_STEPS_CAPACITY = 256  # per-step attribution rows retained

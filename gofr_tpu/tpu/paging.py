@@ -1,9 +1,11 @@
-"""Paged KV serving: page allocator + block-table engine.
+"""The engine: a page pool, block tables and the step programs, on
+engine.py's serving loop.
 
-Dense serving (engine.LLMEngine) gives every slot the same [S] cache rows,
-so one long context inflates every slot's HBM footprint and per-step read
-cost, and growth copies the world. The paged engine fixes this the way the
-TPU wants it fixed (SURVEY.md §5 long-context row; VERDICT r2 missing #4):
+`PagedLLMEngine` is the one engine every caller constructs. engine.py
+(`LLMEngine`) is its loop half: queue, admission heap, pipelined dispatch,
+sync / demux / emit, failure handling. This file is the device half: what
+the chip holds and every program that runs on it (SURVEY.md §5
+long-context row):
 
   - K/V live in a FIXED pool [L, P, Hkv, dh, page_size] allocated once at
     boot — no growth copies, no per-slot max_seq reservation
@@ -39,7 +41,8 @@ import numpy as np
 from ..models.llama import LlamaConfig, llama_prefill_last
 from ..ops.paged_attention import (block_tail, holds_request,
                                    paged_flush_block,
-                                   paged_write_prefill_stacked)
+                                   paged_write_prefill_scales,
+                                   paged_write_prefill_stacked, quantize_kv)
 from .engine import (CacheLostError, GenerationRequest, LLMEngine,
                      _admission_widths, _pin_standard_layout, program_lookup)
 from .ownership import loop_only
@@ -90,15 +93,12 @@ def _pow2_at_least(n: int) -> int:
 class PagedLLMEngine(LLMEngine):
     """Continuous-batching engine over a paged KV pool.
 
-    Inherits the whole serving loop (admission fusion, pipelined dispatch,
-    demux, failure handling) from LLMEngine; overrides the device-state,
-    prefill, and decode layers. page budget: n_pages * page_size tokens
-    TOTAL across slots — callers size it from the capacity plan
-    (plan_capacity(..., paged=True)) instead of n_slots * max_seq.
+    The serving loop (admission fusion, pipelined dispatch, demux, failure
+    handling) is LLMEngine's; the device state and the prefill, decode,
+    chunk, verify, prefix and restore programs are here. page budget:
+    n_pages * page_size tokens TOTAL across slots — callers size it from
+    the capacity plan (plan_capacity) instead of n_slots * max_seq.
     """
-
-    _plan_paged = True  # capacity plan without the dense-cache transients
-    supports_kv_handoff = True  # _admit_handoff can land shipped PageBlobs
 
     def __init__(self, params, cfg: LlamaConfig, *, page_size: int = 128,
                  n_pages: Optional[int] = None, prefix_cache: bool = False,
@@ -108,9 +108,8 @@ class PagedLLMEngine(LLMEngine):
         # chunked prefill runs against bucket-sized per-job TEMPS and
         # scatters into pages once at the final chunk (_chunk_fn_paged);
         # speculative verify gathers pages into contiguous rows per layer
-        # (llama_verify_step_paged). Both compose with the pool since r4;
-        # the spec+int8-KV and spec+chunk exclusions are inherited from
-        # the dense engine (same reasons apply)
+        # (llama_verify_step_paged). Both compose with the pool; spec+int8
+        # KV and spec+chunk are refused by the loop's constructor
         self.page_size = page_size
         self._requested_pages = n_pages
         # what the model's family cannot serve yet is refused by name here,
@@ -153,8 +152,10 @@ class PagedLLMEngine(LLMEngine):
                         else RedisKVTier(kv_redis, ttl_s=kv_redis_ttl_s))
             self.kv_tier = HostKVTier(kv_host_tier_bytes, page_size,
                                       cold=cold)
-        # set pre-super: _init_device_state runs inside super().__init__
+        # everything above can refuse: it runs before the loop half starts
+        # its finisher thread
         super().__init__(params, cfg, **kw)
+        self._init_device_state()
 
     # -- the model, through its protocol ---------------------------------------
     @property
@@ -171,8 +172,8 @@ class PagedLLMEngine(LLMEngine):
 
         jnp = self._jnp
         ps = self.page_size
-        # default pool: full dense equivalent (every slot can reach
-        # max_seq_len); real deployments pass the planned smaller n_pages
+        # default pool: every slot can reach max_seq_len; real deployments
+        # pass the planned smaller n_pages
         n_pages = self._requested_pages or (
             self.n_slots * math.ceil(self.max_seq_len / ps) + 1)
         self.allocator = PageAllocator(n_pages, ps)
@@ -181,16 +182,14 @@ class PagedLLMEngine(LLMEngine):
         # the pages, so every cached entry is invalid by construction
         from .prefixcache import PrefixCache
 
-        self.prefix = (PrefixCache(ps)
-                       if getattr(self, "_prefix_enabled", False) else None)
+        self.prefix = PrefixCache(ps) if self._prefix_enabled else None
         self._prefix_hits: Dict[int, List[int]] = {}
-        self._cache_len = self.max_seq_len  # admission_limit compatibility
         # the pools' leading axis counts the blocks that keep K and V
         L, Hkv, dh = self.model.kv_layers, self.cfg.n_kv_heads, self.cfg.head_dim
         held_in = getattr(self.cfg, "kv_dtype", None) or self.cfg.dtype
         dt = {"bfloat16": jnp.bfloat16, "float32": jnp.float32,
               "float16": jnp.float16, "int8": jnp.int8}[held_in]
-        # the capacity plan (budget_bytes, paged=True) clamped n_slots and
+        # the capacity plan (budget_bytes) clamped n_slots and
         # max_seq_len; the pool derived from them must itself fit — check
         # explicitly, since an explicit n_pages bypasses the plan's sizing
         itemsize = {"bfloat16": 2, "float16": 2, "int8": 1}.get(held_in, 4)
@@ -234,9 +233,10 @@ class PagedLLMEngine(LLMEngine):
             self._place_state()
 
     def _place_state(self) -> None:
-        """Paged pools are STACKED [L, P, Hkv, dh, ps] arrays — the base
-        class's per-layer-tuple placement would iterate the leading axis
-        into L slices. Shard the pool's KV-head axis whole."""
+        """Commit device state to the mesh: the STACKED [L, P, Hkv, dh, ps]
+        pools shard their KV-head axis over tp, loop state replicates.
+        Committed shardings propagate into every compiled program; XLA
+        inserts the tp collectives."""
         import jax
         from jax.sharding import NamedSharding, PartitionSpec
 
@@ -266,12 +266,6 @@ class PagedLLMEngine(LLMEngine):
         if self.k_scale is not None:  # int8: f32 scale pools are pool bytes too
             total += 2 * self.k_scale.size * self.k_scale.dtype.itemsize
         return total
-
-    def _grow_cache(self, needed: int) -> None:
-        """Paged pool never grows — capacity is the page budget."""
-
-    def _decode_need(self) -> int:
-        return 0
 
     # -- admission: page reservation ------------------------------------------
     def submit(self, prompt_tokens, max_new_tokens: int = 128,
@@ -736,16 +730,27 @@ class PagedLLMEngine(LLMEngine):
         return pinned
 
     # -- programs -------------------------------------------------------------
-    def warmup(self, grow: bool = True, k_variants: bool = False) -> None:
+    def warmup(self, k_variants: bool = False) -> None:
+        """Pre-compile single-admission prefill buckets and the decode
+        programs of every table width an admission can produce.
+
+        k_variants=True additionally compiles every fused-admission width
+        per bucket (_admission_widths) and every table width up to
+        max_seq_len. Organic (staggered) arrivals admit in unpredictable
+        group sizes, so without this a production server pays a first-use
+        compile mid-request whenever traffic first produces a new
+        (bucket, K) — a TTFT spike. Costs buckets x log4(slots) compiles
+        at boot, amortized by the compile cache.
+
+        Safe against an already-started loop: compiles run under the same
+        state lock the loop's dispatch phase takes."""
         with self._state_lock:
-            # every fused-admission width: organic staggered traffic
-            # admits in unpredictable group sizes (see the dense warmup)
             ks = (sorted(_admission_widths(self.n_slots)) if k_variants
                   else [1])
             chunk = self.chunk_prefill_tokens
             for bucket in self.prefill_buckets:
                 # buckets routed to the chunk path skip the (dead) fused
-                # program, mirroring the dense warmup's routing
+                # program
                 if not (chunk and bucket > chunk):
                     for K in ks:
                         self._prefill_program(bucket, K)
@@ -841,8 +846,6 @@ class PagedLLMEngine(LLMEngine):
         jnp = self._jnp
         top_k = self.top_k
         from ..models.llama import _np_dtype
-        from ..ops.decode_attention import quantize_kv
-        from ..ops.paged_attention import paged_write_prefill_scales
         from .sampling import sample_tokens
 
         def prefill(params, k_pool, v_pool, k_scale, v_scale, ptokens,
@@ -1009,10 +1012,9 @@ class PagedLLMEngine(LLMEngine):
     # same storage shape the fused paged prefill allocates internally), and
     # the FINAL chunk scatters the whole window into pages with the same
     # paged_write_prefill_stacked the fused path uses. Decode dispatches
-    # interleave between chunks exactly as in the dense engine; the dense
-    # engine's position-parking is unnecessary here because a reserved-but-
-    # inactive slot's table row is all zeros, so lock-step junk writes land
-    # in the garbage page by construction.
+    # interleave between chunks; a reserved-but-inactive slot's table row
+    # is all zeros, so lock-step junk writes land in the garbage page by
+    # construction.
     def _chunk_fn_paged(self, chunk: int, K: int, final: bool):
         cfg, mesh = self.cfg, self.mesh
         jnp = self._jnp
@@ -1067,14 +1069,10 @@ class PagedLLMEngine(LLMEngine):
     def _chunk_fn_paged_q8_final(self, chunk: int, K: int):
         """Final chunk into INT8 pools: the whole full-precision temp
         window quantizes ONCE at the scatter (per token/head scales) —
-        mid-chunks read full-precision temps, so chunked q8 admission is
-        numerically CLOSER to the fused path than the dense engine's
-        chunked-q8 (which re-reads earlier chunks quantized)."""
+        mid-chunks read full-precision temps, as the fused path does."""
         cfg = self.cfg
         jnp = self._jnp
         top_k = self.top_k
-        from ..ops.decode_attention import quantize_kv
-        from ..ops.paged_attention import paged_write_prefill_scales
         from .sampling import sample_tokens
 
         base = self._chunk_fn_paged(chunk, K, final=False)
@@ -1107,10 +1105,9 @@ class PagedLLMEngine(LLMEngine):
     @program_lookup
     def _chunk_program_paged(self, chunk: int, K: int, bucket: int,
                              final: bool):
-        """Unlike the dense engine's (chunk, K)-keyed chunk programs, the
-        paged variants also key on the BUCKET (the temp caches are bucket-
-        wide); buckets above the chunk size are few, so the compile set
-        stays bounded."""
+        """Chunk programs key on (chunk, K) and on the BUCKET (the temp
+        caches are bucket-wide); buckets above the chunk size are few, so
+        the compile set stays bounded."""
         jnp = self._jnp
         from ..models.llama import _np_dtype
 
@@ -1283,8 +1280,7 @@ class PagedLLMEngine(LLMEngine):
     # -- speculative decoding over the pool -----------------------------------
     def _verify_fn_paged(self, d: int, n_table: int):
         """The paged window forward (llama_verify_step_paged) around the
-        SHARED acceptance epilogue (engine.spec_accept_epilogue — one
-        implementation for both engines by construction)."""
+        acceptance epilogue (engine.spec_accept_epilogue)."""
         cfg, mesh = self.cfg, self.mesh
         top_k = self.top_k
         from ..models.llama import llama_verify_step_paged

@@ -29,12 +29,12 @@ explicit ``other`` residual means nothing can hide):
                    MISS's compile time is re-attributed to ``compile``
   ``bind``         after a prefill dispatch: slot binding, request
                    stamps, page assignment and the prefix-cache insert
-  ``page_alloc``   paged engines: page reservation / prefix-cache match /
+  ``page_alloc``   page reservation / prefix-cache match /
                    eviction inside admission readiness (includes the
                    page-wait path — an exhausted pool shows up here).
                    KV spill to the host tier (D2H fetch of evicted pages)
                    also lands here: it happens inside eviction
-  ``kv_restore``   paged engines with the tiered KV cache: host/Redis
+  ``kv_restore``   with the tiered KV cache: host/Redis
                    tier lookup plus the H2D scatter that rebuilds evicted
                    prefix pages in the pool at admission, charged
                    separately from ``page_alloc`` (nested segments
@@ -51,7 +51,6 @@ explicit ``other`` residual means nothing can hide):
                    block tables
   ``compile``      executor cache-miss compiles, re-attributed out of
                    whichever segment the compile happened under
-  ``cache_grow``   dense KV growth copy (program + dispatch)
   ``dispatch``     device program enqueue calls (prefill / decode /
                    verify / chunk), including fault-injection hooks at
                    those sites
@@ -123,7 +122,7 @@ from .ownership import loop_only
 
 SEGMENTS = ("lock_wait", "admission", "page_alloc", "kv_restore",
             "kv_handoff", "host_prep", "program_lookup", "compile",
-            "cache_grow", "dispatch", "bind", "device_sync", "demux", "emit",
+            "dispatch", "bind", "device_sync", "demux", "emit",
             "other")
 
 SPAN_PREFIX = "loop/"

@@ -32,7 +32,7 @@ timeline:
     carried one (so the fleet stitcher, gofr_tpu/fleet/timeline.py, can
     join flows across replicas), plus one async "request" slice per
     request for at-a-glance lifetime;
-  * flight-recorder engine events (cache growth, sheds, resets,
+  * flight-recorder engine events (sheds, resets,
     incidents) as instant events on the loop track.
 
 A DISAGG_MODE=both replica exports BOTH halves: the serving (decode)

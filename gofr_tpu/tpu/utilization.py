@@ -489,7 +489,6 @@ def engine_snapshot(engine, tpu=None) -> Dict[str, Any]:
             "pipeline_depth": engine.pipeline_depth,
             "chunk_prefill_tokens": engine.chunk_prefill_tokens,
             "speculative_tokens": engine.speculative_tokens,
-            "cache_len": getattr(engine, "_cache_len", None),
             "queue_depth": engine._pending.qsize(),
             "inflight_dispatches": len(engine._inflight),
             "draining": engine._draining,

@@ -29,7 +29,7 @@ from gofr_tpu.models.llama import LlamaConfig, llama_init  # noqa: E402
 from gofr_tpu.parallel import MeshPlan, make_mesh  # noqa: E402
 from gofr_tpu.parallel.multihost import initialize_from_config  # noqa: E402
 from gofr_tpu.tpu.admission import AdmissionPlane  # noqa: E402
-from gofr_tpu.tpu.engine import LLMEngine  # noqa: E402
+from gofr_tpu.tpu.paging import PagedLLMEngine  # noqa: E402
 
 PROMPTS = [[1, 2, 3, 4], [9, 8, 7], [5], [11, 12, 13, 14], [3, 1]]
 CANCEL_INDEX = 3          # cancelled after its 2nd token, mid-generation
@@ -42,9 +42,10 @@ CFG = LlamaConfig(vocab_size=128, dim=32, n_layers=2, n_heads=2,
 
 
 def _engine(mesh, plane):
-    return LLMEngine(llama_init(CFG, seed=0), CFG, n_slots=4,
-                     max_seq_len=128, prefill_buckets=(8,),
-                     decode_block_size=4, mesh=mesh, admission_plane=plane)
+    return PagedLLMEngine(llama_init(CFG, seed=0), CFG, n_slots=4,
+                          max_seq_len=128, prefill_buckets=(8,),
+                          decode_block_size=4, mesh=mesh,
+                          admission_plane=plane)
 
 
 def _checksum(token_lists):

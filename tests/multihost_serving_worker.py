@@ -29,7 +29,7 @@ from gofr_tpu.config import MockConfig  # noqa: E402
 from gofr_tpu.models.llama import LlamaConfig, llama_init  # noqa: E402
 from gofr_tpu.parallel import MeshPlan, make_mesh  # noqa: E402
 from gofr_tpu.parallel.multihost import initialize_from_config  # noqa: E402
-from gofr_tpu.tpu.engine import LLMEngine  # noqa: E402
+from gofr_tpu.tpu.paging import PagedLLMEngine  # noqa: E402
 
 PROMPTS = [[1, 2, 3, 4], [9, 8, 7], [5]]
 
@@ -38,8 +38,9 @@ def _serve(mesh):
     cfg = LlamaConfig(vocab_size=128, dim=32, n_layers=2, n_heads=2,
                       n_kv_heads=2, ffn_dim=64, max_seq_len=64,
                       dtype="float32")
-    eng = LLMEngine(llama_init(cfg, seed=0), cfg, n_slots=4, max_seq_len=64,
-                    prefill_buckets=(8,), decode_block_size=4, mesh=mesh)
+    eng = PagedLLMEngine(llama_init(cfg, seed=0), cfg, n_slots=4,
+                         max_seq_len=64,
+                         prefill_buckets=(8,), decode_block_size=4, mesh=mesh)
     # queue everything BEFORE the loop starts: deterministic dispatch order
     reqs = [eng.submit(p, max_new_tokens=6, temperature=0.0)
             for p in PROMPTS]

@@ -18,7 +18,8 @@ import pytest
 
 from gofr_tpu.models.llama import LlamaConfig, llama_init
 from gofr_tpu.tpu.admission import AdmissionPlane, InProcKV
-from gofr_tpu.tpu.engine import EngineDrainingError, LLMEngine
+from gofr_tpu.tpu.engine import EngineDrainingError
+from gofr_tpu.tpu.paging import PagedLLMEngine
 
 CFG = LlamaConfig(vocab_size=128, dim=32, n_layers=2, n_heads=2,
                   n_kv_heads=2, ffn_dim=64, max_seq_len=256, dtype="float32")
@@ -29,8 +30,8 @@ ENGINE_KW = dict(n_slots=4, max_seq_len=64, prefill_buckets=(8,),
 
 def _engine(plane=None, **overrides):
     kw = dict(ENGINE_KW, **overrides)
-    return LLMEngine(llama_init(CFG, seed=0), CFG,
-                     admission_plane=plane, **kw)
+    return PagedLLMEngine(llama_init(CFG, seed=0), CFG,
+                          admission_plane=plane, **kw)
 
 
 def _pair(kv, **overrides):

@@ -114,6 +114,42 @@ def test_clock_fixture_flags_and_scope():
     assert report.exit_code == 2
 
 
+# -- oneengine ----------------------------------------------------------------
+
+def test_oneengine_fixture_flags_forwards_and_bare_loops():
+    """tpu/engine.py imports no model function but the ones excepted by
+    name, and nothing outside tpu/paging.py constructs LLMEngine."""
+    report = _fixture_run("oneengine", "oneengine")
+    assert {(f.file, f.symbol) for f in report.failing
+            if f.qualname == "<module>"} == {
+        ("gofr_tpu/tpu/engine.py", "llama_decode_step"),
+        ("gofr_tpu/tpu/engine.py", "init_kv_cache"),
+        ("tools/soak.py", "LLMEngine("),
+    }
+    assert sum(f.file == "tools/soak.py" for f in report.failing) == 2
+    # classes, params_nbytes and scoring's forward pass; paging.py may
+    # construct the loop; a name that is not called is not a construction
+    assert not any(f.symbol in ("LlamaConfig", "params_nbytes",
+                                "llama_forward_nocache")
+                   or f.file.endswith("paging.py") for f in report.findings)
+    sup = [f for f in report.findings if f.suppressed is not None]
+    assert [(f.symbol, f.suppressed) for f in sup] == [
+        ("llama_prefill", "fixture pragma")]
+    assert report.exit_code == 32
+
+
+def test_oneengine_fixture_holds_the_loops_hooks():
+    """What PagedLLMEngine replaces without super() is a bodiless hook in
+    the loop, and every hook is filled."""
+    report = _fixture_run("oneengine", "oneengine")
+    assert {(f.qualname, f.message.split(":")[0].split(" that ")[0])
+            for f in report.failing if f.qualname != "<module>"} == {
+        ("LLMEngine._export_slot_kv", "a hook of the loop"),
+        ("LLMEngine._admission_ready",
+         "PagedLLMEngine replaces this method without super()"),
+    }
+
+
 # -- ownership ----------------------------------------------------------------
 
 def test_ownership_fixture_flags_offloop_call_and_write():
@@ -251,7 +287,7 @@ def test_rule_exit_bits_compose():
     rules from the status alone."""
     from tools.analysis.passes import BITS
     assert BITS == {"hotloop": 1, "clock": 2, "ownership": 4,
-                    "lockorder": 8, "surface": 16}
+                    "lockorder": 8, "surface": 16, "oneengine": 32}
     hot = _fixture_run("hotloop", "hotloop")
     clk = _fixture_run("clock", "clock")
     assert hot.exit_code | clk.exit_code == 3

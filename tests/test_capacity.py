@@ -81,27 +81,18 @@ def test_plan_prefers_shedding_expensive_axis():
     assert plan.max_seq_len < 8192
 
 
-def test_paged_plan_drops_growth_transient():
-    cfg = LlamaConfig.llama1b()
-    dense = plan_capacity(cfg, 16, 2048, budget_bytes=16 * GIB, clamp=False)
-    paged = plan_capacity(cfg, 16, 2048, budget_bytes=16 * GIB, clamp=False,
-                          paged=True)
-    assert dense.growth_transient_bytes > 0
-    assert paged.growth_transient_bytes == 0
-    assert paged.peak_bytes <= dense.peak_bytes
-
-
 def test_engine_routes_through_plan():
-    """LLMEngine(budget_bytes=...) clamps its own config at construction."""
+    """PagedLLMEngine(budget_bytes=...) clamps its own config at
+    construction."""
     from gofr_tpu.models.llama import llama_init
-    from gofr_tpu.tpu.engine import LLMEngine
+    from gofr_tpu.tpu.paging import PagedLLMEngine
 
     cfg = LlamaConfig.debug()
     params = llama_init(cfg, seed=0)
     # a budget sized so the debug model fits only with a shrunken config:
     # debug cache at 64 slots x 256 seq = 2*2*64*256*2*16*4 bytes = 16 MiB
-    eng = LLMEngine(params, cfg, n_slots=64, max_seq_len=256,
-                    prefill_buckets=(16, 64), budget_bytes=6 << 20)
+    eng = PagedLLMEngine(params, cfg, n_slots=64, max_seq_len=256,
+                         prefill_buckets=(16, 64), budget_bytes=6 << 20)
     assert eng.plan is not None and eng.plan.fits
     assert (eng.n_slots, eng.max_seq_len) != (64, 256)  # clamped
     assert eng.plan.peak_bytes <= int((6 << 20) * 0.92)
@@ -116,11 +107,12 @@ def test_engine_routes_through_plan():
 
 def test_engine_no_budget_keeps_config():
     from gofr_tpu.models.llama import llama_init
-    from gofr_tpu.tpu.engine import LLMEngine
+    from gofr_tpu.tpu.paging import PagedLLMEngine
 
     cfg = LlamaConfig.debug()
-    eng = LLMEngine(llama_init(cfg, seed=0), cfg, n_slots=4, max_seq_len=128,
-                    prefill_buckets=(16,))
+    eng = PagedLLMEngine(llama_init(cfg, seed=0), cfg, n_slots=4,
+                         max_seq_len=128,
+                         prefill_buckets=(16,))
     assert eng.plan is None and eng.n_slots == 4
 
 
@@ -141,7 +133,7 @@ def test_int8_kv_plan_fits_more():
     from gofr_tpu.tpu.capacity import plan_capacity
 
     cfg = LlamaConfig.llama1b()
-    cfg8 = dataclasses.replace(cfg, decode_attn="kernel", kv_dtype="int8")
+    cfg8 = dataclasses.replace(cfg, kv_dtype="int8")
     budget = 16 << 30
     plan_bf16 = plan_capacity(cfg, 256, 2048, budget,
                               prefill_buckets=(512,))
@@ -163,13 +155,12 @@ def test_int8_kv_plan_fits_more():
 def test_llama3_8b_int8_weights_fit_one_v5e_chip():
     """BASELINE config 4 feasibility: 8B bf16 weights (~15 GiB) cannot fit
     a 16 GiB chip with any KV at all, but the int8 tree (~8 GiB) plans a
-    real serving config — the arithmetic bench.py's T3 stage relies on."""
+    real serving config."""
     import dataclasses
 
     from gofr_tpu.models.llama import LlamaConfig
 
-    cfg = dataclasses.replace(LlamaConfig.llama3_8b(),
-                              decode_attn="kernel", kv_dtype="int8")
+    cfg = dataclasses.replace(LlamaConfig.llama3_8b(), kv_dtype="int8")
     w8_bytes = cfg.param_count() * 1 + 4 * (
         # per-output-channel f32 scales: one per output column per matmul
         cfg.vocab_size * 2 + cfg.n_layers * (
@@ -177,7 +168,7 @@ def test_llama3_8b_int8_weights_fit_one_v5e_chip():
             + cfg.dim + 2 * cfg.ffn_dim + cfg.dim))
     budget = 16 << 30
     plan = plan_capacity(cfg, n_slots=64, max_seq_len=512,
-                         budget_bytes=budget, paged=True,
+                         budget_bytes=budget,
                          prefill_buckets=(16, 64, 128, 256),
                          params_nbytes=w8_bytes)
     assert plan.fits
@@ -198,12 +189,11 @@ def test_llama3_70b_int8_weights_fit_tp8_slice():
 
     from gofr_tpu.models.llama import LlamaConfig
 
-    cfg = dataclasses.replace(LlamaConfig.llama3_70b(),
-                              decode_attn="kernel", kv_dtype="int8")
+    cfg = dataclasses.replace(LlamaConfig.llama3_70b(), kv_dtype="int8")
     w8_bytes = cfg.param_count()                  # int8: ~1 byte per param
     budget = 8 * (16 << 30)
     plan = plan_capacity(cfg, n_slots=64, max_seq_len=2048,
-                         budget_bytes=budget, paged=True,
+                         budget_bytes=budget,
                          prefill_buckets=(64, 256, 512),
                          params_nbytes=w8_bytes)
     assert plan.fits

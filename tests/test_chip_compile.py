@@ -27,14 +27,12 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
 from gofr_tpu.models.llama import LlamaConfig, llama_init
-from gofr_tpu.ops.decode_attention import decode_attention
 from gofr_tpu.ops.flash_attention import flash_attention
 from gofr_tpu.ops.paged_attention import (block_tail, paged_attention,
                                           paged_attention_in_block,
                                           paged_flush_block,
                                           paged_write_decode)
-from gofr_tpu.parallel.sharding import (kv_cache_layer_spec, kv_cache_spec,
-                                        kv_scale_pool_spec,
+from gofr_tpu.parallel.sharding import (kv_cache_spec, kv_scale_pool_spec,
                                         serving_param_specs)
 
 # published head geometry of the two presets the chip serves
@@ -240,25 +238,6 @@ def test_flash_attention_streaming_compiles(topo, preset):
     assert _kernels(compiled) == 1
 
 
-@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.int8],
-                         ids=["bf16", "int8"])
-@pytest.mark.parametrize("preset", list(WIDTHS))
-def test_decode_attention_compiles(topo, preset, dtype):
-    H, Hkv, dh = WIDTHS[preset]
-    chips = Chips(topo, 1)
-    B, S = 128, 1024
-    q = chips.shape((B, H, dh), jnp.bfloat16)
-    cache = chips.shape((B, Hkv, dh, S), dtype)
-    lengths = chips.shape((B,), jnp.int32)
-    scales = ((chips.shape((B, Hkv, S), jnp.float32),) * 2
-              if dtype == jnp.int8 else ())
-    compiled = _compile(
-        lambda q, k, v, n, *s: decode_attention(q, k, v, n, *s,
-                                                interpret=False),
-        q, cache, cache, lengths, *scales)
-    assert _kernels(compiled) == 1
-
-
 # -- the four-chip tp mesh ------------------------------------------------------
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.int8],
                          ids=["bf16", "int8"])
@@ -328,9 +307,9 @@ def test_block_tail_kernels_compile_sharded_over_tp(topo, preset):
     assert "all-reduce(" not in compiled.as_text()
 
 
-def test_dense_kernels_compile_sharded_over_tp(topo):
-    """The dense engine's kernel call sites under a mesh: flash prefill
-    (heads of q/k/v over tp) and the decode kernel (cache heads over tp)."""
+def test_flash_prefill_compiles_sharded_over_tp(topo):
+    """The flash prefill's call site under a mesh (heads of q/k/v over
+    tp)."""
     H, Hkv, dh = WIDTHS["llama1b"]
     chips = Chips(topo, 4)
     heads4 = P(None, None, "tp", None)
@@ -339,16 +318,6 @@ def test_dense_kernels_compile_sharded_over_tp(topo):
     compiled = _compile(
         lambda q, k, v: flash_attention(q, k, v, True, interpret=False,
                                         mesh=chips.mesh), q, kv, kv)
-    assert _kernels(compiled) == 1
-
-    B, S = 64, 1024
-    qd = chips.shape((B, H, dh), jnp.bfloat16, P(None, "tp", None))
-    cache = chips.shape((B, Hkv, dh, S), jnp.bfloat16, kv_cache_layer_spec())
-    lengths = chips.shape((B,), jnp.int32)
-    compiled = _compile(
-        lambda q, k, v, n: decode_attention(q, k, v, n, mesh=chips.mesh,
-                                            interpret=False),
-        qd, cache, cache, lengths)
     assert _kernels(compiled) == 1
 
 
@@ -431,23 +400,32 @@ def test_paged_decode_step_compiles_in_place(topo, as_tpu, preset, kv_dtype):
     _assert_pool_in_place(compiled, pools)
 
 
-def test_paged_prefill_step_compiles_in_place(topo, as_tpu):
+@pytest.mark.parametrize("kv_dtype", [None, "int8"], ids=["bf16", "int8"])
+def test_paged_prefill_step_compiles_in_place(topo, as_tpu, kv_dtype):
+    """int8: the window quantizes once at the scatter, values and scale
+    pools both written in place (the harness's `int8-kv` control boots it)."""
     from gofr_tpu.tpu.paging import PagedLLMEngine
 
-    cfg = dataclasses.replace(LlamaConfig.llama1b(), attn_impl="flash")
+    cfg = dataclasses.replace(LlamaConfig.llama1b(), attn_impl="flash",
+                              kv_dtype=kv_dtype)
     chips = Chips(topo, 1)
     engine = _engine_shell(PagedLLMEngine, cfg, None)
-    pools = _pools(chips, cfg.n_kv_heads, cfg.head_dim, jnp.bfloat16,
-                   N_PAGES, cfg.n_layers)
+    pools = _pools(chips, cfg.n_kv_heads, cfg.head_dim,
+                   jnp.int8 if kv_dtype else jnp.bfloat16, N_PAGES,
+                   cfg.n_layers)
     tokens, positions, temps = _loop_state(chips, N_SLOTS)
     bucket, K = 256, 1
     rows = chips.shape((K,), jnp.int32)
+    fn = engine._prefill_fn_q8 if kv_dtype else engine._prefill_fn
+    first = 1 + len(pools) + 4          # the loop state, after the window
     compiled = _compile(
-        engine._prefill_fn(bucket, K), _params(chips, cfg), *pools,
+        fn(bucket, K), _params(chips, cfg), *pools,
         chips.shape((K, bucket), jnp.int32),
         chips.shape((K, bucket // PAGE), jnp.int32), rows, rows,
         tokens, positions, temps, chips.shape((K,), jnp.float32),
-        chips.shape((2,), jnp.uint32), donate=(1, 2, 7, 8, 9))
+        chips.shape((2,), jnp.uint32),
+        donate=tuple(range(1, 1 + len(pools))) + (first, first + 1,
+                                                  first + 2))
     assert _kernels(compiled) == 1      # flash over the fresh window
     _assert_pool_in_place(compiled, pools)
 
@@ -585,30 +563,6 @@ def test_paged_decode_step_compiles_on_tp_mesh(topo, as_tpu):
         (p.shape[0], p.shape[1], p.shape[2] // 4) + p.shape[3:], p.dtype)
         for p in pools]
     _assert_pool_in_place(compiled, quarter)
-
-
-@pytest.mark.slow  # an unrolled 16-layer program: ~10 s, a rehearsal to
-# repeat when the dense engine's programs change, not a tier-1 guard
-@pytest.mark.parametrize("decode_attn", ["xla", "kernel"])
-def test_dense_decode_step_compiles_with_layout_pin(topo, as_tpu,
-                                                    decode_attn):
-    """engine._pin_standard_layout builds Layout(major_to_minor) with no
-    tiling: the TPU compiler has to take it."""
-    from gofr_tpu.tpu.engine import LLMEngine
-
-    cfg = dataclasses.replace(LlamaConfig.llama1b(), decode_attn=decode_attn)
-    chips = Chips(topo, 1)
-    engine = _engine_shell(LLMEngine, cfg, None)
-    B, S = 64, 256
-    layers = tuple(
-        chips.shape((B, cfg.n_kv_heads, cfg.head_dim, S), jnp.bfloat16)
-        for _ in range(cfg.n_layers))
-    tokens, positions, temps = _loop_state(chips, B)
-    compiled = _compile(engine._decode_fn(8), _params(chips, cfg), layers,
-                        layers, tokens, positions, temps,
-                        chips.shape((2,), jnp.uint32), donate=(1, 2))
-    assert _kernels(compiled) == (cfg.n_layers if decode_attn == "kernel"
-                                  else 0)
 
 
 # -- the nemotron_h family's step programs (ISSUE 27) ---------------------------

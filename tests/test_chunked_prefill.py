@@ -1,12 +1,13 @@
 """Chunked prefill: numerics parity with fused admission + interleaving.
 
 Opt-in engine mode (chunk_prefill_tokens > 0): a long prompt is admitted
-as several bounded chunk dispatches against the live cache rows, so decode
-blocks interleave instead of stalling behind one huge prefill — the TTFT
-lever for mixed traffic. These tests pin the hard invariants on CPU:
-token-for-token parity with the fused path (including prompts whose last
-token falls in an EARLY chunk), and correctness while another request is
-mid-decode (parked positions keep lock-step junk out of the prompt range).
+as several bounded chunk dispatches against bucket-sized job temps (one
+page scatter at the final chunk), so decode blocks interleave instead of
+stalling behind one huge prefill — the TTFT lever for mixed traffic. These
+tests pin the hard invariants on CPU: token-for-token parity with the fused
+path (including prompts whose last token falls in an EARLY chunk), and
+correctness while another request is mid-decode (the reserved slot's zero
+table row keeps lock-step junk in the garbage page).
 """
 
 import time
@@ -15,24 +16,17 @@ import pytest
 
 from gofr_tpu.logging import MockLogger
 from gofr_tpu.models.llama import LlamaConfig, llama_init
-from gofr_tpu.tpu.engine import LLMEngine
 from gofr_tpu.tpu.paging import PagedLLMEngine
 
 CFG = LlamaConfig.debug()
 
-# both engines serve the chunk path since r4: dense against live cache
-# rows, paged against bucket-sized job temps + a final page scatter
-ENGINES = [LLMEngine, PagedLLMEngine]
 
-
-def _make(chunk=0, cls=LLMEngine, **kw):
+def _make(chunk=0, **kw):
     params = llama_init(CFG, seed=0)
     defaults = dict(n_slots=4, max_seq_len=128, prefill_buckets=(8, 32),
-                    decode_block_size=4, logger=MockLogger())
-    if cls is PagedLLMEngine:
-        defaults["page_size"] = 16
+                    decode_block_size=4, page_size=16, logger=MockLogger())
     defaults.update(kw)
-    eng = cls(params, CFG, chunk_prefill_tokens=chunk, **defaults)
+    eng = PagedLLMEngine(params, CFG, chunk_prefill_tokens=chunk, **defaults)
     eng.start()
     return eng
 
@@ -46,12 +40,7 @@ PROMPTS = [
 ]
 
 
-@pytest.mark.parametrize("cls", [
-    LLMEngine,
-    # tier-1 wall-clock budget: dense variant stays as the in-lane rep
-    pytest.param(PagedLLMEngine, marks=pytest.mark.slow),
-])
-def test_chunked_matches_fused_token_for_token(cls):
+def test_chunked_matches_fused_token_for_token():
     fused = _make(chunk=0)
     try:
         want = [fused.generate(p, max_new_tokens=8, temperature=0.0)
@@ -59,7 +48,7 @@ def test_chunked_matches_fused_token_for_token(cls):
     finally:
         fused.stop()
 
-    chunked = _make(chunk=8, cls=cls)
+    chunked = _make(chunk=8)
     try:
         got = [chunked.generate(p, max_new_tokens=8, temperature=0.0)
                for p in PROMPTS]
@@ -69,12 +58,11 @@ def test_chunked_matches_fused_token_for_token(cls):
 
 
 @pytest.mark.slow  # tier-1 wall-clock budget; lighter in-lane representative kept
-@pytest.mark.parametrize("cls", ENGINES)
-def test_chunked_admission_during_active_decode(cls):
+def test_chunked_admission_during_active_decode():
     """A chunked admission lands while another request is mid-decode: the
-    decoding request's output must be untouched (dense: parked positions;
-    paged: the reserved slot's zero table row diverts junk to the garbage
-    page) and the new request must match the fused engine."""
+    decoding request's output must be untouched (the reserved slot's zero
+    table row diverts junk to the garbage page) and the new request must
+    match the fused engine."""
     fused = _make(chunk=0)
     try:
         want_long = fused.generate([5, 6, 7], max_new_tokens=40,
@@ -84,7 +72,7 @@ def test_chunked_admission_during_active_decode(cls):
     finally:
         fused.stop()
 
-    eng = _make(chunk=8, decode_block_size=2, cls=cls)
+    eng = _make(chunk=8, decode_block_size=2)
     try:
         long_req = eng.submit([5, 6, 7], max_new_tokens=40, temperature=0.0)
         while long_req.generated < 4:   # ensure decode is genuinely running
@@ -145,9 +133,9 @@ def test_paged_chunked_releases_pages_and_q8_composes():
 
 
 def test_paged_chunk_warmup_compiles_variants():
-    eng = _make(chunk=8, cls=PagedLLMEngine)
+    eng = _make(chunk=8)
     try:
-        eng.warmup(grow=True)
+        eng.warmup()
         names = list(eng.executor.cache_info())
         assert any("llama-paged-chunk-8x1-b32" in n for n in names)
         assert any("llama-paged-chunk-final-8x1-b32" in n for n in names)
@@ -157,28 +145,12 @@ def test_paged_chunk_warmup_compiles_variants():
         eng.stop()
 
 
-def test_chunk_warmup_compiles_variants():
-    """Warmup pre-compiles the chunk variants (first/middle/final) so the
-    first long prompt pays no serving-loop JIT stall."""
-    eng = _make(chunk=8)
-    try:
-        eng.warmup(grow=True)
-        names = list(eng.executor.cache_info())
-        assert any("llama-chunk-8x1-first" in n for n in names)
-        assert any("llama-chunk-8x1-final" in n for n in names)
-        assert any(n.startswith("llama-chunk-8x1-S") for n in names)  # middle
-        # the fused program for the chunk-routed bucket is NOT warmed
-        assert not any("llama-prefill-32x" in n for n in names)
-    finally:
-        eng.stop()
-
-
 def test_chunk_size_must_divide_buckets():
     params = llama_init(CFG, seed=0)
     with pytest.raises(ValueError, match="must divide"):
-        LLMEngine(params, CFG, n_slots=2, max_seq_len=64,
-                  prefill_buckets=(8, 24), chunk_prefill_tokens=8 + 8,
-                  logger=MockLogger())
+        PagedLLMEngine(params, CFG, n_slots=2, max_seq_len=64,
+                       prefill_buckets=(8, 24), chunk_prefill_tokens=8 + 8,
+                       logger=MockLogger())
 
 
 def test_chunked_stop_unblocks_mid_prefill_clients():

@@ -28,13 +28,7 @@ from gofr_tpu.tpu.paging import PagedLLMEngine
 
 CFG = LlamaConfig.debug()
 
-# greedy max_new=8 goldens for llama_init(debug, seed=0) — same tokens a
-# colocated PagedLLMEngine serves (asserted in test_paging's parity tier)
-GOLDENS = [
-    ([5, 6, 7], [435, 48, 235, 272, 186, 312, 185, 26]),
-    ([9, 10, 11, 12, 13, 14, 15, 16, 17], [392, 189, 106, 61, 48, 26, 433, 61]),
-    ([1, 2], [417, 417, 417, 417, 480, 223, 509, 417]),
-]
+PROMPTS = [[5, 6, 7], [9, 10, 11, 12, 13, 14, 15, 16, 17], [1, 2]]
 
 
 class MockLogger:
@@ -52,6 +46,20 @@ def _engine(role, **kw):
                          **base)
     eng.start()
     return eng
+
+
+@pytest.fixture(scope="module")
+def goldens():
+    """[(prompt, greedy max_new=8 tokens)] as a COLOCATED engine of the
+    same seed and sizes serves them, computed here: a literal list ties
+    the file to one JAX version's arithmetic."""
+    eng = _engine("")
+    try:
+        return [(prompt, eng.generate(prompt, max_new_tokens=8,
+                                      temperature=0.0))
+                for prompt in PROMPTS]
+    finally:
+        eng.stop()
 
 
 def _pair(**router_kw):
@@ -129,16 +137,17 @@ def test_queue_transport_sheds_when_full():
 # -- the split pair on a real (CPU) engine ------------------------------------
 
 
-def test_disagg_pair_matches_colocated_goldens_with_zero_decode_prefills():
+def test_disagg_pair_matches_colocated_goldens_with_zero_decode_prefills(
+        goldens):
     pre, dec, router = _pair()
     try:
         reqs = [router.submit(prompt, max_new_tokens=len(golden),
                               temperature=0.0)
-                for prompt, golden in GOLDENS]
-        for (prompt, golden), req in zip(GOLDENS, reqs):
+                for prompt, golden in goldens]
+        for (prompt, golden), req in zip(goldens, reqs):
             assert _collect(req) == golden, f"prompt {prompt}"
-        assert pre.handoffs_total == len(GOLDENS)
-        assert router.coordinator.consumed_total == len(GOLDENS)
+        assert pre.handoffs_total == len(goldens)
+        assert router.coordinator.consumed_total == len(goldens)
         assert (router.fallbacks_total + pre.handoff_fallbacks_total
                 + dec.handoff_fallbacks_total) == 0
     finally:
@@ -163,10 +172,10 @@ class _CorruptTransport(QueueTransport):
         return super().publish(json.dumps(body))
 
 
-def test_corrupt_blob_degrades_to_recompute_not_failure():
+def test_corrupt_blob_degrades_to_recompute_not_failure(goldens):
     pre, dec, router = _pair(transport=_CorruptTransport(maxsize=8))
     try:
-        prompt, golden = GOLDENS[0]
+        prompt, golden = goldens[0]
         req = router.submit(prompt, max_new_tokens=len(golden),
                             temperature=0.0)
         assert _collect(req) == golden  # recompute serves the SAME tokens
@@ -183,11 +192,11 @@ class _LossyTransport(QueueTransport):
         return True
 
 
-def test_lost_handoff_rescued_by_stale_reaper():
+def test_lost_handoff_rescued_by_stale_reaper(goldens):
     pre, dec, router = _pair(transport=_LossyTransport(),
                              handoff_timeout_s=0.3)
     try:
-        prompt, golden = GOLDENS[1]
+        prompt, golden = goldens[1]
         req = router.submit(prompt, max_new_tokens=len(golden),
                             temperature=0.0)
         assert _collect(req) == golden
@@ -197,25 +206,25 @@ def test_lost_handoff_rescued_by_stale_reaper():
         _teardown(pre, dec, router)
 
 
-def test_prefill_worker_death_never_fails_a_stream():
+def test_prefill_worker_death_never_fails_a_stream(goldens):
     pre, dec, router = _pair()
     try:
         in_flight = [router.submit(prompt, max_new_tokens=len(golden),
                                    temperature=0.0)
-                     for prompt, golden in GOLDENS * 2]
+                     for prompt, golden in goldens * 2]
         router.worker.kill()  # mid-flight: sweep + drain re-route survivors
         post_kill = [router.submit(prompt, max_new_tokens=len(golden),
                                    temperature=0.0)
-                     for prompt, golden in GOLDENS]
-        for (prompt, golden), req in zip(GOLDENS * 3, in_flight + post_kill):
+                     for prompt, golden in goldens]
+        for (prompt, golden), req in zip(goldens * 3, in_flight + post_kill):
             assert _collect(req) == golden, f"prompt {prompt}"
             assert req.error is None
-        assert router.fallbacks_total >= len(GOLDENS)  # post-kill at least
+        assert router.fallbacks_total >= len(goldens)  # post-kill at least
     finally:
         _teardown(pre, dec, router)
 
 
-def test_traceparent_survives_the_hop():
+def test_traceparent_survives_the_hop(goldens):
     sent = "00-" + "1234567890abcdef" * 2 + "-" + "fedcba0987654321" + "-01"
     captured = []
 
@@ -226,7 +235,7 @@ def test_traceparent_survives_the_hop():
 
     pre, dec, router = _pair(transport=_Tap())
     try:
-        prompt, golden = GOLDENS[2]
+        prompt, golden = goldens[2]
         req = router.submit(prompt, max_new_tokens=len(golden),
                             temperature=0.0, traceparent=sent)
         assert _collect(req) == golden

@@ -21,15 +21,17 @@ import pytest
 
 from gofr_tpu.container import STATUS_DEGRADED, STATUS_UP
 from gofr_tpu.models.llama import LlamaConfig, llama_init
-from gofr_tpu.tpu.engine import EngineStalledError, LLMEngine
+from gofr_tpu.tpu.engine import EngineStalledError
+from gofr_tpu.tpu.paging import PagedLLMEngine
 
 CFG = LlamaConfig.debug()
 
 
 @pytest.fixture
 def engine():
-    eng = LLMEngine(llama_init(CFG, seed=0), CFG, n_slots=2, max_seq_len=64,
-                    prefill_buckets=(16,), decode_block_size=4)
+    eng = PagedLLMEngine(llama_init(CFG, seed=0), CFG, n_slots=2,
+                         max_seq_len=64,
+                         prefill_buckets=(16,), decode_block_size=4)
     eng.start()
     yield eng
     eng.stop()
@@ -46,8 +48,9 @@ def test_idle_engine_reports_healthy(engine):
 
 
 def test_stopped_engine_reports_zero_stall():
-    eng = LLMEngine(llama_init(CFG, seed=0), CFG, n_slots=2, max_seq_len=64,
-                    prefill_buckets=(16,))
+    eng = PagedLLMEngine(llama_init(CFG, seed=0), CFG, n_slots=2,
+                         max_seq_len=64,
+                         prefill_buckets=(16,))
     assert eng.stall_seconds == 0.0  # never started: nothing to measure
     eng.start()
     eng.stop()

@@ -545,7 +545,7 @@ def test_pubsub_worker_tp_sharded_end_to_end():
             app = module.build_app(config=_cfg(
                 TPU_PLATFORM="cpu", MODEL_PRESET="debug", WARMUP="false",
                 PUBSUB_BACKEND="file", PUBSUB_DIR=broker_dir,
-                TP_SHARDS=str(tp), PAGED="false", REQUEST_TIMEOUT="120"))
+                TP_SHARDS=str(tp), REQUEST_TIMEOUT="120"))
             app.start()
             try:
                 broker = app.container.pubsub
@@ -578,6 +578,15 @@ def test_pubsub_worker_tp_sharded_end_to_end():
         sharded = run(2)
         single = run(1)
     assert sharded == single, "tp broker flow diverged from single-device"
+
+
+def test_llm_server_refuses_an_environment_that_asks_for_the_dense_engine():
+    """`PAGED=false` named an engine that is gone: the boot says so before
+    anything is built, and never serves it from the one engine."""
+    module = _load("llm-server")
+    with pytest.raises(ValueError, match="PAGED=false.*no longer exists"):
+        module.build_app(config=_cfg(TPU_PLATFORM="cpu", MODEL_PRESET="debug",
+                                     WARMUP="false", PAGED="false"))
 
 
 def test_llm_server_boots_from_weights_on_disk(tmp_path):

@@ -20,7 +20,8 @@ import pytest
 from gofr_tpu.container import STATUS_DEGRADED, STATUS_DOWN, STATUS_UP
 from gofr_tpu.logging import MockLogger
 from gofr_tpu.models.llama import LlamaConfig, llama_init
-from gofr_tpu.tpu.engine import (CacheLostError, DeviceLostError, LLMEngine)
+from gofr_tpu.tpu.engine import CacheLostError, DeviceLostError
+from gofr_tpu.tpu.paging import PagedLLMEngine
 from gofr_tpu.tpu.faults import (FaultPlane, InjectedFault,
                                  ResetStormBreaker)
 from gofr_tpu.tpu.flightrecorder import FlightRecorder
@@ -33,7 +34,7 @@ def _engine(**kw):
     defaults = dict(n_slots=8, max_seq_len=128, prefill_buckets=(16, 32),
                     decode_block_size=4, logger=MockLogger())
     defaults.update(kw)
-    return LLMEngine(PARAMS, CFG, **defaults)
+    return PagedLLMEngine(PARAMS, CFG, **defaults)
 
 
 # -- fault plane unit behavior ------------------------------------------------
@@ -175,8 +176,6 @@ def test_paged_engine_replays_and_rereserves_pages():
     """Replay over the paged pool: the reset rebuilds the allocator, the
     survivors re-reserve pages for prompt+emitted at re-admission, and no
     page leaks once every stream completes."""
-    from gofr_tpu.tpu.paging import PagedLLMEngine
-
     plane = FaultPlane(plan=[{"site": "engine.decode", "nth": 2,
                               "action": "raise"}])
     eng = PagedLLMEngine(PARAMS, CFG, n_slots=4, max_seq_len=64,

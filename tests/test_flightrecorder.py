@@ -19,7 +19,7 @@ import urllib.request
 import pytest
 
 from gofr_tpu.models.llama import LlamaConfig, llama_init
-from gofr_tpu.tpu.engine import LLMEngine
+from gofr_tpu.tpu.paging import PagedLLMEngine
 from gofr_tpu.tpu.flightrecorder import FlightRecorder
 from gofr_tpu.tracing import InMemoryExporter, Tracer
 
@@ -33,8 +33,8 @@ def _engine(recorder=None, tracer=None, **kw):
     kw.setdefault("max_seq_len", 64)
     kw.setdefault("prefill_buckets", (16,))
     kw.setdefault("decode_block_size", 4)
-    eng = LLMEngine(llama_init(CFG, seed=0), CFG, tracer=tracer,
-                    flight_recorder=recorder, **kw)
+    eng = PagedLLMEngine(llama_init(CFG, seed=0), CFG, tracer=tracer,
+                         flight_recorder=recorder, **kw)
     eng.start()
     return eng
 

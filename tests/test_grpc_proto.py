@@ -157,7 +157,7 @@ def test_grpc_streams_a_real_generation():
     import os as _os
 
     from gofr_tpu.models.llama import LlamaConfig, llama_init
-    from gofr_tpu.tpu.engine import LLMEngine
+    from gofr_tpu.tpu.paging import PagedLLMEngine
 
     path = _os.path.join(_os.path.dirname(__file__), "..", "examples",
                          "llm-server", "main.py")
@@ -167,8 +167,8 @@ def test_grpc_streams_a_real_generation():
 
     cfg = LlamaConfig.debug()
     params = llama_init(cfg, seed=0)
-    engine = LLMEngine(params, cfg, n_slots=2, max_seq_len=128,
-                       prefill_buckets=(8, 32), sampling_controls=True)
+    engine = PagedLLMEngine(params, cfg, n_slots=2, max_seq_len=128,
+                            prefill_buckets=(8, 32), sampling_controls=True)
     engine.start()
     from gofr_tpu.models.tokenizer import ByteTokenizer
 

@@ -7,8 +7,6 @@ streaming clients against the REAL llm-server app (build_app -> real
 router/middleware/handler/SSE encoder over real sockets), sustained, with
 zero errors tolerated — plus boundary-vs-engine TTFT bookkeeping so a
 regression in the serving stack (not the engine) fails loudly.
-The bench half (run_phase_http in bench.py) records the same boundary
-numbers on TPU runs.
 """
 
 import http.client

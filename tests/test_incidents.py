@@ -23,7 +23,7 @@ import pytest
 from gofr_tpu.logging import MockLogger
 from gofr_tpu.metrics import Manager
 from gofr_tpu.models.llama import LlamaConfig, llama_init
-from gofr_tpu.tpu.engine import LLMEngine
+from gofr_tpu.tpu.paging import PagedLLMEngine
 from gofr_tpu.tpu.faults import FaultPlane
 from gofr_tpu.tpu.flightrecorder import FlightRecorder
 from gofr_tpu.tpu.incidents import (IncidentManager, SLOBurnEngine,
@@ -37,7 +37,7 @@ def _engine(**kw):
     defaults = dict(n_slots=4, max_seq_len=128, prefill_buckets=(16, 32),
                     decode_block_size=4, logger=MockLogger())
     defaults.update(kw)
-    return LLMEngine(PARAMS, CFG, **defaults)
+    return PagedLLMEngine(PARAMS, CFG, **defaults)
 
 
 def _burn(pages=None, clock=None, **kw):
@@ -142,7 +142,7 @@ def test_sheds_and_errors_burn_the_availability_budget():
     assert ("availability", pages[0][1])[0] in [p[0] for p in pages]
     # non-shed engine events must NOT burn anything
     before = snap["slos"]["availability"]["windows"]["slow"]["bad"]
-    recorder.record_engine_event("cache_grow", new_len=64)
+    recorder.record_engine_event("device_reset", error="injected")
     after = burn.snapshot()["slos"]["availability"]["windows"]["slow"]["bad"]
     assert after == before
 
@@ -314,14 +314,15 @@ def test_reset_storm_autocaptures_bundle_and_rate_limits_second_storm(
         assert bundle["steps"]["steps_total"] >= 1
         assert bundle["steps"]["recent"]
         # engine snapshot evidence (the /debug/engine payload)
-        assert bundle["engine"]["engine"]["class"] == "LLMEngine"
+        assert bundle["engine"]["engine"]["class"] == "PagedLLMEngine"
         assert bundle["engine"]["recovery"]["resets_total"] >= 2
         # the deep link: the interrupted streams were in flight at
         # capture time, and the head of slowest_requests is one of them
         assert bundle["slowest_request_id"] in (r1.id, r2.id)
         ids = {r["id"] for r in bundle["slowest_requests"]}
         assert {r1.id, r2.id} <= ids
-        assert bundle["config_fingerprint"]["facts"]["engine"] == "LLMEngine"
+        assert (bundle["config_fingerprint"]["facts"]["engine"]
+                == "PagedLLMEngine")
         # the bundle file persisted and round-trips
         with open(bundle["path"], encoding="utf-8") as fp:
             on_disk = json.load(fp)

@@ -447,13 +447,14 @@ def test_hops_from_detail_roles():
 
 def test_flightrecorder_lookup_trace():
     from gofr_tpu.models.llama import LlamaConfig, llama_init
-    from gofr_tpu.tpu.engine import LLMEngine
+    from gofr_tpu.tpu.paging import PagedLLMEngine
 
     trace = "4bf92f3577b34da6a3ce929d0e0e4736"
     recorder = FlightRecorder(capacity=8)
     cfg = LlamaConfig.debug()
-    eng = LLMEngine(llama_init(cfg, seed=0), cfg, n_slots=2, max_seq_len=64,
-                    prefill_buckets=(16,), flight_recorder=recorder)
+    eng = PagedLLMEngine(llama_init(cfg, seed=0), cfg, n_slots=2,
+                         max_seq_len=64,
+                         prefill_buckets=(16,), flight_recorder=recorder)
     eng.start()
     try:
         first = eng.submit([1, 2, 3], max_new_tokens=3,
@@ -482,7 +483,7 @@ def test_disagg_fleet_journey_trace_continuity(fleet):  # noqa: ARG001
     base_cfg = {
         "HTTP_PORT": "0", "METRICS_PORT": "0", "TPU_PLATFORM": "cpu",
         "MODEL_PRESET": "debug", "WARMUP": "false", "MAX_BATCH": "4",
-        "MAX_SEQ_LEN": "64", "PREFILL_BUCKETS": "8,16", "PAGED": "true",
+        "MAX_SEQ_LEN": "64", "PREFILL_BUCKETS": "8,16",
         "PAGE_SIZE": "8", "REQUEST_TIMEOUT": "300", "LOG_LEVEL": "ERROR",
         "INCIDENT_AUTOPSY": "false"}
     replicas = []

@@ -14,15 +14,16 @@ import pytest
 
 from gofr_tpu.models.llama import (LlamaConfig, init_kv_cache, llama_init,
                                    llama_prefill, quantize_weights)
-from gofr_tpu.tpu.engine import LLMEngine
+from gofr_tpu.tpu.paging import PagedLLMEngine
 
 CFG = LlamaConfig.debug()
 
 
 @pytest.fixture(scope="module")
 def engine():
-    eng = LLMEngine(llama_init(CFG, seed=0), CFG, n_slots=2, max_seq_len=256,
-                    prefill_buckets=(16, 32, 64, 256))
+    eng = PagedLLMEngine(llama_init(CFG, seed=0), CFG, n_slots=2,
+                         max_seq_len=256,
+                         prefill_buckets=(16, 32, 64, 256))
     eng.start()
     yield eng
     eng.stop()
@@ -101,8 +102,6 @@ def test_score_while_engine_is_busy(engine):
 
 
 def test_score_paged_and_int8_engines():
-    from gofr_tpu.tpu.paging import PagedLLMEngine
-
     q8 = quantize_weights(llama_init(CFG, seed=0))
     eng = PagedLLMEngine(q8, CFG, n_slots=2, max_seq_len=64,
                          prefill_buckets=(16, 64), page_size=16)
@@ -267,11 +266,11 @@ def test_score_under_tensor_parallel_mesh():
 
     mesh = make_mesh(MeshPlan(tp=2), devices=jax.devices()[:2])
     params = llama_init(CFG, seed=0)
-    eng_tp = LLMEngine(params, CFG, n_slots=2, max_seq_len=64,
-                       prefill_buckets=(16, 64), mesh=mesh)
+    eng_tp = PagedLLMEngine(params, CFG, n_slots=2, max_seq_len=64,
+                            prefill_buckets=(16, 64), mesh=mesh)
     eng_tp.start()
-    eng_1 = LLMEngine(params, CFG, n_slots=2, max_seq_len=64,
-                      prefill_buckets=(16, 64))
+    eng_1 = PagedLLMEngine(params, CFG, n_slots=2, max_seq_len=64,
+                           prefill_buckets=(16, 64))
     eng_1.start()
     try:
         prompt, completion = [3, 1, 4, 1], [5, 9, 2, 6, 5]
@@ -374,8 +373,9 @@ def test_openai_embeddings_endpoint():
 def test_warmup_scoring_precompiles_every_bucket():
     """After warmup_scoring, client score/embed calls at any bucket hit
     compiled programs — the executor cache does not grow."""
-    eng = LLMEngine(llama_init(CFG, seed=0), CFG, n_slots=2, max_seq_len=64,
-                    prefill_buckets=(16, 32))
+    eng = PagedLLMEngine(llama_init(CFG, seed=0), CFG, n_slots=2,
+                         max_seq_len=64,
+                         prefill_buckets=(16, 32))
     eng.start()
     try:
         ran = eng.warmup_scoring()

@@ -23,7 +23,7 @@ from gofr_tpu import App
 from gofr_tpu.config import MockConfig
 from gofr_tpu.fleet.capacity import FleetCapacity
 from gofr_tpu.models.llama import LlamaConfig, llama_init
-from gofr_tpu.tpu.engine import LLMEngine
+from gofr_tpu.tpu.paging import PagedLLMEngine
 from gofr_tpu.tpu.meter import (HeadroomForecaster, TPUMeter,
                                 register_meter_metrics)
 from gofr_tpu.tpu.qos import _MAX_TENANTS, _TENANT_OVERFLOW
@@ -216,8 +216,8 @@ def test_conservation_live_multi_tenant_engine():
     measured device segments (±5 % over the run), and tenant totals
     equal the per-request sums exactly — over a REAL multi-tenant run."""
     params = llama_init(CFG, seed=0)
-    eng = LLMEngine(params, CFG, n_slots=4, max_seq_len=64,
-                    prefill_buckets=(8, 16), logger=MockLogger())
+    eng = PagedLLMEngine(params, CFG, n_slots=4, max_seq_len=64,
+                         prefill_buckets=(8, 16), logger=MockLogger())
     meter = TPUMeter(cfg=CFG, steps_capacity=8192, done_capacity=256)
     meter.forecaster = HeadroomForecaster(engine=eng)
     eng.start()

@@ -343,11 +343,7 @@ def test_each_feature_the_family_cannot_serve_is_refused_by_name(feature):
         _engine(cfg, params, **REFUSED[feature])
 
 
-def test_the_dense_engine_and_the_post_hoc_passes_refuse_the_family():
-    from gofr_tpu.tpu.engine import LLMEngine
-
-    with pytest.raises(ValueError, match="dense engine serves models/llama"):
-        LLMEngine({}, program_config(), n_slots=2)
+def test_the_post_hoc_passes_refuse_the_family():
     engine = _engine(program_config(), nemotron_h_init(program_config(), 0))
     with pytest.raises(ValueError, match=r"score\(\) is models/llama"):
         engine.score([1, 2], [3])
@@ -392,31 +388,39 @@ def test_the_engine_serves_the_family_on_its_normal_path(seeded):
     assert 0 < routing["experts_touched_per_layer_step"] <= 4
 
 
-def test_llama_behind_the_protocol_serves_the_parents_tokens():
-    """models/llama.py's paged path moved behind the protocol unchanged in
-    arithmetic: the tokens the parent commit served for these prompts
-    (recorded from 991d1a2 with this very script), bit for bit."""
+def test_llama_behind_the_protocol_serves_the_references_tokens():
+    """models/llama.py's paged path sits behind the protocol unchanged in
+    arithmetic: the tokens are the plain cached forward's (`llama_prefill`,
+    `llama_decode_step` over one contiguous cache), computed here."""
+    from gofr_tpu.models.llama import (init_kv_cache, llama_decode_step,
+                                       llama_prefill)
+
     cfg = LlamaConfig.debug()
-    engine = _engine(cfg, llama_init(cfg, seed=0))
+    params = llama_init(cfg, seed=0)
+    engine = _engine(cfg, params)
     assert engine.state == () and engine.model.counters == ()
     assert engine.k_cache.shape[0] == cfg.n_layers
+    prompts = [_tokens(n, 40 + n) for n in (5, 17, 30, 9, 23)]
     engine.start()
     try:
-        requests = [engine.submit(_tokens(n, 40 + n), max_new_tokens=12)
-                    for n in (5, 17, 30, 9, 23)]
+        requests = [engine.submit(p, max_new_tokens=12) for p in prompts]
         served = [r.result(timeout_s=300) for r in requests]
     finally:
         engine.stop()
-    assert served == PARENT_TOKENS
 
+    def reference(prompt):
+        k, v = init_kv_cache(cfg, 1, 128)
+        logits, k, v = llama_prefill(params, cfg,
+                                     jnp.asarray([prompt], jnp.int32), k, v)
+        out = [int(jnp.argmax(logits[0, -1]))]
+        for i in range(11):
+            logits, k, v = llama_decode_step(
+                params, cfg, jnp.asarray([out[-1]], jnp.int32),
+                jnp.asarray([len(prompt) + i], jnp.int32), k, v)
+            out.append(int(jnp.argmax(logits[0])))
+        return out
 
-PARENT_TOKENS = [
-    [130, 290, 185, 19, 232, 41, 266, 196, 260, 88, 88, 126],
-    [115, 218, 9, 100, 266, 272, 396, 100, 422, 415, 271, 319],
-    [134, 99, 57, 363, 187, 56, 99, 57, 214, 317, 317, 317],
-    [259, 121, 256, 214, 256, 214, 256, 214, 211, 155, 214, 211],
-    [394, 190, 394, 464, 394, 340, 222, 461, 394, 464, 394, 184],
-]
+    assert served == [reference(p) for p in prompts]
 
 
 def test_what_a_token_meets_and_what_a_slot_holds():
@@ -441,7 +445,7 @@ def test_what_a_token_meets_and_what_a_slot_holds():
     assert kv_token_bytes(cfg) == 2 * 2 * 2 * 128 * 2
     budget = 16 << 30
     plan = plan_capacity(cfg, 96, 2048, budget, prefill_buckets=(64, 128),
-                         paged=True, params_nbytes=10_570_000_000)
+                         params_nbytes=10_570_000_000)
     assert plan.cache_bytes_max == 96 * 2048 * 2048 \
         + 96 * cfg.state_bytes_per_slot
     llama = LlamaConfig.llama1b()

@@ -1,7 +1,7 @@
-"""INT8 KV-cache serving: quantized engine vs the full-precision engine.
+"""INT8 KV pages: the engine over int8 pools vs over floating-point pools.
 
 The int8 path quantizes K/V on write (per-token per-head scales) and
-dequantizes inside the Pallas decode kernel's dots; the prefill forward is
+dequantizes inside the paged kernel's dots; the prefill forward is
 full-precision (temps quantize only at the splice), so the FIRST sampled
 token must match the fp engine exactly. Later tokens may drift where two
 logits are near-ties — asserted as high agreement, plus determinism.
@@ -12,18 +12,18 @@ import dataclasses
 import pytest
 
 from gofr_tpu.models.llama import LlamaConfig, llama_init
-from gofr_tpu.tpu.engine import LLMEngine
+from gofr_tpu.tpu.paging import PagedLLMEngine
 
 CFG = LlamaConfig.debug()
-CFG_Q8 = dataclasses.replace(CFG, decode_attn="kernel", kv_dtype="int8")
+CFG_Q8 = dataclasses.replace(CFG, kv_dtype="int8")
 
 PROMPTS = [list(range(1, 9)), [7, 5, 3], list(range(20, 50)), [11]]
 
 
 def _serve(cfg, prompts, max_new=12):
     params = llama_init(CFG, seed=0)
-    eng = LLMEngine(params, cfg, n_slots=4, max_seq_len=128,
-                    prefill_buckets=(8, 32), decode_block_size=4)
+    eng = PagedLLMEngine(params, cfg, n_slots=4, max_seq_len=128,
+                         prefill_buckets=(8, 32), decode_block_size=4)
     eng.start()
     try:
         reqs = [eng.submit(p, max_new_tokens=max_new, temperature=0.0)
@@ -34,7 +34,7 @@ def _serve(cfg, prompts, max_new=12):
 
 
 def test_q8_engine_serves_and_matches_fp_closely():
-    fp = _serve(dataclasses.replace(CFG, decode_attn="kernel"), PROMPTS)
+    fp = _serve(CFG, PROMPTS)
     q8 = _serve(CFG_Q8, PROMPTS)
     assert [len(t) for t in q8] == [len(t) for t in fp]
     # prefill is full-precision in both: first sampled token identical
@@ -56,48 +56,17 @@ def test_q8_engine_deterministic():
 
 
 @pytest.mark.slow  # tier-1 wall-clock budget; lighter in-lane representative kept
-def test_q8_engine_grows_cache():
-    """Admission past the boot allocation forces a q8 grow (values AND
-    scales pad together)."""
-    params = llama_init(CFG, seed=0)
-    eng = LLMEngine(params, CFG_Q8, n_slots=2, max_seq_len=128,
-                    prefill_buckets=(8, 64), decode_block_size=4)
-    eng.start()
-    try:
-        small = eng.submit([1, 2, 3], max_new_tokens=4, temperature=0.0)
-        small.result(timeout_s=300)
-        grown = eng.submit(list(range(1, 60)), max_new_tokens=4,
-                           temperature=0.0)
-        out = grown.result(timeout_s=300)
-        assert len(out) == 4
-        assert eng._cache_len >= 64
-        assert eng.k_scale[0].shape[-1] == eng._cache_len
-    finally:
-        eng.stop()
-
-
-def test_q8_requires_kernel_decode():
-    params = llama_init(CFG, seed=0)
-    with pytest.raises(ValueError, match="decode_attn"):
-        LLMEngine(params, dataclasses.replace(CFG, kv_dtype="int8"),
-                  n_slots=2, max_seq_len=64, prefill_buckets=(8,))
-
-
-@pytest.mark.slow  # tier-1 wall-clock budget; lighter in-lane representative kept
 def test_q8_chunked_prefill_matches_fused():
-    """Chunked admission over the int8 cache: same lengths and (near) the
-    fused-q8 tokens. Exact equality is not guaranteed for multi-chunk
-    prompts — the fused path runs full-precision prefill attention and
-    quantizes once at the splice, while chunk N reads chunks 1..N-1 through
-    their quantized values (what decode will read too) — so near-ties may
-    flip; lengths, determinism, and bulk agreement are the contract."""
+    """Chunked admission over the int8 pools: same lengths and (near) the
+    fused-q8 tokens; lengths, determinism, and bulk agreement are the
+    contract."""
     fused = _serve(CFG_Q8, PROMPTS)
 
     def serve_chunked():
         params = llama_init(CFG, seed=0)
-        eng = LLMEngine(params, CFG_Q8, n_slots=4, max_seq_len=128,
-                        prefill_buckets=(8, 32), decode_block_size=4,
-                        chunk_prefill_tokens=8)
+        eng = PagedLLMEngine(params, CFG_Q8, n_slots=4, max_seq_len=128,
+                             prefill_buckets=(8, 32), decode_block_size=4,
+                             chunk_prefill_tokens=8)
         eng.start()
         try:
             reqs = [eng.submit(p, max_new_tokens=12, temperature=0.0)
@@ -117,8 +86,8 @@ def test_q8_chunked_prefill_matches_fused():
 
 @pytest.mark.slow  # tier-1 wall-clock budget; lighter in-lane representative kept
 def test_q8_engine_tp_mesh_matches_single_device():
-    """int8 KV under a tp mesh: values shard KV heads (kv_cache_layer_spec),
-    scales shard alongside (kv_scale_layer_spec); greedy decode must match
+    """int8 KV under a tp mesh: values shard KV heads (kv_cache_spec),
+    scales shard alongside (kv_scale_pool_spec); greedy decode must match
     the single-device q8 engine token-for-token."""
     import jax
 
@@ -130,14 +99,14 @@ def test_q8_engine_tp_mesh_matches_single_device():
         LlamaConfig(vocab_size=256, dim=64, n_layers=2, n_heads=8,
                     n_kv_heads=8, ffn_dim=128, max_seq_len=128,
                     dtype="float32"),
-        decode_attn="kernel", kv_dtype="int8")
+        kv_dtype="int8")
     mesh = make_mesh(MeshPlan(tp=8))
     prompts = [[1, 2, 3, 4, 5], [9, 8, 7], [17]]
 
     def serve(m):
         params = llama_init(dataclasses.replace(cfg, kv_dtype=None), seed=0)
-        eng = LLMEngine(params, cfg, n_slots=4, max_seq_len=64,
-                        prefill_buckets=(8,), mesh=m)
+        eng = PagedLLMEngine(params, cfg, n_slots=4, max_seq_len=64,
+                             prefill_buckets=(8,), mesh=m)
         eng.start()
         try:
             reqs = [eng.submit(p, max_new_tokens=6, temperature=0.0)
@@ -147,69 +116,3 @@ def test_q8_engine_tp_mesh_matches_single_device():
             eng.stop()
 
     assert serve(mesh) == serve(None)
-
-
-def test_q8_tp_scale_sharding_survives_growth():
-    """k/v_scale shard their KV-head axis over tp and KEEP that sharding
-    through _grow_cache's q8 re-pad (the regression class the old
-    init-time guard existed to prevent)."""
-    import jax
-
-    from gofr_tpu.parallel import MeshPlan, make_mesh
-
-    if len(jax.devices()) < 2:
-        pytest.skip("needs >= 2 devices")
-    cfg = dataclasses.replace(
-        LlamaConfig(vocab_size=128, dim=32, n_layers=2, n_heads=4,
-                    n_kv_heads=2, ffn_dim=64, max_seq_len=128,
-                    dtype="float32"),
-        decode_attn="kernel", kv_dtype="int8")
-    mesh = make_mesh(MeshPlan(tp=2), devices=jax.devices()[:2])
-    params = llama_init(dataclasses.replace(cfg, kv_dtype=None), seed=0)
-    eng = LLMEngine(params, cfg, n_slots=2, max_seq_len=128,
-                    prefill_buckets=(8,), mesh=mesh)
-    ks0 = eng.k_scale[0]
-    assert ks0.sharding.shard_shape(ks0.shape)[1] == 1  # Hkv=2 over tp=2
-    eng._grow_cache(64)
-    assert eng._cache_len == 64
-    for scales in (eng.k_scale, eng.v_scale):
-        for s in scales:
-            assert s.shape[-1] == 64
-            assert s.sharding.shard_shape(s.shape)[1] == 1, \
-                "scale sharding dropped by growth"
-    k0 = eng.k_cache[0]
-    assert k0.sharding.shard_shape(k0.shape)[1] == 1
-
-
-def test_kernel_decode_rounds_incompatible_max_seq_len():
-    """decode_attn='kernel' reads the cache in min(512, S)-wide blocks; a
-    max_seq_len like 1000 would make the clamped grow target indivisible
-    and raise MID-SERVING. The engine must round the cap down at boot
-    (ADVICE r3 medium)."""
-    params = llama_init(CFG, seed=0)
-    cfg = dataclasses.replace(CFG, max_seq_len=8192, decode_attn="kernel")
-    eng = LLMEngine(params, cfg, n_slots=2, max_seq_len=1000,
-                    prefill_buckets=(8, 512))
-    assert eng.max_seq_len == 512
-    assert all(b <= 512 for b in eng.prefill_buckets)
-    # multiples of 512 and small caps pass through untouched
-    assert LLMEngine(params, cfg, n_slots=2, max_seq_len=1536,
-                     prefill_buckets=(8,)).max_seq_len == 1536
-    assert LLMEngine(params, cfg, n_slots=2, max_seq_len=300,
-                     prefill_buckets=(8,)).max_seq_len == 300
-    # the xla read has no block constraint: untouched
-    xla_cfg = dataclasses.replace(CFG, max_seq_len=8192)
-    assert LLMEngine(params, xla_cfg, n_slots=2, max_seq_len=1000,
-                     prefill_buckets=(8,)).max_seq_len == 1000
-
-
-def test_kernel_rounding_cannot_strand_requests():
-    """If the 512-rounding leaves NO prefill bucket under the cap, boot
-    must fail loudly — r4 review repro: requests were accepted (admission
-    limit fell back to max_seq_len-1) but no bucket could ever admit
-    them, hanging clients until timeout."""
-    params = llama_init(CFG, seed=0)
-    cfg = dataclasses.replace(CFG, max_seq_len=8192, decode_attn="kernel")
-    with pytest.raises(ValueError, match="no prefill bucket"):
-        LLMEngine(params, cfg, n_slots=2, max_seq_len=1000,
-                  prefill_buckets=(768,))

@@ -16,7 +16,6 @@ import pytest
 
 from gofr_tpu.http.errors import InvalidParam
 from gofr_tpu.models.llama import LlamaConfig, llama_init
-from gofr_tpu.tpu.engine import LLMEngine
 from gofr_tpu.tpu.paging import PagedLLMEngine
 from gofr_tpu.tpu.qos import (BatchLane, CLASS_BAND, LEVEL_LABELS,
                               QoSController, QoSShedError, banded_priority,
@@ -70,8 +69,8 @@ def test_unknown_class_rejected_at_every_door():
     from gofr_tpu.tpu.scheduler import DynamicBatcher
 
     params = llama_init(CFG, seed=0)
-    eng = LLMEngine(params, CFG, n_slots=2, max_seq_len=64,
-                    prefill_buckets=(8, 16), logger=MockLogger())
+    eng = PagedLLMEngine(params, CFG, n_slots=2, max_seq_len=64,
+                         prefill_buckets=(8, 16), logger=MockLogger())
     eng.start()
     try:
         with pytest.raises(InvalidParam):
@@ -214,8 +213,12 @@ def test_class_ordered_admission_under_contention():
     earlier-submitted standard and batch work — the heap's class bands in
     action — while FIFO order holds inside a class."""
     params = llama_init(CFG, seed=0)
-    eng = LLMEngine(params, CFG, n_slots=1, max_seq_len=128,
-                    prefill_buckets=(8,), logger=MockLogger())
+    # pages of 16: at the default 128 this pool would be ONE page, and a
+    # batch request's share of it (1.0) is over `batch_page_fraction` for
+    # good: it would park and never admit
+    eng = PagedLLMEngine(params, CFG, n_slots=1, max_seq_len=128,
+                         page_size=16, prefill_buckets=(8,),
+                         logger=MockLogger())
     eng.qos = _controller(interactive_reserved_slots=0)
     eng.qos.engine = eng
     eng.start()
@@ -287,8 +290,8 @@ def test_batch_lane_round_trip():
     from gofr_tpu.pubsub.inproc import InProcBroker
 
     params = llama_init(CFG, seed=0)
-    eng = LLMEngine(params, CFG, n_slots=2, max_seq_len=64,
-                    prefill_buckets=(8, 16), logger=MockLogger())
+    eng = PagedLLMEngine(params, CFG, n_slots=2, max_seq_len=64,
+                         prefill_buckets=(8, 16), logger=MockLogger())
     broker = InProcBroker()
     lane = BatchLane(eng, broker, max_inflight=2, poll_s=0.05,
                      logger=MockLogger())

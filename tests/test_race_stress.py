@@ -116,10 +116,10 @@ def _engine_submit_cancel_stress(engine_kwargs, prompts, max_new,
     deterministic tokens or raises cleanly; no cross-request leakage.
     on_done(engine) runs after the hammer, before stop (leak gates)."""
     from gofr_tpu.models.llama import LlamaConfig, llama_init
-    from gofr_tpu.tpu.engine import LLMEngine
+    from gofr_tpu.tpu.paging import PagedLLMEngine
 
     cfg = LlamaConfig.debug()
-    eng = (cls or LLMEngine)(llama_init(cfg, seed=0), cfg,
+    eng = (cls or PagedLLMEngine)(llama_init(cfg, seed=0), cfg,
                              logger=MockLogger(), **engine_kwargs)
     eng.start()
     try:
@@ -192,11 +192,13 @@ def test_drain_races_concurrent_submitters():
     completes fully or fails with the draining error — nothing hangs,
     nothing half-generates."""
     from gofr_tpu.models.llama import LlamaConfig, llama_init
-    from gofr_tpu.tpu.engine import EngineDrainingError, LLMEngine
+    from gofr_tpu.tpu.engine import EngineDrainingError
+    from gofr_tpu.tpu.paging import PagedLLMEngine
 
     cfg = LlamaConfig.debug()
-    eng = LLMEngine(llama_init(cfg, seed=0), cfg, n_slots=4, max_seq_len=64,
-                    prefill_buckets=(8,), logger=MockLogger())
+    eng = PagedLLMEngine(llama_init(cfg, seed=0), cfg, n_slots=4,
+                         max_seq_len=64,
+                         prefill_buckets=(8,), logger=MockLogger())
     eng.start()
     outcomes = []
     lock = threading.Lock()
@@ -247,11 +249,13 @@ def test_drain_submit_cancel_race_every_client_terminal():
     import time
 
     from gofr_tpu.models.llama import LlamaConfig, llama_init
-    from gofr_tpu.tpu.engine import EngineDrainingError, LLMEngine
+    from gofr_tpu.tpu.engine import EngineDrainingError
+    from gofr_tpu.tpu.paging import PagedLLMEngine
 
     cfg = LlamaConfig.debug()
-    eng = LLMEngine(llama_init(cfg, seed=0), cfg, n_slots=4, max_seq_len=64,
-                    prefill_buckets=(8,), logger=MockLogger())
+    eng = PagedLLMEngine(llama_init(cfg, seed=0), cfg, n_slots=4,
+                         max_seq_len=64,
+                         prefill_buckets=(8,), logger=MockLogger())
     eng.start()
     outcomes = []
     lock = threading.Lock()
@@ -338,16 +342,17 @@ def test_dynamic_batcher_stop_does_not_race_live_loop():
 
 
 def test_engine_stop_with_wedged_loop_leaves_state_to_live_loop():
-    """LLMEngine.stop() timing out against a loop stuck in a device call
+    """PagedLLMEngine.stop() timing out against a loop stuck in a device call
     must not mutate loop-owned state (engine.py stop/is_alive race): the
     thread stays registered, and when the device answers the loop finishes
     its own teardown."""
     from gofr_tpu.models.llama import LlamaConfig, llama_init
-    from gofr_tpu.tpu.engine import LLMEngine
+    from gofr_tpu.tpu.paging import PagedLLMEngine
 
     cfg = LlamaConfig.debug()
-    eng = LLMEngine(llama_init(cfg, seed=0), cfg, n_slots=2, max_seq_len=64,
-                    prefill_buckets=(8,), logger=MockLogger())
+    eng = PagedLLMEngine(llama_init(cfg, seed=0), cfg, n_slots=2,
+                         max_seq_len=64,
+                         prefill_buckets=(8,), logger=MockLogger())
     eng.STOP_JOIN_S = 0.2
     eng.start()
     eng.generate([1, 2, 3], max_new_tokens=3)  # warm
@@ -458,11 +463,13 @@ def test_wedge_recovery_races_concurrent_submitters():
     import time
 
     from gofr_tpu.models.llama import LlamaConfig, llama_init
-    from gofr_tpu.tpu.engine import EngineStalledError, LLMEngine
+    from gofr_tpu.tpu.engine import EngineStalledError
+    from gofr_tpu.tpu.paging import PagedLLMEngine
 
     cfg = LlamaConfig.debug()
-    eng = LLMEngine(llama_init(cfg, seed=0), cfg, n_slots=4, max_seq_len=64,
-                    prefill_buckets=(8,), decode_block_size=4)
+    eng = PagedLLMEngine(llama_init(cfg, seed=0), cfg, n_slots=4,
+                         max_seq_len=64,
+                         prefill_buckets=(8,), decode_block_size=4)
     eng.STALL_REJECT_S = 0.2
     eng.start()
     # warm so the wedge window isn't spent compiling

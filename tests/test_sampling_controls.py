@@ -17,7 +17,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from gofr_tpu.models.llama import LlamaConfig, llama_init
-from gofr_tpu.tpu.engine import LLMEngine
 from gofr_tpu.tpu.paging import PagedLLMEngine
 from gofr_tpu.tpu.sampling import pack_controls, sample_tokens, temperature_of
 
@@ -78,13 +77,12 @@ def test_temperature_of_both_shapes():
     assert jnp.array_equal(temperature_of(wide), flat)
 
 
-def _serve(cls=LLMEngine, controls=True, submits=None, **kw):
+def _serve(controls=True, submits=None, **kw):
     params = llama_init(CFG, seed=0)
-    if cls is PagedLLMEngine:
-        kw.setdefault("page_size", 16)
-    eng = cls(params, CFG, n_slots=4, max_seq_len=128,
-              prefill_buckets=(8, 32), decode_block_size=4,
-              sampling_controls=controls, **kw)
+    kw.setdefault("page_size", 16)
+    eng = PagedLLMEngine(params, CFG, n_slots=4, max_seq_len=128,
+                         prefill_buckets=(8, 32), decode_block_size=4,
+                         sampling_controls=controls, **kw)
     eng.start()
     try:
         reqs = [eng.submit(p, **(s or {"max_new_tokens": 10,
@@ -101,18 +99,13 @@ def test_controls_engine_greedy_parity():
     assert _serve(controls=True) == _serve(controls=False)
 
 
-@pytest.mark.parametrize("cls", [
-    LLMEngine,
-    # tier-1 wall-clock budget: dense variant stays as the in-lane rep
-    pytest.param(PagedLLMEngine, marks=pytest.mark.slow),
-])
-def test_top_k_one_matches_greedy_end_to_end(cls):
+def test_top_k_one_matches_greedy_end_to_end():
     """temperature 1.0 + top_k=1 leaves one survivor per step: the served
-    tokens must equal the greedy run's token-for-token, on both engines."""
-    want = _serve(cls=cls, controls=False)
+    tokens must equal the greedy run's token-for-token."""
+    want = _serve(controls=False)
     sub = [{"max_new_tokens": 10, "temperature": 1.0, "top_k": 1}
            for _ in PROMPTS]
-    assert _serve(cls=cls, submits=sub) == want
+    assert _serve(submits=sub) == want
 
 
 @pytest.mark.slow  # tier-1 wall-clock budget; lighter in-lane representative kept
@@ -123,50 +116,33 @@ def test_tiny_top_p_matches_greedy_end_to_end():
     assert _serve(submits=sub) == want
 
 
-def test_speculative_composes_with_controls():
-    """Spec mode + sampling controls: greedy rows still match the plain
-    engine exactly (the verify's greedy-row rule reads temperature through
-    temperature_of)."""
-    params = llama_init(CFG, seed=0)
-    eng = LLMEngine(params, CFG, n_slots=4, max_seq_len=128,
-                    prefill_buckets=(8, 32), speculative_tokens=4,
-                    sampling_controls=True)
-    eng.start()
-    try:
-        reqs = [eng.submit(p, max_new_tokens=12, temperature=0.0)
-                for p in PROMPTS]
-        got = [r.result(timeout_s=300) for r in reqs]
-    finally:
-        eng.stop()
-    want = _serve(controls=False, submits=[
-        {"max_new_tokens": 12, "temperature": 0.0} for _ in PROMPTS])
-    assert got == want
-
-
 def test_submit_validation():
     params = llama_init(CFG, seed=0)
-    eng = LLMEngine(params, CFG, n_slots=2, max_seq_len=64,
-                    prefill_buckets=(8,))
+    eng = PagedLLMEngine(params, CFG, n_slots=2, max_seq_len=64,
+                         prefill_buckets=(8,))
     with pytest.raises(ValueError, match="sampling_controls"):
         eng.submit([1, 2], top_p=0.5)
     with pytest.raises(ValueError, match="sampling_controls"):
         eng.submit([1, 2], top_k=5)
-    eng2 = LLMEngine(params, CFG, n_slots=2, max_seq_len=64,
-                     prefill_buckets=(8,), sampling_controls=True)
+    eng2 = PagedLLMEngine(params, CFG, n_slots=2, max_seq_len=64,
+                          prefill_buckets=(8,), sampling_controls=True)
     with pytest.raises(ValueError, match="top_p"):
         eng2.submit([1, 2], top_p=1.5)
     with pytest.raises(ValueError, match="top_k"):
         eng2.submit([1, 2], top_k=-1)
 
 
-def test_paged_speculative_composes_with_controls():
-    """The exact OpenAI-server default stack: paged pool + speculation +
-    sampling controls. The verify program must run (r4 review repro: the
-    paged acceptance used a raw `temps <= 0.0` against [B, 3] controls and
-    crashed on the first proposed draft)."""
+@pytest.mark.parametrize("page_size", [128, 16])
+def test_speculative_composes_with_controls(page_size):
+    """The exact OpenAI-server default stack: page pool + speculation +
+    sampling controls. Greedy rows still match the plain engine exactly
+    (the verify's greedy-row rule reads temperature through
+    temperature_of; r4 review repro: the acceptance used a raw
+    `temps <= 0.0` against [B, 3] controls and crashed on the first
+    proposed draft)."""
     params = llama_init(CFG, seed=0)
     eng = PagedLLMEngine(params, CFG, n_slots=4, max_seq_len=128,
-                         prefill_buckets=(8, 32), page_size=16,
+                         prefill_buckets=(8, 32), page_size=page_size,
                          speculative_tokens=4, sampling_controls=True)
     eng.start()
     try:
@@ -184,8 +160,8 @@ def test_control_row_clears_when_slot_frees():
     control row behind — the sampler gates its [B, V] sort on ANY row's
     controls, so a stale row would tax every later all-greedy batch."""
     params = llama_init(CFG, seed=0)
-    eng = LLMEngine(params, CFG, n_slots=2, max_seq_len=64,
-                    prefill_buckets=(8,), sampling_controls=True)
+    eng = PagedLLMEngine(params, CFG, n_slots=2, max_seq_len=64,
+                         prefill_buckets=(8,), sampling_controls=True)
     eng.start()
     try:
         eng.submit([1, 2, 3], max_new_tokens=4, temperature=0.9,

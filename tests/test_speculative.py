@@ -11,14 +11,9 @@ import dataclasses
 import pytest
 
 from gofr_tpu.models.llama import LlamaConfig, llama_init
-from gofr_tpu.tpu.engine import LLMEngine
 from gofr_tpu.tpu.paging import PagedLLMEngine
 
 CFG = LlamaConfig.debug()
-
-# both engines speculate since r4: the paged verify gathers each slot's
-# pages into contiguous rows per layer (llama_verify_step_paged)
-ENGINES = [LLMEngine, PagedLLMEngine]
 
 # prompts WITH self-repetition (drafts come from bigram lookup in the
 # sequence's own history) and without
@@ -30,13 +25,11 @@ PROMPTS = [
 ]
 
 
-def _serve(prompts, max_new=16, temperature=0.0, spec=0, seed=0,
-           cls=LLMEngine):
+def _serve(prompts, max_new=16, temperature=0.0, spec=0, seed=0):
     params = llama_init(CFG, seed=0)
-    kw = {"page_size": 16} if cls is PagedLLMEngine else {}
-    eng = cls(params, CFG, n_slots=4, max_seq_len=128,
-              prefill_buckets=(8, 32, 64), decode_block_size=4,
-              speculative_tokens=spec, seed=seed, **kw)
+    eng = PagedLLMEngine(params, CFG, n_slots=4, max_seq_len=128,
+                         prefill_buckets=(8, 32, 64), decode_block_size=4,
+                         speculative_tokens=spec, seed=seed, page_size=16)
     eng.start()
     try:
         reqs = [eng.submit(p, max_new_tokens=max_new, temperature=temperature)
@@ -46,24 +39,18 @@ def _serve(prompts, max_new=16, temperature=0.0, spec=0, seed=0,
         eng.stop()
 
 
-@pytest.mark.parametrize("cls", [
-    LLMEngine,
-    # tier-1 wall-clock budget: dense variant stays as the in-lane rep
-    pytest.param(PagedLLMEngine, marks=pytest.mark.slow),
-])
-def test_speculative_greedy_output_identical(cls):
+def test_speculative_greedy_output_identical():
     plain = _serve(PROMPTS, spec=0)
-    spec = _serve(PROMPTS, spec=4, cls=cls)
+    spec = _serve(PROMPTS, spec=4)
     assert spec == plain
 
 
-@pytest.mark.parametrize("cls", ENGINES)
-def test_speculative_single_long_generation_identical(cls):
+def test_speculative_single_long_generation_identical():
     """One slot, long generation: many verify dispatches chain their
     device-side state (positions advance by variable accepted+1)."""
     prompt = [3, 1, 4, 1, 5, 9, 2, 6, 3, 1, 4, 1, 5]
     plain = _serve([prompt], max_new=48, spec=0)
-    spec = _serve([prompt], max_new=48, spec=6, cls=cls)
+    spec = _serve([prompt], max_new=48, spec=6)
     assert spec == plain
 
 
@@ -117,9 +104,9 @@ def test_speculative_accepts_on_periodic_output():
     m = new_metrics_manager()
     m.new_counter("app_tpu_spec_drafted_total", "d")
     m.new_counter("app_tpu_spec_accepted_total", "a")
-    eng = LLMEngine(params, CFG, n_slots=2, max_seq_len=256,
-                    prefill_buckets=(8, 32), speculative_tokens=4,
-                    metrics=m)
+    eng = PagedLLMEngine(params, CFG, n_slots=2, max_seq_len=256,
+                         prefill_buckets=(8, 32), speculative_tokens=4,
+                         metrics=m)
     eng.start()
     try:
         # long generations: the tiny model's output enters a cycle, and
@@ -138,14 +125,14 @@ def test_speculative_accepts_on_periodic_output():
 
 def test_speculative_rejected_combinations():
     params = llama_init(CFG, seed=0)
-    q8 = dataclasses.replace(CFG, decode_attn="kernel", kv_dtype="int8")
+    q8 = dataclasses.replace(CFG, kv_dtype="int8")
     with pytest.raises(ValueError, match="spec"):
-        LLMEngine(params, q8, n_slots=2, max_seq_len=64,
-                  prefill_buckets=(8,), speculative_tokens=4)
+        PagedLLMEngine(params, q8, n_slots=2, max_seq_len=64,
+                       prefill_buckets=(8,), speculative_tokens=4)
     with pytest.raises(ValueError, match="spec"):
-        LLMEngine(params, CFG, n_slots=2, max_seq_len=64,
-                  prefill_buckets=(8, 32), chunk_prefill_tokens=8,
-                  speculative_tokens=4)
+        PagedLLMEngine(params, CFG, n_slots=2, max_seq_len=64,
+                       prefill_buckets=(8, 32), chunk_prefill_tokens=8,
+                       speculative_tokens=4)
 
 
 @pytest.mark.slow  # tier-1 wall-clock budget; lighter in-lane representative kept
@@ -157,7 +144,7 @@ def test_adaptive_speculation_cools_off_and_stays_correct():
     acceptance EMA (not the draftless-round fallback) is what's tested."""
     params = llama_init(CFG, seed=0)
 
-    class Tight(LLMEngine):
+    class Tight(PagedLLMEngine):
         SPEC_EMA_ALPHA = 0.5
         SPEC_MIN_ACCEPT = 0.6
         SPEC_COOLOFF_DISPATCHES = 4
@@ -200,8 +187,8 @@ def test_acceptance_ema_normalizes_by_greedy_eligible_slots():
     from gofr_tpu.tpu.engine import GenerationRequest
 
     params = llama_init(CFG, seed=0)
-    eng = LLMEngine(params, CFG, n_slots=4, max_seq_len=128,
-                    prefill_buckets=(8,), speculative_tokens=4)
+    eng = PagedLLMEngine(params, CFG, n_slots=4, max_seq_len=128,
+                         prefill_buckets=(8,), speculative_tokens=4)
     reqs = []
     for i, temp in enumerate([0.0, 0.0, 0.9, 0.9]):
         r = GenerationRequest([1, 2, 3], max_new_tokens=64, temperature=temp)
@@ -219,7 +206,7 @@ def test_acceptance_ema_normalizes_by_greedy_eligible_slots():
     eng._inflight.append(("verify", (out, n_emit), snapshot, 4,
                           _t.time(), None))
     eng._sync_oldest()
-    a = LLMEngine.SPEC_EMA_ALPHA
+    a = PagedLLMEngine.SPEC_EMA_ALPHA
     # 8 accepted over TWO eligible rows -> 4.0/slot; the diluted (buggy)
     # figure would be 8/4 = 2.0
     assert eng._spec_accept_ema == pytest.approx((1 - a) * 1.0 + a * 4.0)
@@ -236,9 +223,9 @@ def test_mixed_temperature_does_not_cool_off_greedy_traffic():
     params = llama_init(CFG, seed=0)
     m = new_metrics_manager()
     m.new_counter("app_tpu_spec_accepted_total", "a")
-    eng = LLMEngine(params, CFG, n_slots=4, max_seq_len=256,
-                    prefill_buckets=(8, 32, 64), speculative_tokens=4,
-                    metrics=m, seed=0)
+    eng = PagedLLMEngine(params, CFG, n_slots=4, max_seq_len=256,
+                         prefill_buckets=(8, 32, 64), speculative_tokens=4,
+                         metrics=m, seed=0)
     eng.start()
     try:
         greedy = [eng.submit(p, max_new_tokens=96, temperature=0.0)
@@ -256,8 +243,8 @@ def test_mixed_temperature_does_not_cool_off_greedy_traffic():
 
     # greedy rows must still match the plain engine exactly
     params = llama_init(CFG, seed=0)
-    plain = LLMEngine(params, CFG, n_slots=4, max_seq_len=256,
-                      prefill_buckets=(8, 32, 64), seed=0)
+    plain = PagedLLMEngine(params, CFG, n_slots=4, max_seq_len=256,
+                           prefill_buckets=(8, 32, 64), seed=0)
     plain.start()
     try:
         expect = [plain.submit(p, max_new_tokens=96, temperature=0.0).result(
@@ -273,9 +260,9 @@ def test_zero_draft_verify_falls_back_to_block_decode():
     import time as _t
 
     params = llama_init(CFG, seed=0)
-    eng = LLMEngine(params, CFG, n_slots=2, max_seq_len=128,
-                    prefill_buckets=(8, 32), decode_block_size=4,
-                    speculative_tokens=4, seed=3)
+    eng = PagedLLMEngine(params, CFG, n_slots=2, max_seq_len=128,
+                         prefill_buckets=(8, 32), decode_block_size=4,
+                         speculative_tokens=4, seed=3)
     eng.start()
     try:
         reqs = [eng.submit(p, max_new_tokens=12, temperature=0.9)

@@ -169,14 +169,14 @@ def test_step_metrics_published_with_exemplars():
 
 # -- end-to-end: engine + sentinel + fault injection --------------------------
 def _engine(**kw):
-    from gofr_tpu.tpu.engine import LLMEngine
+    from gofr_tpu.tpu.paging import PagedLLMEngine
 
     kw.setdefault("n_slots", 2)
     kw.setdefault("max_seq_len", 128)
     kw.setdefault("prefill_buckets", (16,))
     kw.setdefault("decode_block_size", 1)
     kw.setdefault("pipeline_depth", 1)
-    eng = LLMEngine(llama_init(CFG, seed=0), CFG, **kw)
+    eng = PagedLLMEngine(llama_init(CFG, seed=0), CFG, **kw)
     return eng
 
 

@@ -32,14 +32,14 @@ EXAMPLES = os.path.join(os.path.dirname(__file__), "..", "examples")
 
 
 def _engine(**kw):
-    from gofr_tpu.tpu.engine import LLMEngine
+    from gofr_tpu.tpu.paging import PagedLLMEngine
 
     kw.setdefault("n_slots", 2)
     kw.setdefault("max_seq_len", 128)
     kw.setdefault("prefill_buckets", (16,))
     kw.setdefault("decode_block_size", 1)
     kw.setdefault("pipeline_depth", 1)
-    return LLMEngine(llama_init(CFG, seed=0), CFG, **kw)
+    return PagedLLMEngine(llama_init(CFG, seed=0), CFG, **kw)
 
 
 # -- the trace-event contract, asserted structurally --------------------------
@@ -399,7 +399,7 @@ def test_fleet_timeline_stitches_disagg_replica_e2e():
     replica = llm.build_app(config=MockConfig({
         "HTTP_PORT": "0", "METRICS_PORT": "0", "TPU_PLATFORM": "cpu",
         "MODEL_PRESET": "debug", "WARMUP": "false", "MAX_BATCH": "4",
-        "MAX_SEQ_LEN": "64", "PREFILL_BUCKETS": "8,16", "PAGED": "true",
+        "MAX_SEQ_LEN": "64", "PREFILL_BUCKETS": "8,16",
         "PAGE_SIZE": "8", "REQUEST_TIMEOUT": "300", "LOG_LEVEL": "ERROR",
         "INCIDENT_AUTOPSY": "false", "DISAGG_MODE": "both",
         "APP_NAME": "r0"}))

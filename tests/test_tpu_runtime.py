@@ -233,15 +233,22 @@ def test_batcher_stop_fails_queued():
 
 
 # -- LLM engine ---------------------------------------------------------------
-@pytest.fixture(scope="module")
-def engine():
+@pytest.fixture(scope="module", params=["float", "int8"])
+def engine(request):
+    """The one engine over each pool dtype: floating point (a decode
+    block's K and V flushed once a block) and int8 (one page write a
+    token, `paged_write_decode`)."""
+    import dataclasses
+
     from gofr_tpu.models.llama import LlamaConfig, llama_init
-    from gofr_tpu.tpu.engine import LLMEngine
+    from gofr_tpu.tpu.paging import PagedLLMEngine
 
     cfg = LlamaConfig.debug()
+    if request.param == "int8":
+        cfg = dataclasses.replace(cfg, kv_dtype="int8")
     params = llama_init(cfg, seed=0)
-    eng = LLMEngine(params, cfg, n_slots=4, max_seq_len=64,
-                    prefill_buckets=(8, 16), logger=MockLogger())
+    eng = PagedLLMEngine(params, cfg, n_slots=4, max_seq_len=64,
+                         prefill_buckets=(8, 16), logger=MockLogger())
     eng.start()
     yield eng
     eng.stop()
@@ -257,7 +264,9 @@ def test_engine_generates_deterministically(engine):
 
 
 def test_engine_matches_unbatched_reference(engine):
-    """Greedy engine output == step-by-step nocache reference decode."""
+    """Greedy engine output == step-by-step nocache reference decode. Over
+    int8 pages the prefill is still full precision, so the first token is
+    exact; later reads differ by int8 rounding, where a near-tie may flip."""
     import jax.numpy as jnp
 
     from gofr_tpu.models.llama import llama_forward_nocache
@@ -270,7 +279,12 @@ def test_engine_matches_unbatched_reference(engine):
         logits = llama_forward_nocache(engine.params, engine.cfg,
                                        jnp.asarray([seq], dtype=jnp.int32))
         seq.append(int(np.asarray(jnp.argmax(logits[0, -1]))))
-    assert got == seq[len(prompt):]
+    want = seq[len(prompt):]
+    if engine.cfg.kv_dtype == "int8":
+        assert len(got) == len(want) and got[0] == want[0]
+        assert sum(a == b for a, b in zip(got, want)) >= 4
+    else:
+        assert got == want
 
 
 def test_engine_concurrent_requests(engine):
@@ -323,16 +337,16 @@ def test_engine_pipelined_matches_synchronous():
     fully synchronous block=1/depth=1 configuration, including under fused
     multi-request admission."""
     from gofr_tpu.models.llama import LlamaConfig, llama_init
-    from gofr_tpu.tpu.engine import LLMEngine
+    from gofr_tpu.tpu.paging import PagedLLMEngine
 
     cfg = LlamaConfig.debug()
     params = llama_init(cfg, seed=0)
     prompts = [[1, 2, 3], [7, 8], [4, 5, 6, 9], [2, 2, 2], [11, 12]]
 
     def run(block, depth):
-        eng = LLMEngine(params, cfg, n_slots=4, max_seq_len=64,
-                        prefill_buckets=(8,), decode_block_size=block,
-                        pipeline_depth=depth)
+        eng = PagedLLMEngine(params, cfg, n_slots=4, max_seq_len=64,
+                             prefill_buckets=(8,), decode_block_size=block,
+                             pipeline_depth=depth)
         eng.start()
         try:
             reqs = [eng.submit(p, max_new_tokens=7, temperature=0.0)
@@ -351,13 +365,13 @@ def test_stream_ordering_with_cancels_mid_block():
     exactly `request.emitted`, in order, with the terminal `None` strictly
     last — the invariant the PR-3 replay ledger and SSE streaming build on."""
     from gofr_tpu.models.llama import LlamaConfig, llama_init
-    from gofr_tpu.tpu.engine import LLMEngine
+    from gofr_tpu.tpu.paging import PagedLLMEngine
 
     cfg = LlamaConfig.debug()
     params = llama_init(cfg, seed=0)
-    eng = LLMEngine(params, cfg, n_slots=4, max_seq_len=64,
-                    prefill_buckets=(8,), decode_block_size=4,
-                    pipeline_depth=2)
+    eng = PagedLLMEngine(params, cfg, n_slots=4, max_seq_len=64,
+                         prefill_buckets=(8,), decode_block_size=4,
+                         pipeline_depth=2)
     eng.start()
     try:
         prompts = [[i + 1, i + 2, i + 3] for i in range(6)]
@@ -426,14 +440,16 @@ def test_engine_batch_id_trace_correlation():
     request's span at admission and emits tpu.prefill/tpu.decode dispatch
     spans that close at host sync (SURVEY §5 tracing row)."""
     from gofr_tpu.models.llama import LlamaConfig, llama_init
-    from gofr_tpu.tpu.engine import LLMEngine
+    from gofr_tpu.tpu.paging import PagedLLMEngine
     from gofr_tpu.tracing import InMemoryExporter, Tracer
 
     exporter = InMemoryExporter()
     tracer = Tracer(exporter=exporter)
     cfg = LlamaConfig.debug()
-    eng = LLMEngine(llama_init(cfg, seed=0), cfg, n_slots=2, max_seq_len=64,
-                    prefill_buckets=(8,), logger=MockLogger(), tracer=tracer)
+    eng = PagedLLMEngine(llama_init(cfg, seed=0), cfg, n_slots=2,
+                         max_seq_len=64,
+                         prefill_buckets=(8,), logger=MockLogger(),
+                         tracer=tracer)
     eng.start()
     try:
         span = tracer.start_span("POST /generate")
@@ -473,15 +489,15 @@ def test_engine_flash_prefill_matches_xla():
     import dataclasses
 
     from gofr_tpu.models.llama import LlamaConfig, llama_init
-    from gofr_tpu.tpu.engine import LLMEngine
+    from gofr_tpu.tpu.paging import PagedLLMEngine
 
     prompts = [[5, 6, 7], [9, 10, 11, 12, 13, 14], [1, 2]]
     outs = {}
     for impl in ("xla", "flash"):
         cfg = dataclasses.replace(LlamaConfig.debug(), attn_impl=impl)
-        eng = LLMEngine(llama_init(cfg, seed=0), cfg, n_slots=4,
-                        max_seq_len=64, prefill_buckets=(8,),
-                        logger=MockLogger())
+        eng = PagedLLMEngine(llama_init(cfg, seed=0), cfg, n_slots=4,
+                             max_seq_len=64, prefill_buckets=(8,),
+                             logger=MockLogger())
         eng.start()
         try:
             outs[impl] = [eng.generate(p, max_new_tokens=6, temperature=0.0)
@@ -496,11 +512,12 @@ def test_engine_host_prep_error_fails_only_that_wave():
     wave; active requests and device state survive (VERDICT r2 weak #5)."""
     from gofr_tpu import native
     from gofr_tpu.models.llama import LlamaConfig, llama_init
-    from gofr_tpu.tpu.engine import LLMEngine
+    from gofr_tpu.tpu.paging import PagedLLMEngine
 
     cfg = LlamaConfig.debug()
-    eng = LLMEngine(llama_init(cfg, seed=0), cfg, n_slots=4, max_seq_len=64,
-                    prefill_buckets=(8,), logger=MockLogger())
+    eng = PagedLLMEngine(llama_init(cfg, seed=0), cfg, n_slots=4,
+                         max_seq_len=64,
+                         prefill_buckets=(8,), logger=MockLogger())
     eng.start()
     try:
         # a long-running request that must SURVIVE the other wave's failure
@@ -547,15 +564,15 @@ def test_histogram_record_n_batches():
 def test_engine_stop_unblocks_active_requests():
     """stop() must fail mid-generation requests, never deadlock their clients."""
     from gofr_tpu.models.llama import LlamaConfig, llama_init
-    from gofr_tpu.tpu.engine import LLMEngine
+    from gofr_tpu.tpu.paging import PagedLLMEngine
 
     cfg = LlamaConfig.debug()
     params = llama_init(cfg, seed=0)
     # budget far beyond what the post-stop drain (pipeline_depth * block
     # tokens) can finish, so the slot is still active at loop exit
-    eng = LLMEngine(params, cfg, n_slots=2, max_seq_len=256,
-                    prefill_buckets=(8,), decode_block_size=4,
-                    pipeline_depth=2, logger=MockLogger())
+    eng = PagedLLMEngine(params, cfg, n_slots=2, max_seq_len=256,
+                         prefill_buckets=(8,), decode_block_size=4,
+                         pipeline_depth=2, logger=MockLogger())
     eng.start()
     req = eng.submit([1, 2, 3], max_new_tokens=250, temperature=0.0)
     while req.generated == 0:  # wait until admitted into a slot
@@ -569,11 +586,12 @@ def test_engine_drain_finishes_active_rejects_new():
     """drain(): active generations complete with their full token budget,
     queued/new requests fail fast, stop() afterwards is clean."""
     from gofr_tpu.models.llama import LlamaConfig, llama_init
-    from gofr_tpu.tpu.engine import LLMEngine
+    from gofr_tpu.tpu.paging import PagedLLMEngine
 
     cfg = LlamaConfig.debug()
-    eng = LLMEngine(llama_init(cfg, seed=0), cfg, n_slots=2, max_seq_len=128,
-                    prefill_buckets=(8,), decode_block_size=4)
+    eng = PagedLLMEngine(llama_init(cfg, seed=0), cfg, n_slots=2,
+                         max_seq_len=128,
+                         prefill_buckets=(8,), decode_block_size=4)
     eng.start()
     try:
         active = eng.submit([1, 2, 3], max_new_tokens=24, temperature=0.0)
@@ -614,11 +632,12 @@ def test_priority_admission_order():
     """A high-priority request queued behind low-priority ones is admitted
     first once a slot frees; running generations are never preempted."""
     from gofr_tpu.models.llama import LlamaConfig, llama_init
-    from gofr_tpu.tpu.engine import LLMEngine
+    from gofr_tpu.tpu.paging import PagedLLMEngine
 
     cfg = LlamaConfig.debug()
-    eng = LLMEngine(llama_init(cfg, seed=0), cfg, n_slots=1, max_seq_len=64,
-                    prefill_buckets=(8,), decode_block_size=2)
+    eng = PagedLLMEngine(llama_init(cfg, seed=0), cfg, n_slots=1,
+                         max_seq_len=64,
+                         prefill_buckets=(8,), decode_block_size=2)
     eng.start()
     try:
         blocker = eng.submit([1, 2, 3], max_new_tokens=24, temperature=0.0)
@@ -643,11 +662,12 @@ def test_min_tokens_suppresses_early_stop():
     """stop_tokens are ignored until min_tokens have been emitted; without
     the floor the same stop set ends generation earlier."""
     from gofr_tpu.models.llama import LlamaConfig, llama_init
-    from gofr_tpu.tpu.engine import LLMEngine
+    from gofr_tpu.tpu.paging import PagedLLMEngine
 
     cfg = LlamaConfig.debug()
-    eng = LLMEngine(llama_init(cfg, seed=0), cfg, n_slots=2, max_seq_len=64,
-                    prefill_buckets=(8,), decode_block_size=4)
+    eng = PagedLLMEngine(llama_init(cfg, seed=0), cfg, n_slots=2,
+                         max_seq_len=64,
+                         prefill_buckets=(8,), decode_block_size=4)
     eng.start()
     try:
         prompt = [3, 1, 4]

@@ -165,13 +165,13 @@ def test_executor_compile_table():
 
 
 def _engine(**kw):
-    from gofr_tpu.tpu.engine import LLMEngine
+    from gofr_tpu.tpu.paging import PagedLLMEngine
 
     kw.setdefault("n_slots", 2)
     kw.setdefault("max_seq_len", 64)
     kw.setdefault("prefill_buckets", (16,))
     kw.setdefault("decode_block_size", 4)
-    eng = LLMEngine(llama_init(CFG, seed=0), CFG, **kw)
+    eng = PagedLLMEngine(llama_init(CFG, seed=0), CFG, **kw)
     eng.start()
     return eng
 

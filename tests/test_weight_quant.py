@@ -26,7 +26,7 @@ from gofr_tpu.models.llama import (
     params_nbytes,
     quantize_weights,
 )
-from gofr_tpu.tpu.engine import LLMEngine
+from gofr_tpu.tpu.paging import PagedLLMEngine
 
 CFG = LlamaConfig.debug()
 PROMPTS = [list(range(1, 9)), [7, 5, 3], list(range(20, 50)), [11]]
@@ -114,8 +114,8 @@ def test_logits_close_to_float_model():
 
 
 def _serve(params, cfg=CFG, **kw):
-    eng = LLMEngine(params, cfg, n_slots=4, max_seq_len=128,
-                    prefill_buckets=(8, 32), decode_block_size=4, **kw)
+    eng = PagedLLMEngine(params, cfg, n_slots=4, max_seq_len=128,
+                         prefill_buckets=(8, 32), decode_block_size=4, **kw)
     eng.start()
     try:
         reqs = [eng.submit(p, max_new_tokens=12, temperature=0.0)
@@ -142,8 +142,9 @@ def test_engine_plan_uses_actual_quantized_bytes():
     """The capacity plan must budget the MEASURED int8 tree, not the
     analytic cfg-dtype estimate (4x larger for an f32-config debug model)."""
     q = _qtree()
-    eng = LLMEngine(q, CFG, n_slots=2, max_seq_len=128, prefill_buckets=(8,),
-                    budget_bytes=1 << 30)
+    eng = PagedLLMEngine(q, CFG, n_slots=2, max_seq_len=128,
+                         prefill_buckets=(8,),
+                         budget_bytes=1 << 30)
     assert eng.plan is not None
     assert eng.plan.params_bytes == params_nbytes(q)
     assert eng.plan.params_bytes < CFG.param_count() * 2
@@ -167,8 +168,8 @@ def test_quantized_tp_mesh_matches_single_device():
 
     def serve(m):
         params = quantize_weights(llama_init(cfg, seed=0))
-        eng = LLMEngine(params, cfg, n_slots=4, max_seq_len=64,
-                        prefill_buckets=(8,), mesh=m)
+        eng = PagedLLMEngine(params, cfg, n_slots=4, max_seq_len=64,
+                             prefill_buckets=(8,), mesh=m)
         eng.start()
         try:
             reqs = [eng.submit(p, max_new_tokens=6, temperature=0.0)
@@ -184,7 +185,7 @@ def test_quantized_tp_mesh_matches_single_device():
 def test_quantized_composes_with_int8_kv():
     """Weight quant (HBM for params) and KV quant (HBM for cache) are
     independent axes — both on must still serve deterministically."""
-    cfg = dataclasses.replace(CFG, decode_attn="kernel", kv_dtype="int8")
+    cfg = dataclasses.replace(CFG, kv_dtype="int8")
     out = _serve(_qtree(), cfg=cfg)
     assert [len(t) for t in out] == [12] * len(PROMPTS)
     assert out == _serve(_qtree(), cfg=cfg)

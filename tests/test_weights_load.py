@@ -212,7 +212,7 @@ def test_export_rejects_quantized_tree(tmp_path):
 def test_engine_boots_from_disk_token_parity(tmp_path, weight_dtype):
     """The serving engine fed from disk generates the SAME tokens as the
     engine fed the in-memory tree (greedy, so parity is exact)."""
-    from gofr_tpu.tpu.engine import LLMEngine
+    from gofr_tpu.tpu.paging import PagedLLMEngine
 
     path = str(tmp_path / "model.safetensors")
     export_llama_safetensors(llama_init(CFG, seed=9), path)
@@ -223,8 +223,8 @@ def test_engine_boots_from_disk_token_parity(tmp_path, weight_dtype):
     prompts = [[5, 6, 7, 8], [9, 10, 11, 12, 13, 14]]
     outs = []
     for params in (oracle_params, loaded):
-        eng = LLMEngine(params, CFG, n_slots=2, max_seq_len=64,
-                        prefill_buckets=(8,))
+        eng = PagedLLMEngine(params, CFG, n_slots=2, max_seq_len=64,
+                             prefill_buckets=(8,))
         eng.start()
         try:
             handles = [eng.submit(p, max_new_tokens=12) for p in prompts]

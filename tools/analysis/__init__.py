@@ -15,6 +15,9 @@ lives by, none of which a stock linter knows about:
                   every nested acquisition is acknowledged.
 - ``surface``   — metric names, config keys, and `/debug/*` endpoints are
                   documented where the runtime inventory tests expect them.
+- ``oneengine`` — `gofr_tpu/tpu/engine.py` imports no model function but
+                  the ones excepted by name, and nothing outside
+                  `tpu/paging.py` constructs `LLMEngine`.
 
 Run it with ``python -m tools.analysis`` (see runner.py for the CLI) or
 through :func:`tools.analysis.runner.run` from tests. Everything here is

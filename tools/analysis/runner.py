@@ -1,8 +1,8 @@
 """Runner + CLI: `python -m tools.analysis [--json] [--baseline PATH]`.
 
 Exit status is the OR of the failing rules' bits (hotloop=1 clock=2
-ownership=4 lockorder=8 surface=16), 0 when every finding is either
-pragma-suppressed or baselined. The tier-1 gate (tests/test_analysis.py)
+ownership=4 lockorder=8 surface=16 oneengine=32), 0 when every finding is
+either pragma-suppressed or baselined. The tier-1 gate (tests/test_analysis.py)
 calls :func:`run` in-process and asserts exit 0 over the real tree.
 """
 
@@ -124,7 +124,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
         prog="python -m tools.analysis",
         description="graftlint: repo-invariant static analysis "
                     "(hot-loop sync, clock discipline, thread ownership, "
-                    "lock order, surface inventory)")
+                    "lock order, surface inventory, one engine)")
     parser.add_argument("--root", default=REPO_ROOT,
                         help="tree to analyze (default: this repo)")
     parser.add_argument("--json", action="store_true",

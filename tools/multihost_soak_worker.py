@@ -31,16 +31,17 @@ from gofr_tpu.models.llama import LlamaConfig, llama_init  # noqa: E402
 from gofr_tpu.parallel import MeshPlan, make_mesh  # noqa: E402
 from gofr_tpu.parallel.multihost import initialize_from_config  # noqa: E402
 from gofr_tpu.tpu.admission import AdmissionPlane  # noqa: E402
-from gofr_tpu.tpu.engine import LLMEngine  # noqa: E402
+from gofr_tpu.tpu.paging import PagedLLMEngine  # noqa: E402
 
 CFG = LlamaConfig(vocab_size=128, dim=32, n_layers=2, n_heads=2,
                   n_kv_heads=2, ffn_dim=64, max_seq_len=256, dtype="float32")
 
 
 def _engine(mesh, plane):
-    return LLMEngine(llama_init(CFG, seed=0), CFG, n_slots=4,
-                     max_seq_len=256, prefill_buckets=(8, 16),
-                     decode_block_size=4, mesh=mesh, admission_plane=plane)
+    return PagedLLMEngine(llama_init(CFG, seed=0), CFG, n_slots=4,
+                          max_seq_len=256, prefill_buckets=(8, 16),
+                          decode_block_size=4, page_size=16, mesh=mesh,
+                          admission_plane=plane)
 
 
 def _checksum(streams):

@@ -4,7 +4,7 @@
 Reproduces the round-3 soak profiles as one committed command (VERDICT r3
 weak #4: "soak results are claims, not artifacts"):
 
-    python tools/soak.py mixed       # dense engine, chunked prefill
+    python tools/soak.py mixed       # chunked prefill
     python tools/soak.py paged-int8  # paged pool, int8 pages + weights
     python tools/soak.py spec        # speculative decoding (paged pool)
     python tools/soak.py chat        # multi-turn sessions, tiered KV cache
@@ -42,7 +42,6 @@ def _build(profile: str, preset: str, chaos: bool = False):
     import dataclasses
 
     from gofr_tpu.models.llama import LlamaConfig, llama_init, quantize_weights
-    from gofr_tpu.tpu.engine import LLMEngine
     from gofr_tpu.tpu.paging import PagedLLMEngine
 
     cfg = {"debug": LlamaConfig.debug, "llama1b": LlamaConfig.llama1b}[preset]()
@@ -62,11 +61,10 @@ def _build(profile: str, preset: str, chaos: bool = False):
                   reset_storm_window_s=60.0, breaker_cooldown_s=2.0)
     if profile == "mixed":
         cfg = dataclasses.replace(
-            cfg, attn_impl=cfg.attn_impl if small else "flash",
-            decode_attn="xla" if small else "kernel")
+            cfg, attn_impl=cfg.attn_impl if small else "flash")
         params = llama_init(cfg, seed=0)
-        return LLMEngine(params, cfg, chunk_prefill_tokens=16 if small else 64,
-                         **kw)
+        return PagedLLMEngine(params, cfg, page_size=16 if small else 128,
+                              chunk_prefill_tokens=16 if small else 64, **kw)
     if profile == "paged-int8":
         cfg = dataclasses.replace(cfg, kv_dtype="int8")
         params = quantize_weights(llama_init(cfg, seed=0))
@@ -637,7 +635,7 @@ def run_router(seconds: float, n_threads: int, preset: str) -> bool:
     small = preset == "debug"
     base_cfg = {
         "HTTP_PORT": "0", "METRICS_PORT": "0", "GRPC_PORT": "0",
-        "MODEL_PRESET": preset, "PAGED": "true",
+        "MODEL_PRESET": preset,
         "PAGE_SIZE": "16" if small else "128",
         "PREFIX_CACHE": "true",
         "MAX_SEQ_LEN": "256" if small else "1024",
@@ -920,7 +918,7 @@ def run_qos(seconds: float, n_threads: int, preset: str) -> bool:
     small = preset == "debug"
     app = llm.build_app(config=MockConfig({
         "HTTP_PORT": "0", "METRICS_PORT": "0", "GRPC_PORT": "0",
-        "APP_NAME": "qos-soak", "MODEL_PRESET": preset, "PAGED": "true",
+        "APP_NAME": "qos-soak", "MODEL_PRESET": preset,
         "PAGE_SIZE": "16" if small else "128",
         # the top bucket bounds the preemption resume window
         # (prompt + emitted must re-admit, and buckets clamp to the
@@ -1212,7 +1210,7 @@ def run_capacity(seconds: float, n_threads: int, preset: str) -> bool:
     app = llm.build_app(config=MockConfig({
         "HTTP_PORT": "0", "METRICS_PORT": "0", "GRPC_PORT": "0",
         "APP_NAME": "capacity-soak", "MODEL_PRESET": preset,
-        "PAGED": "true", "PAGE_SIZE": "16" if small else "128",
+        "PAGE_SIZE": "16" if small else "128",
         "MAX_SEQ_LEN": "256" if small else "1024",
         "PREFILL_BUCKETS": "16,64" if small else "64,128,256",
         "MAX_BATCH": "4" if small else "16", "WARMUP": "true",
@@ -1519,7 +1517,7 @@ def run_elastic(seconds: float, n_threads: int, preset: str) -> bool:
     shutil.rmtree(cache_dir, ignore_errors=True)
     base_cfg = {
         "HTTP_PORT": "0", "METRICS_PORT": "0", "GRPC_PORT": "0",
-        "MODEL_PRESET": preset, "PAGED": "true",
+        "MODEL_PRESET": preset,
         "PAGE_SIZE": "16" if small else "128",
         "PREFIX_CACHE": "true", "KV_HOST_TIER_BYTES": str(32 << 20),
         "MAX_SEQ_LEN": "256" if small else "1024",
@@ -1886,7 +1884,7 @@ def run_loadgen(seconds: float, n_threads: int, preset: str) -> bool:
     small = preset == "debug"
     replica_cfg = {
         "HTTP_PORT": "0", "METRICS_PORT": "0", "GRPC_PORT": "0",
-        "MODEL_PRESET": preset, "PAGED": "true",
+        "MODEL_PRESET": preset,
         "PAGE_SIZE": "16" if small else "128",
         "MAX_SEQ_LEN": "256" if small else "1024",
         "PREFILL_BUCKETS": "16,64" if small else "64,128,256",
