@@ -127,6 +127,10 @@ class TPUClient:
         for name, desc in (
             ("app_tpu_queue_depth", "requests waiting for batch assembly"),
             ("app_tpu_active_slots", "occupied continuous-batching slots"),
+            ("app_tpu_decode_blocks_queued",
+             "decode blocks still in flight behind the one the engine loop "
+             "last read (0 while slots decode = a dry sync: the device "
+             "idles through that block's demux and emit)"),
             ("app_tpu_hbm_bytes_used", "HBM bytes in use per device"),
             ("app_tpu_hbm_bytes_limit", "HBM bytes available per device"),
             ("app_tpu_tokens_per_second", "rolling decode throughput"),

@@ -24,7 +24,11 @@ The TPU-first shape of the problem (SURVEY.md §5 long-context + §7.5):
   - up to `pipeline_depth` dispatches are kept in flight; the host syncs the
     oldest block while the device executes the younger ones, so the
     host↔device round-trip and the Python demux loop are overlapped with
-    device compute
+    device compute. The depth is a depth of DECODE work: a prefill in
+    flight is not a decode block (`_room_for_decode`), so admissions
+    passing through the deque never leave the device without a block
+    queued behind the one it runs, and a prefill entry is read in the
+    loop turn that brings it to the deque's head (`_loop`)
   - requests stream tokens out through per-request queues; new requests are
     admitted into free slots between dispatches (continuous batching)
 
@@ -783,6 +787,11 @@ class LLMEngine:
         #   ("decode", out_tokens [B, M] future, [(slot_idx, request)], M)
         #   ("prefill", first_tokens [K] future, [(slot_idx, request)])
         self._inflight: "collections.deque" = collections.deque()
+        # decode blocks read, and those read with slots still decoding and
+        # no decode block queued behind them (loop thread writes;
+        # /debug/engine reads)
+        self.decode_syncs_total = 0
+        self.dry_syncs_total = 0
 
         # wedge detection: the loop stamps this every iteration; a stamp
         # that stops moving while work is in flight means the thread is
@@ -1215,8 +1224,10 @@ class LLMEngine:
     def request_migration(self, sink) -> None:
         """Ask the loop to export every live decode session to ``sink``
         (elastic drain-with-migration, fleet/elastic.py). Thread-safe;
-        returns immediately. The loop waits for in-flight dispatches to
-        sync (pipeline_depth steps at most), then calls
+        returns immediately. The loop stops feeding the pipeline and
+        waits for in-flight dispatches to sync (at most `pipeline_depth`
+        decode blocks, one a loop turn, each with the prefill entries
+        behind it), then calls
         ``sink(request, blobs, n_ctx)`` once per active slot at the
         quiesced boundary: True means the sink took ownership of the
         stream (the slot evacuates, nothing further is emitted locally);
@@ -1452,7 +1463,8 @@ class LLMEngine:
                     if any_active and self._migrate_request:
                         # a migration round is pending: stop feeding the
                         # pipeline so in-flight work drains to the
-                        # quiesced boundary within pipeline_depth syncs
+                        # quiesced boundary within pipeline_depth turns
+                        # (a decode block a turn, its prefills with it)
                         any_active = False
                     if any_active and self.disagg_role == "prefill":
                         # slots on a prefill pool evacuate at prefill
@@ -1479,8 +1491,7 @@ class LLMEngine:
                                 for e in self._inflight):
                             self._dispatch_verify()
                     else:
-                        while (any_active
-                               and len(self._inflight) < self.pipeline_depth):
+                        while any_active and self._room_for_decode():
                             self._dispatch_decode()
                             if self._spec_cooloff > 0:
                                 self._spec_cooloff -= 1
@@ -1506,6 +1517,19 @@ class LLMEngine:
                 # close the step BEFORE any idle park below: the wait time
                 # belongs to the NEXT step's idle_gap, not this step's wall
                 self._finish_step()
+                # prefill entries now at the head ran right behind what
+                # was just read (6-13 ms each, done before its emit was):
+                # read them in this turn, up to the next decode or verify
+                # entry, so their first tokens leave now and the deque
+                # holds decode blocks again. A record a sync, as before.
+                # Decided by the deque's own contents, so every rank of an
+                # admission plane reads the same entries in the same turn
+                while synced and self._inflight \
+                        and self._inflight[0][0] == "prefill":
+                    steps.step_start()
+                    with steps.seg("emit"):
+                        self._sync_oldest()
+                    self._finish_step()
                 if not synced and not self._chunk_jobs \
                         and not self._inflight:
                     with steps.between("park"):
@@ -1545,9 +1569,11 @@ class LLMEngine:
         """Close the step ledger's iteration record and surface a flagged
         straggler as a flight-recorder engine event carrying the dominant
         segment as the cause — the metrics→trace→request drill's anchor."""
+        inflight = len(self._inflight)
         self.steps.step_end(
             active_slots=sum(1 for s in self.slots if s.active),
-            inflight=len(self._inflight),
+            inflight=inflight,
+            inflight_prefill=inflight - self._decode_inflight(),
             queue_depth=self.queue_depth(),
             closing=self._step_closed)
         self._meter_rows = None     # a dropped iteration's rows go with it
@@ -1922,6 +1948,27 @@ class LLMEngine:
         self._inflight.append(("prefill", first, admitted, dspan,
                                time.monotonic()))
 
+    def _decode_inflight(self) -> int:
+        """Decode blocks and verifies in flight: the deque less its
+        prefill entries."""
+        return sum(1 for e in self._inflight if e[0] != "prefill")
+
+    def _room_for_decode(self) -> bool:
+        """Whether the loop's top-up dispatches one more decode block.
+        `pipeline_depth` caps the deque's entries as ever, but a prefill
+        in flight is not a decode block: under that cap alone every
+        admission took a block's place, and a closed loop that admits two
+        or three requests a turn and reads one entry a turn ended with a
+        deque of prefill entries and an idle device (PERF.md, PR 30). So
+        whatever the deque holds of prefills, a turn leaves one decode
+        block queued BEHIND the one the device may be running (a depth-1
+        engine stays synchronous: one). The block is `_decode_block_now`'s:
+        the half block while a prompt waits, which bounds what this costs
+        it. Reads the deque's entry kinds only: mirrored state, so every
+        rank of an admission plane dispatches the same programs."""
+        return (len(self._inflight) < self.pipeline_depth
+                or self._decode_inflight() < min(2, self.pipeline_depth))
+
     def _decode_block_now(self) -> int:
         """Adaptive block: full blocks for pure decode throughput, half
         blocks while requests are waiting to be admitted — sync points come
@@ -2162,6 +2209,9 @@ class LLMEngine:
             return
 
         _, out_tokens, snapshot, block, started, dspan = entry
+        # what the device has to go on with while this block's demux and
+        # emit run on the host
+        queued_behind = self._decode_inflight()
         sync_t0 = time.monotonic()
         try:
             with self.steps.seg("device_sync"):
@@ -2230,12 +2280,19 @@ class LLMEngine:
         if emitted:
             self._obs.counter("app_tpu_tokens_generated_total",
                               float(emitted))
+        # a DRY sync: slots still decode and no decode block was queued
+        # behind this one, so the device runs out of work (at most the
+        # prefills behind it) while the host is here
+        dry = queued_behind == 0 and any(s.active for s in self.slots)
+        self.decode_syncs_total += 1
+        self.dry_syncs_total += dry
+        self._obs.gauge("app_tpu_decode_blocks_queued", queued_behind)
         # every token in this sync shares one measured step time: record the
         # TPOT histogram ONCE per sync, not per token (VERDICT r2 weak #9)
         self.steps.note_sync(
             "decode", tokens=emitted,
             slowest_request_id=slowest.id if slowest else None,
-            page_writes=page_writes)
+            page_writes=page_writes, dry=dry)
         self._obs.hist_n(
             "app_tpu_tpot_seconds", step_s, emitted,
             exemplar=(self._exemplar_of(slowest) if slowest else None))

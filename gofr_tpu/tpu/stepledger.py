@@ -127,8 +127,9 @@ SEGMENTS = ("lock_wait", "admission", "page_alloc", "kv_restore",
 
 SPAN_PREFIX = "loop/"
 
-# step phases, by what the iteration synced (one sync per iteration) or,
-# sync-less, what it dispatched
+# step phases, by what the step synced (one sync a record: a loop turn
+# that reads the prefill entries behind its first sync closes a record for
+# each) or, sync-less, what it dispatched
 PHASES = ("prefill", "decode", "verify", "chunk", "dispatch", "admit")
 
 STEP_SECONDS_BUCKETS = (0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1,
@@ -140,8 +141,9 @@ class StepRecord:
 
     __slots__ = ("seq", "started_at", "wall_s", "idle_gap_s", "phase",
                  "segments", "segments_cpu", "cpu_s", "active_slots",
-                 "inflight", "queue_depth",
-                 "tokens", "page_writes", "dispatches", "slowest_request_id",
+                 "inflight", "inflight_prefill", "queue_depth",
+                 "tokens", "page_writes", "dry_sync", "dispatches",
+                 "slowest_request_id",
                  "straggler", "cause", "baseline_s")
 
     def __init__(self, seq: int, started_at: float, wall_s: float,
@@ -161,13 +163,21 @@ class StepRecord:
         self.segments_cpu = segments_cpu if segments_cpu is not None else {}
         self.cpu_s = cpu_s
         self.active_slots = 0
+        # dispatches still in flight at the step's close, and how many of
+        # them are prefills; the rest are decode blocks and verifies,
+        # which is what the device has to run while the loop is elsewhere
         self.inflight = 0
+        self.inflight_prefill = 0
         self.queue_depth = 0
         self.tokens = 0
         # pages the synced decode block's flush wrote (the paged engine's
         # floating-point pools: one a live row a block, two where the
         # block crossed a page; 0 where no block was synced)
         self.page_writes = 0
+        # a decode block was read with slots still decoding and no decode
+        # block queued behind it: the device ran dry through this step's
+        # demux and emit (engine._sync_oldest)
+        self.dry_sync = False
         self.dispatches: Dict[str, int] = {}
         self.slowest_request_id: Optional[int] = None
         self.straggler = False
@@ -192,11 +202,15 @@ class StepRecord:
                              for k, v in self.segments.items() if v > 0.0},
             "active_slots": self.active_slots,
             "inflight": self.inflight,
+            "inflight_decode": self.inflight - self.inflight_prefill,
+            "inflight_prefill": self.inflight_prefill,
             "queue_depth": self.queue_depth,
             "tokens": self.tokens,
         }
         if self.page_writes:
             out["page_writes"] = self.page_writes
+        if self.dry_sync:
+            out["dry_sync"] = True
         if self.dispatches:
             out["dispatches"] = dict(self.dispatches)
         if self.slowest_request_id is not None:
@@ -311,6 +325,7 @@ class StepLedger:
         self._sync_kind: Optional[str] = None
         self._tokens = 0
         self._page_writes = 0
+        self._dry_sync = False
         self._slowest: Optional[int] = None
 
     # -- wiring ---------------------------------------------------------------
@@ -383,6 +398,7 @@ class StepLedger:
         self._sync_kind = None
         self._tokens = 0
         self._page_writes = 0
+        self._dry_sync = False
         self._slowest = None
 
     class _Seg:
@@ -477,11 +493,12 @@ class StepLedger:
     @loop_only
     def note_sync(self, kind: str, tokens: int = 0,
                   slowest_request_id: Optional[int] = None,
-                  page_writes: int = 0) -> None:
+                  page_writes: int = 0, dry: bool = False) -> None:
         if self._mine():
             self._sync_kind = kind
             self._tokens += int(tokens)
             self._page_writes += int(page_writes)
+            self._dry_sync = self._dry_sync or bool(dry)
             if slowest_request_id is not None:
                 self._slowest = slowest_request_id
 
@@ -501,8 +518,8 @@ class StepLedger:
 
     @loop_only
     def step_end(self, active_slots: int = 0, inflight: int = 0,
-                 queue_depth: int = 0,
-                 closing=None) -> Optional[StepRecord]:
+                 queue_depth: int = 0, closing=None, *,
+                 inflight_prefill: int = 0) -> Optional[StepRecord]:
         """Close the step. Pure-bookkeeping iterations (no dispatch, no
         sync, no tokens) are dropped — their time accumulates into the
         next real step's idle_gap, so an idle engine never floods the
@@ -550,9 +567,11 @@ class StepLedger:
                          min(cpu_s, wall))
         rec.active_slots = int(active_slots)
         rec.inflight = int(inflight)
+        rec.inflight_prefill = int(inflight_prefill)
         rec.queue_depth = int(queue_depth)
         rec.tokens = self._tokens
         rec.page_writes = self._page_writes
+        rec.dry_sync = self._dry_sync
         rec.dispatches = dict(self._dispatches)
         rec.slowest_request_id = self._slowest
         with self.between("step_close"):
@@ -632,8 +651,10 @@ class StepLedger:
         for rec in ring:
             agg = summary.setdefault(rec.phase, {
                 "steps": 0, "wall_s": 0.0, "cpu_s": 0.0, "tokens": 0,
-                "idle_gap_s": 0.0, "segments": {}, "segments_cpu": {}})
+                "idle_gap_s": 0.0, "dry_syncs": 0, "segments": {},
+                "segments_cpu": {}})
             agg["steps"] += 1
+            agg["dry_syncs"] += rec.dry_sync
             agg["wall_s"] += rec.wall_s
             agg["cpu_s"] += rec.cpu_s
             agg["tokens"] += rec.tokens

@@ -473,6 +473,23 @@ class MemorySampler:
 
 
 # -- /debug/engine ------------------------------------------------------------
+def _pipeline_counts(engine) -> Dict[str, Any]:
+    """What the deque holds by kind, and how often a decode block was
+    read with slots still decoding and no decode block queued behind it
+    (a dry sync: the device idles through the loop's demux and emit)."""
+    kinds = [entry[0] for entry in list(engine._inflight)]
+    prefills = kinds.count("prefill")
+    syncs, dry = engine.decode_syncs_total, engine.dry_syncs_total
+    return {
+        "inflight_dispatches": len(kinds),
+        "inflight_decode": len(kinds) - prefills,
+        "inflight_prefill": prefills,
+        "decode_syncs_total": syncs,
+        "dry_syncs_total": dry,
+        "dry_sync_share": round(dry / syncs, 4) if syncs else 0.0,
+    }
+
+
 def engine_snapshot(engine, tpu=None) -> Dict[str, Any]:
     """One JSON snapshot of the whole engine: slots, buckets, page pool,
     utilization window, compile table, HBM. Read-only and best-effort —
@@ -490,7 +507,7 @@ def engine_snapshot(engine, tpu=None) -> Dict[str, Any]:
             "chunk_prefill_tokens": engine.chunk_prefill_tokens,
             "speculative_tokens": engine.speculative_tokens,
             "queue_depth": engine._pending.qsize(),
-            "inflight_dispatches": len(engine._inflight),
+            **_pipeline_counts(engine),
             "draining": engine._draining,
             "stall_seconds": round(engine.stall_seconds, 1),
         },

@@ -255,3 +255,72 @@ def test_stop_sentinel_unparks_an_idle_follower():
     t0 = time.time()
     follower.stop()  # must join promptly, not wait out a wave timeout
     assert time.time() - t0 < 10
+
+
+def _log_dispatches(engine, log):
+    """Every program the loop dispatches and every entry it reads, in
+    order: ("prefill", rows) | ("decode", block) | ("sync", kind)."""
+    bind, decode, sync = (engine._bind_slots, engine._dispatch_decode,
+                          engine._sync_oldest)
+
+    def bind_logged(slots_idx, *args, **kwargs):
+        log.append(("prefill", len(slots_idx)))
+        return bind(slots_idx, *args, **kwargs)
+
+    def decode_logged():
+        log.append(("decode", engine._decode_block_now()))
+        return decode()
+
+    def sync_logged():
+        log.append(("sync", engine._inflight[0][0]))
+        return sync()
+
+    engine._bind_slots, engine._dispatch_decode, engine._sync_oldest = (
+        bind_logged, decode_logged, sync_logged)
+
+
+def test_leader_and_follower_dispatch_the_same_sequence():
+    """The loop's two rules read the deque's entry kinds and nothing
+    rank-local (PR 30): with three prompts admitted by ONE wave while a
+    slot decodes (three prefill entries behind the decode blocks in
+    flight), leader and follower dispatch the same programs and read the
+    same entries in the same order, and neither reads a block dry."""
+    leader, follower, shadows = _pair(InProcKV(), pipeline_depth=4)
+    logs = {"leader": [], "follower": []}
+    _log_dispatches(leader, logs["leader"])
+    _log_dispatches(follower, logs["follower"])
+    requests, admit = [], leader._admit
+
+    def admit_with_late_arrivals():
+        # from the loop thread, so the three enter one wave whatever the
+        # host's timing
+        if len(requests) == 1 and requests[0].generated >= 9:
+            requests.extend(
+                leader.submit(p, max_new_tokens=9, temperature=0.0)
+                for p in PROMPTS[1:4])
+        admit()
+
+    leader._admit = admit_with_late_arrivals
+    requests.append(leader.submit(PROMPTS[0], max_new_tokens=40,
+                                  temperature=0.0))
+    follower.start()
+    leader.start()
+    try:
+        first = requests[0].result(timeout_s=120)
+        assert len(first) == 40 and len(requests) == 4
+        got = [r.result(timeout_s=60) for r in requests[1:]]
+        assert [len(t) for t in got] == [9, 9, 9]
+        _wait_shadows(shadows, 4)
+    finally:
+        leader.stop()
+        follower.stop()
+    n = logs["leader"].index(("sync", "decode"))
+    assert logs["leader"][n:].count(("prefill", 1)) == 3, \
+        "the late three were not admitted into a running decode"
+    # the follower may be cut by the stop sentinel before its last reads
+    m = len(logs["follower"])
+    assert m > n and logs["follower"] == logs["leader"][:m]
+    assert logs["follower"].count(("sync", "prefill")) == 4
+    for engine in (leader, follower):
+        assert engine.dry_syncs_total == 0 < engine.decode_syncs_total
+    assert follower.decode_syncs_total >= leader.decode_syncs_total - 4

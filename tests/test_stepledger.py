@@ -282,6 +282,103 @@ def test_paged_engine_records_page_alloc_segment():
     assert "dispatch" in seen
 
 
+# -- the dry-sync counter and the split `inflight` (PR 30) ---------------------
+def _stubbed_read(kinds, budget=64, **kw):
+    """An engine that never started, two slots bound by hand and a deque
+    of host arrays standing for dispatches of `kinds`: the oldest is read
+    as the loop reads it, one step around one sync."""
+    import collections
+    import time
+
+    import numpy as np
+
+    from gofr_tpu.tpu.engine import GenerationRequest
+
+    eng = _engine(decode_block_size=4, pipeline_depth=4, **kw)
+    reqs = []
+    for slot in eng.slots:
+        slot.request = GenerationRequest([1, 2, 3], max_new_tokens=budget)
+        slot.length, slot.remaining = 3, budget - 1
+        reqs.append(slot.request)
+    live = list(enumerate(reqs))
+    entries = {
+        "decode": ("decode", np.full((eng.n_slots, 4), 7, np.int32), live,
+                   4, time.monotonic(), None),
+        "prefill": ("prefill", np.full((eng.n_slots,), 7, np.int32), live,
+                    None, time.monotonic()),
+    }
+    eng._inflight = collections.deque(entries[k] for k in kinds)
+    eng.steps.step_start()
+    eng._sync_oldest()
+    eng._finish_step()
+    return eng
+
+
+@pytest.mark.parametrize("kinds, budget, dry", [
+    # the parent's top-up counted every entry, so this is where a closed
+    # loop's deque ended: one decode block, then prefills and nothing else
+    (("decode", "prefill", "prefill", "prefill"), 64, True),
+    (("decode", "prefill", "decode"), 64, False),   # a block queued behind
+    (("decode",), 64, True),                        # nothing queued at all
+    (("decode",), 4, False),        # nothing left to decode: not a dry run
+])
+def test_a_decode_read_with_no_block_behind_it_counts_dry(kinds, budget, dry):
+    from gofr_tpu.tpu.utilization import engine_snapshot
+
+    eng = _stubbed_read(kinds, budget)
+    rec = eng.steps.records()[-1]
+    assert rec.phase == "decode" and rec.dry_sync is dry
+    left = kinds[1:]
+    assert rec.inflight == len(left)
+    assert rec.inflight_prefill == left.count("prefill")
+    assert (eng.decode_syncs_total, eng.dry_syncs_total) == (1, int(dry))
+    # /debug/steps: the record's split and flag, the phase's count
+    snap = eng.steps.snapshot()
+    shown = snap["recent"][0]
+    assert shown["inflight"] == len(left)
+    assert shown["inflight_decode"] == left.count("decode")
+    assert shown["inflight_prefill"] == left.count("prefill")
+    assert shown.get("dry_sync", False) is dry
+    assert snap["summary"]["decode"]["dry_syncs"] == int(dry)
+    # /debug/engine: what is in flight by kind, the total and the share
+    shown = engine_snapshot(eng)["engine"]
+    assert shown["inflight_dispatches"] == len(left)
+    assert shown["inflight_decode"] == left.count("decode")
+    assert shown["inflight_prefill"] == left.count("prefill")
+    assert shown["decode_syncs_total"] == 1
+    assert shown["dry_syncs_total"] == int(dry)
+    assert shown["dry_sync_share"] == float(dry)
+
+
+def test_a_prefill_read_is_never_a_dry_sync():
+    from gofr_tpu.tpu.utilization import engine_snapshot
+
+    eng = _stubbed_read(("prefill", "prefill"))
+    rec = eng.steps.records()[-1]
+    assert rec.phase == "prefill" and not rec.dry_sync
+    assert (rec.inflight, rec.inflight_prefill) == (1, 1)
+    assert eng.decode_syncs_total == 0
+    assert engine_snapshot(eng)["engine"]["dry_sync_share"] == 0.0
+
+
+def test_the_queued_blocks_gauge_is_registered_and_set():
+    """`app_tpu_decode_blocks_queued`: what each decode read found behind
+    it; 0 while slots decode is the dry sync."""
+    from gofr_tpu.config import MockConfig
+    from gofr_tpu.metrics import Manager
+    from gofr_tpu.tpu.device import TPUClient
+
+    metrics = Manager()
+    client = TPUClient(MockConfig({}))
+    client.use_metrics(metrics)
+    client.register_metrics()
+    assert metrics.get("app_tpu_decode_blocks_queued") is not None
+    for kinds, queued in ((("decode", "prefill", "decode", "decode"), 2),
+                          (("decode", "prefill"), 0)):
+        _stubbed_read(kinds, metrics=metrics)
+        assert f"app_tpu_decode_blocks_queued {queued}" in metrics.expose()
+
+
 # -- end-to-end: /debug/steps + exemplar drill through the example server ----
 EXAMPLES = os.path.join(os.path.dirname(__file__), "..", "examples")
 

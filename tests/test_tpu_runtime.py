@@ -358,6 +358,122 @@ def test_engine_pipelined_matches_synchronous():
     assert run(1, 1) == run(4, 3)
 
 
+# -- a prefill in flight is not a decode block (PR 30) ---------------------------
+STAGGERED_PROMPTS = [[1, 2, 3], [7, 8], [4, 5, 6, 9], [2, 2, 2]]
+
+
+def _staggered_run(block, depth):
+    """One request decodes; then THREE arrive in the same loop turn, so one
+    `_admit` dispatches three prefill programs behind the decode blocks in
+    flight. The late three are submitted from the loop thread itself, at
+    the top of the first turn after the first request's ninth token, so
+    the run is the same whatever the host's timing. Returns the greedy
+    tokens, the step records, the loop's events in order
+    (("admit",) | ("sync", kind read, kind at the head afterwards, the
+    read prefill's requests all hold a token)) and the engine's counters."""
+    from gofr_tpu.models.llama import LlamaConfig, llama_init
+    from gofr_tpu.tpu.paging import PagedLLMEngine
+
+    cfg = LlamaConfig.debug()
+    eng = PagedLLMEngine(llama_init(cfg, seed=0), cfg, n_slots=4,
+                         max_seq_len=64, prefill_buckets=(8,),
+                         decode_block_size=block, pipeline_depth=depth)
+    events, reqs = [], []
+    admit, sync = eng._admit, eng._sync_oldest
+
+    def admit_logged():
+        if len(reqs) == 1 and reqs[0].generated >= 9:
+            reqs.extend(eng.submit(p, max_new_tokens=9, temperature=0.0)
+                        for p in STAGGERED_PROMPTS[1:])
+        events.append(("admit",))
+        admit()
+
+    def sync_logged():
+        entry = eng._inflight[0]
+        sync()
+        head = eng._inflight[0][0] if eng._inflight else None
+        events.append(("sync", entry[0], head, entry[0] != "prefill" or all(
+            r.generated >= 1 for _, r in entry[2])))
+
+    eng._admit, eng._sync_oldest = admit_logged, sync_logged
+    reqs.append(eng.submit(STAGGERED_PROMPTS[0], max_new_tokens=40,
+                           temperature=0.0))
+    eng.start()
+    try:
+        first = reqs[0].result(timeout_s=120)
+        assert len(reqs) == 4, "the late three were never submitted"
+        tokens = [first] + [r.result(timeout_s=120) for r in reqs[1:]]
+    finally:
+        eng.stop()
+    return {"tokens": tokens, "records": eng.steps.records(recent=1 << 20),
+            "events": events, "decode_syncs": eng.decode_syncs_total,
+            "dry_syncs": eng.dry_syncs_total}
+
+
+@pytest.fixture(scope="module")
+def staggered():
+    runs = {}
+
+    def get(block, depth):
+        if (block, depth) not in runs:
+            runs[block, depth] = _staggered_run(block, depth)
+        return runs[block, depth]
+
+    return get
+
+
+@pytest.mark.parametrize("depth", [2, 4])
+def test_admissions_leave_a_decode_block_queued(staggered, depth):
+    """Rule 1: however many prefill entries one `_admit` put into the
+    deque, every step that closes with a slot decoding closes with a decode
+    block still in flight, and no decode block is read dry."""
+    run = staggered(4, depth)
+    records = run["records"]
+    # the scenario happened: one turn dispatched several prefill programs
+    # while a slot was decoding
+    assert any(r.dispatches.get("prefill", 0) >= 3 for r in records)
+    assert max(r.inflight_prefill for r in records) >= 2
+    busy = [r for r in records if r.active_slots > 0]
+    assert busy and all(r.inflight - r.inflight_prefill >= 1 for r in busy), \
+        [r.summary() for r in busy if r.inflight == r.inflight_prefill]
+    assert not any(r.dry_sync for r in records)
+    assert run["decode_syncs"] > 0 and run["dry_syncs"] == 0
+
+
+@pytest.mark.parametrize("depth", [2, 4])
+def test_staggered_admissions_match_the_synchronous_engine(staggered, depth):
+    """The same greedy tokens as the block 1 / depth 1 engine, which reads
+    every dispatch before it makes the next."""
+    assert staggered(4, depth)["tokens"] == staggered(1, 1)["tokens"]
+    assert [len(t) for t in staggered(1, 1)["tokens"]] == [40, 9, 9, 9]
+
+
+@pytest.mark.parametrize("depth", [2, 4])
+def test_a_prefill_is_read_in_the_turn_it_reaches_the_head(staggered, depth):
+    """Rule 2: when a sync leaves a prefill entry at the head of the
+    deque, the loop's next act is to read it (no admission, no turn of its
+    own), and the read emits the first token of every request it bound."""
+    events = staggered(4, depth)["events"]
+    followed = 0
+    for now, then in zip(events, events[1:]):
+        if now[0] == "sync" and now[2] == "prefill":
+            assert then[:2] == ("sync", "prefill"), (now, then)
+            followed += 1
+    assert followed >= 3            # the late three, at least
+    assert all(e[3] for e in events if e[0] == "sync")
+
+
+def test_a_synchronous_engine_reads_every_block_dry(staggered):
+    """What the counter counts: at depth 1 nothing is ever queued behind
+    the block being read, so every decode block read with a slot still
+    decoding is a dry sync."""
+    run = staggered(1, 1)
+    reads = [r for r in run["records"] if r.phase == "decode"]
+    assert reads and run["decode_syncs"] == len(reads)
+    assert run["dry_syncs"] == sum(r.dry_sync for r in reads) > 0
+    assert all(r.dry_sync == (r.active_slots > 0) for r in reads)
+
+
 @pytest.mark.slow  # tier-1 wall-clock budget; lighter in-lane representative kept
 def test_stream_ordering_with_cancels_mid_block():
     """Batched emission contract: with block-sized queue entries, pipelined
