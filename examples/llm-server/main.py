@@ -4,8 +4,9 @@ POST /generate {"prompt": "...", "max_tokens": 64, "temperature": 0.7,
 "stream": true} -> server-sent events, one JSON per token chunk, then a final
 {"done": true} summary. stream=false returns one JSON response.
 
-Model size comes from MODEL_PRESET (debug | llama1b | llama3-8b, and the
-nemotron_h family's nemotron-h-debug | nemotron-3-nano-30b-a3b-ep2). Weights
+Model size comes from MODEL_PRESET (debug | llama1b | llama3-8b, the
+nemotron_h family's nemotron-h-debug | nemotron-3-nano-30b-a3b-ep2, and the
+mla_moe family's mla-moe-debug | joyai-llm-flash-ep8). Weights
 boot from a real HF-layout safetensors checkpoint when WEIGHTS_PATH is set
 (models.weights.load_llama_safetensors — streaming, int8 quantize-on-load);
 otherwise random-initialised (no checkpoints ship in this environment) with
@@ -22,6 +23,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", ".."))
 from gofr_tpu import App, Stream  # noqa: E402
 from gofr_tpu.http.errors import InvalidParam, ServiceUnavailable  # noqa: E402
 from gofr_tpu.models.llama import LlamaConfig, llama_init  # noqa: E402
+from gofr_tpu.models.mla_moe import MlaMoeConfig, mla_moe_init  # noqa: E402
 from gofr_tpu.models.nemotron_h import (NemotronHConfig,  # noqa: E402
                                         nemotron_h_init)
 from gofr_tpu.models.tokenizer import (ByteTokenizer, DebugTokenizer,  # noqa: E402
@@ -41,7 +43,17 @@ PRESETS = {
     # one chip's share of NVIDIA-Nemotron-3-Nano-30B-A3B, as the benchmark
     # runs it (benchmark/configs/nemotron-3-nano-30b-a3b-ep2.json)
     "nemotron-3-nano-30b-a3b-ep2": NemotronHConfig.nano_30b_a3b_ep2,
+    # the mla_moe family: latent attention (one narrow plane a token in the
+    # page pool) and gated sparse experts
+    "mla-moe-debug": MlaMoeConfig.debug,
+    # one chip's share of JoyAI-LLM-Flash, as the benchmark runs it
+    # (benchmark/configs/joyai-llm-flash-ep8.json)
+    "joyai-llm-flash-ep8": MlaMoeConfig.joyai_llm_flash_ep8,
 }
+
+# the families that boot from seeded weights only: no checkpoint loader and
+# no int8 weight path yet
+SEEDED_ONLY = {NemotronHConfig: nemotron_h_init, MlaMoeConfig: mla_moe_init}
 
 
 def _load_tokenizer(path: str):
@@ -168,12 +180,12 @@ def build_engine(app: App,
     # preset before any bytes load; WEIGHT_DTYPE=int8 quantizes each leaf
     # on device as it streams in, so the float tree never materializes
     weights_path = app.config.get_or_default("WEIGHTS_PATH", "")
-    if isinstance(cfg, NemotronHConfig):
+    if type(cfg) in SEEDED_ONLY:
         if weights_path or weight_dtype:
-            raise ValueError("the nemotron_h family has no checkpoint "
-                             "loader and no int8 weight path yet: unset "
-                             "WEIGHTS_PATH and WEIGHT_DTYPE")
-        params = nemotron_h_init(cfg, seed=0)
+            raise ValueError(f"the {cfg.paged_model().family} family has no "
+                             f"checkpoint loader and no int8 weight path "
+                             f"yet: unset WEIGHTS_PATH and WEIGHT_DTYPE")
+        params = SEEDED_ONLY[type(cfg)](cfg, seed=0)
     elif weights_path:
         from gofr_tpu.models.weights import load_llama_safetensors
 

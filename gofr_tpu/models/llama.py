@@ -63,17 +63,22 @@ class LlamaConfig:
     def paged_model(self):
         """The paged engine's view of this family (models/protocol.py):
         the paged floating-point path, unchanged in arithmetic."""
-        from .protocol import PagedModel
+        from .protocol import PagedModel, kv_planes
+
+        def prefill(params, tokens, lengths, mesh=None):
+            last, k, v, rows = llama_prefill_paged(params, self, tokens,
+                                                   lengths, mesh)
+            return last, (k, v), rows
 
         return PagedModel(
             family="llama_like", program_tag="llama",
+            planes=kv_planes(self.n_kv_heads, self.head_dim),
             kv_layers=self.n_layers, state_shapes=lambda slots: (),
-            prefill=lambda params, tokens, lengths, mesh=None:
-            llama_prefill_paged(params, self, tokens, lengths, mesh),
-            decode=lambda params, tokens, positions, k_pool, v_pool, table,
-            state, tail, step, mesh=None: (*llama_decode_step_paged(
-                params, self, tokens, positions, k_pool, v_pool, table,
-                tail, step, mesh), state, None))
+            prefill=prefill,
+            decode=lambda params, tokens, positions, pools, table, state,
+            tail, step, mesh=None: (*llama_decode_step_paged(
+                params, self, tokens, positions, *pools, table, tail, step,
+                mesh), state, None))
 
     @classmethod
     def debug(cls) -> "LlamaConfig":

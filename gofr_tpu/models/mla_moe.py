@@ -1,0 +1,471 @@
+"""mla_moe family (DeepSeek-V3's form; JoyAI-LLM-Flash is the published
+model the benchmark runs): blocks `h = x + MLA(RMSNorm(x))`,
+`y = h + FFN(RMSNorm(h))`, the first `first_dense` blocks' FFN a dense
+SwiGLU and every later one sparse experts plus one shared expert; after
+the last block a final RMSNorm and an untied head.
+
+Latent attention (MLA), H heads: `c_q = RMSNorm(x W_qa)`;
+`[q_nope | q_rope]_h = c_q W_qb`; `[c_kv | k_r] = x W_kva`;
+`c_kv <- RMSNorm(c_kv)`; `q_rope, k_r <- RoPE` (interleaved pairs, k_r ONE
+vector shared by all heads); `[k_nope | v]_h = c_kv W_kvb`;
+`o_h = softmax((q_nope . k_nope + q_rope . k_r) / sqrt(nope + rope)) v_h`;
+out `concat_h(o_h) W_o`. No YaRN and no mscale (`rope_scaling: null`).
+
+What a token keeps is `c_kv` after its norm and `k_r` after its rotation:
+ONE plane of 1 x (kv_rank + rope_dim) a block in the engine's pages
+(models/protocol.py `planes`), 576 values against 32 heads of K (192) and
+V (128). The two phases compute the same attention in two forms:
+
+- `prefill`: the published, NON-absorbed form over the fresh window: K and
+  V of every head made from the window's latents, causal flash attention
+  with key width 192 and value width 128 (ops/flash_attention.py takes the
+  two widths), the window's `[c_kv | k_r]` out to the page writer.
+- `decode_step`: the absorbed form. W_kvb's key half is folded into the
+  query (`q'_h = q_nope,h W^K_h^T`, kv_rank wide), scores are
+  `q'_h . c_kv + q_rope,h . k_r`, the weighted sum is taken over `c_kv`
+  and only then put through `W^V_h`: ops/mla_read.py, one shared head read
+  once for all H queries. `W^K_h` and `W^V_h` are views of `W_kvb`
+  ([kv_rank, H, nope + v] sliced in the einsum), not second copies.
+
+Experts: `s = sigmoid(x W_r)` over all `n_experts` in float32; the k
+largest of `s + bias` are picked (the bias chooses and does not weigh; one
+routing group, no group limit); weights `s_picked / sum(s_picked) *
+routed_scale`; `y = sum_e w_e down_e(silu(gate_e x) * up_e x) + shared(x)`.
+The block is told which experts it holds (`experts_held`), as nemotron_h's
+is (models/nemotron_h.py: the same routing arithmetic, counters and
+kernel, ops/moe_experts.py in its gated form): what the others would add
+is left out. The dense block, the shared expert and the routers are XLA's.
+
+Weights: {"tok_emb" [V, D], "layers": [one dict a block], "final_norm"
+[D], "lm_head" [D, V]}; matrices [in, out] but the routed experts' three,
+"w1" (up), "wg" (gate), "w2" (down), each [held, F, D]; per-block leaves,
+the layer loop unrolled (block 0 differs in kind).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Any, Dict, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from .llama import _np_dtype, rms_norm
+from .nemotron_h import COUNTERS, FLOAT32_LEAVES, _head, route
+
+__all__ = ["MlaMoeConfig", "mla_moe_init", "prefill", "decode_step",
+           "COUNTERS", "FLOAT32_LEAVES"]
+
+
+@dataclasses.dataclass(frozen=True)
+class MlaMoeConfig:
+    vocab_size: int = 129280
+    dim: int = 2048
+    n_layers: int = 40
+    first_dense: int = 1                    # leading blocks with a dense FFN
+    n_heads: int = 32
+    q_rank: int = 1536
+    kv_rank: int = 512
+    nope_dim: int = 128
+    rope_dim: int = 64
+    v_dim: int = 128
+    dense_dim: int = 7168
+    n_experts: int = 256                    # the router's outputs
+    experts_held: Tuple[int, int] = (0, 256)    # the range this chip holds
+    experts_per_token: int = 8
+    expert_dim: int = 768
+    shared_dim: int = 768
+    routed_scale: float = 2.5
+    rope_theta: float = 32e6
+    max_seq_len: int = 131072
+    rms_eps: float = 1e-6
+    dtype: str = "bfloat16"
+    attn_impl: str = "xla"      # "xla" | "flash": the prefill window
+
+    def __post_init__(self):
+        lo, hi = self.experts_held
+        if not 0 <= lo < hi <= self.n_experts:
+            raise ValueError(f"experts_held {self.experts_held} is not a "
+                             f"range of the {self.n_experts} experts")
+        if not 0 <= self.first_dense <= self.n_layers:
+            raise ValueError("first_dense counts leading blocks")
+
+    @property
+    def kv_layers(self) -> int:
+        """Every block keeps a latent plane in pages."""
+        return self.n_layers
+
+    @property
+    def expert_layers(self) -> int:
+        return self.n_layers - self.first_dense
+
+    @property
+    def held(self) -> int:
+        return self.experts_held[1] - self.experts_held[0]
+
+    @property
+    def qk_dim(self) -> int:
+        return self.nope_dim + self.rope_dim
+
+    @property
+    def latent_dim(self) -> int:
+        """What a token keeps a block: its normed latent and its rotated
+        shared key."""
+        return self.kv_rank + self.rope_dim
+
+    @property
+    def ffn_dim(self) -> int:
+        """The widest activation a block makes (the capacity plan's
+        prefill temporaries): the dense FFN, or every head's q and k."""
+        return max(self.dense_dim if self.first_dense else 0,
+                   self.n_heads * self.qk_dim)
+
+    state_bytes_per_slot = 0    # a sequence's only cached state is pages
+
+    @classmethod
+    def debug(cls) -> "MlaMoeConfig":
+        """CI-sized: compiles in seconds on the CPU. Held: all 8 experts."""
+        return cls(vocab_size=512, dim=64, n_layers=3, n_heads=4, q_rank=48,
+                   kv_rank=32, nope_dim=16, rope_dim=8, v_dim=16,
+                   dense_dim=128, n_experts=8, experts_held=(0, 8),
+                   experts_per_token=2, expert_dim=32, shared_dim=32,
+                   rope_theta=10000.0, max_seq_len=256, dtype="float32")
+
+    @classmethod
+    def joyai_llm_flash_ep8(cls) -> "MlaMoeConfig":
+        """JoyAI-LLM-Flash at its published widths, cut to one v5e chip as
+        benchmark/configs/joyai-llm-flash-ep8.json states: eight chips
+        share each layer, this one holds experts 0-31 of 256 and an eighth
+        of the vocabulary; the dense block and 11 of the 39 expert
+        blocks."""
+        return cls(vocab_size=16160, n_layers=12, experts_held=(0, 32),
+                   max_seq_len=5120)
+
+    def matrix_params(self) -> Dict[str, int]:
+        """Matrix parameters of a block's attention, of the dense FFN, and
+        of an expert FFN as held and as a token meets it (router, shared
+        expert, its k picks)."""
+        D, H = self.dim, self.n_heads
+        per_expert = 3 * D * self.expert_dim
+        outside = D * self.n_experts + 3 * D * self.shared_dim
+        return {
+            "attention": D * self.q_rank + self.q_rank * H * self.qk_dim
+            + D * self.latent_dim
+            + self.kv_rank * H * (self.nope_dim + self.v_dim)
+            + H * self.v_dim * D,
+            "dense": 3 * D * self.dense_dim,
+            "experts_held": outside + self.held * per_expert,
+            "experts_met": outside + self.experts_per_token * per_expert
+            * self.held // self.n_experts,
+        }
+
+    def param_count(self) -> int:
+        """The parameters a TOKEN meets (the utilization ledger's 2 P flops
+        a token): attention, the dense FFN, the router, the shared expert
+        and the share of its k picks that falls on held experts."""
+        m = self.matrix_params()
+        return (self.n_layers * m["attention"]
+                + self.first_dense * m["dense"]
+                + self.expert_layers * m["experts_met"]
+                + self.dim * self.vocab_size)
+
+    def paged_model(self):
+        from .protocol import PagedModel, Plane
+
+        def paged_prefill(params, tokens, lengths, mesh=None):
+            last, latent = prefill(params, self, tokens, lengths)
+            return last, (latent,), ()
+
+        def paged_decode(params, tokens, positions, pools, table, state,
+                         tail, step, mesh=None):
+            logits, latent_tail, counters = decode_step(
+                params, self, tokens, positions, pools[0], table, tail[0],
+                step)
+            return logits, (latent_tail,), state, counters
+
+        return PagedModel(
+            family="mla_moe", program_tag="mla-moe",
+            planes=(Plane("latent", 1, self.latent_dim),),
+            kv_layers=self.n_layers, state_shapes=lambda slots: (),
+            prefill=paged_prefill, decode=paged_decode, counters=COUNTERS,
+            describe=lambda counts, steps: describe(self, counts, steps),
+            refuses=REFUSES)
+
+
+# what the family cannot do yet, refused by name at construction
+_BLOB = ("the page blob (tpu/kvtier.py PageBlob) ships K and V of one "
+         "shape: a one-plane latent page needs a blob version of its own")
+REFUSES = {
+    "prefix_cache": "a prefix hit prefills the prompt's tail against cached "
+                    "pages (llama_prefill_paged_prefix): no such prefill in "
+                    "MLA form yet",
+    "kv_host_tier": _BLOB,
+    "disagg": _BLOB,
+    "speculative_tokens": "the verify window attends gathered K and V "
+                          "pages; the checkpoint's own drafting block "
+                          "(num_nextn_predict_layers) is not loaded",
+    "chunk_prefill_tokens": "a chunk's temporaries are K and V a layer; "
+                            "the latent window has no chunk program",
+    "int8_weights": "no int8 weight path for this family",
+    "kv_dtype": "the int8 pools quantize K and V a head with a scale "
+                "each; the latent plane has no quantized read",
+    "mesh": "no exchange of the expert and vocabulary shares yet, and the "
+            "one latent head cannot split over tp",
+}
+
+
+def describe(cfg: MlaMoeConfig, counts: Dict[str, int], steps: int):
+    """`/debug/engine` "model": the experts held and how the routing of
+    `steps` decode steps fell, under nemotron_h's names."""
+    from .nemotron_h import routing_summary
+
+    out = {"experts_held": cfg.held, "experts_total": cfg.n_experts}
+    routing = routing_summary(counts, steps, cfg.expert_layers, cfg.held,
+                              cfg.experts_per_token)
+    if routing:
+        out["routing"] = routing
+    return out
+
+
+def layer_shapes(cfg: MlaMoeConfig, dense: bool) -> Dict[str, tuple]:
+    D, H = cfg.dim, cfg.n_heads
+    shapes = {"attn_norm": (D,), "wq_a": (D, cfg.q_rank),
+              "q_norm": (cfg.q_rank,), "wq_b": (cfg.q_rank, H * cfg.qk_dim),
+              "wkv_a": (D, cfg.latent_dim), "kv_norm": (cfg.kv_rank,),
+              "wkv_b": (cfg.kv_rank, H * (cfg.nope_dim + cfg.v_dim)),
+              "wo": (H * cfg.v_dim, D), "ffn_norm": (D,)}
+    if dense:
+        return {**shapes, "w_gate": (D, cfg.dense_dim),
+                "w_up": (D, cfg.dense_dim), "w_down": (cfg.dense_dim, D)}
+    expert = (cfg.held, cfg.expert_dim, D)
+    return {**shapes, "router": (D, cfg.n_experts),
+            "router_bias": (cfg.n_experts,), "w1": expert, "wg": expert,
+            "w2": expert, "shared_gate": (D, cfg.shared_dim),
+            "shared_up": (D, cfg.shared_dim),
+            "shared_down": (cfg.shared_dim, D)}
+
+
+def mla_moe_init(cfg: MlaMoeConfig, seed: int = 0) -> Dict[str, Any]:
+    """Random-init params, a jitted call a block."""
+    dt = _np_dtype(cfg.dtype)
+
+    def matrix(key, shape, fan_in):
+        return (jax.random.normal(key, shape, jnp.float32)
+                / math.sqrt(fan_in)).astype(dt)
+
+    def make(key, dense):
+        shapes = layer_shapes(cfg, dense)
+        keys = iter(jax.random.split(key, len(shapes)))
+        out = {}
+        for name, shape in shapes.items():
+            if name.endswith("norm"):
+                out[name] = jnp.ones(shape, dt)
+            elif name == "router_bias":
+                out[name] = jnp.zeros(shape, jnp.float32)
+            else:
+                # the experts' matrices are [held, out, in] (w2: [.., in,
+                # out]): fan-in is D for up and gate, F for down
+                fan_in = (shape[1] if name == "w2" else shape[-1]
+                          if len(shape) == 3 else shape[0])
+                out[name] = matrix(next(keys), shape, fan_in)
+        return out
+
+    make = jax.jit(make, static_argnums=1)
+    key = jax.random.PRNGKey(seed)
+    return {
+        "tok_emb": jax.jit(matrix, static_argnums=(1, 2))(
+            jax.random.fold_in(key, 1), (cfg.vocab_size, cfg.dim), cfg.dim),
+        "layers": [make(jax.random.fold_in(key, 16 + i), i < cfg.first_dense)
+                   for i in range(cfg.n_layers)],
+        "final_norm": jnp.ones((cfg.dim,), dt),
+        "lm_head": jax.jit(matrix, static_argnums=(1, 2))(
+            jax.random.fold_in(key, 2), (cfg.dim, cfg.vocab_size), cfg.dim),
+    }
+
+
+# -- attention ----------------------------------------------------------------
+# (block_q, block_kv) of the prefill's flash kernel, clamped to the window:
+# this family's windows are thousands of tokens and every head has K and V of
+# its own, so the kernel's time is its blocks' MXU passes. On a v5e at 4,096
+# tokens, 32 heads, widths 192 / 128: (128, 128) 7.3 ms a block of the model,
+# (512, 128) 4.0, (512, 256) 2.8, (512, 512) 2.05, (1024, 256) 2.8 (PERF.md
+# section 6, PR 31). The kernel's own default stays what the short windows of
+# the other families were measured with.
+FLASH_BLOCKS = (512, 512)
+
+
+def rope_pairs(x, positions, theta: float):
+    """RoPE over interleaved pairs: (x[2i], x[2i + 1]) turns by
+    position * theta^(-2i / d). x [..., d]; positions broadcast against
+    x's leading dims. (The published code permutes q and k alike to the
+    half-split order first: every q . k is the same.)"""
+    half = x.shape[-1] // 2
+    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    angles = positions.astype(jnp.float32)[..., None] * inv_freq
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    pairs = x.astype(jnp.float32).reshape(*x.shape[:-1], half, 2)
+    even, odd = pairs[..., 0], pairs[..., 1]
+    return jnp.stack([even * cos - odd * sin, even * sin + odd * cos],
+                     axis=-1).reshape(x.shape).astype(x.dtype)
+
+
+def _queries(x, w, positions, cfg: MlaMoeConfig):
+    """(q_nope [..., H, nope], q_rope [..., H, rope] rotated); positions
+    shaped as x's leading dims."""
+    c_q = rms_norm(x @ w["wq_a"], w["q_norm"], cfg.rms_eps)
+    q = (c_q @ w["wq_b"]).reshape(*x.shape[:-1], cfg.n_heads, cfg.qk_dim)
+    q_nope, q_rope = q[..., :cfg.nope_dim], q[..., cfg.nope_dim:]
+    return q_nope, rope_pairs(q_rope, positions[..., None], cfg.rope_theta)
+
+
+def _latent(x, w, positions, cfg: MlaMoeConfig):
+    """[..., kv_rank + rope]: what a token keeps, c_kv normed | k_r
+    rotated."""
+    kv = x @ w["wkv_a"]
+    c_kv = rms_norm(kv[..., :cfg.kv_rank], w["kv_norm"], cfg.rms_eps)
+    k_r = rope_pairs(kv[..., cfg.kv_rank:], positions, cfg.rope_theta)
+    return jnp.concatenate([c_kv, k_r], axis=-1)
+
+
+def _kv_b(w, cfg: MlaMoeConfig):
+    """W_kvb as [kv_rank, H, nope + v]: its key half W^K and its value
+    half W^V are slices of this view."""
+    return w["wkv_b"].reshape(cfg.kv_rank, cfg.n_heads,
+                              cfg.nope_dim + cfg.v_dim)
+
+
+def attention_prefill(x, w, cfg: MlaMoeConfig):
+    """x [K, T, D] (normed): the published form over the fresh window,
+    causal (the padding is on the right, so no real token sees it).
+    Returns (out [K, T, D], latent [K, 1, w, T]: the layout the page
+    writer takes)."""
+    K, T, _ = x.shape
+    H = cfg.n_heads
+    positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (K, T))
+    q_nope, q_rope = _queries(x, w, positions, cfg)
+    latent = _latent(x, w, positions, cfg)                   # [K, T, w]
+    kv = jnp.einsum("ktr,rhn->kthn", latent[..., :cfg.kv_rank],
+                    _kv_b(w, cfg)).astype(x.dtype)
+    k_r = jnp.broadcast_to(latent[:, :, None, cfg.kv_rank:],
+                           (K, T, H, cfg.rope_dim))
+    q = jnp.concatenate([q_nope, q_rope], axis=-1)           # [K,T,H,192]
+    k = jnp.concatenate([kv[..., :cfg.nope_dim], k_r], axis=-1)
+    v = kv[..., cfg.nope_dim:]                               # [K,T,H,128]
+    if cfg.attn_impl == "flash":
+        from ..ops.flash_attention import flash_attention
+
+        attn = flash_attention(q, k, v, True, *FLASH_BLOCKS)
+    else:
+        from ..ops.flash_attention import attention_reference
+
+        attn = attention_reference(q, k, v, causal=True)
+    out = attn.reshape(K, T, H * cfg.v_dim) @ w["wo"]
+    return out, latent.transpose(0, 2, 1)[:, None]
+
+
+def attention_decode(x, w, positions, pool, table, lengths, tail, tail_lens,
+                     layer: int, cfg: MlaMoeConfig):
+    """x [B, D] (normed): the absorbed form. The token's latent goes into
+    the decode block's tail as token tail_lens[b] - 1; the read attends
+    lengths[b] tokens in pages and tail_lens[b] in the tail.
+    Returns (out [B, D], tail)."""
+    from ..ops.mla_read import mla_read
+
+    q_nope, q_rope = _queries(x, w, positions, cfg)
+    new = _latent(x, w, positions, cfg)[:, None]             # [B, 1, w]
+    kv_b = _kv_b(w, cfg)
+    folded = jnp.einsum("bhn,rhn->bhr", q_nope, kv_b[..., :cfg.nope_dim]
+                        ).astype(x.dtype)                    # q' [B, H, r]
+    attended, tail = mla_read(
+        jnp.concatenate([folded, q_rope], axis=-1), new, pool, tail, table,
+        lengths, tail_lens, value_width=cfg.kv_rank,
+        scale=1.0 / math.sqrt(cfg.qk_dim), layer=layer)
+    heads = jnp.einsum("bhr,rhv->bhv", attended, kv_b[..., cfg.nope_dim:]
+                       ).astype(x.dtype)
+    return heads.reshape(x.shape[0], -1) @ w["wo"], tail
+
+
+# -- feed-forward -------------------------------------------------------------
+def _swiglu(x, gate, up, down):
+    return (jax.nn.silu(x @ gate) * (x @ up)) @ down
+
+
+def ffn_prefill(x, w, real, cfg: MlaMoeConfig):
+    """x [K, T, D] (normed); real [K, T] marks tokens that are not
+    padding. The dense FFN, or the held experts by a grouped product over
+    the (token, pick) pairs sorted by expert plus the shared expert."""
+    if "router" not in w:
+        return _swiglu(x, w["w_gate"], w["w_up"], w["w_down"])
+    from ..ops.moe_experts import prefill_experts
+
+    K, T, D = x.shape
+    flat = x.reshape(K * T, D)
+    picks, weights = route(flat, w, cfg)
+    weights = jnp.where(real.reshape(K * T, 1), weights, 0.0)
+    routed = prefill_experts(flat, w["w1"], w["w2"], picks, weights,
+                             cfg.experts_held[0], tm=min(128, max(8, K * T)),
+                             wg=w["wg"])
+    shared = _swiglu(flat, w["shared_gate"], w["shared_up"], w["shared_down"])
+    return (routed.astype(x.dtype) + shared).reshape(K, T, D)
+
+
+def ffn_decode(x, w, live, cfg: MlaMoeConfig):
+    """x [B, D] (normed); live [B]. Returns (out [B, D], counters [3]
+    int32 of COUNTERS less `rows`, zeros for the dense block)."""
+    if "router" not in w:
+        return (_swiglu(x, w["w_gate"], w["w_up"], w["w_down"]),
+                jnp.zeros((3,), jnp.int32))
+    from ..ops.moe_experts import decode_experts
+    from .nemotron_h import combine_held
+
+    combine, counted = combine_held(x, w, live, cfg)
+    routed = decode_experts(x, w["w1"], w["w2"], combine, wg=w["wg"])
+    shared = _swiglu(x, w["shared_gate"], w["shared_up"], w["shared_down"])
+    return routed.astype(x.dtype) + shared, counted
+
+
+def prefill(params, cfg: MlaMoeConfig, tokens, lengths):
+    """tokens [K, T] right-padded; lengths [K]. Returns (last logits
+    [K, V] float32, latent [n_layers, K, 1, w, T])."""
+    K, T = tokens.shape
+    real = jnp.arange(T)[None, :] < lengths[:, None]
+    x = params["tok_emb"][tokens]
+    latents = []
+    for w in params["layers"]:
+        out, latent = attention_prefill(
+            rms_norm(x, w["attn_norm"], cfg.rms_eps), w, cfg)
+        latents.append(latent)
+        x = x + out
+        x = x + ffn_prefill(rms_norm(x, w["ffn_norm"], cfg.rms_eps), w, real,
+                            cfg)
+    last = x[jnp.arange(K), lengths - 1]
+    return _head(last, params, cfg), jnp.stack(latents)
+
+
+def decode_step(params, cfg: MlaMoeConfig, tokens, positions, pool, table,
+                tail, step):
+    """One token a row, step `step` of a decode block. tokens, positions
+    [B]; pool [n_layers, P, 1, w, ps] as the block found it, read only;
+    table [B, NP] (a row that starts at page 0 holds no request); tail the
+    block's latent tail (models/protocol.py). Returns (logits [B, V]
+    float32, tail, counters [len(COUNTERS)] int32)."""
+    from ..ops.paged_attention import holds_request
+    from .llama import _attended_in_block
+
+    live = holds_request(table)
+    lengths, tail_lens = _attended_in_block(table, positions, step)
+    x = params["tok_emb"][tokens]
+    counted = jnp.zeros((3,), jnp.int32)
+    for layer, w in enumerate(params["layers"]):
+        out, tail = attention_decode(
+            rms_norm(x, w["attn_norm"], cfg.rms_eps), w, positions, pool,
+            table, lengths, tail, tail_lens, layer, cfg)
+        x = x + out
+        out, seen = ffn_decode(rms_norm(x, w["ffn_norm"], cfg.rms_eps), w,
+                               live, cfg)
+        counted = counted + seen
+        x = x + out
+    counters = jnp.concatenate([jnp.sum(live, dtype=jnp.int32)[None],
+                                counted])
+    return _head(x, params, cfg), tail, counters

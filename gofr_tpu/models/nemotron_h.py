@@ -189,18 +189,22 @@ class NemotronHConfig:
                 + self.dim * self.vocab_size)
 
     def paged_model(self):
-        from .protocol import PagedModel
+        from .protocol import PagedModel, kv_planes
+
+        def paged_prefill(params, tokens, lengths, mesh=None):
+            last, k, v, rows = prefill(params, self, tokens, lengths)
+            return last, (k, v), rows
 
         return PagedModel(
             family="nemotron_h", program_tag="nemotron-h",
+            planes=kv_planes(self.n_kv_heads, self.head_dim),
             kv_layers=self.kv_layers,
             state_shapes=lambda slots: state_shapes(self, slots),
-            prefill=lambda params, tokens, lengths, mesh=None: prefill(
-                params, self, tokens, lengths),
-            decode=lambda params, tokens, positions, k_pool, v_pool, table,
-            state, tail, step, mesh=None: decode_step(
-                params, self, tokens, positions, k_pool, v_pool, table,
-                state, tail, step),
+            prefill=paged_prefill,
+            decode=lambda params, tokens, positions, pools, table, state,
+            tail, step, mesh=None: decode_step(
+                params, self, tokens, positions, *pools, table, state, tail,
+                step),
             counters=COUNTERS,
             describe=lambda counts, steps: describe(self, counts, steps),
             refuses=REFUSES)
@@ -230,11 +234,23 @@ def describe(cfg: NemotronHConfig, counts: Dict[str, int], steps: int):
     expert block and step."""
     out = {"state_bytes_per_slot": cfg.state_bytes_per_slot,
            "experts_held": cfg.held, "experts_total": cfg.n_experts}
-    layer_steps = steps * cfg.expert_layers
+    routing = routing_summary(counts, steps, cfg.expert_layers, cfg.held,
+                              cfg.experts_per_token)
+    if routing:
+        out["routing"] = routing
+    return out
+
+
+def routing_summary(counts: Dict[str, int], steps: int, expert_layers: int,
+                    held: int, k: int):
+    """What COUNTERS' sums over `steps` decode steps say of the routing,
+    an expert block and step (models/mla_moe.py counts the same); None
+    before any live step."""
+    layer_steps = steps * expert_layers
     if not layer_steps or not counts["rows"]:
-        return out
-    mean = counts["held_picks"] / (layer_steps * cfg.held)
-    out["routing"] = {
+        return None
+    mean = counts["held_picks"] / (layer_steps * held)
+    return {
         "rows_per_step": counts["rows"] / steps,
         "tokens_per_held_expert_mean": mean,
         "tokens_per_held_expert_max_over_mean": (
@@ -243,8 +259,7 @@ def describe(cfg: NemotronHConfig, counts: Dict[str, int], steps: int):
         "experts_touched_per_layer_step":
             counts["experts_touched"] / layer_steps,
         "held_pick_share": counts["held_picks"] / (
-            counts["rows"] * cfg.experts_per_token * cfg.expert_layers)}
-    return out
+            counts["rows"] * k * expert_layers)}
 
 
 def layer_shapes(cfg: NemotronHConfig, kind: str) -> Dict[str, tuple]:
@@ -445,7 +460,7 @@ def mamba_decode(u, w, state, tail, layer: int, live, cfg: NemotronHConfig):
     return out, state, tail
 
 
-def route(x, w, cfg: NemotronHConfig):
+def route(x, w, cfg):
     """(picks [..., k] int32 over ALL experts, weights [..., k] float32):
     sigmoid scores in float32 at full matmul precision (as the published
     code), the k largest of score + bias picked, weighted by their scores
@@ -482,11 +497,12 @@ def experts_prefill(x, w, real, cfg: NemotronHConfig):
     return (routed.astype(x.dtype) + _shared_expert(flat, w)).reshape(K, T, D)
 
 
-def experts_decode(x, w, live, cfg: NemotronHConfig):
-    """x [B, D] (normed); live [B]. Returns (out [B, D], counters [4]
-    int32 of COUNTERS less `rows`)."""
-    from ..ops.moe_experts import decode_experts
-
+def combine_held(x, w, live, cfg):
+    """A decode step's routing over the experts held: (combine [B, held]
+    float32, zero where a row did not pick the expert and for every pick
+    of a row that holds no request; counters [3] int32 of COUNTERS less
+    `rows`). `cfg` any config with the router's fields (models/mla_moe.py
+    routes the same way)."""
     lo, hi = cfg.experts_held
     picks, weights = route(x, w, cfg)
     mine = (picks >= lo) & (picks < hi) & live[:, None]           # [B, k]
@@ -495,8 +511,16 @@ def experts_decode(x, w, live, cfg: NemotronHConfig):
         rows, jnp.where(mine, picks - lo, cfg.held)].set(
             jnp.where(mine, weights, 0.0))[:, :cfg.held]
     tokens = jnp.sum(combine != 0.0, axis=0)                      # an expert
-    counted = jnp.stack([jnp.sum(mine), jnp.sum(tokens > 0),
-                         jnp.max(tokens)]).astype(jnp.int32)
+    return combine, jnp.stack([jnp.sum(mine), jnp.sum(tokens > 0),
+                               jnp.max(tokens)]).astype(jnp.int32)
+
+
+def experts_decode(x, w, live, cfg: NemotronHConfig):
+    """x [B, D] (normed); live [B]. Returns (out [B, D], counters [3]
+    int32 of COUNTERS less `rows`)."""
+    from ..ops.moe_experts import decode_experts
+
+    combine, counted = combine_held(x, w, live, cfg)
     routed = decode_experts(x, w["w1"], w["w2"], combine)
     return routed.astype(x.dtype) + _shared_expert(x, w), counted
 

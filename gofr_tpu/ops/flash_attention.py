@@ -29,6 +29,10 @@ Design notes (pallas_guide.md):
     no dynamic shapes.
   - bf16 operands into the MXU (preferred_element_type=f32 accumulation);
     only softmax statistics and the accumulator stay f32.
+  - two widths: q and k share one (dh, which sets the scale), v and the
+    output may have another (dv). Latent attention's published form has
+    keys of 192 (128 + a rotated 64) and values of 128; padding v to 192
+    would cost half again of the P V product and of v's bytes.
 
 Training uses flash_attention (custom_vjp): the backward pass recomputes
 standard attention under jax.vjp — residuals are just (q, k, v), so the
@@ -67,7 +71,7 @@ def _kernel_resident(q_ref, k_ref, v_ref, o_ref, *, causal: bool,
     from jax.experimental import pallas as pl
 
     block_q = q_ref.shape[2]
-    dh = q_ref.shape[3]
+    dv = v_ref.shape[3]
     i = pl.program_id(2)
     q = q_ref[0, 0]                                        # [bq, dh], model dtype
     q_pos = i * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_kv), 0)
@@ -101,7 +105,7 @@ def _kernel_resident(q_ref, k_ref, v_ref, o_ref, *, causal: bool,
 
     m0 = jnp.full((block_q, 1), DEFAULT_MASK_VALUE, jnp.float32)
     l0 = jnp.zeros((block_q, 1), jnp.float32)
-    acc0 = jnp.zeros((block_q, dh), jnp.float32)
+    acc0 = jnp.zeros((block_q, dv), jnp.float32)
     _, l, acc = jax.lax.fori_loop(0, hi, body, (m0, l0, acc0))
     o_ref[0, 0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
@@ -161,12 +165,13 @@ def _kernel_streaming(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 def _flash_bhtd(q, k, v, *, causal: bool, block_q: int, block_kv: int,
                 interpret: Optional[bool]):
-    """Core call on [B, H, T, dh] q and [B, Hkv, S, dh] k/v layouts."""
+    """Core call on [B, H, T, dh] q, [B, Hkv, S, dh] k and [B, Hkv, S, dv]
+    v layouts."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, T, dh = q.shape
-    _, Hkv, S, _ = k.shape
+    _, Hkv, S, dv = v.shape
     G = H // Hkv
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -180,7 +185,7 @@ def _flash_bhtd(q, k, v, *, causal: bool, block_q: int, block_kv: int,
         k = jnp.pad(k, ((0, 0), (0, 0), (0, Sp - S), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, 0), (0, Sp - S), (0, 0)))
 
-    resident = Sp * dh * q.dtype.itemsize * 2 <= VMEM_KV_BUDGET_BYTES
+    resident = Sp * (dh + dv) * q.dtype.itemsize <= VMEM_KV_BUDGET_BYTES
     if resident:
         kernel = functools.partial(
             _kernel_resident, causal=causal, kv_len=S, block_kv=block_kv,
@@ -194,12 +199,12 @@ def _flash_bhtd(q, k, v, *, causal: bool, block_q: int, block_kv: int,
                                  memory_space=pltpu.VMEM),
                     pl.BlockSpec((1, 1, Sp, dh), lambda b, h, i: (b, h // G, 0, 0),
                                  memory_space=pltpu.VMEM),
-                    pl.BlockSpec((1, 1, Sp, dh), lambda b, h, i: (b, h // G, 0, 0),
+                    pl.BlockSpec((1, 1, Sp, dv), lambda b, h, i: (b, h // G, 0, 0),
                                  memory_space=pltpu.VMEM),
                 ],
-                out_specs=pl.BlockSpec((1, 1, block_q, dh), lambda b, h, i: (b, h, i, 0),
+                out_specs=pl.BlockSpec((1, 1, block_q, dv), lambda b, h, i: (b, h, i, 0),
                                        memory_space=pltpu.VMEM),
-                out_shape=jax.ShapeDtypeStruct((B, H, Tp, dh), q.dtype),
+                out_shape=jax.ShapeDtypeStruct((B, H, Tp, dv), q.dtype),
                 interpret=interpret,
             )(q, k, v)
         return out[:, :, :T, :]
@@ -216,16 +221,16 @@ def _flash_bhtd(q, k, v, *, causal: bool, block_q: int, block_kv: int,
                              memory_space=pltpu.VMEM),
                 pl.BlockSpec((1, 1, block_kv, dh), lambda b, h, i, j: (b, h // G, j, 0),
                              memory_space=pltpu.VMEM),
-                pl.BlockSpec((1, 1, block_kv, dh), lambda b, h, i, j: (b, h // G, j, 0),
+                pl.BlockSpec((1, 1, block_kv, dv), lambda b, h, i, j: (b, h // G, j, 0),
                              memory_space=pltpu.VMEM),
             ],
-            out_specs=pl.BlockSpec((1, 1, block_q, dh), lambda b, h, i, j: (b, h, i, 0),
+            out_specs=pl.BlockSpec((1, 1, block_q, dv), lambda b, h, i, j: (b, h, i, 0),
                                    memory_space=pltpu.VMEM),
-            out_shape=jax.ShapeDtypeStruct((B, H, Tp, dh), q.dtype),
+            out_shape=jax.ShapeDtypeStruct((B, H, Tp, dv), q.dtype),
             scratch_shapes=[
                 pltpu.VMEM((block_q, 1), jnp.float32),    # running max
                 pltpu.VMEM((block_q, 1), jnp.float32),    # running sum
-                pltpu.VMEM((block_q, dh), jnp.float32),   # output accumulator
+                pltpu.VMEM((block_q, dv), jnp.float32),   # output accumulator
             ],
             interpret=interpret,
         )(q, k, v)
@@ -234,7 +239,8 @@ def _flash_bhtd(q, k, v, *, causal: bool, block_q: int, block_kv: int,
 
 def attention_reference(q, k, v, *, causal: bool = True):
     """Unblocked GQA attention in f32 — the numerics oracle and the recompute
-    target for the backward pass. Layout [B, T, H, dh] / [B, S, Hkv, dh].
+    target for the backward pass. Layout [B, T, H, dh] / [B, S, Hkv, dh]
+    (v and the output [.., dv]).
     When T < S under causal, queries are the LAST T positions."""
     B, T, H, dh = q.shape
     S, Hkv = k.shape[1], k.shape[2]
@@ -246,15 +252,15 @@ def attention_reference(q, k, v, *, causal: bool = True):
         s = jnp.where(mask[None, None, None, :, :], s, DEFAULT_MASK_VALUE)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhgts,bshd->bthgd", p, v.astype(jnp.float32))
-    return out.reshape(B, T, H, dh).astype(q.dtype)
+    return out.reshape(B, T, H, v.shape[-1]).astype(q.dtype)
 
 
 def flash_attention(q, k, v, causal: bool = True, block_q: int = 128,
                     block_kv: int = 128, interpret: Optional[bool] = None,
                     *, mesh=None):
-    """Flash attention on [B, T, H, dh] q and [B, S, Hkv, dh] k/v (GQA folds
-    query head h onto kv head h // (H // Hkv)). Returns [B, T, H, dh] in
-    q.dtype.
+    """Flash attention on [B, T, H, dh] q, [B, S, Hkv, dh] k and
+    [B, S, Hkv, dv] v (GQA folds query head h onto kv head h // (H // Hkv)).
+    Returns [B, T, H, dv] in q.dtype.
 
     mesh: the serving mesh when q/k/v are sharded over its "tp" axis on
     their head dims (column-parallel wq/wk/wv); each shard then runs the

@@ -1,9 +1,12 @@
 """Sparse experts on the serving path: the matmuls over the experts HELD.
 
-An expert layer of the nemotron_h family routes every token over all E
-experts and this chip holds a range of them (an up and a down matrix an
-expert, not gated: down(relu(up x)^2)). What a chip
-computes is its own experts' part of each token's weighted sum.
+An expert layer routes every token over all E experts and this chip
+holds a range of them. What a chip computes is its own experts' part of
+each token's weighted sum. An expert comes in two forms, and which one is
+a property of the family's weights, not a setting: an up and a down matrix
+(nemotron_h: down(relu(up x)^2)), or with a gate matrix beside the up
+(mla_moe: down(silu(gate x) * up x), three matrices an expert). `wg=None`
+is the first; the gate is held as the up is, [held, F, D].
 
 The up matrices are held [held, F, D], out by in as the checkpoint stores a
 linear layer, and the kernel contracts the minor dims (x W1^T, the MXU's
@@ -18,6 +21,7 @@ block of `tm` rows by one expert's two matrices and adds the result,
 weighted a row, into that row block's output,
 
     out[rows[s]] (+)= w[wsel[s]] * W2[e[s]] relu(x[rows[s]] W1[e[s]])^2
+    (gated: ... W2[e[s]] (silu(x[rows[s]] Wg[e[s]]) * x[rows[s]] W1[e[s]]))
 
 with e, rows and wsel scalar-prefetched, so each step's blocks are fetched
 by index and a step whose expert is the one before it does not fetch the
@@ -36,7 +40,7 @@ caller onto the last live step's blocks, so they move nothing either.
   not every token through every expert (64/3 of the flops).
 
 Blocks are whole matrices (two of [F, D], 2 x 10 MB at 1856 x 2688 in
-bfloat16, double-buffered): the plainest form; tiling them is a tuning
+bfloat16, double-buffered; gated, three of 3.1 MB at 768 x 2048): the plainest form; tiling them is a tuning
 question (PERF.md section 7).
 
 `experts_reference` is the numerics oracle for both.
@@ -56,20 +60,32 @@ def relu2(x):
     return jnp.square(jax.nn.relu(x))
 
 
-def experts_reference(x, w1, w2, combine):
-    """x [T, D]; w1, w2 [held, F, D]; combine [T, held] float32 (zero
-    where a token did not pick the expert). Every token through every
-    expert: [T, D] float32."""
-    h = relu2(jnp.einsum("td,efd->etf", x, w1,
-                         preferred_element_type=jnp.float32))
+def _hidden(up, gate):
+    """An expert's hidden activation from its up product (and its gate
+    product, where the family's experts are gated), float32."""
+    return relu2(up) if gate is None else jax.nn.silu(gate) * up
+
+
+def experts_reference(x, w1, w2, combine, wg=None):
+    """x [T, D]; w1, w2[, wg] [held, F, D]; combine [T, held] float32
+    (zero where a token did not pick the expert). Every token through
+    every expert: [T, D] float32."""
+    def product(w):
+        return jnp.einsum("td,efd->etf", x, w,
+                          preferred_element_type=jnp.float32)
+
+    h = _hidden(product(w1), None if wg is None else product(wg))
     y = jnp.einsum("etf,efd->etd", h.astype(x.dtype), w2,
                    preferred_element_type=jnp.float32)
     return jnp.einsum("etd,te->td", y, combine)
 
 
-def _kernel(e_ref, rows_ref, wsel_ref, n_ref, x_ref, w1_ref, w2_ref, w_ref,
-            o_ref):
+def _kernel(e_ref, rows_ref, wsel_ref, n_ref, x_ref, w1_ref, *refs):
     from jax.experimental import pallas as pl
+
+    # refs: [the gate matrix,] the down matrix, the weights, the output
+    wg_ref = refs[0] if len(refs) == 4 else None
+    w2_ref, w_ref, o_ref = refs[-3:]
 
     s = pl.program_id(0)
     fresh = jnp.logical_or(
@@ -82,9 +98,14 @@ def _kernel(e_ref, rows_ref, wsel_ref, n_ref, x_ref, w1_ref, w2_ref, w_ref,
     @pl.when(s < n_ref[0])
     def _step():
         x = x_ref[...]
-        h = relu2(jax.lax.dot_general(
-            x, w1_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32))
+
+        def product(w_ref):
+            return jax.lax.dot_general(
+                x, w_ref[0], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+        h = _hidden(product(w1_ref),
+                    None if wg_ref is None else product(wg_ref))
         y = jnp.dot(h.astype(x.dtype), w2_ref[0],
                     preferred_element_type=jnp.float32) * w_ref[0]
 
@@ -98,7 +119,7 @@ def _kernel(e_ref, rows_ref, wsel_ref, n_ref, x_ref, w1_ref, w2_ref, w_ref,
 
 
 def expert_steps(x, w1, w2, weights, experts, rows, wsel, n_steps, tm: int,
-                 *, interpret=None):
+                 *, wg=None, interpret=None):
     """The kernel. x [R * tm, D]; weights [Wn, tm, 1] float32; experts,
     rows, wsel [S] int32 (row blocks in non-decreasing visiting order);
     n_steps int32 scalar. Returns [R * tm, D] float32; a row block that no
@@ -114,13 +135,14 @@ def expert_steps(x, w1, w2, weights, experts, rows, wsel, n_steps, tm: int,
     at = jnp.minimum(jnp.arange(S), jnp.maximum(n_steps - 1, 0))
     experts, rows, wsel = (a.astype(jnp.int32)[at]
                            for a in (experts, rows, wsel))
+    matrix = pl.BlockSpec((1, F, D), lambda s, e, r, w, n: (e[s], 0, 0))
+    matrices = [w1, w2] if wg is None else [w1, wg, w2]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,                   # experts, rows, wsel, n
         grid=(S,),
         in_specs=[
             pl.BlockSpec((tm, D), lambda s, e, r, w, n: (r[s], 0)),
-            pl.BlockSpec((1, F, D), lambda s, e, r, w, n: (e[s], 0, 0)),
-            pl.BlockSpec((1, F, D), lambda s, e, r, w, n: (e[s], 0, 0)),
+            *[matrix] * len(matrices),
             pl.BlockSpec((1, tm, 1), lambda s, e, r, w, n: (w[s], 0, 0))],
         out_specs=pl.BlockSpec((tm, D), lambda s, e, r, w, n: (r[s], 0)),
     )
@@ -133,10 +155,10 @@ def expert_steps(x, w1, w2, weights, experts, rows, wsel, n_steps, tm: int,
                 vmem_limit_bytes=VMEM_LIMIT),
             interpret=interpret,
         )(experts, rows, wsel, jnp.reshape(n_steps, (1,)).astype(jnp.int32),
-          x, w1, w2, weights)
+          x, *matrices, weights)
 
 
-def decode_experts(x, w1, w2, combine, *, interpret=None):
+def decode_experts(x, w1, w2, combine, *, wg=None, interpret=None):
     """One decode step. x [B, D]; combine [B, held] float32, zero for a
     pick that is not held and for every pick of a row that holds no
     request. Experts nobody picked are neither read nor computed."""
@@ -145,12 +167,12 @@ def decode_experts(x, w1, w2, combine, *, interpret=None):
     order = jnp.argsort(jnp.logical_not(touched), stable=True)
     zeros = jnp.zeros((held,), jnp.int32)
     return expert_steps(x, w1, w2, combine.T[:, :, None], order, zeros,
-                        order, jnp.sum(touched), x.shape[0],
+                        order, jnp.sum(touched), x.shape[0], wg=wg,
                         interpret=interpret)
 
 
 def prefill_experts(x, w1, w2, picks, pick_weights, lo: int, tm: int = 128,
-                    *, interpret=None):
+                    *, wg=None, interpret=None):
     """A prefill window. x [T, D]; picks [T, k] int32 expert ids over ALL
     the router's experts; pick_weights [T, k] float32, zero for a token
     that is padding; the held experts are [lo, lo + held). Returns
@@ -183,7 +205,7 @@ def prefill_experts(x, w1, w2, picks, pick_weights, lo: int, tm: int = 128,
     y_sorted = expert_steps(
         x_sorted, w1, w2, weight.reshape(n_blocks, tm, 1),
         jnp.minimum(block_expert, held - 1), steps, steps, jnp.sum(blocks),
-        tm, interpret=interpret)
+        tm, wg=wg, interpret=interpret)
     # back to tokens by gather: pair (t, j) reads its row, or nothing
     where = jnp.zeros((T * k,), jnp.int32).at[order].set(
         jnp.minimum(dest, n_blocks * tm - 1).astype(jnp.int32))

@@ -125,13 +125,16 @@ def paged_attention_reference(q, k_pool, v_pool, table, lengths,
 
 
 def _paged_kernel(layer_ref, table_ref, len_ref, *refs, scale: float,
-                  quantized: bool, tailed: bool):
+                  quantized: bool, tailed: bool, value_width=None):
     """One grid step = one row b: stream the row's live pages (ALL heads of
     a page at a time) through two VMEM buffers a pool and fold each into
     the online softmax, the dots batched over the KV heads. `tailed`, the
     row is in a decode block: its new k and v are put into its tail as
     token tail_len[b] - 1, the tail goes back where it came from, and its
     first tail_len[b] tokens are one more segment of the same softmax.
+    `value_width` (ops/mla_read.py), the page holds ONE plane and a
+    token's value is the first `value_width` of its key's dh values: one
+    pool, one tail, the output [Hkv, G, value_width].
 
     refs: [tail_len (SMEM, with the other scalars),] q, [the row's new k,
     v [Hkv, 1, dh'],] the n stacked pools left in HBM (k, v[, k_scale,
@@ -148,8 +151,9 @@ def _paged_kernel(layer_ref, table_ref, len_ref, *refs, scale: float,
     refs = list(refs)
     tail_len_ref = refs.pop(0) if tailed else None
     q_ref = refs.pop(0)
-    news = [refs.pop(0) for _ in range(2 if tailed else 0)]
-    n = 4 if quantized else 2
+    latent = value_width is not None
+    news = [refs.pop(0) for _ in range((1 if latent else 2) * tailed)]
+    n = 1 if latent else 4 if quantized else 2
     pools = [refs.pop(0) for _ in range(n)]
     tails = [refs.pop(0) for _ in news]
     o_ref = refs.pop(0)
@@ -160,7 +164,7 @@ def _paged_kernel(layer_ref, table_ref, len_ref, *refs, scale: float,
     tail_sems, put_sems = (refs.pop(0), refs.pop(0)) if tailed else (None,
                                                                     None)
     first_slot, = refs
-    k_buf, v_buf = bufs[:2]
+    k_buf, v_buf = bufs[0], None if latent else bufs[1]
     ks_buf, vs_buf = bufs[2:] if quantized else (None, None)
 
     b = pl.program_id(0)
@@ -168,6 +172,7 @@ def _paged_kernel(layer_ref, table_ref, len_ref, *refs, scale: float,
     layer = layer_ref[0]
     length = len_ref[b]
     n_kv, G, dh = q_ref.shape[1:]
+    dv = value_width or dh
     page_size = k_buf.shape[-1]
     n_pages = jnp.minimum((length + page_size - 1) // page_size,
                           table_ref.shape[1])
@@ -203,7 +208,7 @@ def _paged_kernel(layer_ref, table_ref, len_ref, *refs, scale: float,
     slot0 = first_slot[0]
     folded = (jnp.full((n_kv, G, 1), DEFAULT_MASK_VALUE, jnp.float32),
               jnp.zeros((n_kv, G, 1), jnp.float32),
-              jnp.zeros((n_kv, G, dh), jnp.float32))
+              jnp.zeros((n_kv, G, dv), jnp.float32))
 
     if tailed:
         # The tail folds FIRST, while the row's first page (started by the
@@ -260,7 +265,8 @@ def _paged_kernel(layer_ref, table_ref, len_ref, *refs, scale: float,
             # the tail is token-major a head ([Hkv, T, dh], dh on lanes,
             # less the lanes that pad a narrower head): its scores are the
             # plain q . k^T, batched over the KV heads
-            k, v = (buf[b % 2][:, :, :dh] for buf in tail_bufs)
+            k = tail_bufs[0][b % 2][:, :, :dh]
+            v = k[:, :, :dv] if latent else tail_bufs[1][b % 2][:, :, :dh]
             s = scale * jax.lax.dot_general(
                 q, k, (((2,), (2,)), ((0,), (0,))),
                 preferred_element_type=jnp.float32)
@@ -301,7 +307,7 @@ def _paged_kernel(layer_ref, table_ref, len_ref, *refs, scale: float,
             copy.wait()
 
         k = k_buf[slot]                                   # [Hkv, dh, ps]
-        v = v_buf[slot]
+        v = k[:, :dv] if latent else v_buf[slot]
         if quantized:
             k = k.astype(jnp.bfloat16)                    # in-VMEM upcast
         # every head's [G, dh] x [dh, ps], batched over the KV heads
@@ -409,9 +415,13 @@ def paged_attention_in_block(q, k, v, k_pool, v_pool, k_tail, v_tail, table,
                        interpret)
 
 
-def _paged_read(q, pools, table, lengths, block, layer, mesh, interpret):
-    """Both reads' one call. pools: (k, v[, k_scale, v_scale]); block: None
-    or (k, v, k_tail, v_tail, tail_lens) of `paged_attention_in_block`."""
+def _paged_read(q, pools, table, lengths, block, layer, mesh, interpret, *,
+                value_width=None, scale=None, scope: str = "paged_read"):
+    """The reads' one call. pools: (k, v[, k_scale, v_scale]); block: None
+    or (k, v, k_tail, v_tail, tail_lens) of `paged_attention_in_block`.
+    With `value_width` (ops/mla_read.py) the one pool of a one-plane page,
+    block (new, tail, tail_lens), the scores scaled by `scale` and the
+    kernel named `scope`."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -421,8 +431,9 @@ def _paged_read(q, pools, table, lengths, block, layer, mesh, interpret):
     pools = [_stacked(pool, layer) for pool in pools]
     news, tails, tail_lens = [], [], []
     if tailed:
-        news, tail_lens = list(block[:2]), [block[4]]
-        tails = [_stacked(tail, layer) for tail in block[2:4]]
+        m = len(pools)
+        news, tail_lens = list(block[:m]), [block[2 * m]]
+        tails = [_stacked(tail, layer) for tail in block[m:2 * m]]
 
     if _tp(mesh):
         from jax.sharding import PartitionSpec
@@ -450,7 +461,7 @@ def _paged_read(q, pools, table, lengths, block, layer, mesh, interpret):
 
     B, H, dh = q.shape
     Hkv = pools[0].shape[2]
-    G = H // Hkv
+    G, dv = H // Hkv, value_width or dh
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     # the row's new k, v as the tail holds a token: [Hkv, 1, dh'] a row
@@ -459,8 +470,10 @@ def _paged_read(q, pools, table, lengths, block, layer, mesh, interpret):
             for new, tail in zip(news, tails)]
 
     scalars = [layer_arr, table, lengths] + tail_lens
-    kernel = functools.partial(_paged_kernel, scale=1.0 / math.sqrt(dh),
-                               quantized=quantized, tailed=tailed)
+    kernel = functools.partial(_paged_kernel,
+                               scale=scale or 1.0 / math.sqrt(dh),
+                               quantized=quantized, tailed=tailed,
+                               value_width=value_width)
 
     def row_index(b, *scalars):
         return (b, 0, 0, 0)
@@ -469,11 +482,12 @@ def _paged_read(q, pools, table, lengths, block, layer, mesh, interpret):
     # [layer, page] and [layer, row] (a tail is small enough that the
     # compiler may keep all of it in VMEM)
     whole = pl.BlockSpec(memory_space=pl.ANY)
-    out_row = pl.BlockSpec((1, Hkv, G, dh), row_index)
+    q_row = pl.BlockSpec((1, Hkv, G, dh), row_index)
+    out_row = pl.BlockSpec((1, Hkv, G, dv), row_index)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),  # layer, table, lengths[, tail]
         grid=(B,),
-        in_specs=[out_row]
+        in_specs=[q_row]
         + [pl.BlockSpec((1,) + new.shape[1:], row_index) for new in news]
         + [whole] * (len(pools) + len(tails)),
         out_specs=[out_row] + [whole] * len(tails),
@@ -481,16 +495,16 @@ def _paged_read(q, pools, table, lengths, block, layer, mesh, interpret):
                         for pool in pools]
         + [pltpu.VMEM((2,) + x.shape[2:], x.dtype) for x in tails]
         + [pltpu.SemaphoreType.DMA((len(pools), 2))]
-        + [pltpu.SemaphoreType.DMA((2, 2)),
-           pltpu.SemaphoreType.DMA((2,))] * tailed
+        + [pltpu.SemaphoreType.DMA((len(tails), 2)),
+           pltpu.SemaphoreType.DMA((len(tails),))] * tailed
         + [pltpu.SMEM((1,), jnp.int32)],
     )
     first_tail = len(scalars) + 1 + len(news) + len(pools)
-    with kernel_scope("paged_read"):
+    with kernel_scope(scope):
         attended, *tails = pl.pallas_call(
             kernel,
             grid_spec=grid_spec,
-            out_shape=[jax.ShapeDtypeStruct((B, Hkv, G, dh), q.dtype)]
+            out_shape=[jax.ShapeDtypeStruct((B, Hkv, G, dv), q.dtype)]
             + [jax.ShapeDtypeStruct(x.shape, x.dtype) for x in tails],
             # the tails are updated where they lie
             input_output_aliases={first_tail + i: 1 + i
@@ -500,7 +514,7 @@ def _paged_read(q, pools, table, lengths, block, layer, mesh, interpret):
                 dimension_semantics=("arbitrary",)),
             interpret=interpret,
         )(*scalars, q.reshape(B, Hkv, G, dh), *news, *pools, *tails)
-    attended = attended.reshape(B, H, dh)
+    attended = attended.reshape(B, H, dv)
     return attended if not tailed else (attended,
                                         *_unstack(tails, stacked))
 
@@ -670,21 +684,28 @@ def holds_request(table):
     return table[:, 0] > 0
 
 
-def block_tail(k_pool, rows: int, block: int, mesh=None):
-    """(k_tail, v_tail): zeros [L, rows, Hkv, T, dh'] in the pool's dtype
-    for a stacked pool [L, P, Hkv, dh, ps]; under a tp mesh sharded on the
-    heads as the pools are. T >= block and dh' >= dh are whole tiles (16
-    tokens, 128 lanes): the kernels copy a row's [Hkv, T, dh'] out of the
+def plane_tail(pool, rows: int, block: int, mesh=None):
+    """One plane's tail: zeros [L, rows, heads, T, w'] in the pool's dtype
+    for a stacked pool [L, P, heads, w, ps]; under a tp mesh sharded on
+    the heads as the pools are. T >= block and w' >= w are whole tiles (16
+    tokens, 128 lanes): the kernels copy a row's [heads, T, w'] out of the
     stack, and a copy's window has to be whole tiles. The padding is never
     attended and never placed."""
-    L, _, Hkv, dh, _ = k_pool.shape
-    tail = jnp.zeros((L, rows, Hkv, -(-block // 16) * 16,
-                      -(-dh // 128) * 128), k_pool.dtype)
+    L, _, heads, width, _ = pool.shape
+    tail = jnp.zeros((L, rows, heads, -(-block // 16) * 16,
+                      -(-width // 128) * 128), pool.dtype)
     if _tp(mesh):
         from jax.sharding import NamedSharding
 
         tail = jax.lax.with_sharding_constraint(
             tail, NamedSharding(mesh, _heads_spec(5, 2)))
+    return tail
+
+
+def block_tail(k_pool, rows: int, block: int, mesh=None):
+    """(k_tail, v_tail) of pools whose planes are K and V: `plane_tail`,
+    twice."""
+    tail = plane_tail(k_pool, rows, block, mesh)
     return tail, tail
 
 
@@ -705,10 +726,10 @@ def tail_put(k_tail, v_tail, k, v, layer, step):
     return put(k_tail, k), put(v_tail, v)
 
 
-def _flush_kernel(page_ref, row_ref, lane_ref, count_ref, k_tail, v_tail,
-                  k_page, v_page, k_out, v_out):
+def _flush_kernel(page_ref, row_ref, lane_ref, count_ref, *refs):
     """One grid step = one (layer, item): put the tokens of one row's tail
-    that land in one page at their lanes and write the page back. An item
+    that land in one page at their lanes and write the page back, plane by
+    plane (refs: the planes' tails, their pages, the pages out). An item
     (`_flush_items`) is a (row, page its block reaches) that has tokens to
     place; its scalars are the page id, the row, the lane of the tail's
     token 0 in this page's frame (below 0 in a page the block crossed
@@ -723,20 +744,21 @@ def _flush_kernel(page_ref, row_ref, lane_ref, count_ref, k_tail, v_tail,
     `_write_kernel` does for one column."""
     from jax.experimental import pallas as pl
 
+    n = len(refs) // 3
+    tails, pages, outs = refs[:n], refs[n:2 * n], refs[2 * n:]
     item = pl.program_id(1)
     count, lane0 = count_ref[item], lane_ref[item]
-    n_kv, T = k_tail.shape[1:3]
-    dh, ps = k_out.shape[-2:]
+    T, ps = tails[0].shape[2], outs[0].shape[-1]
 
     @pl.when(count > 0)
     def _place():
         token = jax.lax.broadcasted_iota(jnp.int32, (T, ps), 0)
         lane = jax.lax.broadcasted_iota(jnp.int32, (T, ps), 1)
         selection = jnp.logical_and(lane == lane0 + token, token < count)
-        lanes = jax.lax.broadcasted_iota(jnp.int32, (dh, ps), 1)
-        placed = jnp.logical_and(lanes >= lane0, lanes < lane0 + count)
-        for tail, page, out in ((k_tail, k_page, k_out),
-                                (v_tail, v_page, v_out)):
+        for tail, page, out in zip(tails, pages, outs):
+            n_kv, dh = out.shape[1:3]
+            lanes = jax.lax.broadcasted_iota(jnp.int32, (dh, ps), 1)
+            placed = jnp.logical_and(lanes >= lane0, lanes < lane0 + count)
             # bf16 products with 1.0 are exact as they are; f32 values need
             # the full-precision passes or the MXU rounds them to bf16
             precision = (jax.lax.Precision.HIGHEST
@@ -753,8 +775,8 @@ def _flush_kernel(page_ref, row_ref, lane_ref, count_ref, k_tail, v_tail,
     # page, which goes back as it came (never unwritten VMEM)
     @pl.when(jnp.logical_and(count == 0, item == 0))
     def _keep():
-        k_out[...] = k_page[...]
-        v_out[...] = v_page[...]
+        for page, out in zip(pages, outs):
+            out[...] = page[...]
 
 
 def _flush_items(table, starts, counts, ps: int, spans: int):
@@ -785,76 +807,86 @@ def _flush_items(table, starts, counts, ps: int, spans: int):
             jnp.where(step < n_items, of(counts), 0))
 
 
-def paged_flush_block(k_pool, v_pool, k_tail, v_tail, table, starts, counts,
-                      *, mesh=None, interpret=None):
-    """Put a decode block's tail into the pages, in place, every layer at
-    once: token i < counts[b] of row b's tail goes to absolute position
-    starts[b] + i, i.e. column (starts[b] + i) % ps of page
-    table[b, (starts[b] + i) // ps]. A row's page is read and written once
-    (once more for each page boundary its block crossed) whatever
+def flush_planes(pools, tails, table, starts, counts, *, mesh=None,
+                 interpret=None):
+    """Put a decode block's tail into the pages, in place, every layer and
+    every plane at once: token i < counts[b] of row b's tail goes to
+    absolute position starts[b] + i, i.e. column (starts[b] + i) % ps of
+    page table[b, (starts[b] + i) // ps]. A row's page is read and written
+    once (once more for each page boundary its block crossed) whatever
     counts[b] is; a row with counts[b] == 0 moves nothing.
 
-    k/v_pool: [L, P, Hkv, dh, ps]; k/v_tail: [L, B, Hkv, T, dh']
-    (`block_tail`); table: [B, NP]; starts, counts: [B] int32, counts <= T.
-    Returns (k_pool, v_pool). `interpret` as `paged_write_decode` has it:
-    off the TPU, None takes the plain scatter (`_flush_columns`)."""
+    pools: a [L, P, heads, w, ps] a plane; tails: a [L, B, heads, T, w']
+    a plane (`plane_tail`); table: [B, NP]; starts, counts: [B] int32,
+    counts <= T. Returns the pools, a tuple. `interpret` as
+    `paged_write_decode` has it: off the TPU, None takes the plain scatter
+    (`_flush_columns`)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    pools, tails = tuple(pools), tuple(tails)
+    n = len(pools)
     if interpret is None and jax.default_backend() != "tpu":
-        return _flush_columns(k_pool, v_pool, k_tail, v_tail, table, starts,
-                              counts)
+        return _flush_columns(pools, tails, table, starts, counts)
     if _tp(mesh):
         from jax.sharding import PartitionSpec
 
         rep = PartitionSpec()
         heads = _heads_spec(5, 2)
         return jax.shard_map(
-            functools.partial(paged_flush_block, interpret=interpret),
-            mesh=mesh, in_specs=(heads,) * 4 + (rep,) * 3,
-            out_specs=(heads, heads), check_vma=False)(
-                k_pool, v_pool, k_tail, v_tail, table, starts, counts)
+            lambda *a: flush_planes(a[:n], a[n:2 * n], *a[2 * n:],
+                                    interpret=interpret),
+            mesh=mesh, in_specs=(heads,) * (2 * n) + (rep,) * 3,
+            out_specs=(heads,) * n, check_vma=False)(
+                *pools, *tails, table, starts, counts)
 
-    L, _, Hkv, dh, ps = k_pool.shape
-    B, T = k_tail.shape[1], k_tail.shape[3]
+    L, ps = pools[0].shape[0], pools[0].shape[-1]
+    B, T = tails[0].shape[1], tails[0].shape[3]
     spans = (T + ps - 2) // ps + 1    # pages T tokens can reach: 2 at ps=128
 
-    def page_block():
+    def page_block(pool):
         return pl.BlockSpec(
-            (None, 1, Hkv, dh, ps),
+            (None, 1) + pool.shape[2:],
             lambda l, i, pages, rows, lanes, counts: (l, pages[i], 0, 0, 0))
 
-    def tail_block():
+    def tail_block(tail):
         return pl.BlockSpec(
-            (None, 1) + k_tail.shape[2:],
+            (None, 1) + tail.shape[2:],
             lambda l, i, pages, rows, lanes, counts: (l, rows[i], 0, 0, 0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,  # page ids, rows, first lanes, counts
         grid=(L, B * spans),
-        in_specs=[tail_block(), tail_block(), page_block(), page_block()],
-        out_specs=[page_block(), page_block()],
+        in_specs=[tail_block(tail) for tail in tails]
+        + [page_block(pool) for pool in pools],
+        out_specs=[page_block(pool) for pool in pools],
     )
     with kernel_scope("paged_write"):
         return tuple(pl.pallas_call(
             _flush_kernel,
             grid_spec=grid_spec,
-            out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)
-                       for x in (k_pool, v_pool)],
-            # operand order: 4 scalars, 2 tails, 2 pools -> pool i = out i
-            input_output_aliases={6: 0, 7: 1},
+            out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype) for x in pools],
+            # operand order: 4 scalars, n tails, n pools -> pool i = out i
+            input_output_aliases={4 + n + i: i for i in range(n)},
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",) * 2),
             interpret=bool(interpret),
-        )(*_flush_items(table, starts, counts, ps, spans), k_tail, v_tail,
-          k_pool, v_pool))
+        )(*_flush_items(table, starts, counts, ps, spans), *tails, *pools))
 
 
-def _flush_columns(k_pool, v_pool, k_tail, v_tail, table, starts, counts):
+def paged_flush_block(k_pool, v_pool, k_tail, v_tail, table, starts, counts,
+                      *, mesh=None, interpret=None):
+    """`flush_planes` of pools whose planes are K and V. Returns
+    (k_pool, v_pool)."""
+    return flush_planes((k_pool, v_pool), (k_tail, v_tail), table, starts,
+                        counts, mesh=mesh, interpret=interpret)
+
+
+def _flush_columns(pools, tails, table, starts, counts):
     """The flush as one plain scatter a pool: the kernel's reference, and
     what runs off the TPU. A token past its row's count is dropped."""
-    P, ps = k_pool.shape[1], k_pool.shape[-1]
-    T, dh = k_tail.shape[3], k_pool.shape[3]
+    P, ps = pools[0].shape[1], pools[0].shape[-1]
+    T = tails[0].shape[3]
     positions = starts[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
     slots = jnp.clip(positions // ps, 0, table.shape[1] - 1)
     pages = jnp.take_along_axis(table, slots, axis=1)         # [B, T]
@@ -862,8 +894,9 @@ def _flush_columns(k_pool, v_pool, k_tail, v_tail, table, starts, counts):
     pages = jnp.where(held, pages, P)                         # P: dropped
     return tuple(
         pool.at[:, pages, :, :, positions % ps].set(
-            jnp.transpose(tail[..., :dh], (1, 3, 0, 2, 4)), mode="drop")
-        for pool, tail in ((k_pool, k_tail), (v_pool, v_tail)))
+            jnp.transpose(tail[..., :pool.shape[3]], (1, 3, 0, 2, 4)),
+            mode="drop")
+        for pool, tail in zip(pools, tails))
 
 
 def _unstack(pools, stacked: bool):

@@ -56,12 +56,13 @@ class CapacityPlan:
 
 
 def kv_token_bytes(cfg, dtype: Optional[str] = None) -> int:
-    """HBM bytes one cached token occupies across BOTH (k, v) caches:
-    2 * kv_layers * n_kv_heads * head_dim * itemsize, over the blocks that
-    keep K and V (all of models/llama.py's, 6 in 52 of nemotron_h's). The
-    per-token unit the capacity plan and the utilization ledger's bandwidth
-    model share."""
-    return (2 * cfg.kv_layers * cfg.n_kv_heads * cfg.head_dim
+    """HBM bytes one cached token occupies across the planes of its
+    family's page (models/protocol.py `planes`): K and V, 2 * kv_layers *
+    n_kv_heads * head_dim * itemsize, over the blocks that keep them (all
+    of models/llama.py's, 6 in 52 of nemotron_h's); one latent plane of
+    576 a block for mla_moe. The per-token unit the capacity plan and the
+    utilization ledger's bandwidth model share."""
+    return (cfg.paged_model().token_values
             * _dtype_bytes(dtype or getattr(cfg, "kv_dtype", None)
                            or cfg.dtype))
 
@@ -88,15 +89,15 @@ def kv_scales_bytes(cfg, n_slots: int, seq_len: int) -> int:
 def prefill_temp_bytes(cfg, k_max: int, bucket_max: int) -> int:
     """Worst-case fused-admission temporaries for a [K, bucket] prefill.
 
-    Dominant terms: the tmp k/v caches (2 * [L, K, bucket, Hkv, dh]) the
-    prefill writes before splicing, plus per-layer activations (~4 live
+    Dominant terms: the window a plane ([L, K, heads, width, bucket]: K and
+    V, or one latent plane) the prefill writes before splicing, plus
+    per-layer activations (~4 live
     [K, bucket, max(D, F)] tensors inside the scanned layer body — XLA keeps
     a small constant number live, not n_layers). The lm_head buffer is gone:
     prefill projects only [K, D] last-position rows (llama_prefill_last).
     """
     dt = _dtype_bytes(cfg.dtype)
-    tmp_kv = 2 * (cfg.kv_layers * k_max * bucket_max * cfg.n_kv_heads
-                  * cfg.head_dim * dt)
+    tmp_kv = cfg.paged_model().token_values * k_max * bucket_max * dt
     acts = 4 * k_max * bucket_max * max(cfg.dim, cfg.ffn_dim) * dt
     return tmp_kv + acts
 

@@ -33,16 +33,18 @@ consults before committing work.
 
 from __future__ import annotations
 
+import dataclasses
 import math
 from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
 from ..models.llama import LlamaConfig, llama_prefill_last
-from ..ops.paged_attention import (block_tail, holds_request,
-                                   paged_flush_block,
+from ..ops.paged_attention import (flush_planes, holds_request,
                                    paged_write_prefill_scales,
-                                   paged_write_prefill_stacked, quantize_kv)
+                                   paged_write_prefill_stacked,
+                                   paged_write_window, plane_tail,
+                                   quantize_kv)
 from .engine import (CacheLostError, GenerationRequest, LLMEngine,
                      _admission_widths, _pin_standard_layout, program_lookup)
 from .ownership import loop_only
@@ -166,6 +168,29 @@ class PagedLLMEngine(LLMEngine):
         __init__ (the compile rehearsal) has it too."""
         return self.cfg.paged_model()
 
+    # The pools, one a plane of the family's page (models/protocol.py
+    # `planes`), in a list: the prefill and decode programs, the capacity
+    # check and `/debug/engine` run over it. `k_cache` and `v_cache` name
+    # the two pools of a family whose planes are K and V, for the programs
+    # that only such a family runs (prefix tails, tier restores, hand-off
+    # blobs, the int8 pools, verify, chunks: a family with other planes
+    # refuses each by name) and for whoever reads the engine from outside.
+    @property
+    def k_cache(self):
+        return self.pools[0]
+
+    @k_cache.setter
+    def k_cache(self, pool) -> None:
+        self.pools[0] = pool
+
+    @property
+    def v_cache(self):
+        return self.pools[1]
+
+    @v_cache.setter
+    def v_cache(self, pool) -> None:
+        self.pools[1] = pool
+
     # -- device state ---------------------------------------------------------
     def _init_device_state(self) -> None:
         import jax
@@ -184,8 +209,10 @@ class PagedLLMEngine(LLMEngine):
 
         self.prefix = PrefixCache(ps) if self._prefix_enabled else None
         self._prefix_hits: Dict[int, List[int]] = {}
-        # the pools' leading axis counts the blocks that keep K and V
-        L, Hkv, dh = self.model.kv_layers, self.cfg.n_kv_heads, self.cfg.head_dim
+        # one pool a plane of the family's page; the pools' leading axis
+        # counts the blocks that keep pages
+        model = self.model
+        L = model.kv_layers
         held_in = getattr(self.cfg, "kv_dtype", None) or self.cfg.dtype
         dt = {"bfloat16": jnp.bfloat16, "float32": jnp.float32,
               "float16": jnp.float16, "int8": jnp.int8}[held_in]
@@ -193,9 +220,9 @@ class PagedLLMEngine(LLMEngine):
         # max_seq_len; the pool derived from them must itself fit — check
         # explicitly, since an explicit n_pages bypasses the plan's sizing
         itemsize = {"bfloat16": 2, "float16": 2, "int8": 1}.get(held_in, 4)
-        pool_bytes = 2 * L * n_pages * Hkv * dh * ps * itemsize
+        pool_bytes = model.token_values * n_pages * ps * itemsize
         if self._q8:  # f32 dequant scale pools ride along
-            pool_bytes += 2 * L * n_pages * Hkv * ps * 4
+            pool_bytes += 2 * L * n_pages * self.cfg.n_kv_heads * ps * 4
         if self.plan is not None:
             usable = int(self.plan.budget_bytes * 0.92)
             need = (self.plan.params_bytes + pool_bytes
@@ -206,11 +233,12 @@ class PagedLLMEngine(LLMEngine):
                     f"page pool of {n_pages} pages ({pool_bytes >> 20} MiB) "
                     f"does not fit the budget: params + pool + prefill temps "
                     f"= {need >> 20} MiB > {usable >> 20} MiB usable")
-        self.k_cache = jnp.zeros((L, n_pages, Hkv, dh, ps), dtype=dt)
-        self.v_cache = jnp.zeros_like(self.k_cache)
+        self.pools = [jnp.zeros((L, n_pages, plane.heads, plane.width, ps),
+                                dtype=dt) for plane in model.planes]
         self.k_scale = self.v_scale = None
         if self._q8:
-            self.k_scale = jnp.zeros((L, n_pages, Hkv, ps), dtype=jnp.float32)
+            self.k_scale = jnp.zeros((L, n_pages, self.cfg.n_kv_heads, ps),
+                                     dtype=jnp.float32)
             self.v_scale = jnp.zeros_like(self.k_scale)
         B = self.n_slots
         # what a sequence holds beside its pages, a slot's worth each
@@ -244,8 +272,7 @@ class PagedLLMEngine(LLMEngine):
 
         cache_s = NamedSharding(self.mesh, kv_cache_spec())
         rep = NamedSharding(self.mesh, PartitionSpec())
-        self.k_cache = jax.device_put(self.k_cache, cache_s)
-        self.v_cache = jax.device_put(self.v_cache, cache_s)
+        self.pools = [jax.device_put(pool, cache_s) for pool in self.pools]
         if self._q8:
             from ..parallel.sharding import kv_scale_pool_spec
 
@@ -262,7 +289,7 @@ class PagedLLMEngine(LLMEngine):
         return sum(a.size * a.dtype.itemsize for a in self.state)
 
     def pool_bytes(self) -> int:
-        total = 2 * self.k_cache.size * self.k_cache.dtype.itemsize
+        total = sum(pool.size * pool.dtype.itemsize for pool in self.pools)
         if self.k_scale is not None:  # int8: f32 scale pools are pool bytes too
             total += 2 * self.k_scale.size * self.k_scale.dtype.itemsize
         return total
@@ -805,26 +832,30 @@ class PagedLLMEngine(LLMEngine):
                     self._verify_program(width)
 
     def _prefill_fn(self, bucket: int, K: int):
-        model, mesh = self.model, self.mesh
-        top_k = self.top_k
+        model, mesh, jnp = self.model, self.mesh, self._jnp
+        top_k, n = self.top_k, len(model.planes)
         from .sampling import sample_tokens
 
-        def prefill(params, k_pool, v_pool, ptokens, ptable, slots, lengths,
-                    tokens, positions, temps, new_temps, rng, *state):
-            """Fused K-way paged admission: the model's prefill of the
-            [K, bucket] window, its K/V scattered into the slots' pages and
-            its rows' final states into the slots' state (`state`: the
-            family's per-slot arrays, none for a model that holds only
-            pages), first tokens sampled, loop state spliced.
-            ptable: [K, ceil(bucket/ps)] page ids."""
-            k_pool, v_pool = _pin_standard_layout(k_pool, v_pool)
-            last, tmp_k, tmp_v, rows = model.prefill(params, ptokens, lengths,
-                                                     mesh)
+        def prefill(params, *rest):
+            """(params, a pool a plane, ptokens, ptable, slots, lengths,
+            tokens, positions, temps, new_temps, rng, *state). Fused K-way
+            paged admission: the model's prefill of the [K, bucket]
+            window, what it keeps a token scattered into the slots' pages,
+            plane by plane, and its rows' final states into the slots'
+            state (`state`: the family's per-slot arrays, none for a model
+            that holds only pages), first tokens sampled, loop state
+            spliced. ptable: [K, ceil(bucket/ps)] page ids."""
+            (ptokens, ptable, slots, lengths, tokens, positions, temps,
+             new_temps, rng, *state) = rest[n:]
+            pools = [_pin_standard_layout(pool) for pool in rest[:n]]
+            last, windows, rows = model.prefill(params, ptokens, lengths,
+                                                mesh)
             # scatter the window into pages: token t of row k goes to
             # (ptable[k, t // ps], t % ps); pad junk past lengths[k] is
             # redirected to the garbage page so live pages stay clean
-            k_pool, v_pool = paged_write_prefill_stacked(
-                k_pool, v_pool, tmp_k, tmp_v, ptable, lengths)
+            starts = jnp.zeros_like(lengths)
+            pools = [paged_write_window(pool, window, ptable, starts, lengths)
+                     for pool, window in zip(pools, windows)]
             # a slot's state is written whole, in place (donated)
             state = tuple(held.at[:, slots].set(row.astype(held.dtype))
                           for held, row in zip(state, rows))
@@ -832,9 +863,8 @@ class PagedLLMEngine(LLMEngine):
             tokens = tokens.at[slots].set(first)
             positions = positions.at[slots].set(lengths)
             temps = temps.at[slots].set(new_temps)
-            k_pool, v_pool = _pin_standard_layout(k_pool, v_pool)
-            return (k_pool, v_pool, tokens, positions, temps, rng, first,
-                    *state)
+            pools = [_pin_standard_layout(pool) for pool in pools]
+            return (*pools, tokens, positions, temps, rng, first, *state)
 
         return prefill
 
@@ -893,65 +923,68 @@ class PagedLLMEngine(LLMEngine):
                 f"llama-paged-prefill-q8-{bucket}x{K}{self._id_tag}",
                 self._prefill_fn_q8(bucket, K),
                 args, donate_argnums=(1, 2, 3, 4, 9, 10, 11))
-        args = (self.params, self.k_cache, self.v_cache,
+        args = (self.params, *self.pools,
                 jnp.zeros((K, bucket), dtype=jnp.int32),
                 jnp.zeros((K, n_ptable), dtype=jnp.int32),
                 jnp.zeros((K,), dtype=jnp.int32),
                 jnp.ones((K,), dtype=jnp.int32),
                 self._tokens, self._positions, self._temps,
                 self._temps_init(K), self.rng, *self.state)
+        n = len(self.pools)     # the pools, the loop's vectors, the state
         return self.executor.compile(
             f"{self.model.program_tag}-paged-prefill-{bucket}x{K}{self._id_tag}",
             self._prefill_fn(bucket, K), args,
-            donate_argnums=(1, 2, 7, 8, 9) + tuple(
-                range(12, 12 + len(self.state))))
+            donate_argnums=tuple(range(1, 1 + n)) + (n + 5, n + 6, n + 7)
+            + tuple(range(n + 10, n + 10 + len(self.state))))
 
     def _decode_fn_paged(self, block: int, n_table: int):
         model, mesh = self.model, self.mesh
-        top_k = self.top_k
+        top_k, n = self.top_k, len(model.planes)
         import jax
         import jax.numpy as jnp
 
         from .sampling import sample_tokens
 
-        def decode(params, k_pool, v_pool, table, tokens, positions, temps,
-                   rng, *state):
-            """`block` paged decode steps under scan; table [B, n_table];
-            `state` the family's per-slot arrays (none for a model that
-            holds only pages), carried and returned like the pools. The
-            block's new K and V wait in its tail (ops/paged_attention
-            `block_tail`: made here, carried by the scan, dead at return)
-            and the pools are only read until the scan is over; then ONE
-            flush writes each live row's page once. A row that holds no
+        def decode(params, *rest):
+            """(params, a pool a plane, table, tokens, positions, temps,
+            rng, *state). `block` paged decode steps under scan; table
+            [B, n_table]; `state` the family's per-slot arrays (none for a
+            model that holds only pages), carried and returned like the
+            pools. What the block's new tokens must keep waits in its
+            tail, one a plane (ops/paged_attention `plane_tail`: made
+            here, carried by the scan, dead at return) and the pools are
+            only read until the scan is over; then ONE flush writes each
+            live row's page once, every plane. A row that holds no
             request (its table starts at the garbage page) flushes
             nothing. A family that counts (models/protocol.py `counters`)
             gives a row of int32 a step; their sum over the block rides
             below the block's tokens, so one copy to the host carries
             both."""
-            k_pool, v_pool = _pin_standard_layout(k_pool, v_pool)
+            table, tokens, positions, temps, rng, *state = rest[n:]
+            pools = tuple(_pin_standard_layout(pool) for pool in rest[:n])
 
             def step(carry, t):
                 tail, held, tok, pos, rng = carry
                 logits, tail, held, counted = model.decode(
-                    params, tok, pos, k_pool, v_pool, table, held, tail, t,
-                    mesh)
+                    params, tok, pos, pools, table, held, tail, t, mesh)
                 nxt, rng = sample_tokens(logits, rng, temps, top_k=top_k)
                 return (tail, held, nxt, pos + 1, rng), (nxt, counted)
 
-            tail = block_tail(k_pool, tokens.shape[0], block, mesh)
+            tail = tuple(plane_tail(pool, tokens.shape[0], block, mesh)
+                         for pool in pools)
             (tail, state, tok, pos, rng), (out, counted) = jax.lax.scan(
                 step, (tail, tuple(state), tokens, positions, rng),
                 jnp.arange(block, dtype=jnp.int32))
-            k_pool, v_pool = paged_flush_block(
-                k_pool, v_pool, *tail, table, positions,
+            pools = flush_planes(
+                pools, tail, table, positions,
                 jnp.where(holds_request(table), block, 0), mesh=mesh)
-            k_pool, v_pool = _pin_standard_layout(k_pool, v_pool)
+            pools = [_pin_standard_layout(pool) for pool in pools]
             out = out.T
             if counted is not None:
                 below = jnp.zeros((counted.shape[1], block), out.dtype)
                 out = jnp.concatenate(
                     [out, below.at[:, 0].set(counted.sum(axis=0))])
-            return (k_pool, v_pool, tok, pos, rng, out, *state)
+            return (*pools, tok, pos, rng, out, *state)
 
         return decode
 
@@ -996,15 +1029,17 @@ class PagedLLMEngine(LLMEngine):
                 f"llama-paged-decode-q8-x{block}-NP{n_table}{self._id_tag}",
                 self._decode_fn_paged_q8(block, n_table), args,
                 donate_argnums=(1, 2, 3, 4))
-        args = (self.params, self.k_cache, self.v_cache,
+        args = (self.params, *self.pools,
                 jnp.zeros((self.n_slots, n_table), dtype=jnp.int32),
                 self._tokens, self._positions, self._temps, self.rng,
                 *self.state)
+        n = len(self.pools)
         return self.executor.compile(
             f"{self.model.program_tag}-paged-decode-x{block}-NP{n_table}"
             f"{self._id_tag}",
             self._decode_fn_paged(block, n_table), args,
-            donate_argnums=(1, 2) + tuple(range(8, 8 + len(self.state))))
+            donate_argnums=tuple(range(1, 1 + n)) + tuple(
+                range(n + 6, n + 6 + len(self.state))))
 
     # -- chunked prefill over the pool ---------------------------------------
     # A long prompt's chunks run against bucket-sized per-JOB temp caches
@@ -1755,14 +1790,20 @@ class PagedLLMEngine(LLMEngine):
                 if self.write_pages else None)}}
 
     def model_snapshot(self) -> dict:
-        """`/debug/engine` "model": the family, what it holds beside the
+        """`/debug/engine` "model": the family, the planes of its page
+        and the bytes a token keeps in them, what it holds beside the
         pools, and what the family makes of its decode counters since the
         last reset (models/protocol.py `describe`)."""
-        counts = dict(zip(self.model.counters, self.model_counts.tolist()))
-        return {"family": self.model.family,
-                "kv_layers": self.model.kv_layers,
+        model = self.model
+        counts = dict(zip(model.counters, self.model_counts.tolist()))
+        return {"family": model.family,
+                "kv_layers": model.kv_layers,
+                "planes": [dataclasses.asdict(plane)
+                           for plane in model.planes],
+                "cache_bytes_per_token": (
+                    model.token_values * self.pools[0].dtype.itemsize),
                 "state_bytes": self.state_bytes(),
-                **self.model.describe(counts, self.model_count_steps)}
+                **model.describe(counts, self.model_count_steps)}
 
     def _build_table(self) -> np.ndarray:
         """Block table for the current active slots, padded to a power-of-
@@ -1820,16 +1861,17 @@ class PagedLLMEngine(LLMEngine):
                         jnp.asarray(lengths), self._tokens, self._positions,
                         self._temps, jnp.asarray(new_temps), self.rng)
                 else:
-                    (self.k_cache, self.v_cache, self._tokens,
-                     self._positions, self._temps, self.rng, first,
-                     *state) = program(
-                        self.params, self.k_cache, self.v_cache,
+                    out = program(
+                        self.params, *self.pools,
                         jnp.asarray(ptokens), jnp.asarray(ptable),
                         jnp.asarray(np.asarray(slots_idx, dtype=np.int32)),
                         jnp.asarray(lengths), self._tokens, self._positions,
                         self._temps, jnp.asarray(new_temps), self.rng,
                         *self.state)
-                    self.state = tuple(state)
+                    n = len(self.pools)
+                    (self._tokens, self._positions, self._temps, self.rng,
+                     first, *state) = out[n:]
+                    self.pools, self.state = list(out[:n]), tuple(state)
         except Exception as exc:
             raise CacheLostError(f"paged prefill dispatch failed: {exc}") from exc
 
@@ -1870,13 +1912,14 @@ class PagedLLMEngine(LLMEngine):
                                 jnp.asarray(table), self._tokens,
                                 self._positions, self._temps, self.rng)
                 else:
-                    (self.k_cache, self.v_cache, self._tokens,
-                     self._positions, self.rng, out_tokens,
-                     *state) = program(
-                        self.params, self.k_cache, self.v_cache,
+                    out = program(
+                        self.params, *self.pools,
                         jnp.asarray(table), self._tokens, self._positions,
                         self._temps, self.rng, *self.state)
-                    self.state = tuple(state)
+                    n = len(self.pools)
+                    (self._tokens, self._positions, self.rng, out_tokens,
+                     *state) = out[n:]
+                    self.pools, self.state = list(out[:n]), tuple(state)
         except Exception as exc:
             raise CacheLostError(f"paged decode dispatch failed: {exc}") from exc
         self._start_d2h(out_tokens)

@@ -142,8 +142,7 @@ def decode_bytes(cfg, rows: int, steps: int, kv_tokens: int,
     pages, that state read and written a row."""
     weights = params_nbytes if params_nbytes else params_bytes(cfg)
     per_step = (float(weights)
-                + float(kv_tokens) * kv_token_bytes(cfg)
-                + float(rows) * kv_token_bytes(cfg)
+                + float(kv_tokens + rows) * kv_token_bytes(cfg)
                 + 2.0 * rows * cfg.state_bytes_per_slot)
     return float(steps) * per_step
 

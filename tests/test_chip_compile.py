@@ -652,3 +652,89 @@ def test_nemotron_h_prefill_compiles_with_its_state_in_place(topo, as_tpu):
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= held
     assert mem.temp_size_in_bytes < 1 * GIB
+
+
+# -- the mla_moe family's step programs (ISSUE 31) ------------------------------
+def _mla_moe_programs(topo, n_layers=3, slots=128, n_pages=4600):
+    """(config, chips, engine shell, abstract params, the one latent pool,
+    loop state, rng) at JoyAI-LLM-Flash's published widths, the dense block
+    and two expert blocks deep, the pool as the cell sizes it."""
+    from gofr_tpu.models.mla_moe import (FLOAT32_LEAVES, MlaMoeConfig,
+                                         layer_shapes)
+    from gofr_tpu.tpu.paging import PagedLLMEngine
+
+    cfg = dataclasses.replace(MlaMoeConfig.joyai_llm_flash_ep8(),
+                              n_layers=n_layers, attn_impl="flash")
+    chips = Chips(topo, 1)
+    engine = _engine_shell(PagedLLMEngine, cfg, None)
+    params = {
+        "tok_emb": chips.shape((cfg.vocab_size, cfg.dim), jnp.bfloat16),
+        "final_norm": chips.shape((cfg.dim,), jnp.bfloat16),
+        "lm_head": chips.shape((cfg.dim, cfg.vocab_size), jnp.bfloat16),
+        "layers": [{name: chips.shape(shape, jnp.float32 if name in FLOAT32_LEAVES
+                                      else jnp.bfloat16)
+                    for name, shape in layer_shapes(
+                        cfg, i < cfg.first_dense).items()}
+                   for i in range(n_layers)]}
+    pools = tuple(chips.shape((cfg.kv_layers, n_pages, plane.heads,
+                               plane.width, PAGE), jnp.bfloat16)
+                  for plane in engine.model.planes)
+    return (cfg, chips, engine, params, pools, _loop_state(chips, slots),
+            chips.shape((2,), jnp.uint32))
+
+
+def test_mla_moe_decode_step_compiles_with_its_one_pool_in_place(topo,
+                                                                as_tpu):
+    """The cell's decode program shape (128 slots, 4,600 pages, table 64
+    wide) at the published widths: ONE pool (a latent plane of 1 x 576), its
+    tail and its flush; the absorbed read a kernel of its own name a block,
+    the gated experts the kernel nemotron_h names, the flush once, outside
+    the scan; the pool aliased and nothing pool-sized or expert-sized
+    copied."""
+    from gofr_tpu.tpu.executor import _named_after
+
+    cfg, chips, engine, params, pools, loop, rng = _mla_moe_programs(topo)
+    assert [p.shape for p in pools] == [(3, 4600, 1, 576, PAGE)]
+    compiled = _compile(
+        _named_after(engine._decode_fn_paged(16, 64),
+                     "mla-moe-paged-decode-x16-NP64"),
+        params, *pools, chips.shape((128, 64), jnp.int32), *loop, rng,
+        donate=(1,))
+    assert "HloModule jit_decode__x16_NP64," in compiled.as_text()
+    calls = _kernel_calls(compiled)
+    names = sorted(name.rsplit(".", 1)[0] for name, _, _ in calls)
+    assert names == ["mla_read"] * 3 + ["moe_experts"] * 2 + ["paged_write"]
+    bodies = _while_bodies(compiled)
+    for name, _, _ in calls:
+        inside = _computation_of(compiled, name) in bodies
+        assert inside == (not name.startswith("paged_write")), name
+    _assert_pool_in_place(compiled, pools)
+    expert = cfg.held * cfg.dim * cfg.expert_dim * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < expert
+
+
+@pytest.mark.parametrize("bucket", [2048, 4096])
+def test_mla_moe_prefill_compiles_with_two_widths(topo, as_tpu, bucket):
+    """One prompt of the cell's buckets: flash attention with keys of 192
+    and values of 128 (resident at both), the gated experts as a grouped
+    product, the window's latent plane written into the donated pool."""
+    from gofr_tpu.tpu.executor import _named_after
+
+    cfg, chips, engine, params, pools, loop, rng = _mla_moe_programs(topo)
+    rows = chips.shape((1,), jnp.int32)
+    compiled = _compile(
+        _named_after(engine._prefill_fn(bucket, 1),
+                     f"mla-moe-paged-prefill-{bucket}x1"),
+        params, *pools, chips.shape((1, bucket), jnp.int32),
+        chips.shape((1, bucket // PAGE), jnp.int32), rows, rows, *loop,
+        chips.shape((1,), jnp.float32), rng, donate=(1, 6, 7, 8))
+    assert f"HloModule jit_prefill__{bucket}x1," in compiled.as_text()
+    names = sorted(name.rsplit(".", 1)[0]
+                   for name, _, _ in _kernel_calls(compiled))
+    assert names == ["flash_prefill"] * 3 + ["moe_experts"] * 2
+    pool_bytes = sum(np.prod(p.shape) * p.dtype.itemsize for p in pools)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    # the window's temporaries (0.34 GiB at 2,048, 0.6 at 4,096), never a
+    # copy of the 1.9 GiB pool
+    assert mem.temp_size_in_bytes < 1 * GIB
