@@ -15,8 +15,16 @@ folded first and the token put into it, one online softmax) with one pool
 in place of two, G = H queries on the one head, and the value taken as a
 slice of the key's buffer: the same kernel body, told `value_width`, under
 its own name `mla_read`. At 32 heads of 512 + 64 in bfloat16 a page of 128
-tokens is 147 KB and meets 9.0 MFLOP (60 flop a byte): bound by bytes on a
-v5e, with the MXU a quarter busy (32 of its 128 rows) at the roofline.
+tokens is 147 KB, 0.18 us of DMA on a v5e, and meets 9.0 MFLOP (60 flop a
+byte) in two products of one KV head: a loop turn that folded ONE such page
+took 0.49 us, most of it the latencies of a chain in which every step
+waits for the one before (copy, scores, max, exp, value product, rescale),
+and the read sat at 37 % of its byte roofline (PR 31). A turn folds a
+FOLD of pages (`pages_per_fold`: 8 of these, 1.2 MB, one score product of
+8 output tiles, one softmax step over 1,024 tokens), the chain is paid
+once a fold, and the call at the benchmark cell's shape went from 1,815 to
+800 us, 84 % of the roofline over whole live pages (v5e,
+tools/bench_paged_read.py, PR 32).
 
 `mla_read_reference` (gather-based) is the numerics oracle.
 """

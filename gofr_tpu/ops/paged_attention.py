@@ -18,14 +18,16 @@ pages, exactly like ops/flash_attention's streaming kernel.
 
 Grid: (B,), ONE STEP A ROW, sequential. Inside it a loop over the row's
 ceil(length / page_size) live pages and no others, each page ALL Hkv heads
-at once (the dots batch over the KV heads), double-buffered: page i + 1 is
-in flight while page i is folded in. The walk is by row because a grid
+at once (the dots batch over the KV heads), a FOLD of C consecutive pages
+a turn (`pages_per_fold`: C from the page's bytes and the table's
+width), double-buffered: fold i + 1 is in flight while fold i
+goes into the softmax. The walk is by row because a grid
 over (row, table column) pays for every column of the table, live or not
 (on the v5e about half a microsecond each, which at a table a quarter
 full was two thirds of the kernel's time); by row, time follows the live
-tokens. A row is only a few pages, so a DMA queue that drained at every
+tokens. A row is only a few folds, so a DMA queue that drained at every
 row boundary would idle for a large part of each row: before a row folds
-its last page it starts the first page of the next row that has one, and
+its last fold it starts the first fold of the next row that has one, and
 which buffer that is carries over in SMEM scratch. Table entries past a
 row's live pages are never read.
 
@@ -124,27 +126,88 @@ def paged_attention_reference(q, k_pool, v_pool, table, lengths,
     return out.reshape(B, H, dh).astype(q.dtype)
 
 
+# What one fold's pages should weigh at least. A turn of the read's loop
+# costs about 0.3 us whatever it folds (the copies' wait, the MXU's fill and
+# drain, the cross-lane max and the carry that chains the turns, none of
+# which a second page in the same turn pays again), so a fold's copy should
+# last a few times that: 1 MiB is 1.28 us at a v5e's 819 GB/s. Measured
+# there (tools/bench_paged_read.py, PERF.md section 5): a latent page of
+# 144 KiB reads 1,815 us a call at folds of one page, 916 at 4, 800 at 8
+# and 821 at 16; K and V of 2 heads (128 KiB) 231, 150 at 4, 143 at 8; of
+# 8 heads (512 KiB) 330, 317 at 2.
+_FOLD_BYTES = 1 << 20
+
+
+def pages_per_fold(page_bytes: int, table_width: int) -> int:
+    """C, the pages one turn of the read's loop folds (see `_paged_kernel`),
+    from what a call can see and nothing else: the bytes of one page over
+    all the call's pools, scale planes included, which is what the page's
+    heads and widths come to (pages are folded until a fold weighs
+    `_FOLD_BYTES`), and the table's width (a fold is never wider than a row
+    can be). A power of two, so a fold's lanes stay whole tiles at any page
+    size; a fold is under 2 x `_FOLD_BYTES`, so the two buffers a pool that
+    hold it take under 4 MiB of the 16 MiB of VMEM the compiler gives a
+    kernel unasked. The benchmark's cells: a latent page (144 KiB) -> 8;
+    K and V of 2 heads (nemotron, 128 KiB) -> 8; of 8 heads (internlm2,
+    512 KiB) -> 2. The int8 pools follow the same rule, their scale planes
+    riding the fold as [heads, C x ps]."""
+    c = 1
+    while c * page_bytes < _FOLD_BYTES and 2 * c <= table_width:
+        c *= 2
+    return c
+
+
+def fold_of(pools, table_width: int, mesh=None) -> int:
+    """`pages_per_fold` of stacked pools [L, P, heads, ...]: what the read
+    of these pools under a table this wide folds a turn. Under a tp `mesh`
+    each shard runs the kernel on its own share of the heads."""
+    shards = mesh.shape.get("tp", 1) if mesh is not None else 1
+    page_bytes = sum(math.prod(pool.shape[2:]) * pool.dtype.itemsize
+                     for pool in pools)
+    return pages_per_fold(page_bytes // shards, table_width)
+
+
 def _paged_kernel(layer_ref, table_ref, len_ref, *refs, scale: float,
-                  quantized: bool, tailed: bool, value_width=None):
+                  quantized: bool, tailed: bool, fold: int,
+                  value_width=None):
     """One grid step = one row b: stream the row's live pages (ALL heads of
-    a page at a time) through two VMEM buffers a pool and fold each into
-    the online softmax, the dots batched over the KV heads. `tailed`, the
-    row is in a decode block: its new k and v are put into its tail as
-    token tail_len[b] - 1, the tail goes back where it came from, and its
-    first tail_len[b] tokens are one more segment of the same softmax.
-    `value_width` (ops/mla_read.py), the page holds ONE plane and a
-    token's value is the first `value_width` of its key's dh values: one
+    a page at a time) through two VMEM buffers a pool and fold them into
+    the online softmax, the dots batched over the KV heads.
+
+    A FOLD is `fold` = C consecutive pages of the row (`pages_per_fold`),
+    copied side by side into one buffer [Hkv, dh, C x ps] (page c of the
+    fold at lanes [c x ps, (c + 1) x ps)), and one turn of the loop folds
+    one: ONE score product [G, dh] x [dh, C x ps] a head, ONE max / exp /
+    sum / rescale over its C x ps tokens, ONE value product, while the
+    next fold's C copies are in flight. A turn's steps depend on each other
+    (the copy's wait, the MXU's fill and drain, the cross-lane max, the
+    carry (m, l, acc) that chains the turns), so their latencies are paid
+    once a fold, not once a page: they come to about 0.3 us a turn on a v5e,
+    beside which a latent page of 144 KiB is 0.18 us of DMA and a K and V
+    page of 8 heads 0.64 (`_FOLD_BYTES` has the measurements). Only live
+    pages are copied: a row's last fold may hold fewer than C, and the
+    lanes it does not copy keep what an earlier fold left there. Their
+    scores are masked (they lie past the row's length) and their
+    probabilities are 0.0, but 0.0 x NaN is NaN in the value product: the
+    buffers are zeroed before the first copy of a call, and what a live
+    page holds is finite.
+
+    `tailed`, the row is in a decode block: its new k and v are put into
+    its tail as token tail_len[b] - 1, the tail goes back where it came
+    from, and its first tail_len[b] tokens are one more segment of the same
+    softmax. `value_width` (ops/mla_read.py), the page holds ONE plane and
+    a token's value is the first `value_width` of its key's dh values: one
     pool, one tail, the output [Hkv, G, value_width].
 
     refs: [tail_len (SMEM, with the other scalars),] q, [the row's new k,
     v [Hkv, 1, dh'],] the n stacked pools left in HBM (k, v[, k_scale,
     v_scale]), [the two stacked tails left where they are,] o, [the tails
-    again: the outputs alias them,] the pools' n VMEM buffers [2, *page],
-    [the tails' two [2, Hkv, T, dh'],] DMA semaphores [n, 2], [the tails'
-    [2, 2] in and [2] out,] and `first_slot` (SMEM: the buffer this row's
-    first page was started in). int8 pages carry per-token scales; dequant
-    FOLDS into the dots (k's scale multiplies score rows, v's folds into
-    the probabilities)."""
+    again: the outputs alias them,] the pools' n VMEM buffers
+    [2, *page[:-1], C x ps], [the tails' two [2, Hkv, T, dh'],] DMA
+    semaphores [n, 2, C], [the tails' [2, 2] in and [2] out,] and
+    `first_slot` (SMEM: the buffer this row's first fold was started in).
+    int8 pages carry per-token scales; dequant FOLDS into the dots (k's
+    scale multiplies score rows, v's folds into the probabilities)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -173,17 +236,39 @@ def _paged_kernel(layer_ref, table_ref, len_ref, *refs, scale: float,
     length = len_ref[b]
     n_kv, G, dh = q_ref.shape[1:]
     dv = value_width or dh
-    page_size = k_buf.shape[-1]
-    n_pages = jnp.minimum((length + page_size - 1) // page_size,
-                          table_ref.shape[1])
+    page_size = k_buf.shape[-1] // fold
 
-    def page_copies(row, i, slot):
-        page = table_ref[row, i]
-        return [pltpu.make_async_copy(pool.at[layer, page], buf.at[slot],
-                                      sems.at[j, slot])
-                for j, (pool, buf) in enumerate(zip(pools, bufs))]
+    def pages_of(row):
+        return jnp.minimum((len_ref[row] + page_size - 1) // page_size,
+                           table_ref.shape[1])
 
-    def start_first_page_after(row, slot):
+    n_pages = pages_of(b)
+    n_folds = (n_pages + fold - 1) // fold
+
+    def each_copy(row, f, slot, do):
+        """Start or wait for the copies of fold f of `row` into buffer
+        `slot`: its first page, which a fold always has, and those of the
+        C - 1 after it that are live."""
+        live = pages_of(row)
+        for c in range(fold):
+            def _page(c=c):
+                page = table_ref[row, f * fold + c]
+                for j, (pool, buf) in enumerate(zip(pools, bufs)):
+                    window = (slot,) + (slice(None),) * (buf.ndim - 2) + (
+                        pl.ds(c * page_size, page_size),)
+                    do(pltpu.make_async_copy(
+                        pool.at[layer, page], buf.at[window],
+                        sems.at[j, slot, c]))
+
+            if c:
+                pl.when(f * fold + c < live)(_page)
+            else:
+                _page()
+
+    def start_fold(row, f, slot):
+        each_copy(row, f, slot, lambda copy: copy.start())
+
+    def start_first_fold_after(row, slot):
         # the next row that HAS a page: a row of length 0 owns none
         def live_or_end(r):
             return jnp.logical_or(r > last_row,
@@ -196,13 +281,17 @@ def _paged_kernel(layer_ref, table_ref, len_ref, *refs, scale: float,
 
         @pl.when(nxt <= last_row)
         def _start():
-            for copy in page_copies(nxt, 0, slot):
-                copy.start()
+            start_fold(nxt, 0, slot)
 
     @pl.when(b == 0)
     def _first_row():
+        if fold > 1:
+            # lanes a short fold leaves uncopied are read (masked): never
+            # whatever VMEM held
+            for buf in bufs:
+                buf[...] = jnp.zeros(buf.shape, buf.dtype)
         first_slot[0] = 0
-        start_first_page_after(-1, 0)
+        start_first_fold_after(-1, 0)
 
     q = q_ref[0]                                          # [Hkv, G, dh]
     slot0 = first_slot[0]
@@ -211,9 +300,9 @@ def _paged_kernel(layer_ref, table_ref, len_ref, *refs, scale: float,
               jnp.zeros((n_kv, G, dv), jnp.float32))
 
     if tailed:
-        # The tail folds FIRST, while the row's first page (started by the
+        # The tail folds FIRST, while the row's first fold (started by the
         # row before) and its second (started here) stream in: folded last,
-        # it would leave the copy queue one page deep at every row's end.
+        # it would leave the copy queue one fold deep at every row's end.
         # Its own copy was started by the row before, into the buffer of
         # this row's parity, so nothing waits for it either.
         tail_len = tail_len_ref[b]
@@ -238,10 +327,9 @@ def _paged_kernel(layer_ref, table_ref, len_ref, *refs, scale: float,
         def _first_tail():
             start_tail(b)
 
-        @pl.when(n_pages > 1)
-        def _second_page():
-            for copy in page_copies(b, 1, 1 - slot0):
-                copy.start()
+        @pl.when(n_folds > 1)
+        def _second_fold():
+            start_fold(b, 1, 1 - slot0)
 
         @pl.when(b < last_row)
         def _next_tail():
@@ -287,37 +375,39 @@ def _paged_kernel(layer_ref, table_ref, len_ref, *refs, scale: float,
         # a row that holds no request has no tail either: it reads nothing
         folded = jax.lax.cond(tail_len > 0, fold_tail, lambda c: c, folded)
 
-    def fold_page(i, carry):
+    # tokens the walk reaches: it stops at the table's width whatever the
+    # length says, and a fold's lanes past them were not copied this turn
+    reached = jnp.minimum(length, n_pages * page_size)
+
+    def fold_pages(f, carry):
         m_prev, l_prev, acc = carry
-        slot = (slot0 + i) % 2
+        slot = (slot0 + f) % 2
 
-        # keep the DMA queue fed before waiting: this row's next page (the
+        # keep the DMA queue fed before waiting: this row's next fold (the
         # second is under way already where a tail folded first) or, from
-        # its last page, the first page of the next row that has one
-        @pl.when(jnp.logical_and(i + 1 < n_pages, i >= int(tailed)))
-        def _next_page():
-            for copy in page_copies(b, i + 1, 1 - slot):
-                copy.start()
+        # its last fold, the first fold of the next row that has one
+        @pl.when(jnp.logical_and(f + 1 < n_folds, f >= int(tailed)))
+        def _next_fold():
+            start_fold(b, f + 1, 1 - slot)
 
-        @pl.when(i + 1 == n_pages)
+        @pl.when(f + 1 == n_folds)
         def _next_row():
-            start_first_page_after(b, 1 - slot)
+            start_first_fold_after(b, 1 - slot)
 
-        for copy in page_copies(b, i, slot):
-            copy.wait()
+        each_copy(b, f, slot, lambda copy: copy.wait())
 
-        k = k_buf[slot]                                   # [Hkv, dh, ps]
+        k = k_buf[slot]                                   # [Hkv, dh, C ps]
         v = k[:, :dv] if latent else v_buf[slot]
         if quantized:
             k = k.astype(jnp.bfloat16)                    # in-VMEM upcast
-        # every head's [G, dh] x [dh, ps], batched over the KV heads
+        # every head's [G, dh] x [dh, C ps], batched over the KV heads
         s = jax.lax.dot_general(q, k, (((2,), (1,)), ((0,), (0,))),
                                 preferred_element_type=jnp.float32) * scale
         if quantized:
             s = s * ks_buf[slot][:, None, :].astype(jnp.float32)
-        kv_pos = i * page_size + jax.lax.broadcasted_iota(
+        kv_pos = f * (fold * page_size) + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 2)
-        s = jnp.where(kv_pos < length, s, DEFAULT_MASK_VALUE)
+        s = jnp.where(kv_pos < reached, s, DEFAULT_MASK_VALUE)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         pr = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
@@ -330,8 +420,8 @@ def _paged_kernel(layer_ref, table_ref, len_ref, *refs, scale: float,
                                  preferred_element_type=jnp.float32)
         return m_new, l_new, acc * alpha + pv
 
-    _, l, acc = jax.lax.fori_loop(0, n_pages, fold_page, folded)
-    first_slot[0] = (slot0 + n_pages) % 2
+    _, l, acc = jax.lax.fori_loop(0, n_folds, fold_pages, folded)
+    first_slot[0] = (slot0 + n_folds) % 2
     if tailed:
         @pl.when(tail_len > 0)
         def _tail_is_back():
@@ -470,10 +560,11 @@ def _paged_read(q, pools, table, lengths, block, layer, mesh, interpret, *,
             for new, tail in zip(news, tails)]
 
     scalars = [layer_arr, table, lengths] + tail_lens
+    fold = fold_of(pools, table.shape[1])
     kernel = functools.partial(_paged_kernel,
                                scale=scale or 1.0 / math.sqrt(dh),
                                quantized=quantized, tailed=tailed,
-                               value_width=value_width)
+                               fold=fold, value_width=value_width)
 
     def row_index(b, *scalars):
         return (b, 0, 0, 0)
@@ -491,10 +582,12 @@ def _paged_read(q, pools, table, lengths, block, layer, mesh, interpret, *,
         + [pl.BlockSpec((1,) + new.shape[1:], row_index) for new in news]
         + [whole] * (len(pools) + len(tails)),
         out_specs=[out_row] + [whole] * len(tails),
-        scratch_shapes=[pltpu.VMEM((2,) + pool.shape[2:], pool.dtype)
+        # two buffers a pool, each a fold wide: C pages side by side
+        scratch_shapes=[pltpu.VMEM((2,) + pool.shape[2:-1]
+                                   + (fold * pool.shape[-1],), pool.dtype)
                         for pool in pools]
         + [pltpu.VMEM((2,) + x.shape[2:], x.dtype) for x in tails]
-        + [pltpu.SemaphoreType.DMA((len(pools), 2))]
+        + [pltpu.SemaphoreType.DMA((len(pools), 2, fold))]
         + [pltpu.SemaphoreType.DMA((len(tails), 2)),
            pltpu.SemaphoreType.DMA((len(tails),))] * tailed
         + [pltpu.SMEM((1,), jnp.int32)],
@@ -509,7 +602,7 @@ def _paged_read(q, pools, table, lengths, block, layer, mesh, interpret, *,
             # the tails are updated where they lie
             input_output_aliases={first_tail + i: 1 + i
                                   for i in range(len(tails))},
-            # sequential rows: a row starts its successor's first page
+            # sequential rows: a row starts its successor's first fold
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",)),
             interpret=interpret,

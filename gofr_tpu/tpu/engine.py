@@ -784,7 +784,8 @@ class LLMEngine:
         self.migrations_total = 0
 
         # in-flight dispatches awaiting host sync, processed FIFO:
-        #   ("decode", out_tokens [B, M] future, [(slot_idx, request)], M)
+        #   ("decode", out_tokens [B, M] future, [(slot_idx, request)], M,
+        #    dispatched at, dispatch span, table width)
         #   ("prefill", first_tokens [K] future, [(slot_idx, request)])
         self._inflight: "collections.deque" = collections.deque()
         # decode blocks read, and those read with slots still decoding and
@@ -2208,7 +2209,7 @@ class LLMEngine:
                     self._spec_cooloff = self.SPEC_COOLOFF_DISPATCHES
             return
 
-        _, out_tokens, snapshot, block, started, dspan = entry
+        _, out_tokens, snapshot, block, started, dspan, n_table = entry
         # what the device has to go on with while this block's demux and
         # emit run on the host
         queued_behind = self._decode_inflight()
@@ -2233,6 +2234,7 @@ class LLMEngine:
         # actually read each step (the MBU KV term)
         live = [(i, r) for i, r in snapshot if self.slots[i].request is r]
         page_writes = self._note_page_writes(live, block)
+        self._note_page_reads(live, block, n_table)
         self.util.record_decode(
             rows=len(snapshot), steps=block,
             kv_tokens=sum(self.slots[i].length for i, r in live),
@@ -2843,4 +2845,9 @@ class LLMEngine:
     def _note_page_writes(self, live, block: int) -> int:
         """Count a synced decode block's page writes; returns them for
         the step ledger's record."""
+        raise NotImplementedError
+
+    def _note_page_reads(self, live, block: int, n_table: int) -> None:
+        """Count the folds a synced decode block's reads made under a
+        table `n_table` wide."""
         raise NotImplementedError

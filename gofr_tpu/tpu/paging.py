@@ -40,7 +40,7 @@ from typing import Dict, List, Optional, Sequence
 import numpy as np
 
 from ..models.llama import LlamaConfig, llama_prefill_last
-from ..ops.paged_attention import (flush_planes, holds_request,
+from ..ops.paged_attention import (flush_planes, fold_of, holds_request,
                                    paged_write_prefill_scales,
                                    paged_write_prefill_stacked,
                                    paged_write_window, plane_tail,
@@ -253,6 +253,12 @@ class PagedLLMEngine(LLMEngine):
         # decode tokens placed in pages and the page writes that placed
         # them, since the last reset (`_note_page_writes`)
         self.write_tokens = self.write_pages = 0
+        # the decode reads' folds (loop turns of the read's kernel), the
+        # tokens they attended in pages and the lanes they spanned, since
+        # the last reset, and the last block's fold width
+        # (`_note_page_reads`)
+        self.read_folds = self.read_tokens = self.read_lanes = 0
+        self.read_pages_per_fold = None
         self._tokens = jnp.zeros((B,), dtype=jnp.int32)
         self._positions = jnp.zeros((B,), dtype=jnp.int32)
         self._temps = self._temps_init(B)
@@ -1777,17 +1783,54 @@ class PagedLLMEngine(LLMEngine):
         self.write_pages += writes
         return writes
 
+    def _note_page_reads(self, live, block: int, n_table: int) -> None:
+        """Count a synced decode block's folds from what the host holds,
+        no device access: under a table `n_table` wide the read folds C
+        pages a loop turn (ops/paged_attention `fold_of`, from the pools'
+        shapes), and every step of the block, every attention layer, a
+        live row's read walks the pages of its slot's length (pre-demux
+        here: what the block found; the block's own tokens wait in its
+        tail) in ceil(pages / C) folds of C x page_size lanes each. The
+        int8 pools have no tail: step t attends the t + 1 tokens written
+        so far too."""
+        pools = ([self.k_cache, self.v_cache, self.k_scale, self.v_scale]
+                 if self._q8 else self.pools)
+        c, ps = fold_of(pools, n_table, self.mesh), self.page_size
+        tokens = np.asarray([self.slots[i].length for i, _ in live],
+                            np.int64)[:, None]
+        tokens = tokens + (np.arange(1, block + 1) if self._q8
+                           else np.zeros(block, np.int64))
+        pages = np.minimum(-(-tokens // ps), n_table)
+        layers = self.model.kv_layers
+        folds = layers * int((-(-pages // c)).sum())
+        self.read_folds += folds
+        self.read_lanes += folds * c * ps
+        self.read_tokens += layers * int(np.minimum(tokens, pages * ps).sum())
+        self.read_pages_per_fold = c
+
     def paging_snapshot(self) -> dict:
-        """`/debug/engine` "paging": how often the decode block's tail
-        engages. `tokens_per_page_write` is 1.0 where every token rewrites
-        its page, near the block size where a block is flushed once (14-16
-        at blocks of 16, 7-8 while requests wait and the half block
-        runs)."""
-        return {"write": {
-            "tokens": self.write_tokens, "page_writes": self.write_pages,
-            "tokens_per_page_write": (
-                round(self.write_tokens / self.write_pages, 3)
-                if self.write_pages else None)}}
+        """`/debug/engine` "paging". "write": how often the decode block's
+        tail engages. `tokens_per_page_write` is 1.0 where every token
+        rewrites its page, near the block size where a block is flushed
+        once (14-16 at blocks of 16, 7-8 while requests wait and the half
+        block runs). "read": how well the read's folds engage.
+        `pages_per_fold` is C of the last decode block synced, `folds` the
+        read kernel's loop turns (rows x steps x attention layers), and
+        `fold_live_share` the tokens attended in pages over folds x C x
+        page_size: what is left of 1.0 was masked (short rows, ragged
+        last folds and last pages)."""
+        return {
+            "write": {
+                "tokens": self.write_tokens, "page_writes": self.write_pages,
+                "tokens_per_page_write": (
+                    round(self.write_tokens / self.write_pages, 3)
+                    if self.write_pages else None)},
+            "read": {
+                "pages_per_fold": self.read_pages_per_fold,
+                "folds": self.read_folds,
+                "fold_live_share": (
+                    round(self.read_tokens / self.read_lanes, 4)
+                    if self.read_lanes else None)}}
 
     def model_snapshot(self) -> dict:
         """`/debug/engine` "model": the family, the planes of its page
@@ -1928,7 +1971,7 @@ class PagedLLMEngine(LLMEngine):
                                        "tpu.block": block,
                                        "tpu.table_width": n_table})
         self._inflight.append(("decode", out_tokens, snapshot,
-                               block, start, dspan))
+                               block, start, dspan, n_table))
 
     def _reset_device_state(self, exc: BaseException) -> None:
         # slot pages are NOT released individually: _init_device_state

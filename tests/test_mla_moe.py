@@ -37,6 +37,7 @@ from gofr_tpu.ops.flash_attention import (attention_reference,  # noqa: E402
 from gofr_tpu.ops.mla_read import mla_read, mla_read_reference  # noqa: E402
 from gofr_tpu.ops.moe_experts import (decode_experts, experts_reference,  # noqa: E402
                                       prefill_experts)
+from gofr_tpu.ops import paged_attention  # noqa: E402
 from gofr_tpu.ops.paged_attention import (flush_planes, paged_write_window,  # noqa: E402
                                           plane_tail)
 from gofr_tpu.tpu.paging import PagedLLMEngine  # noqa: E402
@@ -294,46 +295,71 @@ def test_the_eight_shares_of_the_expert_layer_add_up_to_the_whole():
             assert np.abs(np.asarray(got.reshape(24, 64) - want)).max() < 1e-5
 
 
-@pytest.mark.parametrize("lengths,tail_lens", [
-    ([37, 0, 16, 5], [3, 0, 1, 4]), ([0, 0, 0, 0], [0, 0, 0, 0]),
-    ([48, 48, 1, 33], [1, 2, 3, 4])])
-def test_mla_read_in_interpret_mode_is_its_oracle(lengths, tail_lens):
+# Pages of 16 tokens. A page this small weighs nothing, so the rule alone
+# would fold a table's width: each case says how many pages a fold is to
+# hold, and the rule is given the weight that makes it so. The rows of the
+# cases at folds of 4 are a fold's edges: exactly C pages, C + 1 (a last
+# fold of one page after a full one), one page, one token, a row of length
+# 0 between two live rows, a row whose pages are still empty, two full
+# folds, a last fold of one page and a token.
+@pytest.mark.parametrize("n_table,fold,lengths,tail_lens", [
+    (3, 2, [37, 0, 16, 5], [3, 0, 1, 4]), (3, 2, [0, 0, 0, 0], [0, 0, 0, 0]),
+    (3, 2, [48, 48, 1, 33], [1, 2, 3, 4]), (3, 1, [37, 0, 16, 5], [3, 0, 1, 4]),
+    (8, 4, [64, 80, 16, 0, 1, 128, 65], [1, 8, 2, 0, 5, 3, 4]),
+    (8, 4, [1, 0, 0, 113, 0, 64, 0], [1, 0, 3, 2, 0, 8, 0]),
+    (8, 4, [128, 17, 0, 96, 49, 0, 4], [8, 1, 0, 7, 2, 0, 3]),
+    (8, 8, [128, 17, 0, 96, 49, 0, 4], [8, 1, 0, 7, 2, 0, 3])])
+def test_mla_read_in_interpret_mode_is_its_oracle(n_table, fold, lengths,
+                                                  tail_lens, monkeypatch):
     """Pages and the block's tail in one softmax, the value the first 32
     of the key's 40 values, all 4 heads on the one latent head; a row that
-    holds no request reads nothing and puts nothing."""
+    holds no request reads nothing and puts nothing. Every page outside
+    the rows' live ranges is NaN, and every table entry past a row's live
+    pages names one: nothing dead is read, and nothing masked reaches the
+    value product."""
     keys = jax.random.split(jax.random.PRNGKey(6), 5)
-    L, B, H, w, r, ps, T = 2, 4, 4, 40, 32, 16, 8
-    pool = jax.random.normal(keys[0], (L, 13, 1, w, ps), jnp.float32)
+    L, B, H, w, r, ps, T = 2, len(lengths), 4, 40, 32, 16, 8
+    monkeypatch.setattr(paged_attention, "_FOLD_BYTES", fold * w * ps * 4)
+    assert paged_attention.pages_per_fold(w * ps * 4, n_table) == fold
+    pool = jax.random.normal(keys[0], (L, 1 + B * n_table, 1, w, ps),
+                             jnp.float32)
     tail = jax.random.normal(keys[1], (L, B, 1, T, 128), jnp.float32)
     q = jax.random.normal(keys[2], (B, H, w), jnp.float32)
     new = jax.random.normal(keys[3], (B, 1, w), jnp.float32)
-    table = jnp.asarray(1 + np.arange(12).reshape(B, 3), jnp.int32)
+    table = 1 + np.arange(B * n_table).reshape(B, n_table)
+    live = np.zeros(pool.shape[1], bool)
+    for b, n in enumerate(lengths):
+        live[table[b, :-(-n // ps)]] = True
+    poisoned = jnp.where(jnp.asarray(live)[None, :, None, None, None], pool,
+                         jnp.nan)
+    table = jnp.asarray(table, jnp.int32)
     lengths, tail_lens = jnp.asarray(lengths), jnp.asarray(tail_lens)
     got, tail_out = jax.jit(lambda *a: mla_read(
         *a, value_width=r, scale=0.2, layer=jnp.int32(1), interpret=True))(
-        q, new, pool, tail, table, lengths, tail_lens)
+        q, new, poisoned, tail, table, lengths, tail_lens)
     # the oracle: the row's pages, then the tail's first tokens with the
     # new one put, laid out as one more run of pages
     put = np.array(tail[1, :, 0, :, :w])
     for b, n in enumerate(np.asarray(tail_lens)):
         if n:
             put[b, n - 1] = np.asarray(new[b, 0])
-    keys_all = np.zeros((B, 4 * ps, w), np.float32)
+    keys_all = np.zeros((B, (n_table + 1) * ps, w), np.float32)
     total = np.asarray(lengths) + np.asarray(tail_lens)
     for b in range(B):
         n = int(lengths[b])
         flat = np.moveaxis(np.asarray(pool[1])[np.asarray(table[b]), 0], 1, 0
-                           ).reshape(w, 3 * ps).T
+                           ).reshape(w, n_table * ps).T
         keys_all[b, :n] = flat[:n]
         keys_all[b, n:n + int(tail_lens[b])] = put[b, :int(tail_lens[b])]
     flat_pool = jnp.asarray(np.moveaxis(
-        keys_all.reshape(B * 4, ps, w), 1, 2)[:, None])
+        keys_all.reshape(B * (n_table + 1), ps, w), 1, 2)[:, None])
     want = mla_read_reference(
-        q, flat_pool, jnp.arange(B * 4, dtype=jnp.int32).reshape(B, 4),
-        jnp.asarray(total), value_width=r, scale=0.2)
+        q, flat_pool, jnp.arange(B * (n_table + 1), dtype=jnp.int32).reshape(
+            B, n_table + 1), jnp.asarray(total), value_width=r, scale=0.2)
     assert got.shape == (B, H, r)
     assert np.abs(np.asarray(got) - np.asarray(want)).max() < 1e-5
     rows = np.asarray(tail_lens) > 0
+    assert not np.asarray(got)[~rows].any()
     assert np.array_equal(np.asarray(tail_out[0]), np.asarray(tail[0]))
     assert np.abs(np.asarray(tail_out[1, :, 0, :, :w])[rows]
                   - put[rows]).max(initial=0.0) < 1e-6
@@ -470,6 +496,36 @@ def test_the_engine_serves_the_family_on_its_normal_path(seeded):
     assert 0 <= routing["held_pick_share"] <= 1
     assert routing["tokens_per_held_expert_max_over_mean"] >= 1
     assert 0 < routing["experts_touched_per_layer_step"] <= 4
+
+
+def test_the_engine_counts_the_folds_its_reads_made(seeded):
+    """`/debug/engine` -> `paging.read` after three decode blocks of one
+    request, then three of another: under the table of 4 these requests
+    take a fold is 4 of the tiny pages; every step of a block, every
+    layer, the read walks the pages of what the block found (its own
+    tokens wait in the tail) in ONE fold of 4 x 16 lanes."""
+    from gofr_tpu.tpu.utilization import engine_snapshot
+
+    _, params = seeded
+    engine = _engine(program_config(), params)
+    assert engine.paging_snapshot()["read"] == {
+        "pages_per_fold": None, "folds": 0, "fold_live_share": None}
+    engine.start()
+    try:
+        for n in (30, 5):
+            # the prefill's token, then three blocks of 4
+            engine.submit(_tokens(n, n), max_new_tokens=13).result(
+                timeout_s=300)
+        read = engine_snapshot(engine)["paging"]["read"]
+    finally:
+        engine.stop()
+    layers, block = 3, 4
+    found = [n + block * k for n in (30, 5) for k in range(3)]
+    assert all(-(-n // 16) <= 4 for n in found)          # one fold each
+    assert read["pages_per_fold"] == 4
+    assert read["folds"] == layers * block * len(found)
+    assert read["fold_live_share"] == round(
+        layers * block * sum(found) / (read["folds"] * 4 * 16), 4)
 
 
 def test_the_two_plane_families_say_k_and_v():

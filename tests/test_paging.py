@@ -17,7 +17,9 @@ import pytest
 from gofr_tpu.models.llama import (LlamaConfig, init_kv_cache,
                                    llama_decode_step, llama_init,
                                    llama_prefill)
+from gofr_tpu.ops import paged_attention as paged_attention_module
 from gofr_tpu.ops.paged_attention import (_write_columns, block_tail,
+                                          fold_of, pages_per_fold,
                                           paged_attention,
                                           paged_attention_in_block,
                                           paged_attention_reference,
@@ -53,23 +55,61 @@ GEOMETRY = {"G2": (4, 2, 32), "G4": (8, 2, 16)}          # H, Hkv, dh
 EDGE_GEOMETRY = {**GEOMETRY, "MQA": (4, 1, 32)}
 
 
-def _paged_case(geometry, dtype, lengths, seed=0):
+# and a table 16 wide, where a turn of the read's loop folds C pages
+# (`pages_per_fold`): the widths the chip's three page shapes take, and 1.
+# Pages this small weigh nothing, so the rule alone would fold a table's
+# width (as it does in the tests above): `_folding` gives it the weight
+# that makes a fold the pages a case names.
+FOLD_TABLE = 16
+FOLD_GEOMETRY = {"Hkv8": (16, 8, 16), "Hkv2": (4, 2, 32), "MQA": (4, 1, 32)}
+FOLD_CASES = [("Hkv8", 1), ("Hkv8", 2), ("Hkv2", 8), ("MQA", 4)]
+
+
+def _folding(monkeypatch, pools, pages):
+    """The rule folds `pages` pages of these pools [P, ...] a turn."""
+    page_bytes = sum(x[0].nbytes for x in pools)
+    monkeypatch.setattr(paged_attention_module, "_FOLD_BYTES",
+                        pages * page_bytes)
+    assert fold_of([x[None] for x in pools], FOLD_TABLE) == pages
+
+
+def _fold_edges(c, ps):
+    """Row lengths at the edges of a fold of c pages: exactly c pages,
+    c + 1 (a last fold of one page after a full one), one page, one token,
+    a row of length 0 between two live rows, a last fold of one token, no
+    row again, two full folds less eleven tokens (room for a block of 8
+    under a table of 2 c pages)."""
+    return [c * ps, (c + 1) * ps, ps, 1, 0, c * ps + 1, 0, 2 * c * ps - 11]
+
+
+def _paged_case(geometry, dtype, lengths, seed=0, n_table=NP_TABLE):
     """q, one layer's pools, a table of DISTINCT pages (page 0 kept as the
     dead entries' target) and the lengths."""
-    H, Hkv, dh = EDGE_GEOMETRY[geometry]
+    H, Hkv, dh = {**EDGE_GEOMETRY, **FOLD_GEOMETRY}[geometry]
     rng = np.random.default_rng(seed)
-    B, n_pool_pages = len(lengths), 40
+    B = len(lengths)
+    n_pool_pages = max(40, 1 + B * n_table)
     q = jnp.asarray(rng.normal(size=(B, H, dh)), dtype=dtype)
     k_pool, v_pool = (
         jnp.asarray(rng.normal(size=(n_pool_pages, Hkv, dh, PS)), dtype=dtype)
         for _ in range(2))
-    table = np.zeros((B, NP_TABLE), np.int32)
+    table = np.zeros((B, n_table), np.int32)
     free = iter(rng.permutation(np.arange(1, n_pool_pages)))
     for b, n in enumerate(lengths):
         for i in range(-(-n // PS)):
             table[b, i] = next(free)
     return (q, k_pool, v_pool, jnp.asarray(table),
             jnp.asarray(lengths, dtype=jnp.int32))
+
+
+def _dead_pages(n_pool_pages, table, lengths, ps):
+    """[P] bool: the pages no live token sits in (page 0, which every dead
+    table entry names, among them)."""
+    live = np.zeros(n_pool_pages, bool)
+    for b, n in enumerate(np.asarray(lengths)):
+        live[np.asarray(table)[b, :-(-int(n) // ps)]] = True
+    assert not live[0]
+    return jnp.asarray(~live)
 
 
 def _in_layer(pool, layer):
@@ -123,6 +163,69 @@ def test_paged_attention_reads_live_pages_only(geometry):
     assert np.isfinite(np.asarray(out)).all()
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
+
+
+def test_the_fold_is_worked_out_from_what_a_call_sees():
+    """`pages_per_fold`: the bytes of a page over the call's pools and the
+    table's width; nothing else. The benchmark's three page shapes, the
+    widths a narrow table leaves, and two buffers of C pages inside a
+    quarter of the kernel's 16 MiB of VMEM."""
+    latent = 1 * 576 * 128 * 2                  # joyai: one plane, bf16
+    nemotron = 2 * 2 * 128 * 128 * 2            # K and V of 2 heads
+    internlm2 = 2 * 8 * 128 * 128 * 2           # K and V of 8 heads
+    assert pages_per_fold(latent, 64) == 8
+    assert pages_per_fold(nemotron, 16) == 8
+    assert pages_per_fold(internlm2, 16) == 2
+    # a fold is never wider than a row can be
+    assert [pages_per_fold(latent, n) for n in (1, 2, 3, 4, 9, 16)] == [
+        1, 2, 2, 4, 8, 8]
+    # nor its two buffers larger than 4 MiB, whatever a page weighs
+    for page_bytes in (1, 1000, latent, 600 << 10, (1 << 20) - 1, 1 << 20,
+                       3 << 20):
+        c = pages_per_fold(page_bytes, 1 << 20)
+        assert c == 1 or 2 * c * page_bytes < 4 << 20
+        assert c * page_bytes >= 1 << 20
+    # from the pools themselves: int8 pages count their scale planes, and
+    # under a tp mesh a shard's bytes are what its kernel sees
+    pool = jnp.zeros((2, 5, 8, 128, 128), jnp.int8)
+    scale = jnp.zeros((2, 5, 8, 128), jnp.float32)
+    assert fold_of([pool, pool, scale, scale], 64) == pages_per_fold(
+        2 * (8 * 128 * 128 + 8 * 128 * 4), 64) == 4
+
+    class TwoShards:
+        shape = {"tp": 2}
+
+    assert fold_of([pool, pool, scale, scale], 64, TwoShards()) == 8
+
+
+@pytest.mark.parametrize("pool", ["f32", "int8"])
+@pytest.mark.parametrize("geometry,c", FOLD_CASES)
+def test_paged_attention_folds_ragged_rows(geometry, c, pool, monkeypatch):
+    """A fold's edges (`_fold_edges`) at folds of 1, 2, 4 and 8 pages,
+    every dead page NaN (the int8 pools': its scales): a short last fold
+    reads no page it does not own, and the lanes it leaves uncopied,
+    masked, do not reach the value product."""
+    q, k, v, table, lens = _paged_case(
+        geometry, jnp.float32, _fold_edges(c, PS), seed=11,
+        n_table=FOLD_TABLE)
+    dead = _dead_pages(k.shape[0], table, lens, PS)
+    if pool == "int8":
+        (k, ks), (v, vs) = quantize_kv(k), quantize_kv(v)
+        scales = [ks, vs]
+        given = [k, v] + [jnp.where(dead[:, None, None], jnp.nan, x)
+                          for x in scales]
+    else:
+        scales = []
+        given = [jnp.where(dead[:, None, None, None], jnp.nan, x)
+                 for x in (k, v)]
+    _folding(monkeypatch, given, c)
+    ref = paged_attention_reference(q, k, v, table, lens, *scales)
+    # its own jit: `_read` keeps a trace by shapes, whatever the fold was
+    out = np.asarray(jax.jit(lambda *a: paged_attention(*a))(
+        q, *given[:2], table, lens, *given[2:]))
+    tol = 5e-2 if pool == "int8" else 2e-5
+    np.testing.assert_allclose(out, np.asarray(ref), rtol=tol, atol=tol)
+    assert not out[np.asarray(lens) == 0].any()
 
 
 def test_decode_step_row_without_request_attends_nothing():
@@ -249,6 +352,56 @@ def test_paged_attention_over_pages_and_tail_matches_reference(t, geometry,
         np.testing.assert_array_equal(got[layer][live], put[layer][live])
         np.testing.assert_array_equal(got[layer][~live], was[layer][~live])
         np.testing.assert_array_equal(got[:layer], was[:layer])
+
+
+@pytest.mark.parametrize("t", [0, 5])
+@pytest.mark.parametrize("geometry,c", [("Hkv8", 2), ("Hkv2", 8),
+                                        ("Hkv2", 1)])
+def test_paged_attention_in_block_folds_ragged_rows(geometry, c, t,
+                                                    monkeypatch):
+    """The read inside a decode block at a fold's edges (`_fold_edges`),
+    pages of 8 tokens under a table 16 wide, step t of a block of 8 with
+    every dead page NaN: against the reference on a pool that had the
+    block's tokens written column by column. The rows of length 0 hold no
+    request."""
+    H, Hkv, dh = FOLD_GEOMETRY[geometry]
+    block, layers = 8, 2
+    starts = _fold_edges(c, PS)
+    rng = np.random.default_rng(13)
+    B, n_pool_pages = len(starts), 1 + len(starts) * FOLD_TABLE
+    k_pool, v_pool = (jnp.asarray(rng.normal(
+        size=(layers, n_pool_pages, Hkv, dh, PS)), jnp.float32)
+        for _ in range(2))
+    _folding(monkeypatch, (k_pool[0], v_pool[0]), c)
+    live = np.asarray(starts) > 0
+    table = np.zeros((B, FOLD_TABLE), np.int32)
+    free = iter(rng.permutation(np.arange(1, n_pool_pages)))
+    for b in np.flatnonzero(live):
+        for i in range((starts[b] + block - 1) // PS + 1):
+            table[b, i] = next(free)
+    table, starts = jnp.asarray(table), jnp.asarray(starts, jnp.int32)
+    news = [jnp.asarray(rng.normal(size=(block, layers, B, Hkv, dh)),
+                        jnp.float32) for _ in range(2)]
+    q = jnp.asarray(rng.normal(size=(B, H, dh)), jnp.float32)
+    live = jnp.asarray(live)
+    k_ref, v_ref = _written_by_columns(k_pool, v_pool, news, table, starts,
+                                       live, t + 1)
+    layer = layers - 1
+    ref = paged_attention_reference(
+        q, k_ref[layer], v_ref[layer], table,
+        jnp.where(live, starts + t + 1, 0))
+    # what the block found in pages: the pages past it are dead, the
+    # block's own among them (its tokens wait in the tail)
+    dead = _dead_pages(n_pool_pages, table, starts, PS)[
+        None, :, None, None, None]
+    out, _, _ = jax.jit(lambda *a, **kw: paged_attention_in_block(*a, **kw))(
+        q, news[0][t, layer], news[1][t, layer],
+        jnp.where(dead, jnp.nan, k_pool), jnp.where(dead, jnp.nan, v_pool),
+        *_tail_of(k_pool, news, t, block), table, jnp.where(live, starts, 0),
+        jnp.where(live, t + 1, 0), layer=jnp.int32(layer))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+    assert not np.asarray(out)[~np.asarray(live)].any()
 
 
 def test_paged_attention_reads_no_tail_of_a_row_without_request():
@@ -521,6 +674,12 @@ def test_every_decode_block_size_serves_the_references_tokens(block):
             <= block / fewest + 1e-3)
     assert sum(r.page_writes for r in records) == write["page_writes"]
     assert all(r.page_writes == 0 for r in records if r.phase != "decode")
+    # and the reads: under the table of 8 these requests take a fold is 8
+    # of the tiny pages, every step of every block, both layers
+    read = paged.paging_snapshot()["read"]
+    assert read["pages_per_fold"] == 8
+    assert read["folds"] >= CFG.n_layers * write["tokens"]
+    assert 0 < read["fold_live_share"] <= 1
 
 
 def test_paged_engine_concurrent_mixed_lengths():
@@ -714,6 +873,24 @@ def test_paged_q8_engine_matches_paged_fp_closely():
     # on a legitimate near-tie flip.
     assert agree / total > 0.3, f"only {agree}/{total} agree"
     assert q8 == serve(cfg_q8)       # deterministic
+
+
+def test_the_int8_engine_counts_its_reads_a_token_longer_each_step():
+    """`paging.read` where there is no block's tail: step t of a block
+    attends what the block found and the t + 1 tokens written since, all
+    in pages (tiny pages under a table of 8: one fold of 8 x 16 lanes)."""
+    import dataclasses
+
+    engine = PagedLLMEngine(
+        llama_init(CFG, seed=0), dataclasses.replace(CFG, kv_dtype="int8"),
+        page_size=16, n_slots=4, max_seq_len=128, prefill_buckets=(8, 64),
+        decode_block_size=4)
+    engine.slots[2].length = 60
+    engine._note_page_reads([(2, None)], 4, 8)
+    read = engine.paging_snapshot()["read"]
+    assert read == {"pages_per_fold": 8, "folds": CFG.n_layers * 4,
+                    "fold_live_share": round(
+                        (61 + 62 + 63 + 64) / (4 * 8 * 16), 4)}
 
 
 def test_quantize_kv_roundtrip_error_bounded():

@@ -303,7 +303,7 @@ def _stubbed_read(kinds, budget=64, **kw):
     live = list(enumerate(reqs))
     entries = {
         "decode": ("decode", np.full((eng.n_slots, 4), 7, np.int32), live,
-                   4, time.monotonic(), None),
+                   4, time.monotonic(), None, 4),     # under a table of 4
         "prefill": ("prefill", np.full((eng.n_slots,), 7, np.int32), live,
                     None, time.monotonic()),
     }

@@ -13,6 +13,11 @@ of about 480 tokens, as `decode-closed` holds) and `chat` (50 live rows and
 live pages, and the largest error against `paged_attention_reference` on
 one layer.
 
+The width of a fold (PR 32: `pages_per_fold`, the pages one turn of the
+read's loop folds) is the module's own rule unless a line says otherwise:
+the tool times other widths by standing in for the rule in the module it
+loaded, which nothing that serves can do.
+
 Then the decode block's tail (PR 28), for every module that has one, at
 two geometries (`internlm2`: 8 KV heads x 2 queries, 24 layers; `nemotron`:
 2 KV heads x 16 queries, 2 layers of attention) under the `closed` table,
@@ -23,7 +28,17 @@ reference, not the serving path), `flush` (one flush of all layers, a
 layer) and, for comparison, `write` (the per-token page write a call,
 which the int8 pools and the verify window still run). `read+tail` is
 checked against the reference on a layer that had the tail's tokens
-written column by column, `flush` against those columns, exactly.
+written column by column, `flush` against those columns, exactly. The
+read is timed at folds of one page as well, at `nemotron` also of 4.
+
+Then the latent read (PR 32: `mla_read`, the same kernel body on one pool)
+at the geometry of `joyai-llm-flash-ep8.longprompt-closed`: 12 layers x
+4,600 pages of 1 x 576 x 128, 128 rows of 32 queries, a table 64 wide, 122
+live rows of 2,560-5,120 tokens, block 16; microseconds a call (the mean
+over the block's 16 steps x 12 layers) at folds of 1, 2, 4, 8 and 16 pages
+and at the rule's own, the share of the device's peak bytes/s over the whole
+live pages, and the largest error against `mla_read_reference` on a layer
+that had the tail's tokens written column by column.
 
 It is not the benchmark: it says what a kernel costs alone, never what a
 cell gains (PERF.md section 5 keeps its table). It refuses a device that
@@ -31,6 +46,7 @@ is not in the benchmark's table of peaks: a CPU timing of the interpreter
 is no kernel time.
 """
 
+import contextlib
 import importlib.util
 import json
 import os
@@ -44,6 +60,7 @@ import numpy as np
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path[:0] = [ROOT, os.path.join(ROOT, "benchmark")]
 import gofr_tpu.ops.paged_attention  # noqa: E402
+from gofr_tpu.ops.mla_read import mla_read_reference  # noqa: E402
 from harness import peaks  # noqa: E402  (the one table of peaks)
 
 L, P, HKV, DH, PS, B, NP, H = 24, 769, 8, 128, 128, 96, 16, 16
@@ -63,18 +80,24 @@ def load(label: str, path: str):
 
 def table_of(name: str, rng, room: int = 0, n_pool_pages: int = P):
     """(table [B, NP], lengths [B], whole live pages). `room`: tokens each
-    live row's pages must still take past its length (a decode block)."""
+    live row's pages must still take past its length (a decode block). A
+    row of length 0 or 1 holds no request: its table is all page 0."""
+    rows, width = B, NP
     if name == "closed":
         lengths = rng.integers(60, 900, size=B)
         lengths[5] = 1152
         lengths[[17, 40, 77]] = 1
+    elif name == "longprompt":
+        rows, width = LATENT["rows"], LATENT["table"]
+        lengths = rng.integers(2560, 5120 - room, size=rows)
+        lengths[[17, 40, 77, 90, 101, 127]] = 0
     else:
         lengths = np.ones(B, np.int64)
         live = rng.permutation(B)[:50]
         lengths[live] = rng.integers(40, 700, size=50)
         lengths[live[0]] = 1100
     n_pages = -(-lengths // PS)
-    table = np.zeros((B, NP), np.int32)
+    table = np.zeros((rows, width), np.int32)
     free = list(rng.permutation(np.arange(1, n_pool_pages)))
     for b in np.flatnonzero(lengths > 1):
         held = -(-(lengths[b] + room) // PS)
@@ -136,6 +159,29 @@ def time_one(module, args) -> float:
 # -- the decode block's tail ----------------------------------------------------
 BLOCK = 16
 GEOMETRIES = {"internlm2": (24, 769, 8, 16), "nemotron": (2, 961, 2, 32)}
+# fold widths timed beside the rule's own (None), where the rule might
+# have chosen otherwise
+FOLDS = {"internlm2": (1, None), "nemotron": (1, 4, None)}
+LATENT = {"layers": 12, "pages": 4600, "width": 576, "value_width": 512,
+          "rows": 128, "heads": 32, "table": 64, "scale": 192 ** -0.5,
+          "folds": (1, 2, 4, 8, 16, None)}
+
+
+@contextlib.contextmanager
+def folding(module, pages, pools, width: int):
+    """Inside, `module`'s reads fold `pages` pages a turn whatever its rule
+    says (None: as the rule has it; a module from before PR 32 folds one).
+    Yields what a read of `pools` under a table `width` wide then folds."""
+    rule = getattr(module, "pages_per_fold", None)
+    if rule is None:
+        yield 1
+        return
+    if pages:
+        module.pages_per_fold = lambda *_: pages
+    try:
+        yield pages or module.fold_of(pools, width)
+    finally:
+        module.pages_per_fold = rule
 
 
 def tail_lines(label: str, module, geometry: str, device) -> None:
@@ -215,10 +261,16 @@ def tail_lines(label: str, module, geometry: str, device) -> None:
             *filled(*module.block_tail(k_pool, B, BLOCK), last, BLOCK - 1),
             table, paged, counts, layer=jnp.int32(last))[0]
 
-    got = jax.jit(last_step)(q, k_pool, v_pool)
     line("tail_put", steps(read=False))
-    line("read+tail", steps(read=True), max_abs_err=float(np.max(np.abs(
-        np.asarray(got, np.float32) - np.asarray(want)))))
+    for pages in FOLDS[geometry]:
+        if pages and not hasattr(module, "pages_per_fold"):
+            continue
+        with folding(module, pages, (k_pool, v_pool), NP) as folded:
+            # a new function a width: jit keeps its traces by function
+            got = jax.jit(lambda *a: last_step(*a))(q, k_pool, v_pool)
+            line("read+tail", steps(read=True), pages_per_fold=folded,
+                 by_rule=pages is None, max_abs_err=float(np.max(np.abs(
+                     np.asarray(got, np.float32) - np.asarray(want)))))
 
     def write(k_pool, v_pool):
         def layer(l, pools):
@@ -254,6 +306,90 @@ def tail_lines(label: str, module, geometry: str, device) -> None:
     line("flush", seconds / (layers * STEPS) * 1e6, equals_columns=exact)
 
 
+def latent_lines(label: str, module, device, peak_bytes_s: float) -> None:
+    """`mla_read` at the `longprompt-closed` cell's geometry: one JSON line
+    a fold width (see the module docstring). The call is `mla_read`'s own
+    (ops/mla_read.py), made on `module`'s `_paged_read`."""
+    g = LATENT
+    layers, rows, w, r = g["layers"], g["rows"], g["width"], g["value_width"]
+    keys = jax.random.split(jax.random.PRNGKey(3), 3)
+    draw = lambda k, shape: jax.random.normal(           # noqa: E731
+        k, shape, jnp.float32).astype(jnp.bfloat16)
+    one = draw(keys[0], (g["pages"], 1, w, PS))
+    q = draw(keys[1], (rows, g["heads"], w))
+    news = draw(keys[2], (BLOCK, rows, 1, w))
+    table, starts, live_pages = table_of(
+        "longprompt", np.random.default_rng(1), room=BLOCK,
+        n_pool_pages=g["pages"])
+    live = table[:, 0] > 0
+    floor_us = live_pages * w * PS * one.dtype.itemsize / peak_bytes_s * 1e6
+
+    # what one layer holds once the block's tokens are written by columns,
+    # and the reference's read of it (16 rows at a time: the oracle
+    # gathers every row's whole table in float32), before the stack is made
+    last = layers - 1
+    want_pool = one
+    for t in range(BLOCK):
+        want_pool = module._write_columns([want_pool[None]], [news[t]],
+                                          table, starts + t, 0)[0][0]
+    oracle = jax.jit(lambda *a: mla_read_reference(
+        *a, value_width=r, scale=g["scale"]))
+    after = jnp.where(live, starts + BLOCK, 0)
+    want = np.concatenate([np.asarray(oracle(
+        q[i:i + 16].astype(jnp.float32), want_pool, table[i:i + 16],
+        after[i:i + 16])) for i in range(0, rows, 16)])
+    del want_pool
+    pool = jax.jit(lambda x: jnp.tile(x[None], (layers, 1, 1, 1, 1)))(one)
+
+    def read(q, new, pool, tail, tail_lens, layer):
+        return module._paged_read(
+            q, [pool], table, starts, (new, tail, tail_lens), layer, None,
+            None, value_width=r, scale=g["scale"], scope="mla_read")
+
+    def run(q, pool):
+        def layer(l, carry):
+            t, acc, tail = carry
+            out, tail = read(q, news[t], pool, tail,
+                             jnp.where(live, t + 1, 0), l)
+            return t, acc + out.astype(jnp.float32), tail
+
+        acc, tail = jax.lax.fori_loop(
+            0, BLOCK, lambda t, carry: jax.lax.fori_loop(
+                0, layers, layer, (t,) + carry)[1:],
+            (jnp.zeros((rows, g["heads"], r), jnp.float32),
+             module.plane_tail(pool, rows, BLOCK)))
+        return acc + tail[0, :, 0, 0, :1][:, :, None]
+
+    def last_step(q, pool):
+        tail = module.plane_tail(pool, rows, BLOCK)
+        for t in range(BLOCK - 1):
+            tail = jax.lax.dynamic_update_slice(
+                tail, jnp.pad(news[t], ((0, 0), (0, 0), (
+                    0, tail.shape[-1] - w)))[None, :, :, None],
+                (last, 0, 0, t, 0))
+        return read(q, news[-1], pool, tail, jnp.where(live, BLOCK, 0),
+                    jnp.int32(last))[0]
+
+    for pages in g["folds"]:
+        if pages and not hasattr(module, "pages_per_fold"):
+            continue
+        with folding(module, pages, (pool,), g["table"]) as folded:
+            # a new function a width: jit keeps its traces by function
+            got = jax.jit(lambda *a: last_step(*a))(q, pool)
+            us = (best_of_five(jax.jit(lambda *a: run(*a)), q, pool)
+                  / (layers * BLOCK) * 1e6)
+        print(json.dumps({
+            "device": device.device_kind, "kernel": label,
+            "what": "mla_read", "geometry": "latent",
+            "rows": int(live.sum()), "live_pages": live_pages,
+            "block": BLOCK, "pages_per_fold": folded,
+            "by_rule": pages is None, "us": round(us, 1),
+            "whole_pages_share_of_peak_pct": round(100 * floor_us / us, 1),
+            "max_abs_err": float(np.max(np.abs(
+                np.asarray(got, np.float32) - np.asarray(want))))}),
+            flush=True)
+
+
 def main(argv) -> None:
     device = jax.devices()[0]
     peak_bytes_s = peaks.of(device.device_kind)["hbm_bytes_per_s"]
@@ -280,6 +416,9 @@ def main(argv) -> None:
                     "device": device.device_kind, "kernel": label,
                     "pool": str(jnp.dtype(dtype)), "table": name,
                     "live_pages": pages, "us_per_call": round(us, 1),
+                    "pages_per_fold": (
+                        module.fold_of((k_pool, v_pool, *scales), NP)
+                        if hasattr(module, "fold_of") else 1),
                     "whole_pages_share_of_peak_pct":
                         round(100 * floor_us / us, 1),
                     "max_abs_err": float(np.max(np.abs(
@@ -289,6 +428,9 @@ def main(argv) -> None:
         if hasattr(module, "block_tail"):
             for geometry in GEOMETRIES:
                 tail_lines(label, module, geometry, device)
+    for label, module in kernels.items():
+        if hasattr(module, "plane_tail"):
+            latent_lines(label, module, device, peak_bytes_s)
 
 
 if __name__ == "__main__":
