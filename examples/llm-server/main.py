@@ -5,8 +5,9 @@ POST /generate {"prompt": "...", "max_tokens": 64, "temperature": 0.7,
 {"done": true} summary. stream=false returns one JSON response.
 
 Model size comes from MODEL_PRESET (debug | llama1b | llama3-8b, the
-nemotron_h family's nemotron-h-debug | nemotron-3-nano-30b-a3b-ep2, and the
-mla_moe family's mla-moe-debug | joyai-llm-flash-ep8). Weights
+nemotron_h family's nemotron-h-debug | nemotron-3-nano-30b-a3b-ep2, the
+mla_moe family's mla-moe-debug | joyai-llm-flash-ep8, and the afmoe family's
+afmoe-debug | trinity-large-preview-ep8). Weights
 boot from a real HF-layout safetensors checkpoint when WEIGHTS_PATH is set
 (models.weights.load_llama_safetensors — streaming, int8 quantize-on-load);
 otherwise random-initialised (no checkpoints ship in this environment) with
@@ -22,6 +23,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", ".."))
 
 from gofr_tpu import App, Stream  # noqa: E402
 from gofr_tpu.http.errors import InvalidParam, ServiceUnavailable  # noqa: E402
+from gofr_tpu.models.afmoe import AfmoeConfig, afmoe_init  # noqa: E402
 from gofr_tpu.models.llama import LlamaConfig, llama_init  # noqa: E402
 from gofr_tpu.models.mla_moe import MlaMoeConfig, mla_moe_init  # noqa: E402
 from gofr_tpu.models.nemotron_h import (NemotronHConfig,  # noqa: E402
@@ -49,11 +51,19 @@ PRESETS = {
     # one chip's share of JoyAI-LLM-Flash, as the benchmark runs it
     # (benchmark/configs/joyai-llm-flash-ep8.json)
     "joyai-llm-flash-ep8": MlaMoeConfig.joyai_llm_flash_ep8,
+    # the afmoe family: window and full attention blocks in one stack, a
+    # page group each in the pool (the window blocks' a ring), gated
+    # attention with normed queries and keys, gated sparse experts
+    "afmoe-debug": AfmoeConfig.debug,
+    # one chip's share of Trinity-Large-Preview, as the benchmark runs it
+    # (benchmark/configs/trinity-large-preview-ep8.json)
+    "trinity-large-preview-ep8": AfmoeConfig.trinity_large_preview_ep8,
 }
 
 # the families that boot from seeded weights only: no checkpoint loader and
 # no int8 weight path yet
-SEEDED_ONLY = {NemotronHConfig: nemotron_h_init, MlaMoeConfig: mla_moe_init}
+SEEDED_ONLY = {NemotronHConfig: nemotron_h_init, MlaMoeConfig: mla_moe_init,
+               AfmoeConfig: afmoe_init}
 
 
 def _load_tokenizer(path: str):

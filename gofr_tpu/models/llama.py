@@ -63,7 +63,7 @@ class LlamaConfig:
     def paged_model(self):
         """The paged engine's view of this family (models/protocol.py):
         the paged floating-point path, unchanged in arithmetic."""
-        from .protocol import PagedModel, kv_planes
+        from .protocol import PagedModel, kv_planes, one_group
 
         def prefill(params, tokens, lengths, mesh=None):
             last, k, v, rows = llama_prefill_paged(params, self, tokens,
@@ -73,7 +73,7 @@ class LlamaConfig:
         return PagedModel(
             family="llama_like", program_tag="llama",
             planes=kv_planes(self.n_kv_heads, self.head_dim),
-            kv_layers=self.n_layers, state_shapes=lambda slots: (),
+            groups=one_group(self.n_layers), state_shapes=lambda slots: (),
             prefill=prefill,
             decode=lambda params, tokens, positions, pools, table, state,
             tail, step, mesh=None: (*llama_decode_step_paged(

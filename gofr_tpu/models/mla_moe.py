@@ -171,7 +171,7 @@ class MlaMoeConfig:
                 + self.dim * self.vocab_size)
 
     def paged_model(self):
-        from .protocol import PagedModel, Plane
+        from .protocol import PagedModel, Plane, one_group
 
         def paged_prefill(params, tokens, lengths, mesh=None):
             last, latent = prefill(params, self, tokens, lengths)
@@ -187,7 +187,7 @@ class MlaMoeConfig:
         return PagedModel(
             family="mla_moe", program_tag="mla-moe",
             planes=(Plane("latent", 1, self.latent_dim),),
-            kv_layers=self.n_layers, state_shapes=lambda slots: (),
+            groups=one_group(self.n_layers), state_shapes=lambda slots: (),
             prefill=paged_prefill, decode=paged_decode, counters=COUNTERS,
             describe=lambda counts, steps: describe(self, counts, steps),
             refuses=REFUSES)

@@ -189,7 +189,7 @@ class NemotronHConfig:
                 + self.dim * self.vocab_size)
 
     def paged_model(self):
-        from .protocol import PagedModel, kv_planes
+        from .protocol import PagedModel, kv_planes, one_group
 
         def paged_prefill(params, tokens, lengths, mesh=None):
             last, k, v, rows = prefill(params, self, tokens, lengths)
@@ -198,7 +198,7 @@ class NemotronHConfig:
         return PagedModel(
             family="nemotron_h", program_tag="nemotron-h",
             planes=kv_planes(self.n_kv_heads, self.head_dim),
-            kv_layers=self.kv_layers,
+            groups=one_group(self.kv_layers),
             state_shapes=lambda slots: state_shapes(self, slots),
             prefill=paged_prefill,
             decode=lambda params, tokens, positions, pools, table, state,

@@ -14,19 +14,35 @@ it:
   llama_like and nemotron_h families); mla_moe keeps ONE plane of 1 x 576,
   a token's normed latent and its rotated shared key. What is said of
   "the pools" below is a tuple in this order.
-- `kv_layers`: how many blocks keep pages: the pools' leading axis. A page
-  id spans these blocks, not all blocks.
+- `groups`: the blocks that keep pages, in PAGE GROUPS, a `PageGroup(name,
+  layers, window)` each: blocks that share a table, an allocator and a
+  reservation. A page id spans its group's blocks. `window` None, a
+  sequence keeps every token (`pages_for(prompt + max_new)` pages); a
+  window of W tokens, a block attends the last W only and a sequence keeps
+  a RING of `ring(page_size)` pages, the token at position p in ring column
+  `(p // page_size) % ring` (tpu/paging.py). Every group holds the
+  family's `planes`; the engine keeps one pool a plane a group,
+  [group layers, pages, heads, width, page_size], group by group.
+  `one_group(n)` is what a family whose blocks all keep every token
+  answers (llama_like, nemotron_h, mla_moe); afmoe answers `full` and
+  `window`. `kv_layers` (the blocks that keep pages, all groups) and
+  `token_values` follow from the groups.
 - `state_shapes(slots)`: ((shape, dtype), ...) of the arrays a sequence
   holds BESIDE its pages, fixed in size, the slot axis second
   ([layers, slots, ...]); () for a model whose only cached state is pages.
 - `prefill(params, tokens [K, bucket], lengths [K], mesh)` from an empty
   state -> (last real position's logits [K, V] float32, a window
-  [kv_layers, K, heads, width, bucket] a plane for the page writer, one
-  [layers, K, ...] array for each of `state_shapes`: the state as of each
-  row's last real token). The engine scatters pages and slot states.
+  [group layers, K, heads, width, bucket] a plane a group (group-major,
+  as the pools lie) for the page writer, one [layers, K, ...] array for
+  each of `state_shapes`: the state as of each row's last real token).
+  The engine scatters pages (a window group's last ring of the prompt
+  only) and slot states.
 - `decode(params, tokens [B], positions [B], pools, table, state, tail,
   step, mesh)` -> (logits [B, V] float32, tail, state, counters): one
-  token a row, step `step` (int32) of a decode block. The pools are READ
+  token a row, step `step` (int32) of a decode block. A family of one
+  group takes its pools, its table and its tail as they are; a family of
+  several takes `pools` and `tail` group-major and `table` a tuple, one
+  a group. The pools are READ
   ONLY here, as the block found them: what the token must keep goes into
   the block's `tail`, one a plane (ops/paged_attention `plane_tail`: the
   engine makes it when the block begins and flushes it into the pages
@@ -47,14 +63,16 @@ it:
 
 `models/llama.py` (pages only, no state, no counters, refuses nothing),
 `models/nemotron_h.py` (pages for 6 blocks in 52, a recurrent state and a
-convolution tail a slot, expert counters) and `models/mla_moe.py` (one
-latent plane a page, expert counters) are the three families.
+convolution tail a slot, expert counters), `models/mla_moe.py` (one
+latent plane a page, expert counters) and `models/afmoe.py` (window and
+full attention blocks, a page group each, expert counters) are the four
+families.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Dict, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 
 @dataclasses.dataclass(frozen=True)
@@ -70,12 +88,40 @@ def kv_planes(heads: int, width: int) -> Tuple[Plane, Plane]:
     return Plane("k", heads, width), Plane("v", heads, width)
 
 
+def ring_pages(window: int, page_size: int) -> int:
+    """Pages a sequence holds at most in a group whose blocks attend the
+    last `window` tokens: the window, the page it starts inside, and the
+    page a decode block (at most a page of steps) may cross into."""
+    return -(-window // page_size) + 2
+
+
+@dataclasses.dataclass(frozen=True)
+class PageGroup:
+    """Blocks that share a table, an allocator and a reservation.
+    `window` None: a sequence keeps every token; W: its last W."""
+    name: str
+    layers: int
+    window: Optional[int] = None
+
+    def ring(self, page_size: int) -> Optional[int]:
+        """`ring_pages` of the group's window; None for a group without
+        one."""
+        if self.window is None:
+            return None
+        return ring_pages(self.window, page_size)
+
+
+def one_group(layers: int) -> Tuple[PageGroup, ...]:
+    """The groups of a family whose blocks all keep every token."""
+    return (PageGroup("pages", layers),)
+
+
 @dataclasses.dataclass(frozen=True)
 class PagedModel:
     family: str
     program_tag: str            # leads the step programs' names
     planes: Tuple[Plane, ...]
-    kv_layers: int
+    groups: Tuple[PageGroup, ...]
     state_shapes: Callable[[int], Tuple]
     prefill: Callable
     decode: Callable
@@ -85,9 +131,29 @@ class PagedModel:
         lambda counts, steps: {})
 
     @property
+    def kv_layers(self) -> int:
+        """Blocks that keep pages, all groups."""
+        return sum(group.layers for group in self.groups)
+
+    @property
+    def plane_values(self) -> int:
+        """Values a token keeps in ONE block's pages, all planes."""
+        return sum(p.heads * p.width for p in self.planes)
+
+    @property
     def token_values(self) -> int:
-        """Values a token keeps in pages, all planes and blocks."""
-        return self.kv_layers * sum(p.heads * p.width for p in self.planes)
+        """Values a token keeps in pages, all planes and blocks, while
+        every group still holds it."""
+        return self.kv_layers * self.plane_values
+
+    def sequence_values(self, tokens: int, page_size: int = 128) -> int:
+        """Values a sequence of `tokens` keeps in pages: every token in a
+        group without a window, at most the ring in a window group."""
+        held = sum(group.layers * (
+            tokens if group.window is None
+            else min(tokens, group.ring(page_size) * page_size))
+            for group in self.groups)
+        return held * self.plane_values
 
     def refuse(self, asked: Dict[str, Any]) -> None:
         """Raise for the first feature in `asked` ({feature: the value the

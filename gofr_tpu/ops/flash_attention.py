@@ -24,6 +24,12 @@ Design notes (pallas_guide.md):
     resident kernel bounds its fori_loop, the streaming kernel predicates
     compute with pl.when (the block fetch still occurs there; block
     scheduling is static).
+  - a window (`window=W`, causal): query i sees keys (i - W, i], W with
+    its own. kv blocks wholly behind the window are skipped as those above
+    the diagonal are: the resident kernel starts its fori_loop at the
+    first block a row of the q block can see, the streaming kernel
+    predicates compute and its K/V index maps stay on the nearest block
+    inside the band, so a skipped step fetches nothing new.
   - padding is static: wrappers pad T/S to block multiples at trace time and
     the mask closes over the true lengths as Python ints — no SMEM scalars,
     no dynamic shapes.
@@ -61,12 +67,17 @@ def _ceil_to(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
 
 
-# K+V bytes per head above which the streaming kernel takes over
+# K+V bytes per head from which the streaming kernel takes over: the
+# resident kernel holds them twice (double-buffered) beside its q, output and
+# score blocks, in the 16 MiB of VMEM the compiler gives a kernel unasked. At
+# exactly 6 MiB (12,288 keys of 128 + 128 in bfloat16, blocks of 512) that
+# came to 16.14 MiB and the chip's compiler refused the kernel
 VMEM_KV_BUDGET_BYTES = 6 * 1024 * 1024
 
 
 def _kernel_resident(q_ref, k_ref, v_ref, o_ref, *, causal: bool,
-                     kv_len: int, block_kv: int, scale: float):
+                     kv_len: int, block_kv: int, scale: float,
+                     window: Optional[int] = None):
     """K/V whole-sequence resident in VMEM; fori_loop over kv blocks."""
     from jax.experimental import pallas as pl
 
@@ -82,6 +93,9 @@ def _kernel_resident(q_ref, k_ref, v_ref, o_ref, *, causal: bool,
         hi = jnp.minimum((i * block_q + block_q + block_kv - 1) // block_kv, n_kv)
     else:
         hi = n_kv
+    # lowest kv block any row of this q block can see
+    lo = 0 if window is None else jnp.maximum(
+        i * block_q - window + 1, 0) // block_kv
 
     def body(j, carry):
         m, l, acc = carry
@@ -94,9 +108,14 @@ def _kernel_resident(q_ref, k_ref, v_ref, o_ref, *, causal: bool,
         mask = kv_pos < kv_len
         if causal:
             mask = jnp.logical_and(mask, kv_pos <= q_pos)
+        if window is not None:
+            mask = jnp.logical_and(mask, kv_pos > q_pos - window)
         s = jnp.where(mask, s, DEFAULT_MASK_VALUE)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
+        if window is not None:
+            # a row may see nothing of the first block of its band
+            p = jnp.where(mask, p, 0.0)
         alpha = jnp.exp(m - m_new)
         l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
         pv = jax.lax.dot_general(p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
@@ -106,12 +125,13 @@ def _kernel_resident(q_ref, k_ref, v_ref, o_ref, *, causal: bool,
     m0 = jnp.full((block_q, 1), DEFAULT_MASK_VALUE, jnp.float32)
     l0 = jnp.zeros((block_q, 1), jnp.float32)
     acc0 = jnp.zeros((block_q, dv), jnp.float32)
-    _, l, acc = jax.lax.fori_loop(0, hi, body, (m0, l0, acc0))
+    _, l, acc = jax.lax.fori_loop(lo, hi, body, (m0, l0, acc0))
     o_ref[0, 0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
 def _kernel_streaming(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
-                      causal: bool, kv_len: int, block_kv: int, scale: float):
+                      causal: bool, kv_len: int, block_kv: int, scale: float,
+                      window: Optional[int] = None):
     from jax.experimental import pallas as pl
 
     block_q = q_ref.shape[2]
@@ -139,10 +159,14 @@ def _kernel_streaming(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
             q_pos = i * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_kv), 0)
             mask = jnp.logical_and(mask, kv_pos <= q_pos)
+            if window is not None:
+                mask = jnp.logical_and(mask, kv_pos > q_pos - window)
         s = jnp.where(mask, s, DEFAULT_MASK_VALUE)
         m_prev = m_scr[:]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))  # [bq,1]
         p = jnp.exp(s - m_new)
+        if window is not None:
+            p = jnp.where(mask, p, 0.0)
         alpha = jnp.exp(m_prev - m_new)
         m_scr[:] = m_new
         l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
@@ -150,7 +174,14 @@ def _kernel_streaming(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
                                  preferred_element_type=jnp.float32)
         acc_scr[:] = acc_scr[:] * alpha + pv
 
-    if causal:
+    if causal and window is not None:
+        # skip kv blocks fully above the diagonal or wholly behind the window
+        @pl.when(jnp.logical_and(
+            j * block_kv <= i * block_q + block_q - 1,
+            j * block_kv + block_kv - 1 > i * block_q - window))
+        def _():
+            compute()
+    elif causal:
         # skip kv blocks fully above the diagonal
         @pl.when(j * block_kv <= i * block_q + block_q - 1)
         def _():
@@ -164,7 +195,7 @@ def _kernel_streaming(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 
 def _flash_bhtd(q, k, v, *, causal: bool, block_q: int, block_kv: int,
-                interpret: Optional[bool]):
+                interpret: Optional[bool], window: Optional[int] = None):
     """Core call on [B, H, T, dh] q, [B, Hkv, S, dh] k and [B, Hkv, S, dv]
     v layouts."""
     from jax.experimental import pallas as pl
@@ -185,11 +216,14 @@ def _flash_bhtd(q, k, v, *, causal: bool, block_q: int, block_kv: int,
         k = jnp.pad(k, ((0, 0), (0, 0), (0, Sp - S), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, 0), (0, Sp - S), (0, 0)))
 
-    resident = Sp * (dh + dv) * q.dtype.itemsize <= VMEM_KV_BUDGET_BYTES
+    # `window` reaches the kernels only where it is given: a call without
+    # one is the program it was
+    windowed = {} if window is None else {"window": window}
+    resident = Sp * (dh + dv) * q.dtype.itemsize < VMEM_KV_BUDGET_BYTES
     if resident:
         kernel = functools.partial(
             _kernel_resident, causal=causal, kv_len=S, block_kv=block_kv,
-            scale=1.0 / math.sqrt(dh))
+            scale=1.0 / math.sqrt(dh), **windowed)
         with kernel_scope("flash_prefill"):
             out = pl.pallas_call(
                 kernel,
@@ -211,7 +245,17 @@ def _flash_bhtd(q, k, v, *, causal: bool, block_q: int, block_kv: int,
 
     kernel = functools.partial(
         _kernel_streaming, causal=causal, kv_len=S, block_kv=block_kv,
-        scale=1.0 / math.sqrt(dh))
+        scale=1.0 / math.sqrt(dh), **windowed)
+
+    def kv_block(i, j):
+        """The kv block step (i, j) fetches: j, or under a window the
+        nearest block inside q block i's band (a step outside it computes
+        nothing, and a block index that repeats is not fetched again)."""
+        if window is None:
+            return j
+        lo = jnp.maximum(i * block_q - window + 1, 0) // block_kv
+        hi = (i * block_q + block_q - 1) // block_kv
+        return jnp.clip(j, lo, hi)
     with kernel_scope("flash_prefill"):
         out = pl.pallas_call(
             kernel,
@@ -219,9 +263,11 @@ def _flash_bhtd(q, k, v, *, causal: bool, block_q: int, block_kv: int,
             in_specs=[
                 pl.BlockSpec((1, 1, block_q, dh), lambda b, h, i, j: (b, h, i, 0),
                              memory_space=pltpu.VMEM),
-                pl.BlockSpec((1, 1, block_kv, dh), lambda b, h, i, j: (b, h // G, j, 0),
+                pl.BlockSpec((1, 1, block_kv, dh),
+                             lambda b, h, i, j: (b, h // G, kv_block(i, j), 0),
                              memory_space=pltpu.VMEM),
-                pl.BlockSpec((1, 1, block_kv, dv), lambda b, h, i, j: (b, h // G, j, 0),
+                pl.BlockSpec((1, 1, block_kv, dv),
+                             lambda b, h, i, j: (b, h // G, kv_block(i, j), 0),
                              memory_space=pltpu.VMEM),
             ],
             out_specs=pl.BlockSpec((1, 1, block_q, dv), lambda b, h, i, j: (b, h, i, 0),
@@ -237,18 +283,23 @@ def _flash_bhtd(q, k, v, *, causal: bool, block_q: int, block_kv: int,
     return out[:, :, :T, :]
 
 
-def attention_reference(q, k, v, *, causal: bool = True):
+def attention_reference(q, k, v, *, causal: bool = True,
+                        window: Optional[int] = None):
     """Unblocked GQA attention in f32 — the numerics oracle and the recompute
     target for the backward pass. Layout [B, T, H, dh] / [B, S, Hkv, dh]
     (v and the output [.., dv]).
-    When T < S under causal, queries are the LAST T positions."""
+    When T < S under causal, queries are the LAST T positions. `window` W
+    (causal): query i sees keys (i - W, i]."""
     B, T, H, dh = q.shape
     S, Hkv = k.shape[1], k.shape[2]
     G = H // Hkv
     qg = q.reshape(B, T, Hkv, G, dh).astype(jnp.float32)
     s = jnp.einsum("bthgd,bshd->bhgts", qg, k.astype(jnp.float32)) / math.sqrt(dh)
     if causal:
-        mask = jnp.arange(S)[None, :] <= jnp.arange(T)[:, None] + (S - T)
+        at = jnp.arange(T)[:, None] + (S - T)
+        mask = jnp.arange(S)[None, :] <= at
+        if window is not None:
+            mask = jnp.logical_and(mask, jnp.arange(S)[None, :] > at - window)
         s = jnp.where(mask[None, None, None, :, :], s, DEFAULT_MASK_VALUE)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhgts,bshd->bthgd", p, v.astype(jnp.float32))
@@ -257,16 +308,20 @@ def attention_reference(q, k, v, *, causal: bool = True):
 
 def flash_attention(q, k, v, causal: bool = True, block_q: int = 128,
                     block_kv: int = 128, interpret: Optional[bool] = None,
-                    *, mesh=None):
+                    *, mesh=None, window: Optional[int] = None):
     """Flash attention on [B, T, H, dh] q, [B, S, Hkv, dh] k and
     [B, S, Hkv, dv] v (GQA folds query head h onto kv head h // (H // Hkv)).
-    Returns [B, T, H, dv] in q.dtype.
+    Returns [B, T, H, dv] in q.dtype. `window` W (causal only): query i
+    sees keys (i - W, i]; kv blocks wholly outside are skipped.
 
     mesh: the serving mesh when q/k/v are sharded over its "tp" axis on
     their head dims (column-parallel wq/wk/wv); each shard then runs the
     kernel on its own heads under shard_map, as in ops/paged_attention."""
+    if window is not None and not causal:
+        raise ValueError("a window is a causal mask's")
     local = functools.partial(_flash_local, causal=causal, block_q=block_q,
-                              block_kv=block_kv, interpret=interpret)
+                              block_kv=block_kv, interpret=interpret,
+                              window=window)
     if mesh is not None and mesh.shape.get("tp", 1) > 1:
         from jax.sharding import PartitionSpec as P
 
@@ -276,30 +331,32 @@ def flash_attention(q, k, v, causal: bool = True, block_q: int = 128,
     return local(q, k, v)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def _flash_local(q, k, v, causal: bool = True, block_q: int = 128,
-                 block_kv: int = 128, interpret: Optional[bool] = None):
+                 block_kv: int = 128, interpret: Optional[bool] = None,
+                 window: Optional[int] = None):
     if causal and q.shape[1] != k.shape[1]:
         # mixed-length causal needs the position offset folded into the mask;
         # the kernel path covers the hot shapes (T==S full-causal, and any
         # non-causal read) — everything else takes the exact oracle
-        return attention_reference(q, k, v, causal=causal)
+        return attention_reference(q, k, v, causal=causal, window=window)
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
     out = _flash_bhtd(qt, kt, vt, causal=causal, block_q=block_q,
-                      block_kv=block_kv, interpret=interpret)
+                      block_kv=block_kv, interpret=interpret, window=window)
     return out.transpose(0, 2, 1, 3)
 
 
-def _flash_fwd(q, k, v, causal, block_q, block_kv, interpret):
-    return _flash_local(q, k, v, causal, block_q, block_kv, interpret), (q, k, v)
+def _flash_fwd(q, k, v, causal, block_q, block_kv, interpret, window):
+    return (_flash_local(q, k, v, causal, block_q, block_kv, interpret,
+                         window), (q, k, v))
 
 
-def _flash_bwd(causal, block_q, block_kv, interpret, residuals, g):
+def _flash_bwd(causal, block_q, block_kv, interpret, window, residuals, g):
     q, k, v = residuals
-    _, vjp = jax.vjp(lambda q, k, v: attention_reference(q, k, v, causal=causal),
-                     q, k, v)
+    _, vjp = jax.vjp(lambda q, k, v: attention_reference(
+        q, k, v, causal=causal, window=window), q, k, v)
     return vjp(g)
 
 

@@ -39,21 +39,52 @@ caller onto the last live step's blocks, so they move nothing either.
   expert's, so a token meets only the experts it picked: a grouped product,
   not every token through every expert (64/3 of the flops).
 
-Blocks are whole matrices (two of [F, D], 2 x 10 MB at 1856 x 2688 in
-bfloat16, double-buffered; gated, three of 3.1 MB at 768 x 2048): the plainest form; tiling them is a tuning
-question (PERF.md section 7).
+A block is a TILE of an expert's width: `tile` of its F rows of each
+matrix, [tile, D], and the grid is (steps, F / tile) with a step's output
+accumulated over its tiles (the hidden activation is elementwise over F,
+so a tile's up and gate products meet only that tile's rows of the down
+matrix). `width_tile` takes the whole of F wherever an expert's matrices,
+double-buffered, fit the kernel's VMEM: two of 10 MB at 1856 x 2688 in
+bfloat16 (nemotron_h), three of 3.1 MB at 768 x 2048 (mla_moe): one tile, a
+grid of steps alone, the programs they were. Three of 18.9 MB at 3072 x
+3072 (afmoe) do not fit twice over and go in two tiles of 1536. Odd steps
+walk the tiles backwards, so two steps of one expert share the tile between
+them and a prefill's second row block of an expert fetches one tile, not
+two.
 
 `experts_reference` is the numerics oracle for both.
 """
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
 from .scopes import kernel_scope
 
-VMEM_LIMIT = 100 * 1024 * 1024     # an expert's two [F, D] blocks, twice
+# what the kernel asks of VMEM (a v5e has 128 MiB): a tile of each of an
+# expert's matrices twice (double-buffered), the row block in and its
+# float32 output
+VMEM_LIMIT = 100 * 1024 * 1024
+# the share of it the matrices' tiles may take
+_MATRIX_BYTES = VMEM_LIMIT * 4 // 5
+
+
+def width_tile(F: int, D: int, matrices: int, itemsize: int) -> int:
+    """The rows of an expert's width a block holds: all F where the
+    `matrices` [F, D] matrices fit `_MATRIX_BYTES` double-buffered, else
+    the largest divisor of F in whole 128-row tiles that does."""
+    def fits(tile):
+        return 2 * matrices * tile * D * itemsize <= _MATRIX_BYTES
+
+    if fits(F):
+        return F
+    for parts in range(2, F // 128 + 1):
+        if F % (parts * 128) == 0 and fits(F // parts):
+            return F // parts
+    raise ValueError(f"no tile of an expert's width {F} x {D} fits VMEM")
 
 
 def relu2(x):
@@ -80,7 +111,8 @@ def experts_reference(x, w1, w2, combine, wg=None):
     return jnp.einsum("etd,te->td", y, combine)
 
 
-def _kernel(e_ref, rows_ref, wsel_ref, n_ref, x_ref, w1_ref, *refs):
+def _kernel(e_ref, rows_ref, wsel_ref, n_ref, x_ref, w1_ref, *refs,
+            tiled: bool = False):
     from jax.experimental import pallas as pl
 
     # refs: [the gate matrix,] the down matrix, the weights, the output
@@ -90,6 +122,9 @@ def _kernel(e_ref, rows_ref, wsel_ref, n_ref, x_ref, w1_ref, *refs):
     s = pl.program_id(0)
     fresh = jnp.logical_or(
         s == 0, rows_ref[s] != rows_ref[jnp.maximum(s - 1, 0)])
+    if tiled:
+        # a row block's first visit is its first step's first tile
+        fresh = jnp.logical_and(fresh, pl.program_id(1) == 0)
 
     @pl.when(jnp.logical_and(s == 0, n_ref[0] == 0))
     def _nothing_routed():
@@ -123,7 +158,8 @@ def expert_steps(x, w1, w2, weights, experts, rows, wsel, n_steps, tm: int,
     """The kernel. x [R * tm, D]; weights [Wn, tm, 1] float32; experts,
     rows, wsel [S] int32 (row blocks in non-decreasing visiting order);
     n_steps int32 scalar. Returns [R * tm, D] float32; a row block that no
-    live step names is left unwritten."""
+    live step names is left unwritten. A block holds `width_tile`'s rows
+    of an expert's width."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -135,23 +171,52 @@ def expert_steps(x, w1, w2, weights, experts, rows, wsel, n_steps, tm: int,
     at = jnp.minimum(jnp.arange(S), jnp.maximum(n_steps - 1, 0))
     experts, rows, wsel = (a.astype(jnp.int32)[at]
                            for a in (experts, rows, wsel))
-    matrix = pl.BlockSpec((1, F, D), lambda s, e, r, w, n: (e[s], 0, 0))
     matrices = [w1, w2] if wg is None else [w1, wg, w2]
+    tile = width_tile(F, D, len(matrices), w1.dtype.itemsize)
+    tiles = F // tile
+    if tiles == 1:
+        # the whole width a block: a grid of steps alone
+        grid, kernel = (S,), _kernel
+        matrix = pl.BlockSpec((1, F, D), lambda s, e, r, w, n: (e[s], 0, 0))
+
+        def by_step(of):
+            return lambda s, e, r, w, n: of(s, e, r, w)
+    else:
+        grid, kernel = (S, tiles), functools.partial(_kernel, tiled=True)
+        # odd steps walk the tiles backwards: the tile two steps of one
+        # expert meet at is fetched once. A step past the last live one
+        # stays on the tile that one ended at
+        def tile_of(s, f, n):
+            def walked(step, f):
+                return jnp.where(step % 2 == 1, tiles - 1 - f, f)
+
+            return jnp.where(s < n[0], walked(s, f),
+                             walked(jnp.maximum(n[0] - 1, 0), tiles - 1))
+
+        matrix = pl.BlockSpec(
+            (1, tile, D),
+            lambda s, f, e, r, w, n: (e[s], tile_of(s, f, n), 0))
+
+        def by_step(of):
+            return lambda s, f, e, r, w, n: of(s, e, r, w)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,                   # experts, rows, wsel, n
-        grid=(S,),
+        grid=grid,
         in_specs=[
-            pl.BlockSpec((tm, D), lambda s, e, r, w, n: (r[s], 0)),
+            pl.BlockSpec((tm, D), by_step(lambda s, e, r, w: (r[s], 0))),
             *[matrix] * len(matrices),
-            pl.BlockSpec((1, tm, 1), lambda s, e, r, w, n: (w[s], 0, 0))],
-        out_specs=pl.BlockSpec((tm, D), lambda s, e, r, w, n: (r[s], 0)),
+            pl.BlockSpec((1, tm, 1),
+                         by_step(lambda s, e, r, w: (w[s], 0, 0)))],
+        out_specs=pl.BlockSpec((tm, D),
+                               by_step(lambda s, e, r, w: (r[s], 0))),
     )
     with kernel_scope("moe_experts"):
         return pl.pallas_call(
-            _kernel, grid_spec=grid_spec,
+            kernel, grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((T, D), jnp.float32),
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("arbitrary",),
+                dimension_semantics=("arbitrary",) * len(grid),
                 vmem_limit_bytes=VMEM_LIMIT),
             interpret=interpret,
         )(experts, rows, wsel, jnp.reshape(n_steps, (1,)).astype(jnp.int32),

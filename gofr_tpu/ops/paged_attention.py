@@ -61,6 +61,16 @@ same read-modify-write, every row). Prefill windows are written as WHOLE
 pages (`paged_write_window`), whose [Hkv, dh, ps] window is the storage
 layout's own minor dims.
 
+A WINDOW block (models/afmoe.py: sliding attention) attends the last W
+tokens only, and its group's table is a RING (tpu/paging.py: logical page j
+in column j % ring, W / page_size + 2 columns). The same kernel, told one
+more scalar a row: the LOWER BOUND, the first position the row still sees.
+The walk starts at the page that holds it (pages wholly before it are never
+copied), tokens before it are masked, in the pages and in the block's tail
+alike, and the kernel is named `window_read`; the flush finds a token's
+page through the ring too. A read without a bound is the kernel it was, to
+the instruction.
+
 The XLA `paged_attention_reference` (gather-based) is the numerics oracle.
 """
 
@@ -169,7 +179,7 @@ def fold_of(pools, table_width: int, mesh=None) -> int:
 
 def _paged_kernel(layer_ref, table_ref, len_ref, *refs, scale: float,
                   quantized: bool, tailed: bool, fold: int,
-                  value_width=None):
+                  value_width=None, ring=None):
     """One grid step = one row b: stream the row's live pages (ALL heads of
     a page at a time) through two VMEM buffers a pool and fold them into
     the online softmax, the dots batched over the KV heads.
@@ -192,6 +202,13 @@ def _paged_kernel(layer_ref, table_ref, len_ref, *refs, scale: float,
     buffers are zeroed before the first copy of a call, and what a live
     page holds is finite.
 
+    With a `ring` (a window group's, tpu/paging.py) the row attends from a
+    LOWER BOUND on (one more scalar a row, `lower`: the first position it
+    still sees): the walk starts at the page that holds it (pages wholly
+    before it are never copied), tokens before it are masked, in the pages
+    and in the tail alike, and logical page j of the row lies in table
+    column j % `ring`.
+
     `tailed`, the row is in a decode block: its new k and v are put into
     its tail as token tail_len[b] - 1, the tail goes back where it came
     from, and its first tail_len[b] tokens are one more segment of the same
@@ -199,7 +216,7 @@ def _paged_kernel(layer_ref, table_ref, len_ref, *refs, scale: float,
     a token's value is the first `value_width` of its key's dh values: one
     pool, one tail, the output [Hkv, G, value_width].
 
-    refs: [tail_len (SMEM, with the other scalars),] q, [the row's new k,
+    refs: [tail_len,] [lower (SMEM, with the other scalars),] q, [the row's new k,
     v [Hkv, 1, dh'],] the n stacked pools left in HBM (k, v[, k_scale,
     v_scale]), [the two stacked tails left where they are,] o, [the tails
     again: the outputs alias them,] the pools' n VMEM buffers
@@ -213,6 +230,8 @@ def _paged_kernel(layer_ref, table_ref, len_ref, *refs, scale: float,
 
     refs = list(refs)
     tail_len_ref = refs.pop(0) if tailed else None
+    windowed = ring is not None
+    lower_ref = refs.pop(0) if windowed else None
     q_ref = refs.pop(0)
     latent = value_width is not None
     news = [refs.pop(0) for _ in range((1 if latent else 2) * tailed)]
@@ -238,9 +257,23 @@ def _paged_kernel(layer_ref, table_ref, len_ref, *refs, scale: float,
     dv = value_width or dh
     page_size = k_buf.shape[-1] // fold
 
+    def first_page(row):
+        """The page a row's walk starts at: the one its lower bound is
+        in."""
+        return lower_ref[row] // page_size if windowed else 0
+
     def pages_of(row):
-        return jnp.minimum((len_ref[row] + page_size - 1) // page_size,
-                           table_ref.shape[1])
+        """Pages a row's walk takes, from `first_page` on."""
+        pages = (len_ref[row] + page_size - 1) // page_size
+        if not windowed:
+            return jnp.minimum(pages, table_ref.shape[1])
+        return jnp.clip(pages - first_page(row), 0, ring)
+
+    def column(row, walked):
+        """The table column of the `walked`-th page of a row's walk."""
+        if not windowed:
+            return walked
+        return (first_page(row) + walked) % ring
 
     n_pages = pages_of(b)
     n_folds = (n_pages + fold - 1) // fold
@@ -252,7 +285,7 @@ def _paged_kernel(layer_ref, table_ref, len_ref, *refs, scale: float,
         live = pages_of(row)
         for c in range(fold):
             def _page(c=c):
-                page = table_ref[row, f * fold + c]
+                page = table_ref[row, column(row, f * fold + c)]
                 for j, (pool, buf) in enumerate(zip(pools, bufs)):
                     window = (slot,) + (slice(None),) * (buf.ndim - 2) + (
                         pl.ds(c * page_size, page_size),)
@@ -271,6 +304,9 @@ def _paged_kernel(layer_ref, table_ref, len_ref, *refs, scale: float,
     def start_first_fold_after(row, slot):
         # the next row that HAS a page: a row of length 0 owns none
         def live_or_end(r):
+            if windowed:    # a bound past a row's pages leaves it none
+                return jnp.logical_or(
+                    r > last_row, pages_of(jnp.minimum(r, last_row)) > 0)
             return jnp.logical_or(r > last_row,
                                   len_ref[jnp.minimum(r, last_row)] > 0)
 
@@ -360,6 +396,11 @@ def _paged_kernel(layer_ref, table_ref, len_ref, *refs, scale: float,
                 preferred_element_type=jnp.float32)
             held = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
                     < tail_len)
+            if windowed:
+                # tail token i is at position length + i
+                held = jnp.logical_and(
+                    held, jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+                    >= lower_ref[b] - length)
             m_new = jnp.maximum(m_prev, jnp.max(
                 jnp.where(held, s, DEFAULT_MASK_VALUE), axis=-1,
                 keepdims=True))
@@ -378,6 +419,10 @@ def _paged_kernel(layer_ref, table_ref, len_ref, *refs, scale: float,
     # tokens the walk reaches: it stops at the table's width whatever the
     # length says, and a fold's lanes past them were not copied this turn
     reached = jnp.minimum(length, n_pages * page_size)
+    if windowed:
+        # the walk starts at the page the row's lower bound is in
+        walk_from = first_page(b) * page_size
+        reached = jnp.minimum(length, walk_from + n_pages * page_size)
 
     def fold_pages(f, carry):
         m_prev, l_prev, acc = carry
@@ -407,9 +452,18 @@ def _paged_kernel(layer_ref, table_ref, len_ref, *refs, scale: float,
             s = s * ks_buf[slot][:, None, :].astype(jnp.float32)
         kv_pos = f * (fold * page_size) + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 2)
-        s = jnp.where(kv_pos < reached, s, DEFAULT_MASK_VALUE)
+        if windowed:
+            kv_pos = kv_pos + walk_from
+        seen = kv_pos < reached
+        if windowed:
+            seen = jnp.logical_and(seen, kv_pos >= lower_ref[b])
+        s = jnp.where(seen, s, DEFAULT_MASK_VALUE)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         pr = jnp.exp(s - m_new)
+        if windowed:
+            # a fold may hold no token the row still sees (its bound lies
+            # in the tail): exp(mask - mask) is 1.0, not 0.0
+            pr = jnp.where(seen, pr, 0.0)
         alpha = jnp.exp(m_prev - m_new)
         l_new = l_prev * alpha + jnp.sum(pr, axis=-1, keepdims=True)
         if quantized:
@@ -487,7 +541,7 @@ def paged_attention(q, k_pool, v_pool, table, lengths, k_scale=None,
 
 def paged_attention_in_block(q, k, v, k_pool, v_pool, k_tail, v_tail, table,
                              lengths, tail_lens, *, layer=None, mesh=None,
-                             interpret=None):
+                             interpret=None, window=None, ring=None):
     """One step's attention inside a decode block (floating-point pools):
     row b's new k, v [B, Hkv, dh] become token tail_lens[b] - 1 of its tail
     (`block_tail`, stacked like the pools: [L, B, Hkv, T, dh'], or one
@@ -499,19 +553,34 @@ def paged_attention_in_block(q, k, v, k_pool, v_pool, k_tail, v_tail, table,
     place. Keys past tail_lens[b] may hold anything; values there must be
     finite (`block_tail` makes zeros): they meet a probability of 0.0.
     q, pools, table, layer, mesh: as `paged_attention` has them; the tails
-    and k, v split on Hkv under a tp mesh."""
+    and k, v split on Hkv under a tp mesh.
+
+    `window` W (a window block; `ring` the columns of its group's ring,
+    tpu/paging.py): the row's token at position p = lengths[b] +
+    tail_lens[b] - 1 attends (p - W, p], W keys with its own: the read is
+    told the lower bound lengths + tail_lens - W, finds logical page j in
+    table column j % ring, and runs under the scope `window_read`."""
+    if window is None:
+        return _paged_read(q, [k_pool, v_pool], table, lengths,
+                           (k, v, k_tail, v_tail, tail_lens), layer, mesh,
+                           interpret)
+    lower = jnp.maximum(lengths + tail_lens - window, 0).astype(jnp.int32)
     return _paged_read(q, [k_pool, v_pool], table, lengths,
                        (k, v, k_tail, v_tail, tail_lens), layer, mesh,
-                       interpret)
+                       interpret, lower=lower, ring=ring,
+                       scope="window_read")
 
 
 def _paged_read(q, pools, table, lengths, block, layer, mesh, interpret, *,
-                value_width=None, scale=None, scope: str = "paged_read"):
+                value_width=None, scale=None, scope: str = "paged_read",
+                lower=None, ring=None):
     """The reads' one call. pools: (k, v[, k_scale, v_scale]); block: None
     or (k, v, k_tail, v_tail, tail_lens) of `paged_attention_in_block`.
     With `value_width` (ops/mla_read.py) the one pool of a one-plane page,
     block (new, tail, tail_lens), the scores scaled by `scale` and the
-    kernel named `scope`."""
+    kernel named `scope`. `lower` [B] int32: the first position each row
+    still sees, and `ring` the columns of the ring its table holds: given
+    together."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -525,6 +594,8 @@ def _paged_read(q, pools, table, lengths, block, layer, mesh, interpret, *,
         news, tail_lens = list(block[:m]), [block[2 * m]]
         tails = [_stacked(tail, layer) for tail in block[m:2 * m]]
 
+    if _tp(mesh) and lower is not None:
+        raise NotImplementedError("no windowed read under a tp mesh yet")
     if _tp(mesh):
         from jax.sharding import PartitionSpec
 
@@ -561,10 +632,12 @@ def _paged_read(q, pools, table, lengths, block, layer, mesh, interpret, *,
 
     scalars = [layer_arr, table, lengths] + tail_lens
     fold = fold_of(pools, table.shape[1])
-    kernel = functools.partial(_paged_kernel,
-                               scale=scale or 1.0 / math.sqrt(dh),
-                               quantized=quantized, tailed=tailed,
-                               fold=fold, value_width=value_width)
+    static = dict(scale=scale or 1.0 / math.sqrt(dh), quantized=quantized,
+                  tailed=tailed, fold=fold, value_width=value_width)
+    if lower is not None:
+        scalars.append(lower)
+        static.update(ring=int(ring))
+    kernel = functools.partial(_paged_kernel, **static)
 
     def row_index(b, *scalars):
         return (b, 0, 0, 0)
@@ -576,7 +649,7 @@ def _paged_read(q, pools, table, lengths, block, layer, mesh, interpret, *,
     q_row = pl.BlockSpec((1, Hkv, G, dh), row_index)
     out_row = pl.BlockSpec((1, Hkv, G, dv), row_index)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=len(scalars),  # layer, table, lengths[, tail]
+        num_scalar_prefetch=len(scalars),  # layer, table, lengths[, tail][, lower]
         grid=(B,),
         in_specs=[q_row]
         + [pl.BlockSpec((1,) + new.shape[1:], row_index) for new in news]
@@ -872,14 +945,22 @@ def _flush_kernel(page_ref, row_ref, lane_ref, count_ref, *refs):
             out[...] = page[...]
 
 
-def _flush_items(table, starts, counts, ps: int, spans: int):
+def _ring_column(slots, ring, width: int):
+    """Table columns of logical pages `slots`: column j % ring of a window
+    group's ring, column j (inside the table) otherwise."""
+    if ring:
+        return slots % ring
+    return jnp.clip(slots, 0, width - 1)
+
+
+def _flush_items(table, starts, counts, ps: int, spans: int, ring=None):
     """The flush's scalars (see `_flush_kernel`), [B * spans] each: one
     item a (row, page its block reaches), the items with tokens to place
     first, in row order, so that consecutive grid steps move consecutive
     pages and the steps left over move nothing."""
     B, NP = table.shape
     span = jnp.arange(spans, dtype=jnp.int32)[None, :]
-    slots = jnp.clip(starts[:, None] // ps + span, 0, NP - 1)
+    slots = _ring_column(starts[:, None] // ps + span, ring, NP)
     pages = jnp.take_along_axis(table, slots, axis=1)      # [B, spans]
     off = (starts % ps)[:, None]
     live = jnp.logical_and(counts[:, None] > 0,
@@ -901,13 +982,14 @@ def _flush_items(table, starts, counts, ps: int, spans: int):
 
 
 def flush_planes(pools, tails, table, starts, counts, *, mesh=None,
-                 interpret=None):
+                 interpret=None, ring=None):
     """Put a decode block's tail into the pages, in place, every layer and
     every plane at once: token i < counts[b] of row b's tail goes to
     absolute position starts[b] + i, i.e. column (starts[b] + i) % ps of
     page table[b, (starts[b] + i) // ps]. A row's page is read and written
     once (once more for each page boundary its block crossed) whatever
-    counts[b] is; a row with counts[b] == 0 moves nothing.
+    counts[b] is; a row with counts[b] == 0 moves nothing. `ring`: the
+    table holds a window group's ring, logical page j in column j % ring.
 
     pools: a [L, P, heads, w, ps] a plane; tails: a [L, B, heads, T, w']
     a plane (`plane_tail`); table: [B, NP]; starts, counts: [B] int32,
@@ -920,7 +1002,7 @@ def flush_planes(pools, tails, table, starts, counts, *, mesh=None,
     pools, tails = tuple(pools), tuple(tails)
     n = len(pools)
     if interpret is None and jax.default_backend() != "tpu":
-        return _flush_columns(pools, tails, table, starts, counts)
+        return _flush_columns(pools, tails, table, starts, counts, ring)
     if _tp(mesh):
         from jax.sharding import PartitionSpec
 
@@ -928,7 +1010,7 @@ def flush_planes(pools, tails, table, starts, counts, *, mesh=None,
         heads = _heads_spec(5, 2)
         return jax.shard_map(
             lambda *a: flush_planes(a[:n], a[n:2 * n], *a[2 * n:],
-                                    interpret=interpret),
+                                    interpret=interpret, ring=ring),
             mesh=mesh, in_specs=(heads,) * (2 * n) + (rep,) * 3,
             out_specs=(heads,) * n, check_vma=False)(
                 *pools, *tails, table, starts, counts)
@@ -964,7 +1046,8 @@ def flush_planes(pools, tails, table, starts, counts, *, mesh=None,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",) * 2),
             interpret=bool(interpret),
-        )(*_flush_items(table, starts, counts, ps, spans), *tails, *pools))
+        )(*_flush_items(table, starts, counts, ps, spans, ring), *tails,
+          *pools))
 
 
 def paged_flush_block(k_pool, v_pool, k_tail, v_tail, table, starts, counts,
@@ -975,13 +1058,13 @@ def paged_flush_block(k_pool, v_pool, k_tail, v_tail, table, starts, counts,
                         counts, mesh=mesh, interpret=interpret)
 
 
-def _flush_columns(pools, tails, table, starts, counts):
+def _flush_columns(pools, tails, table, starts, counts, ring=None):
     """The flush as one plain scatter a pool: the kernel's reference, and
     what runs off the TPU. A token past its row's count is dropped."""
     P, ps = pools[0].shape[1], pools[0].shape[-1]
     T = tails[0].shape[3]
     positions = starts[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
-    slots = jnp.clip(positions // ps, 0, table.shape[1] - 1)
+    slots = _ring_column(positions // ps, ring, table.shape[1])
     pages = jnp.take_along_axis(table, slots, axis=1)         # [B, T]
     held = jnp.arange(T)[None, :] < counts[:, None]
     pages = jnp.where(held, pages, P)                         # P: dropped

@@ -60,21 +60,37 @@ def kv_token_bytes(cfg, dtype: Optional[str] = None) -> int:
     family's page (models/protocol.py `planes`): K and V, 2 * kv_layers *
     n_kv_heads * head_dim * itemsize, over the blocks that keep them (all
     of models/llama.py's, 6 in 52 of nemotron_h's); one latent plane of
-    576 a block for mla_moe. The per-token unit the capacity plan and the
-    utilization ledger's bandwidth model share."""
+    576 a block for mla_moe. The per-token unit of the utilization
+    ledger's bandwidth model, and of the capacity plan for a family whose
+    blocks all keep every token (`kv_cache_bytes` counts a sequence)."""
     return (cfg.paged_model().token_values
+            * _dtype_bytes(dtype or getattr(cfg, "kv_dtype", None)
+                           or cfg.dtype))
+
+
+def kv_sequence_bytes(cfg, seq_len: int, dtype: Optional[str] = None,
+                      page_size: int = 128) -> int:
+    """HBM bytes ONE sequence of `seq_len` tokens occupies in pages: bytes
+    a sequence, not bytes a token times a length. A page group without a
+    window (models/protocol.py `groups`) keeps every token; a window group
+    keeps at most its ring of pages, however long the sequence grows: the
+    afmoe family's 13,312-token sequence keeps 13,312 tokens in its full
+    block and 34 x 128 in each of four sliding blocks."""
+    return (cfg.paged_model().sequence_values(seq_len, page_size)
             * _dtype_bytes(dtype or getattr(cfg, "kv_dtype", None)
                            or cfg.dtype))
 
 
 def kv_cache_bytes(cfg, n_slots: int, seq_len: int,
                    dtype: Optional[str] = None) -> int:
-    """Both (k, v) caches: 2 * [L, B, Hkv, dh, S] in the cache dtype.
+    """The pages of `n_slots` sequences of `seq_len` tokens, every plane
+    and block (K and V: 2 * [L, B, Hkv, dh, S] in the cache dtype).
 
     Exact HBM bytes: the S-minor layout is tile-aligned on TPU (no padding
     expansion — see init_kv_cache), so element count × itemsize is the
     physical footprint."""
-    return n_slots * seq_len * kv_token_bytes(cfg, dtype=dtype or cfg.dtype)
+    return n_slots * kv_sequence_bytes(cfg, seq_len,
+                                       dtype=dtype or cfg.dtype)
 
 
 def params_bytes(cfg) -> int:

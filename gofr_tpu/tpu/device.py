@@ -167,6 +167,9 @@ class TPUClient:
              "HBM bytes per device (kind=in_use|limit)"),
             ("app_tpu_kv_pool_pages",
              "KV page-pool occupancy (kind=used|free)"),
+            ("app_tpu_pool_pages",
+             "pages in use a page group (group=the family's group names: "
+             "models/protocol.py `groups`)"),
             ("app_tpu_breaker_state",
              "reset-storm breaker state (0=closed, 1=half_open, 2=open)"),
             ("app_tpu_moe_routing",

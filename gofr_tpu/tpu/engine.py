@@ -270,8 +270,8 @@ class GenerationRequest:
 
 
 class _Slot:
-    __slots__ = ("request", "length", "remaining", "pages", "chunking",
-                 "history")
+    __slots__ = ("request", "length", "remaining", "pages", "more_pages",
+                 "chunking", "history")
 
     def __init__(self):
         self.request: Optional[GenerationRequest] = None
@@ -280,6 +280,10 @@ class _Slot:
         self.pages: Optional[List[int]] = None  # owned page
         # ids, table order (shared prefix pages first; _finish_slot asks
         # the prefix cache which pages it owns)
+        # its pages in the page groups beside the primary one, {group
+        # index: page ids in ring-column order} (tpu/paging.py; None for a
+        # family of one group)
+        self.more_pages = None
         # chunked prefill in progress: the slot is RESERVED (its window
         # is being filled chunk by chunk) but not yet emitting — excluded
         # from the free list and from decode demux until the final chunk
@@ -2294,7 +2298,8 @@ class LLMEngine:
         self.steps.note_sync(
             "decode", tokens=emitted,
             slowest_request_id=slowest.id if slowest else None,
-            page_writes=page_writes, dry=dry)
+            page_writes=page_writes, dry=dry,
+            window_pages=self._window_pages_used())
         self._obs.hist_n(
             "app_tpu_tpot_seconds", step_s, emitted,
             exemplar=(self._exemplar_of(slowest) if slowest else None))
@@ -2559,7 +2564,7 @@ class LLMEngine:
                     slot.length = 0
                     slot.remaining = 0
                     slot.history = None
-                    slot.pages = None
+                    slot.pages = slot.more_pages = None
             self._init_device_state()
             self._replay_or_fail(survivors, exc)
 
@@ -2714,7 +2719,7 @@ class LLMEngine:
         slot.length = 0
         slot.remaining = 0
         slot.history = None
-        slot.pages = None
+        slot.pages = slot.more_pages = None
         if (self.sampling_controls and request is not None
                 and (request.top_p or request.top_k)):
             idx = next((i for i, s in enumerate(self.slots) if s is slot),
@@ -2840,6 +2845,11 @@ class LLMEngine:
 
     def _note_model_counts(self, tokens_host, block: int) -> None:
         """Fold a synced decode block's counter rows into the totals."""
+        raise NotImplementedError
+
+    def _window_pages_used(self) -> int:
+        """Pages in use in the page groups that keep a window (the step
+        record's `window_pages`)."""
         raise NotImplementedError
 
     def _note_page_writes(self, live, block: int) -> int:

@@ -25,6 +25,17 @@ long-context row):
     the program ever sees the tail
   - the block table is host-owned (plain numpy) and uploaded per dispatch,
     bucketed to power-of-two widths to bound compiled decode variants
+  - a family whose blocks differ in what they keep says PAGE GROUPS
+    (models/protocol.py `groups`): an allocator, a table and a reservation
+    a group, a pool a plane a group. A group without a window is what the
+    lines above say. A WINDOW group's blocks attend the last W tokens
+    only: a sequence reserves min(pages_for(prompt + max_new), ring)
+    pages there at admission and frees them at finish like the others
+    (nothing is released in flight), the token at position p lives in ring
+    column (p // page_size) % ring, the prefill writer puts the prompt's
+    last ring of pages only, and the read is told a lower bound a row. Its
+    pool is sized for every slot's whole ring, so admission never waits on
+    it: `allocator` stays the first unbounded group's, the one that fills
 
 The allocator is the HBM analog of the reference's connection-pool
 bookkeeping (sql.go pool stats): a resource ledger the serving loop
@@ -201,17 +212,41 @@ class PagedLLMEngine(LLMEngine):
         # pass the planned smaller n_pages
         n_pages = self._requested_pages or (
             self.n_slots * math.ceil(self.max_seq_len / ps) + 1)
-        self.allocator = PageAllocator(n_pages, ps)
+        # an allocator a page group (models/protocol.py `groups`): the
+        # PRIMARY group, the first that keeps every token, has the pool the
+        # caller sized, and `allocator`, `_reservations` and a slot's
+        # `pages` are its; a window group's pool holds every slot's whole
+        # ring and its pages ride beside (`_more_reservations`, a slot's
+        # `more_pages`, {group index: pages})
+        model = self.model
+        # a group's ring of pages a sequence (None: every token's pages)
+        self._rings = rings = [group.ring(ps) for group in model.groups]
+        if any(rings) and self.decode_block_size > ps:
+            raise ValueError(
+                f"decode_block_size {self.decode_block_size} is over "
+                f"page_size {ps}: a window group's ring leaves one page "
+                f"for a decode block to cross into")
+        self._primary = next((i for i, ring in enumerate(rings)
+                              if ring is None), 0)
+        self.allocators = [
+            PageAllocator(n_pages if i == self._primary
+                          else self.n_slots * ring + 1, ps)
+            for i, ring in enumerate(rings)]
+        self.allocator = self.allocators[self._primary]
         self._reservations: Dict[int, List[int]] = {}
+        self._more_reservations: Dict[int, Dict[int, List[int]]] = {}
+        # pages reserved a group and the sequences that reserved them,
+        # since the last reset (`/debug/engine` paging.groups)
+        self._reserved_pages = [0] * len(rings)
+        self._reserved_sequences = 0
         # prefix cache rebuilds with the pool: a device-state reset zeroes
         # the pages, so every cached entry is invalid by construction
         from .prefixcache import PrefixCache
 
         self.prefix = PrefixCache(ps) if self._prefix_enabled else None
         self._prefix_hits: Dict[int, List[int]] = {}
-        # one pool a plane of the family's page; the pools' leading axis
-        # counts the blocks that keep pages
-        model = self.model
+        # one pool a plane of the family's page a group, group by group;
+        # a pool's leading axis counts its group's blocks
         L = model.kv_layers
         held_in = getattr(self.cfg, "kv_dtype", None) or self.cfg.dtype
         dt = {"bfloat16": jnp.bfloat16, "float32": jnp.float32,
@@ -220,7 +255,10 @@ class PagedLLMEngine(LLMEngine):
         # max_seq_len; the pool derived from them must itself fit — check
         # explicitly, since an explicit n_pages bypasses the plan's sizing
         itemsize = {"bfloat16": 2, "float16": 2, "int8": 1}.get(held_in, 4)
-        pool_bytes = model.token_values * n_pages * ps * itemsize
+        pool_bytes = sum(
+            group.layers * model.plane_values * allocator.n_pages
+            for group, allocator in zip(model.groups, self.allocators)
+        ) * ps * itemsize
         if self._q8:  # f32 dequant scale pools ride along
             pool_bytes += 2 * L * n_pages * self.cfg.n_kv_heads * ps * 4
         if self.plan is not None:
@@ -233,8 +271,11 @@ class PagedLLMEngine(LLMEngine):
                     f"page pool of {n_pages} pages ({pool_bytes >> 20} MiB) "
                     f"does not fit the budget: params + pool + prefill temps "
                     f"= {need >> 20} MiB > {usable >> 20} MiB usable")
-        self.pools = [jnp.zeros((L, n_pages, plane.heads, plane.width, ps),
-                                dtype=dt) for plane in model.planes]
+        self.pools = [jnp.zeros((group.layers, allocator.n_pages,
+                                 plane.heads, plane.width, ps), dtype=dt)
+                      for group, allocator in zip(model.groups,
+                                                  self.allocators)
+                      for plane in model.planes]
         self.k_scale = self.v_scale = None
         if self._q8:
             self.k_scale = jnp.zeros((L, n_pages, self.cfg.n_kv_heads, ps),
@@ -259,6 +300,10 @@ class PagedLLMEngine(LLMEngine):
         # (`_note_page_reads`)
         self.read_folds = self.read_tokens = self.read_lanes = 0
         self.read_pages_per_fold = None
+        # the same a group beside the primary: {group index: [folds,
+        # tokens, lanes, pages a fold]}
+        self._more_reads = {i: [0, 0, 0, None] for i in range(len(rings))
+                            if i != self._primary}
         self._tokens = jnp.zeros((B,), dtype=jnp.int32)
         self._positions = jnp.zeros((B,), dtype=jnp.int32)
         self._temps = self._temps_init(B)
@@ -409,12 +454,54 @@ class PagedLLMEngine(LLMEngine):
                                            once=True, need=need)
             return False
         self._reservations[request.id] = pages
+        if not self._reserve_more(request):
+            self.allocator.release(self._reservations.pop(request.id))
+            return False
+        self._note_reserved(request)
         return True
+
+    def _group_pages(self, request: GenerationRequest, index: int) -> int:
+        """The pages `request` holds in group `index`: every token's, or at
+        most the ring of a window group."""
+        need, ring = self._request_pages(request), self._rings[index]
+        return need if ring is None else min(need, ring)
+
+    def _reserve_more(self, request: GenerationRequest) -> bool:
+        """The request's pages in the groups beside the primary. Their
+        pools hold every slot's whole ring, so this fails only for a
+        caller that reserves more requests than there are slots."""
+        if len(self.allocators) == 1 or request.id in self._more_reservations:
+            return True
+        more = {}
+        for index, allocator in enumerate(self.allocators):
+            if index == self._primary:
+                continue
+            pages = allocator.alloc(self._group_pages(request, index))
+            if pages is None:
+                for had, got in more.items():
+                    self.allocators[had].release(got)
+                return False
+            more[index] = pages
+        self._more_reservations[request.id] = more
+        return True
+
+    def _note_reserved(self, request: GenerationRequest) -> None:
+        self._reserved_sequences += 1
+        self._reserved_pages[self._primary] += len(
+            self._reservations[request.id])
+        for index, pages in self._more_reservations.get(
+                request.id, {}).items():
+            self._reserved_pages[index] += len(pages)
+
+    def _release_more(self, more) -> None:
+        for index, pages in (more or {}).items():
+            self.allocators[index].release(pages)
 
     def _abort_admission(self, request: GenerationRequest) -> None:
         pages = self._reservations.pop(request.id, None)
         if pages is not None:
             self.allocator.release(pages)
+        self._release_more(self._more_reservations.pop(request.id, None))
         shared = self._prefix_hits.pop(request.id, None)
         if shared:
             for page_id in shared:
@@ -450,6 +537,8 @@ class PagedLLMEngine(LLMEngine):
         the disaggregated hand-off evacuation."""
         if slot.pages is None:
             return
+        self._release_more(slot.more_pages)
+        slot.more_pages = None
         if self.prefix is not None:
             keep = []
             for page_id in slot.pages:
@@ -542,6 +631,8 @@ class PagedLLMEngine(LLMEngine):
         # pool gauges ride the off-loop finisher: values are READ here on
         # the loop thread (allocator state is loop-owned), flushed off it
         used, free = self.allocator.used_pages, self.allocator.free_pages
+        by_group = [(group.name, allocator.used_pages) for group, allocator
+                    in zip(self.model.groups, self.allocators)]
 
         routing = (self.model_snapshot().get("routing", {})
                    if self.model_count_steps else {})
@@ -550,6 +641,8 @@ class PagedLLMEngine(LLMEngine):
             self._obs.gauge("app_tpu_pages_used", used)
             self._obs.gauge("app_tpu_kv_pool_pages", used, kind="used")
             self._obs.gauge("app_tpu_kv_pool_pages", free, kind="free")
+            for name, pages in by_group:
+                self._obs.gauge("app_tpu_pool_pages", pages, group=name)
             for what, value in routing.items():
                 self._obs.gauge("app_tpu_moe_routing", value, what=what)
 
@@ -839,20 +932,26 @@ class PagedLLMEngine(LLMEngine):
 
     def _prefill_fn(self, bucket: int, K: int):
         model, mesh, jnp = self.model, self.mesh, self._jnp
-        top_k, n = self.top_k, len(model.planes)
+        top_k, planes, g = self.top_k, len(model.planes), len(model.groups)
+        n = planes * g
         from .sampling import sample_tokens
 
         def prefill(params, *rest):
-            """(params, a pool a plane, ptokens, ptable, slots, lengths,
-            tokens, positions, temps, new_temps, rng, *state). Fused K-way
+            """(params, a pool a plane a group, ptokens, a ptable a group,
+            slots, lengths, tokens, positions, temps, new_temps, rng,
+            *state). Fused K-way
             paged admission: the model's prefill of the [K, bucket]
             window, what it keeps a token scattered into the slots' pages,
             plane by plane, and its rows' final states into the slots'
             state (`state`: the family's per-slot arrays, none for a model
             that holds only pages), first tokens sampled, loop state
-            spliced. ptable: [K, ceil(bucket/ps)] page ids."""
-            (ptokens, ptable, slots, lengths, tokens, positions, temps,
-             new_temps, rng, *state) = rest[n:]
+            spliced. ptable: [K, ceil(bucket/ps)] page ids, column j the
+            page of the prompt's tokens [j ps, (j + 1) ps); a window
+            group's names its ring's pages at the prompt's last ring of
+            columns and the garbage page before them (`_prefill_tables`)."""
+            ptokens, *ptables = rest[n:n + 1 + g]
+            (slots, lengths, tokens, positions, temps,
+             new_temps, rng, *state) = rest[n + 1 + g:]
             pools = [_pin_standard_layout(pool) for pool in rest[:n]]
             last, windows, rows = model.prefill(params, ptokens, lengths,
                                                 mesh)
@@ -860,8 +959,9 @@ class PagedLLMEngine(LLMEngine):
             # (ptable[k, t // ps], t % ps); pad junk past lengths[k] is
             # redirected to the garbage page so live pages stay clean
             starts = jnp.zeros_like(lengths)
-            pools = [paged_write_window(pool, window, ptable, starts, lengths)
-                     for pool, window in zip(pools, windows)]
+            pools = [paged_write_window(pool, window, ptables[i // planes],
+                                        starts, lengths)
+                     for i, (pool, window) in enumerate(zip(pools, windows))]
             # a slot's state is written whole, in place (donated)
             state = tuple(held.at[:, slots].set(row.astype(held.dtype))
                           for held, row in zip(state, rows))
@@ -931,30 +1031,38 @@ class PagedLLMEngine(LLMEngine):
                 args, donate_argnums=(1, 2, 3, 4, 9, 10, 11))
         args = (self.params, *self.pools,
                 jnp.zeros((K, bucket), dtype=jnp.int32),
-                jnp.zeros((K, n_ptable), dtype=jnp.int32),
+                *[jnp.zeros((K, n_ptable), dtype=jnp.int32)
+                  for _ in self.allocators],
                 jnp.zeros((K,), dtype=jnp.int32),
                 jnp.ones((K,), dtype=jnp.int32),
                 self._tokens, self._positions, self._temps,
                 self._temps_init(K), self.rng, *self.state)
-        n = len(self.pools)     # the pools, the loop's vectors, the state
+        # the pools, the loop's vectors (past the tables, one a group),
+        # the state
+        n = len(self.pools)
+        at = n + len(self.allocators) - 1
         return self.executor.compile(
             f"{self.model.program_tag}-paged-prefill-{bucket}x{K}{self._id_tag}",
             self._prefill_fn(bucket, K), args,
-            donate_argnums=tuple(range(1, 1 + n)) + (n + 5, n + 6, n + 7)
-            + tuple(range(n + 10, n + 10 + len(self.state))))
+            donate_argnums=tuple(range(1, 1 + n)) + (at + 5, at + 6, at + 7)
+            + tuple(range(at + 10, at + 10 + len(self.state))))
 
     def _decode_fn_paged(self, block: int, n_table: int):
         model, mesh = self.model, self.mesh
-        top_k, n = self.top_k, len(model.planes)
+        top_k, planes, g = self.top_k, len(model.planes), len(model.groups)
+        n = planes * g
         import jax
         import jax.numpy as jnp
 
         from .sampling import sample_tokens
 
         def decode(params, *rest):
-            """(params, a pool a plane, table, tokens, positions, temps,
-            rng, *state). `block` paged decode steps under scan; table
-            [B, n_table]; `state` the family's per-slot arrays (none for a
+            """(params, a pool a plane a group, a table a group, tokens,
+            positions, temps, rng, *state). `block` paged decode steps
+            under scan; the primary group's table [B, n_table], a window
+            group's [B, ring] (`_build_tables`); a family of one group is
+            handed its one table, a family of several all of them
+            (models/protocol.py); `state` the family's per-slot arrays (none for a
             model that holds only pages), carried and returned like the
             pools. What the block's new tokens must keep waits in its
             tail, one a plane (ops/paged_attention `plane_tail`: made
@@ -966,7 +1074,9 @@ class PagedLLMEngine(LLMEngine):
             gives a row of int32 a step; their sum over the block rides
             below the block's tokens, so one copy to the host carries
             both."""
-            table, tokens, positions, temps, rng, *state = rest[n:]
+            tables = rest[n:n + g]
+            tokens, positions, temps, rng, *state = rest[n + g:]
+            table = tables[0] if g == 1 else tuple(tables)
             pools = tuple(_pin_standard_layout(pool) for pool in rest[:n])
 
             def step(carry, t):
@@ -981,10 +1091,17 @@ class PagedLLMEngine(LLMEngine):
             (tail, state, tok, pos, rng), (out, counted) = jax.lax.scan(
                 step, (tail, tuple(state), tokens, positions, rng),
                 jnp.arange(block, dtype=jnp.int32))
-            pools = flush_planes(
-                pools, tail, table, positions,
-                jnp.where(holds_request(table), block, 0), mesh=mesh)
-            pools = [_pin_standard_layout(pool) for pool in pools]
+            # a flush a group, through its own table (a window group's is
+            # its ring)
+            flushed = []
+            rings = [group.ring(pools[0].shape[-1]) for group in model.groups]
+            for i, (table, ring) in enumerate(zip(tables, rings)):
+                mine = slice(i * planes, (i + 1) * planes)
+                flushed += flush_planes(
+                    pools[mine], tail[mine], table, positions,
+                    jnp.where(holds_request(table), block, 0), mesh=mesh,
+                    ring=ring)
+            pools = [_pin_standard_layout(pool) for pool in flushed]
             out = out.T
             if counted is not None:
                 below = jnp.zeros((counted.shape[1], block), out.dtype)
@@ -1036,16 +1153,17 @@ class PagedLLMEngine(LLMEngine):
                 self._decode_fn_paged_q8(block, n_table), args,
                 donate_argnums=(1, 2, 3, 4))
         args = (self.params, *self.pools,
-                jnp.zeros((self.n_slots, n_table), dtype=jnp.int32),
+                *[jnp.zeros((self.n_slots, width), dtype=jnp.int32)
+                  for width in self._table_widths(n_table)],
                 self._tokens, self._positions, self._temps, self.rng,
                 *self.state)
-        n = len(self.pools)
+        at = len(self.pools) + len(self.allocators) - 1
         return self.executor.compile(
             f"{self.model.program_tag}-paged-decode-x{block}-NP{n_table}"
             f"{self._id_tag}",
             self._decode_fn_paged(block, n_table), args,
-            donate_argnums=tuple(range(1, 1 + n)) + tuple(
-                range(n + 6, n + 6 + len(self.state))))
+            donate_argnums=tuple(range(1, 1 + len(self.pools))) + tuple(
+                range(at + 6, at + 6 + len(self.state))))
 
     # -- chunked prefill over the pool ---------------------------------------
     # A long prompt's chunks run against bucket-sized per-JOB temp caches
@@ -1545,6 +1663,7 @@ class PagedLLMEngine(LLMEngine):
                       if self.prefix is not None else [])
             slot = self.slots[slots_idx[row]]
             slot.pages = list(shared) + fresh
+            slot.more_pages = self._more_reservations.pop(request.id, None)
             if self.prefix is not None:
                 self.prefix.insert(request.resume_tokens, slot.pages)
 
@@ -1766,6 +1885,10 @@ class PagedLLMEngine(LLMEngine):
             self.model_counts += tokens_host[self.n_slots:, 0]
             self.model_count_steps += block
 
+    def _window_pages_used(self) -> int:
+        return sum(allocator.used_pages for group, allocator in zip(
+            self.model.groups, self.allocators) if group.window is not None)
+
     def _note_page_writes(self, live, block: int) -> int:
         """Count a synced decode block's page writes from what the host
         holds, no device access: a live row's `block` tokens began at its
@@ -1793,20 +1916,38 @@ class PagedLLMEngine(LLMEngine):
         tail) in ceil(pages / C) folds of C x page_size lanes each. The
         int8 pools have no tail: step t attends the t + 1 tokens written
         so far too."""
+        planes = len(self.model.planes)
         pools = ([self.k_cache, self.v_cache, self.k_scale, self.v_scale]
-                 if self._q8 else self.pools)
+                 if self._q8 else self.pools[self._primary * planes:
+                                             (self._primary + 1) * planes])
         c, ps = fold_of(pools, n_table, self.mesh), self.page_size
         tokens = np.asarray([self.slots[i].length for i, _ in live],
                             np.int64)[:, None]
         tokens = tokens + (np.arange(1, block + 1) if self._q8
                            else np.zeros(block, np.int64))
         pages = np.minimum(-(-tokens // ps), n_table)
-        layers = self.model.kv_layers
+        layers = self.model.groups[self._primary].layers
         folds = layers * int((-(-pages // c)).sum())
         self.read_folds += folds
         self.read_lanes += folds * c * ps
         self.read_tokens += layers * int(np.minimum(tokens, pages * ps).sum())
         self.read_pages_per_fold = c
+        # a window group's read walks from the page its lower bound is in:
+        # step t's token at position length + t sees from length + t + 1
+        # - window on, at most a ring of pages
+        for index, read in self._more_reads.items():
+            group, ring = self.model.groups[index], self._rings[index]
+            c = fold_of(self.pools[index * planes:(index + 1) * planes],
+                        ring, self.mesh)
+            lower = np.maximum(
+                tokens + np.arange(1, block + 1) - group.window, 0)
+            pages = np.clip(-(-tokens // ps) - lower // ps, 0, ring)
+            seen = np.minimum(tokens, (lower // ps + pages) * ps) - lower
+            folds = group.layers * int((-(-pages // c)).sum())
+            read[0] += folds
+            read[1] += group.layers * int(np.maximum(seen, 0).sum())
+            read[2] += folds * c * ps
+            read[3] = c
 
     def paging_snapshot(self) -> dict:
         """`/debug/engine` "paging". "write": how often the decode block's
@@ -1818,19 +1959,40 @@ class PagedLLMEngine(LLMEngine):
         read kernel's loop turns (rows x steps x attention layers), and
         `fold_live_share` the tokens attended in pages over folds x C x
         page_size: what is left of 1.0 was masked (short rows, ragged
-        last folds and last pages)."""
+        last folds and last pages; in a window group also the tokens of
+        the walk's first page that lie before the lower bound). "read" is
+        the primary group's; "groups" has every page group: its blocks,
+        its window, its pool's pages and how many are in use, the pages a
+        sequence reserved there on average since the last reset, and its
+        own "read"."""
+        def read_of(folds, tokens, lanes, c):
+            return {"pages_per_fold": c, "folds": folds,
+                    "fold_live_share": (round(tokens / lanes, 4)
+                                        if lanes else None)}
+
+        groups = []
+        for index, (group, allocator) in enumerate(zip(self.model.groups,
+                                                       self.allocators)):
+            primary = index == self._primary
+            groups.append({
+                "name": group.name, "layers": group.layers,
+                "window": group.window, "pages": allocator.n_pages - 1,
+                "used": allocator.used_pages,
+                "reserved_per_sequence_mean": (
+                    round(self._reserved_pages[index]
+                          / self._reserved_sequences, 2)
+                    if self._reserved_sequences else None),
+                "read": (read_of(self.read_folds, self.read_tokens,
+                                 self.read_lanes, self.read_pages_per_fold)
+                         if primary else read_of(*self._more_reads[index]))})
         return {
             "write": {
                 "tokens": self.write_tokens, "page_writes": self.write_pages,
                 "tokens_per_page_write": (
                     round(self.write_tokens / self.write_pages, 3)
                     if self.write_pages else None)},
-            "read": {
-                "pages_per_fold": self.read_pages_per_fold,
-                "folds": self.read_folds,
-                "fold_live_share": (
-                    round(self.read_tokens / self.read_lanes, 4)
-                    if self.read_lanes else None)}}
+            "read": groups[self._primary]["read"],
+            "groups": groups}
 
     def model_snapshot(self) -> dict:
         """`/debug/engine` "model": the family, the planes of its page
@@ -1845,6 +2007,9 @@ class PagedLLMEngine(LLMEngine):
                            for plane in model.planes],
                 "cache_bytes_per_token": (
                     model.token_values * self.pools[0].dtype.itemsize),
+                "cache_bytes_per_sequence": (
+                    model.sequence_values(self.max_seq_len, self.page_size)
+                    * self.pools[0].dtype.itemsize),
                 "state_bytes": self.state_bytes(),
                 **model.describe(counts, self.model_count_steps)}
 
@@ -1859,6 +2024,53 @@ class PagedLLMEngine(LLMEngine):
         for i, slot in active:
             table[i, :len(slot.pages)] = slot.pages
         return table
+
+    def _table_widths(self, n_table: int) -> List[int]:
+        """The tables' widths, a group: `n_table` the primary group's, a
+        window group's its ring (column j % ring holds logical page j: no
+        garbage column, a position never falls off a ring)."""
+        return [n_table if ring is None else ring for ring in self._rings]
+
+    def _build_tables(self) -> List[np.ndarray]:
+        """A block table a group: `_build_table`, and beside it each other
+        group's pages in ring-column order, fixed for a request's life."""
+        tables = [None] * len(self.allocators)
+        tables[self._primary] = self._build_table()
+        widths = self._table_widths(tables[self._primary].shape[1])
+        for index, width in enumerate(widths):
+            if index == self._primary:
+                continue
+            table = np.zeros((self.n_slots, width), dtype=np.int32)
+            for i, slot in enumerate(self.slots):
+                if slot.active:
+                    pages = slot.more_pages[index]
+                    table[i, :len(pages)] = pages
+            tables[index] = table
+        return tables
+
+    def _prefill_tables(self, batch, n_ptable: int) -> List[np.ndarray]:
+        """A [K, n_ptable] prefill table a group, column j the page of the
+        prompt's tokens [j ps, (j + 1) ps). A window group's names the
+        prompt's LAST ring of pages only, each at its ring column's page
+        (j % ring), and the garbage page for what lies before them: the
+        writer puts what a later token can still see."""
+        K = len(batch)
+        tables = []
+        for index, allocator in enumerate(self.allocators):
+            ring = self._rings[index]
+            table = np.zeros((K, n_ptable), dtype=np.int32)
+            for row, request in enumerate(batch):
+                if index == self._primary:
+                    pages = self._reservations[request.id][:n_ptable]
+                    table[row, :len(pages)] = pages
+                    continue
+                pages = self._more_reservations[request.id][index]
+                last = min(n_ptable, allocator.pages_for(
+                    len(request.resume_tokens)))
+                for j in range(max(0, last - ring), last):
+                    table[row, j] = pages[j % ring]
+            tables.append(table)
+        return tables
 
     def _dispatch_prefill(self, bucket: int, slots_idx: List[int],
                           batch: List[GenerationRequest]) -> None:
@@ -1876,16 +2088,18 @@ class PagedLLMEngine(LLMEngine):
         with self.steps.seg("host_prep"):
             ptokens, lengths, new_temps = self._prep_admission(bucket, batch)
             n_ptable = max(1, math.ceil(bucket / self.page_size))
-            ptable = np.zeros((K, n_ptable), dtype=np.int32)
-            for row, request in enumerate(batch):
-                pages = self._reservations.get(request.id)
-                if pages is None:  # direct submit path outside _admit (tests)
-                    pages = self.allocator.alloc(self._request_pages(request))
-                    if pages is None:
-                        raise RuntimeError("page pool exhausted at dispatch")
-                    self._reservations[request.id] = pages
-                prompt_pages = pages[:n_ptable]
-                ptable[row, :len(prompt_pages)] = prompt_pages
+            for request in batch:
+                if request.id in self._reservations:
+                    continue    # direct submit path outside _admit (tests)
+                pages = self.allocator.alloc(self._request_pages(request))
+                if pages is None:
+                    raise RuntimeError("page pool exhausted at dispatch")
+                self._reservations[request.id] = pages
+                if not self._reserve_more(request):
+                    raise RuntimeError("page pool exhausted at dispatch")
+                self._note_reserved(request)
+            ptables = self._prefill_tables(batch, n_ptable)
+            ptable = ptables[self._primary]
 
         program = self._prefill_program(bucket, K)
         self.steps.note_dispatch("prefill")
@@ -1906,7 +2120,8 @@ class PagedLLMEngine(LLMEngine):
                 else:
                     out = program(
                         self.params, *self.pools,
-                        jnp.asarray(ptokens), jnp.asarray(ptable),
+                        jnp.asarray(ptokens),
+                        *[jnp.asarray(table) for table in ptables],
                         jnp.asarray(np.asarray(slots_idx, dtype=np.int32)),
                         jnp.asarray(lengths), self._tokens, self._positions,
                         self._temps, jnp.asarray(new_temps), self.rng,
@@ -1935,7 +2150,8 @@ class PagedLLMEngine(LLMEngine):
         # garbage (0) for every row so dead steps can never write into a
         # live page
         with self.steps.seg("host_prep"):
-            table = self._build_table()
+            tables = self._build_tables()
+        table = tables[self._primary]
         n_table = table.shape[1]
         block = self._decode_block_now()
         program = self._decode_program_paged(n_table, block)
@@ -1957,7 +2173,8 @@ class PagedLLMEngine(LLMEngine):
                 else:
                     out = program(
                         self.params, *self.pools,
-                        jnp.asarray(table), self._tokens, self._positions,
+                        *[jnp.asarray(table) for table in tables],
+                        self._tokens, self._positions,
                         self._temps, self.rng, *self.state)
                     n = len(self.pools)
                     (self._tokens, self._positions, self.rng, out_tokens,
@@ -1980,5 +2197,6 @@ class PagedLLMEngine(LLMEngine):
         # re-admission (super holds the state lock; only the loop thread
         # touches _reservations, so clearing here is safe)
         self._reservations.clear()
+        self._more_reservations.clear()
         self._prefix_hits.clear()
         super()._reset_device_state(exc)

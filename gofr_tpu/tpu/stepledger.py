@@ -142,7 +142,8 @@ class StepRecord:
     __slots__ = ("seq", "started_at", "wall_s", "idle_gap_s", "phase",
                  "segments", "segments_cpu", "cpu_s", "active_slots",
                  "inflight", "inflight_prefill", "queue_depth",
-                 "tokens", "page_writes", "dry_sync", "dispatches",
+                 "tokens", "page_writes", "window_pages", "dry_sync",
+                 "dispatches",
                  "slowest_request_id",
                  "straggler", "cause", "baseline_s")
 
@@ -174,6 +175,9 @@ class StepRecord:
         # floating-point pools: one a live row a block, two where the
         # block crossed a page; 0 where no block was synced)
         self.page_writes = 0
+        # pages in use in the page groups that keep a window, as the
+        # synced decode block's sync found them (0 for a family without)
+        self.window_pages = 0
         # a decode block was read with slots still decoding and no decode
         # block queued behind it: the device ran dry through this step's
         # demux and emit (engine._sync_oldest)
@@ -209,6 +213,8 @@ class StepRecord:
         }
         if self.page_writes:
             out["page_writes"] = self.page_writes
+        if self.window_pages:
+            out["window_pages"] = self.window_pages
         if self.dry_sync:
             out["dry_sync"] = True
         if self.dispatches:
@@ -325,6 +331,7 @@ class StepLedger:
         self._sync_kind: Optional[str] = None
         self._tokens = 0
         self._page_writes = 0
+        self._window_pages = 0
         self._dry_sync = False
         self._slowest: Optional[int] = None
 
@@ -398,6 +405,7 @@ class StepLedger:
         self._sync_kind = None
         self._tokens = 0
         self._page_writes = 0
+        self._window_pages = 0
         self._dry_sync = False
         self._slowest = None
 
@@ -493,11 +501,13 @@ class StepLedger:
     @loop_only
     def note_sync(self, kind: str, tokens: int = 0,
                   slowest_request_id: Optional[int] = None,
-                  page_writes: int = 0, dry: bool = False) -> None:
+                  page_writes: int = 0, dry: bool = False,
+                  window_pages: int = 0) -> None:
         if self._mine():
             self._sync_kind = kind
             self._tokens += int(tokens)
             self._page_writes += int(page_writes)
+            self._window_pages = max(self._window_pages, int(window_pages))
             self._dry_sync = self._dry_sync or bool(dry)
             if slowest_request_id is not None:
                 self._slowest = slowest_request_id
@@ -571,6 +581,7 @@ class StepLedger:
         rec.queue_depth = int(queue_depth)
         rec.tokens = self._tokens
         rec.page_writes = self._page_writes
+        rec.window_pages = self._window_pages
         rec.dry_sync = self._dry_sync
         rec.dispatches = dict(self._dispatches)
         rec.slowest_request_id = self._slowest

@@ -349,6 +349,9 @@ def register_utilization_metrics(metrics) -> None:
          "HBM bytes per device (kind=in_use|limit)"),
         ("app_tpu_kv_pool_pages",
          "KV page-pool occupancy (kind=used|free)"),
+        ("app_tpu_pool_pages",
+         "pages in use a page group (group=the family's group names: "
+         "models/protocol.py `groups`)"),
         ("app_tpu_kv_tier_bytes",
          "host KV tier occupancy in bytes (kind=used|capacity)"),
         ("app_tpu_kv_tier_pages",

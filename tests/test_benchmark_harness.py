@@ -64,6 +64,12 @@ LEFT = {
         "test_one_slots_page_table_off_by_one_is_not_correct",
         "test_one_layers_weights_off_is_not_correct"),
         "whole runs at --tiny size"),
+    "test_afmoe": dict.fromkeys((
+        "test_the_new_cell_is_correct_at_tiny_size",
+        "test_a_pool_of_either_group_in_bfloat16_is_not_as_stated",
+        "test_one_slots_page_table_off_by_one_in_a_group_is_not_correct",
+        "test_a_fault_in_the_block_is_not_correct"),
+        "whole runs at --tiny size"),
 }
 
 

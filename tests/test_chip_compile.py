@@ -738,3 +738,101 @@ def test_mla_moe_prefill_compiles_with_two_widths(topo, as_tpu, bucket):
     # the window's temporaries (0.34 GiB at 2,048, 0.6 at 4,096), never a
     # copy of the 1.9 GiB pool
     assert mem.temp_size_in_bytes < 1 * GIB
+
+
+# -- the afmoe family's step programs (ISSUE 33) --------------------------------
+def _afmoe_programs(topo, slots=32, n_pages=2300):
+    """(config, chips, engine shell, abstract params, the pools of both page
+    groups, loop state, rng) at Trinity-Large-Preview's published widths,
+    three blocks deep (dense and sliding, experts and sliding, experts and
+    full), the pools as the cell sizes them: the full group's as configured,
+    the window group's every slot's ring of 34 pages."""
+    from gofr_tpu.models.afmoe import (FLOAT32_LEAVES, FULL, SLIDING,
+                                       AfmoeConfig, layer_shapes)
+    from gofr_tpu.tpu.paging import PagedLLMEngine
+
+    cfg = dataclasses.replace(
+        AfmoeConfig.trinity_large_preview_ep8(), n_layers=3,
+        layer_types=(SLIDING, SLIDING, FULL), attn_impl="flash")
+    chips = Chips(topo, 1)
+    engine = _engine_shell(PagedLLMEngine, cfg, None)
+    params = {
+        "tok_emb": chips.shape((cfg.vocab_size, cfg.dim), jnp.bfloat16),
+        "final_norm": chips.shape((cfg.dim,), jnp.bfloat16),
+        "lm_head": chips.shape((cfg.dim, cfg.vocab_size), jnp.bfloat16),
+        "layers": [{name: chips.shape(shape, jnp.float32 if name in FLOAT32_LEAVES
+                                      else jnp.bfloat16)
+                    for name, shape in layer_shapes(
+                        cfg, i < cfg.first_dense).items()}
+                   for i in range(cfg.n_layers)]}
+    model = engine.model
+    assert [(g.name, g.layers, g.ring(PAGE)) for g in model.groups] == [
+        ("full", 1, None), ("window", 2, 34)]
+    pools = tuple(chips.shape((group.layers,
+                               n_pages if group.window is None
+                               else slots * group.ring(PAGE) + 1,
+                               plane.heads, plane.width, PAGE), jnp.bfloat16)
+                  for group in model.groups for plane in model.planes)
+    return (cfg, chips, engine, params, pools, _loop_state(chips, slots),
+            chips.shape((2,), jnp.uint32))
+
+
+def test_afmoe_decode_step_compiles_with_both_groups_in_place(topo, as_tpu):
+    """The cell's decode program shape (32 slots, a full-group table 128
+    wide, the window group's ring of 34) at the published widths: the full
+    block's read under `paged_read`, the sliding blocks' under
+    `window_read` (a lower bound a row, its table a ring), the tiled gated
+    experts (two tiles of 1536 of an expert's 3072: three matrices of 18.9
+    MB do not fit VMEM twice over), a flush a page group outside the scan;
+    the four pools aliased and nothing pool-sized or expert-sized copied."""
+    from gofr_tpu.tpu.executor import _named_after
+
+    cfg, chips, engine, params, pools, loop, rng = _afmoe_programs(topo)
+    assert [p.shape for p in pools] == [(1, 2300, 8, 128, PAGE)] * 2 + [
+        (2, 1089, 8, 128, PAGE)] * 2
+    compiled = _compile(
+        _named_after(engine._decode_fn_paged(16, 128),
+                     "afmoe-paged-decode-x16-NP128"),
+        params, *pools, chips.shape((32, 128), jnp.int32),
+        chips.shape((32, 34), jnp.int32), *loop, rng, donate=(1, 2, 3, 4))
+    assert "HloModule jit_decode__x16_NP128," in compiled.as_text()
+    calls = _kernel_calls(compiled)
+    names = sorted(name.rsplit(".", 1)[0] for name, _, _ in calls)
+    assert names == ["moe_experts"] * 2 + ["paged_read"] + [
+        "paged_write"] * 2 + ["window_read"] * 2
+    bodies = _while_bodies(compiled)
+    for name, _, _ in calls:
+        inside = _computation_of(compiled, name) in bodies
+        assert inside == (not name.startswith("paged_write")), name
+    _assert_pool_in_place(compiled, pools)
+    expert = cfg.held * cfg.dim * cfg.expert_dim * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < expert
+
+
+def test_afmoe_prefill_compiles_windowed_and_in_pieces(topo, as_tpu):
+    """One prompt of the cell's widest bucket (12,288 tokens: K and V a head
+    stream, 6.3 MB): flash attention told the window on the sliding blocks,
+    the token-wise half in three pieces of 4,096 tokens, the tiled experts
+    as a grouped product, both groups' windows written into the donated
+    pools through a table a group."""
+    from gofr_tpu.tpu.executor import _named_after
+
+    cfg, chips, engine, params, pools, loop, rng = _afmoe_programs(topo)
+    rows, bucket = chips.shape((1,), jnp.int32), 12288
+    tables = [chips.shape((1, bucket // PAGE), jnp.int32)] * 2
+    compiled = _compile(
+        _named_after(engine._prefill_fn(bucket, 1),
+                     f"afmoe-paged-prefill-{bucket}x1"),
+        params, *pools, chips.shape((1, bucket), jnp.int32), *tables, rows,
+        rows, *loop, chips.shape((1,), jnp.float32), rng,
+        donate=(1, 2, 3, 4, 10, 11, 12))
+    assert f"HloModule jit_prefill__{bucket}x1," in compiled.as_text()
+    names = sorted(name.rsplit(".", 1)[0]
+                   for name, _, _ in _kernel_calls(compiled))
+    assert names == ["flash_prefill"] * 3 + ["moe_experts"] * 2
+    pool_bytes = sum(np.prod(p.shape) * p.dtype.itemsize for p in pools)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    # the window's temporaries, never a copy of a pool (1.1 and 1.06 GiB)
+    # nor the grouped experts' rows for every (token, pick) pair of 12k
+    assert mem.temp_size_in_bytes < 2 * GIB

@@ -40,6 +40,19 @@ and at the rule's own, the share of the device's peak bytes/s over the whole
 live pages, and the largest error against `mla_read_reference` on a layer
 that had the tail's tokens written column by column.
 
+Then (PR 33) the two reads of `trinity-large-preview-ep8.mixedlen-closed`,
+8 KV heads x 6 queries of 128, 32 rows of which 31 live at contexts of
+1,500-13,000 tokens, block 16: `window_read` over the window group (4
+layers x 1,089 pages, a ring of 34 pages a row, the lower bound position -
+4,095 a row) and `paged_read` over the full block beside it (1 layer x
+2,300 pages, a table 128 wide); microseconds a call, the share of the
+device's peak bytes/s over the tokens a row still sees, and the largest
+error against masked attention over the same pages. And beside them the
+two other kernels that cell added work to, alone: the tiled gated experts
+at 3072 x 3072 (32 held, the 12 a step of 31 rows touches) and the prefill's
+flash attention over 12,288 tokens of 48 / 8 heads, told the window and not
+(`only=trinity` runs this part alone).
+
 It is not the benchmark: it says what a kernel costs alone, never what a
 cell gains (PERF.md section 5 keeps its table). It refuses a device that
 is not in the benchmark's table of peaks: a CPU timing of the interpreter
@@ -390,9 +403,166 @@ def latent_lines(label: str, module, device, peak_bytes_s: float) -> None:
             flush=True)
 
 
+TRINITY = {"rows": 32, "kv": 8, "heads": 48, "window": 4096, "ring": 34,
+           "window_layers": 4, "full_pages": 2300, "full_table": 128,
+           "held": 32, "width": 3072, "touched": 12, "prompt": 12288}
+
+
+def trinity_lines(module, device, peak_bytes_s: float) -> None:
+    """The afmoe cell's reads, and its experts and prefill attention alone
+    (see the module docstring): one JSON line a measurement."""
+    g = TRINITY
+    rows, n_kv, heads, W, ring = (g["rows"], g["kv"], g["heads"],
+                                  g["window"], g["ring"])
+    rng = np.random.default_rng(4)
+    lengths = rng.integers(1500, 13000 - BLOCK, size=rows)
+    lengths[rows // 4] = 0         # a row that holds no request
+    live = lengths > 0
+    keys = jax.random.split(jax.random.PRNGKey(5), 6)
+    draw = lambda k, shape: jax.random.normal(           # noqa: E731
+        k, shape, jnp.float32).astype(jnp.bfloat16)
+    q = draw(keys[0], (rows, heads, DH))
+    news = [draw(k, (BLOCK, rows, n_kv, DH)) for k in keys[1:3]]
+    paged = jnp.asarray(np.where(live, lengths, 0), jnp.int32)
+
+    def line(what: str, us: float, **more) -> None:
+        print(json.dumps({"device": device.device_kind, "kernel": "tree",
+                          "what": what, "geometry": "trinity",
+                          "us": round(us, 1), **more}), flush=True)
+
+    def masked(q, keys_, values, seen):
+        """Attention of every row over [rows, S] gathered tokens."""
+        s = jnp.einsum("bhgd,bhds->bhgs", q.reshape(
+            rows, n_kv, heads // n_kv, DH).astype(jnp.float32),
+            keys_.astype(jnp.float32)) / DH ** 0.5
+        p = jax.nn.softmax(jnp.where(seen[:, None, None], s, -1e30), -1)
+        out = jnp.einsum("bhgs,bhds->bhgd", p, values.astype(jnp.float32))
+        return jnp.where(jnp.any(seen, -1)[:, None, None, None], out,
+                         0.0).reshape(rows, heads, DH)
+
+    for what, layers, pages, width, windowed in (
+            ("window_read", g["window_layers"], rows * ring + 1, ring, True),
+            ("paged_read", 1, g["full_pages"], g["full_table"], False)):
+        one = [draw(k, (pages, n_kv, DH, PS)) for k in keys[3:5]]
+        stack = jax.jit(lambda x: jnp.tile(x[None], (layers, 1, 1, 1, 1)))
+        k_pool, v_pool = stack(one[0]), stack(one[1])
+        table = np.zeros((rows, width), np.int32)
+        free = iter(rng.permutation(np.arange(1, pages)))
+        for b in np.flatnonzero(live):
+            held = min(-(-(lengths[b] + BLOCK) // PS), width)
+            table[b, :held] = [next(free) for _ in range(held)]
+        table = jnp.asarray(table)
+        more = {"window": W, "ring": ring} if windowed else {}
+
+        def run(q, k_pool, v_pool):
+            def layer(l, carry):
+                t, acc, k_tail, v_tail = carry
+                out, k_tail, v_tail = module.paged_attention_in_block(
+                    q, news[0][t], news[1][t], k_pool, v_pool, k_tail,
+                    v_tail, table, paged, jnp.where(live, t + 1, 0),
+                    layer=l, **more)
+                return t, acc + out.astype(jnp.float32), k_tail, v_tail
+
+            acc, k_tail, v_tail = jax.lax.fori_loop(
+                0, BLOCK, lambda t, carry: jax.lax.fori_loop(
+                    0, layers, layer, (t,) + carry)[1:],
+                (jnp.zeros(q.shape, jnp.float32),
+                 *module.block_tail(k_pool, rows, BLOCK)))
+            return acc + k_tail[0, :, 0, 0, :1][:, :, None]
+
+        us = (best_of_five(jax.jit(run), q, k_pool, v_pool)
+              / (layers * BLOCK) * 1e6)
+        # step 0 of a block against masked attention over the same pages:
+        # logical page j of a row in column j % ring (the ring) or j
+        got = jax.jit(lambda q, k, v: module.paged_attention_in_block(
+            q, news[0][0], news[1][0], k, v,
+            *module.block_tail(k, rows, BLOCK), table, paged,
+            jnp.where(live, 1, 0), layer=jnp.int32(layers - 1), **more)[0])(
+                q, k_pool, v_pool)
+        span = ring if windowed else width
+        first = (np.maximum(lengths + 1 - W, 0) // PS if windowed
+                 else np.zeros(rows, np.int64))
+        logical = first[:, None] + np.arange(span)[None, :]
+        columns = jnp.asarray(logical % ring if windowed else logical)
+        at = jnp.asarray(logical[:, :, None] * PS + np.arange(PS)).reshape(
+            rows, span * PS)
+        seen = at < paged[:, None]
+        if windowed:
+            seen = jnp.logical_and(seen, at >= (paged + 1 - W)[:, None])
+        gathered = [jnp.moveaxis(pool[-1][jnp.take_along_axis(
+            table, columns, 1)], 1, 3).reshape(rows, n_kv, DH, span * PS)
+            for pool in (k_pool, v_pool)]
+        # the step's own token, which the read puts into the tail
+        gathered = [jnp.concatenate([x, new[0][:, :, :, None]], -1)
+                    for x, new in zip(gathered, news)]
+        want = jax.jit(masked)(q, *gathered, jnp.concatenate(
+            [seen, jnp.asarray(live)[:, None]], -1))
+        tokens = int(np.minimum(lengths, W).sum() if windowed
+                     else lengths.sum())
+        floor_us = tokens * 2 * n_kv * DH * 2 / peak_bytes_s * 1e6
+        line(what, us, rows=int(live.sum()), tokens_seen=tokens,
+             block=BLOCK, pages_per_fold=module.fold_of((k_pool, v_pool),
+                                                        width),
+             seen_tokens_share_of_peak_pct=round(100 * floor_us / us, 1),
+             max_abs_err=float(np.max(np.abs(
+                 np.asarray(got, np.float32) - np.asarray(want)))))
+        del k_pool, v_pool, gathered
+
+    # the tiled gated experts, a decode step of 31 rows: 12 of 32 touched
+    from gofr_tpu.ops.moe_experts import decode_experts, width_tile
+
+    D = F = g["width"]
+    make = jax.jit(lambda k: (jax.random.normal(
+        k, (g["held"], F, D), jnp.float32) / D ** 0.5).astype(jnp.bfloat16))
+    w1, wg, w2 = (make(k) for k in jax.random.split(keys[5], 3))
+    x = draw(keys[0], (rows, D))
+    combine = np.zeros((rows, g["held"]), np.float32)
+    combine[np.arange(rows), np.arange(rows) % g["touched"]] = 0.5
+    combine = jnp.asarray(combine)
+    def steps(x, w1, wg, w2):
+        # STEPS calls in one program, each fed by the one before: one call
+        # alone is mostly the dispatch
+        def one(_, acc):
+            fed = x + (acc[:, :1] * 0.0).astype(x.dtype)
+            return acc + decode_experts(fed, w1, w2, combine, wg=wg)
+
+        return jax.lax.fori_loop(0, STEPS, one,
+                                 jnp.zeros((rows, D), jnp.float32))
+
+    us = best_of_five(jax.jit(steps), x, w1, wg, w2) / STEPS * 1e6
+    floor_us = g["touched"] * 3 * F * D * 2 / peak_bytes_s * 1e6
+    line("moe_experts", us, rows=rows, experts_touched=g["touched"],
+         tile=width_tile(F, D, 3, 2),
+         touched_matrices_share_of_peak_pct=round(100 * floor_us / us, 1))
+    del w1, wg, w2
+
+    # the prefill's flash attention over one prompt of 12,288 tokens
+    from gofr_tpu.models.afmoe import FLASH_BLOCKS
+    from gofr_tpu.ops.flash_attention import flash_attention
+
+    T = g["prompt"]
+    qp = draw(keys[0], (1, T, heads, DH))
+    kp, vp = draw(keys[1], (1, T, n_kv, DH)), draw(keys[2], (1, T, n_kv, DH))
+    peak_flops = peaks.of(device.device_kind)["bf16_flops"]
+    for window in (W, None):
+        fn = jax.jit(lambda q, k, v: flash_attention(
+            q, k, v, True, *FLASH_BLOCKS, window=window))
+        us = best_of_five(fn, qp, kp, vp) * 1e6
+        pairs = (T * (T + 1) // 2 if window is None
+                 else W * (W + 1) // 2 + (T - W) * W)
+        line("flash_prefill", us, tokens=T, window=window,
+             blocks=list(FLASH_BLOCKS),
+             seen_pairs_share_of_mxu_peak_pct=round(
+                 100 * (4 * pairs * heads * DH / peak_flops) / (us / 1e6),
+                 1))
+
+
 def main(argv) -> None:
     device = jax.devices()[0]
     peak_bytes_s = peaks.of(device.device_kind)["hbm_bytes_per_s"]
+    if "only=trinity" in argv:
+        return trinity_lines(gofr_tpu.ops.paged_attention, device,
+                             peak_bytes_s)
     kernels = {"tree": gofr_tpu.ops.paged_attention}
     kernels.update((label, load(label, path)) for label, path in
                    (arg.split("=", 1) for arg in argv))
@@ -431,6 +601,7 @@ def main(argv) -> None:
     for label, module in kernels.items():
         if hasattr(module, "plane_tail"):
             latent_lines(label, module, device, peak_bytes_s)
+    trinity_lines(gofr_tpu.ops.paged_attention, device, peak_bytes_s)
 
 
 if __name__ == "__main__":
