@@ -63,16 +63,55 @@ from .stepledger import StepLedger
 from .utilization import UtilizationLedger
 
 
+class LookupCount:
+    """What the `_<kind>_program` lookups cost (`/debug/engine` ->
+    `engine.program_lookup`). A MISS lowered its program or loaded it from
+    disk; its seconds are a compile's and are not counted here. The
+    seconds and the longest are the warm lookups': host arithmetic over
+    shapes, so `longest_ms` stays in the low milliseconds however deep the
+    device's queue is. The loop thread and warm-up both look up."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.lookups = self.misses = 0
+        self.seconds = self.longest = 0.0
+
+    def note(self, seconds: float, missed: bool) -> None:
+        with self._lock:
+            self.lookups += 1
+            if missed:
+                self.misses += 1
+                return
+            self.seconds += seconds
+            self.longest = max(self.longest, seconds)
+
+    def snapshot(self) -> Dict[str, Any]:
+        with self._lock:
+            return {"lookups_total": self.lookups,
+                    "misses_total": self.misses,
+                    "seconds_total": round(self.seconds, 6),
+                    "longest_ms": round(self.longest * 1e3, 3)}
+
+
 def program_lookup(fn):
-    """A `_<kind>_program` method: building the example arguments and the
-    Executor.compile lookup that finds the (warm) program. On the loop
-    thread that is the step ledger's `program_lookup` segment; elsewhere
-    (warm-up, scoring) the ledger's thread guard makes it a plain call."""
+    """A `_<kind>_program` method: describing the program's arguments
+    (the arrays the engine holds as they are, every other as a
+    `jax.ShapeDtypeStruct`) and the Executor.compile lookup that finds the
+    (warm) program. It makes no array and issues no device operation
+    (graftlint `hotloop` holds that), so it never waits for the device's
+    queue. On the loop thread it is the step ledger's `program_lookup`
+    segment; elsewhere (warm-up, scoring) the ledger's thread guard makes
+    it a plain call. Every one is counted in `self.lookups`."""
 
     @functools.wraps(fn)
     def lookup(self, *args, **kwargs):
+        held = self.executor.cache_size     # a miss adds a program to it
+        start = time.monotonic()
         with self.steps.seg("program_lookup"):
-            return fn(self, *args, **kwargs)
+            program = fn(self, *args, **kwargs)
+        self.lookups.note(time.monotonic() - start,
+                          self.executor.cache_size != held)
+        return program
 
     return lookup
 
@@ -816,12 +855,15 @@ class LLMEngine:
         # rolling throughput window
         self._tok_window: "collections.deque" = collections.deque()
 
+    def _temps_shape(self, rows: int) -> Tuple[int, ...]:
+        """Per-row sampling state, float32: [rows] temperatures, or
+        [rows, 3] (temperature, top_p, top_k) under sampling_controls."""
+        return (rows, 3) if self.sampling_controls else (rows,)
+
     def _temps_init(self, rows: int):
-        """Zeroed per-row sampling state: [rows] temperatures, or [rows, 3]
-        (temperature, top_p, top_k) under sampling_controls."""
+        """That state zeroed, on the device."""
         jnp = self._jnp
-        shape = (rows, 3) if self.sampling_controls else (rows,)
-        return jnp.zeros(shape, dtype=jnp.float32)
+        return jnp.zeros(self._temps_shape(rows), dtype=jnp.float32)
 
     # -- public API -----------------------------------------------------------
     @property

@@ -57,7 +57,8 @@ from ..ops.paged_attention import (flush_planes, fold_of, holds_request,
                                    paged_write_window, plane_tail,
                                    quantize_kv)
 from .engine import (CacheLostError, GenerationRequest, LLMEngine,
-                     _admission_widths, _pin_standard_layout, program_lookup)
+                     LookupCount, _admission_widths, _pin_standard_layout,
+                     program_lookup)
 from .ownership import loop_only
 
 
@@ -101,6 +102,17 @@ class PageAllocator:
 
 def _pow2_at_least(n: int) -> int:
     return 1 << max(0, (n - 1).bit_length())
+
+
+def _shaped(shape, dtype=np.int32):
+    """An argument a program lookup describes and does not hold: shape and
+    dtype, no array. A lookup makes nothing on the device, so it waits
+    for nothing queued there; a miss lowers from these as it would from
+    uncommitted arrays of zeros (`Executor.compile` reads shape and dtype,
+    and no device, of a leaf without a sharding)."""
+    import jax
+
+    return jax.ShapeDtypeStruct(shape, dtype)
 
 
 class PagedLLMEngine(LLMEngine):
@@ -288,6 +300,8 @@ class PagedLLMEngine(LLMEngine):
         # is written whole by its prefill at admission
         self.state = tuple(jnp.zeros(shape, dtype=dtype)
                            for shape, dtype in self.model.state_shapes(B))
+        # the program lookups since the last reset (`program_lookup`)
+        self.lookups = LookupCount()
         # the family's decode counters, summed since the last reset
         self.model_counts = np.zeros(len(self.model.counters), np.int64)
         self.model_count_steps = 0
@@ -788,14 +802,11 @@ class PagedLLMEngine(LLMEngine):
 
     @program_lookup
     def _restore_program(self, n: int):
-        jnp = self._jnp
         L, _, Hkv, dh, ps = self.k_cache.shape
-        kv = (jnp.zeros((L, n, Hkv, dh, ps), dtype=self.k_cache.dtype),
-              jnp.zeros((L, n, Hkv, dh, ps), dtype=self.k_cache.dtype))
-        ids = jnp.zeros((n,), dtype=jnp.int32)
+        kv = (_shaped((L, n, Hkv, dh, ps), self.k_cache.dtype),) * 2
+        ids = _shaped((n,))
         if self._q8:
-            scales = (jnp.zeros((L, n, Hkv, ps), dtype=jnp.float32),
-                      jnp.zeros((L, n, Hkv, ps), dtype=jnp.float32))
+            scales = (_shaped((L, n, Hkv, ps), np.float32),) * 2
             args = (self.k_cache, self.v_cache, self.k_scale, self.v_scale,
                     ids, *kv, *scales)
             return self.executor.compile(
@@ -1014,29 +1025,25 @@ class PagedLLMEngine(LLMEngine):
 
     @program_lookup
     def _prefill_program(self, bucket: int, K: int):
-        jnp = self._jnp
         n_ptable = max(1, math.ceil(bucket / self.page_size))
+        new_temps = _shaped(self._temps_shape(K), np.float32)
         if self._q8:
             args = (self.params, self.k_cache, self.v_cache, self.k_scale,
                     self.v_scale,
-                    jnp.zeros((K, bucket), dtype=jnp.int32),
-                    jnp.zeros((K, n_ptable), dtype=jnp.int32),
-                    jnp.zeros((K,), dtype=jnp.int32),
-                    jnp.ones((K,), dtype=jnp.int32),
+                    _shaped((K, bucket)), _shaped((K, n_ptable)),
+                    _shaped((K,)), _shaped((K,)),
                     self._tokens, self._positions, self._temps,
-                    self._temps_init(K), self.rng)
+                    new_temps, self.rng)
             return self.executor.compile(
                 f"llama-paged-prefill-q8-{bucket}x{K}{self._id_tag}",
                 self._prefill_fn_q8(bucket, K),
                 args, donate_argnums=(1, 2, 3, 4, 9, 10, 11))
         args = (self.params, *self.pools,
-                jnp.zeros((K, bucket), dtype=jnp.int32),
-                *[jnp.zeros((K, n_ptable), dtype=jnp.int32)
-                  for _ in self.allocators],
-                jnp.zeros((K,), dtype=jnp.int32),
-                jnp.ones((K,), dtype=jnp.int32),
+                _shaped((K, bucket)),
+                *[_shaped((K, n_ptable)) for _ in self.allocators],
+                _shaped((K,)), _shaped((K,)),
                 self._tokens, self._positions, self._temps,
-                self._temps_init(K), self.rng, *self.state)
+                new_temps, self.rng, *self.state)
         # the pools, the loop's vectors (past the tables, one a group),
         # the state
         n = len(self.pools)
@@ -1141,19 +1148,17 @@ class PagedLLMEngine(LLMEngine):
 
     @program_lookup
     def _decode_program_paged(self, n_table: int, block: Optional[int] = None):
-        jnp = self._jnp
         block = block or self.decode_block_size
         if self._q8:
             args = (self.params, self.k_cache, self.v_cache, self.k_scale,
-                    self.v_scale,
-                    jnp.zeros((self.n_slots, n_table), dtype=jnp.int32),
+                    self.v_scale, _shaped((self.n_slots, n_table)),
                     self._tokens, self._positions, self._temps, self.rng)
             return self.executor.compile(
                 f"llama-paged-decode-q8-x{block}-NP{n_table}{self._id_tag}",
                 self._decode_fn_paged_q8(block, n_table), args,
                 donate_argnums=(1, 2, 3, 4))
         args = (self.params, *self.pools,
-                *[jnp.zeros((self.n_slots, width), dtype=jnp.int32)
+                *[_shaped((self.n_slots, width))
                   for width in self._table_widths(n_table)],
                 self._tokens, self._positions, self._temps, self.rng,
                 *self.state)
@@ -1267,33 +1272,26 @@ class PagedLLMEngine(LLMEngine):
         """Chunk programs key on (chunk, K) and on the BUCKET (the temp
         caches are bucket-wide); buckets above the chunk size are few, so
         the compile set stays bounded."""
-        jnp = self._jnp
         from ..models.llama import _np_dtype
 
         Hkv, dh = self.cfg.n_kv_heads, self.cfg.head_dim
         L = self.cfg.n_layers
         dt = _np_dtype(self.cfg.dtype)
-        tmp = tuple(jnp.zeros((K, Hkv, dh, bucket), dtype=dt)
-                    for _ in range(L))
-        common = (jnp.zeros((K, chunk), dtype=jnp.int32),
-                  jnp.zeros((K, chunk), dtype=jnp.int32))
+        tmp = tuple(_shaped((K, Hkv, dh, bucket), dt) for _ in range(L))
+        common = (_shaped((K, chunk)), _shaped((K, chunk)))
+        selected = _shaped((K, self.cfg.vocab_size), np.float32)
         if not final:
             args = (self.params, tmp, tmp, *common,
-                    jnp.ones((K,), dtype=jnp.int32),
-                    jnp.zeros((), dtype=jnp.int32),
-                    jnp.zeros((K, self.cfg.vocab_size), dtype=jnp.float32))
+                    _shaped((K,)), _shaped(()), selected)
             return self.executor.compile(
                 f"llama-paged-chunk-{chunk}x{K}-b{bucket}{self._id_tag}",
                 self._chunk_fn_paged(chunk, K, final=False), args,
                 donate_argnums=(1, 2, 7))
         n_ptable = max(1, math.ceil(bucket / self.page_size))
-        tail = (jnp.zeros((K, n_ptable), dtype=jnp.int32),
-                jnp.zeros((K,), dtype=jnp.int32),
-                jnp.ones((K,), dtype=jnp.int32),
-                jnp.zeros((), dtype=jnp.int32),
-                jnp.zeros((K, self.cfg.vocab_size), dtype=jnp.float32),
+        tail = (_shaped((K, n_ptable)), _shaped((K,)), _shaped((K,)),
+                _shaped(()), selected,
                 self._tokens, self._positions, self._temps,
-                self._temps_init(K), self.rng)
+                _shaped(self._temps_shape(K), np.float32), self.rng)
         if self._q8:
             args = (self.params, self.k_cache, self.v_cache, self.k_scale,
                     self.v_scale, tmp, tmp, *common, *tail)
@@ -1461,13 +1459,11 @@ class PagedLLMEngine(LLMEngine):
 
     @program_lookup
     def _verify_program(self, n_table: int):
-        jnp = self._jnp
         d = self.speculative_tokens
         args = (self.params, self.k_cache, self.v_cache,
-                jnp.zeros((self.n_slots, n_table), dtype=jnp.int32),
+                _shaped((self.n_slots, n_table)),
                 self._tokens, self._positions, self._temps, self.rng,
-                jnp.zeros((self.n_slots, d), dtype=jnp.int32),
-                jnp.zeros((self.n_slots,), dtype=jnp.int32))
+                _shaped((self.n_slots, d)), _shaped((self.n_slots,)))
         name = f"llama-paged-verify-x{d}-NP{n_table}{self._id_tag}"
         return self.executor.compile(name, self._verify_fn_paged(d, n_table),
                                      args, donate_argnums=(1, 2))
@@ -1547,14 +1543,10 @@ class PagedLLMEngine(LLMEngine):
 
     @program_lookup
     def _prefix_program(self, bucket: int, K: int, n_table: int):
-        jnp = self._jnp
-        common = (jnp.zeros((K, bucket), dtype=jnp.int32),
-                  jnp.zeros((K, n_table), dtype=jnp.int32),
-                  jnp.zeros((K,), dtype=jnp.int32),
-                  jnp.zeros((K,), dtype=jnp.int32),
-                  jnp.ones((K,), dtype=jnp.int32),
+        common = (_shaped((K, bucket)), _shaped((K, n_table)),
+                  _shaped((K,)), _shaped((K,)), _shaped((K,)),
                   self._tokens, self._positions, self._temps,
-                  self._temps_init(K), self.rng)
+                  _shaped(self._temps_shape(K), np.float32), self.rng)
         if self._q8:
             args = (self.params, self.k_cache, self.v_cache, self.k_scale,
                     self.v_scale, *common)

@@ -24,9 +24,12 @@ explicit ``other`` residual means nothing can hide):
                    exchange on the multi-controller plane
   ``program_lookup``  every ``Executor.compile`` lookup the loop makes
                    for a step program (prefill / prefix / decode / chunk /
-                   verify): building the example arguments and hashing
-                   the argument tree to find the cached program. A cache
-                   MISS's compile time is re-attributed to ``compile``
+                   verify / restore): describing the arguments (shapes and
+                   dtypes: no array is made, so no wait for the device's
+                   queue) and hashing the argument tree to find the cached
+                   program. A cache MISS's compile time is re-attributed
+                   to ``compile``; ``/debug/engine`` counts the lookups
+                   (``engine.program_lookup``)
   ``bind``         after a prefill dispatch: slot binding, request
                    stamps, page assignment and the prefix-cache insert
   ``page_alloc``   page reservation / prefix-cache match /

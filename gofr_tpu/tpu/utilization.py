@@ -510,6 +510,7 @@ def engine_snapshot(engine, tpu=None) -> Dict[str, Any]:
             "speculative_tokens": engine.speculative_tokens,
             "queue_depth": engine._pending.qsize(),
             **_pipeline_counts(engine),
+            "program_lookup": engine.lookups.snapshot(),
             "draining": engine._draining,
             "stall_seconds": round(engine.stall_seconds, 1),
         },

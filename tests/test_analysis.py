@@ -98,6 +98,29 @@ def test_hotloop_fixture_flags_and_decoys():
     assert report.exit_code == 1
 
 
+@pytest.mark.parametrize("method, found", [
+    # an array made to describe a shape: through `self._jnp`'s local name,
+    # through the module's `jnp`, through `jax.random`
+    ("Paged._prefill_program", {("lookup.zeros", None), ("lookup.ones", None),
+                                ("lookup.PRNGKey", None)}),
+    # the decoy: jax.ShapeDtypeStruct, jnp.dtype and numpy describe, or stay
+    # on the host
+    ("Paged._decode_program", set()),
+    ("Paged._restore_program", {
+        ("lookup.zeros", "the fixture's designated array")}),
+    # not a lookup: the engine's own state is made with jnp.zeros
+    ("Paged._init_device_state", set()),
+])
+def test_a_program_lookup_that_makes_an_array_is_a_hotloop_finding(method,
+                                                                   found):
+    """Inside `@program_lookup` a call rooted at jax / jnp / self._jnp
+    that makes an array waits out the device's queue (PR 36)."""
+    report = _fixture_run("lookup", "hotloop")
+    assert {(f.symbol, f.suppressed) for f in report.findings
+            if f.qualname == method} == found
+    assert report.exit_code == 1
+
+
 # -- clock --------------------------------------------------------------------
 
 def test_clock_fixture_flags_and_scope():

@@ -18,6 +18,14 @@ graph. Inside that set we flag:
   the implicit ``__float__`` on a DeviceArray syncs just as hard as
   ``.item()``
 
+A second check holds the program lookups (PR 36): inside a method
+decorated ``@program_lookup`` (`tpu/paging.py`'s ``_<kind>_program``),
+a call rooted at ``jax`` / ``jnp`` / ``self._jnp`` (or a local name bound
+to it) is a finding unless it only DESCRIBES an array
+(``jax.ShapeDtypeStruct``, ``jnp.dtype``): ``jnp.zeros`` there runs an
+executable on the device's stream and returned when everything queued
+before it had run, 70-545 ms at a time, to tell the executor a shape.
+
 The loop necessarily syncs SOMEWHERE — the designated sync points
 (`_sync_oldest`'s completion check, the hand-off fetch) carry
 ``# lint: hotloop-ok <reason>`` pragmas; everything else is a
@@ -49,9 +57,18 @@ _NUMPY_SYNC_FNS = ("asarray", "array")
 _COERCIONS = ("float", "int", "bool")
 
 
+# a lookup may describe an array; it may not make one
+LOOKUP_DECORATOR = "program_lookup"
+_DESCRIBES = ("ShapeDtypeStruct", "dtype")
+
+
 def is_root(fn_name: str, relpath: str) -> bool:
     return relpath.startswith(ROOT_DIR) and any(
         fnmatch.fnmatchcase(fn_name, pat) for pat in ROOT_PATTERNS)
+
+
+def _is_device_root(root) -> bool:
+    return root in _DEVICE_ROOTS or (root or "").startswith("jax.")
 
 
 def _device_tainted_names(project: Project, mod: ModuleInfo,
@@ -68,7 +85,7 @@ def _device_tainted_names(project: Project, mod: ModuleInfo,
         produced = False
         fn = val.func
         root = project.alias_root(mod, fn)
-        if root in _DEVICE_ROOTS or (root or "").startswith("jax."):
+        if _is_device_root(root):
             produced = True
         elif isinstance(fn, ast.Attribute) and fn.attr == "run":
             owner = fn.value
@@ -96,16 +113,82 @@ def _device_arg(project: Project, mod: ModuleInfo, arg: ast.expr,
     if isinstance(arg, ast.Subscript):
         return isinstance(arg.value, ast.Name) and arg.value.id in tainted
     if isinstance(arg, ast.Call):
-        root = project.alias_root(mod, arg.func)
-        return root in _DEVICE_ROOTS or (root or "").startswith("jax.")
+        return _is_device_root(project.alias_root(mod, arg.func))
     return False
+
+
+def _is_lookup(fn_node) -> bool:
+    for dec in getattr(fn_node, "decorator_list", ()):
+        name = dec.attr if isinstance(dec, ast.Attribute) \
+            else getattr(dec, "id", None)
+        if name == LOOKUP_DECORATOR:
+            return True
+    return False
+
+
+def _is_engine_jnp(node: ast.expr) -> bool:
+    return (isinstance(node, ast.Attribute) and node.attr == "_jnp"
+            and isinstance(node.value, ast.Name) and node.value.id == "self")
+
+
+def _through_engine_jnp(node: ast.expr) -> bool:
+    """`self._jnp.zeros`, `self._jnp.linalg.norm`: some link of the
+    attribute chain is the engine's `self._jnp`."""
+    while isinstance(node, ast.Attribute):
+        if _is_engine_jnp(node):
+            return True
+        node = node.value
+    return False
+
+
+def _jnp_names(fn_node) -> set:
+    """Local names bound to the engine's `self._jnp`: `jnp = self._jnp`,
+    `model, jnp = self.model, self._jnp`."""
+    names = set()
+    for node in ast.walk(fn_node):
+        if not isinstance(node, ast.Assign):
+            continue
+        for tgt in node.targets:
+            pairs = [(tgt, node.value)]
+            if (isinstance(tgt, ast.Tuple)
+                    and isinstance(node.value, ast.Tuple)
+                    and len(tgt.elts) == len(node.value.elts)):
+                pairs = list(zip(tgt.elts, node.value.elts))
+            names.update(t.id for t, v in pairs
+                         if isinstance(t, ast.Name) and _is_engine_jnp(v))
+    return names
+
+
+def _lookup_findings(project: Project) -> List[Finding]:
+    findings: List[Finding] = []
+    for key in sorted(project.functions):
+        fn = project.functions[key]
+        if not _is_lookup(fn.node):
+            continue
+        mod = project.modules[fn.relpath]
+        local = _jnp_names(fn.node)
+        for node in ast.walk(fn.node):
+            if not (isinstance(node, ast.Call)
+                    and isinstance(node.func, ast.Attribute)):
+                continue
+            root = project.alias_root(mod, node.func)
+            on_device = (_through_engine_jnp(node.func) or root in local
+                         or _is_device_root(root))
+            if on_device and node.func.attr not in _DESCRIBES:
+                findings.append(Finding(
+                    RULE, fn.relpath, fn.qualname, f"lookup.{node.func.attr}",
+                    "a program lookup makes an array (%s): it waits for "
+                    "everything queued on the device to learn a shape; "
+                    "describe it with jax.ShapeDtypeStruct"
+                    % node.func.attr, node.lineno))
+    return findings
 
 
 def run(project: Project) -> List[Finding]:
     roots = [fn.key for fn in project.functions.values()
              if is_root(fn.name, fn.relpath)]
     hot = project.reachable(sorted(roots))
-    findings: List[Finding] = []
+    findings: List[Finding] = _lookup_findings(project)
     for key in sorted(hot):
         fn = project.functions[key]
         mod = project.modules[fn.relpath]
