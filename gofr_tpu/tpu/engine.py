@@ -230,7 +230,18 @@ class GenerationRequest:
         self.enqueued_at = time.monotonic()
         # first time _admit moved it from the queue into the admission heap
         self.dequeued_at: Optional[float] = None
-        self.admitted_at: Optional[float] = None   # prefill dispatch time
+        # `_admit` took it for this round: a slot, its pages and the
+        # admission cap are settled; what follows is the loop's own work
+        self.granted_at: Optional[float] = None
+        # its prefill program has been enqueued on the device (stamped
+        # once the program call has returned)
+        self.admitted_at: Optional[float] = None
+        # the deque's contents ahead of that program at the enqueue:
+        # decode steps (d + 1 a verify) and other prefill programs
+        self.ahead_steps: Optional[int] = None
+        self.ahead_prefills: Optional[int] = None
+        # decode steps its row computed after its last token
+        self.overrun_steps: Optional[int] = None
         self.first_token_at: Optional[float] = None
         self.finished_at: Optional[float] = None
         self.generated = 0
@@ -734,6 +745,10 @@ class LLMEngine:
         # and IncidentManager.trigger never blocks the loop (captures run
         # on a daemon thread)
         self.incidents = None
+        # host sampling profiler (tpu/hostprof.py): None unless
+        # App.enable_hostprof attaches one; a flagged step asks it
+        # whether the host stood still, incident bundles for its stacks
+        self.hostprof = None
         # QoS serving plane (tpu/qos.py): None unless App.enable_qos wires
         # a QoSController — same zero-overhead contract as the planes
         # above (one attribute check per submit / admission round)
@@ -836,6 +851,11 @@ class LLMEngine:
         # /debug/engine reads)
         self.decode_syncs_total = 0
         self.dry_syncs_total = 0
+        # row-steps the decode blocks and verifies read so far computed
+        # (rows of the snapshot x steps), and those of them computed for a
+        # row after its request's last token (`_overrun_steps`)
+        self.row_steps_total = 0
+        self.overrun_steps_total = 0
 
         # wedge detection: the loop stamps this every iteration; a stamp
         # that stops moving while work is in flight means the thread is
@@ -1638,19 +1658,28 @@ class LLMEngine:
             phase, rows, queued = staged
             self.meter.account_step(rec, phase, rows, queued)
         if rec.straggler:
+            # did the host itself stand still? The sampler thread's
+            # lateness inside this step's wall (tpu/hostprof.py): late too
+            # = the machine's stall; on time = the device's or the
+            # runtime's. None without a sampler
+            host_late_ms = (self.hostprof.late_ms_within(
+                rec.started_at, rec.started_at + rec.wall_s)
+                if self.hostprof is not None else None)
             if self.recorder is not None:
                 self.recorder.record_engine_event(
                     "step_straggler", step=rec.seq, phase=rec.phase,
                     wall_s=round(rec.wall_s, 6), cause=rec.cause,
                     baseline_s=round(rec.baseline_s or 0.0, 6),
-                    request_id=rec.slowest_request_id)
+                    request_id=rec.slowest_request_id,
+                    host_late_ms=host_late_ms)
             if self.incidents is not None:
                 # a streak of flagged steps (not one) escalates to an
                 # incident; the manager does the streak accounting
                 self.incidents.note_straggler(
                     step=rec.seq, phase=rec.phase, cause=rec.cause,
                     wall_s=round(rec.wall_s, 6),
-                    request_id=rec.slowest_request_id)
+                    request_id=rec.slowest_request_id,
+                    host_late_ms=host_late_ms)
 
     def _breaker_probe(self) -> None:
         """The reset-storm breaker's half-open probe: ONE tiny device
@@ -1828,6 +1857,9 @@ class LLMEngine:
             if not self._admission_ready(request):
                 heapq.heappush(self._admission_heap, entry)  # stays parked
                 break
+            # granted: from here to `admitted_at` the request waits for
+            # nothing but this round's own prep, lookup and enqueue
+            request.granted_at = time.monotonic()
             taken.append(request)
         if not taken:
             return
@@ -1957,7 +1989,12 @@ class LLMEngine:
         # prefix, chunk final) — they all bind through here
         admitted = []
         now = time.monotonic()
+        # what this wave's program was enqueued behind: the deque's own
+        # contents (mirrored state under an admission plane)
+        ahead_steps, ahead_prefills = self._queued_ahead()
         for row, request in enumerate(batch):
+            request.ahead_steps = ahead_steps
+            request.ahead_prefills = ahead_prefills
             if request.admitted_at is None:  # chunk jobs stamped at chunk 1
                 request.admitted_at = now
                 self._obs.hist("app_tpu_queue_wait_seconds",
@@ -1999,6 +2036,32 @@ class LLMEngine:
         """Decode blocks and verifies in flight: the deque less its
         prefill entries."""
         return sum(1 for e in self._inflight if e[0] != "prefill")
+
+    def _queued_ahead(self) -> Tuple[int, int]:
+        """(decode steps, prefill programs) the deque holds: what a
+        program enqueued now runs behind, but for the part of the oldest
+        entry the device has already done. A decode entry's fourth field
+        is its block, a verify's its d drafts (d + 1 positions)."""
+        steps = prefills = 0
+        for entry in self._inflight:
+            if entry[0] == "prefill":
+                prefills += 1
+            else:
+                steps += entry[3] + (entry[0] == "verify")
+        return steps, prefills
+
+    def _overrun_steps(self, slot_idx: int, request: "GenerationRequest",
+                       unread: int) -> None:
+        """Decode steps the row `slot_idx` computes for `request` after
+        its last token, noted on the request as it finishes: `unread`
+        steps of the entry being read, and every step of the decode
+        blocks already queued whose snapshot holds it (the module
+        docstring's junk decoding of a freed slot)."""
+        held = (slot_idx, request)
+        steps = unread + sum(e[3] for e in self._inflight
+                             if e[0] == "decode" and held in e[2])
+        request.overrun_steps = steps
+        self.overrun_steps_total += steps
 
     def _room_for_decode(self) -> bool:
         """Whether the loop's top-up dispatches one more decode block.
@@ -2144,6 +2207,7 @@ class LLMEngine:
                 n_first += 1
                 if (request.hit_stop(token) or slot.remaining <= 0
                         or self._is_cancelled(request)):
+                    self._overrun_steps(slot_idx, request, 0)
                     self._finish_slot(slot)
                 elif self.disagg_role == "prefill":
                     # disaggregated prefill pool: the slot never enters
@@ -2223,6 +2287,7 @@ class LLMEngine:
                     self.recorder.record_decode_block(
                         request.id, n, elapsed / n)
                 if finishes[j]:
+                    self._overrun_steps(slot_idx, request, d + 1 - n)
                     self._finish_slot(slot)
             if emitted:
                 self._obs.counter("app_tpu_tokens_generated_total",
@@ -2240,6 +2305,7 @@ class LLMEngine:
                               else None))
             self._obs.hist("app_tpu_batch_size", n_active)
             self._track_throughput(emitted)
+            self.row_steps_total += len(snapshot) * (d + 1)
             # adaptive speculation: fold this dispatch's accepted-per-
             # GREEDY-ELIGIBLE-slot into the EMA; a cold streak pauses
             # verifies for a stretch of pipelined block decodes (the loop
@@ -2324,6 +2390,7 @@ class LLMEngine:
                 # per token), recorded before the slot can go terminal
                 self.recorder.record_decode_block(request.id, n, step_s)
             if finishes[j]:
+                self._overrun_steps(slot_idx, request, block - n)
                 self._finish_slot(slot)
         if emitted:
             self._obs.counter("app_tpu_tokens_generated_total",
@@ -2334,6 +2401,7 @@ class LLMEngine:
         dry = queued_behind == 0 and any(s.active for s in self.slots)
         self.decode_syncs_total += 1
         self.dry_syncs_total += dry
+        self.row_steps_total += len(snapshot) * block
         self._obs.gauge("app_tpu_decode_blocks_queued", queued_behind)
         # every token in this sync shares one measured step time: record the
         # TPOT histogram ONCE per sync, not per token (VERDICT r2 weak #9)

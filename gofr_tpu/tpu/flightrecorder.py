@@ -4,8 +4,8 @@ The aggregate surfaces (metrics histograms, the HTTP span) answer "how is
 the fleet doing"; this module answers "where did THIS request spend its
 time" — the question a blown TTFT budget raises. It keeps a bounded,
 thread-safe ring of per-request event timelines covering the engine
-lifecycle the HTTP trace cannot see (enqueued → dequeued → admitted →
-prefill → first token → decode blocks → finished/aborted), and on completion:
+lifecycle the HTTP trace cannot see (enqueued → dequeued → granted →
+admitted → first token → decode blocks → finished/aborted), and on completion:
 
   * synthesizes engine child spans (``engine.queue`` / ``engine.prefill``
     / ``engine.decode``) through the existing tracing.Tracer, parented
@@ -59,7 +59,8 @@ class RequestRecord:
 
     __slots__ = ("id", "prompt_tokens", "max_new_tokens", "priority",
                  "trace_id", "parent_span_id", "enqueued_at", "dequeued_at",
-                 "admitted_at", "first_token_at", "finished_at", "generated",
+                 "granted_at", "admitted_at", "first_token_at", "finished_at",
+                 "generated", "ahead_steps", "ahead_prefills", "overrun_steps",
                  "outcome", "error", "slot", "bucket", "batch_id", "chunked",
                  "handoff", "events", "events_dropped", "wall0", "mono0")
 
@@ -78,10 +79,24 @@ class RequestRecord:
         # the engine loop picked it up (queue -> admission heap): splits
         # the queue wait into loop-away (pickup) and parked-for-a-resource
         self.dequeued_at: Optional[float] = None
+        # `_admit` took it: a slot is free, its pages are reserved and the
+        # admission cap let it through. What follows is the loop's own
+        # work on its wave (host prep, the program lookup, the enqueue)
+        self.granted_at: Optional[float] = None
+        # its prefill program has been ENQUEUED on the device (stamped
+        # after the program call returned), not started and not done
         self.admitted_at: Optional[float] = None
         self.first_token_at: Optional[float] = None
         self.finished_at: Optional[float] = None
         self.generated = 0
+        # what the device's queue held ahead of its prefill program at
+        # the enqueue: decode steps (a block's steps, d + 1 a verify) and
+        # other prompts' prefill programs, still unread by the loop
+        self.ahead_steps: Optional[int] = None
+        self.ahead_prefills: Optional[int] = None
+        # decode steps its row computed after its last token: the rest of
+        # the block it finished in and every block already queued
+        self.overrun_steps: Optional[int] = None
         self.outcome: Optional[str] = None
         self.error: Optional[str] = None
         self.slot: Optional[int] = None
@@ -118,18 +133,25 @@ class RequestRecord:
         """Monotonic, non-overlapping phase durations: queue is
         enqueued→admitted, prefill is admitted→first token, decode is
         first token→finish. A phase a request never reached is absent.
-        The queue wait splits at `dequeued`: pickup (the loop had not come
-        round to the queue) + parked (picked up, waiting for a slot, pages
-        or the admission cap), so ttft_s == pickup_s + parked_s +
-        prefill_s."""
+        The queue wait splits at `dequeued` and at `granted`: pickup (the
+        loop had not come round to the queue) + parked (picked up, waiting
+        for a slot, pages or the admission cap) + dispatch (granted: the
+        loop preparing and enqueueing its wave's prefill program, which
+        is where the runtime holds the host while the device's queue is
+        full), so ttft_s == pickup_s + parked_s + dispatch_s + prefill_s.
+        A record without `granted_at` has no dispatch_s and its parked_s
+        runs to `admitted`, as both did before the stamp."""
         out: Dict[str, float] = {}
         if self.dequeued_at is not None:
             out["pickup_s"] = max(0.0, self.dequeued_at - self.enqueued_at)
         if self.admitted_at is not None:
             out["queue_s"] = max(0.0, self.admitted_at - self.enqueued_at)
+            granted = self.admitted_at
+            if self.granted_at is not None:
+                granted = self.granted_at
+                out["dispatch_s"] = max(0.0, self.admitted_at - granted)
             if self.dequeued_at is not None:
-                out["parked_s"] = max(
-                    0.0, self.admitted_at - self.dequeued_at)
+                out["parked_s"] = max(0.0, granted - self.dequeued_at)
             if self.first_token_at is not None:
                 out["prefill_s"] = max(
                     0.0, self.first_token_at - self.admitted_at)
@@ -165,7 +187,8 @@ class RequestRecord:
             "phases": self.phases(),
         }
         for key in ("outcome", "error", "slot", "bucket", "batch_id",
-                    "trace_id"):
+                    "trace_id", "ahead_steps", "ahead_prefills",
+                    "overrun_steps"):
             value = getattr(self, key)
             if value is not None:
                 out[key] = value
@@ -296,11 +319,18 @@ class FlightRecorder:
                 if rec.admitted_at is not None:
                     return  # chunk path: admitted at chunk 1, bound later
                 rec.admitted_at = request.admitted_at or time.monotonic()
+                rec.granted_at = getattr(request, "granted_at", None)
                 rec.slot = slot
                 rec.bucket = bucket
                 rec.chunked = chunked
-                rec.add_event("admitted", {"slot": slot, "bucket": bucket},
-                              self.max_events, t=rec.admitted_at)
+                data = {"slot": slot, "bucket": bucket}
+                for key in ("ahead_steps", "ahead_prefills"):
+                    value = getattr(request, key, None)
+                    if value is not None:
+                        setattr(rec, key, value)
+                        data[key] = value
+                rec.add_event("admitted", data, self.max_events,
+                              t=rec.admitted_at)
         except Exception:  # noqa: BLE001
             pass
 
@@ -352,6 +382,7 @@ class FlightRecorder:
                     return
                 rec.finished_at = request.finished_at or time.monotonic()
                 rec.generated = request.generated
+                rec.overrun_steps = getattr(request, "overrun_steps", None)
                 rec.outcome = reason
                 if request.error is not None:
                     rec.error = str(request.error)
@@ -506,10 +537,14 @@ class FlightRecorder:
                 "trace_id": r.trace_id,
                 "enqueued_at": r.enqueued_at,
                 "dequeued_at": r.dequeued_at,
+                "granted_at": r.granted_at,
                 "admitted_at": r.admitted_at,
                 "first_token_at": r.first_token_at,
                 "finished_at": r.finished_at,
                 "generated": r.generated,
+                "ahead_steps": r.ahead_steps,
+                "ahead_prefills": r.ahead_prefills,
+                "overrun_steps": r.overrun_steps,
                 "outcome": r.outcome,
                 "handoff": r.handoff,
             } for r in recs]

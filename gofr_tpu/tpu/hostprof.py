@@ -22,7 +22,16 @@ in production:
   * the sampler measures ITS OWN cost — the wall time spent inside
     sampling iterations — and reports it in its output, so "is the
     profiler cheap enough" is answered by the profiler
-    (acceptance: < 2% of loop wall-clock at the default rate).
+    (acceptance: < 2% of loop wall-clock at the default rate);
+  * the sampler notes how LATE each of its own wake-ups came against the
+    interval it asked for. A thread that asks for 20 ms and is given the
+    processor 2 s later says the host stood still: the machine's stall,
+    not the device's. The longest lateness and a short ring of those
+    over 100 ms are in the payload, and the engine puts the lateness
+    inside a flagged step's wall on its ``step_straggler`` event
+    (``host_late_ms``): a stall with the sampler late too is the
+    machine's; one with the sampler on time is the device's or the
+    runtime's.
 
 Operator surface (install_routes / App.enable_hostprof):
 
@@ -42,6 +51,7 @@ under the ownership pass by construction; its stamps are all
 
 from __future__ import annotations
 
+import collections
 import sys
 import threading
 import time
@@ -59,6 +69,10 @@ MAX_DEPTH = 32
 # interval so the measured share converges below this, half the 2%
 # always-on acceptance bound
 OVERHEAD_BUDGET = 0.01
+# a wake-up later than this against the interval asked for is kept in the
+# ring of late wake-ups (a sampler's ordinary jitter is a millisecond)
+LATE_RING_S = 0.1
+LATE_RING = 32
 
 CLASSES = ("loop", "finisher", "http", "other")
 
@@ -97,6 +111,12 @@ class HostProfiler:
         self._throttled = 0       # intervals the governor stretched
         self._interval_eff = self.interval_s
         self._started_mono: Optional[float] = None
+        # how late the sampler's own wake-ups came: when the sleep in
+        # progress is due to end, the longest lateness so far, and the
+        # (woke at, seconds late) of those over LATE_RING_S
+        self._due_at: Optional[float] = None
+        self._late_max_s = 0.0
+        self._late: "collections.deque" = collections.deque(maxlen=LATE_RING)
 
     def use_metrics(self, metrics) -> None:
         if metrics is not None:
@@ -124,8 +144,21 @@ class HostProfiler:
     def running(self) -> bool:
         return self._thread is not None and self._thread.is_alive()
 
+    def _sleep(self, seconds: float) -> bool:
+        """The sampler's sleep; True once stop() was called (a test holds
+        the sampler back here)."""
+        return self._stop.wait(seconds)
+
     def _run(self) -> None:
-        while not self._stop.wait(self._next_interval()):
+        while True:
+            wait = self._next_interval()
+            due = time.monotonic() + wait
+            with self._lock:
+                self._due_at = due
+            stopped = self._sleep(wait)
+            self._note_wake(due, time.monotonic())
+            if stopped:
+                return
             try:
                 self.sample_once()
             except Exception as exc:  # noqa: BLE001 - keep sampling
@@ -135,6 +168,29 @@ class HostProfiler:
                                            exc)
                     except Exception:  # noqa: BLE001
                         pass
+
+    def _note_wake(self, due: float, woke: float) -> None:
+        late = woke - due
+        with self._lock:
+            self._due_at = None
+            if late > self._late_max_s:
+                self._late_max_s = late
+            if late >= LATE_RING_S:
+                self._late.append((woke, late))
+
+    def late_ms_within(self, t0: float, t1: float) -> float:
+        """The sampler's largest lateness, in ms, that overlaps the
+        monotonic interval [t0, t1]: of the late wake-ups in the ring,
+        each late from when it was due until it woke, and of the sleep
+        in progress if it was due before t1 and has not ended. 0.0 when
+        the sampler woke on time throughout (or is not running)."""
+        now = time.monotonic()
+        with self._lock:
+            worst = max((late for woke, late in self._late
+                         if woke - late <= t1 and woke >= t0), default=0.0)
+            if self._due_at is not None and self._due_at <= t1:
+                worst = max(worst, now - self._due_at)
+        return round(max(0.0, worst) * 1e3, 3)
 
     def _next_interval(self) -> float:
         """Duty-cycle governor: the sleep that keeps steady-state
@@ -249,6 +305,16 @@ class HostProfiler:
                 "interval_s": round(self._interval_eff, 6),
                 "throttled": self._throttled,
             }
+            # how late the sampler's own wake-ups came (epochs through
+            # one anchor read here, like the flight recorder's display)
+            anchor = time.time() - now  # lint: clock-ok display of monotonic stamps
+            late = {
+                "longest_ms": round(self._late_max_s * 1e3, 3),
+                "over_ms": LATE_RING_S * 1e3,
+                "recent": [{"t": round(anchor + woke, 3),
+                            "late_ms": round(seconds * 1e3, 3)}
+                           for woke, seconds in self._late],
+            }
             samples_total = self.samples_total
         self._obs.gauge("app_tpu_hostprof_overhead_share",
                         overhead["share"])
@@ -259,6 +325,7 @@ class HostProfiler:
             "wall_s": round(wall, 3),
             "max_stacks": self.max_stacks,
             "overhead": overhead,
+            "late": late,
             "threads": threads,
         }
 

@@ -482,6 +482,7 @@ def _pipeline_counts(engine) -> Dict[str, Any]:
     kinds = [entry[0] for entry in list(engine._inflight)]
     prefills = kinds.count("prefill")
     syncs, dry = engine.decode_syncs_total, engine.dry_syncs_total
+    rows, overrun = engine.row_steps_total, engine.overrun_steps_total
     return {
         "inflight_dispatches": len(kinds),
         "inflight_decode": len(kinds) - prefills,
@@ -489,6 +490,11 @@ def _pipeline_counts(engine) -> Dict[str, Any]:
         "decode_syncs_total": syncs,
         "dry_syncs_total": dry,
         "dry_sync_share": round(dry / syncs, 4) if syncs else 0.0,
+        # row-steps the decode blocks and verifies computed, and the
+        # share of them computed for a row after its request's last token
+        "row_steps_total": rows,
+        "overrun_steps_total": overrun,
+        "overrun_share": round(overrun / rows, 4) if rows else 0.0,
     }
 
 

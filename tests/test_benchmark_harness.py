@@ -132,3 +132,99 @@ def test_the_span_metrics_are_declared_once_with_their_cells():
         assert names.count(name) == 1
         assert names.index(name) > names.index("decode_step_dev_ms")
         assert cells[name][:len(first)] == first
+
+
+# -- what a prompt met on the device's queue (ISSUE 37) ------------------------
+FIVE = ["internlm2-1.8b.decode-closed", "internlm2-1.8b.chat-open",
+        "nemotron-3-nano-30b-a3b-ep2.decode-closed",
+        "joyai-llm-flash-ep8.longprompt-closed",
+        "trinity-large-preview-ep8.mixedlen-closed"]
+OPEN = ["internlm2-1.8b.chat-open"]
+# metric -> (its cells, what it moves, the loop it reads, its layer)
+QUEUE_METRICS = {
+    "dispatch_hold_p95_ms": (OPEN, "ttft_p95_ms", "open", "engine loop"),
+    "prefill_ahead_steps_mean": (OPEN, "ttft_p95_ms", "open",
+                                 "step programs"),
+    "decode_overrun_share_pct": (FIVE, "out_tok_s", None, "engine loop"),
+    "prefill_dev_ms_per_call": (FIVE, "out_tok_s", None, "step programs"),
+    "prefill_dev_share_pct": (FIVE, "out_tok_s", None, "step programs"),
+}
+
+
+def _queue_run(loop="open"):
+    """A run as serve.measure and run.py leave it, as far as the five
+    readers look: 21 requests due in the window with the recorder's
+    stamps, and a reduced trace of one chip."""
+    records = [{"index": i, "due": 1.0 + i, "t_send": 1.0 + i}
+               for i in range(21)]
+    requests = [{"trace_id": format(i + 1, "032x"), "enqueued_at": 10.0,
+                 "granted_at": 10.5, "admitted_at": 10.5 + 0.001 * i,
+                 "first_token_at": 11.0, "finished_at": 12.0,
+                 "ahead_steps": 8 * (i % 4), "ahead_prefills": i % 2,
+                 "generated": 41, "overrun_steps": 10}
+                for i in range(21)]
+    trace = {"devices": 1, "window_s": 5.0, "busy_s": 4.9, "modules": {
+        "jit_prefill__512x1": {"busy_s": 0.3, "count": 30},
+        "jit_prefill__llama_paged_prefix_128x4_NP8": {"busy_s": 0.2,
+                                                      "count": 10},
+        "jit_decode__x16_NP16": {"busy_s": 4.4, "count": 40}}}
+    return {"result": {"records": records}, "requests": requests,
+            "loaded": {"mix": {"loop": loop}}, "t_open": 0.0,
+            "t_close": 100.0, "steps": [], "trace": trace}
+
+
+def _without(run, *keys):
+    return {**run, "requests": [{k: v for k, v in rec.items()
+                                 if k not in keys}
+                                for rec in run["requests"]]}
+
+
+@pytest.mark.parametrize("name,value,absent", [
+    # 95th percentile of 0 .. 20 ms is 19
+    ("dispatch_hold_p95_ms", 19.0, lambda run: _without(run, "granted_at")),
+    # 8 x (0, 1, 2, 3, ...) over 21 requests
+    ("prefill_ahead_steps_mean", 8 * 30 / 21,
+     lambda run: _without(run, "ahead_steps")),
+    # 10 of 40 + 10 row-steps a request
+    ("decode_overrun_share_pct", 20.0,
+     lambda run: _without(run, "overrun_steps")),
+    # 0.5 s in 40 calls; of a 5 s window
+    ("prefill_dev_ms_per_call", 12.5, lambda run: {**run, "trace": None}),
+    ("prefill_dev_share_pct", 10.0, lambda run: {**run, "trace": None}),
+])
+def test_a_queue_reader_reads_its_input_and_nothing_without_it(name, value,
+                                                               absent):
+    data = _module("test_hostspans").data
+    read = data.layer_metrics()[name].read
+    run = _queue_run()
+    assert read(run) == pytest.approx(value)
+    # the parent commit's program, an untraced run: nothing, no error
+    assert read(absent(run)) is None
+    if name.startswith("prefill_dev"):
+        run["trace"]["modules"] = {"jit_decode__x16_NP16":
+                                   {"busy_s": 4.4, "count": 40}}
+        assert read(run) is None
+    elif name == "decode_overrun_share_pct":
+        # a request still decoding at the window's close has no finish
+        assert read(_without(run, "finished_at")) is None
+
+
+@pytest.mark.parametrize("name", sorted(QUEUE_METRICS))
+def test_a_queue_metric_is_declared_once_as_its_reader_says(name):
+    data = _module("test_hostspans").data
+    cells, moves, loop, layer = QUEUE_METRICS[name]
+    declared = [m for m in data.benchmark_json()["per_layer"]
+                if m["name"] == name]
+    assert len(declared) == 1
+    module = data.layer_metrics()[name]
+    assert declared[0]["workloads"] == cells
+    assert (module.MOVES, getattr(module, "LOOP", None), module.LAYER) \
+        == (moves, loop, layer)
+    assert (declared[0]["moves"], declared[0]["layer"], declared[0]["unit"],
+            declared[0]["source"], declared[0]["better"]) \
+        == (module.MOVES, module.LAYER, module.UNIT, module.SOURCE,
+            module.BETTER)
+    for cell in cells:
+        loaded = data.load_cell(cell)
+        assert moves in loaded["cell"]["end_to_end"]
+        assert loop in (None, loaded["mix"]["loop"])

@@ -246,3 +246,74 @@ def test_incident_bundle_during_stall_embeds_loop_stacks(tmp_path):
     assert stacks, f"bundle carried no loop stacks: {sorted(bundle)}"
     for entry in stacks:
         assert entry["stack"] and entry["samples"] >= 1
+
+
+# -- did the host itself stand still? (ISSUE 37) ------------------------------
+def test_lateness_is_kept_against_the_interval_asked_for():
+    """The sampler's own wake-ups, by hand: one on time, one 0.4 s late.
+    The late one is the longest, is in the ring, and overlaps a step
+    from when it was DUE until it woke."""
+    prof = HostProfiler(hz=50.0)
+    prof._note_wake(due=100.0, woke=100.001)
+    prof._note_wake(due=100.02, woke=100.42)
+    late = prof.snapshot()["late"]
+    assert late["longest_ms"] == pytest.approx(400.0)
+    assert [row["late_ms"] for row in late["recent"]] == [
+        pytest.approx(400.0)]
+    assert prof.late_ms_within(100.3, 100.35) == pytest.approx(400.0)
+    assert prof.late_ms_within(100.5, 101.0) == 0.0
+    assert prof.late_ms_within(99.0, 100.01) == 0.0
+    # a sleep in progress that was due inside the step and has not ended
+    prof._due_at = time.monotonic() - 0.25
+    assert prof.late_ms_within(prof._due_at - 1.0, prof._due_at + 1.0) >= 250.0
+    assert prof.late_ms_within(prof._due_at - 2.0, prof._due_at - 1.0) == 0.0
+
+
+@pytest.mark.parametrize("sampler", ["on-time", "held"])
+def test_a_straggler_says_whether_the_host_stood_still(sampler, tmp_path):
+    """An injected `engine.sync` delay of 0.8 s flags a step. With the
+    sampler waking on time through it, `host_late_ms` is a fraction of
+    the stall: the device's or the runtime's. With the sampler held back
+    as the loop is (every wake-up 0.5 s late), it says so: the
+    machine's. On the engine event and in the incident bundle."""
+    from gofr_tpu.tpu.faults import FaultPlane
+    from gofr_tpu.tpu.flightrecorder import FlightRecorder
+    from gofr_tpu.tpu.incidents import IncidentManager
+
+    recorder = FlightRecorder(capacity=16)
+    eng = _engine(flight_recorder=recorder)
+    eng.steps.configure(straggler_k=3.0, min_samples=6, baseline_alpha=0.2)
+    eng.faults = FaultPlane(plan=[{"site": "engine.sync", "action": "delay",
+                                   "delay_s": 0.8, "nth": 20}])
+    prof = HostProfiler(hz=100.0)
+    if sampler == "held":
+        def held(seconds):
+            time.sleep(seconds + 0.5)
+            return prof._stop.is_set()
+        prof._sleep = held
+    eng.hostprof = prof
+    eng.incidents = inc = IncidentManager(
+        engine=eng, dir=str(tmp_path), cooldown_s=0.0, straggler_streak=1)
+    prof.start()
+    eng.start()
+    eng.warmup()
+    try:
+        eng.generate([1, 2, 3], max_new_tokens=25)
+    finally:
+        eng.stop()
+        prof.stop()
+    events = [e for e in recorder.snapshot()["engine_events"]
+              if e["event"] == "step_straggler"
+              and e["cause"] == "device_sync"]
+    assert events, recorder.snapshot()["engine_events"]
+    late = events[0]["host_late_ms"]
+    if sampler == "held":
+        assert late >= 450.0
+        assert prof.snapshot()["late"]["longest_ms"] >= 450.0
+    else:
+        assert late < 400.0       # the stall was 800: the host kept time
+    assert inc.wait_idle(30.0)
+    bundles = [b for b in inc.index()["incidents"]
+               if b["trigger"] == "straggler_streak"]
+    assert bundles and "host_late_ms" in inc.lookup(
+        bundles[0]["id"])["context"]

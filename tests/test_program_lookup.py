@@ -202,3 +202,23 @@ def test_a_miss_is_counted_and_its_seconds_are_not():
     count.note(0.004, missed=False)
     assert count.snapshot() == {"lookups_total": 3, "misses_total": 1,
                                 "seconds_total": 0.006, "longest_ms": 4.0}
+
+
+# -- the device's time in prefill programs is read by this name (ISSUE 37) ----
+@pytest.mark.parametrize("case,name,module,donated", [
+    ("prefill", "llama-paged-prefill-8x1", "jit_prefill__8x1",
+     (1, 2, 7, 8, 9)),
+    ("prefix", "llama-paged-prefix-8x1-NP4",
+     "jit_prefill__llama_paged_prefix_8x1_NP4", (1, 2, 8, 9, 10)),
+])
+def test_a_prefill_programs_module_name_starts_jit_prefill(case, name,
+                                                           module, donated):
+    """`benchmark/layer_metrics/prefill_dev_*` select the trace's XLA
+    modules on the prefix `jit_prefill`: the plain and the prefix prefill
+    program both carry it, under the program name and the donation that
+    key their artifacts (a renamed program compiles cold)."""
+    kind, method, args = LOOKUPS[case]
+    program = getattr(_engine(kind), method)(*args)
+    assert f"HloModule {module}" in program.compiled.as_text()[:400]
+    assert program.name == program.key[0] == name
+    assert program.key[3] == donated
