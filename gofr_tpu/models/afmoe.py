@@ -404,7 +404,7 @@ def _pieces(T: int, most: int = PIECE) -> int:
 def _ffn_in_pieces(x, w, real, cfg: AfmoeConfig):
     """`ffn_prefill` over a window's tokens a piece at a time, one piece
     after another: its temporaries (the dense hidden, the grouped experts'
-    sorted rows for every (token, pick) pair) are a piece's."""
+    sorted rows and their float32 products) are a piece's."""
     K, T, D = x.shape
     n = _pieces(T)
     if n == 1:

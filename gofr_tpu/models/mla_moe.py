@@ -404,8 +404,8 @@ def ffn_prefill(x, w, real, cfg: MlaMoeConfig):
     picks, weights = route(flat, w, cfg)
     weights = jnp.where(real.reshape(K * T, 1), weights, 0.0)
     routed = prefill_experts(flat, w["w1"], w["w2"], picks, weights,
-                             cfg.experts_held[0], tm=min(128, max(8, K * T)),
-                             wg=w["wg"])
+                             cfg.experts_held[0], cfg.n_experts,
+                             tm=min(128, max(8, K * T)), wg=w["wg"])
     shared = _swiglu(flat, w["shared_gate"], w["shared_up"], w["shared_down"])
     return (routed.astype(x.dtype) + shared).reshape(K, T, D)
 

@@ -492,7 +492,7 @@ def experts_prefill(x, w, real, cfg: NemotronHConfig):
     picks, weights = route(flat, w, cfg)
     weights = jnp.where(real.reshape(K * T, 1), weights, 0.0)
     routed = prefill_experts(flat, w["w1"], w["w2"], picks, weights,
-                             cfg.experts_held[0],
+                             cfg.experts_held[0], cfg.n_experts,
                              tm=min(128, max(8, K * T)))
     return (routed.astype(x.dtype) + _shared_expert(flat, w)).reshape(K, T, D)
 

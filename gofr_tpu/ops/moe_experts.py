@@ -37,7 +37,21 @@ caller onto the last live step's blocks, so they move nothing either.
 - PREFILL (`prefill_experts`): the (token, pick) pairs that fell on held
   experts are sorted by expert into blocks of `tm` rows, a block one
   expert's, so a token meets only the experts it picked: a grouped product,
-  not every token through every expert (64/3 of the flops).
+  not every token through every expert (64/3 of the flops). The layout is
+  sized by the pairs the experts HELD can expect, not by every pair: a
+  PIECE is `piece_blocks` row blocks, `T k held / total` pairs with a
+  fixed margin and a ragged end an expert (a chip with 32 of 256 experts
+  lays out 72 blocks for a window of 4,096 x 8 where every pair would
+  need 288; a chip that holds every expert gets what every pair needs). A
+  call walks the sorted held pairs a piece at a time, in one loop with as
+  many turns as the pairs that did fall here need: a gather of the piece's
+  rows, one call of the kernel on a grid of the piece's blocks, its rows
+  added onto their tokens in float32 (where a window has fewer pairs than
+  a piece has rows, many experts and few tokens, the piece's pairs go
+  back, each from the row it sits in). One turn where the routing is near
+  uniform, about `total / held` where every pair is held, none where none
+  is: no pair is dropped whatever the routing, and there is no capacity to
+  set.
 
 A block is a TILE of an expert's width: `tile` of its F rows of each
 matrix, [tile, D], and the grid is (steps, F / tile) with a step's output
@@ -236,43 +250,107 @@ def decode_experts(x, w1, w2, combine, *, wg=None, interpret=None):
                         interpret=interpret)
 
 
-def prefill_experts(x, w1, w2, picks, pick_weights, lo: int, tm: int = 128,
-                    *, wg=None, interpret=None):
+# the room a piece keeps over the pairs uniform routing sends here: a chip
+# whose experts draw 5/4 of the mean still walks one piece (the ragged ends
+# are sized at a block an expert and fill half of one on average, which is
+# as much room again: tools/bench_prefill_experts.py, PERF.md section 5)
+_PIECE_MARGIN = (5, 4)
+
+
+def piece_blocks(T: int, k: int, held: int, total: int, tm: int) -> int:
+    """The row blocks of `tm` a prefill's grouped product lays out at a
+    time, from the shapes alone: the (token, pick) pairs expected on
+    `held` of `total` experts with `_PIECE_MARGIN`'s room, and a ragged
+    end a held expert; never more than every pair needs (which is what
+    a chip that holds every expert gets)."""
+    over, under = _PIECE_MARGIN
+    expected = -(-T * k * held * over // (total * under * tm))
+    return min(expected, -(-T * k // tm)) + held
+
+
+def prefill_experts(x, w1, w2, picks, pick_weights, lo: int, total: int,
+                    tm: int = 128, *, wg=None, interpret=None):
     """A prefill window. x [T, D]; picks [T, k] int32 expert ids over ALL
-    the router's experts; pick_weights [T, k] float32, zero for a token
-    that is padding; the held experts are [lo, lo + held). Returns
-    [T, D] float32: each token's sum over its picks that are held."""
+    the router's `total` experts; pick_weights [T, k] float32, zero for a
+    token that is padding; the held experts are [lo, lo + held). Returns
+    [T, D] float32: each token's sum over its picks that are held.
+
+    The pairs that fell on held experts, sorted by expert, are walked a
+    PIECE of `piece_blocks` row blocks at a time: their rows gathered,
+    one `expert_steps` call, its rows (or its pairs, where the window has
+    fewer of them) added onto their tokens. As many pieces as the pairs
+    that did fall here need: one where the routing is near uniform,
+    ~total / held where every pair is held, none where none is. No pair
+    is dropped whatever the routing."""
     T, D = x.shape
     held, k = w1.shape[0], picks.shape[1]
+    pairs, C = T * k, piece_blocks(T, k, held, total, tm)
+    if (held + 1) * pairs >= 2 ** 31:
+        raise ValueError(f"{held} experts x {pairs} (token, pick) pairs do "
+                         f"not share an int32 sort key")
     local = jnp.logical_and(
         jnp.logical_and(picks >= lo, picks < lo + held), pick_weights != 0.0)
     key = jnp.where(local, picks - lo, held).reshape(-1)         # [T * k]
-    order = jnp.argsort(key, stable=True)
-    sizes = jnp.bincount(key, length=held + 1)[:held]
+    # one sort of (expert, pair) in one int32 (no two alike, so no need
+    # of a stable one), the weights riding along: the held pairs come
+    # first, an expert's together, in token order
+    by_expert, weight_sorted = jax.lax.sort(
+        (key * pairs + jnp.arange(pairs, dtype=jnp.int32),
+         pick_weights.reshape(-1)), num_keys=1, is_stable=False)
+    token_sorted = by_expert % pairs // k
+    experts = jnp.arange(held, dtype=jnp.int32)
+    sizes = jnp.sum(key[:, None] == experts, axis=0, dtype=jnp.int32)
     blocks = (sizes + tm - 1) // tm                              # a group
-    first_block = jnp.cumsum(blocks) - blocks
-    first_sorted = jnp.cumsum(sizes) - sizes
-    n_blocks = -(-T * k // tm) + held          # the most the pairs can need
-    # where each sorted pair sits: its group's first row + its rank there
-    group = key[order]
-    rank = jnp.arange(T * k) - first_sorted[jnp.minimum(group, held - 1)]
-    dest = jnp.where(group < held,
-                     first_block[jnp.minimum(group, held - 1)] * tm + rank,
-                     n_blocks * tm)                              # dropped
-    token = jnp.full((n_blocks * tm,), T, jnp.int32).at[dest].set(
-        (order // k).astype(jnp.int32), mode="drop")
-    weight = jnp.zeros((n_blocks * tm,), jnp.float32).at[dest].set(
-        pick_weights.reshape(-1)[order], mode="drop")
-    x_sorted = jnp.concatenate([x, jnp.zeros((1, D), x.dtype)])[token]
-    block_expert = jnp.searchsorted(jnp.cumsum(blocks), jnp.arange(n_blocks),
-                                    side="right")
-    steps = jnp.arange(n_blocks, dtype=jnp.int32)
-    y_sorted = expert_steps(
-        x_sorted, w1, w2, weight.reshape(n_blocks, tm, 1),
-        jnp.minimum(block_expert, held - 1), steps, steps, jnp.sum(blocks),
-        tm, wg=wg, interpret=interpret)
-    # back to tokens by gather: pair (t, j) reads its row, or nothing
-    where = jnp.zeros((T * k,), jnp.int32).at[order].set(
-        jnp.minimum(dest, n_blocks * tm - 1).astype(jnp.int32))
-    rows = y_sorted[where].reshape(T, k, D)
-    return jnp.sum(jnp.where(local[:, :, None], rows, 0.0), axis=1)
+    ends = jnp.cumsum(blocks)
+    first_block, live = ends - blocks, ends[-1]
+    group_ends = jnp.cumsum(sizes)
+    first_sorted = group_ends - sizes
+    # the way back costs a row what it costs live or dead (~0.1 us of a
+    # v5e): no more rows go back than the window has pairs
+    back = min(C * tm, pairs)
+    steps = jnp.arange(C, dtype=jnp.int32)
+    row = jnp.arange(tm, dtype=jnp.int32)
+
+    def piece(carry):
+        at, out = carry
+        block = at * C + steps
+        # a block knows its expert, the expert its first block and its
+        # first sorted pair (tables of `held`, read by comparison)
+        expert = jnp.minimum(
+            jnp.sum(ends[None, :] <= block[:, None], axis=1), held - 1)
+        mine = expert[:, None] == experts
+
+        def of_expert(table):
+            return jnp.sum(jnp.where(mine, table, 0), axis=1)
+
+        before = (block - of_expert(first_block)) * tm   # rows of the group
+        first = of_expert(first_sorted) + before
+        n_rows = jnp.where(block < live, of_expert(sizes) - before, 0)
+        real = (row < n_rows[:, None]).reshape(-1)               # [C * tm]
+        sorted_at = (first[:, None] + row).reshape(-1)
+        # a row past its group's end belongs to token T, which is nobody
+        token = jnp.where(real, token_sorted[sorted_at], T)
+        weight = jnp.where(real, weight_sorted[sorted_at], 0.0)
+        y = expert_steps(
+            x[jnp.minimum(token, T - 1)], w1, w2, weight.reshape(C, tm, 1),
+            expert, steps, steps, jnp.minimum(live - at * C, C), tm, wg=wg,
+            interpret=interpret)
+        if back < C * tm:
+            # fewer pairs than a piece has rows (many experts, few
+            # tokens: ragged ends all): the piece's sorted pairs go
+            # back, each from the row it sits in, not the piece's rows
+            pair = first[0] + jnp.arange(back, dtype=jnp.int32)
+            its = jnp.minimum(jnp.sum(
+                group_ends[None, :] <= pair[:, None], axis=1), held - 1)
+            its = its[:, None] == experts
+            block_of = jnp.sum(jnp.where(its, first_block, 0), axis=1)
+            sorted_of = jnp.sum(jnp.where(its, first_sorted, 0), axis=1)
+            at_row = (block_of - at * C) * tm + pair - sorted_of
+            here = jnp.logical_and(pair < group_ends[-1], at_row < C * tm)
+            token = jnp.where(here, token_sorted[pair], T)
+            y = y[jnp.minimum(at_row, C * tm - 1)]
+        return at + 1, out.at[token].add(y, mode="drop")
+
+    return jax.lax.while_loop(
+        lambda carry: carry[0] * C < live, piece,
+        (jnp.int32(0), jnp.zeros((T, D), jnp.float32)))[1]

@@ -362,7 +362,7 @@ def test_the_tiled_expert_kernel_is_experts_reference(gated, tile,
             if 0 <= e < held:
                 dense[t, e] += float(weights[t, j])
     want = experts_reference(xp, w1, w2, jnp.asarray(dense), wg)
-    got = prefill_experts(xp, w1, w2, picks, weights, 1, tm=8, wg=wg,
+    got = prefill_experts(xp, w1, w2, picks, weights, 1, 8, tm=8, wg=wg,
                           interpret=True)
     assert np.abs(np.asarray(got - want)).max() < 5e-6
 

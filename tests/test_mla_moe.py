@@ -401,7 +401,7 @@ def test_gated_moe_experts_prefill_is_its_oracle(tm):
     weights = jax.random.uniform(keys[4], (T, k), jnp.float32, 0.2, 1.0)
     weights = weights.at[17:].set(0.0)                 # padding tokens
     got = jax.jit(lambda *a: prefill_experts(
-        *a[:5], lo, tm=tm, wg=a[5], interpret=True))(
+        *a[:5], lo, 8, tm=tm, wg=a[5], interpret=True))(
         x, w1, w2, picks, weights, wg)
     combine = np.zeros((T, 8), np.float32)
     combine[np.arange(T)[:, None], np.asarray(picks)] = np.asarray(weights)

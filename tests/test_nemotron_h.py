@@ -305,7 +305,7 @@ def test_moe_experts_prefill_sorts_by_expert_and_is_its_oracle(tm):
                        jax.random.split(keys[3], T)]).astype(jnp.int32)
     weights = jax.random.uniform(keys[4], (T, k), jnp.float32, 0.2, 1.0)
     weights = weights.at[17:].set(0.0)                 # padding tokens
-    got = jax.jit(lambda *a: prefill_experts(*a, lo, tm=tm, interpret=True))(
+    got = jax.jit(lambda *a: prefill_experts(*a, lo, 8, tm=tm, interpret=True))(
         x, w1, w2, picks, weights)
     combine = np.zeros((T, 8), np.float32)
     combine[np.arange(T)[:, None], np.asarray(picks)] = np.asarray(weights)
