@@ -6,8 +6,9 @@ POST /generate {"prompt": "...", "max_tokens": 64, "temperature": 0.7,
 
 Model size comes from MODEL_PRESET (debug | llama1b | llama3-8b, the
 nemotron_h family's nemotron-h-debug | nemotron-3-nano-30b-a3b-ep2, the
-mla_moe family's mla-moe-debug | joyai-llm-flash-ep8, and the afmoe family's
-afmoe-debug | trinity-large-preview-ep8). Weights
+mla_moe family's mla-moe-debug | joyai-llm-flash-ep8 | mla-moe-hc-debug |
+xing4.0-29b-a4b-ep8, and the afmoe family's afmoe-debug |
+trinity-large-preview-ep8). Weights
 boot from a real HF-layout safetensors checkpoint when WEIGHTS_PATH is set
 (models.weights.load_llama_safetensors — streaming, int8 quantize-on-load);
 otherwise random-initialised (no checkpoints ship in this environment) with
@@ -51,6 +52,11 @@ PRESETS = {
     # one chip's share of JoyAI-LLM-Flash, as the benchmark runs it
     # (benchmark/configs/joyai-llm-flash-ep8.json)
     "joyai-llm-flash-ep8": MlaMoeConfig.joyai_llm_flash_ep8,
+    # the same family with a residual stream of four mixed copies (mHC) and
+    # YaRN; one chip's share of Xing4.0-29B-A4B, as the benchmark runs it
+    # (benchmark/configs/xing4.0-29b-a4b-ep8.json)
+    "mla-moe-hc-debug": MlaMoeConfig.debug_hc,
+    "xing4.0-29b-a4b-ep8": MlaMoeConfig.xing4_0_29b_a4b_ep8,
     # the afmoe family: window and full attention blocks in one stack, a
     # page group each in the pool (the window blocks' a ring), gated
     # attention with normed queries and keys, gated sparse experts

@@ -195,9 +195,10 @@ def _kernel_streaming(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 
 def _flash_bhtd(q, k, v, *, causal: bool, block_q: int, block_kv: int,
-                interpret: Optional[bool], window: Optional[int] = None):
+                interpret: Optional[bool], window: Optional[int] = None,
+                scale: Optional[float] = None):
     """Core call on [B, H, T, dh] q, [B, Hkv, S, dh] k and [B, Hkv, S, dv]
-    v layouts."""
+    v layouts. `scale` of the scores: 1 / sqrt(dh) unless given."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -206,6 +207,8 @@ def _flash_bhtd(q, k, v, *, causal: bool, block_q: int, block_kv: int,
     G = H // Hkv
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    if scale is None:
+        scale = 1.0 / math.sqrt(dh)
 
     block_q = min(block_q, _ceil_to(T, 16))
     block_kv = min(block_kv, _ceil_to(S, 16))
@@ -223,7 +226,7 @@ def _flash_bhtd(q, k, v, *, causal: bool, block_q: int, block_kv: int,
     if resident:
         kernel = functools.partial(
             _kernel_resident, causal=causal, kv_len=S, block_kv=block_kv,
-            scale=1.0 / math.sqrt(dh), **windowed)
+            scale=scale, **windowed)
         with kernel_scope("flash_prefill"):
             out = pl.pallas_call(
                 kernel,
@@ -245,7 +248,7 @@ def _flash_bhtd(q, k, v, *, causal: bool, block_q: int, block_kv: int,
 
     kernel = functools.partial(
         _kernel_streaming, causal=causal, kv_len=S, block_kv=block_kv,
-        scale=1.0 / math.sqrt(dh), **windowed)
+        scale=scale, **windowed)
 
     def kv_block(i, j):
         """The kv block step (i, j) fetches: j, or under a window the
@@ -284,17 +287,20 @@ def _flash_bhtd(q, k, v, *, causal: bool, block_q: int, block_kv: int,
 
 
 def attention_reference(q, k, v, *, causal: bool = True,
-                        window: Optional[int] = None):
+                        window: Optional[int] = None,
+                        scale: Optional[float] = None):
     """Unblocked GQA attention in f32 — the numerics oracle and the recompute
     target for the backward pass. Layout [B, T, H, dh] / [B, S, Hkv, dh]
     (v and the output [.., dv]).
     When T < S under causal, queries are the LAST T positions. `window` W
-    (causal): query i sees keys (i - W, i]."""
+    (causal): query i sees keys (i - W, i]. `scale` of the scores:
+    1 / sqrt(dh) unless given."""
     B, T, H, dh = q.shape
     S, Hkv = k.shape[1], k.shape[2]
     G = H // Hkv
     qg = q.reshape(B, T, Hkv, G, dh).astype(jnp.float32)
-    s = jnp.einsum("bthgd,bshd->bhgts", qg, k.astype(jnp.float32)) / math.sqrt(dh)
+    s = jnp.einsum("bthgd,bshd->bhgts", qg, k.astype(jnp.float32))
+    s = s / math.sqrt(dh) if scale is None else s * scale
     if causal:
         at = jnp.arange(T)[:, None] + (S - T)
         mask = jnp.arange(S)[None, :] <= at
@@ -308,11 +314,13 @@ def attention_reference(q, k, v, *, causal: bool = True,
 
 def flash_attention(q, k, v, causal: bool = True, block_q: int = 128,
                     block_kv: int = 128, interpret: Optional[bool] = None,
-                    *, mesh=None, window: Optional[int] = None):
+                    *, mesh=None, window: Optional[int] = None,
+                    scale: Optional[float] = None):
     """Flash attention on [B, T, H, dh] q, [B, S, Hkv, dh] k and
     [B, S, Hkv, dv] v (GQA folds query head h onto kv head h // (H // Hkv)).
     Returns [B, T, H, dv] in q.dtype. `window` W (causal only): query i
-    sees keys (i - W, i]; kv blocks wholly outside are skipped.
+    sees keys (i - W, i]; kv blocks wholly outside are skipped. `scale` of
+    the scores: 1 / sqrt(dh) unless given (YaRN's mscale: models/mla_moe.py).
 
     mesh: the serving mesh when q/k/v are sharded over its "tp" axis on
     their head dims (column-parallel wq/wk/wv); each shard then runs the
@@ -321,7 +329,7 @@ def flash_attention(q, k, v, causal: bool = True, block_q: int = 128,
         raise ValueError("a window is a causal mask's")
     local = functools.partial(_flash_local, causal=causal, block_q=block_q,
                               block_kv=block_kv, interpret=interpret,
-                              window=window)
+                              window=window, scale=scale)
     if mesh is not None and mesh.shape.get("tp", 1) > 1:
         from jax.sharding import PartitionSpec as P
 
@@ -331,32 +339,35 @@ def flash_attention(q, k, v, causal: bool = True, block_q: int = 128,
     return local(q, k, v)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def _flash_local(q, k, v, causal: bool = True, block_q: int = 128,
                  block_kv: int = 128, interpret: Optional[bool] = None,
-                 window: Optional[int] = None):
+                 window: Optional[int] = None, scale: Optional[float] = None):
     if causal and q.shape[1] != k.shape[1]:
         # mixed-length causal needs the position offset folded into the mask;
         # the kernel path covers the hot shapes (T==S full-causal, and any
         # non-causal read) — everything else takes the exact oracle
-        return attention_reference(q, k, v, causal=causal, window=window)
+        return attention_reference(q, k, v, causal=causal, window=window,
+                                   scale=scale)
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
     out = _flash_bhtd(qt, kt, vt, causal=causal, block_q=block_q,
-                      block_kv=block_kv, interpret=interpret, window=window)
+                      block_kv=block_kv, interpret=interpret, window=window,
+                      scale=scale)
     return out.transpose(0, 2, 1, 3)
 
 
-def _flash_fwd(q, k, v, causal, block_q, block_kv, interpret, window):
+def _flash_fwd(q, k, v, causal, block_q, block_kv, interpret, window, scale):
     return (_flash_local(q, k, v, causal, block_q, block_kv, interpret,
-                         window), (q, k, v))
+                         window, scale), (q, k, v))
 
 
-def _flash_bwd(causal, block_q, block_kv, interpret, window, residuals, g):
+def _flash_bwd(causal, block_q, block_kv, interpret, window, scale,
+               residuals, g):
     q, k, v = residuals
     _, vjp = jax.vjp(lambda q, k, v: attention_reference(
-        q, k, v, causal=causal, window=window), q, k, v)
+        q, k, v, causal=causal, window=window, scale=scale), q, k, v)
     return vjp(g)
 
 

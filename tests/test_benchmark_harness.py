@@ -70,6 +70,10 @@ LEFT = {
         "test_one_slots_page_table_off_by_one_in_a_group_is_not_correct",
         "test_a_fault_in_the_block_is_not_correct"),
         "whole runs at --tiny size"),
+    "test_mla_moe_hc": dict.fromkeys((
+        "test_a_phi_in_bfloat16_is_not_as_stated",
+        "test_a_fault_in_the_program_is_not_correct"),
+        "whole runs at --tiny size"),
 }
 
 
@@ -135,19 +139,20 @@ def test_the_span_metrics_are_declared_once_with_their_cells():
 
 
 # -- what a prompt met on the device's queue (ISSUE 37) ------------------------
-FIVE = ["internlm2-1.8b.decode-closed", "internlm2-1.8b.chat-open",
-        "nemotron-3-nano-30b-a3b-ep2.decode-closed",
-        "joyai-llm-flash-ep8.longprompt-closed",
-        "trinity-large-preview-ep8.mixedlen-closed"]
+EVERY = ["internlm2-1.8b.decode-closed", "internlm2-1.8b.chat-open",
+         "nemotron-3-nano-30b-a3b-ep2.decode-closed",
+         "joyai-llm-flash-ep8.longprompt-closed",
+         "trinity-large-preview-ep8.mixedlen-closed",
+         "xing4.0-29b-a4b-ep8.decode-closed"]
 OPEN = ["internlm2-1.8b.chat-open"]
 # metric -> (its cells, what it moves, the loop it reads, its layer)
 QUEUE_METRICS = {
     "dispatch_hold_p95_ms": (OPEN, "ttft_p95_ms", "open", "engine loop"),
     "prefill_ahead_steps_mean": (OPEN, "ttft_p95_ms", "open",
                                  "step programs"),
-    "decode_overrun_share_pct": (FIVE, "out_tok_s", None, "engine loop"),
-    "prefill_dev_ms_per_call": (FIVE, "out_tok_s", None, "step programs"),
-    "prefill_dev_share_pct": (FIVE, "out_tok_s", None, "step programs"),
+    "decode_overrun_share_pct": (EVERY, "out_tok_s", None, "engine loop"),
+    "prefill_dev_ms_per_call": (EVERY, "out_tok_s", None, "step programs"),
+    "prefill_dev_share_pct": (EVERY, "out_tok_s", None, "step programs"),
 }
 
 

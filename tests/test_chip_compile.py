@@ -655,15 +655,17 @@ def test_nemotron_h_prefill_compiles_with_its_state_in_place(topo, as_tpu):
 
 
 # -- the mla_moe family's step programs (ISSUE 31) ------------------------------
-def _mla_moe_programs(topo, n_layers=3, slots=128, n_pages=4600):
+def _mla_moe_programs(topo, n_layers=3, slots=128, n_pages=4600,
+                      preset="joyai_llm_flash_ep8"):
     """(config, chips, engine shell, abstract params, the one latent pool,
     loop state, rng) at JoyAI-LLM-Flash's published widths, the dense block
-    and two expert blocks deep, the pool as the cell sizes it."""
+    and two expert blocks deep, the pool as the cell sizes it (or another
+    `preset` of the family's)."""
     from gofr_tpu.models.mla_moe import (FLOAT32_LEAVES, MlaMoeConfig,
                                          layer_shapes)
     from gofr_tpu.tpu.paging import PagedLLMEngine
 
-    cfg = dataclasses.replace(MlaMoeConfig.joyai_llm_flash_ep8(),
+    cfg = dataclasses.replace(getattr(MlaMoeConfig, preset)(),
                               n_layers=n_layers, attn_impl="flash")
     chips = Chips(topo, 1)
     engine = _engine_shell(PagedLLMEngine, cfg, None)
@@ -738,6 +740,86 @@ def test_mla_moe_prefill_compiles_with_two_widths(topo, as_tpu, bucket):
     # the window's temporaries (0.34 GiB at 2,048, 0.6 at 4,096), never a
     # copy of the 1.9 GiB pool
     assert mem.temp_size_in_bytes < 1 * GIB
+
+
+# -- the mla_moe family with a mixed residual stream and YaRN (ISSUE 39) --------
+def _xing_programs(topo):
+    """Xing4.0-29B-A4B's published widths (4 copies of 3,584), the two
+    dense blocks and one expert block deep, 96 slots and 865 pages as the
+    cell sizes them."""
+    return _mla_moe_programs(topo, slots=96, n_pages=865,
+                             preset="xing4_0_29b_a4b_ep8")
+
+
+def test_the_residual_mix_compiles_two_kernels_a_sublayer(topo, as_tpu):
+    """The cell's decode program shape (96 slots, table 16 wide): around
+    each of a block's two sublayers the stream of [96, 14336] goes through
+    `mhc_pre` and `mhc_post`, kernels of their own names inside the step
+    loop beside the latent read and the experts, ONE block each (no pad,
+    no ragged last block); the pool aliased, nothing pool-sized copied."""
+    from gofr_tpu.tpu.executor import _named_after
+
+    cfg, chips, engine, params, pools, loop, rng = _xing_programs(topo)
+    assert cfg.hc_mult == 4 and cfg.first_dense == 2
+    assert params["layers"][2]["ffn_hc_phi"].shape == (24, 14336)
+    assert params["layers"][2]["ffn_hc_phi"].dtype == jnp.float32
+    compiled = _compile(
+        _named_after(engine._decode_fn_paged(16, 16),
+                     "mla-moe-paged-decode-x16-NP16"),
+        params, *pools, chips.shape((96, 16), jnp.int32), *loop, rng,
+        donate=(1,))
+    calls = _kernel_calls(compiled)
+    names = sorted(name.rsplit(".", 1)[0] for name, _, _ in calls)
+    assert names == (["mhc_post"] * 6 + ["mhc_pre"] * 6 + ["mla_read"] * 3
+                     + ["moe_experts"] + ["paged_write"])
+    bodies = _while_bodies(compiled)
+    for name, _, _ in calls:
+        inside = _computation_of(compiled, name) in bodies
+        assert inside == (not name.startswith("paged_write")), name
+    assert all("bf16[96,14336]" in line.partition(" = ")[2][:40]
+               for line in _instructions(compiled, "mhc_post"))
+    _assert_pool_in_place(compiled, pools)
+
+
+def _instructions(compiled, kernel: str) -> list:
+    found = [line for line in compiled.as_text().splitlines()
+             if line.strip().removeprefix("ROOT ").startswith("%" + kernel)]
+    assert len(found) == 6
+    return found
+
+
+@pytest.mark.parametrize("K,bucket", [
+    (16, 128),      # the cell's widest: [2048, 14336] (59 MB), 16 tiles
+    (1, 64),        # 64 rows: one block
+    (3, 64)])       # 192 rows: a tile and a half, padded to two
+def test_the_residual_mix_compiles_in_a_prefill(topo, as_tpu, K, bucket):
+    """The stream of a prefill is mixed by the two kernels a tile of 128
+    rows at a time, and more rows than a tile that are no multiple of it
+    are padded to one (ops/mhc.py `_whole_tiles`): no shape makes a ragged
+    last block, which once stopped the chip (PR 39). Flash attention with
+    YaRN's scale; the window's latent plane written into the donated
+    pool."""
+    from gofr_tpu.tpu.executor import _named_after
+
+    cfg, chips, engine, params, pools, loop, rng = _xing_programs(topo)
+    rows = chips.shape((K,), jnp.int32)
+    compiled = _compile(
+        _named_after(engine._prefill_fn(bucket, K),
+                     f"mla-moe-paged-prefill-{bucket}x{K}"),
+        params, *pools, chips.shape((K, bucket), jnp.int32),
+        chips.shape((K, 1), jnp.int32), rows, rows, *loop,
+        chips.shape((K,), jnp.float32), rng, donate=(1, 6, 7, 8))
+    calls = _kernel_calls(compiled)
+    names = sorted(name.rsplit(".", 1)[0] for name, _, _ in calls)
+    assert names == (["flash_prefill"] * 3 + ["mhc_post"] * 6
+                     + ["mhc_pre"] * 6 + ["moe_experts"])
+    # every block of `mhc_post` is whole: the rows themselves up to a tile,
+    # whole tiles of the padded stream above
+    rows = K * bucket
+    padded = rows if rows <= 128 else -(-rows // 128) * 128
+    assert all(f"bf16[{padded},14336]" in line.partition(" = ")[2][:40]
+               for line in _instructions(compiled, "mhc_post"))
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 * GIB
 
 
 # -- the afmoe family's step programs (ISSUE 33) --------------------------------
