@@ -24,7 +24,14 @@ FOLD of pages (`pages_per_fold`: 8 of these, 1.2 MB, one score product of
 8 output tiles, one softmax step over 1,024 tokens), the chain is paid
 once a fold, and the call at the benchmark cell's shape went from 1,815 to
 800 us, 84 % of the roofline over whole live pages (v5e,
-tools/bench_paged_read.py, PR 32).
+tools/bench_paged_read.py, PR 32). A fold's width is what it copied (PR
+40): a row's last fold is computed over the least power of two of pages
+that covers its live ones (no less than a quarter of the fold), because with 32 query rows the two products of
+8 pages are 0.57 us whatever the pages hold. At rows of 16-40 pages the
+copies hide that (800 us still); at rows of 1-9 pages (xing's
+`decode-closed`: 4.2 a row) a call went from 156.4 to 143.3 us, 1.68 to
+1.54 us a row, of which 1.1 is the row's own chain of waits whatever its
+width (`only=short`).
 
 `mla_read_reference` (gather-based) is the numerics oracle.
 """

@@ -29,7 +29,13 @@ tokens. A row is only a few folds, so a DMA queue that drained at every
 row boundary would idle for a large part of each row: before a row folds
 its last fold it starts the first fold of the next row that has one, and
 which buffer that is carries over in SMEM scratch. Table entries past a
-row's live pages are never read.
+row's live pages are never read. A fold's WIDTH is what it copied: every
+fold of a row but its last holds C pages and is computed over C; the last
+holds 1 to C and is computed over the least power of two of pages that
+covers them, but no less than C / 4 (`fold_branch`: at most C / 2 pages,
+it runs after the loop in a copy of the turn's body that wide, chosen by
+a scalar the kernel already holds), so a row of one page under a fold of
+8 pays for two pages' products, not for eight pages' of masked lanes.
 
 The pool is STACKED over layers ([L, P, Hkv, dh, ps]) and carried whole
 through the step programs' layer loops, so everything here takes the
@@ -177,6 +183,37 @@ def fold_of(pools, table_width: int, mesh=None) -> int:
     return pages_per_fold(page_bytes // shards, table_width)
 
 
+# The widths a fold is computed at: C, C / 2 and C / 4, no narrower. Each
+# width is one more copy of a turn's body to trace and lower in every read of
+# every unrolled block, and each halving wins half of what the one before
+# did: at xing's rows a latent read of one page a row takes 153 us a call
+# computed at 8 pages, 125 at 4, 112 at 2 and 105 at 1 (v5e,
+# tools/bench_paged_read.py), while a fourth width took the cold lowering of
+# that cell's decode program from 10 % over the parent's to 16 % (PERF.md
+# section 6, PR 40).
+_FOLD_WIDTHS = 3
+
+
+def fold_widths(fold: int) -> tuple:
+    """The widths, in pages, a fold of C = `fold` pages can be computed
+    at: C first, then its halves down to a quarter of it (8, 4, 2; 2, 1 of
+    a fold of 2). The read's kernel holds one copy of a turn's body for
+    each: the loop's for C, one after the loop for each narrower width."""
+    return tuple(fold >> i for i in range(min(_FOLD_WIDTHS,
+                                              fold.bit_length())))
+
+
+def fold_branch(live, fold: int):
+    """Which of `fold_widths(fold)` a fold that holds `live` of its C pages
+    is computed at: the narrowest of them that covers what was copied (the
+    least power of two of pages that does, but no less than C / 4), so a
+    full fold (and any of more than C / 2 pages) takes the first. Plain
+    comparisons and sums, so the kernel calls it on a scalar it holds in
+    SMEM and the host's counter (tpu/paging.py `_note_page_reads`) on
+    arrays of page counts."""
+    return sum((live <= width) * 1 for width in fold_widths(fold)[1:])
+
+
 def _paged_kernel(layer_ref, table_ref, len_ref, *refs, scale: float,
                   quantized: bool, tailed: bool, fold: int,
                   value_width=None, ring=None):
@@ -195,12 +232,25 @@ def _paged_kernel(layer_ref, table_ref, len_ref, *refs, scale: float,
     once a fold, not once a page: they come to about 0.3 us a turn on a v5e,
     beside which a latent page of 144 KiB is 0.18 us of DMA and a K and V
     page of 8 heads 0.64 (`_FOLD_BYTES` has the measurements). Only live
-    pages are copied: a row's last fold may hold fewer than C, and the
-    lanes it does not copy keep what an earlier fold left there. Their
-    scores are masked (they lie past the row's length) and their
-    probabilities are 0.0, but 0.0 x NaN is NaN in the value product: the
-    buffers are zeroed before the first copy of a call, and what a live
-    page holds is finite.
+    pages are copied, and a fold is computed as wide as what it copied:
+    w pages, the least of `fold_widths` that covers them (`fold_branch` on
+    `pages_of(b)`, a scalar in SMEM). The folds before a row's last hold
+    C pages each, and so does w for a last fold more than half live: all
+    of those run in the loop, whose turn is computed at C and chooses
+    nothing. A last fold of at most C / 2 pages runs after the loop over
+    the first w x ps lanes of its buffer, in one of the copies of a turn's
+    body kept for C / 2 and C / 4 pages, and ends the row itself (writes the
+    output), so no branch hands the softmax's carry on; at `fold` 1 there
+    is one width and no choice. With 32 queries of a latent page the
+    two products of a full fold are 0.57 us of a one-fold row's 1.65, and a
+    row of two pages is 1.24 (tools/bench_paged_read.py `only=short`). The
+    lanes of a narrowed fold that hold no live page (3 pages are computed
+    at 4, one at 2 under a fold of 8) keep what an earlier fold left
+    there. Their scores are masked
+    (they lie past the row's length) and their probabilities are 0.0, but
+    0.0 x NaN is NaN in the value product: the buffers are zeroed before
+    the first copy of a call, and what a live page holds is finite. A lane
+    that is not computed adds nothing either: the sums lose only zeros.
 
     With a `ring` (a window group's, tpu/paging.py) the row attends from a
     LOWER BOUND on (one more scalar a row, `lower`: the first position it
@@ -424,32 +474,20 @@ def _paged_kernel(layer_ref, table_ref, len_ref, *refs, scale: float,
         walk_from = first_page(b) * page_size
         reached = jnp.minimum(length, walk_from + n_pages * page_size)
 
-    def fold_pages(f, carry):
+    def fold_lanes(f, slot, pages: int, carry):
+        """Fold the first `pages` pages' lanes of buffer `slot`, which
+        holds fold f of the row, into the softmax."""
         m_prev, l_prev, acc = carry
-        slot = (slot0 + f) % 2
-
-        # keep the DMA queue fed before waiting: this row's next fold (the
-        # second is under way already where a tail folded first) or, from
-        # its last fold, the first fold of the next row that has one
-        @pl.when(jnp.logical_and(f + 1 < n_folds, f >= int(tailed)))
-        def _next_fold():
-            start_fold(b, f + 1, 1 - slot)
-
-        @pl.when(f + 1 == n_folds)
-        def _next_row():
-            start_first_fold_after(b, 1 - slot)
-
-        each_copy(b, f, slot, lambda copy: copy.wait())
-
-        k = k_buf[slot]                                   # [Hkv, dh, C ps]
-        v = k[:, :dv] if latent else v_buf[slot]
+        lanes = pl.ds(0, pages * page_size)
+        k = k_buf[slot, :, :, lanes]                      # [Hkv, dh, w ps]
+        v = k[:, :dv] if latent else v_buf[slot, :, :, lanes]
         if quantized:
             k = k.astype(jnp.bfloat16)                    # in-VMEM upcast
-        # every head's [G, dh] x [dh, C ps], batched over the KV heads
+        # every head's [G, dh] x [dh, w ps], batched over the KV heads
         s = jax.lax.dot_general(q, k, (((2,), (1,)), ((0,), (0,))),
                                 preferred_element_type=jnp.float32) * scale
         if quantized:
-            s = s * ks_buf[slot][:, None, :].astype(jnp.float32)
+            s = s * ks_buf[slot, :, lanes][:, None, :].astype(jnp.float32)
         kv_pos = f * (fold * page_size) + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 2)
         if windowed:
@@ -467,21 +505,62 @@ def _paged_kernel(layer_ref, table_ref, len_ref, *refs, scale: float,
         alpha = jnp.exp(m_prev - m_new)
         l_new = l_prev * alpha + jnp.sum(pr, axis=-1, keepdims=True)
         if quantized:
-            pr = pr * vs_buf[slot][:, None, :].astype(jnp.float32)
+            pr = pr * vs_buf[slot, :, lanes][:, None, :].astype(jnp.float32)
             v = v.astype(jnp.bfloat16)
         pv = jax.lax.dot_general(pr.astype(v.dtype), v,
                                  (((2,), (2,)), ((0,), (0,))),
                                  preferred_element_type=jnp.float32)
         return m_new, l_new, acc * alpha + pv
 
-    _, l, acc = jax.lax.fori_loop(0, n_folds, fold_pages, folded)
+    def fold_pages(f, carry):
+        """One turn of the loop: a fold computed at all C pages."""
+        slot = (slot0 + f) % 2
+
+        # keep the DMA queue fed before waiting: this row's next fold (the
+        # second is under way already where a tail folded first) or, from
+        # its last fold, the first fold of the next row that has one
+        @pl.when(jnp.logical_and(f + 1 < n_folds, f >= int(tailed)))
+        def _next_fold():
+            start_fold(b, f + 1, 1 - slot)
+
+        @pl.when(f + 1 == n_folds)
+        def _next_row():
+            start_first_fold_after(b, 1 - slot)
+
+        each_copy(b, f, slot, lambda copy: copy.wait())
+        return fold_lanes(f, slot, fold, carry)
+
+    def finish(carry):
+        _, l, acc = carry
+        o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+
+    def narrow_last_fold(pages: int, carry):
+        """The row's last fold where it holds at most C / 2 pages: its turn
+        over the first `pages` pages' lanes alone, after the loop, ending
+        the row itself (no branch hands the softmax's carry on)."""
+        f = n_folds - 1
+        slot = (slot0 + f) % 2
+        start_first_fold_after(b, 1 - slot)
+        each_copy(b, f, slot, lambda copy: copy.wait())
+        finish(fold_lanes(f, slot, pages, carry))
+
+    # a fold is computed as wide as what it copied: the loop takes every
+    # fold computed at C (all but the last, and the last too where over
+    # half of it is live), and a narrower last fold runs after it at its
+    # own width; a row without a page holds C of a fold it never had,
+    # and ends as its tail left it
+    narrowed = fold_branch(n_pages - (n_folds - 1) * fold, fold)
+    folded = jax.lax.fori_loop(0, n_folds - (narrowed > 0) * 1, fold_pages,
+                               folded)
+    jax.lax.switch(narrowed, [finish] + [
+        functools.partial(narrow_last_fold, pages)
+        for pages in fold_widths(fold)[1:]], folded)
     first_slot[0] = (slot0 + n_folds) % 2
     if tailed:
         @pl.when(tail_len > 0)
         def _tail_is_back():
             for copy in put_copies:
                 copy.wait()
-    o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
 def _stacked(pool, layer):

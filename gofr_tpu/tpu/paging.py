@@ -51,7 +51,8 @@ from typing import Dict, List, Optional, Sequence
 import numpy as np
 
 from ..models.llama import LlamaConfig, llama_prefill_last
-from ..ops.paged_attention import (flush_planes, fold_of, holds_request,
+from ..ops.paged_attention import (flush_planes, fold_branch, fold_of,
+                                   fold_widths, holds_request,
                                    paged_write_prefill_scales,
                                    paged_write_prefill_stacked,
                                    paged_write_window, plane_tail,
@@ -113,6 +114,19 @@ def _shaped(shape, dtype=np.int32):
     import jax
 
     return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def _folds(pages, c: int):
+    """(folds, folds computed under `c` pages wide, pages computed) of
+    reads that walk `pages` pages each (an int array) in folds of `c`, as
+    the read's kernel runs them (ops/paged_attention `_paged_kernel`):
+    every fold of a read but its last holds `c` pages and is computed at
+    `c`; the last at `fold_widths(c)[fold_branch(what it holds, c)]`."""
+    folds, walked = -(-pages // c), pages > 0
+    last = np.take(fold_widths(c),
+                   fold_branch(pages - (folds - 1) * c, c)) * walked
+    return (int(folds.sum()), int(((last < c) & walked).sum()),
+            int(((folds - 1) * c * walked + last).sum()))
 
 
 class PagedLLMEngine(LLMEngine):
@@ -308,15 +322,16 @@ class PagedLLMEngine(LLMEngine):
         # decode tokens placed in pages and the page writes that placed
         # them, since the last reset (`_note_page_writes`)
         self.write_tokens = self.write_pages = 0
-        # the decode reads' folds (loop turns of the read's kernel), the
-        # tokens they attended in pages and the lanes they spanned, since
-        # the last reset, and the last block's fold width
-        # (`_note_page_reads`)
-        self.read_folds = self.read_tokens = self.read_lanes = 0
+        # the decode reads' folds (loop turns of the read's kernel), those
+        # computed under a whole fold's width, the tokens they attended in
+        # pages and the lanes they computed, since the last reset, and the
+        # last block's fold width (`_note_page_reads`)
+        self.read_folds = self.read_narrowed = 0
+        self.read_tokens = self.read_lanes = 0
         self.read_pages_per_fold = None
         # the same a group beside the primary: {group index: [folds,
-        # tokens, lanes, pages a fold]}
-        self._more_reads = {i: [0, 0, 0, None] for i in range(len(rings))
+        # tokens, lanes, pages a fold, narrowed folds]}
+        self._more_reads = {i: [0, 0, 0, None, 0] for i in range(len(rings))
                             if i != self._primary}
         self._tokens = jnp.zeros((B,), dtype=jnp.int32)
         self._positions = jnp.zeros((B,), dtype=jnp.int32)
@@ -1905,9 +1920,10 @@ class PagedLLMEngine(LLMEngine):
         shapes), and every step of the block, every attention layer, a
         live row's read walks the pages of its slot's length (pre-demux
         here: what the block found; the block's own tokens wait in its
-        tail) in ceil(pages / C) folds of C x page_size lanes each. The
-        int8 pools have no tail: step t attends the t + 1 tokens written
-        so far too."""
+        tail) in ceil(pages / C) folds, each C x page_size lanes but the
+        row's last, which is computed as wide as the pages it copied
+        (`_folds`). The int8 pools have no tail: step t attends the t + 1
+        tokens written so far too."""
         planes = len(self.model.planes)
         pools = ([self.k_cache, self.v_cache, self.k_scale, self.v_scale]
                  if self._q8 else self.pools[self._primary * planes:
@@ -1919,9 +1935,10 @@ class PagedLLMEngine(LLMEngine):
                            else np.zeros(block, np.int64))
         pages = np.minimum(-(-tokens // ps), n_table)
         layers = self.model.groups[self._primary].layers
-        folds = layers * int((-(-pages // c)).sum())
-        self.read_folds += folds
-        self.read_lanes += folds * c * ps
+        folds, narrowed, computed = _folds(pages, c)
+        self.read_folds += layers * folds
+        self.read_narrowed += layers * narrowed
+        self.read_lanes += layers * computed * ps
         self.read_tokens += layers * int(np.minimum(tokens, pages * ps).sum())
         self.read_pages_per_fold = c
         # a window group's read walks from the page its lower bound is in:
@@ -1935,11 +1952,12 @@ class PagedLLMEngine(LLMEngine):
                 tokens + np.arange(1, block + 1) - group.window, 0)
             pages = np.clip(-(-tokens // ps) - lower // ps, 0, ring)
             seen = np.minimum(tokens, (lower // ps + pages) * ps) - lower
-            folds = group.layers * int((-(-pages // c)).sum())
-            read[0] += folds
+            folds, narrowed, computed = _folds(pages, c)
+            read[0] += group.layers * folds
             read[1] += group.layers * int(np.maximum(seen, 0).sum())
-            read[2] += folds * c * ps
+            read[2] += group.layers * computed * ps
             read[3] = c
+            read[4] += group.layers * narrowed
 
     def paging_snapshot(self) -> dict:
         """`/debug/engine` "paging". "write": how often the decode block's
@@ -1948,17 +1966,22 @@ class PagedLLMEngine(LLMEngine):
         once (14-16 at blocks of 16, 7-8 while requests wait and the half
         block runs). "read": how well the read's folds engage.
         `pages_per_fold` is C of the last decode block synced, `folds` the
-        read kernel's loop turns (rows x steps x attention layers), and
-        `fold_live_share` the tokens attended in pages over folds x C x
-        page_size: what is left of 1.0 was masked (short rows, ragged
-        last folds and last pages; in a window group also the tokens of
-        the walk's first page that lie before the lower bound). "read" is
+        read kernel's loop turns (rows x steps x attention layers),
+        `narrowed_folds` those of them computed under C pages wide (a
+        row's last fold is as wide as the pages it copied, rounded up to
+        a power of two: ops/paged_attention `fold_branch`), and
+        `fold_live_share` the tokens attended in pages over the lanes the
+        folds computed: what is left of 1.0 was masked (a half-filled last
+        page, the pages a power of two adds; in a window group also the
+        tokens of the walk's first page that lie before the lower
+        bound). "read" is
         the primary group's; "groups" has every page group: its blocks,
         its window, its pool's pages and how many are in use, the pages a
         sequence reserved there on average since the last reset, and its
         own "read"."""
-        def read_of(folds, tokens, lanes, c):
+        def read_of(folds, tokens, lanes, c, narrowed):
             return {"pages_per_fold": c, "folds": folds,
+                    "narrowed_folds": narrowed,
                     "fold_live_share": (round(tokens / lanes, 4)
                                         if lanes else None)}
 
@@ -1975,7 +1998,8 @@ class PagedLLMEngine(LLMEngine):
                           / self._reserved_sequences, 2)
                     if self._reserved_sequences else None),
                 "read": (read_of(self.read_folds, self.read_tokens,
-                                 self.read_lanes, self.read_pages_per_fold)
+                                 self.read_lanes, self.read_pages_per_fold,
+                                 self.read_narrowed)
                          if primary else read_of(*self._more_reads[index]))})
         return {
             "write": {

@@ -17,6 +17,7 @@ few float32 roundings a block: 3e-5 after a prefill, 6e-5 over decode
 steps. The same comparison with a fault (the window ignored, a full block
 turned, the gate left out) reads over 1e-2."""
 
+import functools
 import math
 import os
 import sys
@@ -395,15 +396,23 @@ def test_windowed_flash_is_masked_attention(T, window, bq, bkv, budget,
     assert (np.abs(np.asarray(plain - want)).max() > 0.1) == (window < T)
 
 
-def test_the_windowed_read_through_a_ring_is_attention_over_the_window():
+@pytest.mark.parametrize("W,fold", [(40, 4), (104, 8)])
+def test_the_windowed_read_through_a_ring_is_attention_over_the_window(
+        W, fold):
     """Rows below, at and far beyond the window (163 tokens: the ring of 5
     pages wrapped twice), a row that holds no request, every step of a
     block of 16 (so the lower bound crosses a page's edge inside the
     block); what the ring's pages hold beyond a row's length is junk; then
-    the flush through the ring, plain and as the kernel."""
+    the flush through the ring, plain and as the kernel. Under a window of
+    104 the ring is 9 pages and a fold 8: the walks of 1, 3, 7, 8 and 9
+    pages end in last folds computed at 2, 4 and 8 pages."""
+    from gofr_tpu.ops.paged_attention import fold_of
+
     rng = np.random.default_rng(0)
-    ps, W, Hkv, G, dh, B, T, L = 16, 40, 2, 3, 32, 5, 16, 2
+    ps, Hkv, G, dh, B, T, L = 16, 2, 3, 32, 5, 16, 2
     ring = -(-W // ps) + 2
+    assert fold_of([np.zeros((L, 1, Hkv, dh, ps), np.float32)] * 2,
+                   ring) == fold
     lengths = np.array([0, 7, 40, 97, 163])
     K = rng.standard_normal((L, B, 200, Hkv, dh)).astype(np.float32)
     V = rng.standard_normal((L, B, 200, Hkv, dh)).astype(np.float32)
@@ -425,17 +434,20 @@ def test_the_windowed_read_through_a_ring_is_attention_over_the_window():
     k_tail, v_tail = plane_tail(k_pool, B, T), plane_tail(v_pool, B, T)
     live = table[:, 0] > 0
     worst = 0.0
+    # one trace for the block's 16 steps x 2 layers
+    read = jax.jit(functools.partial(paged_attention_in_block, window=W,
+                                     ring=ring, interpret=True))
     for step in range(T):
         q = rng.standard_normal((B, Hkv * G, dh)).astype(np.float32)
         for layer in range(L):
             at = lengths + step
-            out, k_tail, v_tail = paged_attention_in_block(
+            out, k_tail, v_tail = read(
                 jnp.asarray(q), jnp.asarray(K[layer, np.arange(B), at]),
                 jnp.asarray(V[layer, np.arange(B), at]), k_pool, v_pool,
                 k_tail, v_tail, jnp.asarray(table),
                 jnp.asarray(np.where(live, lengths, 0), jnp.int32),
                 jnp.asarray(np.where(live, step + 1, 0), jnp.int32),
-                layer=jnp.int32(layer), window=W, ring=ring, interpret=True)
+                layer=jnp.int32(layer))
             assert np.all(np.asarray(out[0]) == 0.0)
             for b in range(1, B):
                 lo = max(0, at[b] - W + 1)
@@ -522,7 +534,9 @@ def test_the_engine_serves_through_both_groups_what_the_reference_puts_first(
     assert window["reserved_per_sequence_mean"] == 4.0
     assert full["used"] == 0 and window["used"] == 0
     assert window["read"]["folds"] > 0 and full["read"]["folds"] > 0
-    assert 0 < window["read"]["fold_live_share"] <= 1
+    for read in (window["read"], full["read"]):
+        assert 0 < read["narrowed_folds"] <= read["folds"]
+        assert 0 < read["fold_live_share"] <= 1
     model = engine.model_snapshot()
     assert model["family"] == "afmoe" and model["kv_layers"] == 5
     assert model["cache_bytes_per_token"] == 5 * 2 * 2 * 16 * 4

@@ -695,8 +695,13 @@ def test_mla_moe_decode_step_compiles_with_its_one_pool_in_place(topo,
     copied."""
     from gofr_tpu.tpu.executor import _named_after
 
+    from gofr_tpu.ops.paged_attention import fold_of, fold_widths
+
     cfg, chips, engine, params, pools, loop, rng = _mla_moe_programs(topo)
     assert [p.shape for p in pools] == [(3, 4600, 1, 576, PAGE)]
+    # a fold of 8 latent pages, a row's last one computed at 8, 4 or 2 (PR
+    # 40): three copies of a turn's body in each read, for the chip
+    assert fold_widths(fold_of(pools, 64)) == (8, 4, 2)
     compiled = _compile(
         _named_after(engine._decode_fn_paged(16, 64),
                      "mla-moe-paged-decode-x16-NP64"),
@@ -759,8 +764,12 @@ def test_the_residual_mix_compiles_two_kernels_a_sublayer(topo, as_tpu):
     no ragged last block); the pool aliased, nothing pool-sized copied."""
     from gofr_tpu.tpu.executor import _named_after
 
+    from gofr_tpu.ops.paged_attention import fold_of, fold_widths
+
     cfg, chips, engine, params, pools, loop, rng = _xing_programs(topo)
     assert cfg.hc_mult == 4 and cfg.first_dense == 2
+    # rows of 1-9 pages under a fold of 8: the read's width branches
+    assert fold_widths(fold_of(pools, 16)) == (8, 4, 2)
     assert params["layers"][2]["ffn_hc_phi"].shape == (24, 14336)
     assert params["layers"][2]["ffn_hc_phi"].dtype == jnp.float32
     compiled = _compile(

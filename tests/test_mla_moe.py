@@ -301,14 +301,20 @@ def test_the_eight_shares_of_the_expert_layer_add_up_to_the_whole():
 # cases at folds of 4 are a fold's edges: exactly C pages, C + 1 (a last
 # fold of one page after a full one), one page, one token, a row of length
 # 0 between two live rows, a row whose pages are still empty, two full
-# folds, a last fold of one page and a token.
+# folds, a last fold of one page and a token. The rows of the cases under
+# a table of 16 are last folds of every width a fold of 8 is computed at
+# (`fold_branch`): 1, 2, 3, 4, 5, 7 and 8 live pages, a row of one token,
+# and a row of 9 pages (a full fold, then one page) after a row of one.
 @pytest.mark.parametrize("n_table,fold,lengths,tail_lens", [
     (3, 2, [37, 0, 16, 5], [3, 0, 1, 4]), (3, 2, [0, 0, 0, 0], [0, 0, 0, 0]),
     (3, 2, [48, 48, 1, 33], [1, 2, 3, 4]), (3, 1, [37, 0, 16, 5], [3, 0, 1, 4]),
     (8, 4, [64, 80, 16, 0, 1, 128, 65], [1, 8, 2, 0, 5, 3, 4]),
     (8, 4, [1, 0, 0, 113, 0, 64, 0], [1, 0, 3, 2, 0, 8, 0]),
     (8, 4, [128, 17, 0, 96, 49, 0, 4], [8, 1, 0, 7, 2, 0, 3]),
-    (8, 8, [128, 17, 0, 96, 49, 0, 4], [8, 1, 0, 7, 2, 0, 3])])
+    (8, 8, [128, 17, 0, 96, 49, 0, 4], [8, 1, 0, 7, 2, 0, 3]),
+    (16, 8, [16, 144, 1, 29, 48, 0, 65], [1, 8, 2, 3, 5, 0, 4]),
+    (16, 8, [80, 112, 0, 128, 3, 130, 17], [3, 1, 0, 8, 2, 6, 7]),
+    (16, 8, [1, 241, 16, 0, 33, 256, 2], [8, 8, 1, 0, 4, 3, 1])])
 def test_mla_read_in_interpret_mode_is_its_oracle(n_table, fold, lengths,
                                                   tail_lens, monkeypatch):
     """Pages and the block's tail in one softmax, the value the first 32
@@ -503,13 +509,15 @@ def test_the_engine_counts_the_folds_its_reads_made(seeded):
     request, then three of another: under the table of 4 these requests
     take a fold is 4 of the tiny pages; every step of a block, every
     layer, the read walks the pages of what the block found (its own
-    tokens wait in the tail) in ONE fold of 4 x 16 lanes."""
+    tokens wait in the tail) in ONE fold, computed as wide as the pages it
+    copied: 2 pages of 30 tokens at 2, 3 pages at 4, one page at 1."""
     from gofr_tpu.tpu.utilization import engine_snapshot
 
     _, params = seeded
     engine = _engine(program_config(), params)
     assert engine.paging_snapshot()["read"] == {
-        "pages_per_fold": None, "folds": 0, "fold_live_share": None}
+        "pages_per_fold": None, "folds": 0, "narrowed_folds": 0,
+        "fold_live_share": None}
     engine.start()
     try:
         for n in (30, 5):
@@ -524,8 +532,10 @@ def test_the_engine_counts_the_folds_its_reads_made(seeded):
     assert all(-(-n // 16) <= 4 for n in found)          # one fold each
     assert read["pages_per_fold"] == 4
     assert read["folds"] == layers * block * len(found)
+    assert [-(-n // 16) for n in found] == [2, 3, 3, 1, 1, 1]
+    assert read["narrowed_folds"] == layers * block * 4
     assert read["fold_live_share"] == round(
-        layers * block * sum(found) / (read["folds"] * 4 * 16), 4)
+        sum(found) / ((2 + 4 + 4 + 1 + 1 + 1) * 16), 4)
 
 
 def test_the_two_plane_families_say_k_and_v():

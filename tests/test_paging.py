@@ -19,7 +19,8 @@ from gofr_tpu.models.llama import (LlamaConfig, init_kv_cache,
                                    llama_prefill)
 from gofr_tpu.ops import paged_attention as paged_attention_module
 from gofr_tpu.ops.paged_attention import (_write_columns, block_tail,
-                                          fold_of, pages_per_fold,
+                                          fold_branch, fold_of, fold_widths,
+                                          pages_per_fold,
                                           paged_attention,
                                           paged_attention_in_block,
                                           paged_attention_reference,
@@ -62,7 +63,10 @@ EDGE_GEOMETRY = {**GEOMETRY, "MQA": (4, 1, 32)}
 # that makes a fold the pages a case names.
 FOLD_TABLE = 16
 FOLD_GEOMETRY = {"Hkv8": (16, 8, 16), "Hkv2": (4, 2, 32), "MQA": (4, 1, 32)}
-FOLD_CASES = [("Hkv8", 1), ("Hkv8", 2), ("Hkv2", 8), ("MQA", 4)]
+FOLD_CASES = [("Hkv8", 1, "edges"), ("Hkv8", 2, "edges"),
+              ("Hkv2", 8, "edges"), ("MQA", 4, "edges"),
+              ("Hkv8", 8, "narrowed"), ("Hkv2", 8, "narrowed"),
+              ("MQA", 8, "narrowed")]
 
 
 def _folding(monkeypatch, pools, pages):
@@ -80,6 +84,21 @@ def _fold_edges(c, ps):
     row again, two full folds less eleven tokens (room for a block of 8
     under a table of 2 c pages)."""
     return [c * ps, (c + 1) * ps, ps, 1, 0, c * ps + 1, 0, 2 * c * ps - 11]
+
+
+def _narrowed_folds(c, ps):
+    """Row lengths whose last folds are computed at every width of a
+    fold of c = 8 pages (`fold_branch`: 2, 4, 8): one page, then c + 1 pages (a
+    full fold and a last one of one page: the turn after a wide one must
+    not take what it left for live), one token, last folds of 2, 3, 4, 5,
+    7 and 8 live pages with their last page full, nearly full or holding
+    one token, a row of length 0, and c + 3 pages less eleven tokens
+    (room for a block of 8 under a table of 2 c pages)."""
+    return [ps, (c + 1) * ps, 1, 2 * ps - 3, 2 * ps + 1, 4 * ps, 5 * ps - 1,
+            6 * ps + 1, 8 * ps, 0, (c + 3) * ps - 11]
+
+
+FOLD_ROWS = {"edges": _fold_edges, "narrowed": _narrowed_folds}
 
 
 def _paged_case(geometry, dtype, lengths, seed=0, n_table=NP_TABLE):
@@ -198,15 +217,71 @@ def test_the_fold_is_worked_out_from_what_a_call_sees():
     assert fold_of([pool, pool, scale, scale], 64, TwoShards()) == 8
 
 
-@pytest.mark.parametrize("pool", ["f32", "int8"])
-@pytest.mark.parametrize("geometry,c", FOLD_CASES)
-def test_paged_attention_folds_ragged_rows(geometry, c, pool, monkeypatch):
-    """A fold's edges (`_fold_edges`) at folds of 1, 2, 4 and 8 pages,
-    every dead page NaN (the int8 pools': its scales): a short last fold
-    reads no page it does not own, and the lanes it leaves uncopied,
-    masked, do not reach the value product."""
+@pytest.mark.parametrize("c", [1, 2, 4, 8, 16])
+def test_a_fold_is_computed_at_the_least_power_of_two_that_covers_it(c):
+    """`fold_branch`: which of `fold_widths(c)` (c, c / 2, c / 4) a fold of
+    n live pages is computed at. Never narrower than what was copied, a
+    power of two, never wider than the fold, and the least such that is
+    no less than a quarter of the fold; the same answer for a Python int,
+    an array of the host's counter and a traced scalar of the kernel's."""
+    widths = fold_widths(c)
+    assert widths == tuple(w for w in (c, c // 2, c // 4) if w)
+    live = np.arange(1, c + 1)
+    computed = [widths[fold_branch(int(n), c)] for n in live]
+    for n, w in zip(live, computed):
+        assert n <= w <= c and w & (w - 1) == 0
+        assert w == widths[-1] or w // 2 < n
+    if c == 8:
+        assert computed == [2, 2, 4, 4, 8, 8, 8, 8]
+    if c == 1:      # one width: nothing to choose, for anybody
+        return
+    assert np.take(widths, fold_branch(live, c)).tolist() == computed
+    traced = jax.jit(jax.vmap(lambda n: fold_branch(n, c)))(jnp.asarray(live))
+    assert np.take(widths, np.asarray(traced)).tolist() == computed
+
+
+def _width_choices(jaxpr, in_loop=False):
+    """[(branches, inside a loop)] of every `cond` of more than two
+    branches in a jaxpr, kernels' included."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "cond" and len(eqn.params["branches"]) > 2:
+            found.append((len(eqn.params["branches"]), in_loop))
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found += _width_choices(
+                        sub, in_loop or eqn.primitive.name == "while")
+    return found
+
+
+@pytest.mark.parametrize("c", [1, 4, 8])
+def test_only_a_rows_last_fold_chooses_its_width(c, monkeypatch):
+    """The kernel holds ONE choice among a fold's widths, outside the
+    loop over a row's full folds (a full fold's turn is computed at C
+    pages and branches on no width), and none at folds of one page."""
     q, k, v, table, lens = _paged_case(
-        geometry, jnp.float32, _fold_edges(c, PS), seed=11,
+        "Hkv2", jnp.float32, _fold_edges(c, PS), n_table=FOLD_TABLE)
+    _folding(monkeypatch, (k, v), c)
+    jaxpr = jax.make_jaxpr(lambda *a: paged_attention(*a, interpret=True))(
+        q, k, v, table, lens).jaxpr
+    assert _width_choices(jaxpr) == ([(len(fold_widths(c)), False)]
+                                     if c > 1 else [])
+
+
+@pytest.mark.parametrize("pool", ["f32", "int8"])
+@pytest.mark.parametrize("geometry,c,rows", FOLD_CASES)
+def test_paged_attention_folds_ragged_rows(geometry, c, rows, pool,
+                                           monkeypatch):
+    """A fold's edges (`_fold_edges`) at folds of 1, 2, 4 and 8 pages, and
+    last folds of every width a fold of 8 is computed at
+    (`_narrowed_folds`) at 8, 2 and 1 KV heads, every dead page NaN (the
+    int8 pools': its scales): a short last fold reads no page it does not
+    own, and the lanes it leaves uncopied or does not compute do not reach
+    the value product."""
+    q, k, v, table, lens = _paged_case(
+        geometry, jnp.float32, FOLD_ROWS[rows](c, PS), seed=11,
         n_table=FOLD_TABLE)
     dead = _dead_pages(k.shape[0], table, lens, PS)
     if pool == "int8":
@@ -355,18 +430,20 @@ def test_paged_attention_over_pages_and_tail_matches_reference(t, geometry,
 
 
 @pytest.mark.parametrize("t", [0, 5])
-@pytest.mark.parametrize("geometry,c", [("Hkv8", 2), ("Hkv2", 8),
-                                        ("Hkv2", 1)])
-def test_paged_attention_in_block_folds_ragged_rows(geometry, c, t,
+@pytest.mark.parametrize("geometry,c,rows", [
+    ("Hkv8", 2, "edges"), ("Hkv2", 8, "edges"), ("Hkv2", 1, "edges"),
+    ("Hkv8", 8, "narrowed"), ("Hkv2", 8, "narrowed"),
+    ("MQA", 8, "narrowed")])
+def test_paged_attention_in_block_folds_ragged_rows(geometry, c, rows, t,
                                                     monkeypatch):
-    """The read inside a decode block at a fold's edges (`_fold_edges`),
-    pages of 8 tokens under a table 16 wide, step t of a block of 8 with
-    every dead page NaN: against the reference on a pool that had the
-    block's tokens written column by column. The rows of length 0 hold no
-    request."""
+    """The read inside a decode block at a fold's edges (`_fold_edges`)
+    and at last folds of every width (`_narrowed_folds`), pages of 8
+    tokens under a table 16 wide, step t of a block of 8 with every dead
+    page NaN: against the reference on a pool that had the block's tokens
+    written column by column. The rows of length 0 hold no request."""
     H, Hkv, dh = FOLD_GEOMETRY[geometry]
     block, layers = 8, 2
-    starts = _fold_edges(c, PS)
+    starts = FOLD_ROWS[rows](c, PS)
     rng = np.random.default_rng(13)
     B, n_pool_pages = len(starts), 1 + len(starts) * FOLD_TABLE
     k_pool, v_pool = (jnp.asarray(rng.normal(
@@ -679,6 +756,7 @@ def test_every_decode_block_size_serves_the_references_tokens(block):
     read = paged.paging_snapshot()["read"]
     assert read["pages_per_fold"] == 8
     assert read["folds"] >= CFG.n_layers * write["tokens"]
+    assert 0 < read["narrowed_folds"] <= read["folds"]
     assert 0 < read["fold_live_share"] <= 1
 
 
@@ -875,22 +953,40 @@ def test_paged_q8_engine_matches_paged_fp_closely():
     assert q8 == serve(cfg_q8)       # deterministic
 
 
-def test_the_int8_engine_counts_its_reads_a_token_longer_each_step():
+def test_the_int8_engine_counts_its_reads_a_token_longer_each_step(
+        monkeypatch):
     """`paging.read` where there is no block's tail: step t of a block
     attends what the block found and the t + 1 tokens written since, all
-    in pages (tiny pages under a table of 8: one fold of 8 x 16 lanes)."""
+    in pages. Six blocks of 4 over tiny pages under a table of 16, a fold
+    given the weight of 8 of them: the row grows from 4 pages to 10, so
+    its last fold is computed at 4 pages, then at 8, then (a second fold
+    of one page, then two) at 2, counted here a step at a time."""
     import dataclasses
 
     engine = PagedLLMEngine(
         llama_init(CFG, seed=0), dataclasses.replace(CFG, kv_dtype="int8"),
-        page_size=16, n_slots=4, max_seq_len=128, prefill_buckets=(8, 64),
+        page_size=16, n_slots=4, max_seq_len=256, prefill_buckets=(8, 64),
         decode_block_size=4)
-    engine.slots[2].length = 60
-    engine._note_page_reads([(2, None)], 4, 8)
+    _folding(monkeypatch, [x[0] for x in (engine.k_cache, engine.v_cache,
+                                          engine.k_scale, engine.v_scale)],
+             8)
+    folds = narrowed = tokens = lanes = 0
+    for found in (60, 63, 112, 126, 130, 150):
+        engine.slots[2].length = found
+        engine._note_page_reads([(2, None)], 4, 16)
+        for attended in range(found + 1, found + 5):
+            pages = -(-attended // 16)
+            last = pages % 8 or 8
+            width = min(w for w in (2, 4, 8) if w >= last)
+            folds += -(-pages // 8)
+            narrowed += width < 8
+            tokens += attended
+            lanes += (pages - last + width) * 16
+    assert narrowed == 4 + 1 + 0 + 2 + 4 + 4 and folds == 3 * 4 + 6 + 2 * 8
     read = engine.paging_snapshot()["read"]
-    assert read == {"pages_per_fold": 8, "folds": CFG.n_layers * 4,
-                    "fold_live_share": round(
-                        (61 + 62 + 63 + 64) / (4 * 8 * 16), 4)}
+    assert read == {"pages_per_fold": 8, "folds": CFG.n_layers * folds,
+                    "narrowed_folds": CFG.n_layers * narrowed,
+                    "fold_live_share": round(tokens / lanes, 4)}
 
 
 def test_quantize_kv_roundtrip_error_bounded():

@@ -1,6 +1,6 @@
 """The paged kernels alone, at the benchmark cells' shapes, on the chip.
 
-    chiprun -- python tools/bench_paged_read.py [label=path/to/paged_attention.py ...]
+    chiprun -- python tools/bench_paged_read.py [only=part,...] [label=path/to/paged_attention.py ...]
 
 Times `paged_attention` of this tree, and of any other copy of the module
 given as label=path (a parent's unpacked under build/, an experiment), over
@@ -50,8 +50,24 @@ device's peak bytes/s over the tokens a row still sees, and the largest
 error against masked attention over the same pages. And beside them the
 two other kernels that cell added work to, alone: the tiled gated experts
 at 3072 x 3072 (32 held, the 12 a step of 31 rows touches) and the prefill's
-flash attention over 12,288 tokens of 48 / 8 heads, told the window and not
-(`only=trinity` runs this part alone).
+flash attention over 12,288 tokens of 48 / 8 heads, told the window and not.
+
+Then (PR 40) the SHORT rows of `xing4.0-29b-a4b-ep8.decode-closed`: the
+latent read at 20 layers x 865 pages, 96 rows of 32 queries under a table 16
+wide, 93 live rows at the lengths `decode-closed` holds (32-128 in, up to
+512-1,024 out, a request caught anywhere in its life: 1-9 pages, nearly all
+ONE fold of 8), block 16. A fold is computed as wide as the pages it copied
+(`fold_branch`); beside the rule's own line the tool times folds of 1, 2 and
+4 pages, the same rows with every fold computed at all C pages (`computed_
+pages`: the program of a module from before PR 40, made in this one by
+standing in for `fold_branch`), and tables whose live rows ALL hold n pages
+(`pages_a_row` 1, 2, 4, 8), each computed at every width that covers n (the
+kernel still chooses: its choice is stood in for, not taken out): what a
+row costs by what it copies and by what it computes. And nemotron's
+read+tail (2 KV heads x 16 queries, 2 layers) at the same rows.
+`only=` names the parts to run, in the order above: `pools`, `tail`,
+`latent`, `short`, `trinity` (every module's two reads of that cell; its
+experts and prefill attention beside the tree's).
 
 It is not the benchmark: it says what a kernel costs alone, never what a
 cell gains (PERF.md section 5 keeps its table). It refuses a device that
@@ -91,12 +107,27 @@ def load(label: str, path: str):
     return module
 
 
-def table_of(name: str, rng, room: int = 0, n_pool_pages: int = P):
+def table_of(name, rng, room: int = 0, n_pool_pages: int = P):
     """(table [B, NP], lengths [B], whole live pages). `room`: tokens each
     live row's pages must still take past its length (a decode block). A
-    row of length 0 or 1 holds no request: its table is all page 0."""
+    row of length 0 or 1 holds no request: its table is all page 0. `name`
+    an int: every live row holds that many pages, the last one filled to
+    anywhere."""
     rows, width = B, NP
-    if name == "closed":
+    if isinstance(name, int):
+        lengths = (name - 1) * PS + rng.integers(1, PS + 1 - room, size=B)
+        lengths[[17, 40, 77]] = 0
+    elif name == "short":
+        # a slot of `decode-closed` at a random instant: a request (drawn
+        # in proportion to how long it lasts) somewhere in its output
+        prompts = rng.integers(32, 129, size=4 * B)
+        outputs = rng.integers(512, 1025, size=4 * B)
+        held = rng.choice(4 * B, size=B, replace=False,
+                          p=outputs / outputs.sum())
+        lengths = prompts[held] + (
+            rng.random(B) * (outputs[held] - room)).astype(np.int64)
+        lengths[[17, 40, 77]] = 0
+    elif name == "closed":
         lengths = rng.integers(60, 900, size=B)
         lengths[5] = 1152
         lengths[[17, 40, 77]] = 1
@@ -175,9 +206,16 @@ GEOMETRIES = {"internlm2": (24, 769, 8, 16), "nemotron": (2, 961, 2, 32)}
 # fold widths timed beside the rule's own (None), where the rule might
 # have chosen otherwise
 FOLDS = {"internlm2": (1, None), "nemotron": (1, 4, None)}
-LATENT = {"layers": 12, "pages": 4600, "width": 576, "value_width": 512,
-          "rows": 128, "heads": 32, "table": 64, "scale": 192 ** -0.5,
-          "folds": (1, 2, 4, 8, 16, None)}
+LATENT = {"name": "latent", "layers": 12, "pages": 4600, "width": 576,
+          "value_width": 512, "rows": 128, "heads": 32, "table": 64,
+          "scale": 192 ** -0.5, "folds": (1, 2, 4, 8, 16, None),
+          "tables": ("longprompt",)}
+# xing's cell: the same page, short rows (`table_of`: "short", and every live
+# row of n pages)
+SHORT = {"name": "short", "layers": 20, "pages": 865, "width": 576,
+         "value_width": 512, "rows": B, "heads": 32, "table": NP,
+         "scale": 192 ** -0.5, "folds": (1, 2, 4, None),
+         "tables": ("short", 1, 2, 4, 8)}
 
 
 @contextlib.contextmanager
@@ -197,9 +235,29 @@ def folding(module, pages, pools, width: int):
         module.pages_per_fold = rule
 
 
-def tail_lines(label: str, module, geometry: str, device) -> None:
-    """The block's tail at one geometry (layers, pages, KV heads, heads):
-    one JSON line a measurement (see the module docstring)."""
+@contextlib.contextmanager
+def computing(module, pages):
+    """Inside, `module`'s reads compute a fold at least `pages` pages wide,
+    whatever it copied (None, or a module from before PR 40: as the module
+    has it): the module's own choice where that is wider, which it is for
+    a row without a page (the kernel gives it a whole fold and runs no
+    turn: a narrow turn there would wait for copies nobody started)."""
+    rule = getattr(module, "fold_branch", None)
+    if pages and rule is not None:
+        module.fold_branch = lambda live, fold: jnp.minimum(
+            rule(live, fold), module.fold_widths(fold).index(pages))
+    try:
+        yield
+    finally:
+        if rule is not None:
+            module.fold_branch = rule
+
+
+def tail_lines(label: str, module, geometry: str, device,
+               rows: str = "closed") -> None:
+    """The block's tail at one geometry (layers, pages, KV heads, heads)
+    under the table `rows` names: one JSON line a measurement (see the
+    module docstring); under another table than `closed`, the reads only."""
     layers, pages, n_kv, heads = GEOMETRIES[geometry]
     keys = jax.random.split(jax.random.PRNGKey(2), 5)
     draw = lambda k, shape: jax.random.normal(           # noqa: E731
@@ -209,7 +267,7 @@ def tail_lines(label: str, module, geometry: str, device) -> None:
     k_pool, v_pool = stack(one[0]), stack(one[1])
     q = draw(keys[2], (B, heads, DH))
     news = [draw(k, (BLOCK, B, n_kv, DH)) for k in keys[3:]]
-    table, starts, live_pages = table_of("closed", np.random.default_rng(1),
+    table, starts, live_pages = table_of(rows, np.random.default_rng(1),
                                          room=BLOCK, n_pool_pages=pages)
     live = table[:, 0] > 0
     paged = jnp.where(live, starts, 0)
@@ -218,7 +276,7 @@ def tail_lines(label: str, module, geometry: str, device) -> None:
     def line(what: str, us: float, **more) -> None:
         print(json.dumps({
             "device": device.device_kind, "kernel": label, "what": what,
-            "geometry": geometry, "rows": int(live.sum()),
+            "geometry": geometry, "table": rows, "rows": int(live.sum()),
             "live_pages": live_pages, "block": BLOCK,
             "us": round(us, 1), **more}), flush=True)
 
@@ -274,7 +332,9 @@ def tail_lines(label: str, module, geometry: str, device) -> None:
             *filled(*module.block_tail(k_pool, B, BLOCK), last, BLOCK - 1),
             table, paged, counts, layer=jnp.int32(last))[0]
 
-    line("tail_put", steps(read=False))
+    reads_only = rows != "closed"
+    if not reads_only:
+        line("tail_put", steps(read=False))
     for pages in FOLDS[geometry]:
         if pages and not hasattr(module, "pages_per_fold"):
             continue
@@ -284,6 +344,8 @@ def tail_lines(label: str, module, geometry: str, device) -> None:
             line("read+tail", steps(read=True), pages_per_fold=folded,
                  by_rule=pages is None, max_abs_err=float(np.max(np.abs(
                      np.asarray(got, np.float32) - np.asarray(want)))))
+    if reads_only:
+        return
 
     def write(k_pool, v_pool):
         def layer(l, pools):
@@ -319,11 +381,13 @@ def tail_lines(label: str, module, geometry: str, device) -> None:
     line("flush", seconds / (layers * STEPS) * 1e6, equals_columns=exact)
 
 
-def latent_lines(label: str, module, device, peak_bytes_s: float) -> None:
-    """`mla_read` at the `longprompt-closed` cell's geometry: one JSON line
-    a fold width (see the module docstring). The call is `mla_read`'s own
-    (ops/mla_read.py), made on `module`'s `_paged_read`."""
-    g = LATENT
+def latent_lines(label: str, module, device, peak_bytes_s: float,
+                 g: dict = LATENT) -> None:
+    """`mla_read` at a latent geometry (`LATENT`: the `longprompt-closed`
+    cell's; `SHORT`: xing's `decode-closed`), under each of its tables: one
+    JSON line a fold width, and under a table of n pages a row one a width
+    its folds are computed at (see the module docstring). The call is
+    `mla_read`'s own (ops/mla_read.py), made on `module`'s `_paged_read`."""
     layers, rows, w, r = g["layers"], g["rows"], g["width"], g["value_width"]
     keys = jax.random.split(jax.random.PRNGKey(3), 3)
     draw = lambda k, shape: jax.random.normal(           # noqa: E731
@@ -331,76 +395,107 @@ def latent_lines(label: str, module, device, peak_bytes_s: float) -> None:
     one = draw(keys[0], (g["pages"], 1, w, PS))
     q = draw(keys[1], (rows, g["heads"], w))
     news = draw(keys[2], (BLOCK, rows, 1, w))
-    table, starts, live_pages = table_of(
-        "longprompt", np.random.default_rng(1), room=BLOCK,
-        n_pool_pages=g["pages"])
-    live = table[:, 0] > 0
-    floor_us = live_pages * w * PS * one.dtype.itemsize / peak_bytes_s * 1e6
-
-    # what one layer holds once the block's tokens are written by columns,
-    # and the reference's read of it (16 rows at a time: the oracle
-    # gathers every row's whole table in float32), before the stack is made
     last = layers - 1
-    want_pool = one
-    for t in range(BLOCK):
-        want_pool = module._write_columns([want_pool[None]], [news[t]],
-                                          table, starts + t, 0)[0][0]
     oracle = jax.jit(lambda *a: mla_read_reference(
         *a, value_width=r, scale=g["scale"]))
-    after = jnp.where(live, starts + BLOCK, 0)
-    want = np.concatenate([np.asarray(oracle(
-        q[i:i + 16].astype(jnp.float32), want_pool, table[i:i + 16],
-        after[i:i + 16])) for i in range(0, rows, 16)])
-    del want_pool
+
+    def case(name):
+        """A table, and what the reference reads of one layer once the
+        block's tokens are written by columns (16 rows at a time: the
+        oracle gathers every row's whole table in float32)."""
+        table, starts, live_pages = table_of(
+            name, np.random.default_rng(1), room=BLOCK,
+            n_pool_pages=g["pages"])
+        live = table[:, 0] > 0
+        want_pool = one
+        for t in range(BLOCK):
+            want_pool = module._write_columns([want_pool[None]], [news[t]],
+                                              table, starts + t, 0)[0][0]
+        after = jnp.where(live, starts + BLOCK, 0)
+        want = np.concatenate([np.asarray(oracle(
+            q[i:i + 16].astype(jnp.float32), want_pool, table[i:i + 16],
+            after[i:i + 16])) for i in range(0, rows, 16)])
+        return name, table, starts, live, live_pages, want
+
+    # before the stack is made: the written layer is a pool's worth itself
+    cases = [case(name) for name in g["tables"]]
     pool = jax.jit(lambda x: jnp.tile(x[None], (layers, 1, 1, 1, 1)))(one)
+    rule = getattr(module, "fold_of", lambda *_: 1)((pool,), g["table"])
 
-    def read(q, new, pool, tail, tail_lens, layer):
-        return module._paged_read(
-            q, [pool], table, starts, (new, tail, tail_lens), layer, None,
-            None, value_width=r, scale=g["scale"], scope="mla_read")
+    for name, table, starts, live, live_pages, want in cases:
+        floor_us = (live_pages * w * PS * one.dtype.itemsize / peak_bytes_s
+                    * 1e6)
 
-    def run(q, pool):
-        def layer(l, carry):
-            t, acc, tail = carry
-            out, tail = read(q, news[t], pool, tail,
-                             jnp.where(live, t + 1, 0), l)
-            return t, acc + out.astype(jnp.float32), tail
+        def read(q, new, pool, tail, tail_lens, layer):
+            return module._paged_read(
+                q, [pool], table, starts, (new, tail, tail_lens), layer,
+                None, None, value_width=r, scale=g["scale"],
+                scope="mla_read")
 
-        acc, tail = jax.lax.fori_loop(
-            0, BLOCK, lambda t, carry: jax.lax.fori_loop(
-                0, layers, layer, (t,) + carry)[1:],
-            (jnp.zeros((rows, g["heads"], r), jnp.float32),
-             module.plane_tail(pool, rows, BLOCK)))
-        return acc + tail[0, :, 0, 0, :1][:, :, None]
+        def run(q, pool):
+            def layer(l, carry):
+                t, acc, tail = carry
+                out, tail = read(q, news[t], pool, tail,
+                                 jnp.where(live, t + 1, 0), l)
+                return t, acc + out.astype(jnp.float32), tail
 
-    def last_step(q, pool):
-        tail = module.plane_tail(pool, rows, BLOCK)
-        for t in range(BLOCK - 1):
-            tail = jax.lax.dynamic_update_slice(
-                tail, jnp.pad(news[t], ((0, 0), (0, 0), (
-                    0, tail.shape[-1] - w)))[None, :, :, None],
-                (last, 0, 0, t, 0))
-        return read(q, news[-1], pool, tail, jnp.where(live, BLOCK, 0),
-                    jnp.int32(last))[0]
+            acc, tail = jax.lax.fori_loop(
+                0, BLOCK, lambda t, carry: jax.lax.fori_loop(
+                    0, layers, layer, (t,) + carry)[1:],
+                (jnp.zeros((rows, g["heads"], r), jnp.float32),
+                 module.plane_tail(pool, rows, BLOCK)))
+            return acc + tail[0, :, 0, 0, :1][:, :, None]
 
-    for pages in g["folds"]:
-        if pages and not hasattr(module, "pages_per_fold"):
-            continue
-        with folding(module, pages, (pool,), g["table"]) as folded:
-            # a new function a width: jit keeps its traces by function
-            got = jax.jit(lambda *a: last_step(*a))(q, pool)
-            us = (best_of_five(jax.jit(lambda *a: run(*a)), q, pool)
-                  / (layers * BLOCK) * 1e6)
-        print(json.dumps({
-            "device": device.device_kind, "kernel": label,
-            "what": "mla_read", "geometry": "latent",
-            "rows": int(live.sum()), "live_pages": live_pages,
-            "block": BLOCK, "pages_per_fold": folded,
-            "by_rule": pages is None, "us": round(us, 1),
-            "whole_pages_share_of_peak_pct": round(100 * floor_us / us, 1),
-            "max_abs_err": float(np.max(np.abs(
-                np.asarray(got, np.float32) - np.asarray(want))))}),
-            flush=True)
+        def last_step(q, pool):
+            tail = module.plane_tail(pool, rows, BLOCK)
+            for t in range(BLOCK - 1):
+                tail = jax.lax.dynamic_update_slice(
+                    tail, jnp.pad(news[t], ((0, 0), (0, 0), (
+                        0, tail.shape[-1] - w)))[None, :, :, None],
+                    (last, 0, 0, t, 0))
+            return read(q, news[-1], pool, tail, jnp.where(live, BLOCK, 0),
+                        jnp.int32(last))[0]
+
+        # (pages a fold, pages a fold is computed at): None = the module's
+        # own. A table of n pages a row: the rule's fold as it is, then
+        # computed at every width that covers n; the cell's rows: every
+        # fold width, then the rule's with nothing narrowed
+        narrows = hasattr(module, "fold_branch")
+        if isinstance(name, int):
+            lines = [(None, None)]
+            if narrows:
+                lines += [(None, width) for width in reversed(
+                    module.fold_widths(rule)) if width >= name]
+        else:
+            lines = [(pages, None) for pages in g["folds"]]
+            if narrows and g is SHORT:
+                lines.append((None, rule))
+        for pages, computed in lines:
+            if pages and not hasattr(module, "pages_per_fold"):
+                continue
+            with folding(module, pages, (pool,), g["table"]) as folded, \
+                    computing(module, computed):
+                # a new function a width: jit keeps its traces by function
+                got = jax.jit(lambda *a: last_step(*a))(q, pool)
+                us = (best_of_five(jax.jit(lambda *a: run(*a)), q, pool)
+                      / (layers * BLOCK) * 1e6)
+            print(json.dumps({
+                "device": device.device_kind, "kernel": label,
+                "what": "mla_read", "geometry": g["name"],
+                **({"pages_a_row": name} if isinstance(name, int)
+                   else {"table": name}),
+                "rows": int(live.sum()), "live_pages": live_pages,
+                "block": BLOCK, "pages_per_fold": folded,
+                "by_rule": pages is None,
+                "computed_pages": computed or (
+                    "copied" if narrows else folded),
+                "us": round(us, 1),
+                "us_a_row": round(us / int(live.sum()), 3),
+                "whole_pages_share_of_peak_pct": round(
+                    100 * floor_us / us, 1),
+                "max_abs_err": float(np.max(np.abs(
+                    np.asarray(got, np.float32) - np.asarray(want))))}),
+                flush=True)
 
 
 TRINITY = {"rows": 32, "kv": 8, "heads": 48, "window": 4096, "ring": 34,
@@ -408,9 +503,10 @@ TRINITY = {"rows": 32, "kv": 8, "heads": 48, "window": 4096, "ring": 34,
            "held": 32, "width": 3072, "touched": 12, "prompt": 12288}
 
 
-def trinity_lines(module, device, peak_bytes_s: float) -> None:
-    """The afmoe cell's reads, and its experts and prefill attention alone
-    (see the module docstring): one JSON line a measurement."""
+def trinity_lines(label: str, module, device, peak_bytes_s: float) -> None:
+    """The afmoe cell's reads through `module` and, beside the tree's, its
+    experts and prefill attention alone (see the module docstring): one
+    JSON line a measurement."""
     g = TRINITY
     rows, n_kv, heads, W, ring = (g["rows"], g["kv"], g["heads"],
                                   g["window"], g["ring"])
@@ -426,7 +522,7 @@ def trinity_lines(module, device, peak_bytes_s: float) -> None:
     paged = jnp.asarray(np.where(live, lengths, 0), jnp.int32)
 
     def line(what: str, us: float, **more) -> None:
-        print(json.dumps({"device": device.device_kind, "kernel": "tree",
+        print(json.dumps({"device": device.device_kind, "kernel": label,
                           "what": what, "geometry": "trinity",
                           "us": round(us, 1), **more}), flush=True)
 
@@ -507,6 +603,8 @@ def trinity_lines(module, device, peak_bytes_s: float) -> None:
              max_abs_err=float(np.max(np.abs(
                  np.asarray(got, np.float32) - np.asarray(want)))))
         del k_pool, v_pool, gathered
+    if label != "tree":
+        return
 
     # the tiled gated experts, a decode step of 31 rows: 12 of 32 touched
     from gofr_tpu.ops.moe_experts import decode_experts, width_tile
@@ -557,15 +655,39 @@ def trinity_lines(module, device, peak_bytes_s: float) -> None:
                  1))
 
 
+PARTS = ("pools", "tail", "latent", "short", "trinity")
+
+
 def main(argv) -> None:
     device = jax.devices()[0]
     peak_bytes_s = peaks.of(device.device_kind)["hbm_bytes_per_s"]
-    if "only=trinity" in argv:
-        return trinity_lines(gofr_tpu.ops.paged_attention, device,
-                             peak_bytes_s)
+    only = [arg[5:].split(",") for arg in argv if arg.startswith("only=")]
+    parts = only[0] if only else PARTS
     kernels = {"tree": gofr_tpu.ops.paged_attention}
     kernels.update((label, load(label, path)) for label, path in
-                   (arg.split("=", 1) for arg in argv))
+                   (arg.split("=", 1) for arg in argv
+                    if not arg.startswith("only=")))
+    if "pools" in parts:
+        pool_lines(kernels, device, peak_bytes_s)
+    for label, module in kernels.items():
+        if "tail" in parts and hasattr(module, "block_tail"):
+            for geometry in GEOMETRIES:
+                tail_lines(label, module, geometry, device)
+    for label, module in kernels.items():
+        if "latent" in parts and hasattr(module, "plane_tail"):
+            latent_lines(label, module, device, peak_bytes_s)
+    for label, module in kernels.items():
+        if "short" in parts and hasattr(module, "plane_tail"):
+            latent_lines(label, module, device, peak_bytes_s, SHORT)
+            tail_lines(label, module, "nemotron", device, rows="short")
+    for label, module in kernels.items():
+        if "trinity" in parts and hasattr(module, "plane_tail"):
+            trinity_lines(label, module, device, peak_bytes_s)
+
+
+def pool_lines(kernels: dict, device, peak_bytes_s: float) -> None:
+    """The read without a tail over internlm2's pools, bfloat16 and int8,
+    under the `closed` and `chat` tables: one JSON line a module."""
     reference = gofr_tpu.ops.paged_attention.paged_attention_reference
     q = jax.random.normal(jax.random.PRNGKey(1), (B, H, DH), jnp.bfloat16)
     for dtype in (jnp.bfloat16, jnp.int8):
@@ -594,14 +716,6 @@ def main(argv) -> None:
                     "max_abs_err": float(np.max(np.abs(
                         np.asarray(got, np.float32) - want)))}), flush=True)
         del k_pool, v_pool, scales, args
-    for label, module in kernels.items():
-        if hasattr(module, "block_tail"):
-            for geometry in GEOMETRIES:
-                tail_lines(label, module, geometry, device)
-    for label, module in kernels.items():
-        if hasattr(module, "plane_tail"):
-            latent_lines(label, module, device, peak_bytes_s)
-    trinity_lines(gofr_tpu.ops.paged_attention, device, peak_bytes_s)
 
 
 if __name__ == "__main__":
