@@ -7,8 +7,9 @@ POST /generate {"prompt": "...", "max_tokens": 64, "temperature": 0.7,
 Model size comes from MODEL_PRESET (debug | llama1b | llama3-8b, the
 nemotron_h family's nemotron-h-debug | nemotron-3-nano-30b-a3b-ep2, the
 mla_moe family's mla-moe-debug | joyai-llm-flash-ep8 | mla-moe-hc-debug |
-xing4.0-29b-a4b-ep8, and the afmoe family's afmoe-debug |
-trinity-large-preview-ep8). Weights
+xing4.0-29b-a4b-ep8, the afmoe family's afmoe-debug |
+trinity-large-preview-ep8, and the kda_moe family's kda-moe-debug |
+solar-open2-250b-ep8). Weights
 boot from a real HF-layout safetensors checkpoint when WEIGHTS_PATH is set
 (models.weights.load_llama_safetensors — streaming, int8 quantize-on-load);
 otherwise random-initialised (no checkpoints ship in this environment) with
@@ -25,6 +26,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", ".."))
 from gofr_tpu import App, Stream  # noqa: E402
 from gofr_tpu.http.errors import InvalidParam, ServiceUnavailable  # noqa: E402
 from gofr_tpu.models.afmoe import AfmoeConfig, afmoe_init  # noqa: E402
+from gofr_tpu.models.kda_moe import KdaMoeConfig, kda_moe_init  # noqa: E402
 from gofr_tpu.models.llama import LlamaConfig, llama_init  # noqa: E402
 from gofr_tpu.models.mla_moe import MlaMoeConfig, mla_moe_init  # noqa: E402
 from gofr_tpu.models.nemotron_h import (NemotronHConfig,  # noqa: E402
@@ -64,12 +66,19 @@ PRESETS = {
     # one chip's share of Trinity-Large-Preview, as the benchmark runs it
     # (benchmark/configs/trinity-large-preview-ep8.json)
     "trinity-large-preview-ep8": AfmoeConfig.trinity_large_preview_ep8,
+    # the kda_moe family: three Kimi Delta Attention (gated delta rule)
+    # blocks to every gated NoPE GQA block, a matrix state a slot beside
+    # the pool, gated sparse experts in every block
+    "kda-moe-debug": KdaMoeConfig.debug,
+    # one chip's share of Solar-Open2-250B, as the benchmark runs it
+    # (benchmark/configs/solar-open2-250b-ep8.json)
+    "solar-open2-250b-ep8": KdaMoeConfig.solar_open2_250b_ep8,
 }
 
 # the families that boot from seeded weights only: no checkpoint loader and
 # no int8 weight path yet
 SEEDED_ONLY = {NemotronHConfig: nemotron_h_init, MlaMoeConfig: mla_moe_init,
-               AfmoeConfig: afmoe_init}
+               AfmoeConfig: afmoe_init, KdaMoeConfig: kda_moe_init}
 
 
 def _load_tokenizer(path: str):

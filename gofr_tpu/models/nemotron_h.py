@@ -45,6 +45,7 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..ops.short_conv import conv_decode, conv_prefill
 from .llama import _np_dtype, rms_norm
 
 KINDS = {"M": "mamba", "E": "experts", "*": "attention"}
@@ -377,8 +378,8 @@ def mamba_prefill(u, w, lengths, cfg: NemotronHConfig):
     [K, N, heads * P] float32 as of each row's last real token, tail
     [K, W - 1, conv_dim])."""
     K, T, _ = u.shape
-    H, P, G, N, W = (cfg.mamba_heads, cfg.mamba_head_dim, cfg.n_groups,
-                     cfg.state_size, cfg.conv_kernel)
+    H, P, G, N = (cfg.mamba_heads, cfg.mamba_head_dim, cfg.n_groups,
+                  cfg.state_size)
     Q = min(cfg.chunk_size, T)
     if T % Q:
         raise ValueError(f"window {T} is not a multiple of the chunk {Q}")
@@ -386,11 +387,7 @@ def mamba_prefill(u, w, lengths, cfg: NemotronHConfig):
     real = jnp.arange(T)[None, :] < lengths[:, None]              # [K, T]
     # the tail decode continues from: xBC (before the convolution) at
     # lengths - (W - 1) ... lengths - 1, zeros before the sequence's start
-    at = lengths[:, None] - (W - 1) + jnp.arange(W - 1)[None, :]  # [K, W-1]
-    tail = jnp.where((at >= 0)[:, :, None], jnp.take_along_axis(
-        xBC, jnp.maximum(at, 0)[:, :, None], axis=1), 0).astype(u.dtype)
-    padded = jnp.pad(xBC, ((0, 0), (W - 1, 0), (0, 0)))
-    conv = sum(w["conv_w"][j] * padded[:, j:j + T] for j in range(W))
+    conv, tail = conv_prefill(xBC, w["conv_w"], lengths, u.dtype)
     x, B, C = _split_xbc(jax.nn.silu(conv + w["conv_b"]), cfg)
     x = x.reshape(K, T, H, P).astype(jnp.float32)
     B, C = B.astype(jnp.float32), C.astype(jnp.float32)
@@ -440,13 +437,10 @@ def mamba_decode(u, w, state, tail, layer: int, live, cfg: NemotronHConfig):
     blocks; live [B]. Returns (out [B, D], state, tail)."""
     from ..ops.ssm_update import ssm_update
 
-    H, P, W = cfg.mamba_heads, cfg.mamba_head_dim, cfg.conv_kernel
+    H, P = cfg.mamba_heads, cfg.mamba_head_dim
     z, xBC, dt = _split_proj(_in_proj(u, w), cfg)
-    window = jnp.concatenate([tail[layer].astype(jnp.float32),
-                              xBC[:, None]], axis=1)              # [B, W, c]
-    tail = tail.at[layer].set(window[:, 1:].astype(tail.dtype))
-    conv = jnp.sum(w["conv_w"][None] * window, axis=1) + w["conv_b"]
-    x, B, C = _split_xbc(jax.nn.silu(conv), cfg)
+    conv, tail = conv_decode(tail, layer, xBC, w["conv_w"])
+    x, B, C = _split_xbc(jax.nn.silu(conv + w["conv_b"]), cfg)
     x = x.reshape(-1, H, P).astype(jnp.float32)
     dt = jax.nn.softplus(dt.astype(jnp.float32) + w["dt_bias"])   # [B, H]
     decay = jnp.exp(dt * -jnp.exp(w["A_log"]))

@@ -11,9 +11,9 @@ it:
   engine keeps one pool a plane, [kv_layers, pages, heads, width,
   page_size], and allocates, writes, flushes, plans and reports by this
   answer. `kv_planes(Hkv, dh)` is K and V of grouped-query attention (the
-  llama_like and nemotron_h families); mla_moe keeps ONE plane of 1 x 576,
-  a token's normed latent and its rotated shared key. What is said of
-  "the pools" below is a tuple in this order.
+  llama_like, nemotron_h and kda_moe families); mla_moe keeps ONE plane of
+  1 x 576, a token's normed latent and its rotated shared key. What is
+  said of "the pools" below is a tuple in this order.
 - `groups`: the blocks that keep pages, in PAGE GROUPS, a `PageGroup(name,
   layers, window)` each: blocks that share a table, an allocator and a
   reservation. A page id spans its group's blocks. `window` None, a
@@ -24,7 +24,7 @@ it:
   family's `planes`; the engine keeps one pool a plane a group,
   [group layers, pages, heads, width, page_size], group by group.
   `one_group(n)` is what a family whose blocks all keep every token
-  answers (llama_like, nemotron_h, mla_moe); afmoe answers `full` and
+  answers (llama_like, nemotron_h, mla_moe, kda_moe); afmoe answers `full` and
   `window`. `kv_layers` (the blocks that keep pages, all groups) and
   `token_values` follow from the groups.
 - `state_shapes(slots)`: ((shape, dtype), ...) of the arrays a sequence
@@ -64,9 +64,11 @@ it:
 `models/llama.py` (pages only, no state, no counters, refuses nothing),
 `models/nemotron_h.py` (pages for 6 blocks in 52, a recurrent state and a
 convolution tail a slot, expert counters), `models/mla_moe.py` (one
-latent plane a page, expert counters) and `models/afmoe.py` (window and
-full attention blocks, a page group each, expert counters) are the four
-families.
+latent plane a page, expert counters), `models/afmoe.py` (window and
+full attention blocks, a page group each, expert counters) and
+`models/kda_moe.py` (pages for one block in four, a matrix state of the
+delta rule and a convolution tail a slot, expert counters and `kda_rows`)
+are the five families.
 """
 
 from __future__ import annotations

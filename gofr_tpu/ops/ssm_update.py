@@ -43,6 +43,17 @@ from .scopes import kernel_scope
 LANE_TILES = 2      # a block is [N, heads * P / 2] float32: 1 MiB at 128 x 2048
 
 
+def live_rows_first(live):
+    """live [S] bool -> (order [S] int32, n_live int32): the live rows
+    first; every later step stays on the last live row, whose blocks the
+    pipeline then leaves alone (ops/kda_update.py skips dead rows the same
+    way)."""
+    S = live.shape[0]
+    order = jnp.argsort(jnp.logical_not(live), stable=True).astype(jnp.int32)
+    n_live = jnp.sum(live).astype(jnp.int32)
+    return order[jnp.minimum(jnp.arange(S), jnp.maximum(n_live - 1, 0))], n_live
+
+
 def ssm_update_reference(state, layer, decay, xdt, B, C, live):
     """state [L, S, N, HP] float32; layer int; decay, xdt [S, HP] float32
     (exp(dt A) and dt x, a value a (head, p)); B, C [S, G, N] float32; live
@@ -95,10 +106,7 @@ def ssm_update(state, layer, decay, xdt, B, C, live, *, interpret=None):
     groups, width = G // tiles, HP // tiles
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    # live rows first; every later step stays on the last live row's blocks
-    order = jnp.argsort(jnp.logical_not(live), stable=True).astype(jnp.int32)
-    n_live = jnp.sum(live).astype(jnp.int32)
-    order = order[jnp.minimum(jnp.arange(S), jnp.maximum(n_live - 1, 0))]
+    order, n_live = live_rows_first(live)
     # the two row operands share one 8-sublane tile; B and C arrive as
     # columns over n, a lane tile's groups side by side
     rows = jnp.zeros((S, 8, HP), jnp.float32)

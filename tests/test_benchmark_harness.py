@@ -74,6 +74,10 @@ LEFT = {
         "test_a_phi_in_bfloat16_is_not_as_stated",
         "test_a_fault_in_the_program_is_not_correct"),
         "whole runs at --tiny size"),
+    "test_kda_moe": dict.fromkeys((
+        "test_the_new_cell_is_correct_at_tiny_size",
+        "test_a_fault_in_the_program_is_not_correct"),
+        "whole runs at --tiny size"),
 }
 
 
@@ -143,7 +147,8 @@ EVERY = ["internlm2-1.8b.decode-closed", "internlm2-1.8b.chat-open",
          "nemotron-3-nano-30b-a3b-ep2.decode-closed",
          "joyai-llm-flash-ep8.longprompt-closed",
          "trinity-large-preview-ep8.mixedlen-closed",
-         "xing4.0-29b-a4b-ep8.decode-closed"]
+         "xing4.0-29b-a4b-ep8.decode-closed",
+         "solar-open2-250b-ep8.decode256-closed"]
 OPEN = ["internlm2-1.8b.chat-open"]
 # metric -> (its cells, what it moves, the loop it reads, its layer)
 QUEUE_METRICS = {

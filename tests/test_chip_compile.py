@@ -927,3 +927,97 @@ def test_afmoe_prefill_compiles_windowed_and_in_pieces(topo, as_tpu):
     # the window's temporaries, never a copy of a pool (1.1 and 1.06 GiB)
     # nor the grouped experts' rows for every (token, pick) pair of 12k
     assert mem.temp_size_in_bytes < 2 * GIB
+
+
+# -- the kda_moe family's step programs (ISSUE 41) ------------------------------
+def _kda_moe_programs(topo, slots=256, n_pages=2561):
+    """(config, chips, engine shell, abstract params, pools, state, loop
+    state, rng) of the cell `solar-open2-250b-ep8.decode256-closed`: the
+    whole cut, one period of four blocks at the published widths."""
+    from gofr_tpu.models.kda_moe import (FLOAT32_LEAVES, KdaMoeConfig,
+                                         layer_shapes)
+    from gofr_tpu.tpu.paging import PagedLLMEngine
+
+    cfg = dataclasses.replace(KdaMoeConfig.solar_open2_250b_ep8(),
+                              attn_impl="flash")
+    chips = Chips(topo, 1)
+    engine = _engine_shell(PagedLLMEngine, cfg, None)
+    params = {
+        "tok_emb": chips.shape((cfg.vocab_size, cfg.dim), jnp.bfloat16),
+        "final_norm": chips.shape((cfg.dim,), jnp.bfloat16),
+        "lm_head": chips.shape((cfg.dim, cfg.vocab_size), jnp.bfloat16),
+        "layers": [{name: chips.shape(shape, jnp.float32 if name in FLOAT32_LEAVES
+                                      else jnp.bfloat16)
+                    for name, shape in layer_shapes(
+                        cfg, i in cfg.gqa_layers).items()}
+                   for i in range(cfg.n_layers)]}
+    pools = _pools(chips, cfg.n_kv_heads, cfg.head_dim, jnp.bfloat16,
+                   n_pages, cfg.kv_layers)
+    state = tuple(chips.shape(shape, dtype)
+                  for shape, dtype in engine.model.state_shapes(slots))
+    return (cfg, chips, engine, params, pools, state,
+            _loop_state(chips, slots), chips.shape((2,), jnp.uint32))
+
+
+def test_kda_moe_decode_step_compiles_with_its_state_in_place(topo, as_tpu):
+    """The cell's decode program shape (256 slots, 2,561 pages, table 16
+    wide) over one GQA and three KDA blocks at the published widths: the
+    per-slot state (a 4 MiB matrix state a slot a KDA block, 3.3 GB in all)
+    and the pools are aliased, no state-sized or expert-sized copy is made,
+    the module is named `jit_decode...` and the new kernel's instruction
+    after its scope, in the scan's body."""
+    from gofr_tpu.tpu.executor import _named_after
+
+    cfg, chips, engine, params, pools, state, loop, rng = \
+        _kda_moe_programs(topo)
+    compiled = _compile(
+        _named_after(engine._decode_fn_paged(16, 16),
+                     "kda-moe-paged-decode-x16-NP16"),
+        params, *pools, chips.shape((256, 16), jnp.int32), *loop, rng, *state,
+        donate=(1, 2, 8, 9))
+    assert "HloModule jit_decode__x16_NP16," in compiled.as_text()
+    calls = _kernel_calls(compiled)
+    names = sorted(name.rsplit(".", 1)[0] for name, _, _ in calls)
+    assert names == ["kda_update"] * 3 + ["moe_experts"] * 4 + [
+        "paged_read", "paged_write"]
+    bodies = _while_bodies(compiled)
+    assert all(_computation_of(compiled, name) in bodies
+               for name, _, _ in calls if name.startswith("kda_update"))
+    held = sum(np.prod(a.shape) * a.dtype.itemsize for a in pools + state)
+    assert held > 4.5e9
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= held
+    expert = cfg.held * cfg.dim * cfg.expert_dim * 2
+    assert mem.temp_size_in_bytes < expert // 2, (
+        f"{mem.temp_size_in_bytes / GIB:.2f} GiB of temporaries: a copy of "
+        f"the state or of a block's experts")
+
+
+def test_kda_moe_prefill_compiles_with_its_state_in_place(topo, as_tpu):
+    """The cell's widest admission (16 x 128): the slots' state rows (16 x
+    12.6 MB) are written into the donated state, the KDA blocks run the
+    chunkwise form (no kernel of their own, no scan over tokens), the
+    experts as a grouped product (one kernel a block), the GQA block as the
+    flash kernel; the temporaries fit beside 11.3 GB."""
+    from gofr_tpu.tpu.executor import _named_after
+
+    cfg, chips, engine, params, pools, state, loop, rng = \
+        _kda_moe_programs(topo)
+    K, bucket = 16, 128
+    rows = chips.shape((K,), jnp.int32)
+    compiled = _compile(
+        _named_after(engine._prefill_fn(bucket, K),
+                     "kda-moe-paged-prefill-128x16"),
+        params, *pools, chips.shape((K, bucket), jnp.int32),
+        chips.shape((K, bucket // PAGE), jnp.int32), rows, rows, *loop,
+        chips.shape((K,), jnp.float32), rng, *state,
+        donate=(1, 2, 7, 8, 9, 12, 13))
+    assert "HloModule jit_prefill__128x16," in compiled.as_text()
+    names = sorted(name.rsplit(".", 1)[0]
+                   for name, _, _ in _kernel_calls(compiled))
+    assert names.count("moe_experts") == 4 and "flash_prefill" in names
+    assert "kda_update" not in names
+    held = sum(np.prod(a.shape) * a.dtype.itemsize for a in pools + state)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= held
+    assert mem.temp_size_in_bytes < 3 * GIB
