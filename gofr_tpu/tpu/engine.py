@@ -21,14 +21,19 @@ The TPU-first shape of the problem (SURVEY.md §5 long-context + §7.5):
     dispatch, sampling on device each step and returning a [B, M] token
     block; ALL loop state (current tokens, positions, temperatures, rng,
     the pools) stays on device between dispatches
-  - up to `pipeline_depth` dispatches are kept in flight; the host syncs the
-    oldest block while the device executes the younger ones, so the
-    host↔device round-trip and the Python demux loop are overlapped with
-    device compute. The depth is a depth of DECODE work: a prefill in
-    flight is not a decode block (`_room_for_decode`), so admissions
-    passing through the deque never leave the device without a block
-    queued behind the one it runs, and a prefill entry is read in the
-    loop turn that brings it to the deque's head (`_loop`)
+  - dispatches are kept in flight; the host syncs the oldest block while
+    the device executes the younger ones, so the host↔device round-trip
+    and the Python demux loop are overlapped with device compute. How
+    many: as many decode blocks as the host's own turn needs to stay
+    ahead of the device, worked out every turn from the turn's length
+    against a block's time on the device (`tpu/queuedepth.py`), and never
+    more than `pipeline_depth`, where it also stands whenever the loop
+    cannot know better. A block queued beyond that is latency for the
+    next prompt and nothing else. The depth is a depth of DECODE work: a
+    prefill in flight is not a decode block (`_room_for_decode`), so
+    admissions passing through the deque never leave the device without
+    a block queued behind the one it runs, and a prefill entry is read
+    in the loop turn that brings it to the deque's head (`_loop`)
   - requests stream tokens out through per-request queues; new requests are
     admitted into free slots between dispatches (continuous batching)
 
@@ -58,6 +63,7 @@ from .executor import Executor, next_bucket
 from .obs import MetricsHook
 from .ownership import loop_only
 from . import qos
+from .queuedepth import QueueDepth
 from .sampling import pack_controls, sample_tokens, temperature_of
 from .stepledger import StepLedger
 from .utilization import UtilizationLedger
@@ -851,6 +857,11 @@ class LLMEngine:
         # /debug/engine reads)
         self.decode_syncs_total = 0
         self.dry_syncs_total = 0
+        # how many decode entries the loop keeps queued, of the
+        # `pipeline_depth` it may: worked out a turn from the loop's own
+        # turn against an entry's time on the device (tpu/queuedepth.py)
+        self.queue = QueueDepth(self.pipeline_depth,
+                                mirrored=admission_plane is not None)
         # row-steps the decode blocks and verifies read so far computed
         # (rows of the snapshot x steps), and those of them computed for a
         # row after its request's last token (`_overrun_steps`)
@@ -1557,8 +1568,9 @@ class LLMEngine:
                                 e[0] in ("verify", "decode")
                                 for e in self._inflight):
                             self._dispatch_verify()
-                    else:
-                        while any_active and self._room_for_decode():
+                    elif any_active:
+                        self.queue.turn()
+                        while self._room_for_decode():
                             self._dispatch_decode()
                             if self._spec_cooloff > 0:
                                 self._spec_cooloff -= 1
@@ -1599,6 +1611,7 @@ class LLMEngine:
                     self._finish_step()
                 if not synced and not self._chunk_jobs \
                         and not self._inflight:
+                    self.queue.note_park()
                     with steps.between("park"):
                         self._wake.wait(timeout=0.05)
                     self._wake.clear()
@@ -1641,6 +1654,7 @@ class LLMEngine:
             active_slots=sum(1 for s in self.slots if s.active),
             inflight=inflight,
             inflight_prefill=inflight - self._decode_inflight(),
+            depth_now=self.queue.depth_now,
             queue_depth=self.queue_depth(),
             closing=self._step_closed)
         self._meter_rows = None     # a dropped iteration's rows go with it
@@ -1649,6 +1663,7 @@ class LLMEngine:
     def _step_closed(self, rec) -> None:
         """`step_end`'s hooks on the record it just published, inside its
         `loop/step_close` span."""
+        self.queue.note_record(rec)
         staged = self._meter_rows
         if self.meter is not None and staged is not None:
             # attribution happens HERE, not at the sync site: the step
@@ -2064,29 +2079,44 @@ class LLMEngine:
         self.overrun_steps_total += steps
 
     def _room_for_decode(self) -> bool:
-        """Whether the loop's top-up dispatches one more decode block.
-        `pipeline_depth` caps the deque's entries as ever, but a prefill
-        in flight is not a decode block: under that cap alone every
-        admission took a block's place, and a closed loop that admits two
-        or three requests a turn and reads one entry a turn ended with a
-        deque of prefill entries and an idle device (PERF.md, PR 30). So
-        whatever the deque holds of prefills, a turn leaves one decode
-        block queued BEHIND the one the device may be running (a depth-1
-        engine stays synchronous: one). The block is `_decode_block_now`'s:
-        the half block while a prompt waits, which bounds what this costs
-        it. Reads the deque's entry kinds only: mirrored state, so every
-        rank of an admission plane dispatches the same programs."""
-        return (len(self._inflight) < self.pipeline_depth
-                or self._decode_inflight() < min(2, self.pipeline_depth))
+        """Whether the loop's top-up dispatches one more decode block:
+        while the deque holds fewer decode entries than this turn's depth
+        (`self.queue.depth_now`, worked out once a turn in `_loop` from
+        the host's turn against an entry's time on the device:
+        tpu/queuedepth.py). `pipeline_depth` is the cap of that depth and,
+        as ever, of the deque's entries of both kinds, but for one thing:
+        a prefill in flight is not a decode block. Under the entries' cap
+        alone every admission took a block's place, and a closed loop
+        that admits two or three requests a turn and reads one entry a
+        turn ended with a deque of prefill entries and an idle device
+        (PERF.md, PR 30); so whatever the deque holds of prefills, a turn
+        leaves one decode block queued BEHIND the one the device may be
+        running (a depth-1 engine stays synchronous: one).
+
+        At the cap the first clause follows from the second, which is the
+        rule as it was: so it stays, to the letter, under an admission
+        plane, where the depth IS the cap because only mirrored state
+        (the deque's entry kinds) may choose a program and a rank's
+        clock is not; before the loop has both estimates; and after the
+        queue ran dry. Below the cap the first clause decides: a prompt
+        admitted this turn is enqueued behind one decode block the
+        device has just started, not behind three it has not."""
+        decode = self._decode_inflight()
+        return (decode < self.queue.depth_now
+                and (len(self._inflight) < self.pipeline_depth
+                     or decode < min(2, self.pipeline_depth)))
 
     def _decode_block_now(self) -> int:
-        """Adaptive block: full blocks for pure decode throughput, half
-        blocks while requests are waiting to be admitted — sync points come
-        twice as often, so admission (and TTFT) isn't gated behind a full
-        block of in-flight decode (measured on v5e: block 4 vs 8 is
-        -34% decode throughput but -66% p50 TTFT under Poisson load; the
-        adaptive switch pays the short-block cost only under queue
-        pressure)."""
+        """The block a decode entry dispatched now runs: `decode_block_size`
+        steps, or half of it while a request waits to be admitted (parked
+        on the admission heap or still in the submit queue), so that the
+        read it waits behind comes sooner. Chosen at the dispatch, by what
+        waits THEN: `_admit` has usually just drained the queue, so in an
+        open loop most blocks are full ones and a prompt's pickup waits
+        out the block that was running when it arrived (PERF.md,
+        `pickup_wait_p95_ms`); a closed loop whose clients always wait
+        runs half blocks throughout, and pays a flush a block for it.
+        Warm-up compiles these two blocks and no other."""
         # multi-controller: _pending is leader-local (a submit racing in
         # after this iteration's wave is invisible to followers), so only
         # the mirrored heap may influence the block size — a rank-local
@@ -2141,6 +2171,9 @@ class LLMEngine:
     def _sync_oldest(self) -> None:
         import numpy as np
 
+        # a read's wait starts here: the sync-site fault's delay is the
+        # device's (or the transport's) lateness, as the ledger has it
+        sync_t0 = time.monotonic()
         with self.steps.seg("device_sync"):
             if self.faults is not None:
                 # sync-site chaos: latency (delay rules) or a simulated PJRT
@@ -2149,7 +2182,6 @@ class LLMEngine:
         entry = self._inflight.popleft()
         if entry[0] == "prefill":
             _, first, admitted, dspan, dispatched_at = entry
-            sync_t0 = time.monotonic()
             try:
                 with self.steps.seg("device_sync"):
                     first_host = np.asarray(first)  # blocks until the device got there
@@ -2223,7 +2255,6 @@ class LLMEngine:
         if entry[0] == "verify":
             _, fut, snapshot, d, started, dspan = entry
             out_dev, n_emit_dev = fut
-            sync_t0 = time.monotonic()
             try:
                 with self.steps.seg("device_sync"):
                     out_host = np.asarray(out_dev)         # [B, d+1]
@@ -2237,6 +2268,7 @@ class LLMEngine:
                 dspan.end()
             synced = time.monotonic()
             elapsed = synced - started
+            self.queue.note_break()
             # a verify scores d+1 positions per row; slot lengths are read
             # BEFORE the demux advances them, i.e. the dispatched context
             live = [(i, r) for i, r, _ in snapshot
@@ -2325,7 +2357,6 @@ class LLMEngine:
         # what the device has to go on with while this block's demux and
         # emit run on the host
         queued_behind = self._decode_inflight()
-        sync_t0 = time.monotonic()
         try:
             with self.steps.seg("device_sync"):
                 tokens_host = np.asarray(out_tokens)  # [B, block]; device sync point
@@ -2352,6 +2383,7 @@ class LLMEngine:
             kv_tokens=sum(self.slots[i].length for i, r in live),
             dispatched_at=started, synced_at=synced,
             sync_wait_s=synced - sync_t0)
+        self.queue.note_read(synced, synced - sync_t0, queued_behind)
         # pre-demux deepest context: the lock-step batch's cost driver
         slowest = max(live, key=lambda e: self.slots[e[0]].length,
                       default=(None, None))[1]
@@ -2401,6 +2433,8 @@ class LLMEngine:
         dry = queued_behind == 0 and any(s.active for s in self.slots)
         self.decode_syncs_total += 1
         self.dry_syncs_total += dry
+        if dry:
+            self.queue.ran_dry()
         self.row_steps_total += len(snapshot) * block
         self._obs.gauge("app_tpu_decode_blocks_queued", queued_behind)
         # every token in this sync shares one measured step time: record the
@@ -2657,6 +2691,7 @@ class LLMEngine:
                     dspan.set_status(False, str(exc))
                     dspan.end()
             self._inflight.clear()
+            self.queue.reset()
             survivors: List[GenerationRequest] = []
             while self._chunk_jobs:  # mid-prefill KV rows died with the
                 job = self._chunk_jobs.popleft()  # cache; nothing emitted

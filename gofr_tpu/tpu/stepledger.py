@@ -144,7 +144,7 @@ class StepRecord:
 
     __slots__ = ("seq", "started_at", "wall_s", "idle_gap_s", "phase",
                  "segments", "segments_cpu", "cpu_s", "active_slots",
-                 "inflight", "inflight_prefill", "queue_depth",
+                 "inflight", "inflight_prefill", "depth_now", "queue_depth",
                  "tokens", "page_writes", "window_pages", "dry_sync",
                  "dispatches",
                  "slowest_request_id",
@@ -172,6 +172,9 @@ class StepRecord:
         # which is what the device has to run while the loop is elsewhere
         self.inflight = 0
         self.inflight_prefill = 0
+        # the decode entries the loop was keeping queued this turn, of
+        # the `pipeline_depth` it may (tpu/queuedepth.py); 0 = not told
+        self.depth_now = 0
         self.queue_depth = 0
         self.tokens = 0
         # pages the synced decode block's flush wrote (the paged engine's
@@ -211,6 +214,7 @@ class StepRecord:
             "inflight": self.inflight,
             "inflight_decode": self.inflight - self.inflight_prefill,
             "inflight_prefill": self.inflight_prefill,
+            "depth_now": self.depth_now,
             "queue_depth": self.queue_depth,
             "tokens": self.tokens,
         }
@@ -532,7 +536,8 @@ class StepLedger:
     @loop_only
     def step_end(self, active_slots: int = 0, inflight: int = 0,
                  queue_depth: int = 0, closing=None, *,
-                 inflight_prefill: int = 0) -> Optional[StepRecord]:
+                 inflight_prefill: int = 0,
+                 depth_now: int = 0) -> Optional[StepRecord]:
         """Close the step. Pure-bookkeeping iterations (no dispatch, no
         sync, no tokens) are dropped — their time accumulates into the
         next real step's idle_gap, so an idle engine never floods the
@@ -581,6 +586,7 @@ class StepLedger:
         rec.active_slots = int(active_slots)
         rec.inflight = int(inflight)
         rec.inflight_prefill = int(inflight_prefill)
+        rec.depth_now = int(depth_now)
         rec.queue_depth = int(queue_depth)
         rec.tokens = self._tokens
         rec.page_writes = self._page_writes

@@ -478,7 +478,9 @@ class MemorySampler:
 def _pipeline_counts(engine) -> Dict[str, Any]:
     """What the deque holds by kind, and how often a decode block was
     read with slots still decoding and no decode block queued behind it
-    (a dry sync: the device idles through the loop's demux and emit)."""
+    (a dry sync: the device idles through the loop's demux and emit);
+    `queue`: how many decode entries the loop keeps queued, of the
+    `pipeline_depth` it may, and from what (tpu/queuedepth.py)."""
     kinds = [entry[0] for entry in list(engine._inflight)]
     prefills = kinds.count("prefill")
     syncs, dry = engine.decode_syncs_total, engine.dry_syncs_total
@@ -490,6 +492,7 @@ def _pipeline_counts(engine) -> Dict[str, Any]:
         "decode_syncs_total": syncs,
         "dry_syncs_total": dry,
         "dry_sync_share": round(dry / syncs, 4) if syncs else 0.0,
+        "queue": engine.queue.snapshot(),
         # row-steps the decode blocks and verifies computed, and the
         # share of them computed for a row after its request's last token
         "row_steps_total": rows,

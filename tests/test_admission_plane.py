@@ -324,3 +324,53 @@ def test_leader_and_follower_dispatch_the_same_sequence():
     for engine in (leader, follower):
         assert engine.dry_syncs_total == 0 < engine.decode_syncs_total
     assert follower.decode_syncs_total >= leader.decode_syncs_total - 4
+
+
+def test_under_a_plane_the_deque_is_the_caps():
+    """The depth the loop works out from its own clock (ISSUE 42) is a
+    rank's own: under a plane it never leaves the cap, so every answer of
+    `_room_for_decode` is the mirrored rule's as it stood (the deque's
+    entries under `pipeline_depth`, or under two decode blocks), turn
+    for turn, on both ranks, even with reads so slow that a single
+    controller would queue two blocks for four
+    (tests/test_queue_depth.py: the same delay takes it to two)."""
+    from gofr_tpu.tpu.faults import FaultPlane
+
+    leader, follower, shadows = _pair(InProcKV(), pipeline_depth=4)
+    logs = {"leader": [], "follower": []}
+    answers = []
+    for name, engine in (("leader", leader), ("follower", follower)):
+        engine.faults = FaultPlane(plan=[
+            {"site": "engine.sync", "action": "delay", "delay_s": 0.02,
+             "times": 0}])
+        _log_dispatches(engine, logs[name])
+
+        def room_logged(engine=engine, room=engine._room_for_decode):
+            decode = engine._decode_inflight()
+            as_it_was = (len(engine._inflight) < 4 or decode < 2)
+            answers.append((room(), as_it_was))
+            return answers[-1][0]
+
+        engine._room_for_decode = room_logged
+    requests = [leader.submit(PROMPTS[0], max_new_tokens=80,
+                              temperature=0.0)]
+    follower.start()
+    leader.start()
+    try:
+        while requests[0].generated < 40:
+            time.sleep(0.01)
+        requests += [leader.submit(p, max_new_tokens=9, temperature=0.0)
+                     for p in PROMPTS[1:3]]
+        for request in requests:
+            request.result(timeout_s=120)
+        _wait_shadows(shadows, 3)
+    finally:
+        leader.stop()
+        follower.stop()
+    assert len(answers) > 40 and all(got == was for got, was in answers)
+    for engine in (leader, follower):
+        shown = engine.queue.snapshot()
+        assert shown["depth_now"] == 4 and shown["shallow_share"] == 0.0
+        assert shown["turns_by_depth"][4] > 10
+    m = len(logs["follower"])
+    assert m > 20 and logs["follower"] == logs["leader"][:m]
