@@ -4,13 +4,10 @@ POST /generate {"prompt": "...", "max_tokens": 64, "temperature": 0.7,
 "stream": true} -> server-sent events, one JSON per token chunk, then a final
 {"done": true} summary. stream=false returns one JSON response.
 
-Model size comes from MODEL_PRESET (debug | llama1b | llama3-8b, the
-nemotron_h family's nemotron-h-debug | nemotron-3-nano-30b-a3b-ep2, the
-mla_moe family's mla-moe-debug | joyai-llm-flash-ep8 | mla-moe-hc-debug |
-xing4.0-29b-a4b-ep8, the afmoe family's afmoe-debug |
-trinity-large-preview-ep8, and the kda_moe family's kda-moe-debug |
-solar-open2-250b-ep8). Weights
-boot from a real HF-layout safetensors checkpoint when WEIGHTS_PATH is set
+The model comes from MODEL_PRESET: any preset of any family
+gofr_tpu/models/families.py lists (docs/model-families.md says what each
+is). Weights boot from a real HF-layout safetensors checkpoint when
+WEIGHTS_PATH is set, for a family that has a loader
 (models.weights.load_llama_safetensors — streaming, int8 quantize-on-load);
 otherwise random-initialised (no checkpoints ship in this environment) with
 identical serving/throughput/latency behavior.
@@ -25,60 +22,15 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", ".."))
 
 from gofr_tpu import App, Stream  # noqa: E402
 from gofr_tpu.http.errors import InvalidParam, ServiceUnavailable  # noqa: E402
-from gofr_tpu.models.afmoe import AfmoeConfig, afmoe_init  # noqa: E402
-from gofr_tpu.models.kda_moe import KdaMoeConfig, kda_moe_init  # noqa: E402
-from gofr_tpu.models.llama import LlamaConfig, llama_init  # noqa: E402
-from gofr_tpu.models.mla_moe import MlaMoeConfig, mla_moe_init  # noqa: E402
-from gofr_tpu.models.nemotron_h import (NemotronHConfig,  # noqa: E402
-                                        nemotron_h_init)
+from gofr_tpu.models import families  # noqa: E402
 from gofr_tpu.models.tokenizer import (ByteTokenizer, DebugTokenizer,  # noqa: E402
                                        StreamingDecoder)
 from gofr_tpu.tpu.device import TPUClient  # noqa: E402
 from gofr_tpu.tpu.paging import PagedLLMEngine  # noqa: E402
 from gofr_tpu.tpu.executor import Executor, enable_compile_cache  # noqa: E402
 
-PRESETS = {
-    "debug": LlamaConfig.debug,
-    "llama1b": LlamaConfig.llama1b,
-    "llama3-8b": LlamaConfig.llama3_8b,
-    "llama3-70b": LlamaConfig.llama3_70b,  # TP_SHARDS=8 territory (config 5)
-    # the nemotron_h family (docs/model-families.md): Mamba-2, sparse-expert
-    # and attention blocks in one stack, served by the paged engine only
-    "nemotron-h-debug": NemotronHConfig.debug,
-    # one chip's share of NVIDIA-Nemotron-3-Nano-30B-A3B, as the benchmark
-    # runs it (benchmark/configs/nemotron-3-nano-30b-a3b-ep2.json)
-    "nemotron-3-nano-30b-a3b-ep2": NemotronHConfig.nano_30b_a3b_ep2,
-    # the mla_moe family: latent attention (one narrow plane a token in the
-    # page pool) and gated sparse experts
-    "mla-moe-debug": MlaMoeConfig.debug,
-    # one chip's share of JoyAI-LLM-Flash, as the benchmark runs it
-    # (benchmark/configs/joyai-llm-flash-ep8.json)
-    "joyai-llm-flash-ep8": MlaMoeConfig.joyai_llm_flash_ep8,
-    # the same family with a residual stream of four mixed copies (mHC) and
-    # YaRN; one chip's share of Xing4.0-29B-A4B, as the benchmark runs it
-    # (benchmark/configs/xing4.0-29b-a4b-ep8.json)
-    "mla-moe-hc-debug": MlaMoeConfig.debug_hc,
-    "xing4.0-29b-a4b-ep8": MlaMoeConfig.xing4_0_29b_a4b_ep8,
-    # the afmoe family: window and full attention blocks in one stack, a
-    # page group each in the pool (the window blocks' a ring), gated
-    # attention with normed queries and keys, gated sparse experts
-    "afmoe-debug": AfmoeConfig.debug,
-    # one chip's share of Trinity-Large-Preview, as the benchmark runs it
-    # (benchmark/configs/trinity-large-preview-ep8.json)
-    "trinity-large-preview-ep8": AfmoeConfig.trinity_large_preview_ep8,
-    # the kda_moe family: three Kimi Delta Attention (gated delta rule)
-    # blocks to every gated NoPE GQA block, a matrix state a slot beside
-    # the pool, gated sparse experts in every block
-    "kda-moe-debug": KdaMoeConfig.debug,
-    # one chip's share of Solar-Open2-250B, as the benchmark runs it
-    # (benchmark/configs/solar-open2-250b-ep8.json)
-    "solar-open2-250b-ep8": KdaMoeConfig.solar_open2_250b_ep8,
-}
-
-# the families that boot from seeded weights only: no checkpoint loader and
-# no int8 weight path yet
-SEEDED_ONLY = {NemotronHConfig: nemotron_h_init, MlaMoeConfig: mla_moe_init,
-               AfmoeConfig: afmoe_init, KdaMoeConfig: kda_moe_init}
+# {the name MODEL_PRESET takes: a constructor of the family's config}
+PRESETS = families.presets()
 
 
 def _load_tokenizer(path: str):
@@ -205,28 +157,24 @@ def build_engine(app: App,
     # preset before any bytes load; WEIGHT_DTYPE=int8 quantizes each leaf
     # on device as it streams in, so the float tree never materializes
     weights_path = app.config.get_or_default("WEIGHTS_PATH", "")
-    if type(cfg) in SEEDED_ONLY:
-        if weights_path or weight_dtype:
-            raise ValueError(f"the {cfg.paged_model().family} family has no "
-                             f"checkpoint loader and no int8 weight path "
-                             f"yet: unset WEIGHTS_PATH and WEIGHT_DTYPE")
-        params = SEEDED_ONLY[type(cfg)](cfg, seed=0)
-    elif weights_path:
-        from gofr_tpu.models.weights import load_llama_safetensors
-
+    family = families.family_of(cfg)
+    seeded_only = not hasattr(family, "load_checkpoint")
+    if seeded_only and (weights_path or weight_dtype):
+        raise ValueError(f"the {cfg.paged_model().family} family has no "
+                         f"checkpoint loader and no int8 weight path "
+                         f"yet: unset WEIGHTS_PATH and WEIGHT_DTYPE")
+    if weights_path:
         t_load = time.time()
-        params = load_llama_safetensors(cfg, weights_path,
+        params = family.load_checkpoint(cfg, weights_path,
                                         weight_dtype=weight_dtype,
                                         logger=app.logger)
         app.logger.infof("loaded weights from %s in %.1fs (%s)",
                          weights_path, time.time() - t_load,
                          weight_dtype or cfg.dtype)
     elif weight_dtype == "int8":
-        from gofr_tpu.models.llama import llama_init_quantized
-
-        params = llama_init_quantized(cfg, seed=0)
+        params = family.init_quantized(cfg, seed=0)
     else:
-        params = llama_init(cfg, seed=0)
+        params = family.init(cfg, seed=0)
     # TP_SHARDS>1 serves tensor-parallel over the chip slice (BASELINE
     # config 5: Llama-70B TP=8 on v5e-8) — same engine, sharded mesh
     tp = app.config.get_int("TP_SHARDS", 1)

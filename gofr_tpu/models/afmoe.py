@@ -17,12 +17,10 @@ RMSNorm with its own weight and `h = E[token] * sqrt(D)`:
         shared(m) + sum over the k picks of w_e expert_e(m)
     h = h + RMS_post_mlp(y)
 
-and `logits = W_head RMS_final(h)`. The experts are models/mla_moe.py's
-(sigmoid scores in float32, the k largest of score + bias picked, weighted
-by their scores normalised and scaled; gated SwiGLU experts through
-ops/moe_experts.py, whose blocks tile an expert's width where three
-matrices of 3072 x 3072 do not fit VMEM twice over): `ffn_prefill` and
-`ffn_decode` are imported from there, and so are the counters.
+and `logits = W_head RMS_final(h)`. The FFN, dense or routed, is
+models/experts.py's in its gated form (ops/moe_experts.py's blocks tile an
+expert's width where three matrices of 3072 x 3072 do not fit VMEM twice
+over), and so are the counters.
 
 What a token keeps is K (after its norm and, on a sliding block, its
 rotation) and V of Hkv heads a block, in TWO PAGE GROUPS
@@ -42,10 +40,8 @@ pages a period of four blocks where one group would keep 4 x 104.
   position - W + 1 a row and its table a ring
   (ops/paged_attention.py `paged_attention_in_block`).
 
-Weights: {"tok_emb" [V, D], "layers": [one dict a block], "final_norm"
-[D], "lm_head" [D, V]}; matrices [in, out] but the routed experts' three
-([held, F, D], as mla_moe's); per-block leaves, the layer loop unrolled
-(blocks differ in kind).
+Weights: the tree models/blocks.py `init_blocks` lays out, `layer_shapes`
+a block.
 """
 
 from __future__ import annotations
@@ -57,9 +53,10 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from .llama import _np_dtype, rms_norm, rope
-from .mla_moe import ffn_decode, ffn_prefill
-from .nemotron_h import COUNTERS, FLOAT32_LEAVES, _head
+from . import experts
+from .blocks import (head, init_blocks, live_and_attended, np_dtype, rms_norm,
+                     rope, seeded_block)
+from .experts import COUNTERS, FLOAT32_LEAVES, ffn_decode, ffn_prefill
 
 __all__ = ["AfmoeConfig", "afmoe_init", "prefill", "decode_step",
            "COUNTERS", "FLOAT32_LEAVES"]
@@ -68,7 +65,7 @@ SLIDING, FULL = "sliding_attention", "full_attention"
 
 # (block_q, block_kv) of the prefill's flash kernel: this family's windows
 # run to 12k tokens, so the kernel's time is its blocks' MXU passes
-# (models/mla_moe.py FLASH_BLOCKS has the measurements at 4,096 tokens)
+# (PERF.md section 6, PR 31, has the measurements at 4,096 tokens)
 FLASH_BLOCKS = (512, 512)
 
 # the most tokens the token-wise half of a prefill block takes at once
@@ -76,7 +73,7 @@ PIECE = 4096
 
 
 @dataclasses.dataclass(frozen=True)
-class AfmoeConfig:
+class AfmoeConfig(experts.HeldExperts):
     vocab_size: int = 200192
     dim: int = 3072
     n_layers: int = 60
@@ -101,10 +98,7 @@ class AfmoeConfig:
     attn_impl: str = "xla"      # "xla" | "flash": the prefill window
 
     def __post_init__(self):
-        lo, hi = self.experts_held
-        if not 0 <= lo < hi <= self.n_experts:
-            raise ValueError(f"experts_held {self.experts_held} is not a "
-                             f"range of the {self.n_experts} experts")
+        self.check_experts_held()
         if not 0 <= self.first_dense <= self.n_layers:
             raise ValueError("first_dense counts leading blocks")
         if len(self.layer_types) != self.n_layers or any(
@@ -124,10 +118,6 @@ class AfmoeConfig:
     @property
     def expert_layers(self) -> int:
         return self.n_layers - self.first_dense
-
-    @property
-    def held(self) -> int:
-        return self.experts_held[1] - self.experts_held[0]
 
     @property
     def ffn_dim(self) -> int:
@@ -165,14 +155,10 @@ class AfmoeConfig:
         of an expert FFN as held and as a token meets it (router, shared
         expert, its k picks)."""
         D, q = self.dim, self.n_heads * self.head_dim
-        per_expert = 3 * D * self.expert_dim
-        outside = D * self.n_experts + 3 * D * self.shared_dim
         return {
             "attention": 3 * D * q + 2 * D * self.n_kv_heads * self.head_dim,
             "dense": 3 * D * self.dense_dim,
-            "experts_held": outside + self.held * per_expert,
-            "experts_met": outside + self.experts_per_token * per_expert
-            * self.held // self.n_experts,
+            **experts.expert_params(self),
         }
 
     def param_count(self) -> int:
@@ -252,17 +238,10 @@ REFUSES = {
 
 
 def describe(cfg: AfmoeConfig, counts: Dict[str, int], steps: int):
-    """`/debug/engine` "model": the experts held, the window, and how the
-    routing of `steps` decode steps fell, under nemotron_h's names."""
-    from .nemotron_h import routing_summary
-
-    out = {"experts_held": cfg.held, "experts_total": cfg.n_experts,
-           "window": cfg.window, "window_layers": cfg.window_layers}
-    routing = routing_summary(counts, steps, cfg.expert_layers, cfg.held,
-                              cfg.experts_per_token)
-    if routing:
-        out["routing"] = routing
-    return out
+    """`/debug/engine` "model": the window, the experts held and how the
+    routing of `steps` decode steps fell."""
+    return {"window": cfg.window, "window_layers": cfg.window_layers,
+            **experts.describe(cfg, counts, steps)}
 
 
 def layer_shapes(cfg: AfmoeConfig, dense: bool) -> Dict[str, tuple]:
@@ -276,50 +255,21 @@ def layer_shapes(cfg: AfmoeConfig, dense: bool) -> Dict[str, tuple]:
     if dense:
         return {**shapes, "w_gate": (D, cfg.dense_dim),
                 "w_up": (D, cfg.dense_dim), "w_down": (cfg.dense_dim, D)}
-    expert = (cfg.held, cfg.expert_dim, D)
-    return {**shapes, "router": (D, cfg.n_experts),
-            "router_bias": (cfg.n_experts,), "w1": expert, "wg": expert,
-            "w2": expert, "shared_gate": (D, cfg.shared_dim),
-            "shared_up": (D, cfg.shared_dim),
-            "shared_down": (cfg.shared_dim, D)}
+    return {**shapes, **experts.expert_shapes(cfg)}
 
 
 def afmoe_init(cfg: AfmoeConfig, seed: int = 0) -> Dict[str, Any]:
     """Random-init params, a jitted call a block."""
-    dt = _np_dtype(cfg.dtype)
+    return init_blocks(
+        cfg, seed, [i < cfg.first_dense for i in range(cfg.n_layers)],
+        lambda key, dense: seeded_block(key, layer_shapes(cfg, dense),
+                                        np_dtype(cfg.dtype)))
 
-    def matrix(key, shape, fan_in):
-        return (jax.random.normal(key, shape, jnp.float32)
-                / math.sqrt(fan_in)).astype(dt)
 
-    def make(key, dense):
-        shapes = layer_shapes(cfg, dense)
-        keys = iter(jax.random.split(key, len(shapes)))
-        out = {}
-        for name, shape in shapes.items():
-            if name.endswith("norm"):
-                out[name] = jnp.ones(shape, dt)
-            elif name == "router_bias":
-                out[name] = jnp.zeros(shape, jnp.float32)
-            else:
-                # the experts' matrices are [held, out, in] (w2: [.., in,
-                # out]): fan-in is D for up and gate, F for down
-                fan_in = (shape[1] if name == "w2" else shape[-1]
-                          if len(shape) == 3 else shape[0])
-                out[name] = matrix(next(keys), shape, fan_in)
-        return out
-
-    make = jax.jit(make, static_argnums=1)
-    key = jax.random.PRNGKey(seed)
-    return {
-        "tok_emb": jax.jit(matrix, static_argnums=(1, 2))(
-            jax.random.fold_in(key, 1), (cfg.vocab_size, cfg.dim), cfg.dim),
-        "layers": [make(jax.random.fold_in(key, 16 + i), i < cfg.first_dense)
-                   for i in range(cfg.n_layers)],
-        "final_norm": jnp.ones((cfg.dim,), dt),
-        "lm_head": jax.jit(matrix, static_argnums=(1, 2))(
-            jax.random.fold_in(key, 2), (cfg.dim, cfg.vocab_size), cfg.dim),
-    }
+# for models/families.py
+PRESETS = {"afmoe-debug": AfmoeConfig.debug,
+           "trinity-large-preview-ep8": AfmoeConfig.trinity_large_preview_ep8}
+init = afmoe_init
 
 
 # -- attention ----------------------------------------------------------------
@@ -437,7 +387,7 @@ def prefill(params, cfg: AfmoeConfig, tokens, lengths):
         x = x + rms_norm(out, w["post_mlp_norm"], cfg.rms_eps)
     last = x[jnp.arange(K), lengths - 1]
     windows = tuple(jnp.stack(plane) for planes in kept for plane in planes)
-    return _head(last, params, cfg), windows
+    return head(last, params, cfg.rms_eps), windows
 
 
 def decode_step(params, cfg: AfmoeConfig, tokens, positions, pools, tables,
@@ -448,11 +398,7 @@ def decode_step(params, cfg: AfmoeConfig, tokens, positions, pools, tables,
     request); tail the block's tails, as the pools lie
     (models/protocol.py). Returns (logits [B, V] float32, tail, counters
     [len(COUNTERS)] int32)."""
-    from ..ops.paged_attention import holds_request
-    from .llama import _attended_in_block
-
-    live = holds_request(tables[0])
-    lengths, tail_lens = _attended_in_block(tables[0], positions, step)
+    live, lengths, tail_lens = live_and_attended(tables[0], positions, step)
     x = _embed(params, tokens, cfg)
     tail = list(tail)
     counted = jnp.zeros((3,), jnp.int32)
@@ -468,6 +414,5 @@ def decode_step(params, cfg: AfmoeConfig, tokens, positions, pools, tables,
                                live, cfg)
         counted = counted + seen
         x = x + rms_norm(out, w["post_mlp_norm"], cfg.rms_eps)
-    counters = jnp.concatenate([jnp.sum(live, dtype=jnp.int32)[None],
-                                counted])
-    return _head(x, params, cfg), tuple(tail), counters
+    counters = experts.step_counters(live, counted)
+    return head(x, params, cfg.rms_eps), tuple(tail), counters

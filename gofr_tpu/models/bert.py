@@ -61,19 +61,14 @@ class BertConfig:
         return embed + 2 * self.dim + self.n_layers * per_layer + pooler
 
 
-def _np_dtype(name: str):
-    import jax.numpy as jnp
-
-    return {"bfloat16": jnp.bfloat16, "float32": jnp.float32,
-            "float16": jnp.float16}[name]
-
-
 def bert_init(cfg: BertConfig, seed: int = 0) -> Dict[str, Any]:
     """Random-init params pytree with stacked [L, ...] layer weights."""
     import jax
     import jax.numpy as jnp
 
-    dtype = _np_dtype(cfg.dtype)
+    from .blocks import np_dtype
+
+    dtype = np_dtype(cfg.dtype)
     key = jax.random.PRNGKey(seed)
     keys = jax.random.split(key, 10)
     L, D, F = cfg.n_layers, cfg.dim, cfg.ffn_dim

@@ -18,10 +18,8 @@ KDA (H heads of d_k = d_v, a convolution of W taps, a channel each):
     out = (sigmoid((x W_ga) W_gb) (.) RMS_head(o)) W_o
 GQA: q, k, v = x W_q, x W_k, x W_v; causal softmax(q k^T / sqrt(dh)), not
     turned, not normed; out = (sigmoid(x W_gate) (.) attn) W_o
-MoE: models/mla_moe.py's (sigmoid scores in float32, the k largest of
-    score + bias picked, weighted by their scores normalised and scaled;
-    SwiGLU experts through ops/moe_experts.py and one shared expert):
-    `ffn_prefill` and `ffn_decode` are imported from there.
+MoE: models/experts.py's in its gated form (SwiGLU experts through
+    ops/moe_experts.py and one shared expert).
 
 On the serving path a sequence holds TWO kinds of cached state: pages of K
 and V for the GQA blocks (one block in four) and, for every KDA block, the
@@ -34,15 +32,13 @@ the weights (ops/kda_update.py says how the state is laid out).
   KDA blocks run the chunkwise form (ops/kda_chunk.py, jax.numpy); a padded
   position gets g = 0 and b = 0, so the state is the state as of each
   row's LAST REAL token, and the tail is taken at lengths - (W - 1) ...
-  lengths - 1 (ops/short_conv.py, models/nemotron_h.py's).
+  lengths - 1 (ops/short_conv.py).
 - `decode_step`: one token a row; the state updated in place by
   ops/kda_update.py, live rows only (`attn_impl: "xla"`: its jax.numpy
   form), the GQA block's read through `paged_attention_in_block`.
 
-Weights: {"tok_emb" [V, D], "layers": [one dict a block], "final_norm"
-[D], "lm_head" [D, V]}; matrices [in, out] but the routed experts' three
-([held, F, D], as mla_moe's); per-block leaves, the layer loop unrolled
-(blocks differ in kind).
+Weights: the tree models/blocks.py `init_blocks` lays out, `layer_shapes`
+a block.
 """
 
 from __future__ import annotations
@@ -55,22 +51,22 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.short_conv import conv_decode, conv_prefill
-from .llama import _np_dtype, rms_norm
-from .mla_moe import ffn_decode, ffn_prefill
-from .nemotron_h import COUNTERS as EXPERT_COUNTERS
-from .nemotron_h import _head, routing_summary
+from . import experts
+from .blocks import (head, init_blocks, live_and_attended, np_dtype, rms_norm,
+                     seeded_block)
+from .experts import ffn_decode, ffn_prefill
 
 # leaves held in float32 whatever `dtype` is: the decay's constants and the
 # router's bias
-FLOAT32_LEAVES = ("A_log", "dt_bias", "router_bias")
+FLOAT32_LEAVES = ("A_log", "dt_bias") + experts.FLOAT32_LEAVES
 
-# what a decode step counts: the expert families' four and the live rows
+# what a decode step counts: the expert layer's four and the live rows
 # whose KDA state was updated, summed over the KDA blocks
-COUNTERS = EXPERT_COUNTERS + ("kda_rows",)
+COUNTERS = experts.COUNTERS + ("kda_rows",)
 
 
 @dataclasses.dataclass(frozen=True)
-class KdaMoeConfig:
+class KdaMoeConfig(experts.HeldExperts):
     vocab_size: int = 196608
     dim: int = 4096
     n_layers: int = 48
@@ -96,10 +92,7 @@ class KdaMoeConfig:
                                 # attention and the decode update's kernel
 
     def __post_init__(self):
-        lo, hi = self.experts_held
-        if not 0 <= lo < hi <= self.n_experts:
-            raise ValueError(f"experts_held {self.experts_held} is not a "
-                             f"range of the {self.n_experts} experts")
+        self.check_experts_held()
         if any(not 0 <= l < self.n_layers for l in self.gqa_layers):
             raise ValueError(f"gqa_layers {self.gqa_layers} names blocks "
                              f"of {self.n_layers}")
@@ -116,10 +109,6 @@ class KdaMoeConfig:
     @property
     def expert_layers(self) -> int:
         return self.n_layers
-
-    @property
-    def held(self) -> int:
-        return self.experts_held[1] - self.experts_held[0]
 
     @property
     def kda_dim(self) -> int:
@@ -176,16 +165,12 @@ class KdaMoeConfig:
         as held and as a token meets it (router, shared expert, its k
         picks)."""
         D, r = self.dim, self.gate_rank
-        per_expert = 3 * D * self.expert_dim
-        outside = D * self.n_experts + 3 * D * self.shared_dim
         q = self.n_heads * self.head_dim
         return {
             "kda": 4 * D * self.kda_dim + 2 * r * (D + self.kda_dim)
             + D * self.kda_heads + self.conv_kernel * self.conv_dim,
             "attention": 3 * D * q + 2 * D * self.n_kv_heads * self.head_dim,
-            "experts_held": outside + self.held * per_expert,
-            "experts_met": outside + self.experts_per_token * per_expert
-            * self.held // self.n_experts,
+            **experts.expert_params(self),
         }
 
     def param_count(self) -> int:
@@ -248,24 +233,15 @@ def describe(cfg: KdaMoeConfig, counts: Dict[str, int], steps: int):
            "kda_state_bytes_per_slot": cfg.kda_state_bytes,
            "conv_tail_bytes_per_slot": cfg.conv_tail_bytes,
            "kda_state_dtype": str(jnp.dtype(state_shapes(cfg, 1)[0][1])),
-           "experts_held": cfg.held, "experts_total": cfg.n_experts}
+           **experts.describe(cfg, counts, steps)}
     if steps:
         out["kda_rows_per_step"] = counts["kda_rows"] / steps
-    routing = routing_summary(counts, steps, cfg.expert_layers, cfg.held,
-                              cfg.experts_per_token)
-    if routing:
-        out["routing"] = routing
     return out
 
 
 def layer_shapes(cfg: KdaMoeConfig, gqa: bool) -> Dict[str, tuple]:
     D, r = cfg.dim, cfg.gate_rank
-    expert = (cfg.held, cfg.expert_dim, D)
-    ffn = {"ffn_norm": (D,), "router": (D, cfg.n_experts),
-           "router_bias": (cfg.n_experts,), "w1": expert, "wg": expert,
-           "w2": expert, "shared_gate": (D, cfg.shared_dim),
-           "shared_up": (D, cfg.shared_dim),
-           "shared_down": (cfg.shared_dim, D)}
+    ffn = {"ffn_norm": (D,), **experts.expert_shapes(cfg)}
     if gqa:
         q, kv = cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim
         return {"mixer_norm": (D,), "wq": (D, q), "wk": (D, kv),
@@ -284,48 +260,27 @@ def kda_moe_init(cfg: KdaMoeConfig, seed: int = 0) -> Dict[str, Any]:
     that a channel's half-life runs from tens to thousands of tokens: A
     uniform in [1, 16] a head, the step log-uniform in [1e-4, 1e-2] a
     channel through the inverse softplus."""
-    dt = _np_dtype(cfg.dtype)
+    def decay_leaf(name, shape, keys):
+        if name == "A_log":
+            return jnp.log(jax.random.uniform(
+                next(keys), shape, jnp.float32, 1.0, 16.0))
+        if name == "dt_bias":
+            step = jnp.exp(jax.random.uniform(
+                next(keys), shape, jnp.float32, math.log(1e-4),
+                math.log(1e-2)))
+            return step + jnp.log(-jnp.expm1(-step))
+        return None
 
-    def matrix(key, shape, fan_in):
-        return (jax.random.normal(key, shape, jnp.float32)
-                / math.sqrt(fan_in)).astype(dt)
+    return init_blocks(
+        cfg, seed, [i in cfg.gqa_layers for i in range(cfg.n_layers)],
+        lambda key, gqa: seeded_block(key, layer_shapes(cfg, gqa),
+                                      np_dtype(cfg.dtype), decay_leaf))
 
-    def make(key, gqa):
-        shapes = layer_shapes(cfg, gqa)
-        keys = iter(jax.random.split(key, len(shapes)))
-        out = {}
-        for name, shape in shapes.items():
-            if name.endswith("norm"):
-                out[name] = jnp.ones(shape, dt)
-            elif name == "router_bias":
-                out[name] = jnp.zeros(shape, jnp.float32)
-            elif name == "A_log":
-                out[name] = jnp.log(jax.random.uniform(
-                    next(keys), shape, jnp.float32, 1.0, 16.0))
-            elif name == "dt_bias":
-                step = jnp.exp(jax.random.uniform(
-                    next(keys), shape, jnp.float32, math.log(1e-4),
-                    math.log(1e-2)))
-                out[name] = step + jnp.log(-jnp.expm1(-step))
-            else:
-                # the experts' matrices are [held, out, in] (w2: [.., in,
-                # out]): fan-in is D for up and gate, F for down
-                fan_in = (shape[1] if name == "w2" else shape[-1]
-                          if len(shape) == 3 else shape[0])
-                out[name] = matrix(next(keys), shape, fan_in)
-        return out
 
-    make = jax.jit(make, static_argnums=1)
-    key = jax.random.PRNGKey(seed)
-    return {
-        "tok_emb": jax.jit(matrix, static_argnums=(1, 2))(
-            jax.random.fold_in(key, 1), (cfg.vocab_size, cfg.dim), cfg.dim),
-        "layers": [make(jax.random.fold_in(key, 16 + i), i in cfg.gqa_layers)
-                   for i in range(cfg.n_layers)],
-        "final_norm": jnp.ones((cfg.dim,), dt),
-        "lm_head": jax.jit(matrix, static_argnums=(1, 2))(
-            jax.random.fold_in(key, 2), (cfg.dim, cfg.vocab_size), cfg.dim),
-    }
+# for models/families.py
+PRESETS = {"kda-moe-debug": KdaMoeConfig.debug,
+           "solar-open2-250b-ep8": KdaMoeConfig.solar_open2_250b_ep8}
+init = kda_moe_init
 
 
 def state_shapes(cfg: KdaMoeConfig, slots: int):
@@ -335,7 +290,7 @@ def state_shapes(cfg: KdaMoeConfig, slots: int):
     return (((cfg.kda_layers, slots, cfg.kda_heads, cfg.kda_head_dim,
               cfg.kda_head_dim), jnp.float32),
             ((cfg.kda_layers, slots, cfg.conv_kernel - 1, cfg.conv_dim),
-             _np_dtype(cfg.dtype)))
+             np_dtype(cfg.dtype)))
 
 
 # -- the KDA mixer ------------------------------------------------------------
@@ -498,7 +453,7 @@ def prefill(params, cfg: KdaMoeConfig, tokens, lengths):
 
     kv = (0, K, cfg.n_kv_heads, cfg.head_dim, T)
     (state_like, _), (tail_like, _) = state_shapes(cfg, K)
-    return (_head(last, params, cfg), stacked(ks, kv), stacked(vs, kv),
+    return (head(last, params, cfg.rms_eps), stacked(ks, kv), stacked(vs, kv),
             (stacked(states, state_like), stacked(tails, tail_like)))
 
 
@@ -510,12 +465,8 @@ def decode_step(params, cfg: KdaMoeConfig, tokens, positions, k_pool, v_pool,
     state = (kda, tail); kv_tail the block's (k_tail, v_tail)
     (models/protocol.py). Returns (logits [B, V] float32, kv_tail, state,
     counters [len(COUNTERS)] int32)."""
-    from ..ops.paged_attention import holds_request
-    from .llama import _attended_in_block
-
     kda, tail = state
-    live = holds_request(table)
-    lengths, tail_lens = _attended_in_block(table, positions, step)
+    live, lengths, tail_lens = live_and_attended(table, positions, step)
     x = params["tok_emb"][tokens]
     counted = jnp.zeros((3,), jnp.int32)
     d = a = 0
@@ -537,4 +488,4 @@ def decode_step(params, cfg: KdaMoeConfig, tokens, positions, k_pool, v_pool,
     rows = jnp.sum(live, dtype=jnp.int32)
     counters = jnp.concatenate([rows[None], counted,
                                 (rows * cfg.kda_layers)[None]])
-    return _head(x, params, cfg), kv_tail, (kda, tail), counters
+    return head(x, params, cfg.rms_eps), kv_tail, (kda, tail), counters

@@ -112,19 +112,12 @@ class LlamaConfig:
         return 2 * embed + self.n_layers * per_layer + self.dim
 
 
-def _np_dtype(name: str):
-    import jax.numpy as jnp
-
-    return {"bfloat16": jnp.bfloat16, "float32": jnp.float32,
-            "float16": jnp.float16, "int8": jnp.int8}[name]
-
-
 def llama_init(cfg: LlamaConfig, seed: int = 0) -> Dict[str, Any]:
     """Random-init params pytree with stacked [L, ...] layer weights."""
     import jax
     import jax.numpy as jnp
 
-    dtype = _np_dtype(cfg.dtype)
+    dtype = np_dtype(cfg.dtype)
     key = jax.random.PRNGKey(seed)
     keys = jax.random.split(key, 8)
     L, D, H, Hkv, dh, F, V = (cfg.n_layers, cfg.dim, cfg.n_heads, cfg.n_kv_heads,
@@ -169,35 +162,14 @@ def init_kv_cache(cfg: LlamaConfig, batch: int, seq_len: Optional[int] = None,
 
     S = seq_len or cfg.max_seq_len
     shape = (cfg.n_layers, batch, cfg.n_kv_heads, cfg.head_dim, S)
-    dt = _np_dtype(dtype or cfg.dtype)
+    dt = np_dtype(dtype or cfg.dtype)
     return jnp.zeros(shape, dtype=dt), jnp.zeros(shape, dtype=dt)
-
-
-def rms_norm(x, weight, eps: float):
-    import jax.numpy as jnp
-
-    x32 = x.astype(jnp.float32)
-    normed = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
-    return (normed * weight.astype(jnp.float32)).astype(x.dtype)
-
-
-def rope(x, positions, theta: float):
-    """Rotate-half RoPE. x: [B, T, H, dh]; positions: [B, T] int32."""
-    import jax.numpy as jnp
-
-    dh = x.shape[-1]
-    half = dh // 2
-    inv_freq = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
-    angles = positions.astype(jnp.float32)[..., None] * inv_freq  # [B, T, half]
-    cos = jnp.cos(angles)[:, :, None, :]  # [B, T, 1, half]
-    sin = jnp.sin(angles)[:, :, None, :]
-    x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
-    rotated = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
-    return rotated.astype(x.dtype)
 
 
 import jax  # noqa: E402  (after dataclass defs so module import stays light)
 import jax.numpy as jnp  # noqa: E402
+
+from .blocks import attended_in_block, np_dtype, rms_norm, rope  # noqa: E402
 
 
 # ---------------------------------------------------------------------------
@@ -258,7 +230,7 @@ def _embed(params, cfg: LlamaConfig, tokens):
     if e.dtype == jnp.int8:
         scale = params["tok_emb_s"][tokens]          # [...,] f32 per row
         return (e.astype(jnp.float32) * scale[..., None]).astype(
-            _np_dtype(cfg.dtype))
+            np_dtype(cfg.dtype))
     return e
 
 
@@ -270,7 +242,7 @@ def _head(x, params):
     return (x @ w).astype(jnp.float32)
 
 
-def _quantize_leaf(w, axis: int):
+def quantize_leaf(w, axis: int):
     """Symmetric per-channel int8: returns (w8, scale) with scale shaped as
     w minus `axis` (the contraction dim)."""
     wf = w.astype(jnp.float32)
@@ -283,7 +255,7 @@ def _quantize_leaf(w, axis: int):
 # weight name -> contraction axis reduced away by its scale. Layer weights
 # are stacked [L, in, out]; tok_emb [V, D] scales per row (gather dim);
 # lm_head [D, V] per output channel. Norm vectors stay float.
-_QUANT_AXES = {"wq": -2, "wk": -2, "wv": -2, "wo": -2,
+QUANT_AXES = {"wq": -2, "wk": -2, "wv": -2, "wo": -2,
                "w_gate": -2, "w_up": -2, "w_down": -2}
 
 
@@ -297,12 +269,12 @@ def quantize_weights(params: Dict[str, Any]) -> Dict[str, Any]:
     crowds the chip, use llama_init_quantized, which never materializes
     the float tree at all.
     """
-    q = jax.jit(_quantize_leaf, static_argnums=1)
+    q = jax.jit(quantize_leaf, static_argnums=1)
 
     out_layers = {}
     layers = params["layers"]
-    for name in list(_QUANT_AXES):
-        w8, s = q(layers.pop(name), _QUANT_AXES[name])
+    for name in list(QUANT_AXES):
+        w8, s = q(layers.pop(name), QUANT_AXES[name])
         jax.block_until_ready(w8)
         out_layers[name] = w8
         out_layers[name + "_s"] = s
@@ -328,7 +300,7 @@ def llama_init_quantized(cfg: LlamaConfig, seed: int = 0) -> Dict[str, Any]:
     8B vs ~17 GiB for init-then-quantize, which OOMs a 16 GiB chip).
     Numerically identical to quantize_weights(llama_init(cfg, seed)).
     """
-    dtype = _np_dtype(cfg.dtype)
+    dtype = np_dtype(cfg.dtype)
     key = jax.random.PRNGKey(seed)
     keys = jax.random.split(key, 8)
     L, D, H, Hkv, dh, F, V = (cfg.n_layers, cfg.dim, cfg.n_heads,
@@ -341,7 +313,7 @@ def llama_init_quantized(cfg: LlamaConfig, seed: int = 0) -> Dict[str, Any]:
     def gen_q(k, shape, fan_in, axis):
         w = (jax.random.normal(k, shape, dtype=jnp.float32)
              * (1.0 / math.sqrt(fan_in))).astype(dtype)
-        return _quantize_leaf(w, axis)
+        return quantize_leaf(w, axis)
 
     # (key, shape, fan_in, scale axis) — mirrors llama_init's spec table
     spec = {
@@ -441,7 +413,7 @@ def _attention_block(x, layer, k_cache_l, v_cache_l, positions, cfg: LlamaConfig
     return out, k_cache_l, v_cache_l
 
 
-def _ffn_block(x, layer, cfg: LlamaConfig):
+def ffn_block(x, layer, cfg: LlamaConfig):
     normed = rms_norm(x, layer["ffn_norm"], cfg.rms_eps)
     gate = jax.nn.silu(_mm(normed, layer, "w_gate"))
     up = _mm(normed, layer, "w_up")
@@ -469,7 +441,7 @@ def llama_forward_hidden(params, cfg: LlamaConfig, tokens, positions, k_cache,
         attn_out, k_l, v_l = _attention_block(x, layer, k_l, v_l, positions,
                                               cfg, mesh)
         x = x + attn_out
-        x = x + _ffn_block(x, layer, cfg)
+        x = x + ffn_block(x, layer, cfg)
         return x, (k_l, v_l)
 
     x, (k_cache, v_cache) = jax.lax.scan(
@@ -518,7 +490,7 @@ def llama_prefill_paged(params, cfg: LlamaConfig, tokens, lengths, mesh=None):
     ()): no state beside the pages."""
     K, bucket = tokens.shape
     tmp_k = jnp.zeros((cfg.n_layers, K, cfg.n_kv_heads, cfg.head_dim, bucket),
-                      dtype=_np_dtype(cfg.dtype))
+                      dtype=np_dtype(cfg.dtype))
     pos_grid = jnp.broadcast_to(
         jnp.arange(bucket, dtype=jnp.int32)[None, :], (K, bucket))
     last, tmp_k, tmp_v = llama_prefill_last(
@@ -581,7 +553,7 @@ def llama_prefill_chunk(params, cfg: LlamaConfig, tokens, positions,
         attn, k_rows, v_rows = _attention_block(x, layer, k_rows, v_rows,
                                                 positions, cfg, mesh)
         x = x + attn
-        x = x + _ffn_block(x, layer, cfg)
+        x = x + ffn_block(x, layer, cfg)
         k_out[l] = k_out[l].at[slots].set(k_rows)
         v_out[l] = v_out[l].at[slots].set(v_rows)
     if project_last is None:
@@ -602,19 +574,6 @@ def _attended_lengths(table, positions):
     from ..ops.paged_attention import holds_request
 
     return jnp.where(holds_request(table), positions + 1, 0)
-
-
-def _attended_in_block(table, positions, step):
-    """The same for step `step` of a decode block whose new tokens wait in
-    the block's tail (ops/paged_attention `block_tail`): (tokens attended
-    in pages: the row's context when the block began; tokens attended in
-    the tail: this step's and the block's earlier ones), both 0 for a row
-    that holds no request."""
-    from ..ops.paged_attention import holds_request
-
-    live = holds_request(table)
-    return (jnp.where(live, positions - step, 0),
-            jnp.where(live, step + 1, 0))
 
 
 def llama_decode_step_paged(params, cfg: LlamaConfig, tokens, positions,
@@ -647,7 +606,7 @@ def llama_decode_step_paged(params, cfg: LlamaConfig, tokens, positions,
     H, Hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     x = _embed(params, cfg, tokens)[:, None]               # [B, 1, D]
     pos_grid = positions[:, None]                          # [B, 1]
-    lengths, tail_lens = _attended_in_block(table, positions, step)
+    lengths, tail_lens = attended_in_block(table, positions, step)
 
     def layer_body(l, state):
         x, k_tail, v_tail = state
@@ -662,7 +621,7 @@ def llama_decode_step_paged(params, cfg: LlamaConfig, tokens, positions,
             q[:, 0], k[:, 0], v[:, 0], k_pool, v_pool, k_tail, v_tail,
             table, lengths, tail_lens, layer=l, mesh=mesh)
         x = x + _mm(attn.reshape(B, 1, H * dh), layer, "wo")
-        x = x + _ffn_block(x, layer, cfg)
+        x = x + ffn_block(x, layer, cfg)
         return x, k_tail, v_tail
 
     x, k_tail, v_tail = jax.lax.fori_loop(
@@ -710,7 +669,7 @@ def llama_decode_step_paged_q8(params, cfg: LlamaConfig, tokens, positions,
         attn = paged_attention(q[:, 0], k_pool, v_pool, table, lengths,
                                ks_pool, vs_pool, layer=l, mesh=mesh)
         x = x + _mm(attn.reshape(B, 1, H * dh), layer, "wo")
-        x = x + _ffn_block(x, layer, cfg)
+        x = x + ffn_block(x, layer, cfg)
         return x, k_pool, v_pool, ks_pool, vs_pool
 
     x, k_pool, v_pool, ks_pool, vs_pool = jax.lax.fori_loop(
@@ -789,7 +748,7 @@ def llama_verify_step_paged(params, cfg: LlamaConfig, tokens, drafts,
                           v_rows,
                           preferred_element_type=jnp.float32).astype(x.dtype)
         x = x + _mm(attn.reshape(B, d + 1, H * dh), layer, "wo")
-        x = x + _ffn_block(x, layer, cfg)
+        x = x + ffn_block(x, layer, cfg)
         return x, k_pool, v_pool
 
     x, k_pool, v_pool = jax.lax.fori_loop(
@@ -870,7 +829,7 @@ def llama_prefill_paged_prefix(params, cfg: LlamaConfig, tokens, prefix_lens,
                           v_rows,
                           preferred_element_type=jnp.float32).astype(x.dtype)
         x = x + _mm(attn.reshape(K, T, H * dh), layer, "wo")
-        x = x + _ffn_block(x, layer, cfg)
+        x = x + ffn_block(x, layer, cfg)
         return x, k_pool, v_pool
 
     x, k_pool, v_pool = jax.lax.fori_loop(
@@ -903,7 +862,7 @@ def llama_prefill_paged_prefix_q8(params, cfg: LlamaConfig, tokens,
     ps = k_pool.shape[-1]
     NP = table.shape[1]
     S = NP * ps
-    dt = _np_dtype(cfg.dtype)
+    dt = np_dtype(cfg.dtype)
     pos_grid = prefix_lens[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
     x = _embed(params, cfg, tokens)
 
@@ -946,7 +905,7 @@ def llama_prefill_paged_prefix_q8(params, cfg: LlamaConfig, tokens,
                           v_deq,
                           preferred_element_type=jnp.float32).astype(x.dtype)
         x = x + _mm(attn.reshape(K, T, H * dh), layer, "wo")
-        x = x + _ffn_block(x, layer, cfg)
+        x = x + ffn_block(x, layer, cfg)
         return x, k_pool, v_pool, ks_pool, vs_pool
 
     x, k_pool, v_pool, ks_pool, vs_pool = jax.lax.fori_loop(
@@ -957,7 +916,7 @@ def llama_prefill_paged_prefix_q8(params, cfg: LlamaConfig, tokens,
     return logits, k_pool, v_pool, ks_pool, vs_pool
 
 
-def _attention_block_nocache(x, layer, positions, cfg: LlamaConfig,
+def attention_block_nocache(x, layer, positions, cfg: LlamaConfig,
                              attn_fn=None, mesh=None):
     """Plain causal attention sublayer (no cache). x: [B, T, D] -> [B, T, D].
 
@@ -993,9 +952,9 @@ def forward_nocache_at(params, cfg: LlamaConfig, tokens, positions,
     x = _embed(params, cfg, tokens)
 
     def body(x, layer):
-        x = x + _attention_block_nocache(x, layer, positions, cfg, attn_fn,
+        x = x + attention_block_nocache(x, layer, positions, cfg, attn_fn,
                                          mesh)
-        x = x + _ffn_block(x, layer, cfg)
+        x = x + ffn_block(x, layer, cfg)
         return x, None
 
     x, _ = jax.lax.scan(body, x, params["layers"])
@@ -1012,3 +971,13 @@ def llama_forward_nocache(params, cfg: LlamaConfig, tokens, mesh=None):
     B, T = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None, :], (B, T))
     return forward_nocache_at(params, cfg, tokens, positions, mesh=mesh)
+
+
+# for models/families.py; the last two are the checkpoint loader and the int8
+# weight path, which this family alone has
+PRESETS = {"debug": LlamaConfig.debug, "llama1b": LlamaConfig.llama1b,
+           "llama3-8b": LlamaConfig.llama3_8b,
+           "llama3-70b": LlamaConfig.llama3_70b}   # TP_SHARDS=8 territory
+init = llama_init
+init_quantized = llama_init_quantized
+from .weights import load_llama_safetensors as load_checkpoint  # noqa: E402

@@ -33,14 +33,9 @@ V (128). The two phases compute the same attention in two forms:
   once for all H queries. `W^K_h` and `W^V_h` are views of `W_kvb`
   ([kv_rank, H, nope + v] sliced in the einsum), not second copies.
 
-Experts: `s = sigmoid(x W_r)` over all `n_experts` in float32; the k
-largest of `s + bias` are picked (the bias chooses and does not weigh; one
-routing group, no group limit); weights `s_picked / sum(s_picked) *
-routed_scale`; `y = sum_e w_e down_e(silu(gate_e x) * up_e x) + shared(x)`.
-The block is told which experts it holds (`experts_held`), as nemotron_h's
-is (models/nemotron_h.py: the same routing arithmetic, counters and
-kernel, ops/moe_experts.py in its gated form): what the others would add
-is left out. The dense block, the shared expert and the routers are XLA's.
+The FFN, dense or routed, is models/experts.py's in its gated form
+(`y = sum_e w_e down_e(silu(gate_e x) * up_e x) + shared(x)`), the block
+told which experts it holds (`experts_held`).
 
 The residual path. `hc_mult` 1: one stream, `h = x + F(RMSNorm(x))` as
 above. `hc_mult` n > 1 (manifold-constrained hyper-connections, mHC;
@@ -55,10 +50,8 @@ float32 leaves a block: `attn_hc_phi` / `ffn_hc_phi` [2n + n^2, n D],
 `.._hc_scale` [3], `.._hc_bias` [2n + n^2]. The kernels where `attn_impl`
 is "flash", the same arithmetic in jax.numpy where it is "xla".
 
-Weights: {"tok_emb" [V, D], "layers": [one dict a block], "final_norm"
-[D], "lm_head" [D, V]}; matrices [in, out] but the routed experts' three,
-"w1" (up), "wg" (gate), "w2" (down), each [held, F, D]; per-block leaves,
-the layer loop unrolled (block 0 differs in kind).
+Weights: the tree models/blocks.py `init_blocks` lays out, `layer_shapes`
+a block.
 """
 
 from __future__ import annotations
@@ -70,9 +63,10 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from .llama import _np_dtype, rms_norm
-from .nemotron_h import COUNTERS, _head, route
-from .nemotron_h import FLOAT32_LEAVES as _ROUTER_LEAVES
+from . import experts
+from .blocks import (head, init_blocks, live_and_attended, np_dtype, rms_norm,
+                     seeded_block)
+from .experts import COUNTERS, ffn_decode, ffn_prefill
 
 __all__ = ["MlaMoeConfig", "YarnScaling", "mla_moe_init", "prefill",
            "decode_step", "COUNTERS", "HC_COUNTERS", "FLOAT32_LEAVES"]
@@ -80,7 +74,7 @@ __all__ = ["MlaMoeConfig", "YarnScaling", "mla_moe_init", "prefill",
 # the mix's leaves of an `hc_mult` > 1 block, a sublayer: float32 always
 HC_LEAVES = tuple(f"{sub}_hc_{leaf}" for sub in ("attn", "ffn")
                   for leaf in ("phi", "scale", "bias"))
-FLOAT32_LEAVES = _ROUTER_LEAVES + HC_LEAVES
+FLOAT32_LEAVES = experts.FLOAT32_LEAVES + HC_LEAVES
 # what an `hc_mult` > 1 decode step counts beside COUNTERS: (row, sublayer)
 # pairs of live rows whose H_res logits met the clamp
 HC_COUNTERS = COUNTERS + ("hc_clamped",)
@@ -131,7 +125,7 @@ class YarnScaling:
 
 
 @dataclasses.dataclass(frozen=True)
-class MlaMoeConfig:
+class MlaMoeConfig(experts.HeldExperts):
     vocab_size: int = 129280
     dim: int = 2048
     n_layers: int = 40
@@ -164,10 +158,7 @@ class MlaMoeConfig:
     attn_impl: str = "xla"
 
     def __post_init__(self):
-        lo, hi = self.experts_held
-        if not 0 <= lo < hi <= self.n_experts:
-            raise ValueError(f"experts_held {self.experts_held} is not a "
-                             f"range of the {self.n_experts} experts")
+        self.check_experts_held()
         if not 0 <= self.first_dense <= self.n_layers:
             raise ValueError("first_dense counts leading blocks")
         if self.hc_mult < 1:
@@ -185,10 +176,6 @@ class MlaMoeConfig:
     @property
     def expert_layers(self) -> int:
         return self.n_layers - self.first_dense
-
-    @property
-    def held(self) -> int:
-        return self.experts_held[1] - self.experts_held[0]
 
     @property
     def qk_dim(self) -> int:
@@ -275,17 +262,13 @@ class MlaMoeConfig:
         of an expert FFN as held and as a token meets it (router, shared
         expert, its k picks)."""
         D, H = self.dim, self.n_heads
-        per_expert = 3 * D * self.expert_dim
-        outside = D * self.n_experts + 3 * D * self.shared_dim
         return {
             "attention": D * self.q_rank + self.q_rank * H * self.qk_dim
             + D * self.latent_dim
             + self.kv_rank * H * (self.nope_dim + self.v_dim)
             + H * self.v_dim * D,
             "dense": 3 * D * self.dense_dim,
-            "experts_held": outside + self.held * per_expert,
-            "experts_met": outside + self.experts_per_token * per_expert
-            * self.held // self.n_experts,
+            **experts.expert_params(self),
             # the two sublayers' phi of an `hc_mult` > 1 block (float32)
             "mix": 2 * self.hc_columns * self.hc_mult * D
             if self.hc_mult > 1 else 0,
@@ -348,15 +331,10 @@ REFUSES = {
 
 
 def describe(cfg: MlaMoeConfig, counts: Dict[str, int], steps: int):
-    """`/debug/engine` "model": the experts held and how the routing of
-    `steps` decode steps fell, under nemotron_h's names."""
-    from .nemotron_h import routing_summary
-
-    out = {"experts_held": cfg.held, "experts_total": cfg.n_experts}
-    routing = routing_summary(counts, steps, cfg.expert_layers, cfg.held,
-                              cfg.experts_per_token)
-    if routing:
-        out["routing"] = routing
+    """`/debug/engine` "model": the experts held, how the routing of
+    `steps` decode steps fell, and the residual path where the stream has
+    copies."""
+    out = experts.describe(cfg, counts, steps)
     if cfg.hc_mult > 1:
         out["residual"] = {"streams": cfg.hc_mult,
                            "sinkhorn_iters": cfg.hc_sinkhorn_iters,
@@ -384,58 +362,33 @@ def layer_shapes(cfg: MlaMoeConfig, dense: bool) -> Dict[str, tuple]:
     if dense:
         return {**shapes, "w_gate": (D, cfg.dense_dim),
                 "w_up": (D, cfg.dense_dim), "w_down": (cfg.dense_dim, D)}
-    expert = (cfg.held, cfg.expert_dim, D)
-    return {**shapes, "router": (D, cfg.n_experts),
-            "router_bias": (cfg.n_experts,), "w1": expert, "wg": expert,
-            "w2": expert, "shared_gate": (D, cfg.shared_dim),
-            "shared_up": (D, cfg.shared_dim),
-            "shared_down": (cfg.shared_dim, D)}
+    return {**shapes, **experts.expert_shapes(cfg)}
 
 
 def mla_moe_init(cfg: MlaMoeConfig, seed: int = 0) -> Dict[str, Any]:
     """Random-init params, a jitted call a block."""
-    dt = _np_dtype(cfg.dtype)
+    def mix_leaf(name, shape, keys):
+        if name.endswith("_hc_phi"):
+            return (jax.random.normal(next(keys), shape, jnp.float32)
+                    / math.sqrt(shape[1]))
+        if name.endswith("_hc_scale"):
+            return jnp.ones(shape, jnp.float32)
+        if name.endswith("_hc_bias"):
+            return 0.5 * jax.random.normal(next(keys), shape, jnp.float32)
+        return None
 
-    def matrix(key, shape, fan_in):
-        return (jax.random.normal(key, shape, jnp.float32)
-                / math.sqrt(fan_in)).astype(dt)
+    return init_blocks(
+        cfg, seed, [i < cfg.first_dense for i in range(cfg.n_layers)],
+        lambda key, dense: seeded_block(key, layer_shapes(cfg, dense),
+                                        np_dtype(cfg.dtype), mix_leaf))
 
-    def make(key, dense):
-        shapes = layer_shapes(cfg, dense)
-        keys = iter(jax.random.split(key, len(shapes)))
-        out = {}
-        for name, shape in shapes.items():
-            if name.endswith("norm"):
-                out[name] = jnp.ones(shape, dt)
-            elif name == "router_bias":
-                out[name] = jnp.zeros(shape, jnp.float32)
-            elif name.endswith("_hc_phi"):
-                out[name] = (jax.random.normal(next(keys), shape, jnp.float32)
-                             / math.sqrt(shape[1]))
-            elif name.endswith("_hc_scale"):
-                out[name] = jnp.ones(shape, jnp.float32)
-            elif name.endswith("_hc_bias"):
-                out[name] = 0.5 * jax.random.normal(next(keys), shape,
-                                                    jnp.float32)
-            else:
-                # the experts' matrices are [held, out, in] (w2: [.., in,
-                # out]): fan-in is D for up and gate, F for down
-                fan_in = (shape[1] if name == "w2" else shape[-1]
-                          if len(shape) == 3 else shape[0])
-                out[name] = matrix(next(keys), shape, fan_in)
-        return out
 
-    make = jax.jit(make, static_argnums=1)
-    key = jax.random.PRNGKey(seed)
-    return {
-        "tok_emb": jax.jit(matrix, static_argnums=(1, 2))(
-            jax.random.fold_in(key, 1), (cfg.vocab_size, cfg.dim), cfg.dim),
-        "layers": [make(jax.random.fold_in(key, 16 + i), i < cfg.first_dense)
-                   for i in range(cfg.n_layers)],
-        "final_norm": jnp.ones((cfg.dim,), dt),
-        "lm_head": jax.jit(matrix, static_argnums=(1, 2))(
-            jax.random.fold_in(key, 2), (cfg.dim, cfg.vocab_size), cfg.dim),
-    }
+# for models/families.py
+PRESETS = {"mla-moe-debug": MlaMoeConfig.debug,
+           "joyai-llm-flash-ep8": MlaMoeConfig.joyai_llm_flash_ep8,
+           "mla-moe-hc-debug": MlaMoeConfig.debug_hc,
+           "xing4.0-29b-a4b-ep8": MlaMoeConfig.xing4_0_29b_a4b_ep8}
+init = mla_moe_init
 
 
 # -- attention ----------------------------------------------------------------
@@ -550,45 +503,6 @@ def attention_decode(x, w, positions, pool, table, lengths, tail, tail_lens,
     return heads.reshape(x.shape[0], -1) @ w["wo"], tail
 
 
-# -- feed-forward -------------------------------------------------------------
-def _swiglu(x, gate, up, down):
-    return (jax.nn.silu(x @ gate) * (x @ up)) @ down
-
-
-def ffn_prefill(x, w, real, cfg: MlaMoeConfig):
-    """x [K, T, D] (normed); real [K, T] marks tokens that are not
-    padding. The dense FFN, or the held experts by a grouped product over
-    the (token, pick) pairs sorted by expert plus the shared expert."""
-    if "router" not in w:
-        return _swiglu(x, w["w_gate"], w["w_up"], w["w_down"])
-    from ..ops.moe_experts import prefill_experts
-
-    K, T, D = x.shape
-    flat = x.reshape(K * T, D)
-    picks, weights = route(flat, w, cfg)
-    weights = jnp.where(real.reshape(K * T, 1), weights, 0.0)
-    routed = prefill_experts(flat, w["w1"], w["w2"], picks, weights,
-                             cfg.experts_held[0], cfg.n_experts,
-                             tm=min(128, max(8, K * T)), wg=w["wg"])
-    shared = _swiglu(flat, w["shared_gate"], w["shared_up"], w["shared_down"])
-    return (routed.astype(x.dtype) + shared).reshape(K, T, D)
-
-
-def ffn_decode(x, w, live, cfg: MlaMoeConfig):
-    """x [B, D] (normed); live [B]. Returns (out [B, D], counters [3]
-    int32 of COUNTERS less `rows`, zeros for the dense block)."""
-    if "router" not in w:
-        return (_swiglu(x, w["w_gate"], w["w_up"], w["w_down"]),
-                jnp.zeros((3,), jnp.int32))
-    from ..ops.moe_experts import decode_experts
-    from .nemotron_h import combine_held
-
-    combine, counted = combine_held(x, w, live, cfg)
-    routed = decode_experts(x, w["w1"], w["w2"], combine, wg=w["wg"])
-    shared = _swiglu(x, w["shared_gate"], w["shared_up"], w["shared_down"])
-    return routed.astype(x.dtype) + shared, counted
-
-
 # -- the residual path --------------------------------------------------------
 def _fan_out(x, cfg: MlaMoeConfig):
     """The embedding as the stream: `hc_mult` equal copies, [.., n D]."""
@@ -665,7 +579,7 @@ def prefill(params, cfg: MlaMoeConfig, tokens, lengths):
         x = _mix_out(x, ffn_prefill(rms_norm(u, w["ffn_norm"], cfg.rms_eps),
                                     w, real, cfg), h, cfg)
     last = _fan_in(x[jnp.arange(K), lengths - 1], cfg)
-    return _head(last, params, cfg), jnp.stack(latents)
+    return head(last, params, cfg.rms_eps), jnp.stack(latents)
 
 
 def decode_step(params, cfg: MlaMoeConfig, tokens, positions, pool, table,
@@ -676,11 +590,7 @@ def decode_step(params, cfg: MlaMoeConfig, tokens, positions, pool, table,
     block's latent tail (models/protocol.py). Returns (logits [B, V]
     float32, tail, counters int32: COUNTERS, or HC_COUNTERS where the
     stream has copies)."""
-    from ..ops.paged_attention import holds_request
-    from .llama import _attended_in_block
-
-    live = holds_request(table)
-    lengths, tail_lens = _attended_in_block(table, positions, step)
+    live, lengths, tail_lens = live_and_attended(table, positions, step)
     x = _fan_out(params["tok_emb"][tokens], cfg)
     counted = jnp.zeros((3,), jnp.int32)
     clamped = 0
@@ -697,8 +607,6 @@ def decode_step(params, cfg: MlaMoeConfig, tokens, positions, pool, table,
         x = _mix_out(x, out, g, cfg)
         if h is not None:
             clamped = clamped + _clamped(h, live, cfg) + _clamped(g, live, cfg)
-    counters = [jnp.sum(live, dtype=jnp.int32)[None], counted]
-    if cfg.hc_mult > 1:
-        counters.append(clamped[None])
-    counters = jnp.concatenate(counters)
-    return _head(_fan_in(x, cfg), params, cfg), tail, counters
+    counters = experts.step_counters(
+        live, counted, *((clamped,) if cfg.hc_mult > 1 else ()))
+    return head(_fan_in(x, cfg), params, cfg.rms_eps), tail, counters

@@ -17,7 +17,8 @@ from typing import Any, Dict
 import jax
 import jax.numpy as jnp
 
-from .llama import LlamaConfig, _attention_block_nocache, _np_dtype, rms_norm
+from .blocks import np_dtype, rms_norm
+from .llama import LlamaConfig, attention_block_nocache
 
 
 @dataclasses.dataclass(frozen=True)
@@ -33,7 +34,7 @@ class MoELlamaConfig(LlamaConfig):
 
 
 def moe_llama_init(cfg: MoELlamaConfig, seed: int = 0) -> Dict[str, Any]:
-    dtype = _np_dtype(cfg.dtype)
+    dtype = np_dtype(cfg.dtype)
     key = jax.random.PRNGKey(seed)
     keys = jax.random.split(key, 9)
     L, D, H, Hkv, dh, F, V, E = (cfg.n_layers, cfg.dim, cfg.n_heads,
@@ -101,7 +102,7 @@ def moe_llama_forward_nocache(params, cfg: MoELlamaConfig, tokens):
 
     def body(carry, layer):
         x, aux = carry
-        x = x + _attention_block_nocache(x, layer, positions, cfg)
+        x = x + attention_block_nocache(x, layer, positions, cfg)
         ffn_out, layer_aux = moe_ffn(x, layer, cfg)
         x = x + ffn_out
         return (x, aux + layer_aux), None

@@ -21,19 +21,13 @@ protocol (models/protocol.py) hands both to the engine:
 - `decode_step`: one token a row over the pool and the per-slot states,
   the state updated in place by ops/ssm_update.py, live rows only.
 
-An expert block routes over all `n_experts` and is told which it holds
-(`experts_held`): the tree carries only those ([held, D, F]), and what the
-others would add is left out, here and in the reference alike (the other
-chip of the pair adds its share; on one chip there is no exchange and
-nothing stands in for it). Rows that hold no request are kept out of the
-routing and of the counters.
+An expert block is models/experts.py's in its ungated form (relu^2 experts
+and shared expert): it routes over all `n_experts` and is told which it
+holds (`experts_held`), the other chip of the pair adding its share.
 
-Weights: {"tok_emb" [V, D], "layers": [one dict a block], "final_norm" [D],
-"lm_head" [D, V]}; matrices [in, out] but the experts' up matrices "w1"
-[held, F, D], kept [out, in] as the checkpoint stores them (ops/
-moe_experts.py says why); per-block leaves, never stacked: the blocks differ in
-kind, the layer loop is unrolled, and a static slice of a stack feeding a
-matmul may be copied (1.3 GB for a block's experts).
+Weights: the tree models/blocks.py `init_blocks` lays out, `layer_shapes`
+a block; the experts' up matrices "w1" [held, F, D] are kept [out, in] as
+the checkpoint stores them (ops/moe_experts.py says why).
 """
 
 from __future__ import annotations
@@ -46,22 +40,20 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.short_conv import conv_decode, conv_prefill
-from .llama import _np_dtype, rms_norm
+from . import experts
+from .blocks import (head, init_blocks, live_and_attended, matrix, np_dtype,
+                     rms_norm)
+from .experts import COUNTERS, ffn_decode, ffn_prefill
 
 KINDS = {"M": "mamba", "E": "experts", "*": "attention"}
 
 # leaves held in float32 whatever `dtype` is, as the published checkpoint
 # keeps them: the state-space constants and the router's bias
-FLOAT32_LEAVES = ("A_log", "D", "dt_bias", "router_bias")
-
-# what a decode step counts, a row of int32 a step (summed over the expert
-# blocks): live rows, picks that fell on held experts, held experts touched,
-# the busiest held expert's tokens
-COUNTERS = ("rows", "held_picks", "experts_touched", "busiest_expert_tokens")
+FLOAT32_LEAVES = ("A_log", "D", "dt_bias") + experts.FLOAT32_LEAVES
 
 
 @dataclasses.dataclass(frozen=True)
-class NemotronHConfig:
+class NemotronHConfig(experts.HeldExperts):
     vocab_size: int = 131072
     dim: int = 2688
     pattern: str = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
@@ -86,10 +78,7 @@ class NemotronHConfig:
     attn_impl: str = "xla"      # "xla" | "flash": the prefill window
 
     def __post_init__(self):
-        lo, hi = self.experts_held
-        if not 0 <= lo < hi <= self.n_experts:
-            raise ValueError(f"experts_held {self.experts_held} is not a "
-                             f"range of the {self.n_experts} experts")
+        self.check_experts_held()
         if set(self.pattern) - set(KINDS):
             raise ValueError(f"pattern {self.pattern!r}: use M, E and *")
 
@@ -108,10 +97,6 @@ class NemotronHConfig:
     @property
     def expert_layers(self) -> int:
         return self.pattern.count("E")
-
-    @property
-    def held(self) -> int:
-        return self.experts_held[1] - self.experts_held[0]
 
     @property
     def d_inner(self) -> int:
@@ -166,16 +151,12 @@ class NemotronHConfig:
         """Matrix parameters of one block of each kind, and of what a token
         meets in an expert block (router, shared expert, its k picks)."""
         D = self.dim
-        per_expert = 2 * D * self.expert_dim
-        outside = D * self.n_experts + 2 * D * self.shared_dim
         return {
             "mamba": D * self.in_proj_dim + self.d_inner * D
             + self.conv_kernel * self.conv_dim,
             "attention": 2 * D * (self.n_heads + self.n_kv_heads)
             * self.head_dim,
-            "experts_held": outside + self.held * per_expert,
-            "experts_met": outside + self.experts_per_token * per_expert
-            * self.held // self.n_experts,
+            **experts.expert_params(self, gated=False),
         }
 
     def param_count(self) -> int:
@@ -233,34 +214,8 @@ def describe(cfg: NemotronHConfig, counts: Dict[str, int], steps: int):
     """`/debug/engine` "model": what a slot holds, the experts held, and
     how the routing of `steps` decode steps fell (COUNTERS' sums), an
     expert block and step."""
-    out = {"state_bytes_per_slot": cfg.state_bytes_per_slot,
-           "experts_held": cfg.held, "experts_total": cfg.n_experts}
-    routing = routing_summary(counts, steps, cfg.expert_layers, cfg.held,
-                              cfg.experts_per_token)
-    if routing:
-        out["routing"] = routing
-    return out
-
-
-def routing_summary(counts: Dict[str, int], steps: int, expert_layers: int,
-                    held: int, k: int):
-    """What COUNTERS' sums over `steps` decode steps say of the routing,
-    an expert block and step (models/mla_moe.py counts the same); None
-    before any live step."""
-    layer_steps = steps * expert_layers
-    if not layer_steps or not counts["rows"]:
-        return None
-    mean = counts["held_picks"] / (layer_steps * held)
-    return {
-        "rows_per_step": counts["rows"] / steps,
-        "tokens_per_held_expert_mean": mean,
-        "tokens_per_held_expert_max_over_mean": (
-            counts["busiest_expert_tokens"] / layer_steps / mean
-            if mean else 0.0),
-        "experts_touched_per_layer_step":
-            counts["experts_touched"] / layer_steps,
-        "held_pick_share": counts["held_picks"] / (
-            counts["rows"] * k * expert_layers)}
+    return {"state_bytes_per_slot": cfg.state_bytes_per_slot,
+            **experts.describe(cfg, counts, steps)}
 
 
 def layer_shapes(cfg: NemotronHConfig, kind: str) -> Dict[str, tuple]:
@@ -272,12 +227,7 @@ def layer_shapes(cfg: NemotronHConfig, kind: str) -> Dict[str, tuple]:
                 "A_log": (cfg.mamba_heads,), "D": (cfg.mamba_heads,),
                 "gate_norm": (cfg.d_inner,), "out_proj": (cfg.d_inner, D)}
     if kind == "experts":
-        return {"norm": (D,), "router": (D, cfg.n_experts),
-                "router_bias": (cfg.n_experts,),
-                "w1": (cfg.held, cfg.expert_dim, D),
-                "w2": (cfg.held, cfg.expert_dim, D),
-                "shared_w1": (D, cfg.shared_dim),
-                "shared_w2": (cfg.shared_dim, D)}
+        return {"norm": (D,), **experts.expert_shapes(cfg, gated=False)}
     q, kv = cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim
     return {"norm": (D,), "wq": (D, q), "wk": (D, kv), "wv": (D, kv),
             "wo": (q, D)}
@@ -288,11 +238,7 @@ def nemotron_h_init(cfg: NemotronHConfig, seed: int = 0) -> Dict[str, Any]:
     1.3 GB at the published widths). The state-space constants as Mamba-2
     initialises them: A uniform in [1, 16], dt log-uniform in [1e-3, 1e-1]
     through the inverse softplus, D ones."""
-    dt = _np_dtype(cfg.dtype)
-
-    def matrix(key, shape, fan_in):
-        return (jax.random.normal(key, shape, jnp.float32)
-                / math.sqrt(fan_in)).astype(dt)
+    dt = np_dtype(cfg.dtype)
 
     def make(key, kind):
         shapes = layer_shapes(cfg, kind)
@@ -317,20 +263,17 @@ def nemotron_h_init(cfg: NemotronHConfig, seed: int = 0) -> Dict[str, Any]:
                 out[name] = jnp.zeros(shape, jnp.float32)
             else:
                 out[name] = matrix(next(keys), shape,
-                                   shape[-1] if name == "w1" else shape[-2])
+                                   shape[-1] if name == "w1" else shape[-2],
+                                   dt)
         return out
 
-    make = jax.jit(make, static_argnums=1)
-    key = jax.random.PRNGKey(seed)
-    return {
-        "tok_emb": jax.jit(matrix, static_argnums=(1, 2))(
-            jax.random.fold_in(key, 1), (cfg.vocab_size, cfg.dim), cfg.dim),
-        "layers": [make(jax.random.fold_in(key, 16 + i), KINDS[mark])
-                   for i, mark in enumerate(cfg.pattern)],
-        "final_norm": jnp.ones((cfg.dim,), dt),
-        "lm_head": jax.jit(matrix, static_argnums=(1, 2))(
-            jax.random.fold_in(key, 2), (cfg.dim, cfg.vocab_size), cfg.dim),
-    }
+    return init_blocks(cfg, seed, [KINDS[mark] for mark in cfg.pattern], make)
+
+
+# for models/families.py
+PRESETS = {"nemotron-h-debug": NemotronHConfig.debug,
+           "nemotron-3-nano-30b-a3b-ep2": NemotronHConfig.nano_30b_a3b_ep2}
+init = nemotron_h_init
 
 
 def state_shapes(cfg: NemotronHConfig, slots: int):
@@ -341,7 +284,7 @@ def state_shapes(cfg: NemotronHConfig, slots: int):
     return (((cfg.mamba_layers, slots, cfg.state_size, cfg.d_inner),
              jnp.float32),
             ((cfg.mamba_layers, slots, cfg.conv_kernel - 1, cfg.conv_dim),
-             _np_dtype(cfg.dtype)))
+             np_dtype(cfg.dtype)))
 
 
 # -- mixers -------------------------------------------------------------------
@@ -454,71 +397,6 @@ def mamba_decode(u, w, state, tail, layer: int, live, cfg: NemotronHConfig):
     return out, state, tail
 
 
-def route(x, w, cfg):
-    """(picks [..., k] int32 over ALL experts, weights [..., k] float32):
-    sigmoid scores in float32 at full matmul precision (as the published
-    code), the k largest of score + bias picked, weighted by their scores
-    normalised and scaled."""
-    s = jax.nn.sigmoid(jnp.dot(
-        x.astype(jnp.float32), w["router"].astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST))
-    _, picks = jax.lax.top_k(s + w["router_bias"], cfg.experts_per_token)
-    chosen = jnp.take_along_axis(s, picks, axis=-1)
-    chosen = cfg.routed_scale * chosen / (
-        jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
-    return picks.astype(jnp.int32), chosen
-
-
-def _shared_expert(x, w):
-    from ..ops.moe_experts import relu2
-
-    return relu2(x @ w["shared_w1"]) @ w["shared_w2"]
-
-
-def experts_prefill(x, w, real, cfg: NemotronHConfig):
-    """x [K, T, D] (normed); real [K, T] marks tokens that are not padding.
-    The held experts by a grouped product over the (token, pick) pairs
-    sorted by expert; the shared expert over every token."""
-    from ..ops.moe_experts import prefill_experts
-
-    K, T, D = x.shape
-    flat = x.reshape(K * T, D)
-    picks, weights = route(flat, w, cfg)
-    weights = jnp.where(real.reshape(K * T, 1), weights, 0.0)
-    routed = prefill_experts(flat, w["w1"], w["w2"], picks, weights,
-                             cfg.experts_held[0], cfg.n_experts,
-                             tm=min(128, max(8, K * T)))
-    return (routed.astype(x.dtype) + _shared_expert(flat, w)).reshape(K, T, D)
-
-
-def combine_held(x, w, live, cfg):
-    """A decode step's routing over the experts held: (combine [B, held]
-    float32, zero where a row did not pick the expert and for every pick
-    of a row that holds no request; counters [3] int32 of COUNTERS less
-    `rows`). `cfg` any config with the router's fields (models/mla_moe.py
-    routes the same way)."""
-    lo, hi = cfg.experts_held
-    picks, weights = route(x, w, cfg)
-    mine = (picks >= lo) & (picks < hi) & live[:, None]           # [B, k]
-    rows = jnp.arange(x.shape[0])[:, None]
-    combine = jnp.zeros((x.shape[0], cfg.held + 1), jnp.float32).at[
-        rows, jnp.where(mine, picks - lo, cfg.held)].set(
-            jnp.where(mine, weights, 0.0))[:, :cfg.held]
-    tokens = jnp.sum(combine != 0.0, axis=0)                      # an expert
-    return combine, jnp.stack([jnp.sum(mine), jnp.sum(tokens > 0),
-                               jnp.max(tokens)]).astype(jnp.int32)
-
-
-def experts_decode(x, w, live, cfg: NemotronHConfig):
-    """x [B, D] (normed); live [B]. Returns (out [B, D], counters [3]
-    int32 of COUNTERS less `rows`)."""
-    from ..ops.moe_experts import decode_experts
-
-    combine, counted = combine_held(x, w, live, cfg)
-    routed = decode_experts(x, w["w1"], w["w2"], combine)
-    return routed.astype(x.dtype) + _shared_expert(x, w), counted
-
-
 def _qkv(x, w, cfg: NemotronHConfig):
     lead = x.shape[:-1]
     q = (x @ w["wq"]).reshape(*lead, cfg.n_heads, cfg.head_dim)
@@ -566,11 +444,6 @@ def attention_decode(x, w, k_pool, v_pool, table, lengths, tail, tail_lens,
     return attn.reshape(x.shape[0], -1) @ w["wo"], tuple(tail)
 
 
-def _head(x, params, cfg: NemotronHConfig):
-    x = rms_norm(x, params["final_norm"], cfg.rms_eps)
-    return (x @ params["lm_head"]).astype(jnp.float32)
-
-
 def prefill(params, cfg: NemotronHConfig, tokens, lengths):
     """tokens [K, T] right-padded; lengths [K]. Returns (last logits
     [K, V] float32, k, v [kv_layers, K, Hkv, dh, T], (state
@@ -586,7 +459,7 @@ def prefill(params, cfg: NemotronHConfig, tokens, lengths):
             states.append(state)
             tails.append(tail)
         elif mark == "E":
-            out = experts_prefill(normed, w, real, cfg)
+            out = ffn_prefill(normed, w, real, cfg)
         else:
             out, k, v = attention_prefill(normed, w, cfg)
             ks.append(k)
@@ -600,7 +473,7 @@ def prefill(params, cfg: NemotronHConfig, tokens, lengths):
 
     kv = (0, K, cfg.n_kv_heads, cfg.head_dim, T)
     (state_like, _), (tail_like, _) = state_shapes(cfg, K)
-    return (_head(last, params, cfg), stacked(ks, kv), stacked(vs, kv),
+    return (head(last, params, cfg.rms_eps), stacked(ks, kv), stacked(vs, kv),
             (stacked(states, state_like), stacked(tails, tail_like)))
 
 
@@ -612,12 +485,8 @@ def decode_step(params, cfg: NemotronHConfig, tokens, positions, k_pool,
     state = (ssm, tail); kv_tail the block's (k_tail, v_tail)
     (models/protocol.py). Returns (logits [B, V] float32, kv_tail, state,
     counters [len(COUNTERS)] int32)."""
-    from ..ops.paged_attention import holds_request
-    from .llama import _attended_in_block
-
     ssm, tail = state
-    live = holds_request(table)
-    lengths, tail_lens = _attended_in_block(table, positions, step)
+    live, lengths, tail_lens = live_and_attended(table, positions, step)
     x = params["tok_emb"][tokens]
     counted = jnp.zeros((3,), jnp.int32)
     m = a = 0
@@ -627,7 +496,7 @@ def decode_step(params, cfg: NemotronHConfig, tokens, positions, k_pool,
             out, ssm, tail = mamba_decode(normed, w, ssm, tail, m, live, cfg)
             m += 1
         elif mark == "E":
-            out, seen = experts_decode(normed, w, live, cfg)
+            out, seen = ffn_decode(normed, w, live, cfg)
             counted = counted + seen
         else:
             out, kv_tail = attention_decode(
@@ -635,6 +504,5 @@ def decode_step(params, cfg: NemotronHConfig, tokens, positions, k_pool,
                 tail_lens, a, cfg)
             a += 1
         x = x + out
-    counters = jnp.concatenate([jnp.sum(live, dtype=jnp.int32)[None],
-                                counted])
-    return _head(x, params, cfg), kv_tail, (ssm, tail), counters
+    counters = experts.step_counters(live, counted)
+    return head(x, params, cfg.rms_eps), kv_tail, (ssm, tail), counters

@@ -10,9 +10,8 @@ it:
 - `planes`: what a page HOLDS, a `Plane(name, heads, width)` each: the
   engine keeps one pool a plane, [kv_layers, pages, heads, width,
   page_size], and allocates, writes, flushes, plans and reports by this
-  answer. `kv_planes(Hkv, dh)` is K and V of grouped-query attention (the
-  llama_like, nemotron_h and kda_moe families); mla_moe keeps ONE plane of
-  1 x 576, a token's normed latent and its rotated shared key. What is
+  answer. `kv_planes(Hkv, dh)` is K and V of grouped-query attention; a
+  family may keep other planes (one narrow latent plane, say). What is
   said of "the pools" below is a tuple in this order.
 - `groups`: the blocks that keep pages, in PAGE GROUPS, a `PageGroup(name,
   layers, window)` each: blocks that share a table, an allocator and a
@@ -24,8 +23,8 @@ it:
   family's `planes`; the engine keeps one pool a plane a group,
   [group layers, pages, heads, width, page_size], group by group.
   `one_group(n)` is what a family whose blocks all keep every token
-  answers (llama_like, nemotron_h, mla_moe, kda_moe); afmoe answers `full` and
-  `window`. `kv_layers` (the blocks that keep pages, all groups) and
+  answers; one with window blocks answers a group each (`full`,
+  `window`). `kv_layers` (the blocks that keep pages, all groups) and
   `token_values` follow from the groups.
 - `state_shapes(slots)`: ((shape, dtype), ...) of the arrays a sequence
   holds BESIDE its pages, fixed in size, the slot axis second
@@ -61,14 +60,9 @@ it:
   static facts and what it makes of its counters' sums ({name: sum} over
   `steps` decode steps).
 
-`models/llama.py` (pages only, no state, no counters, refuses nothing),
-`models/nemotron_h.py` (pages for 6 blocks in 52, a recurrent state and a
-convolution tail a slot, expert counters), `models/mla_moe.py` (one
-latent plane a page, expert counters), `models/afmoe.py` (window and
-full attention blocks, a page group each, expert counters) and
-`models/kda_moe.py` (pages for one block in four, a matrix state of the
-delta rule and a convolution tail a slot, expert counters and `kda_rows`)
-are the five families.
+Who implements it: `models/families.py` lists the family modules;
+docs/model-families.md says what each keeps in pages, beside them, and
+counts.
 """
 
 from __future__ import annotations

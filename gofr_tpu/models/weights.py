@@ -289,7 +289,7 @@ def load_llama_safetensors(cfg, path: str,
     before any bytes are read — a preset/checkpoint mismatch fails fast
     with the offending tensor named). weight_dtype: None keeps cfg.dtype
     storage; "int8" quantizes each leaf on device as it loads
-    (per-output-channel scales, models.llama._quantize_leaf) so peak device
+    (per-output-channel scales, models.llama.quantize_leaf) so peak device
     memory is the int8 tree plus ONE float leaf.
 
     Returns the same pytree structure as llama_init / quantize_weights —
@@ -298,7 +298,8 @@ def load_llama_safetensors(cfg, path: str,
     """
     import jax
 
-    from .llama import _QUANT_AXES, _np_dtype as jax_dtype, _quantize_leaf
+    from .blocks import np_dtype as jax_dtype
+    from .llama import QUANT_AXES, quantize_leaf
 
     reader = CheckpointReader(path)
     # jnp scalar types are numpy/ml_dtypes types — np.dtype() accepts both
@@ -336,7 +337,7 @@ def load_llama_safetensors(cfg, path: str,
         raise ValueError(f"weight_dtype must be int8 or None, "
                          f"got {weight_dtype!r}")
     quantize = weight_dtype == "int8"
-    q = jax.jit(_quantize_leaf, static_argnums=1) if quantize else None
+    q = jax.jit(quantize_leaf, static_argnums=1) if quantize else None
 
     def log(msg, *args):
         if logger is not None:
@@ -373,7 +374,7 @@ def load_llama_safetensors(cfg, path: str,
 
     for leaf in _LAYER_MAP:
         host = _stack_layers(reader, cfg, leaf, np_target)
-        axis = _QUANT_AXES.get(leaf)
+        axis = QUANT_AXES.get(leaf)
         dev, s = place(f"layers.{leaf}", host, axis)
         del host
         layers[leaf] = dev

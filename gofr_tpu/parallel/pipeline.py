@@ -68,7 +68,7 @@ def pipelined_llama_forward(params, cfg, tokens, mesh, n_microbatches: int = 4):
     """
     from jax.sharding import PartitionSpec as P
 
-    from ..models.llama import rms_norm
+    from ..models.blocks import rms_norm
 
     B, T = tokens.shape
     if B % n_microbatches != 0:
@@ -78,12 +78,12 @@ def pipelined_llama_forward(params, cfg, tokens, mesh, n_microbatches: int = 4):
 
     def stage_fn(local_layers, h):
         # h: [mb, T, D]; local_layers: pytree with leading local-L axis
-        from ..models.llama import _attention_block_nocache, _ffn_block
+        from ..models.llama import attention_block_nocache, ffn_block
 
         def body(h, layer):
-            attn = _attention_block_nocache(h, layer, positions[:h.shape[0]], cfg)
+            attn = attention_block_nocache(h, layer, positions[:h.shape[0]], cfg)
             h = h + attn
-            h = h + _ffn_block(h, layer, cfg)
+            h = h + ffn_block(h, layer, cfg)
             return h, None
 
         h, _ = jax.lax.scan(body, h, local_layers)

@@ -1007,7 +1007,7 @@ class PagedLLMEngine(LLMEngine):
         cfg, mesh = self.cfg, self.mesh
         jnp = self._jnp
         top_k = self.top_k
-        from ..models.llama import _np_dtype
+        from ..models.blocks import np_dtype
         from .sampling import sample_tokens
 
         def prefill(params, k_pool, v_pool, k_scale, v_scale, ptokens,
@@ -1016,7 +1016,7 @@ class PagedLLMEngine(LLMEngine):
             L, P, Hkv, dh, _ = k_pool.shape
             k_pool, v_pool = _pin_standard_layout(k_pool, v_pool)
             tmp_k = jnp.zeros((L, K, Hkv, dh, bucket),
-                              dtype=_np_dtype(cfg.dtype))
+                              dtype=np_dtype(cfg.dtype))
             tmp_v = jnp.zeros_like(tmp_k)
             pos_grid = jnp.broadcast_to(
                 jnp.arange(bucket, dtype=jnp.int32)[None, :], (K, bucket))
@@ -1287,11 +1287,11 @@ class PagedLLMEngine(LLMEngine):
         """Chunk programs key on (chunk, K) and on the BUCKET (the temp
         caches are bucket-wide); buckets above the chunk size are few, so
         the compile set stays bounded."""
-        from ..models.llama import _np_dtype
+        from ..models.blocks import np_dtype
 
         Hkv, dh = self.cfg.n_kv_heads, self.cfg.head_dim
         L = self.cfg.n_layers
-        dt = _np_dtype(self.cfg.dtype)
+        dt = np_dtype(self.cfg.dtype)
         tmp = tuple(_shaped((K, Hkv, dh, bucket), dt) for _ in range(L))
         common = (_shaped((K, chunk)), _shaped((K, chunk)))
         selected = _shaped((K, self.cfg.vocab_size), np.float32)
@@ -1327,7 +1327,7 @@ class PagedLLMEngine(LLMEngine):
         import time as _time
 
         jnp = self._jnp
-        from ..models.llama import _np_dtype
+        from ..models.blocks import np_dtype
 
         with self.steps.seg("host_prep"):
             ptokens, lengths, new_temps = self._prep_admission(bucket, batch)
@@ -1344,7 +1344,7 @@ class PagedLLMEngine(LLMEngine):
                 prompt_pages = pages[:n_ptable]
                 ptable[row, :len(prompt_pages)] = prompt_pages
             Hkv, dh = self.cfg.n_kv_heads, self.cfg.head_dim
-            dt = _np_dtype(self.cfg.dtype)
+            dt = np_dtype(self.cfg.dtype)
             tmp_shape = (K, Hkv, dh, bucket)
 
             def temp():

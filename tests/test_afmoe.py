@@ -38,7 +38,8 @@ from gofr_tpu.models import afmoe  # noqa: E402
 from gofr_tpu.models.afmoe import (FULL, REFUSES, SLIDING,  # noqa: E402
                                    AfmoeConfig, decode_step, prefill)
 from gofr_tpu.models.llama import LlamaConfig, llama_init  # noqa: E402
-from gofr_tpu.models.mla_moe import MlaMoeConfig, ffn_decode, ffn_prefill  # noqa: E402
+from gofr_tpu.models.experts import ffn_decode, ffn_prefill  # noqa: E402
+from gofr_tpu.models.mla_moe import MlaMoeConfig  # noqa: E402
 from gofr_tpu.models.mla_moe import mla_moe_init  # noqa: E402
 from gofr_tpu.models.nemotron_h import NemotronHConfig, nemotron_h_init  # noqa: E402
 from gofr_tpu.ops.flash_attention import (attention_reference,  # noqa: E402
@@ -474,17 +475,13 @@ def test_the_windowed_read_through_a_ring_is_attention_over_the_window(
 
 # -- the engine ---------------------------------------------------------------
 def test_the_family_refuses_by_name_what_it_cannot_serve():
+    """What it refuses (tests/test_families.py asks the engine for each),
+    and a decode block that would cross more than one page of a ring."""
     cfg = AfmoeConfig.debug()
     params = afmoe.afmoe_init(cfg, seed=1)
     assert set(REFUSES) == {"prefix_cache", "kv_host_tier", "disagg",
                             "speculative_tokens", "chunk_prefill_tokens",
                             "int8_weights", "kv_dtype", "mesh"}
-    for kw, named in ((dict(prefix_cache=True), "prefix_cache"),
-                      (dict(speculative_tokens=2), "speculative_tokens"),
-                      (dict(chunk_prefill_tokens=16), "chunk_prefill_tokens")):
-        with pytest.raises(ValueError, match=f"afmoe family refuses {named}"):
-            PagedLLMEngine(params, cfg, n_slots=2, max_seq_len=64,
-                           page_size=8, **kw)
     with pytest.raises(ValueError, match="over page_size"):
         PagedLLMEngine(params, cfg, n_slots=2, max_seq_len=64, page_size=8,
                        decode_block_size=16)
@@ -628,25 +625,18 @@ def test_the_config_and_the_capacity_plan_count_what_the_cut_holds():
     assert 32 * 13312 * 5 * 4096 > 8.7e9 > 4.1e9 > plan.cache_bytes_max
 
 
-def test_the_front_door_starts_the_family_from_its_preset():
-    """examples/llm-server builds the family's engine from MODEL_PRESET as
-    it builds Llama's (no prefix cache: the family refuses it), refuses by
-    name a variable whose field the preset's config does not have, and
-    `/debug/engine`'s sections show the page groups."""
+def test_the_front_door_shows_the_family_s_two_page_groups():
+    """examples/llm-server builds the family's engine from MODEL_PRESET
+    (tests/test_families.py: every family's) with no prefix cache, which
+    the family refuses, and `/debug/engine`'s sections show the page
+    groups."""
     import gofr_tpu
     from test_examples import _cfg, _load
 
     module = _load("llm-server")
-    settings = dict(TPU_PLATFORM="cpu", MODEL_PRESET="afmoe-debug",
-                    WARMUP="false", MAX_BATCH="2", MAX_SEQ_LEN="128",
-                    PAGE_SIZE="16")
-    with pytest.raises(ValueError, match="afmoe-debug has no kv_dtype"):
-        module.build_engine(gofr_tpu.App(config=_cfg(**settings,
-                                                     KV_DTYPE="int8")))
-    with pytest.raises(ValueError, match="afmoe family has no checkpoint"):
-        module.build_engine(gofr_tpu.App(config=_cfg(**settings,
-                                                     WEIGHT_DTYPE="int8")))
-    engine = module.build_engine(gofr_tpu.App(config=_cfg(**settings)))
+    engine = module.build_engine(gofr_tpu.App(config=_cfg(
+        TPU_PLATFORM="cpu", MODEL_PRESET="afmoe-debug", WARMUP="false",
+        MAX_BATCH="2", MAX_SEQ_LEN="128", PAGE_SIZE="16")))
     try:
         assert engine.model.family == "afmoe" and engine.prefix is None
         request = engine.submit(engine.tokenizer.encode("hello there, afmoe"),

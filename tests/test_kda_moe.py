@@ -24,7 +24,7 @@ if BENCH not in sys.path:
 
 from harness import data  # noqa: E402
 
-from gofr_tpu.models import kda_moe  # noqa: E402
+from gofr_tpu.models import experts, kda_moe  # noqa: E402
 from gofr_tpu.models.kda_moe import (COUNTERS, KdaMoeConfig, REFUSES,  # noqa: E402
                                      decode_step, kda_moe_init, prefill,
                                      state_shapes)
@@ -406,10 +406,10 @@ def test_the_eight_shares_add_up_to_the_uncut_block(seeded):
             want = part(normed, held_w, held, True)
             cfg = dataclasses.replace(program_config(), n_experts=16,
                                       experts_held=held)
-            got, _ = jax.jit(lambda x, w, live: kda_moe.ffn_decode(
+            got, _ = jax.jit(lambda x, w, live: experts.ffn_decode(
                 x, w, live, cfg))(normed, held_w, live)
             assert np.abs(np.asarray(got - want)).max() < 1e-5
-            got = jax.jit(lambda x, w, real: kda_moe.ffn_prefill(
+            got = jax.jit(lambda x, w, real: experts.ffn_prefill(
                 x, w, real, cfg))(normed.reshape(2, 12, 64), held_w,
                                   jnp.ones((2, 12), bool))
             assert np.abs(np.asarray(got.reshape(24, 64) - want)).max() < 1e-5
@@ -423,31 +423,11 @@ def _engine(cfg, params, **kw):
                           decode_block_size=4, **kw)
 
 
-REFUSED = {
-    "prefix_cache": {"prefix_cache": True},
-    "kv_host_tier": {"kv_host_tier_bytes": 1 << 20},
-    "disagg": {"disagg_role": "decode"},
-    "speculative_tokens": {"speculative_tokens": 2},
-    "chunk_prefill_tokens": {"chunk_prefill_tokens": 16},
-    "int8_weights": {},
-    "kv_dtype": {},
-    "mesh": {"mesh": object()},
-}
-
-
-@pytest.mark.parametrize("feature", sorted(REFUSES))
-def test_each_feature_the_family_cannot_serve_is_refused_by_name(feature):
-    cfg = program_config()
-    assert "KDA state" in REFUSES[feature] or feature == "int8_weights"
-    params = {"lm_head_s": 0} if feature == "int8_weights" else {}
-    if feature == "kv_dtype":
-        # the config has no such field; one that carries it is refused
-        cfg = type("WithKvDtype", (KdaMoeConfig,), {"kv_dtype": "int8"})(
-            **{f.name: getattr(cfg, f.name)
-               for f in dataclasses.fields(cfg)})
-    with pytest.raises(ValueError, match=f"kda_moe family refuses "
-                                         f"{feature}="):
-        _engine(cfg, params, **REFUSED[feature])
+def test_every_refusal_but_the_weights_names_the_kda_state():
+    """tests/test_families.py asks the engine for each; here, what only
+    this family's reasons say."""
+    assert all("KDA state" in reason for feature, reason in REFUSES.items()
+               if feature != "int8_weights")
 
 
 def test_the_engine_serves_the_family_on_its_normal_path(seeded):
@@ -519,30 +499,6 @@ def test_what_a_token_meets_and_what_a_slot_holds():
                          params_nbytes=2 * held, clamp=False)
     assert plan.cache_bytes_max == 256 * 1280 * 4096 \
         + 256 * cfg.state_bytes_per_slot
-
-
-def test_the_front_door_starts_the_family_from_its_preset():
-    """examples/llm-server builds the family's engine from MODEL_PRESET as
-    it builds Llama's, and refuses by name a variable whose field the
-    preset's config does not have."""
-    import gofr_tpu
-    from test_examples import _cfg, _load
-
-    module = _load("llm-server")
-    settings = dict(TPU_PLATFORM="cpu", MODEL_PRESET="kda-moe-debug",
-                    WARMUP="false", MAX_BATCH="2", MAX_SEQ_LEN="128",
-                    PAGE_SIZE="16")
-    with pytest.raises(ValueError, match="kda-moe-debug has no kv_dtype"):
-        module.build_engine(gofr_tpu.App(config=_cfg(**settings,
-                                                     KV_DTYPE="int8")))
-    engine = module.build_engine(gofr_tpu.App(config=_cfg(**settings)))
-    try:
-        assert engine.model.family == "kda_moe"
-        request = engine.submit(engine.tokenizer.encode("hello"),
-                                max_new_tokens=4)
-        assert len(request.result(timeout_s=120)) == 4
-    finally:
-        engine.stop()
 
 
 def test_the_program_and_the_reference_name_the_same_leaves(seeded):

@@ -197,7 +197,10 @@ def test_segments_cpu_tells_working_from_waiting():
     assert rec.segments["emit"] >= 0.25
     assert rec.segments_cpu["emit"] < 0.05
     assert rec.segments_cpu["demux"] >= 0.24
-    assert rec.segments_cpu["demux"] >= 0.5 * rec.segments["demux"]
+    # against the blocked segment, not against its own wall time: what share
+    # of its wall a spinning thread gets is the machine's load, not the
+    # ledger's arithmetic
+    assert rec.segments_cpu["demux"] >= 5 * rec.segments_cpu["emit"]
     assert 0.24 <= rec.cpu_s <= rec.wall_s
     shown = rec.summary()
     assert set(shown["segments_cpu"]) == set(shown["segments"])

@@ -28,10 +28,10 @@ if BENCH not in sys.path:
 
 from harness import data  # noqa: E402
 
-from gofr_tpu.models.mla_moe import (COUNTERS, MlaMoeConfig, REFUSES,  # noqa: E402
+from gofr_tpu.models.experts import ffn_decode, ffn_prefill  # noqa: E402
+from gofr_tpu.models.mla_moe import (COUNTERS, MlaMoeConfig,  # noqa: E402
                                      attention_decode, attention_prefill,
-                                     decode_step, ffn_decode, ffn_prefill,
-                                     mla_moe_init, prefill)
+                                     decode_step, mla_moe_init, prefill)
 from gofr_tpu.ops.flash_attention import (attention_reference,  # noqa: E402
                                           flash_attention)
 from gofr_tpu.ops.mla_read import mla_read, mla_read_reference  # noqa: E402
@@ -442,30 +442,6 @@ def _engine(cfg, params, **kw):
                           **kw)
 
 
-REFUSED = {
-    "prefix_cache": {"prefix_cache": True},
-    "kv_host_tier": {"kv_host_tier_bytes": 1 << 20},
-    "disagg": {"disagg_role": "decode"},
-    "speculative_tokens": {"speculative_tokens": 2},
-    "chunk_prefill_tokens": {"chunk_prefill_tokens": 16},
-    "int8_weights": {},
-    "kv_dtype": {},
-    "mesh": {"mesh": object()},
-}
-
-
-@pytest.mark.parametrize("feature", sorted(REFUSES))
-def test_each_feature_the_family_cannot_serve_is_refused_by_name(feature):
-    cfg = program_config()
-    if feature == "kv_dtype":
-        cfg = type("WithKvDtype", (), {
-            "paged_model": cfg.paged_model, "kv_dtype": "int8"})()
-    params = {"lm_head_s": 0} if feature == "int8_weights" else {}
-    with pytest.raises(ValueError, match=f"mla_moe family refuses "
-                                         f"{feature}="):
-        _engine(cfg, params, **REFUSED[feature])
-
-
 def test_the_engine_serves_the_family_on_its_normal_path(seeded):
     """Admission, page allocator, loop, demux: more requests than slots, so
     slots are reused by prompts of other lengths; every served token is the
@@ -601,30 +577,3 @@ def test_the_debug_preset_builds_and_steps():
     finally:
         engine.stop()
     assert len(out) == 6
-
-
-def test_the_front_door_starts_the_family_from_its_preset():
-    """examples/llm-server builds the family's engine from MODEL_PRESET as
-    it builds Llama's, and refuses by name a variable whose field the
-    preset's config does not have."""
-    import gofr_tpu
-    from test_examples import _cfg, _load
-
-    module = _load("llm-server")
-    settings = dict(TPU_PLATFORM="cpu", MODEL_PRESET="mla-moe-debug",
-                    WARMUP="false", MAX_BATCH="2", MAX_SEQ_LEN="128",
-                    PAGE_SIZE="16")
-    with pytest.raises(ValueError, match="mla-moe-debug has no kv_dtype"):
-        module.build_engine(gofr_tpu.App(config=_cfg(**settings,
-                                                     KV_DTYPE="int8")))
-    with pytest.raises(ValueError, match="mla_moe family has no checkpoint"):
-        module.build_engine(gofr_tpu.App(config=_cfg(**settings,
-                                                     WEIGHT_DTYPE="int8")))
-    engine = module.build_engine(gofr_tpu.App(config=_cfg(**settings)))
-    try:
-        assert engine.model.family == "mla_moe"
-        request = engine.submit(engine.tokenizer.encode("hello"),
-                                max_new_tokens=4)
-        assert len(request.result(timeout_s=120)) == 4
-    finally:
-        engine.stop()

@@ -37,9 +37,10 @@ from harness import data  # noqa: E402
 from test_mla_moe import Served, _follow, _tokens  # noqa: E402
 
 from gofr_tpu.models import mla_moe  # noqa: E402
+from gofr_tpu.models.experts import ffn_decode, ffn_prefill  # noqa: E402
 from gofr_tpu.models.mla_moe import (HC_COUNTERS, MlaMoeConfig,  # noqa: E402
-                                     YarnScaling, decode_step, ffn_decode,
-                                     ffn_prefill, mla_moe_init, prefill)
+                                     YarnScaling, decode_step, mla_moe_init,
+                                     prefill)
 from gofr_tpu.ops import mhc  # noqa: E402
 from gofr_tpu.tpu import capacity  # noqa: E402
 
@@ -270,10 +271,9 @@ def test_one_copy_without_yarn_is_the_program_it_was():
     them (x + F(norm(x)), the scale 192^-0.5 where the kernels keep it)
     give the same jaxpr and the same logits bit for bit, and no leaf, no
     counter and no operation of the mix is in them."""
-    from gofr_tpu.models.llama import _attended_in_block, rms_norm
+    from gofr_tpu.models.blocks import attended_in_block, head, rms_norm
     from gofr_tpu.models.mla_moe import (COUNTERS, attention_decode,
                                          attention_prefill, layer_shapes)
-    from gofr_tpu.models.nemotron_h import _head
     from gofr_tpu.ops.paged_attention import holds_request, plane_tail
 
     cfg = MlaMoeConfig.debug()
@@ -298,11 +298,11 @@ def test_one_copy_without_yarn_is_the_program_it_was():
             x = x + ffn_prefill(rms_norm(x, w["ffn_norm"], cfg.rms_eps), w,
                                 real, cfg)
         last = x[jnp.arange(K), lengths - 1]
-        return _head(last, params, cfg), jnp.stack(latents)
+        return head(last, params, cfg.rms_eps), jnp.stack(latents)
 
     def decode_was(params, tokens, positions, pool, table, tail, step):
         live = holds_request(table)
-        lengths, tail_lens = _attended_in_block(table, positions, step)
+        lengths, tail_lens = attended_in_block(table, positions, step)
         x = params["tok_emb"][tokens]
         counted = jnp.zeros((3,), jnp.int32)
         for layer, w in enumerate(params["layers"]):
@@ -316,7 +316,7 @@ def test_one_copy_without_yarn_is_the_program_it_was():
             x = x + out
         counters = jnp.concatenate([jnp.sum(live, dtype=jnp.int32)[None],
                                     counted])
-        return _head(x, params, cfg), tail, counters
+        return head(x, params, cfg.rms_eps), tail, counters
 
     now = jax.make_jaxpr(lambda p, t, n: prefill(p, cfg, t, n))(
         params, tokens, lengths)

@@ -21,9 +21,9 @@ if BENCH not in sys.path:
 from harness import data  # noqa: E402
 
 from gofr_tpu.models.llama import LlamaConfig, llama_init  # noqa: E402
-from gofr_tpu.models.nemotron_h import (NemotronHConfig, REFUSES,  # noqa: E402
-                                        decode_step, experts_decode,
-                                        experts_prefill, nemotron_h_init,
+from gofr_tpu.models.experts import ffn_decode, ffn_prefill  # noqa: E402
+from gofr_tpu.models.nemotron_h import (NemotronHConfig,  # noqa: E402
+                                        decode_step, nemotron_h_init,
                                         prefill, state_shapes)
 from gofr_tpu.ops.moe_experts import (decode_experts, experts_reference,  # noqa: E402
                                       prefill_experts)
@@ -246,10 +246,10 @@ def test_the_two_shares_of_the_expert_layer_add_up_to_the_whole(seeded):
         for half, held in zip(halves, ((0, 4), (4, 8))):
             want = reference.expert_mixer(x, half, dims, held=held)
             cfg = program_config(held)
-            got, _ = experts_decode(x, half, live, cfg)
+            got, _ = ffn_decode(x, half, live, cfg)
             assert np.abs(np.asarray(got - want)).max() < 1e-5
-            got = experts_prefill(x.reshape(2, 12, 64), half,
-                                  jnp.ones((2, 12), bool), cfg)
+            got = ffn_prefill(x.reshape(2, 12, 64), half,
+                              jnp.ones((2, 12), bool), cfg)
             assert np.abs(np.asarray(got.reshape(24, 64) - want)).max() < 1e-5
 
 
@@ -321,26 +321,6 @@ def _engine(cfg, params, **kw):
                           page_size=16, n_pages=33,
                           prefill_buckets=(16, 32), decode_block_size=4,
                           **kw)
-
-
-REFUSED = {
-    "prefix_cache": {"prefix_cache": True},
-    "kv_host_tier": {"kv_host_tier_bytes": 1 << 20},
-    "disagg": {"disagg_role": "decode"},
-    "speculative_tokens": {"speculative_tokens": 2},
-    "chunk_prefill_tokens": {"chunk_prefill_tokens": 16},
-    "int8_weights": {},
-    "mesh": {"mesh": object()},
-}
-
-
-@pytest.mark.parametrize("feature", sorted(REFUSES))
-def test_each_feature_the_family_cannot_serve_is_refused_by_name(feature):
-    cfg = program_config()
-    params = {"lm_head_s": 0} if feature == "int8_weights" else {}
-    with pytest.raises(ValueError, match=f"nemotron_h family refuses "
-                                         f"{feature}="):
-        _engine(cfg, params, **REFUSED[feature])
 
 
 def test_the_post_hoc_passes_refuse_the_family():
@@ -471,27 +451,3 @@ def test_the_expert_and_vocabulary_shares_have_specs():
     assert specs["lm_head"] == P(None, "ep")
     assert specs["layers"][0]["in_proj"] == specs["layers"][1]["router"] \
         == specs["layers"][1]["shared_w1"] == P()
-
-
-def test_the_front_door_starts_the_family_from_its_preset():
-    """examples/llm-server builds the family's engine from MODEL_PRESET as
-    it builds Llama's, and refuses by name a variable whose field the
-    preset's config does not have."""
-    import gofr_tpu
-    from test_examples import _cfg, _load
-
-    module = _load("llm-server")
-    settings = dict(TPU_PLATFORM="cpu", MODEL_PRESET="nemotron-h-debug",
-                    WARMUP="false", MAX_BATCH="2", MAX_SEQ_LEN="128",
-                    PAGE_SIZE="16")
-    with pytest.raises(ValueError, match="nemotron-h-debug has no kv_dtype"):
-        module.build_engine(gofr_tpu.App(config=_cfg(**settings,
-                                                     KV_DTYPE="int8")))
-    engine = module.build_engine(gofr_tpu.App(config=_cfg(**settings)))
-    try:
-        assert engine.model.family == "nemotron_h"
-        request = engine.submit(engine.tokenizer.encode("hello"),
-                                max_new_tokens=4)
-        assert len(request.result(timeout_s=120)) == 4
-    finally:
-        engine.stop()

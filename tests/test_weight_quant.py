@@ -19,7 +19,7 @@ import jax.numpy as jnp
 from gofr_tpu.models.llama import (
     LlamaConfig,
     _q_matmul,
-    _quantize_leaf,
+    quantize_leaf,
     llama_forward_nocache,
     llama_init,
     llama_init_quantized,
@@ -89,7 +89,7 @@ def test_q_matmul_close_to_dequant_reference():
     x = jax.random.normal(jax.random.PRNGKey(2), (8, 64), dtype=jnp.float32)
     w = jax.random.normal(jax.random.PRNGKey(3), (64, 128),
                           dtype=jnp.float32) * 0.1
-    w8, s = _quantize_leaf(w, -2)
+    w8, s = quantize_leaf(w, -2)
     ref = x @ (w8.astype(jnp.float32) * s[None, :])
     out = _q_matmul(x, w8, s)
     rel = jnp.linalg.norm(ref - out) / jnp.linalg.norm(ref)
