@@ -21,7 +21,8 @@ from __future__ import annotations
 import importlib
 from typing import Callable, Dict
 
-FAMILIES = ("llama", "nemotron_h", "mla_moe", "afmoe", "kda_moe")
+FAMILIES = ("llama", "nemotron_h", "mla_moe", "afmoe", "kda_moe",
+            "sparse_linear")
 
 
 def modules():

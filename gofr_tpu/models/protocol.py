@@ -11,8 +11,13 @@ it:
   engine keeps one pool a plane, [kv_layers, pages, heads, width,
   page_size], and allocates, writes, flushes, plans and reports by this
   answer. `kv_planes(Hkv, dh)` is K and V of grouped-query attention; a
-  family may keep other planes (one narrow latent plane, say). What is
-  said of "the pools" below is a tuple in this order.
+  family may keep other planes (one narrow latent plane, say), and a plane
+  may have a STRIDE: a column every `stride` tokens instead of a value a
+  token ([kv_layers, pages, heads, page_size / stride, width]: the
+  compressed keys of models/sparse_linear.py), written by the prefill's
+  window and flushed from a decode block's tail like the others, a column
+  when the tokens it speaks of are all there. What is said of "the pools"
+  below is a tuple in this order.
 - `groups`: the blocks that keep pages, in PAGE GROUPS, a `PageGroup(name,
   layers, window)` each: blocks that share a table, an allocator and a
   reservation. A page id spans its group's blocks. `window` None, a
@@ -32,7 +37,8 @@ it:
 - `prefill(params, tokens [K, bucket], lengths [K], mesh)` from an empty
   state -> (last real position's logits [K, V] float32, a window
   [group layers, K, heads, width, bucket] a plane a group (group-major,
-  as the pools lie) for the page writer, one [layers, K, ...] array for
+  as the pools lie; [group layers, K, heads, bucket / stride, width] for
+  a plane with a stride) for the page writer, one [layers, K, ...] array for
   each of `state_shapes`: the state as of each row's last real token).
   The engine scatters pages (a window group's last ring of the prompt
   only) and slot states.
@@ -45,7 +51,9 @@ it:
   ONLY here, as the block found them: what the token must keep goes into
   the block's `tail`, one a plane (ops/paged_attention `plane_tail`: the
   engine makes it when the block begins and flushes it into the pages
-  when the block is over), and the read attends the row's pages as of the
+  when the block is over; a plane with a stride has [layers, B, heads,
+  ceil(block / stride), width], the columns the block's steps complete in
+  order), and the read attends the row's pages as of the
   block's start plus the tail's first step + 1 tokens
   (`paged_attention_in_block` and `ops/mla_read` do both). State is
   updated in place. `counters` is
@@ -73,10 +81,41 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 @dataclasses.dataclass(frozen=True)
 class Plane:
-    """One plane of a page: `heads` x `width` values a token a block."""
+    """One plane of a page: `heads` x `width` values a token a block, or,
+    with a `stride`, a COLUMN of `heads` x `width` values for every
+    `stride` tokens: column j says something of the tokens [stride j,
+    stride j + span) and exists once the sequence holds them all."""
     name: str
     heads: int
     width: int
+    stride: int = 1
+    span: int = 1
+
+    def columns(self, tokens):
+        """Columns that exist in a sequence of `tokens` tokens (an int or
+        an array of them): every token's for a plane without a stride."""
+        if self.stride == 1:
+            return tokens
+        short = tokens - self.span + self.stride
+        return short * (short > 0) // self.stride
+
+    def describe(self) -> dict:
+        """What `/debug/engine` shows of the plane: its stride and span
+        only where it has one."""
+        said = dataclasses.asdict(self)
+        return said if self.stride > 1 else {
+            k: said[k] for k in ("name", "heads", "width")}
+
+    def pool_shape(self, layers: int, pages: int, page_size: int) -> tuple:
+        """The pool the engine keeps for this plane in a group of `layers`
+        blocks: token-minor for a plane of a value a token ([.., heads,
+        width, page_size]: ops/paged_attention.py), width-minor for one
+        with a stride ([.., heads, page_size / stride, width]: a column is
+        read and written whole)."""
+        if self.stride == 1:
+            return (layers, pages, self.heads, self.width, page_size)
+        return (layers, pages, self.heads, page_size // self.stride,
+                self.width)
 
 
 def kv_planes(heads: int, width: int) -> Tuple[Plane, Plane]:
@@ -133,8 +172,9 @@ class PagedModel:
 
     @property
     def plane_values(self) -> int:
-        """Values a token keeps in ONE block's pages, all planes."""
-        return sum(p.heads * p.width for p in self.planes)
+        """Values a token keeps in ONE block's pages, all planes (a plane
+        with a stride its share of a column)."""
+        return sum(p.heads * p.width // p.stride for p in self.planes)
 
     @property
     def token_values(self) -> int:

@@ -77,6 +77,23 @@ alike, and the kernel is named `window_read`; the flush finds a token's
 page through the ring too. A read without a bound is the kernel it was, to
 the instruction.
 
+A BLOCK-SPARSE read (models/sparse_linear.py through
+ops/sparse_attention.py `sparse_read`) is the same kernel too, told one more
+scalar a (row, listed page): the row's table is the LIST of the pages that
+hold a block it chose, and a bit a block of `sub_block` tokens says which of
+a listed page's blocks it attends; the rest of the page is masked as tokens
+past a row's length are, and the kernel is named `sparse_read`. Each (row,
+KV head) is a row of the kernel there (the pools seen a head a page), since
+the KV heads choose apart.
+
+A plane with a STRIDE (models/protocol.py `Plane.stride`: a column of
+heads x width values every `stride` tokens, the compressed keys of that
+family) lies width-minor, [L, P, heads, page_size / stride, width], and is
+read and written a whole column at a time by plain gathers and scatters
+(`column_tail`, `flush_columns`, `paged_write_columns`): a column's window
+is the storage layout's own minor dim, so none of what the paragraphs above
+say of token-minor pages applies to it.
+
 The XLA `paged_attention_reference` (gather-based) is the numerics oracle.
 """
 
@@ -216,7 +233,7 @@ def fold_branch(live, fold: int):
 
 def _paged_kernel(layer_ref, table_ref, len_ref, *refs, scale: float,
                   quantized: bool, tailed: bool, fold: int,
-                  value_width=None, ring=None):
+                  value_width=None, ring=None, sub_block=None):
     """One grid step = one row b: stream the row's live pages (ALL heads of
     a page at a time) through two VMEM buffers a pool and fold them into
     the online softmax, the dots batched over the KV heads.
@@ -282,6 +299,7 @@ def _paged_kernel(layer_ref, table_ref, len_ref, *refs, scale: float,
     tail_len_ref = refs.pop(0) if tailed else None
     windowed = ring is not None
     lower_ref = refs.pop(0) if windowed else None
+    bits_ref = refs.pop(0) if sub_block else None
     q_ref = refs.pop(0)
     latent = value_width is not None
     news = [refs.pop(0) for _ in range((1 if latent else 2) * tailed)]
@@ -495,10 +513,23 @@ def _paged_kernel(layer_ref, table_ref, len_ref, *refs, scale: float,
         seen = kv_pos < reached
         if windowed:
             seen = jnp.logical_and(seen, kv_pos >= lower_ref[b])
+        if sub_block:
+            # the blocks of each listed page that the row chose
+            # (a row of lanes, broadcast over the heads afterwards)
+            row = (1, 1, s.shape[2])
+            block = jax.lax.broadcasted_iota(jnp.int32, row, 2) // sub_block
+            per = page_size // sub_block
+            chosen = jnp.zeros(row, bool)
+            for c in range(pages):
+                bits = bits_ref[b, f * fold + c]
+                for i in range(per):
+                    chosen = jnp.logical_or(chosen, jnp.logical_and(
+                        block == c * per + i, (bits >> i) & 1 == 1))
+            seen = jnp.logical_and(seen, chosen)
         s = jnp.where(seen, s, DEFAULT_MASK_VALUE)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         pr = jnp.exp(s - m_new)
-        if windowed:
+        if windowed or sub_block:
             # a fold may hold no token the row still sees (its bound lies
             # in the tail): exp(mask - mask) is 1.0, not 0.0
             pr = jnp.where(seen, pr, 0.0)
@@ -652,14 +683,16 @@ def paged_attention_in_block(q, k, v, k_pool, v_pool, k_tail, v_tail, table,
 
 def _paged_read(q, pools, table, lengths, block, layer, mesh, interpret, *,
                 value_width=None, scale=None, scope: str = "paged_read",
-                lower=None, ring=None):
+                lower=None, ring=None, sub=None):
     """The reads' one call. pools: (k, v[, k_scale, v_scale]); block: None
     or (k, v, k_tail, v_tail, tail_lens) of `paged_attention_in_block`.
     With `value_width` (ops/mla_read.py) the one pool of a one-plane page,
     block (new, tail, tail_lens), the scores scaled by `scale` and the
     kernel named `scope`. `lower` [B] int32: the first position each row
     still sees, and `ring` the columns of the ring its table holds: given
-    together."""
+    together. `sub` (bits [B, NP] int32, the tokens of a block): the table
+    lists the pages each row chose and bit i of bits[b, j] says whether it
+    attends block i of its j-th listed page."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -673,8 +706,9 @@ def _paged_read(q, pools, table, lengths, block, layer, mesh, interpret, *,
         news, tail_lens = list(block[:m]), [block[2 * m]]
         tails = [_stacked(tail, layer) for tail in block[m:2 * m]]
 
-    if _tp(mesh) and lower is not None:
-        raise NotImplementedError("no windowed read under a tp mesh yet")
+    if _tp(mesh) and (lower is not None or sub is not None):
+        raise NotImplementedError(
+            "no windowed or block-sparse read under a tp mesh yet")
     if _tp(mesh):
         from jax.sharding import PartitionSpec
 
@@ -716,6 +750,9 @@ def _paged_read(q, pools, table, lengths, block, layer, mesh, interpret, *,
     if lower is not None:
         scalars.append(lower)
         static.update(ring=int(ring))
+    if sub is not None:
+        scalars.append(sub[0])
+        static.update(sub_block=int(sub[1]))
     kernel = functools.partial(_paged_kernel, **static)
 
     def row_index(b, *scalars):
@@ -728,7 +765,7 @@ def _paged_read(q, pools, table, lengths, block, layer, mesh, interpret, *,
     q_row = pl.BlockSpec((1, Hkv, G, dh), row_index)
     out_row = pl.BlockSpec((1, Hkv, G, dv), row_index)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=len(scalars),  # layer, table, lengths[, tail][, lower]
+        num_scalar_prefetch=len(scalars),  # layer, table, lengths[, tail][, lower][, bits]
         grid=(B,),
         in_specs=[q_row]
         + [pl.BlockSpec((1,) + new.shape[1:], row_index) for new in news]
@@ -1152,6 +1189,69 @@ def _flush_columns(pools, tails, table, starts, counts, ring=None):
             jnp.transpose(tail[..., :pool.shape[3]], (1, 3, 0, 2, 4)),
             mode="drop")
         for pool, tail in zip(pools, tails))
+
+
+# -- a plane with a stride ----------------------------------------------------
+# models/protocol.py `Plane.stride`: a COLUMN of heads x width values for
+# every `stride` tokens, the pool [L, P, heads, page_size / stride, width],
+# width-minor: a column is read whole (a gather through the page table) and
+# written whole, so its writes are plain scatters whose window is the
+# storage layout's own minor dim, and nothing here needs a kernel.
+def column_tail(pool, rows: int, columns: int):
+    """A strided plane's tail: zeros [L, rows, heads, columns, width], the
+    columns a decode block's steps complete, in order."""
+    L, _, heads, _, width = pool.shape
+    return jnp.zeros((L, rows, heads, columns, width), pool.dtype)
+
+
+def flush_columns(pool, tail, table, starts, counts):
+    """Put the columns a decode block completed into the pages, in place:
+    column i < counts[b] of row b's tail is column starts[b] + i of the row,
+    i.e. column (starts[b] + i) % cols of page table[b, (starts[b] + i) //
+    cols]. pool [L, P, heads, cols, width]; tail [L, B, heads, n, width];
+    table [B, NP]; starts, counts [B] int32. A scatter a (block, head): its
+    window is one column's [width]."""
+    L, P, heads, cols, _ = pool.shape
+    n = tail.shape[3]
+    column = starts[:, None] + jnp.arange(n, dtype=jnp.int32)[None, :]
+    pages = jnp.take_along_axis(
+        table, jnp.clip(column // cols, 0, table.shape[1] - 1), axis=1)
+    held = jnp.arange(n)[None, :] < counts[:, None]
+    pages = jnp.where(held, pages, P)                         # P: dropped
+    for layer in range(L):
+        for head in range(heads):
+            pool = pool.at[layer, pages, head, column % cols].set(
+                tail[layer, :, head], mode="drop")
+    return pool
+
+
+def paged_write_columns(pool, window, table, counts):
+    """Write a prefill window's columns into a strided plane's pool as
+    WHOLE pages. pool [L, P, heads, cols, width]; window [L, K, heads, n,
+    width], the columns 0 .. n - 1 of row k from the start of its prompt;
+    table [K, NP]; counts [K] the columns that exist. A column past its
+    row's count is written as zeros, and a page wholly past it diverts to
+    the garbage page, as `paged_write_window` has it."""
+    L, _, heads, cols, width = pool.shape
+    K, n = window.shape[1], window.shape[3]
+    n_src = -(-n // cols)
+    if n_src * cols != n:
+        window = jnp.pad(window, ((0, 0),) * 3 + ((0, n_src * cols - n),
+                                                   (0, 0)))
+    at = jnp.arange(n_src * cols, dtype=jnp.int32)
+    live = (at[None, :] < counts[:, None])[None, :, None, :, None]
+    window = jnp.where(live, window, jnp.zeros((), window.dtype))
+    # [L, K, heads, n_src, cols, width] -> [L, K * n_src, heads, cols, width]
+    pages = window.reshape(L, K, heads, n_src, cols, width)
+    pages = jnp.moveaxis(pages, 3, 2).reshape(L, K * n_src, heads, cols,
+                                              width)
+    slot = jnp.arange(n_src, dtype=jnp.int32)[None, :]
+    page_ids = jnp.take_along_axis(
+        table, jnp.clip(jnp.broadcast_to(slot, (K, n_src)), 0,
+                        table.shape[1] - 1), axis=1)
+    page_ids = jnp.where(slot * cols < counts[:, None], page_ids,
+                         jnp.int32(0)).reshape(K * n_src)
+    return pool.at[:, page_ids].set(_row_major(pages.astype(pool.dtype)))
 
 
 def _unstack(pools, stacked: bool):

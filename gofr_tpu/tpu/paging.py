@@ -44,15 +44,15 @@ consults before committing work.
 
 from __future__ import annotations
 
-import dataclasses
 import math
 from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
 from ..models.llama import LlamaConfig, llama_prefill_last
-from ..ops.paged_attention import (flush_planes, fold_branch, fold_of,
-                                   fold_widths, holds_request,
+from ..ops.paged_attention import (column_tail, flush_columns, flush_planes,
+                                   fold_branch, fold_of, fold_widths,
+                                   holds_request, paged_write_columns,
                                    paged_write_prefill_scales,
                                    paged_write_prefill_stacked,
                                    paged_write_window, plane_tail,
@@ -297,8 +297,9 @@ class PagedLLMEngine(LLMEngine):
                     f"page pool of {n_pages} pages ({pool_bytes >> 20} MiB) "
                     f"does not fit the budget: params + pool + prefill temps "
                     f"= {need >> 20} MiB > {usable >> 20} MiB usable")
-        self.pools = [jnp.zeros((group.layers, allocator.n_pages,
-                                 plane.heads, plane.width, ps), dtype=dt)
+        self.pools = [jnp.zeros(plane.pool_shape(group.layers,
+                                                 allocator.n_pages, ps),
+                                dtype=dt)
                       for group, allocator in zip(model.groups,
                                                   self.allocators)
                       for plane in model.planes]
@@ -984,9 +985,15 @@ class PagedLLMEngine(LLMEngine):
             # scatter the window into pages: token t of row k goes to
             # (ptable[k, t // ps], t % ps); pad junk past lengths[k] is
             # redirected to the garbage page so live pages stay clean
+            # (a plane with a stride: the columns that exist, whole pages
+            # of them, width-minor)
             starts = jnp.zeros_like(lengths)
             pools = [paged_write_window(pool, window, ptables[i // planes],
                                         starts, lengths)
+                     if model.planes[i % planes].stride == 1
+                     else paged_write_columns(
+                         pool, window, ptables[i // planes],
+                         model.planes[i % planes].columns(lengths))
                      for i, (pool, window) in enumerate(zip(pools, windows))]
             # a slot's state is written whole, in place (donated)
             state = tuple(held.at[:, slots].set(row.astype(held.dtype))
@@ -1108,8 +1115,13 @@ class PagedLLMEngine(LLMEngine):
                 nxt, rng = sample_tokens(logits, rng, temps, top_k=top_k)
                 return (tail, held, nxt, pos + 1, rng), (nxt, counted)
 
-            tail = tuple(plane_tail(pool, tokens.shape[0], block, mesh)
-                         for pool in pools)
+            strided = [plane.stride > 1 for plane in model.planes] * g
+            tail = tuple(
+                column_tail(pool, tokens.shape[0],
+                            -(-block // model.planes[i % planes].stride))
+                if strided[i]
+                else plane_tail(pool, tokens.shape[0], block, mesh)
+                for i, pool in enumerate(pools))
             (tail, state, tok, pos, rng), (out, counted) = jax.lax.scan(
                 step, (tail, tuple(state), tokens, positions, rng),
                 jnp.arange(block, dtype=jnp.int32))
@@ -1117,12 +1129,24 @@ class PagedLLMEngine(LLMEngine):
             # its ring)
             flushed = []
             rings = [group.ring(pools[0].shape[-1]) for group in model.groups]
+            # (the planes of a value a token in one call; a plane with a
+            # stride the columns its block completed, a scatter of its own)
             for i, (table, ring) in enumerate(zip(tables, rings)):
-                mine = slice(i * planes, (i + 1) * planes)
-                flushed += flush_planes(
-                    pools[mine], tail[mine], table, positions,
-                    jnp.where(holds_request(table), block, 0), mesh=mesh,
-                    ring=ring)
+                mine = range(i * planes, (i + 1) * planes)
+                live = holds_request(table)
+                dense = [j for j in mine if not strided[j]]
+                written = dict(zip(dense, flush_planes(
+                    [pools[j] for j in dense], [tail[j] for j in dense],
+                    table, positions, jnp.where(live, block, 0), mesh=mesh,
+                    ring=ring)))
+                for j in set(mine) - set(dense):
+                    plane = model.planes[j % planes]
+                    start = plane.columns(positions)
+                    written[j] = flush_columns(
+                        pools[j], tail[j], table, start, jnp.where(
+                            live, plane.columns(positions + block) - start,
+                            0))
+                flushed += [written[j] for j in mine]
             pools = [_pin_standard_layout(pool) for pool in flushed]
             out = out.T
             if counted is not None:
@@ -1925,9 +1949,12 @@ class PagedLLMEngine(LLMEngine):
         (`_folds`). The int8 pools have no tail: step t attends the t + 1
         tokens written so far too."""
         planes = len(self.model.planes)
+        # (the planes a read walks: one with a stride is not among them)
         pools = ([self.k_cache, self.v_cache, self.k_scale, self.v_scale]
-                 if self._q8 else self.pools[self._primary * planes:
-                                             (self._primary + 1) * planes])
+                 if self._q8 else [
+                     self.pools[self._primary * planes + i]
+                     for i, plane in enumerate(self.model.planes)
+                     if plane.stride == 1])
         c, ps = fold_of(pools, n_table, self.mesh), self.page_size
         tokens = np.asarray([self.slots[i].length for i, _ in live],
                             np.int64)[:, None]
@@ -2019,8 +2046,7 @@ class PagedLLMEngine(LLMEngine):
         counts = dict(zip(model.counters, self.model_counts.tolist()))
         return {"family": model.family,
                 "kv_layers": model.kv_layers,
-                "planes": [dataclasses.asdict(plane)
-                           for plane in model.planes],
+                "planes": [plane.describe() for plane in model.planes],
                 "cache_bytes_per_token": (
                     model.token_values * self.pools[0].dtype.itemsize),
                 "cache_bytes_per_sequence": (

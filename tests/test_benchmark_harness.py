@@ -74,7 +74,18 @@ LEFT = {
         "test_a_phi_in_bfloat16_is_not_as_stated",
         "test_a_fault_in_the_program_is_not_correct"),
         "whole runs at --tiny size"),
-    "test_kda_moe": dict.fromkeys((
+    "test_kda_moe": {
+        **dict.fromkeys((
+            "test_the_new_cell_is_correct_at_tiny_size",
+            "test_a_fault_in_the_program_is_not_correct"),
+            "whole runs at --tiny size"),
+        # as test_hostspans' above: it asserts that solar's cell ENDS every
+        # list it is in, so no PR can append a cell beside it, and no PR
+        # but a `benchmark` one may edit it; what it guards is held below
+        # (`test_solars_cell_follows_nemotrons_where_it_reports`)
+        "test_the_cell_reports_what_nemotrons_cell_reports_but_its_kernel":
+            "pins the lists' ends"},
+    "test_sparse_linear": dict.fromkeys((
         "test_the_new_cell_is_correct_at_tiny_size",
         "test_a_fault_in_the_program_is_not_correct"),
         "whole runs at --tiny size"),
@@ -142,13 +153,38 @@ def test_the_span_metrics_are_declared_once_with_their_cells():
         assert cells[name][:len(first)] == first
 
 
+def test_solars_cell_follows_nemotrons_where_it_reports():
+    """What benchmark/tests/test_kda_moe.py's pin is there for, in the form
+    that lets a later PR append: solar's cell is in every list nemotron's
+    decode-closed cell is in (`ssm_update_roofline` apart), after it, and
+    its traffic is decode-closed's at 256 clients."""
+    data = _module("test_hostspans").data
+    nemotron = "nemotron-3-nano-30b-a3b-ep2.decode-closed"
+    solar = "solar-open2-250b-ep8.decode256-closed"
+    bench = data.benchmark_json()
+    for metric in bench["per_layer"] + bench["end_to_end"]:
+        cells = metric.get("workloads")
+        if cells is None or metric["name"] == "ssm_update_roofline":
+            continue
+        if nemotron in cells:
+            assert cells.index(solar) > cells.index(nemotron), metric["name"]
+    assert [w["chips"] for w in bench["workloads"]
+            if w["name"] == solar] == [1]
+    mix = data.load_cell(solar)["mix"]
+    base = data.load_cell("internlm2-1.8b.decode-closed")["mix"]
+    assert (mix["clients"], mix["grid"]) == (256, 256)
+    assert all(mix[k] == base[k] for k in ("prompt_tokens", "output_tokens",
+                                           "ramp", "loop", "sharing"))
+
+
 # -- what a prompt met on the device's queue (ISSUE 37) ------------------------
 EVERY = ["internlm2-1.8b.decode-closed", "internlm2-1.8b.chat-open",
          "nemotron-3-nano-30b-a3b-ep2.decode-closed",
          "joyai-llm-flash-ep8.longprompt-closed",
          "trinity-large-preview-ep8.mixedlen-closed",
          "xing4.0-29b-a4b-ep8.decode-closed",
-         "solar-open2-250b-ep8.decode256-closed"]
+         "solar-open2-250b-ep8.decode256-closed",
+         "minicpm-sala-pp4.longctx-closed"]
 OPEN = ["internlm2-1.8b.chat-open"]
 # metric -> (its cells, what it moves, the loop it reads, its layer)
 QUEUE_METRICS = {

@@ -1021,3 +1021,103 @@ def test_kda_moe_prefill_compiles_with_its_state_in_place(topo, as_tpu):
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= held
     assert mem.temp_size_in_bytes < 3 * GIB
+
+
+# -- the sparse_linear family (ISSUE 46) ---------------------------------------
+def _sparse_linear_programs(topo, slots=64, n_pages=7681):
+    """(config, chips, engine shell, abstract params, pools, state, loop
+    state, rng) of the cell `minicpm-sala-pp4.longctx-closed`: the whole
+    cut, published blocks 9-16 at the published widths, three pools."""
+    from gofr_tpu.models.sparse_linear import (SparseLinearConfig,
+                                               layer_shapes)
+    from gofr_tpu.tpu.paging import PagedLLMEngine
+
+    cfg = dataclasses.replace(SparseLinearConfig.minicpm_sala_pp4(),
+                              attn_impl="flash")
+    chips = Chips(topo, 1)
+    engine = _engine_shell(PagedLLMEngine, cfg, None)
+    params = {
+        "tok_emb": chips.shape((cfg.vocab_size, cfg.dim), jnp.bfloat16),
+        "final_norm": chips.shape((cfg.dim,), jnp.bfloat16),
+        "lm_head": chips.shape((cfg.dim, cfg.vocab_size), jnp.bfloat16),
+        "layers": [{name: chips.shape(shape, jnp.bfloat16)
+                    for name, shape in layer_shapes(cfg, mixer).items()}
+                   for mixer in cfg.mixers]}
+    model = engine.model
+    pools = tuple(chips.shape(plane.pool_shape(model.kv_layers, n_pages,
+                                               PAGE), jnp.bfloat16)
+                  for plane in model.planes)
+    state = tuple(chips.shape(shape, dtype)
+                  for shape, dtype in model.state_shapes(slots))
+    return (cfg, chips, engine, params, pools, state,
+            _loop_state(chips, slots), chips.shape((2,), jnp.uint32))
+
+
+def test_sparse_linear_decode_step_compiles_with_three_pools_in_place(
+        topo, as_tpu):
+    """The cell's decode program shape (64 slots, 7,681 pages, table 128
+    wide) over two sparse and six lightning blocks at the published
+    widths: the three pools (K, V and the compressed keys, a column every
+    16 tokens) and the per-slot state (a 2 MiB matrix a slot a lightning
+    block, 0.8 GB in all) are aliased, no pool-sized or state-sized copy is
+    made, the module is named `jit_decode...`, and the choice, the read and
+    the update are kernels named after their scopes, in the scan's body;
+    one flush of K and V outside it."""
+    from gofr_tpu.tpu.executor import _named_after
+
+    cfg, chips, engine, params, pools, state, loop, rng = \
+        _sparse_linear_programs(topo)
+    compiled = _compile(
+        _named_after(engine._decode_fn_paged(16, 128),
+                     "sparse-linear-paged-decode-x16-NP128"),
+        params, *pools, chips.shape((64, 128), jnp.int32), *loop, rng, *state,
+        donate=(1, 2, 3, 9, 10))
+    assert "HloModule jit_decode__x16_NP128," in compiled.as_text()
+    calls = _kernel_calls(compiled)
+    names = sorted(name.rsplit(".", 1)[0] for name, _, _ in calls)
+    assert names == ["lightning_update"] * 6 + ["paged_write"] + [
+        "sparse_read"] * 2 + ["sparse_select"] * 2
+    bodies = _while_bodies(compiled)
+    assert all((_computation_of(compiled, name) in bodies)
+               == (not name.startswith("paged_write"))
+               for name, _, _ in calls)
+    held = sum(np.prod(a.shape) * a.dtype.itemsize for a in pools + state)
+    assert 2.8e9 < held < 2.9e9
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= held
+    # the gathered compressed keys, the logits and the step's activations;
+    # a copy of a K or V pool would be 1 GB, of the state 0.8
+    assert mem.temp_size_in_bytes < 0.7 * GIB, (
+        f"{mem.temp_size_in_bytes / GIB:.2f} GiB of temporaries: a copy of "
+        f"a pool or of the state")
+
+
+def test_sparse_linear_prefill_compiles_dense_and_chosen(topo, as_tpu):
+    """The cell's widest admission (1 x 12,288): the queries under
+    dense_len run the flash kernel, the 4,096 past it the masked flash
+    kernel over the blocks each chose (a kernel of its own name a sparse
+    block), the lightning blocks the chunkwise form (no kernel of their
+    own, no scan over tokens); the slot's state row and three windows are
+    written into the donated state and pools; the temporaries (a 16,384-wide
+    FFN over 12,288 tokens) fit beside 8.5 GB."""
+    from gofr_tpu.tpu.executor import _named_after
+
+    cfg, chips, engine, params, pools, state, loop, rng = \
+        _sparse_linear_programs(topo)
+    K, bucket = 1, 12288
+    rows = chips.shape((K,), jnp.int32)
+    compiled = _compile(
+        _named_after(engine._prefill_fn(bucket, K),
+                     "sparse-linear-paged-prefill-12288x1"),
+        params, *pools, chips.shape((K, bucket), jnp.int32),
+        chips.shape((K, bucket // PAGE), jnp.int32), rows, rows, *loop,
+        chips.shape((K,), jnp.float32), rng, *state,
+        donate=(1, 2, 3, 8, 9, 10, 13, 14))
+    assert "HloModule jit_prefill__12288x1," in compiled.as_text()
+    names = sorted(name.rsplit(".", 1)[0]
+                   for name, _, _ in _kernel_calls(compiled))
+    assert names == ["flash_prefill"] * 2 + ["sparse_prefill"] * 2
+    held = sum(np.prod(a.shape) * a.dtype.itemsize for a in pools + state)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= held
+    assert mem.temp_size_in_bytes < 3 * GIB
