@@ -17,10 +17,12 @@ The TPU-first shape of the problem (SURVEY.md §5 long-context + §7.5):
     number of compiled programs, and multiple admissions are fused into ONE
     prefill dispatch ([K, bucket] prompts scattered into K slots, first token
     sampled on device) — admission costs one host→device round-trip, not K
-  - the decode program runs `decode_block_size` steps under lax.scan per
-    dispatch, sampling on device each step and returning a [B, M] token
-    block; ALL loop state (current tokens, positions, temperatures, rng,
-    the pools) stays on device between dispatches
+  - the decode program runs a block of steps under lax.scan per dispatch
+    (`decode_block_size`, or half of it where a request waits or the
+    host keeps the device fed at half blocks: `_decode_block_now`),
+    sampling on device each step and returning a [B, M] token block;
+    ALL loop state (current tokens, positions, temperatures, rng, the
+    pools) stays on device between dispatches
   - dispatches are kept in flight; the host syncs the oldest block while
     the device executes the younger ones, so the host↔device round-trip
     and the Python demux loop are overlapped with device compute. How
@@ -858,10 +860,12 @@ class LLMEngine:
         self.decode_syncs_total = 0
         self.dry_syncs_total = 0
         # how many decode entries the loop keeps queued, of the
-        # `pipeline_depth` it may: worked out a turn from the loop's own
-        # turn against an entry's time on the device (tpu/queuedepth.py)
+        # `pipeline_depth` it may, and whether they are full or half
+        # blocks: worked out a turn from the loop's own turn against a
+        # step's time on the device (tpu/queuedepth.py)
         self.queue = QueueDepth(self.pipeline_depth,
-                                mirrored=admission_plane is not None)
+                                mirrored=admission_plane is not None,
+                                block=self.decode_block_size)
         # row-steps the decode blocks and verifies read so far computed
         # (rows of the snapshot x steps), and those of them computed for a
         # row after its request's last token (`_overrun_steps`)
@@ -1569,7 +1573,7 @@ class LLMEngine:
                                 for e in self._inflight):
                             self._dispatch_verify()
                     elif any_active:
-                        self.queue.turn()
+                        self.queue.turn(self._request_waits())
                         while self._room_for_decode():
                             self._dispatch_decode()
                             if self._spec_cooloff > 0:
@@ -2082,9 +2086,10 @@ class LLMEngine:
         """Whether the loop's top-up dispatches one more decode block:
         while the deque holds fewer decode entries than this turn's depth
         (`self.queue.depth_now`, worked out once a turn in `_loop` from
-        the host's turn against an entry's time on the device:
-        tpu/queuedepth.py). `pipeline_depth` is the cap of that depth and,
-        as ever, of the deque's entries of both kinds, but for one thing:
+        the host's turn against the time on the device of the block the
+        turn dispatches: tpu/queuedepth.py). `pipeline_depth` is the cap
+        of that depth and, as ever, of the deque's entries of both kinds,
+        but for one thing:
         a prefill in flight is not a decode block. Under the entries' cap
         alone every admission took a block's place, and a closed loop
         that admits two or three requests a turn and reads one entry a
@@ -2106,25 +2111,34 @@ class LLMEngine:
                 and (len(self._inflight) < self.pipeline_depth
                      or decode < min(2, self.pipeline_depth)))
 
-    def _decode_block_now(self) -> int:
-        """The block a decode entry dispatched now runs: `decode_block_size`
-        steps, or half of it while a request waits to be admitted (parked
-        on the admission heap or still in the submit queue), so that the
-        read it waits behind comes sooner. Chosen at the dispatch, by what
-        waits THEN: `_admit` has usually just drained the queue, so in an
-        open loop most blocks are full ones and a prompt's pickup waits
-        out the block that was running when it arrived (PERF.md,
-        `pickup_wait_p95_ms`); a closed loop whose clients always wait
-        runs half blocks throughout, and pays a flush a block for it.
-        Warm-up compiles these two blocks and no other."""
+    def _request_waits(self) -> bool:
+        """Whether a request waits to be admitted: parked on the
+        admission heap or still in the submit queue."""
         # multi-controller: _pending is leader-local (a submit racing in
         # after this iteration's wave is invisible to followers), so only
         # the mirrored heap may influence the block size — a rank-local
         # block choice would dispatch mismatched SPMD programs
-        if self._admission_heap or (self._plane is None
-                                    and self._pending.qsize()):
-            return max(1, self.decode_block_size // 2)
-        return self.decode_block_size
+        return bool(self._admission_heap or (self._plane is None
+                                             and self._pending.qsize()))
+
+    def _decode_block_now(self) -> int:
+        """The block the decode entry being dispatched runs, counted on
+        `engine.queue` under why (the rule: tpu/queuedepth.py). Half of
+        `decode_block_size` while a request waits, so that the read it
+        waits behind comes sooner, chosen by what waits at the dispatch:
+        a closed loop whose clients always wait runs half blocks
+        throughout. `_admit` has usually just drained the queue, so an
+        open loop under its knee would never see one; there the half
+        block comes from the turn's estimates, whenever the host keeps
+        the device fed at half blocks with room under `pipeline_depth`,
+        so a prompt's pickup waits out the half block that was running
+        when it arrived and its prefill the half block queued behind
+        (PERF.md, `pickup_wait_p95_ms`, `prefill_ahead_steps_mean`); each
+        block pays a flush. `decode_block_size` otherwise: without the
+        estimates, and under an admission plane unless the mirrored heap
+        holds a request. Warm-up compiles these two blocks and no
+        other."""
+        return self.queue.dispatched(self._request_waits())
 
     def _start_d2h(self, *outputs) -> None:
         """Kick off the device->host transfer of dispatch OUTPUTS at
@@ -2383,7 +2397,7 @@ class LLMEngine:
             kv_tokens=sum(self.slots[i].length for i, r in live),
             dispatched_at=started, synced_at=synced,
             sync_wait_s=synced - sync_t0)
-        self.queue.note_read(synced, synced - sync_t0, queued_behind)
+        self.queue.note_read(synced, synced - sync_t0, queued_behind, block)
         # pre-demux deepest context: the lock-step batch's cost driver
         slowest = max(live, key=lambda e: self.slots[e[0]].length,
                       default=(None, None))[1]
@@ -2443,7 +2457,7 @@ class LLMEngine:
             "decode", tokens=emitted,
             slowest_request_id=slowest.id if slowest else None,
             page_writes=page_writes, dry=dry,
-            window_pages=self._window_pages_used())
+            window_pages=self._window_pages_used(), block_steps=block)
         self._obs.hist_n(
             "app_tpu_tpot_seconds", step_s, emitted,
             exemplar=(self._exemplar_of(slowest) if slowest else None))

@@ -2199,7 +2199,7 @@ class PagedLLMEngine(LLMEngine):
         program = self._decode_program_paged(n_table, block)
         snapshot = [(i, slot.request) for i, slot in enumerate(self.slots)
                     if slot.active]
-        self.steps.note_dispatch("decode")
+        self.steps.note_dispatch("decode", steps=block)
         start = _time.monotonic()
         try:
             with self.steps.seg("dispatch"):

@@ -146,7 +146,7 @@ class StepRecord:
                  "segments", "segments_cpu", "cpu_s", "active_slots",
                  "inflight", "inflight_prefill", "depth_now", "queue_depth",
                  "tokens", "page_writes", "window_pages", "dry_sync",
-                 "dispatches",
+                 "block_steps", "dispatches",
                  "slowest_request_id",
                  "straggler", "cause", "baseline_s")
 
@@ -188,6 +188,11 @@ class StepRecord:
         # block queued behind it: the device ran dry through this step's
         # demux and emit (engine._sync_oldest)
         self.dry_sync = False
+        # the steps of the decode block this record read: a full block or
+        # a half one (engine._decode_block_now); 0 where it read none
+        self.block_steps = 0
+        # what the iteration enqueued, by kind; `decode_steps` the steps
+        # of its decode blocks together
         self.dispatches: Dict[str, int] = {}
         self.slowest_request_id: Optional[int] = None
         self.straggler = False
@@ -224,6 +229,8 @@ class StepRecord:
             out["window_pages"] = self.window_pages
         if self.dry_sync:
             out["dry_sync"] = True
+        if self.block_steps:
+            out["block_steps"] = self.block_steps
         if self.dispatches:
             out["dispatches"] = dict(self.dispatches)
         if self.slowest_request_id is not None:
@@ -340,6 +347,7 @@ class StepLedger:
         self._page_writes = 0
         self._window_pages = 0
         self._dry_sync = False
+        self._block_steps = 0
         self._slowest: Optional[int] = None
 
     # -- wiring ---------------------------------------------------------------
@@ -414,6 +422,7 @@ class StepLedger:
         self._page_writes = 0
         self._window_pages = 0
         self._dry_sync = False
+        self._block_steps = 0
         self._slowest = None
 
     class _Seg:
@@ -501,17 +510,22 @@ class StepLedger:
             self._frames[-1][2] += seconds
 
     @loop_only
-    def note_dispatch(self, kind: str) -> None:
+    def note_dispatch(self, kind: str, steps: int = 0) -> None:
+        """One enqueued program of `kind`; `steps`: a decode block's."""
         if self._mine():
             self._dispatches[kind] = self._dispatches.get(kind, 0) + 1
+            if steps:
+                key = kind + "_steps"
+                self._dispatches[key] = self._dispatches.get(key, 0) + steps
 
     @loop_only
     def note_sync(self, kind: str, tokens: int = 0,
                   slowest_request_id: Optional[int] = None,
                   page_writes: int = 0, dry: bool = False,
-                  window_pages: int = 0) -> None:
+                  window_pages: int = 0, block_steps: int = 0) -> None:
         if self._mine():
             self._sync_kind = kind
+            self._block_steps = int(block_steps)
             self._tokens += int(tokens)
             self._page_writes += int(page_writes)
             self._window_pages = max(self._window_pages, int(window_pages))
@@ -592,6 +606,7 @@ class StepLedger:
         rec.page_writes = self._page_writes
         rec.window_pages = self._window_pages
         rec.dry_sync = self._dry_sync
+        rec.block_steps = self._block_steps
         rec.dispatches = dict(self._dispatches)
         rec.slowest_request_id = self._slowest
         with self.between("step_close"):
@@ -671,10 +686,11 @@ class StepLedger:
         for rec in ring:
             agg = summary.setdefault(rec.phase, {
                 "steps": 0, "wall_s": 0.0, "cpu_s": 0.0, "tokens": 0,
-                "idle_gap_s": 0.0, "dry_syncs": 0, "segments": {},
-                "segments_cpu": {}})
+                "idle_gap_s": 0.0, "dry_syncs": 0, "block_steps": 0,
+                "segments": {}, "segments_cpu": {}})
             agg["steps"] += 1
             agg["dry_syncs"] += rec.dry_sync
+            agg["block_steps"] += rec.block_steps
             agg["wall_s"] += rec.wall_s
             agg["cpu_s"] += rec.cpu_s
             agg["tokens"] += rec.tokens

@@ -480,7 +480,8 @@ def _pipeline_counts(engine) -> Dict[str, Any]:
     read with slots still decoding and no decode block queued behind it
     (a dry sync: the device idles through the loop's demux and emit);
     `queue`: how many decode entries the loop keeps queued, of the
-    `pipeline_depth` it may, and from what (tpu/queuedepth.py)."""
+    `pipeline_depth` it may, how many steps each runs, and from what
+    (tpu/queuedepth.py)."""
     kinds = [entry[0] for entry in list(engine._inflight)]
     prefills = kinds.count("prefill")
     syncs, dry = engine.decode_syncs_total, engine.dry_syncs_total

@@ -268,7 +268,9 @@ def _log_dispatches(engine, log):
         return bind(slots_idx, *args, **kwargs)
 
     def decode_logged():
-        log.append(("decode", engine._decode_block_now()))
+        # the block the dispatch is about to choose, not counted twice
+        log.append(("decode",
+                    engine.queue.block(engine._request_waits())[0]))
         return decode()
 
     def sync_logged():
@@ -338,7 +340,7 @@ def test_under_a_plane_the_deque_is_the_caps():
 
     leader, follower, shadows = _pair(InProcKV(), pipeline_depth=4)
     logs = {"leader": [], "follower": []}
-    answers = []
+    answers, blocks = [], []
     for name, engine in (("leader", leader), ("follower", follower)):
         engine.faults = FaultPlane(plan=[
             {"site": "engine.sync", "action": "delay", "delay_s": 0.02,
@@ -351,7 +353,15 @@ def test_under_a_plane_the_deque_is_the_caps():
             answers.append((room(), as_it_was))
             return answers[-1][0]
 
+        def block_logged(engine=engine, block=engine._decode_block_now):
+            as_it_was = (engine.decode_block_size // 2
+                         if engine._admission_heap
+                         else engine.decode_block_size)
+            blocks.append((block(), as_it_was))
+            return blocks[-1][0]
+
         engine._room_for_decode = room_logged
+        engine._decode_block_now = block_logged
     requests = [leader.submit(PROMPTS[0], max_new_tokens=80,
                               temperature=0.0)]
     follower.start()
@@ -368,9 +378,11 @@ def test_under_a_plane_the_deque_is_the_caps():
         leader.stop()
         follower.stop()
     assert len(answers) > 40 and all(got == was for got, was in answers)
+    assert len(blocks) > 20 and all(got == was for got, was in blocks)
     for engine in (leader, follower):
         shown = engine.queue.snapshot()
         assert shown["depth_now"] == 4 and shown["shallow_share"] == 0.0
         assert shown["turns_by_depth"][4] > 10
+        assert shown["blocks"]["half_host_room"] == 0 < shown["blocks"]["full"]
     m = len(logs["follower"])
     assert m > 20 and logs["follower"] == logs["leader"][:m]

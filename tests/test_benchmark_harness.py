@@ -192,6 +192,8 @@ QUEUE_METRICS = {
     "prefill_ahead_steps_mean": (OPEN, "ttft_p95_ms", "open",
                                  "step programs"),
     "decode_overrun_share_pct": (EVERY, "out_tok_s", None, "engine loop"),
+    # how long a decode block is (ISSUE 47)
+    "decode_block_steps_mean": (OPEN, "ttft_p95_ms", "open", "engine loop"),
     "prefill_dev_ms_per_call": (EVERY, "out_tok_s", None, "step programs"),
     "prefill_dev_share_pct": (EVERY, "out_tok_s", None, "step programs"),
 }
@@ -214,9 +216,26 @@ def _queue_run(loop="open"):
         "jit_prefill__llama_paged_prefix_128x4_NP8": {"busy_s": 0.2,
                                                       "count": 10},
         "jit_decode__x16_NP16": {"busy_s": 4.4, "count": 40}}}
+    # ten turns of a full block and thirty of a half, two of them with a
+    # second block enqueued, and the prefill reads between them
+    steps = ([{"phase": "decode",
+               "dispatches": {"decode": 1, "decode_steps": 16}}] * 10
+             + [{"phase": "decode",
+                 "dispatches": {"decode": 1, "decode_steps": 8}}] * 28
+             + [{"phase": "decode", "dispatches": {
+                 "decode": 2, "decode_steps": 16, "prefill": 1}}] * 2
+             + [{"phase": "prefill", "dispatches": {}}] * 5)
     return {"result": {"records": records}, "requests": requests,
             "loaded": {"mix": {"loop": loop}}, "t_open": 0.0,
-            "t_close": 100.0, "steps": [], "trace": trace}
+            "t_close": 100.0, "steps": steps, "trace": trace}
+
+
+def _without_block_steps(run):
+    """The parent's step records: the blocks, not their steps."""
+    return {**run, "steps": [
+        {**row, "dispatches": {k: v for k, v in row["dispatches"].items()
+                               if k != "decode_steps"}}
+        for row in run["steps"]]}
 
 
 def _without(run, *keys):
@@ -234,6 +253,8 @@ def _without(run, *keys):
     # 10 of 40 + 10 row-steps a request
     ("decode_overrun_share_pct", 20.0,
      lambda run: _without(run, "overrun_steps")),
+    # 10 x 16 + 28 x 8 + 2 x 16 steps in 10 + 28 + 4 blocks
+    ("decode_block_steps_mean", 416 / 42, _without_block_steps),
     # 0.5 s in 40 calls; of a 5 s window
     ("prefill_dev_ms_per_call", 12.5, lambda run: {**run, "trace": None}),
     ("prefill_dev_share_pct", 10.0, lambda run: {**run, "trace": None}),
