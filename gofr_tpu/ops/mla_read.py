@@ -31,7 +31,10 @@ that covers its live ones (no less than a quarter of the fold), because with 32 
 copies hide that (800 us still); at rows of 1-9 pages (xing's
 `decode-closed`: 4.2 a row) a call went from 156.4 to 143.3 us, 1.68 to
 1.54 us a row, of which 1.1 is the row's own chain of waits whatever its
-width (`only=short`).
+width (`only=short`). That chain is what PR 48 works on: a grid step of
+the kernel walks several consecutive rows (`rows_a_step`: four of these),
+so a row pays no grid step of its own, and a row of one fold takes its
+tail and its fold in ONE softmax step (`_paged_kernel` `short_row`).
 
 `mla_read_reference` (gather-based) is the numerics oracle.
 """

@@ -16,26 +16,35 @@ materialized gathered cache (an XLA gather would copy the whole live cache
 every step). Online softmax (m, l, acc) carries in f32 across a row's
 pages, exactly like ops/flash_attention's streaming kernel.
 
-Grid: (B,), ONE STEP A ROW, sequential. Inside it a loop over the row's
-ceil(length / page_size) live pages and no others, each page ALL Hkv heads
-at once (the dots batch over the KV heads), a FOLD of C consecutive pages
-a turn (`pages_per_fold`: C from the page's bytes and the table's
-width), double-buffered: fold i + 1 is in flight while fold i
-goes into the softmax. The walk is by row because a grid
-over (row, table column) pays for every column of the table, live or not
-(on the v5e about half a microsecond each, which at a table a quarter
-full was two thirds of the kernel's time); by row, time follows the live
-tokens. A row is only a few folds, so a DMA queue that drained at every
-row boundary would idle for a large part of each row: before a row folds
-its last fold it starts the first fold of the next row that has one, and
-which buffer that is carries over in SMEM scratch. Table entries past a
-row's live pages are never read. A fold's WIDTH is what it copied: every
+Grid: (B / R,), ONE STEP A GROUP OF R CONSECUTIVE ROWS, sequential
+(`rows_a_step`: R from the bytes of a fold, whose buffers are kept for two
+groups, and from B, which it divides; 4 at the benchmark's shapes). A row
+is walked over its ceil(length / page_size) live pages and no others, each
+page ALL Hkv heads at once (the dots batch over the KV heads), a FOLD of C
+consecutive pages a softmax step (`pages_per_fold`: C from the page's bytes
+and the table's width). The walk is by row because a grid over (row, table
+column) pays for every column of the table, live or not (on the v5e about
+half a microsecond each, which at a table a quarter full was two thirds of
+the kernel's time); by row, time follows the live tokens. It is by SEVERAL
+rows a step because a grid step is paid whatever its row holds (its blocks
+handed over, its bookkeeping: a third of a microsecond), and a row of one
+fold is little else: at xing's rows (1-9 latent pages of 144 KiB) a row's
+own chain was 1.08 us beside 0.071 us a page computed. A group's first
+folds, and its rows' tails, are started by the group before, so the DMA
+queue never drains between rows. Where every row of a group holds ONE fold
+at most, the step takes them in turn with a row's tail and its fold in ONE
+softmax step (`short_row`: no carry, no rescale, nothing handed from row to
+row); a group that holds a longer row walks row by row (`walk_row`): the
+tail first, then a loop over the row's folds, double-buffered (fold i + 1
+is in flight while fold i goes into the softmax), and before the group's
+last row folds its last fold it starts the next group's. Table entries past
+a row's live pages are never read. A fold's WIDTH is what it copied: every
 fold of a row but its last holds C pages and is computed over C; the last
 holds 1 to C and is computed over the least power of two of pages that
 covers them, but no less than C / 4 (`fold_branch`: at most C / 2 pages,
-it runs after the loop in a copy of the turn's body that wide, chosen by
-a scalar the kernel already holds), so a row of one page under a fold of
-8 pays for two pages' products, not for eight pages' of masked lanes.
+it runs in a copy of the step's body that wide, chosen by a scalar the
+kernel already holds), so a row of one page under a fold of 8 pays for two
+pages' products, not for eight pages' of masked lanes.
 
 The pool is STACKED over layers ([L, P, Hkv, dh, ps]) and carried whole
 through the step programs' layer loops, so everything here takes the
@@ -194,10 +203,7 @@ def fold_of(pools, table_width: int, mesh=None) -> int:
     """`pages_per_fold` of stacked pools [L, P, heads, ...]: what the read
     of these pools under a table this wide folds a turn. Under a tp `mesh`
     each shard runs the kernel on its own share of the heads."""
-    shards = mesh.shape.get("tp", 1) if mesh is not None else 1
-    page_bytes = sum(math.prod(pool.shape[2:]) * pool.dtype.itemsize
-                     for pool in pools)
-    return pages_per_fold(page_bytes // shards, table_width)
+    return pages_per_fold(_page_bytes(pools, mesh), table_width)
 
 
 # The widths a fold is computed at: C, C / 2 and C / 4, no narrower. Each
@@ -209,6 +215,82 @@ def fold_of(pools, table_width: int, mesh=None) -> int:
 # that cell's decode program from 10 % over the parent's to 16 % (PERF.md
 # section 6, PR 40).
 _FOLD_WIDTHS = 3
+
+
+# What the buffers of the read's folds may take of the 16 MiB of VMEM the
+# compiler gives a kernel unasked (the rest: the blocks of q and of the
+# output, the tails' buffers, what the products keep between them), and the
+# most rows a grid step walks whatever they weigh. At xing's rows (1-9
+# latent pages, v5e, tools/bench_paged_read.py `only=short`) a call takes
+# 122.0 us at one row a step, 118.2 at 2, 118.8 at 3 and 118.2 at 4: past
+# two rows a group's size buys nothing, so the budget is what fits, not a
+# knob (PERF.md section 5, PR 48).
+_GROUP_BYTES = 11 << 20
+_GROUP_ROWS = 8
+# and of its DMA semaphores, one a copy (a page of a pool) a buffer: a core
+# holds 512 for everything
+_GROUP_COPIES = 384
+
+
+def rows_a_step(fold_bytes: int, fold_copies: int, rows: int) -> int:
+    """R, the consecutive rows one grid step of the read walks (see
+    `_paged_kernel`), from what a call can see and nothing else: the
+    bytes of one fold over all the call's pools and the copies that bring
+    it (a page of a pool each; the kernel keeps 2 R + 1 buffers that wide,
+    inside `_GROUP_BYTES`, and a semaphore a copy, inside `_GROUP_COPIES`),
+    and the call's rows, which R divides, so that every group is whole and
+    no block reaches past an array. 1 where nothing else divides them or
+    fits."""
+    return max(r for r in range(1, _GROUP_ROWS + 1)
+               if rows % r == 0 and (r == 1 or (
+                   (2 * r + 1) * fold_bytes <= _GROUP_BYTES
+                   and (2 * r + 1) * fold_copies <= _GROUP_COPIES)))
+
+
+def _page_bytes(pools, mesh=None) -> int:
+    """The bytes of one page over stacked pools [L, P, heads, ...]; under
+    a tp `mesh`, of a shard's share of the heads."""
+    shards = mesh.shape.get("tp", 1) if mesh is not None else 1
+    return sum(math.prod(pool.shape[2:]) * pool.dtype.itemsize
+               for pool in pools) // shards
+
+
+def group_of(pools, table_width: int, rows: int, mesh=None) -> int:
+    """`rows_a_step` of stacked pools [L, P, heads, ...] read under a
+    table this wide by a call of `rows` rows."""
+    fold = fold_of(pools, table_width, mesh)
+    return rows_a_step(fold * _page_bytes(pools, mesh), fold * len(pools),
+                       rows)
+
+
+# Whether a row of one fold takes its tail and its fold in ONE softmax step
+# (`_paged_kernel` `short_row`). Not a setting: tools/bench_paged_read.py
+# stands in for it to time the two steps apart.
+_JOIN_ONE_FOLD = True
+
+
+def _short_groups(lengths, tail_lens, lower, page_size: int, fold: int,
+                  group: int, width: int):
+    """[B / R] int32: which groups of R = `group` consecutive rows take the
+    short rows' step of `_paged_kernel`: those whose rows each hold at
+    most one fold and, if a page at all, a tail to join it (a row that
+    holds no request has neither; one whose tokens are all in its tail
+    still walks: it has no fold to join; a read without tails joins
+    nothing and asks for the one fold alone). From the scalars the kernel
+    is given, by the kernel's own count of a row's pages (`pages_of`:
+    `width` the table's, or the ring's where a row has a `lower` bound),
+    once a call instead of once a grid step and a hand-over."""
+    pages = (lengths + page_size - 1) // page_size
+    if lower is None:
+        pages = jnp.minimum(pages, width)
+    else:
+        pages = jnp.clip(pages - lower // page_size, 0, width)
+    short = pages <= fold
+    if tail_lens is not None:
+        short = jnp.logical_and(short, (pages > 0) == (tail_lens > 0))
+    if not _JOIN_ONE_FOLD:
+        short = jnp.zeros_like(short)
+    return jnp.all(short.reshape(-1, group), axis=1).astype(jnp.int32)
 
 
 def fold_widths(fold: int) -> tuple:
@@ -232,42 +314,69 @@ def fold_branch(live, fold: int):
 
 
 def _paged_kernel(layer_ref, table_ref, len_ref, *refs, scale: float,
-                  quantized: bool, tailed: bool, fold: int,
+                  quantized: bool, tailed: bool, fold: int, group: int,
                   value_width=None, ring=None, sub_block=None):
-    """One grid step = one row b: stream the row's live pages (ALL heads of
-    a page at a time) through two VMEM buffers a pool and fold them into
-    the online softmax, the dots batched over the KV heads.
+    """One grid step = one GROUP of R = `group` consecutive rows
+    (`rows_a_step`): stream each row's live pages (ALL heads of a page at
+    a time) through VMEM buffers and fold them into the online softmax,
+    the dots batched over the KV heads.
 
     A FOLD is `fold` = C consecutive pages of the row (`pages_per_fold`),
     copied side by side into one buffer [Hkv, dh, C x ps] (page c of the
-    fold at lanes [c x ps, (c + 1) x ps)), and one turn of the loop folds
+    fold at lanes [c x ps, (c + 1) x ps)), and one softmax step folds
     one: ONE score product [G, dh] x [dh, C x ps] a head, ONE max / exp /
-    sum / rescale over its C x ps tokens, ONE value product, while the
-    next fold's C copies are in flight. A turn's steps depend on each other
-    (the copy's wait, the MXU's fill and drain, the cross-lane max, the
-    carry (m, l, acc) that chains the turns), so their latencies are paid
-    once a fold, not once a page: they come to about 0.3 us a turn on a v5e,
-    beside which a latent page of 144 KiB is 0.18 us of DMA and a K and V
-    page of 8 heads 0.64 (`_FOLD_BYTES` has the measurements). Only live
-    pages are copied, and a fold is computed as wide as what it copied:
-    w pages, the least of `fold_widths` that covers them (`fold_branch` on
-    `pages_of(b)`, a scalar in SMEM). The folds before a row's last hold
-    C pages each, and so does w for a last fold more than half live: all
-    of those run in the loop, whose turn is computed at C and chooses
-    nothing. A last fold of at most C / 2 pages runs after the loop over
-    the first w x ps lanes of its buffer, in one of the copies of a turn's
-    body kept for C / 2 and C / 4 pages, and ends the row itself (writes the
-    output), so no branch hands the softmax's carry on; at `fold` 1 there
-    is one width and no choice. With 32 queries of a latent page the
-    two products of a full fold are 0.57 us of a one-fold row's 1.65, and a
-    row of two pages is 1.24 (tools/bench_paged_read.py `only=short`). The
-    lanes of a narrowed fold that hold no live page (3 pages are computed
-    at 4, one at 2 under a fold of 8) keep what an earlier fold left
-    there. Their scores are masked
-    (they lie past the row's length) and their probabilities are 0.0, but
-    0.0 x NaN is NaN in the value product: the buffers are zeroed before
-    the first copy of a call, and what a live page holds is finite. A lane
-    that is not computed adds nothing either: the sums lose only zeros.
+    sum / rescale over its C x ps tokens, ONE value product. A step's parts
+    depend on each other (the copy's wait, the MXU's fill and drain, the
+    cross-lane max, the carry (m, l, acc) that chains the steps), so their
+    latencies are paid once a fold, not once a page: they come to about
+    0.3 us a step on a v5e, beside which a latent page of 144 KiB is 0.18 us
+    of DMA and a K and V page of 8 heads 0.64 (`_FOLD_BYTES` has the
+    measurements). Only live pages are copied, and a fold is computed as
+    wide as what it copied: w pages, the least of `fold_widths` that covers
+    them (`fold_branch` on `pages_of(row)`, a scalar in SMEM).
+
+    THE BUFFERS are 2 R + 1 a pool, a fold wide each: row r of a group of
+    parity p (the grid step's, 0 or 1) OWNS buffer p R + r, where its
+    first fold lands, and one SPARE is every row's in turn. What a step
+    does depends on what its rows hold, which the call works out once
+    from the scalars (`_short_groups`: a flag a group, in SMEM):
+
+    SHORT ROWS. Where every row of the group holds at most ONE fold
+    (`pages_of(row) <= C`; a row without a request holds none), all its
+    rows' first folds and tails are in flight when its step begins (what
+    came before started them: `hand_on`). The step starts what the read
+    needs after it at once, then takes its rows in turn (`short_row`): the
+    tail's scores and the fold's lanes go into ONE softmax step (one max,
+    one exp, one sum over both, two value products, no carry and no
+    rescale), computed at the fold's width w, and the output is written;
+    the tails go back together when the last row is done. A row pays no
+    grid step of its own, no hand-over from the row before, and one
+    softmax step instead of two (`tools/bench_paged_read.py only=short`
+    has what each part gave).
+
+    A GROUP THAT HOLDS A LONGER ROW walks row by row as the kernel always
+    did (`walk_row`), each row handing on to the next: the tail folds
+    FIRST (while the row's first fold, started by the row before, and its
+    second, started at once into the spare, stream in: folded last, it
+    would leave the copy queue one fold deep at every row's end), then a
+    loop over the folds, fold f + 1 in flight (in the buffer fold f - 1
+    left) while fold f goes into the softmax at C pages; a last fold of at
+    most C / 2 pages runs after the loop over the first w x ps lanes of its
+    buffer, in one of the copies of a step's body kept for C / 2 and C / 4
+    pages, which ends the row itself (no branch hands the softmax's carry
+    on); at `fold` 1 there is one width and no choice. Before a row waits for its last fold it
+    starts what the read needs next (`hand_on`): the next row's first
+    fold, or from the group's last row the next group's (its first row's
+    alone if that group walks too, every row's if it takes the short rows'
+    step), so the copy queue never drains at a row's end or a group's.
+
+    The lanes of a narrowed fold that hold no live page (3 pages are
+    computed at 4, one at 2 under a fold of 8) keep what an earlier fold
+    left there. Their scores are masked (they lie past the row's length)
+    and their probabilities are 0.0, but 0.0 x NaN is NaN in the value
+    product: the buffers are zeroed before the first copy of a call, and
+    what a live page holds is finite. A lane that is not computed adds
+    nothing either: the sums lose only zeros.
 
     With a `ring` (a window group's, tpu/paging.py) the row attends from a
     LOWER BOUND on (one more scalar a row, `lower`: the first position it
@@ -283,13 +392,14 @@ def _paged_kernel(layer_ref, table_ref, len_ref, *refs, scale: float,
     a token's value is the first `value_width` of its key's dh values: one
     pool, one tail, the output [Hkv, G, value_width].
 
-    refs: [tail_len,] [lower (SMEM, with the other scalars),] q, [the row's new k,
-    v [Hkv, 1, dh'],] the n stacked pools left in HBM (k, v[, k_scale,
-    v_scale]), [the two stacked tails left where they are,] o, [the tails
-    again: the outputs alias them,] the pools' n VMEM buffers
-    [2, *page[:-1], C x ps], [the tails' two [2, Hkv, T, dh'],] DMA
-    semaphores [n, 2, C], [the tails' [2, 2] in and [2] out,] and
-    `first_slot` (SMEM: the buffer this row's first fold was started in).
+    refs: [tail_len,] [lower,] [bits,] short (`_short_groups`: which
+    groups take the short rows' step; SMEM, with the other scalars), q
+    [R, Hkv, G, dh], [the rows' new k, v [R, Hkv, 1, dh'],] the n stacked
+    pools left in HBM (k, v[, k_scale, v_scale]), [the two stacked tails
+    left where they are,] o, [the tails again: the outputs alias them,]
+    the pools' n VMEM buffers [2 R + 1, *page[:-1], C x ps], [the tails'
+    two [2 R, Hkv, T, dh'],] DMA semaphores [n, 2 R + 1, C], [the tails'
+    [2, 2 R] in and [2, R] out].
     int8 pages carry per-token scales; dequant FOLDS into the dots (k's
     scale multiplies score rows, v's folds into the probabilities)."""
     from jax.experimental import pallas as pl
@@ -300,6 +410,7 @@ def _paged_kernel(layer_ref, table_ref, len_ref, *refs, scale: float,
     windowed = ring is not None
     lower_ref = refs.pop(0) if windowed else None
     bits_ref = refs.pop(0) if sub_block else None
+    short_ref = refs.pop(0)
     q_ref = refs.pop(0)
     latent = value_width is not None
     news = [refs.pop(0) for _ in range((1 if latent else 2) * tailed)]
@@ -311,19 +422,30 @@ def _paged_kernel(layer_ref, table_ref, len_ref, *refs, scale: float,
     bufs = [refs.pop(0) for _ in range(n)]
     tail_bufs = [refs.pop(0) for _ in news]
     sems = refs.pop(0)
-    tail_sems, put_sems = (refs.pop(0), refs.pop(0)) if tailed else (None,
-                                                                    None)
-    first_slot, = refs
+    tail_sems, put_sems = refs if tailed else (None, None)
     k_buf, v_buf = bufs[0], None if latent else bufs[1]
     ks_buf, vs_buf = bufs[2:] if quantized else (None, None)
 
-    b = pl.program_id(0)
-    last_row = pl.num_programs(0) - 1
+    R = group
+    g = pl.program_id(0)
+    last_group = pl.num_programs(0) - 1
+    base, spare = g * R, 2 * R
     layer = layer_ref[0]
-    length = len_ref[b]
     n_kv, G, dh = q_ref.shape[1:]
     dv = value_width or dh
     page_size = k_buf.shape[-1] // fold
+
+    def own_of(row):
+        """The buffer a row owns: its place in its group, in the half of
+        the buffers its group's parity names."""
+        return row // R % 2 * R + row % R
+
+    def each_row(body):
+        """`body(r)` for the R rows of a group: one copy of it, whatever
+        R is."""
+        if R == 1:
+            return body(0)
+        jax.lax.fori_loop(0, R, lambda r, _: body(r) or 0, 0)
 
     def first_page(row):
         """The page a row's walk starts at: the one its lower bound is
@@ -343,159 +465,154 @@ def _paged_kernel(layer_ref, table_ref, len_ref, *refs, scale: float,
             return walked
         return (first_page(row) + walked) % ring
 
-    n_pages = pages_of(b)
-    n_folds = (n_pages + fold - 1) // fold
-
     def each_copy(row, f, slot, do):
         """Start or wait for the copies of fold f of `row` into buffer
         `slot`: its first page, which a fold always has, and those of the
         C - 1 after it that are live."""
-        live = pages_of(row)
-        for c in range(fold):
-            def _page(c=c):
-                page = table_ref[row, column(row, f * fold + c)]
-                for j, (pool, buf) in enumerate(zip(pools, bufs)):
-                    window = (slot,) + (slice(None),) * (buf.ndim - 2) + (
-                        pl.ds(c * page_size, page_size),)
-                    do(pltpu.make_async_copy(
-                        pool.at[layer, page], buf.at[window],
-                        sems.at[j, slot, c]))
+        def _page(c, _):
+            page = table_ref[row, column(row, f * fold + c)]
+            lane = pl.multiple_of(c * page_size, page_size)
+            for j, (pool, buf) in enumerate(zip(pools, bufs)):
+                window = (slot,) + (slice(None),) * (buf.ndim - 2) + (
+                    pl.ds(lane, page_size),)
+                do(pltpu.make_async_copy(
+                    pool.at[layer, page], buf.at[window],
+                    sems.at[j, slot, c]))
+            return 0
 
-            if c:
-                pl.when(f * fold + c < live)(_page)
-            else:
-                _page()
+        jax.lax.fori_loop(
+            0, jnp.clip(pages_of(row) - f * fold, 1, fold), _page, 0)
 
     def start_fold(row, f, slot):
         each_copy(row, f, slot, lambda copy: copy.start())
 
-    def start_first_fold_after(row, slot):
-        # the next row that HAS a page: a row of length 0 owns none
-        def live_or_end(r):
-            if windowed:    # a bound past a row's pages leaves it none
-                return jnp.logical_or(
-                    r > last_row, pages_of(jnp.minimum(r, last_row)) > 0)
-            return jnp.logical_or(r > last_row,
-                                  len_ref[jnp.minimum(r, last_row)] > 0)
+    def wait_fold(row, f, slot):
+        each_copy(row, f, slot, lambda copy: copy.wait())
 
-        nxt, _ = jax.lax.while_loop(
-            lambda c: jnp.logical_not(c[1]),
-            lambda c: (c[0] + 1, live_or_end(c[0] + 1)),
-            (row + 1, live_or_end(row + 1)))
+    def tail_copies(row, slot):
+        return [pltpu.make_async_copy(
+            tail.at[layer, row], buf.at[slot], tail_sems.at[j, slot])
+            for j, (tail, buf) in enumerate(zip(tails, tail_bufs))]
 
-        @pl.when(nxt <= last_row)
-        def _start():
-            start_fold(nxt, 0, slot)
-
-    @pl.when(b == 0)
-    def _first_row():
-        if fold > 1:
-            # lanes a short fold leaves uncopied are read (masked): never
-            # whatever VMEM held
-            for buf in bufs:
-                buf[...] = jnp.zeros(buf.shape, buf.dtype)
-        first_slot[0] = 0
-        start_first_fold_after(-1, 0)
-
-    q = q_ref[0]                                          # [Hkv, G, dh]
-    slot0 = first_slot[0]
-    folded = (jnp.full((n_kv, G, 1), DEFAULT_MASK_VALUE, jnp.float32),
-              jnp.zeros((n_kv, G, 1), jnp.float32),
-              jnp.zeros((n_kv, G, dv), jnp.float32))
-
-    if tailed:
-        # The tail folds FIRST, while the row's first fold (started by the
-        # row before) and its second (started here) stream in: folded last,
-        # it would leave the copy queue one fold deep at every row's end.
-        # Its own copy was started by the row before, into the buffer of
-        # this row's parity, so nothing waits for it either.
-        tail_len = tail_len_ref[b]
-
-        def tail_copies(row):
-            return [pltpu.make_async_copy(
-                tail.at[layer, row], buf.at[row % 2], tail_sems.at[j, row % 2])
-                for j, (tail, buf) in enumerate(zip(tails, tail_bufs))]
-
-        put_copies = [
-            pltpu.make_async_copy(buf.at[b % 2], tail.at[layer, b],
-                                  put_sems.at[j])
+    def put_copies(row, r, slot):
+        return [pltpu.make_async_copy(
+            buf.at[slot], tail.at[layer, row], put_sems.at[j, r])
             for j, (tail, buf) in enumerate(zip(tails_out, tail_bufs))]
 
-        def start_tail(row):
-            @pl.when(tail_len_ref[jnp.minimum(row, last_row)] > 0)
-            def _start():
-                for copy in tail_copies(row):
-                    copy.start()
-
-        @pl.when(b == 0)
-        def _first_tail():
-            start_tail(b)
-
-        @pl.when(n_folds > 1)
-        def _second_fold():
-            start_fold(b, 1, 1 - slot0)
-
-        @pl.when(b < last_row)
-        def _next_tail():
-            start_tail(b + 1)
-
-        def fold_tail(carry):
-            m_prev, l_prev, acc = carry
-            for copy in tail_copies(b):
-                copy.wait()
-            # the step's token joins the tail: here, for this row's read,
-            # and where the tail lives, for the steps to come (the copy
-            # back runs under the page loop; a whole tail, since one
-            # token's row of a packed tile cannot be copied alone)
-            token = jax.lax.broadcasted_iota(
-                jnp.int32, tail_bufs[0].shape[1:], 1)
-            for buf, new in zip(tail_bufs, news):
-                buf[b % 2] = jnp.where(token == tail_len - 1, new[0],
-                                       buf[b % 2])
-            for copy in put_copies:
+    def start_tail(row):
+        @pl.when(tail_len_ref[row] > 0)
+        def _start():
+            for copy in tail_copies(row, own_of(row)):
                 copy.start()
-            # the tail is token-major a head ([Hkv, T, dh], dh on lanes,
-            # less the lanes that pad a narrower head): its scores are the
-            # plain q . k^T, batched over the KV heads
-            k = tail_bufs[0][b % 2][:, :, :dh]
-            v = k[:, :, :dv] if latent else tail_bufs[1][b % 2][:, :, :dh]
-            s = scale * jax.lax.dot_general(
-                q, k, (((2,), (2,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32)
-            held = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-                    < tail_len)
-            if windowed:
-                # tail token i is at position length + i
-                held = jnp.logical_and(
-                    held, jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-                    >= lower_ref[b] - length)
-            m_new = jnp.maximum(m_prev, jnp.max(
-                jnp.where(held, s, DEFAULT_MASK_VALUE), axis=-1,
-                keepdims=True))
-            pr = jnp.where(held, jnp.exp(s - m_new), 0.0)
-            alpha = jnp.exp(m_prev - m_new)
-            pv = jax.lax.dot_general(pr.astype(v.dtype), v,
-                                     (((2,), (1,)), ((0,), (0,))),
-                                     preferred_element_type=jnp.float32)
-            return (m_new,
-                    l_prev * alpha + jnp.sum(pr, axis=-1, keepdims=True),
-                    acc * alpha + pv)
 
-        # a row that holds no request has no tail either: it reads nothing
-        folded = jax.lax.cond(tail_len > 0, fold_tail, lambda c: c, folded)
+    def hand_on(row):
+        """What the read needs first after `row`, started into the buffers
+        of the rows it belongs to: inside a group that walks, the next
+        row's first fold (its tail is under way: `walk_row`); at a group's
+        end, the next group's first row's first fold and tail or, where
+        that group takes the short rows' step, all its rows'. A row of
+        length 0 owns no page, a bound past a row's pages leaves it none,
+        and a row that holds no request has no tail either."""
+        first = row + 1
+        new_group = first % R == 0
+        # (the last group's flag again where there is no next)
+        count = jnp.where(jnp.logical_and(new_group, short_ref[
+            jnp.minimum(first // R, last_group)] > 0), R, 1)
 
-    # tokens the walk reaches: it stops at the table's width whatever the
-    # length says, and a fold's lanes past them were not copied this turn
-    reached = jnp.minimum(length, n_pages * page_size)
-    if windowed:
-        # the walk starts at the page the row's lower bound is in
-        walk_from = first_page(b) * page_size
-        reached = jnp.minimum(length, walk_from + n_pages * page_size)
+        def _row(i, _):
+            nxt = first + i
 
-    def fold_lanes(f, slot, pages: int, carry):
-        """Fold the first `pages` pages' lanes of buffer `slot`, which
-        holds fold f of the row, into the softmax."""
-        m_prev, l_prev, acc = carry
+            @pl.when(pages_of(nxt) > 0)
+            def _first_fold():
+                start_fold(nxt, 0, own_of(nxt))
+
+            if tailed:
+                pl.when(new_group)(lambda: start_tail(nxt))
+            return 0
+
+        jax.lax.fori_loop(
+            0, jnp.where(first < (last_group + 1) * R, count, 0), _row, 0)
+
+    short = short_ref[g] > 0
+
+    def zero(slots):
+        # lanes a short fold leaves uncopied are read (masked): never
+        # whatever VMEM held
+        if fold > 1:
+            for buf in bufs:
+                buf[slots] = jnp.zeros((slots.size,) + buf.shape[1:],
+                                       buf.dtype)
+
+    def _before_the_rows(i, _):
+        """What a step starts before anything else: the first, what the
+        call needs first (into buffers zeroed just before: the others are
+        zeroed while those copies fly); one of short rows, what the read
+        needs after them (a group that walks hands on from its last row's
+        last fold)."""
+        hand_on(jnp.where(i == 0, -1, base + R - 1))
+        pl.when(i == 0)(lambda: zero(pl.ds(R, R + 1)))
+        return 0
+
+    pl.when(g == 0)(lambda: zero(pl.ds(0, R)))
+    jax.lax.fori_loop(jnp.where(g == 0, 0, 1), jnp.where(short, 2, 1),
+                      _before_the_rows, 0)
+
+    def take_tail(row, r, slot):
+        """The row's tail is here (the group before started its copy): the
+        step's token joins it, for this row's read, and where the tail
+        lives, for the steps to come (the copy back runs under the row's
+        folds; a whole tail, since one token's row of a packed tile cannot
+        be copied alone)."""
+        for copy in tail_copies(row, slot):
+            copy.wait()
+        token = jax.lax.broadcasted_iota(
+            jnp.int32, tail_bufs[0].shape[1:], 1)
+        for buf, new in zip(tail_bufs, news):
+            buf[slot] = jnp.where(token == tail_len_ref[row] - 1, new[r],
+                                  buf[slot])
+        for copy in put_copies(row, r, slot):
+            copy.start()
+
+    def tail_is_back(row, r, slot):
+        @pl.when(tail_len_ref[row] > 0)
+        def _wait():
+            for copy in put_copies(row, r, slot):
+                copy.wait()
+
+    # What a softmax step attends, a part a segment: (scores with what the
+    # row does not see at the mask's value, which tokens it sees where a
+    # part may hold none of them (None where it always holds one: their
+    # probabilities underflow to 0.0 by themselves), the values, the
+    # dimensions the value product contracts, the values' scales or None).
+    def tail_part(row, q, slot):
+        """The first tail_len tokens of the row's tail."""
+        # the tail is token-major a head ([Hkv, T, dh], dh on lanes, less
+        # the lanes that pad a narrower head): its scores are the plain
+        # q . k^T, batched over the KV heads
+        k = tail_bufs[0][slot][:, :, :dh]
+        v = k[:, :, :dv] if latent else tail_bufs[1][slot][:, :, :dh]
+        s = scale * jax.lax.dot_general(
+            q, k, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)
+        token = jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+        held = token < tail_len_ref[row]
+        if windowed:
+            # tail token i is at position length + i
+            held = jnp.logical_and(
+                held, token >= lower_ref[row] - len_ref[row])
+        # (the step's own token is always held: a tail has one to see)
+        return (jnp.where(held, s, DEFAULT_MASK_VALUE), None, v,
+                (((2,), (1,)), ((0,), (0,))), None)
+
+    def lanes_part(row, q, f, slot, pages: int):
+        """The first `pages` pages' lanes of buffer `slot`, which holds
+        fold f of the row."""
+        n_pages = pages_of(row)
+        # tokens the walk reaches: it stops at the table's width whatever
+        # the length says, and a fold's lanes past them were not copied
+        # this turn; it starts at the page the row's lower bound is in
+        walk_from = first_page(row) * page_size
+        reached = jnp.minimum(len_ref[row], walk_from + n_pages * page_size)
         lanes = pl.ds(0, pages * page_size)
         k = k_buf[slot, :, :, lanes]                      # [Hkv, dh, w ps]
         v = k[:, :dv] if latent else v_buf[slot, :, :, lanes]
@@ -512,86 +629,164 @@ def _paged_kernel(layer_ref, table_ref, len_ref, *refs, scale: float,
             kv_pos = kv_pos + walk_from
         seen = kv_pos < reached
         if windowed:
-            seen = jnp.logical_and(seen, kv_pos >= lower_ref[b])
+            seen = jnp.logical_and(seen, kv_pos >= lower_ref[row])
         if sub_block:
             # the blocks of each listed page that the row chose
             # (a row of lanes, broadcast over the heads afterwards)
-            row = (1, 1, s.shape[2])
-            block = jax.lax.broadcasted_iota(jnp.int32, row, 2) // sub_block
+            lane_row = (1, 1, s.shape[2])
+            block = jax.lax.broadcasted_iota(
+                jnp.int32, lane_row, 2) // sub_block
             per = page_size // sub_block
-            chosen = jnp.zeros(row, bool)
+            chosen = jnp.zeros(lane_row, bool)
             for c in range(pages):
-                bits = bits_ref[b, f * fold + c]
+                bits = bits_ref[row, f * fold + c]
                 for i in range(per):
                     chosen = jnp.logical_or(chosen, jnp.logical_and(
                         block == c * per + i, (bits >> i) & 1 == 1))
             seen = jnp.logical_and(seen, chosen)
-        s = jnp.where(seen, s, DEFAULT_MASK_VALUE)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        pr = jnp.exp(s - m_new)
-        if windowed or sub_block:
-            # a fold may hold no token the row still sees (its bound lies
-            # in the tail): exp(mask - mask) is 1.0, not 0.0
-            pr = jnp.where(seen, pr, 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = l_prev * alpha + jnp.sum(pr, axis=-1, keepdims=True)
+        v_scale = None
         if quantized:
-            pr = pr * vs_buf[slot, :, lanes][:, None, :].astype(jnp.float32)
+            v_scale = vs_buf[slot, :, lanes][:, None, :].astype(jnp.float32)
             v = v.astype(jnp.bfloat16)
-        pv = jax.lax.dot_general(pr.astype(v.dtype), v,
-                                 (((2,), (2,)), ((0,), (0,))),
-                                 preferred_element_type=jnp.float32)
-        return m_new, l_new, acc * alpha + pv
+        # a fold may hold no token the row still sees (its bound lies in
+        # the tail): exp(mask - mask) is 1.0, not 0.0
+        return (jnp.where(seen, s, DEFAULT_MASK_VALUE),
+                seen if windowed or sub_block else None, v,
+                (((2,), (2,)), ((0,), (0,))), v_scale)
 
-    def fold_pages(f, carry):
-        """One turn of the loop: a fold computed at all C pages."""
-        slot = (slot0 + f) % 2
+    def attend(carry, parts):
+        """One online-softmax step over `parts`: one max, one exp and one
+        sum over all of them, a value product each. `carry` (m, l, acc) of
+        the steps before, or None for a row's only step."""
+        m_new = functools.reduce(jnp.maximum, [
+            jnp.max(s, axis=-1, keepdims=True) for s, *_ in parts])
+        if carry is not None:
+            m_new = jnp.maximum(carry[0], m_new)
+        l_new = acc_new = None
+        for s, seen, v, dims, v_scale in parts:
+            pr = jnp.exp(s - m_new)
+            if seen is not None:
+                pr = jnp.where(seen, pr, 0.0)
+            l_part = jnp.sum(pr, axis=-1, keepdims=True)
+            if v_scale is not None:
+                pr = pr * v_scale
+            pv = jax.lax.dot_general(pr.astype(v.dtype), v, dims,
+                                     preferred_element_type=jnp.float32)
+            l_new = l_part if l_new is None else l_new + l_part
+            acc_new = pv if acc_new is None else acc_new + pv
+        if carry is not None:
+            alpha = jnp.exp(carry[0] - m_new)
+            l_new = carry[1] * alpha + l_new
+            acc_new = carry[2] * alpha + acc_new
+        return m_new, l_new, acc_new
 
-        # keep the DMA queue fed before waiting: this row's next fold (the
-        # second is under way already where a tail folded first) or, from
-        # its last fold, the first fold of the next row that has one
-        @pl.when(jnp.logical_and(f + 1 < n_folds, f >= int(tailed)))
-        def _next_fold():
-            start_fold(b, f + 1, 1 - slot)
-
-        @pl.when(f + 1 == n_folds)
-        def _next_row():
-            start_first_fold_after(b, 1 - slot)
-
-        each_copy(b, f, slot, lambda copy: copy.wait())
-        return fold_lanes(f, slot, fold, carry)
-
-    def finish(carry):
+    def finish(r, carry):
         _, l, acc = carry
-        o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+        o_ref[r] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
-    def narrow_last_fold(pages: int, carry):
-        """The row's last fold where it holds at most C / 2 pages: its turn
-        over the first `pages` pages' lanes alone, after the loop, ending
-        the row itself (no branch hands the softmax's carry on)."""
-        f = n_folds - 1
-        slot = (slot0 + f) % 2
-        start_first_fold_after(b, 1 - slot)
-        each_copy(b, f, slot, lambda copy: copy.wait())
-        finish(fold_lanes(f, slot, pages, carry))
+    def walk_row(r):
+        """Row r of the group, however many folds it holds."""
+        row = base + r
+        own = own_of(row)
+        q = q_ref[r]                                      # [Hkv, G, dh]
+        n_pages = pages_of(row)
+        n_folds = (n_pages + fold - 1) // fold
 
-    # a fold is computed as wide as what it copied: the loop takes every
-    # fold computed at C (all but the last, and the last too where over
-    # half of it is live), and a narrower last fold runs after it at its
-    # own width; a row without a page holds C of a fold it never had,
-    # and ends as its tail left it
-    narrowed = fold_branch(n_pages - (n_folds - 1) * fold, fold)
-    folded = jax.lax.fori_loop(0, n_folds - (narrowed > 0) * 1, fold_pages,
-                               folded)
-    jax.lax.switch(narrowed, [finish] + [
-        functools.partial(narrow_last_fold, pages)
-        for pages in fold_widths(fold)[1:]], folded)
-    first_slot[0] = (slot0 + n_folds) % 2
-    if tailed:
-        @pl.when(tail_len > 0)
-        def _tail_is_back():
-            for copy in put_copies:
-                copy.wait()
+        def slot_of(f):
+            return jnp.where(f % 2 == 0, own, spare)
+
+        folded = (jnp.full((n_kv, G, 1), DEFAULT_MASK_VALUE, jnp.float32),
+                  jnp.zeros((n_kv, G, 1), jnp.float32),
+                  jnp.zeros((n_kv, G, dv), jnp.float32))
+        if tailed:
+            @pl.when(n_folds > 1)
+            def _second_fold():
+                start_fold(row, 1, spare)
+
+            if R > 1:
+                # the next row's tail, a row ahead (a group's first row's
+                # comes with its first fold: `hand_on`)
+                pl.when(r < R - 1)(lambda: start_tail(row + 1))
+
+            def fold_tail(carry):
+                take_tail(row, r, own)
+                return attend(carry, [tail_part(row, q, own)])
+
+            # a row that holds no request has no tail either: it reads
+            # nothing
+            folded = jax.lax.cond(tail_len_ref[row] > 0, fold_tail,
+                                  lambda c: c, folded)
+
+        # a fold is computed as wide as what it copied: the loop takes
+        # every fold computed at C (all but the last, and the last too
+        # where over half of it is live), and a narrower last fold runs
+        # after it at its own width and ends the row; a row without a page
+        # holds C of a fold it never had, and ends as its tail left it
+        narrowed = jnp.int32(0) + fold_branch(
+            n_pages - (n_folds - 1) * fold, fold)
+        last = n_folds - 1
+
+        def fold_pages(f, carry):
+            """One turn of the loop: a fold computed at all C pages."""
+            slot = slot_of(f)
+
+            # keep the DMA queue fed before waiting: this row's next fold
+            # (the second is under way already where a tail folded first)
+            # or, from its last fold, what the read needs after this row
+            @pl.when(jnp.logical_and(f < last, f >= int(tailed)))
+            def _next_fold():
+                start_fold(row, f + 1, own + spare - slot)
+
+            pl.when(f == last)(lambda: hand_on(row))
+            wait_fold(row, f, slot)
+            return attend(carry, [lanes_part(row, q, f, slot, fold)])
+
+        folded = jax.lax.fori_loop(0, n_folds - (narrowed > 0) * 1,
+                                   fold_pages, folded)
+
+        @pl.when(jnp.logical_or(narrowed > 0, n_folds == 0))
+        def _last_fold_is_narrow_or_none():
+            hand_on(row)
+            pl.when(narrowed > 0)(
+                lambda: wait_fold(row, last, slot_of(last)))
+
+        jax.lax.switch(narrowed, [functools.partial(finish, r)] + [
+            lambda carry, pages=pages: finish(r, attend(carry, [lanes_part(
+                row, q, last, slot_of(last), pages)]))
+            for pages in fold_widths(fold)[1:]], folded)
+        if tailed:
+            tail_is_back(row, r, own)
+
+    def short_row(r):
+        """Row r of a group whose rows hold one fold each at most: tail
+        and fold in one softmax step."""
+        row = base + r
+        own = own_of(row)
+        n_pages = pages_of(row)
+
+        def live():
+            q, parts = q_ref[r], []
+            if tailed:
+                take_tail(row, r, own)
+                parts = [tail_part(row, q, own)]
+            wait_fold(row, 0, own)
+            jax.lax.switch(fold_branch(n_pages, fold), [
+                lambda pages=pages: finish(r, attend(None, parts + [
+                    lanes_part(row, q, 0, own, pages)]))
+                for pages in fold_widths(fold)])
+
+        def dead():
+            o_ref[r] = jnp.zeros(o_ref.shape[1:], o_ref.dtype)
+
+        # a row that holds no request reads nothing
+        jax.lax.cond(n_pages > 0, live, dead)
+
+    def short_rows():
+        each_row(short_row)
+        if tailed:
+            each_row(lambda r: tail_is_back(base + r, r, own_of(base + r)))
+
+    jax.lax.cond(short, short_rows, lambda: each_row(walk_row))
 
 
 def _stacked(pool, layer):
@@ -745,41 +940,48 @@ def _paged_read(q, pools, table, lengths, block, layer, mesh, interpret, *,
 
     scalars = [layer_arr, table, lengths] + tail_lens
     fold = fold_of(pools, table.shape[1])
+    group = group_of(pools, table.shape[1], B)
     static = dict(scale=scale or 1.0 / math.sqrt(dh), quantized=quantized,
-                  tailed=tailed, fold=fold, value_width=value_width)
+                  tailed=tailed, fold=fold, group=group,
+                  value_width=value_width)
     if lower is not None:
         scalars.append(lower)
         static.update(ring=int(ring))
     if sub is not None:
         scalars.append(sub[0])
         static.update(sub_block=int(sub[1]))
+    scalars.append(_short_groups(
+        lengths, tail_lens[0] if tailed else None, lower,
+        pools[0].shape[-1], fold, group, ring or table.shape[1]))
     kernel = functools.partial(_paged_kernel, **static)
 
-    def row_index(b, *scalars):
-        return (b, 0, 0, 0)
+    def group_index(g, *scalars):
+        return (g, 0, 0, 0)
 
     # the pools and tails stay where they are, whole: the kernel copies
     # [layer, page] and [layer, row] (a tail is small enough that the
     # compiler may keep all of it in VMEM)
     whole = pl.BlockSpec(memory_space=pl.ANY)
-    q_row = pl.BlockSpec((1, Hkv, G, dh), row_index)
-    out_row = pl.BlockSpec((1, Hkv, G, dv), row_index)
+    q_rows = pl.BlockSpec((group, Hkv, G, dh), group_index)
+    out_rows = pl.BlockSpec((group, Hkv, G, dv), group_index)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=len(scalars),  # layer, table, lengths[, tail][, lower][, bits]
-        grid=(B,),
-        in_specs=[q_row]
-        + [pl.BlockSpec((1,) + new.shape[1:], row_index) for new in news]
+        # layer, table, lengths[, tail][, lower][, bits], short
+        num_scalar_prefetch=len(scalars),
+        grid=(B // group,),
+        in_specs=[q_rows]
+        + [pl.BlockSpec((group,) + new.shape[1:], group_index)
+           for new in news]
         + [whole] * (len(pools) + len(tails)),
-        out_specs=[out_row] + [whole] * len(tails),
-        # two buffers a pool, each a fold wide: C pages side by side
-        scratch_shapes=[pltpu.VMEM((2,) + pool.shape[2:-1]
+        out_specs=[out_rows] + [whole] * len(tails),
+        # a buffer a pool for each row of two groups and one to spare, each
+        # a fold wide: C pages side by side
+        scratch_shapes=[pltpu.VMEM((2 * group + 1,) + pool.shape[2:-1]
                                    + (fold * pool.shape[-1],), pool.dtype)
                         for pool in pools]
-        + [pltpu.VMEM((2,) + x.shape[2:], x.dtype) for x in tails]
-        + [pltpu.SemaphoreType.DMA((len(pools), 2, fold))]
-        + [pltpu.SemaphoreType.DMA((len(tails), 2)),
-           pltpu.SemaphoreType.DMA((len(tails),))] * tailed
-        + [pltpu.SMEM((1,), jnp.int32)],
+        + [pltpu.VMEM((2 * group,) + x.shape[2:], x.dtype) for x in tails]
+        + [pltpu.SemaphoreType.DMA((len(pools), 2 * group + 1, fold))]
+        + [pltpu.SemaphoreType.DMA((len(tails), 2 * group)),
+           pltpu.SemaphoreType.DMA((len(tails), group))] * tailed,
     )
     first_tail = len(scalars) + 1 + len(news) + len(pools)
     with kernel_scope(scope):
@@ -791,7 +993,7 @@ def _paged_read(q, pools, table, lengths, block, layer, mesh, interpret, *,
             # the tails are updated where they lie
             input_output_aliases={first_tail + i: 1 + i
                                   for i in range(len(tails))},
-            # sequential rows: a row starts its successor's first fold
+            # sequential groups: a group starts its successor's first folds
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",)),
             interpret=interpret,

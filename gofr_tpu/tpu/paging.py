@@ -52,7 +52,8 @@ import numpy as np
 from ..models.llama import LlamaConfig, llama_prefill_last
 from ..ops.paged_attention import (column_tail, flush_columns, flush_planes,
                                    fold_branch, fold_of, fold_widths,
-                                   holds_request, paged_write_columns,
+                                   group_of, holds_request,
+                                   paged_write_columns,
                                    paged_write_prefill_scales,
                                    paged_write_prefill_stacked,
                                    paged_write_window, plane_tail,
@@ -127,6 +128,24 @@ def _folds(pages, c: int):
                    fold_branch(pages - (folds - 1) * c, c)) * walked
     return (int(folds.sum()), int(((last < c) & walked).sum()),
             int(((folds - 1) * c * walked + last).sum()))
+
+
+def _groups(pages, held, c: int, r: int):
+    """(row reads that took the short rows' step, row reads, groups that
+    walked row by row, groups read) of reads whose rows, ALL the kernel's
+    in their order, walk `pages` pages each ([rows, reads] ints; `held`
+    [rows]: which hold a request), `r` consecutive rows a grid step in
+    folds of `c`, as the read's kernel runs them (ops/paged_attention
+    `_paged_kernel`): a group takes the short rows' step where each of its
+    rows holds one fold at most (and a page, if it holds a request); one
+    longer row and the group walks. Rows and groups that hold no request
+    are not counted."""
+    fits = (pages <= c) & ((pages > 0) == held[:, None])
+    short = fits.reshape(-1, r, pages.shape[1]).all(axis=1)
+    rows = held.reshape(-1, r).sum(axis=1)[:, None]       # a group's live
+    return (int((short * rows).sum()), int(rows.sum()) * pages.shape[1],
+            int((~short & (rows > 0)).sum()),
+            int((rows > 0).sum()) * pages.shape[1])
 
 
 class PagedLLMEngine(LLMEngine):
@@ -330,10 +349,13 @@ class PagedLLMEngine(LLMEngine):
         self.read_folds = self.read_narrowed = 0
         self.read_tokens = self.read_lanes = 0
         self.read_pages_per_fold = None
+        # and how its rows were walked (`_groups`): row reads in the short
+        # rows' step, row reads, groups that walked, groups read
+        self.read_groups = np.zeros(4, np.int64)
         # the same a group beside the primary: {group index: [folds,
-        # tokens, lanes, pages a fold, narrowed folds]}
-        self._more_reads = {i: [0, 0, 0, None, 0] for i in range(len(rings))
-                            if i != self._primary}
+        # tokens, lanes, pages a fold, narrowed folds, `_groups`' four]}
+        self._more_reads = {i: [0, 0, 0, None, 0, np.zeros(4, np.int64)]
+                            for i in range(len(rings)) if i != self._primary}
         self._tokens = jnp.zeros((B,), dtype=jnp.int32)
         self._positions = jnp.zeros((B,), dtype=jnp.int32)
         self._temps = self._temps_init(B)
@@ -1947,7 +1969,10 @@ class PagedLLMEngine(LLMEngine):
         tail) in ceil(pages / C) folds, each C x page_size lanes but the
         row's last, which is computed as wide as the pages it copied
         (`_folds`). The int8 pools have no tail: step t attends the t + 1
-        tokens written so far too."""
+        tokens written so far too. And how the kernel took its rows
+        (`_groups`): R consecutive slots a grid step (`group_of`, from the
+        pools' shapes and the slots), the slots without a request among
+        them."""
         planes = len(self.model.planes)
         # (the planes a read walks: one with a stride is not among them)
         pools = ([self.k_cache, self.v_cache, self.k_scale, self.v_scale]
@@ -1956,6 +1981,16 @@ class PagedLLMEngine(LLMEngine):
                      for i, plane in enumerate(self.model.planes)
                      if plane.stride == 1])
         c, ps = fold_of(pools, n_table, self.mesh), self.page_size
+        slots = np.asarray([i for i, _ in live], np.int64)
+        held = np.zeros(len(self.slots), bool)
+        held[slots] = True
+
+        def groups(pools, width, pages, c, layers):
+            every = np.zeros((len(held), block), np.int64)
+            every[slots] = pages
+            return layers * np.asarray(_groups(every, held, c, group_of(
+                pools, width, len(held), self.mesh)), np.int64)
+
         tokens = np.asarray([self.slots[i].length for i, _ in live],
                             np.int64)[:, None]
         tokens = tokens + (np.arange(1, block + 1) if self._q8
@@ -1968,13 +2003,14 @@ class PagedLLMEngine(LLMEngine):
         self.read_lanes += layers * computed * ps
         self.read_tokens += layers * int(np.minimum(tokens, pages * ps).sum())
         self.read_pages_per_fold = c
+        self.read_groups += groups(pools, n_table, pages, c, layers)
         # a window group's read walks from the page its lower bound is in:
         # step t's token at position length + t sees from length + t + 1
         # - window on, at most a ring of pages
         for index, read in self._more_reads.items():
             group, ring = self.model.groups[index], self._rings[index]
-            c = fold_of(self.pools[index * planes:(index + 1) * planes],
-                        ring, self.mesh)
+            pools = self.pools[index * planes:(index + 1) * planes]
+            c = fold_of(pools, ring, self.mesh)
             lower = np.maximum(
                 tokens + np.arange(1, block + 1) - group.window, 0)
             pages = np.clip(-(-tokens // ps) - lower // ps, 0, ring)
@@ -1985,6 +2021,7 @@ class PagedLLMEngine(LLMEngine):
             read[2] += group.layers * computed * ps
             read[3] = c
             read[4] += group.layers * narrowed
+            read[5] += groups(pools, ring, pages, c, group.layers)
 
     def paging_snapshot(self) -> dict:
         """`/debug/engine` "paging". "write": how often the decode block's
@@ -2001,16 +2038,26 @@ class PagedLLMEngine(LLMEngine):
         folds computed: what is left of 1.0 was masked (a half-filled last
         page, the pages a power of two adds; in a window group also the
         tokens of the walk's first page that lie before the lower
-        bound). "read" is
+        bound). `short_row_share` is the share of the rows' reads that
+        took the short rows' step (a grid step walks R consecutive slots,
+        and where each holds one fold at most, takes tail and fold in one
+        softmax step: ops/paged_attention `rows_a_step`), and
+        `groups_split_share` the share of the groups read that walked row
+        by row because one row was longer (`_groups`). "read" is
         the primary group's; "groups" has every page group: its blocks,
         its window, its pool's pages and how many are in use, the pages a
         sequence reserved there on average since the last reset, and its
         own "read"."""
-        def read_of(folds, tokens, lanes, c, narrowed):
+        def read_of(folds, tokens, lanes, c, narrowed, groups):
+            short, rows, split, read = (int(n) for n in groups)
             return {"pages_per_fold": c, "folds": folds,
                     "narrowed_folds": narrowed,
                     "fold_live_share": (round(tokens / lanes, 4)
-                                        if lanes else None)}
+                                        if lanes else None),
+                    "short_row_share": (round(short / rows, 4)
+                                        if rows else None),
+                    "groups_split_share": (round(split / read, 4)
+                                           if read else None)}
 
         groups = []
         for index, (group, allocator) in enumerate(zip(self.model.groups,
@@ -2026,7 +2073,7 @@ class PagedLLMEngine(LLMEngine):
                     if self._reserved_sequences else None),
                 "read": (read_of(self.read_folds, self.read_tokens,
                                  self.read_lanes, self.read_pages_per_fold,
-                                 self.read_narrowed)
+                                 self.read_narrowed, self.read_groups)
                          if primary else read_of(*self._more_reads[index]))})
         return {
             "write": {

@@ -9,7 +9,7 @@ import numpy as np
 
 from gofr_tpu.ops import paged_attention as paged_attention_module
 from gofr_tpu.ops.paged_attention import (_write_columns, block_tail,
-                                          fold_of, tail_put)
+                                          fold_of, plane_tail, tail_put)
 
 # -- kernel -------------------------------------------------------------------
 # The read walks each row's live pages: one loop iteration a page, the
@@ -164,3 +164,101 @@ def _tail_of(k_pool, news, steps, block):
             tail = tail_put(*tail, news[0][t, layer], news[1][t, layer],
                             layer, t)
     return tail
+
+
+# -- several short rows a grid step -------------------------------------------
+# The read walks R consecutive rows a grid step (`rows_a_step`), and where
+# each of them holds one fold at most, takes them in the short rows' step
+# (the tail and the fold in one softmax step). Pages of 8 tokens, folds of
+# C = 4 pages under a table 16 wide, groups of R = 4 rows (the rule is given
+# the cap that makes it so), 12 rows: three groups, so that a group's
+# buffers are used again by the group after next. Rows, by the pages they
+# hold: all one page; 1..C pages mixed; rows that hold no request inside a
+# group; ONE row of several folds (its group walks row by row between two
+# groups that do not); no request at all; several folds in every row (no
+# group takes the short rows' step); and 9 rows, which 4 does not divide
+# (the rule takes 3).
+GROUP_FOLD, GROUP_ROWS, GROUP_BLOCK = 4, 4, 16
+GROUP_PAGES = {
+    "one-page": [1] * 12,
+    "mixed": [1, 2, 3, 4, 4, 1, 3, 2, 2, 4, 1, 3],
+    "dead-inside": [1, 0, 3, 4, 4, 1, 0, 0, 2, 4, 1, 3],
+    "one-long": [1, 2, 3, 4, 4, 9, 3, 2, 2, 4, 1, 3],
+    "all-dead": [0] * 12,
+    "all-long": [5, 9, 6, 12, 7, 5, 10, 8, 9, 6, 11, 5],
+    "ragged": [1, 2, 0, 4, 9, 1, 3, 2, 2]}
+# H, Hkv, dh[, value width]: the latent page's one head of 576 read by 32
+# queries, 2 KV heads x 16 queries, 8 KV heads x 2
+GROUP_GEOMETRY = {"latent": (32, 1, 576, 512), "Hkv2xG16": (32, 2, 32),
+                  "Hkv8xG2": (16, 8, 16)}
+
+
+def _grouping(monkeypatch, rows: int, most: int = GROUP_ROWS):
+    """The rule walks the most rows a step that divide `rows`, up to
+    `most`. Returns them."""
+    monkeypatch.setattr(paged_attention_module, "_GROUP_ROWS", most)
+    return max(r for r in range(1, most + 1) if rows % r == 0)
+
+
+def _group_lengths(rows: str, seed=0):
+    """Tokens each row holds in pages: its last page filled to anywhere,
+    a whole page now and then."""
+    rng = np.random.default_rng(seed)
+    pages = np.asarray(GROUP_PAGES[rows])
+    last = np.where(np.arange(len(pages)) % 5 == 4, PS,
+                    rng.integers(1, PS + 1, size=len(pages)))
+    return np.where(pages > 0, (pages - 1) * PS + last, 0)
+
+
+def _group_case(geometry, rows, t, seed=0, layers=2):
+    """A decode block's step t over the rows `rows` names: stacked pools
+    (K alone for the latent geometry) that hold each row's context, a
+    table with room for the block, the block's first t + 1 tokens a plane
+    [t + 1, B, Hkv, dh], q, the rows' starts and which of them hold a
+    request."""
+    H, Hkv, dh = GROUP_GEOMETRY[geometry][:3]
+    rng = np.random.default_rng(seed)
+    starts = _group_lengths(rows, seed)
+    B, planes = len(starts), 1 if geometry == "latent" else 2
+    n_pool_pages = 1 + B * FOLD_TABLE
+    pools = [jnp.asarray(rng.normal(
+        size=(layers, n_pool_pages, Hkv, dh, PS)), jnp.float32)
+        for _ in range(planes)]
+    live = starts > 0
+    table = np.zeros((B, FOLD_TABLE), np.int32)
+    free = iter(rng.permutation(np.arange(1, n_pool_pages)))
+    for b in np.flatnonzero(live):
+        for i in range((starts[b] + GROUP_BLOCK - 1) // PS + 1):
+            table[b, i] = next(free)
+    news = [jnp.asarray(rng.normal(size=(t + 1, B, Hkv, dh)), jnp.float32)
+            for _ in range(planes)]
+    q = jnp.asarray(rng.normal(size=(B, H, dh)), jnp.float32)
+    return (q, pools, jnp.asarray(table), news,
+            jnp.asarray(starts, jnp.int32), jnp.asarray(live))
+
+
+def _group_written(pools, news, table, starts, live, layer):
+    """`layer` of the pools once the block's tokens so far are written
+    column by column (a row without a request writes to page 0)."""
+    table = jnp.where(live[:, None], table, 0)
+    pools = [pool[layer][None] for pool in pools]
+    for t in range(news[0].shape[0]):
+        pools = _write_columns(pools, [new[t] for new in news], table,
+                               starts + t, 0)
+    return [pool[0] for pool in pools]
+
+
+def _group_tails(pools, news, layer, rows):
+    """The planes' tails holding all but the last of `news` in `layer`
+    (the last is the step's own token, which the read puts)."""
+    tails = [plane_tail(pool, rows, GROUP_BLOCK) for pool in pools]
+    for t in range(news[0].shape[0] - 1):
+        tails = [_token_put(tail, new[t], layer, t)
+                 for tail, new in zip(tails, news)]
+    return tails
+
+
+def _token_put(tail, new, layer, t):
+    """new [B, Hkv, dh] as token t of every row of `layer`'s tail."""
+    dh = new.shape[-1]
+    return tail.at[layer, :, :, t, :dh].set(new.astype(tail.dtype))

@@ -493,7 +493,8 @@ def test_the_engine_counts_the_folds_its_reads_made(seeded):
     engine = _engine(program_config(), params)
     assert engine.paging_snapshot()["read"] == {
         "pages_per_fold": None, "folds": 0, "narrowed_folds": 0,
-        "fold_live_share": None}
+        "fold_live_share": None, "short_row_share": None,
+        "groups_split_share": None}
     engine.start()
     try:
         for n in (30, 5):
@@ -512,6 +513,9 @@ def test_the_engine_counts_the_folds_its_reads_made(seeded):
     assert read["narrowed_folds"] == layers * block * 4
     assert read["fold_live_share"] == round(
         sum(found) / ((2 + 4 + 4 + 1 + 1 + 1) * 16), 4)
+    # every row one fold: each read took the short rows' step
+    assert read["short_row_share"] == 1.0
+    assert read["groups_split_share"] == 0.0
 
 
 def test_the_two_plane_families_say_k_and_v():

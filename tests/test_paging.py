@@ -342,7 +342,7 @@ def test_the_int8_engine_counts_its_reads_a_token_longer_each_step(
     _folding(monkeypatch, [x[0] for x in (engine.k_cache, engine.v_cache,
                                           engine.k_scale, engine.v_scale)],
              8)
-    folds = narrowed = tokens = lanes = 0
+    folds = narrowed = tokens = lanes = short = 0
     for found in (60, 63, 112, 126, 130, 150):
         engine.slots[2].length = found
         engine._note_page_reads([(2, None)], 4, 16)
@@ -351,14 +351,46 @@ def test_the_int8_engine_counts_its_reads_a_token_longer_each_step(
             last = pages % 8 or 8
             width = min(w for w in (2, 4, 8) if w >= last)
             folds += -(-pages // 8)
+            short += pages <= 8
             narrowed += width < 8
             tokens += attended
             lanes += (pages - last + width) * 16
     assert narrowed == 4 + 1 + 0 + 2 + 4 + 4 and folds == 3 * 4 + 6 + 2 * 8
     read = engine.paging_snapshot()["read"]
+    # the four slots are one group of the kernel's, slot 2 its live row:
+    # the group walks whenever that row holds a second fold
+    assert short == 4 + 4 + 4 + 2
     assert read == {"pages_per_fold": 8, "folds": CFG.n_layers * folds,
                     "narrowed_folds": CFG.n_layers * narrowed,
-                    "fold_live_share": round(tokens / lanes, 4)}
+                    "fold_live_share": round(tokens / lanes, 4),
+                    "short_row_share": round(short / 24, 4),
+                    "groups_split_share": round(1 - short / 24, 4)}
+
+
+def test_the_short_rows_and_the_groups_that_walk_are_counted_on_host_arrays():
+    """`_groups`: from the pages every kernel row walks a read and which
+    rows hold a request, the row reads that took the short rows' step and
+    the groups that walked. Twelve rows, two reads each, folds of 8 pages:
+    a group whose second read finds a row on its ninth page; a group
+    without a request (counted nowhere); a group with a live row that
+    holds no page yet (it has no fold to join: the group walks)."""
+    import numpy as np
+
+    from gofr_tpu.tpu.paging import _groups
+
+    pages = np.array([[1, 1], [8, 9], [0, 0], [3, 3],
+                      [0, 0], [0, 0], [0, 0], [0, 0],
+                      [2, 2], [4, 4], [8, 8], [0, 0]])
+    held = np.array([1, 1, 0, 1, 0, 0, 0, 0, 1, 1, 1, 1], bool)
+    # four rows a grid step: 3 row reads of group 0's first read
+    assert _groups(pages, held, 8, 4) == (3, 14, 3, 4)
+    # a row a step: every row a group of its own
+    assert _groups(pages, held, 8, 1) == (11, 14, 3, 14)
+    # two a step: rows 0-1 split on the second read, rows 10-11 on both
+    assert _groups(pages, held, 8, 2) == (2 + 2 + 4, 14, 1 + 2, 8)
+    # a fold of 2 pages: only the rows of one or two pages fit
+    assert _groups(pages, held, 2, 4) == (0, 14, 4, 4)
+    assert _groups(pages, held, 2, 1) == (4, 14, 10, 14)
 
 
 def test_paged_priority_no_head_of_line_inversion():

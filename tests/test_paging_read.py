@@ -7,19 +7,25 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from paging_cases import (EDGE_GEOMETRY, FOLD_CASES, FOLD_GEOMETRY,
-                          FOLD_ROWS, FOLD_TABLE, GEOMETRY, N_LAYERS, PS,
-                          RAGGED, ROW_LENGTHS, TAIL_GEOMETRY, TAIL_IDLE,
-                          _dead_pages, _fold_edges, _folding, _in_layer,
-                          _paged_case, _tail_case, _tail_of,
-                          _written_by_columns)
+                          FOLD_ROWS, FOLD_TABLE, GEOMETRY, GROUP_BLOCK,
+                          GROUP_FOLD, GROUP_GEOMETRY, GROUP_PAGES, N_LAYERS,
+                          PS, RAGGED, ROW_LENGTHS, TAIL_GEOMETRY, TAIL_IDLE,
+                          _dead_pages, _fold_edges, _folding, _group_case,
+                          _group_lengths, _group_tails, _group_written,
+                          _grouping, _in_layer, _paged_case, _tail_case,
+                          _tail_of, _token_put, _written_by_columns)
 
 from gofr_tpu.models.llama import LlamaConfig, llama_init
+from gofr_tpu.ops import paged_attention as paged_attention_module
+from gofr_tpu.ops import sparse_attention as sparse
+from gofr_tpu.ops.mla_read import mla_read, mla_read_reference
 from gofr_tpu.ops.paged_attention import (block_tail, fold_branch, fold_of,
-                                          fold_widths, pages_per_fold,
-                                          paged_attention,
+                                          fold_widths, group_of,
+                                          pages_per_fold, paged_attention,
                                           paged_attention_in_block,
                                           paged_attention_reference,
-                                          quantize_kv)
+                                          plane_tail, quantize_kv,
+                                          rows_a_step, tail_put)
 
 CFG = LlamaConfig.debug()
 
@@ -142,15 +148,16 @@ def _width_choices(jaxpr, in_loop=False):
 
 @pytest.mark.parametrize("c", [1, 4, 8])
 def test_only_a_rows_last_fold_chooses_its_width(c, monkeypatch):
-    """The kernel holds ONE choice among a fold's widths, outside the
-    loop over a row's full folds (a full fold's turn is computed at C
-    pages and branches on no width), and none at folds of one page."""
+    """The kernel holds TWO choices among a fold's widths, the short
+    rows' step's and the walk's after its loop, both outside the loop over
+    a row's full folds (a full fold's turn is computed at C pages and
+    branches on no width), and none at folds of one page."""
     q, k, v, table, lens = _paged_case(
         "Hkv2", jnp.float32, _fold_edges(c, PS), n_table=FOLD_TABLE)
     _folding(monkeypatch, (k, v), c)
     jaxpr = jax.make_jaxpr(lambda *a: paged_attention(*a, interpret=True))(
         q, k, v, table, lens).jaxpr
-    assert _width_choices(jaxpr) == ([(len(fold_widths(c)), False)]
+    assert _width_choices(jaxpr) == ([(len(fold_widths(c)), False)] * 2
                                      if c > 1 else [])
 
 
@@ -361,3 +368,258 @@ def test_paged_attention_int8_matches_reference(geometry, lengths):
                               table, lens, _in_layer(ks, last),
                               _in_layer(vs, last), layer=jnp.int32(last))
     np.testing.assert_array_equal(np.asarray(stacked), np.asarray(out))
+
+
+# -- several short rows a grid step -------------------------------------------
+def test_the_rows_a_step_are_worked_out_from_what_a_call_sees():
+    """`rows_a_step`: the bytes of a fold over the call's pools and the
+    copies that bring it, against what 2 R + 1 buffers may take, and the
+    call's rows, which R divides. The benchmark's shapes, rows that
+    nothing divides, and a fold too heavy or of too many copies for more
+    than a row."""
+    latent = 8 * 576 * 128 * 2                  # a fold of 8 latent pages
+    internlm2 = 2 * 2 * 8 * 128 * 128 * 2       # of 2 pages of K and V
+    for rows in (96, 128, 32, 256, 64):
+        assert rows_a_step(latent, 8, rows) == 4
+        assert rows_a_step(internlm2, 4, rows) == 4
+    assert rows_a_step(latent, 8, 97) == 1      # a prime: every row alone
+    assert rows_a_step(latent, 8, 9) == 3 and rows_a_step(latent, 8, 6) == 3
+    assert rows_a_step(1, 1, 12) == 6 and rows_a_step(1, 1, 16) == 8
+    for fold_bytes in (1, latent, internlm2, 3 << 20, 5 << 20):
+        for copies in (1, 8, 64, 200):
+            for rows in (1, 7, 12, 96):
+                r = rows_a_step(fold_bytes, copies, rows)
+                assert rows % r == 0 and 1 <= r <= 8
+                assert r == 1 or ((2 * r + 1) * fold_bytes <= 11 << 20
+                                  and (2 * r + 1) * copies <= 384)
+    # from the pools themselves, as `fold_of` has it
+    pool = jnp.zeros((2, 5, 1, 576, 128), jnp.bfloat16)
+    assert fold_of([pool], 16) == 8 and group_of([pool], 16, 96) == 4
+    assert group_of([pool], 16, 50) == 2
+
+
+GROUP_CASES = (
+    [("latent", rows, t) for rows in GROUP_PAGES for t in (0, 7, 15)]
+    + [("Hkv2xG16", rows, 7) for rows in GROUP_PAGES]
+    + [("Hkv8xG2", rows, t) for rows in ("mixed", "dead-inside", "one-long")
+       for t in (0, 15)])
+
+
+def _read_a_group_case(geometry, q, pools, tails, table, news, starts, live,
+                       t, layer):
+    """(the read's output, the tails it returns) at step t of a block."""
+    paged, counts = jnp.where(live, starts, 0), jnp.where(live, t + 1, 0)
+    if geometry == "latent":
+        out, tail = jax.jit(lambda *a: mla_read(
+            *a, value_width=GROUP_GEOMETRY[geometry][3], scale=0.07,
+            layer=jnp.int32(layer)))(
+                q, news[0][t], pools[0], tails[0], table, paged, counts)
+        return out, [tail]
+    out, *tails = jax.jit(lambda *a: paged_attention_in_block(
+        *a, layer=jnp.int32(layer)))(
+            q, news[0][t], news[1][t], *pools, *tails, table, paged, counts)
+    return out, tails
+
+
+@pytest.mark.parametrize("geometry,rows,t", GROUP_CASES)
+def test_rows_walked_several_a_grid_step_match_reference(geometry, rows, t,
+                                                         monkeypatch):
+    """Step t of a block of 16 over the rows `GROUP_PAGES` names, four
+    rows a grid step (three where four does not divide them) at folds of
+    4 pages, every dead page NaN: against the reference on a pool that had
+    the block's tokens written column by column; the tails come back with
+    the step's token put and nothing else changed."""
+    q, pools, table, news, starts, live = _group_case(geometry, rows, t,
+                                                      seed=17)
+    _folding(monkeypatch, [pool[0] for pool in pools], GROUP_FOLD)
+    B, layer = q.shape[0], 1
+    assert _grouping(monkeypatch, B) == (3 if rows == "ragged" else 4)
+    assert group_of(pools, FOLD_TABLE, B) == _grouping(monkeypatch, B)
+    written = _group_written(pools, news, table, starts, live, layer)
+    after = jnp.where(live, starts + t + 1, 0)
+    if geometry == "latent":
+        want = mla_read_reference(q, written[0], table, after,
+                                  value_width=GROUP_GEOMETRY[geometry][3],
+                                  scale=0.07)
+    else:
+        want = paged_attention_reference(q, *written, table, after)
+    # what the block found in pages: the pages past it are dead, the
+    # block's own among them (its tokens wait in the tail)
+    dead = _dead_pages(pools[0].shape[1], table, starts, PS)[
+        None, :, None, None, None]
+    tails = _group_tails(pools, news, layer, B)
+    got, tails_out = _read_a_group_case(
+        geometry, q, [jnp.where(dead, jnp.nan, pool) for pool in pools],
+        tails, table, news, starts, live, t, layer)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+    idle = ~np.asarray(live)
+    assert not np.asarray(got)[idle].any()
+    for tail_out, tail, new in zip(tails_out, tails, news):
+        put = np.asarray(_token_put(tail, new[t], layer, t))
+        np.testing.assert_array_equal(np.asarray(tail_out)[:, ~idle],
+                                      put[:, ~idle])
+        np.testing.assert_array_equal(np.asarray(tail_out)[:, idle],
+                                      np.asarray(tail)[:, idle])
+
+
+def test_rows_of_several_folds_walk_as_a_row_a_step_does(monkeypatch):
+    """A table whose rows all hold several folds, and one with a single
+    such row: a group that holds one walks row by row through the loop a
+    row a step runs, so its rows' outputs are the same BITS at four rows a
+    step, at one, and with the short rows' step taken out of the kernel;
+    the rows of the groups that took that step are the same numbers."""
+    for rows, walked in (("all-long", slice(None)), ("one-long", slice(4, 8))):
+        q, pools, table, news, starts, live = _group_case(
+            "Hkv2xG16", rows, 7, seed=23)
+        _folding(monkeypatch, [pool[0] for pool in pools], GROUP_FOLD)
+        tails = _group_tails(pools, news, 1, q.shape[0])
+        outs = []
+        for most, joined in ((4, True), (1, True), (4, False)):
+            _grouping(monkeypatch, q.shape[0], most)
+            monkeypatch.setattr(paged_attention_module, "_JOIN_ONE_FOLD",
+                                joined)
+            outs.append(np.asarray(_read_a_group_case(
+                "Hkv2xG16", q, pools, tails, table, news, starts, live, 7,
+                1)[0]))
+        # (at one row a step a row of one fold is a group of its own)
+        for other in outs[1 if rows == "all-long" else 2:]:
+            np.testing.assert_array_equal(outs[0][walked], other[walked])
+        for other in outs[1:]:
+            np.testing.assert_allclose(outs[0], other, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("rows", ["mixed", "dead-inside", "one-long",
+                                  "all-dead", "ragged"])
+def test_rows_walked_several_a_grid_step_over_int8_pools(rows, monkeypatch):
+    """The same rows over int8 pools (no tail: the step reads pages
+    alone), every dead page's scales NaN."""
+    q, k, v, table, lens = _paged_case(
+        "Hkv2", jnp.float32, [int(n) for n in _group_lengths(rows, 19)],
+        seed=19, n_table=FOLD_TABLE)
+    (k, ks), (v, vs) = quantize_kv(k), quantize_kv(v)
+    dead = _dead_pages(k.shape[0], table, lens, PS)[:, None, None]
+    given = [k, v] + [jnp.where(dead, jnp.nan, x) for x in (ks, vs)]
+    _folding(monkeypatch, given, GROUP_FOLD)
+    assert _grouping(monkeypatch, q.shape[0]) in (3, 4)
+    ref = paged_attention_reference(q, k, v, table, lens, ks, vs)
+    out = np.asarray(jax.jit(lambda *a: paged_attention(*a))(
+        q, k, v, table, lens, *given[2:]))
+    np.testing.assert_allclose(out, np.asarray(ref), rtol=5e-2, atol=5e-2)
+    assert not out[np.asarray(lens) == 0].any()
+
+
+RING_LENGTHS = {
+    # every context inside the window: the bound is 0 or in an early page
+    "inside": [5, 30, 12, 0, 24, 17, 9, 26, 31, 0, 20, 3],
+    # contexts the window has left behind: the walk starts pages in
+    "past": [100, 64, 41, 0, 77, 50, 33, 200, 90, 58, 0, 129]}
+
+
+@pytest.mark.parametrize("t", [0, 7, 15])
+@pytest.mark.parametrize("lengths,c", [("inside", 4), ("past", 4),
+                                       ("inside", 2)])
+def test_rows_walked_several_a_grid_step_through_a_ring(lengths, c, t,
+                                                        monkeypatch):
+    """A window of 24 tokens over a ring of 5 pages of 8, four rows a grid
+    step, step t of a block of 16: each row attends (p - 24, p] of its own
+    history, through the pages the ring still holds and the tail. At folds
+    of 4 pages every row is one fold; at folds of 2 the rows of three and
+    four pages walk in groups of their own."""
+    W, ring, T, Hkv, G, dh = 24, 5, GROUP_BLOCK, 2, 4, 16
+    lens = np.asarray(RING_LENGTHS[lengths])
+    B = len(lens)
+    rng = np.random.default_rng(29)
+    K, V = (rng.normal(size=(B, lens.max() + T, Hkv, dh)).astype(np.float32)
+            for _ in range(2))
+    table = np.zeros((B, ring), np.int32)
+    k_pool, v_pool = (np.full((2, 1 + B * ring, Hkv, dh, PS), 99.0,
+                              np.float32) for _ in range(2))
+    for b in np.flatnonzero(lens):
+        table[b] = 1 + b * ring + rng.permutation(ring)
+        pages = -(-lens[b] // PS)
+        for j in range(max(0, pages - ring), pages):
+            n = min(PS, lens[b] - j * PS)
+            for pool, X in ((k_pool, K), (v_pool, V)):
+                pool[1, table[b, j % ring], :, :, :n] = X[
+                    b, j * PS:j * PS + n].transpose(1, 2, 0)
+    k_pool, v_pool = jnp.asarray(k_pool), jnp.asarray(v_pool)
+    _folding(monkeypatch, (k_pool[0], v_pool[0]), c)
+    monkeypatch.setattr(paged_attention_module, "pages_per_fold",
+                        lambda *_: c)
+    assert _grouping(monkeypatch, B) == 4
+    live = lens > 0
+    at = np.arange(B)[:, None], lens[:, None] + np.arange(t)[None, :]
+    tails = [plane_tail(k_pool, B, T).at[1, :, :, :t, :dh].set(
+        jnp.asarray(X[at].transpose(0, 2, 1, 3))) for X in (K, V)]
+    q = rng.normal(size=(B, Hkv * G, dh)).astype(np.float32)
+    out, _, _ = jax.jit(lambda *a: paged_attention_in_block(
+        *a, layer=jnp.int32(1), window=W, ring=ring))(
+            jnp.asarray(q), jnp.asarray(K[np.arange(B), lens + t]),
+            jnp.asarray(V[np.arange(B), lens + t]), k_pool, v_pool, *tails,
+            jnp.asarray(table), jnp.asarray(np.where(live, lens, 0),
+                                            jnp.int32),
+            jnp.asarray(np.where(live, t + 1, 0), jnp.int32))
+    out = np.asarray(out)
+    assert not out[~live].any()
+    for b in np.flatnonzero(live):
+        p = lens[b] + t
+        lo = max(0, p - W + 1)
+        s = np.einsum("hgd,shd->hgs", q[b].reshape(Hkv, G, dh),
+                      K[b, lo:p + 1]) / np.sqrt(dh)
+        pr = np.exp(s - s.max(-1, keepdims=True))
+        want = np.einsum("hgs,shd->hgd", pr / pr.sum(-1, keepdims=True),
+                         V[b, lo:p + 1])
+        np.testing.assert_allclose(out[b].reshape(Hkv, G, dh), want,
+                                   rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("t", [0, 7])
+@pytest.mark.parametrize("seed", [7, 8])
+def test_rows_walked_several_a_grid_step_over_chosen_blocks(seed, t,
+                                                            monkeypatch):
+    """`sparse_read`: each (row, KV head) is a row of the kernel (12 of
+    them, four a grid step), its table the list of the pages that hold a
+    block it chose, at folds of 4 listed pages: lists of one fold and of
+    two, a row that holds no request, step t of a block."""
+    keys = iter(jax.random.split(jax.random.PRNGKey(seed), 12))
+    B, H, Hkv, dh, ps, bs, NP, P, layer = 6, 4, 2, 16, 16, 8, 8, 60, 1
+    k_pool, v_pool = (jax.random.normal(next(keys), (2, P, Hkv, dh, ps))
+                      for _ in range(2))
+    monkeypatch.setattr(paged_attention_module, "_FOLD_BYTES",
+                        GROUP_FOLD * 2 * dh * ps * 4)
+    assert _grouping(monkeypatch, B * Hkv) == 4
+    table = np.random.default_rng(seed).permutation(
+        np.arange(1, P))[:B * NP].reshape(B, NP).astype(np.int32)
+    table[2] = 0                                  # holds no request
+    table = jnp.asarray(table)
+    lengths = jnp.asarray([100, 37, 0, 16, 128, 9], jnp.int32)
+    tail_lens = jnp.where(lengths > 0, t + 1, 0).astype(jnp.int32)
+    k_tail, v_tail = (jnp.zeros_like(plane_tail(k_pool, B, 8)).at[
+        ..., :dh].set(jax.random.normal(next(keys), (2, B, Hkv, 16, dh)))
+        for _ in range(2))
+    q = jax.random.normal(next(keys), (B, H, dh))
+    k, v = (jax.random.normal(next(keys), (B, Hkv, dh)) for _ in range(2))
+    n_blocks = NP * ps // bs
+    block = jnp.arange(n_blocks)[None, None, :]
+    chosen = jax.random.bernoulli(next(keys), 0.5, (B, Hkv, n_blocks))
+    own = ((lengths - 1) // bs)[:, None, None]
+    chosen = jnp.logical_or(jnp.logical_or(chosen, block == 0),
+                            block >= own - 1)
+    chosen = jnp.logical_and(chosen, block * bs < lengths[:, None, None])
+    put = tail_put(k_tail, v_tail, k, v, layer, t)
+    want = sparse.sparse_read_reference(
+        q, k_pool, v_pool, *put, table, chosen, lengths, tail_lens,
+        layer=layer, block_size=bs)
+    pages, bits, held = sparse.page_lists(chosen, table, lengths, ps, bs, NP)
+    got, k_out, v_out = sparse.sparse_read(
+        q, k, v, k_pool, v_pool, k_tail, v_tail, pages, bits, held,
+        tail_lens, layer=layer, block_size=bs, interpret=True)
+    live = np.asarray(lengths) > 0
+    np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live],
+                               rtol=2e-5, atol=2e-5)
+    assert not np.asarray(got)[~live].any()
+    for mine, theirs in ((k_out, put[0]), (v_out, put[1])):
+        np.testing.assert_array_equal(
+            np.asarray(mine[layer, live, ..., :dh]),
+            np.asarray(theirs[layer, live, ..., :dh]))

@@ -65,6 +65,14 @@ standing in for `fold_branch`), and tables whose live rows ALL hold n pages
 kernel still chooses: its choice is stood in for, not taken out): what a
 row costs by what it copies and by what it computes. And nemotron's
 read+tail (2 KV heads x 16 queries, 2 layers) at the same rows.
+Since PR 48 a grid step of the read walks R consecutive rows
+(`rows_a_step`) and takes a one-fold row's tail and fold in one softmax
+step: beside the rule's own line `short` times the cell's rows at R = 1 with
+the tail's step apart (the walk of a module from before PR 48, made in this
+one by standing in for the rule and for `_JOIN_ONE_FOLD`), at R = 1 with the
+tail joined, at the rule's R with the tail's step apart, and at any other R
+given as `groups=2,3`: what each part gave. `only=rows` is those lines alone
+(under a minute a module: the first look at a change to the walk).
 `only=` names the parts to run, in the order above: `pools`, `tail`,
 `latent`, `short`, `trinity` (every module's two reads of that cell; its
 experts and prefill attention beside the tree's).
@@ -253,6 +261,30 @@ def computing(module, pages):
             module.fold_branch = rule
 
 
+@contextlib.contextmanager
+def walking(module, rows, joined):
+    """Inside, a grid step of `module`'s reads walks `rows` rows whatever
+    its rule says, and a row of one fold takes its tail and its fold in one
+    softmax step or not, as `joined` says (None: as the module has them; a
+    module from before PR 48 walks a row a step and joins nothing). Yields
+    (rows a step as the rule would have them for the call given, joined)
+    through a function of the call's pools, table width and rows."""
+    rule = getattr(module, "rows_a_step", None)
+    if rule is None:
+        yield lambda *_: (1, False)
+        return
+    was = module._JOIN_ONE_FOLD
+    if rows:
+        module.rows_a_step = lambda *_: rows
+    if joined is not None:
+        module._JOIN_ONE_FOLD = joined
+    try:
+        yield lambda pools, width, n: (module.group_of(pools, width, n),
+                                       module._JOIN_ONE_FOLD)
+    finally:
+        module.rows_a_step, module._JOIN_ONE_FOLD = rule, was
+
+
 def tail_lines(label: str, module, geometry: str, device,
                rows: str = "closed") -> None:
     """The block's tail at one geometry (layers, pages, KV heads, heads)
@@ -382,7 +414,7 @@ def tail_lines(label: str, module, geometry: str, device,
 
 
 def latent_lines(label: str, module, device, peak_bytes_s: float,
-                 g: dict = LATENT) -> None:
+                 g: dict = LATENT, groups=(), rows_only=False) -> None:
     """`mla_read` at a latent geometry (`LATENT`: the `longprompt-closed`
     cell's; `SHORT`: xing's `decode-closed`), under each of its tables: one
     JSON line a fold width, and under a table of n pages a row one a width
@@ -418,7 +450,7 @@ def latent_lines(label: str, module, device, peak_bytes_s: float,
         return name, table, starts, live, live_pages, want
 
     # before the stack is made: the written layer is a pool's worth itself
-    cases = [case(name) for name in g["tables"]]
+    cases = [case(name) for name in g["tables"][:1 if rows_only else None]]
     pool = jax.jit(lambda x: jnp.tile(x[None], (layers, 1, 1, 1, 1)))(one)
     rule = getattr(module, "fold_of", lambda *_: 1)((pool,), g["table"])
 
@@ -456,29 +488,41 @@ def latent_lines(label: str, module, device, peak_bytes_s: float,
             return read(q, news[-1], pool, tail, jnp.where(live, BLOCK, 0),
                         jnp.int32(last))[0]
 
-        # (pages a fold, pages a fold is computed at): None = the module's
-        # own. A table of n pages a row: the rule's fold as it is, then
-        # computed at every width that covers n; the cell's rows: every
-        # fold width, then the rule's with nothing narrowed
+        # (pages a fold, pages a fold is computed at, rows a grid step,
+        # whether a one-fold row's tail joins its fold's softmax step):
+        # None = the module's own. A table of n pages a row: the rule's
+        # fold as it is, then computed at every width that covers n; the
+        # cell's rows: every fold width, then the rule's with nothing
+        # narrowed, then (the short rows) the walk a row a step with the
+        # tail's step apart (the walk of a module from before PR 48), each
+        # of the two parts alone, and any other `groups=` asked for
         narrows = hasattr(module, "fold_branch")
         if isinstance(name, int):
-            lines = [(None, None)]
+            lines = [(None, None, None, None)]
             if narrows:
-                lines += [(None, width) for width in reversed(
+                lines += [(None, width, None, None) for width in reversed(
                     module.fold_widths(rule)) if width >= name]
         else:
-            lines = [(pages, None) for pages in g["folds"]]
+            lines = [(pages, None, None, None) for pages in g["folds"]]
             if narrows and g is SHORT:
-                lines.append((None, rule))
-        for pages, computed in lines:
+                lines.append((None, rule, None, None))
+            if rows_only:
+                lines = [(None, None, None, None)]
+            if g is SHORT and hasattr(module, "rows_a_step"):
+                lines += [(None, None, 1, False), (None, None, 1, True),
+                          (None, None, None, False)]
+                lines += [(None, None, rows, True) for rows in groups]
+        for pages, computed, walked, joined in lines:
             if pages and not hasattr(module, "pages_per_fold"):
                 continue
             with folding(module, pages, (pool,), g["table"]) as folded, \
-                    computing(module, computed):
+                    computing(module, computed), \
+                    walking(module, walked, joined) as walk:
                 # a new function a width: jit keeps its traces by function
                 got = jax.jit(lambda *a: last_step(*a))(q, pool)
                 us = (best_of_five(jax.jit(lambda *a: run(*a)), q, pool)
                       / (layers * BLOCK) * 1e6)
+                rows_a_step, tail_joined = walk((pool,), g["table"], rows)
             print(json.dumps({
                 "device": device.device_kind, "kernel": label,
                 "what": "mla_read", "geometry": g["name"],
@@ -489,6 +533,8 @@ def latent_lines(label: str, module, device, peak_bytes_s: float,
                 "by_rule": pages is None,
                 "computed_pages": computed or (
                     "copied" if narrows else folded),
+                "rows_a_step": rows_a_step, "tail_joined": tail_joined,
+                "by_rows_rule": walked is None and joined is None,
                 "us": round(us, 1),
                 "us_a_row": round(us / int(live.sum()), 3),
                 "whole_pages_share_of_peak_pct": round(
@@ -655,7 +701,7 @@ def trinity_lines(label: str, module, device, peak_bytes_s: float) -> None:
                  1))
 
 
-PARTS = ("pools", "tail", "latent", "short", "trinity")
+PARTS = ("pools", "tail", "latent", "short", "trinity")   # and `rows`, asked for
 
 
 def main(argv) -> None:
@@ -663,10 +709,12 @@ def main(argv) -> None:
     peak_bytes_s = peaks.of(device.device_kind)["hbm_bytes_per_s"]
     only = [arg[5:].split(",") for arg in argv if arg.startswith("only=")]
     parts = only[0] if only else PARTS
+    groups = [int(rows) for arg in argv if arg.startswith("groups=")
+              for rows in arg[7:].split(",")]
     kernels = {"tree": gofr_tpu.ops.paged_attention}
     kernels.update((label, load(label, path)) for label, path in
                    (arg.split("=", 1) for arg in argv
-                    if not arg.startswith("only=")))
+                    if not arg.startswith(("only=", "groups="))))
     if "pools" in parts:
         pool_lines(kernels, device, peak_bytes_s)
     for label, module in kernels.items():
@@ -677,8 +725,12 @@ def main(argv) -> None:
         if "latent" in parts and hasattr(module, "plane_tail"):
             latent_lines(label, module, device, peak_bytes_s)
     for label, module in kernels.items():
+        if "rows" in parts and hasattr(module, "plane_tail"):
+            latent_lines(label, module, device, peak_bytes_s, SHORT, groups,
+                         rows_only=True)
+    for label, module in kernels.items():
         if "short" in parts and hasattr(module, "plane_tail"):
-            latent_lines(label, module, device, peak_bytes_s, SHORT)
+            latent_lines(label, module, device, peak_bytes_s, SHORT, groups)
             tail_lines(label, module, "nemotron", device, rows="short")
     for label, module in kernels.items():
         if "trinity" in parts and hasattr(module, "plane_tail"):
